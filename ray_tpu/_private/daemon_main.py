@@ -60,17 +60,10 @@ async def amain(args) -> None:
     # TPU topology discovery (replaces reference's GPU autodetect,
     # _private/resource_spec.py:287). Only the head claims real chips.
     if args.head and not args.no_tpu_detect:
-        try:
-            from ray_tpu._private import tpu_topology
-            resources = {**tpu_topology.detect().resource_dict(),
-                         **resources}
-            chips = _detect_tpu_chips()
-            if chips:
-                resources.setdefault("TPU", float(chips))
-            if "TPU" in resources:
-                resources.setdefault("tpu-host", 1.0)
-        except Exception:
-            pass
+        from ray_tpu._private import tpu_topology
+        resources = {**tpu_topology.detect().resource_dict(), **resources}
+        if "TPU" in resources:
+            resources.setdefault("tpu-host", 1.0)
 
     worker_env = json.loads(args.worker_env) if args.worker_env else {}
     raylet = Raylet(
@@ -130,18 +123,6 @@ async def amain(args) -> None:
         await dashboard.close()
     if gcs is not None:
         await gcs.close()
-
-
-def _detect_tpu_chips() -> int:
-    """TPU chip count without initializing a JAX backend in the daemon."""
-    env = os.environ.get("RT_NUM_TPU_CHIPS")
-    if env:
-        return int(env)
-    # Avoid importing jax here (slow, and would claim the chip); rely on
-    # device files like libtpu does.
-    import glob
-    accels = glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")
-    return len(accels)
 
 
 def main():

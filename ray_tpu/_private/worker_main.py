@@ -817,17 +817,6 @@ def main():
         for p in reversed(driver_sys_path.split(os.pathsep)):
             if p and p not in sys.path:
                 sys.path.insert(0, p)
-    # Honor JAX_PLATFORMS even when a sitecustomize imported jax at
-    # interpreter start and pinned a platform: config.update still wins as
-    # long as no backend has been initialized yet.  Without this, workers
-    # spawned with _worker_env={"JAX_PLATFORMS": "cpu"} would still grab
-    # the TPU chip on first jax use.
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms and "jax" in sys.modules:
-        try:
-            sys.modules["jax"].config.update("jax_platforms", platforms)
-        except Exception:
-            pass
     _set_proc_title("ray_tpu::worker")
 
     core = CoreWorker(

@@ -10,7 +10,7 @@ hosts sharing ICI), not an instance:
   * async provisioning — the cloud API returns long-running operations;
     the provider polls them off the autoscaler's critical path and
     surfaces nodes only when the whole slice is READY.
-  * error taxonomy — QUOTA/CAPACITY errors (common for TPU pools) are
+  * error classes — QUOTA/CAPACITY errors (common for TPU pools) are
     retried with backoff up to a budget; permanent errors mark the launch
     failed so the autoscaler's demand loop can pick a different shape.
 

@@ -19,8 +19,9 @@ Durability rules (same discipline as the spill files / BENCH_LASTGOOD):
   append) and records with a foreign schema version — a corrupt cache
   degrades to a cold cache, it never raises into the kernel call path.
 
-The file lives at ``$RT_AUTOTUNE_CACHE`` (default
-``~/.cache/ray_tpu/autotune.jsonl``) and is shared across processes:
+The file lives at ``$RT_AUTOTUNE_CACHE`` (default ``.autotune.jsonl`` at
+the root of the checkout, git-ignored: what a fresh tree does follows from
+the tree) and is shared across processes:
 ``lookup`` re-stats the file (throttled) and reloads when another process
 appended, so a sweep in one process is visible to trainers in another
 without restarts.
@@ -40,7 +41,9 @@ from typing import Any, Dict, Optional, Tuple
 from ray_tpu.autotune import metrics as _am
 
 SCHEMA_VERSION = 1
-DEFAULT_PATH = os.path.join("~", ".cache", "ray_tpu", "autotune.jsonl")
+DEFAULT_PATH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".autotune.jsonl")
 
 # How often lookup() is willing to re-stat the backing file for changes
 # made by OTHER processes.  The stat is cheap but the kernel call path is

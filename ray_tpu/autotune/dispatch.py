@@ -172,21 +172,6 @@ def choose(B: int, S: int, N: int, H: int, dtype: Any, causal: bool = True,
     return _heuristic_variant(S, allowed_t), None
 
 
-def auto_variant(B: int, S: int, N: int, H: int, dtype: Any,
-                 causal: bool = True,
-                 allowed: Tuple[str, ...] = ("flash", "dense"),
-                 mesh=None) -> str:
-    """Model-facing entry point for attention="auto": never raises,
-    never tunes unless RT_AUTOTUNE_ON_MISS=inline, returns a variant
-    name from ``allowed``."""
-    try:
-        v, _ = choose(B, S, N, H, dtype, causal, allowed=allowed,
-                      mesh=mesh)
-        return v if v in allowed else allowed[-1]
-    except Exception:
-        return allowed[-1]
-
-
 # --------------------------------------------------------------- tuning
 
 def tune_attention(B: int, S: int, N: int, H: int, dtype: Any,
@@ -318,7 +303,7 @@ def attention(q, k, v, causal: bool = True, sm_scale=None,
                         layout, mesh, cfg)
 
 
-__all__ = ["attention", "choose", "auto_variant", "tune_attention",
+__all__ = ["attention", "choose", "tune_attention",
            "choose_variant_from_timings", "applicable_variants",
            "make_splash_kernel", "clear_memo", "on_miss_mode",
            "VARIANT_OP"]

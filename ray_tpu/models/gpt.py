@@ -22,9 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
-from ray_tpu.util import jax_compat
-
-jax_compat.install()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,11 +185,8 @@ def _flash_profitable(S: int) -> bool:
     rejects sub-8 blocks, which very short or odd S would hit."""
     if S < 1024 or S % 128:
         return False
-    try:    # flash only pays off on real TPU; CPU/interpret is dense's
-        import jax as _jax
-        return _jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    # flash only pays off on real TPU; CPU/interpret is dense's
+    return jax.default_backend() != "cpu"
 
 
 def _auto_attention_variant(B: int, S: int, cfg) -> str:
@@ -202,16 +196,34 @@ def _auto_attention_variant(B: int, S: int, cfg) -> str:
     heuristic unchanged (RT_AUTOTUNE_ON_MISS=inline tunes instead).
     Only flash/dense are selectable here — ring requires an explicit
     mesh topology commitment (cfg.attention="ring")."""
-    try:
-        from ray_tpu.autotune.dispatch import choose
-        v, rec = choose(B, S, cfg.num_heads,
-                        cfg.embed_dim // cfg.num_heads, cfg.dtype,
-                        causal=True, allowed=("flash", "dense"))
-        if rec is not None:
-            return v
-    except Exception:
-        pass
+    from ray_tpu.autotune.dispatch import choose
+    v, rec = choose(B, S, cfg.num_heads, cfg.embed_dim // cfg.num_heads,
+                    cfg.dtype, causal=True, allowed=("flash", "dense"))
+    if rec is not None:
+        return v
     return "flash" if _flash_profitable(S) else "dense"
+
+
+def _flash_attention_bnsh(rules: Optional[LogicalAxisRules], mesh=None):
+    """Head-major [B,N,S,H] flash attention for a model's block.  The
+    compiler cannot partition a Mosaic kernel, so across several devices
+    it runs per shard under ``shard_map``: batch over the data axes, heads
+    over ``tp``, every shard holding whole sequences (sequence parallelism
+    is ring attention's).  ``mesh`` defaults to the one ``set_mesh`` made
+    current."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def attn_fn(q, k, v):
+        return flash_attention(q, k, v, True, None, None, None, None, "bnsh")
+
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    if rules is not None and mesh.size > 1:
+        spec = rules.spec_for(("batch", "heads", None, None))
+        attn_fn = jax.shard_map(attn_fn, mesh=mesh, in_specs=(spec,) * 3,
+                                out_specs=spec, check_vma=False)
+    attn_fn._layout = "bnsh"
+    return attn_fn
 
 
 def _dense_causal_attention(q, k, v):
@@ -319,12 +331,7 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)
     elif attention == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        def attn_fn(q, k, v):
-            return flash_attention(q, k, v, True, None, None, None, None,
-                                   "bnsh")
-        attn_fn._layout = "bnsh"
+        attn_fn = _flash_attention_bnsh(rules, mesh)
     else:
         attn_fn = _dense_causal_attention_bnsh
 

@@ -220,11 +220,8 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
         from ray_tpu.models.gpt import _auto_attention_variant
         attention = _auto_attention_variant(B, S, cfg)
     if attention == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        def attn_fn(q, k, v):
-            return flash_attention(q, k, v, True, None, None, None, None,
-                                   "bnsh")
+        from ray_tpu.models.gpt import _flash_attention_bnsh
+        attn_fn = _flash_attention_bnsh(rules, mesh)
     else:
         from ray_tpu.models.gpt import _dense_causal_attention_bnsh
 

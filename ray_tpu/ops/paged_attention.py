@@ -35,10 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.util import jax_compat
-
-jax_compat.install()
-
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     lengths: jax.Array, page_table: jax.Array, *,

@@ -27,10 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.util import jax_compat
-
-jax_compat.install()
-
 _NEG_INF = -1e30
 
 
@@ -96,22 +92,11 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sp",
     from jax.sharding import PartitionSpec as P
 
     spec = P(tuple(batch_axes), axis_name, head_axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_sharded, axis_name=axis_name),
-        mesh, (spec, spec, spec), spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return fn(q, k, v)
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (stable API, check_vma) with a fallback to the
-    pre-graduation jax.experimental.shard_map (check_rep) so ring/Ulysses
-    run on both sides of the rename."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
 
 
 def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True):

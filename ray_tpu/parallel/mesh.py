@@ -67,12 +67,8 @@ class MeshSpec:
                 f"MeshSpec needs {n} devices, only {len(devices)} available")
         devices = list(devices)[:n]
         shape = tuple(self.axis_sizes.values())
-        try:
-            from jax.experimental import mesh_utils
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=devices)
-        except Exception:
-            dev_array = np.asarray(devices).reshape(shape)
+        from jax.experimental import mesh_utils
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
         return Mesh(dev_array, axis_names=tuple(self.axis_sizes.keys()))
 
     @staticmethod

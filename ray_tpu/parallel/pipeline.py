@@ -43,10 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.util import jax_compat
-
-jax_compat.install()
-
 
 def _stage_machinery(axis_name: str):
     pp = jax.lax.psum(1, axis_name)

@@ -25,10 +25,6 @@ import numpy as np
 
 import jax
 
-from ray_tpu.util import jax_compat
-
-jax_compat.install()
-
 DP_AXIS = "dp"
 
 

@@ -51,6 +51,13 @@ class Deployment:
 
     def _spec(self) -> DeploymentSpec:
         import cloudpickle
+        opts = self.config.get("ray_actor_options") or {}
+        unknown = sorted(set(opts) - {"num_cpus", "resources",
+                                      "runtime_env"})
+        if unknown:
+            raise ValueError(
+                f"ray_actor_options {unknown} not supported (a replica "
+                'asks for its chip through {"resources": {"TPU": 1}})')
         return DeploymentSpec(
             name=self.name,
             callable_blob=cloudpickle.dumps(
@@ -60,14 +67,11 @@ class Deployment:
                 "max_concurrent_queries", 8),
             route_prefix=self.config.get("route_prefix",
                                          f"/{self.name}"),
-            resources=self.config.get("ray_actor_options", {}).get(
-                "resources"),
-            num_cpus=self.config.get("ray_actor_options", {}).get(
-                "num_cpus", 1.0),
+            resources=opts.get("resources"),
+            num_cpus=opts.get("num_cpus", 1.0),
             autoscaling=self.config.get("autoscaling_config"),
             user_config=self.config.get("user_config"),
-            runtime_env=self.config.get("ray_actor_options", {}).get(
-                "runtime_env"),
+            runtime_env=opts.get("runtime_env"),
         )
 
 

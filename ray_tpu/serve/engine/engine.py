@@ -128,17 +128,26 @@ class InferenceEngine:
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
 
-        # Jit with params/config closed over: one compile per entry
-        # point, shapes fixed ([1, max_prompt_len] prefill,
-        # [max_batch] decode), so the steady-state loop never re-traces.
-        def _prefill(tokens, length, kp, vp, pt):
-            return prefill_fn(self._params, mc, tokens, length, kp, vp, pt)
+        # One compile per entry point, shapes fixed ([1, max_prompt_len]
+        # prefill, [max_batch] decode), so the steady-state loop never
+        # re-traces.  The parameters are arguments, not closed over: as
+        # constants they would be part of the program and of its
+        # compile-cache key, one copy per entry point.
+        def _prefill(params, tokens, length, kp, vp, pt):
+            return prefill_fn(params, mc, tokens, length, kp, vp, pt)
 
-        def _decode(token, pos, kp, vp, pt):
-            return decode_fn(self._params, mc, token, pos, kp, vp, pt)
+        def _decode(params, token, pos, kp, vp, pt):
+            return decode_fn(params, mc, token, pos, kp, vp, pt)
 
         self._prefill = jax.jit(_prefill)
         self._decode = jax.jit(_decode)
+        # What stats() says about where this engine runs: the device that
+        # holds the KV pool, and how long each program's first dispatch
+        # took to finish (trace + compile or cache load + run).
+        dev = next(iter(self._k_pages.devices()))
+        self._device = {"platform": dev.platform, "kind": dev.device_kind,
+                        "count": jax.device_count()}
+        self._first_call_s: Dict[str, float] = {}
 
         self._waiting: collections.deque = collections.deque()
         self._active: Dict[int, _Sequence] = {}   # slot -> sequence
@@ -199,9 +208,11 @@ class InferenceEngine:
             seq.cancelled = True
             self._wake.set()
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
         return {"active": len(self._active), "waiting": len(self._waiting),
-                "free_pages": self._alloc.free_pages, "steps": self._steps}
+                "free_pages": self._alloc.free_pages, "steps": self._steps,
+                "device": self._device,
+                "first_call_s": dict(self._first_call_s)}
 
     def close(self):
         if self._loop_task is not None:
@@ -309,10 +320,14 @@ class InferenceEngine:
                     toks = np.zeros((1, S), np.int32)
                     toks[0, : len(seq.prompt)] = seq.prompt
                     def _run(seq=seq, toks=toks):
+                        t0 = time.perf_counter()
                         logits, kp, vp = self._prefill(
-                            toks, np.int32(len(seq.prompt)),
+                            self._params, toks, np.int32(len(seq.prompt)),
                             self._k_pages, self._v_pages, seq.row[None])
-                        return int(jnp.argmax(logits[0])), kp, vp
+                        tok = int(jnp.argmax(logits[0]))
+                        self._first_call_s.setdefault(
+                            "prefill", time.perf_counter() - t0)
+                        return tok, kp, vp
                     tok, self._k_pages, self._v_pages = \
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
@@ -340,9 +355,14 @@ class InferenceEngine:
                     pos[slot] = seq.pos
                     tables[slot] = seq.row
                 def _step():
+                    t0 = time.perf_counter()
                     logits, kp, vp = self._decode(
-                        token, pos, self._k_pages, self._v_pages, tables)
-                    return np.asarray(jnp.argmax(logits, axis=-1)), kp, vp
+                        self._params, token, pos, self._k_pages,
+                        self._v_pages, tables)
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                    self._first_call_s.setdefault(
+                        "decode", time.perf_counter() - t0)
+                    return nxt, kp, vp
                 nxt, self._k_pages, self._v_pages = \
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
@@ -387,5 +407,5 @@ class LLMServer:
                 deadline=resilience.current_deadline()):
             yield tok
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
         return self._engine.stats()

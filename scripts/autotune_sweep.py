@@ -10,7 +10,7 @@ Each shape is BxSxNxH (batch x seq x heads x head_dim).  For every shape
 the sweep tunes each applicable variant's own config (flash block_q/
 block_k grid, splash block set when the shape admits it) and persists
 the per-variant records plus the crossover winner (``attention_variant``)
-to $RT_AUTOTUNE_CACHE (default ~/.cache/ray_tpu/autotune.jsonl).  Ship
+to $RT_AUTOTUNE_CACHE (default <checkout>/.autotune.jsonl).  Ship
 that file to the fleet (or point RT_AUTOTUNE_CACHE at shared storage)
 and every worker dispatches from measured timings with zero warm-up.
 

@@ -18,12 +18,14 @@ import time
 
 import pytest
 
+_REPO_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.mark.slow
 def test_serve_survives_head_crash(tmp_path):
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, _REPO_DIR)
     import __graft_entry__ as g
-    env = {**g.hermetic_cpu_env(), "PYTHONPATH": "/root/repo",
+    env = {**g.hermetic_cpu_env(),
            "RT_SESSION_DIR": str(tmp_path / "session")}
 
     def cli(*args, timeout=120):

@@ -77,16 +77,20 @@ def test_gpt_train_step_loss_decreases(spec):
     assert losses[-1] < losses[0]
 
 
-def test_gpt_sharded_matches_single_device():
-    """Same seed, same batch: dp=8 sharded step == single-device step."""
+@pytest.mark.parametrize("attention", ["auto", "flash"])
+def test_gpt_sharded_matches_single_device(attention):
+    """Same seed, same batch: dp=8 sharded step == single-device step.
+    With "flash" the kernel runs per shard under shard_map (the compiler
+    cannot partition it), which must not change the loss either."""
+    cfg = dataclasses_replace(TINY, attention=attention)
     batch = _batch(B=8, key=7)
     tx = optax.sgd(1e-2)
 
     def run(spec_build):
         if spec_build is None:
-            params = gpt_init(jax.random.PRNGKey(0), TINY)
+            params = gpt_init(jax.random.PRNGKey(0), cfg)
             opt_state = tx.init(params)
-            step = make_train_step(TINY, tx, None, donate=False)
+            step = make_train_step(cfg, tx, None, donate=False)
             for _ in range(2):
                 params, opt_state, m = step(params, opt_state, batch)
             return float(m["loss"])
@@ -94,10 +98,10 @@ def test_gpt_sharded_matches_single_device():
         mesh = spec.build()
         rules = LogicalAxisRules.for_transformer(spec)
         with jax.sharding.set_mesh(mesh):
-            params = gpt_init(jax.random.PRNGKey(0), TINY)
-            params = shard_params(params, mesh, rules, gpt_param_axes(TINY))
+            params = gpt_init(jax.random.PRNGKey(0), cfg)
+            params = shard_params(params, mesh, rules, gpt_param_axes(cfg))
             opt_state = tx.init(params)
-            step = make_train_step(TINY, tx, rules, donate=False)
+            step = make_train_step(cfg, tx, rules, donate=False)
             for _ in range(2):
                 params, opt_state, m = step(params, opt_state, batch)
             return float(m["loss"])

@@ -5,9 +5,11 @@ forms a real torch.distributed group (rank-0 TCP rendezvous), DDP
 averages gradients across workers, metrics flow via session.report.
 """
 
+import os
 import subprocess
 import sys
 
+_REPO_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCRIPT = """
 import numpy as np
@@ -57,9 +59,9 @@ print("TORCH_TRAINER_OK", round(result.metrics["loss"], 4))
 
 
 def test_torch_trainer_ddp_end_to_end():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, _REPO_DIR)
     import __graft_entry__ as g
-    env = {**g.hermetic_cpu_env(), "PYTHONPATH": "/root/repo"}
+    env = g.hermetic_cpu_env()
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]

@@ -1,0 +1,234 @@
+"""The main path's programs, compiled for a v5e chip that is described and
+not attached (on-chip-measurement guide, section 2.3).
+
+Nothing runs, so these say nothing about results or times: they say that
+the chip's compiler accepts each program at its real size — kernel tiling,
+fast-memory use, device memory, partitioning — which interpret mode on the
+CPU cannot.  Each case lowers with explicit shapes on ``topo.devices`` and
+asserts ``tpu_custom_call`` wherever a Pallas kernel must be in the text.
+Code that picks interpret mode from ``jax.default_backend()`` would pick it
+here (the backend is the CPU), so the cases pass ``interpret=False``
+themselves, or steer ``_resolve`` where a model makes the call.
+"""
+
+import dataclasses
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models.gpt import (GPTConfig, gpt_decode_step, gpt_init,
+                                gpt_param_axes, gpt_prefill,
+                                init_paged_cache, make_train_step)
+from ray_tpu.ops.ring_attention import make_ring_attention_fn
+from ray_tpu.parallel import LogicalAxisRules, MeshSpec
+from ray_tpu.parallel.sharding import logical_sharding
+
+# the module: ray_tpu.ops re-exports the function under the same name
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
+
+# GPT-2-small as published (vocab 50257, not the padded default), at the
+# sizes chip_smoke.py trains and serves it.
+GPT2 = GPTConfig(vocab_size=50257, attention="flash", remat=True,
+                 remat_policy="dots", ce_block=256)
+B, S = 32, 1024
+PAGE, MAX_PROMPT, MAX_NEW, MAX_BATCH = 16, 512, 128, 16
+MAXP = (MAX_PROMPT + MAX_NEW) // PAGE
+NUM_PAGES = MAX_BATCH * MAXP + 1
+SERVE = dataclasses.replace(GPT2, max_seq_len=MAX_PROMPT + MAX_NEW)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: the next one would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Models call flash_attention with interpret=None; make that mean the
+    compiled kernel, as it does on the chip."""
+    real = fa._resolve
+    monkeypatch.setattr(
+        fa, "_resolve",
+        lambda q, causal, bq, bk, interpret, layout:
+            real(q, causal, bq, bk, False, layout))
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one chip"
+    return used
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` (from eval_shape) placed by ``sharding`` (one
+    sharding, or a matching tree of them)."""
+    if not isinstance(sharding, dict):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding)
+
+
+# ------------------------------------------------------------------ kernels
+
+def _flash(q, k, v, layout="bsnh"):
+    return fa.flash_attention(q, k, v, True, None, None, None, False, layout)
+
+
+def _flash_gqa(q, k, v):
+    # models/llama.py: head-major, K/V repeated up to the query heads.
+    rep = q.shape[1] // k.shape[1]
+    return _flash(q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1),
+                  "bnsh")
+
+
+def _grads(fn):
+    return jax.grad(lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+FLASH_CASES = {
+    "train-32x1024": (_flash, (32, 1024, 12, 64), (32, 1024, 12, 64)),
+    "long-2x4096": (_flash, (2, 4096, 12, 64), (2, 4096, 12, 64)),
+    "gqa-12q4kv": (_flash_gqa, (8, 12, 1024, 64), (8, 4, 1024, 64)),
+}
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", FLASH_CASES)
+def test_flash_kernel_compiles(topo, case, direction):
+    fn, q_shape, kv_shape = FLASH_CASES[case]
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16, sharding=one)
+    compiled, text = _compile(fn if direction == "fwd" else _grads(fn),
+                              q, kv, kv)
+    # forward is one kernel; backward runs it again, then dq and dk/dv
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    _fits(compiled)
+
+
+# --------------------------------------------------------------- train step
+
+def _train_step_args(cfg, mesh, rules, tx):
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    shardings = jax.tree.map(
+        lambda ann: logical_sharding(mesh, rules, ann), gpt_param_axes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))
+    params = _on(shardings, params)
+    # What tx.init gives on placed parameters: each moment lies as its
+    # parameter does, and the step count is replicated.
+    opt = optax.tree_map_params(
+        tx, lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(tx.init, params), shardings,
+        transform_non_params=lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P())))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (B, S + 1), jnp.int32,
+        sharding=logical_sharding(mesh, rules, ("batch", None)))}
+    return params, opt, batch
+
+
+@pytest.mark.parametrize("spec", [MeshSpec(), MeshSpec(fsdp=2, tp=2)],
+                         ids=["1chip", "fsdp2xtp2"])
+def test_gpt2_small_train_step_compiles(topo, compiled_kernels, spec):
+    mesh = spec.build(devices=topo.devices[:spec.num_devices])
+    rules = LogicalAxisRules.for_transformer(spec)
+    tx = optax.adamw(3e-4, b2=0.95)
+    args = _train_step_args(GPT2, mesh, rules, tx)
+    with jax.sharding.set_mesh(mesh):
+        compiled = make_train_step(GPT2, tx, rules).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "flash kernel missing from the step"
+    if spec.num_devices > 1:
+        assert "all-reduce" in text or "reduce-scatter" in text
+    _fits(compiled)
+
+
+def test_moe_train_step_compiles(topo):
+    # ops/moe.py is jax.numpy: only its step needs the compiler's word.
+    cfg = GPTConfig(vocab_size=50257, num_layers=2, attention="dense",
+                    remat=True, remat_policy="dots", ce_block=256,
+                    num_experts=8, expert_top_k=2)
+    spec = MeshSpec()
+    mesh = spec.build(devices=topo.devices[:1])
+    rules = LogicalAxisRules.for_transformer(spec)
+    tx = optax.adamw(3e-4, b2=0.95)
+    params, opt, _ = _train_step_args(cfg, mesh, rules, tx)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (8, S + 1), jnp.int32, sharding=NamedSharding(mesh, P()))}
+    with jax.sharding.set_mesh(mesh):
+        _fits(make_train_step(cfg, tx, rules).lower(params, opt, batch)
+              .compile())
+
+
+# ------------------------------------------------------------------ serving
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_paged_engine_program_compiles(topo, program):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: gpt_init(jax.random.PRNGKey(0), SERVE)))
+    kp, vp = _on(one, jax.eval_shape(
+        lambda: init_paged_cache(SERVE, NUM_PAGES, PAGE)))
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    if program == "prefill":
+        compiled, _ = _compile(
+            lambda p, *a: gpt_prefill(p, SERVE, *a),
+            params, arg((1, MAX_PROMPT)), arg(()), kp, vp, arg((1, MAXP)))
+    else:
+        compiled, _ = _compile(
+            lambda p, *a: gpt_decode_step(p, SERVE, *a),
+            params, arg((MAX_BATCH,)), arg((MAX_BATCH,)), kp, vp,
+            arg((MAX_BATCH, MAXP)))
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------- four chips
+
+def test_ring_attention_sp4_compiles(topo):
+    mesh = MeshSpec(sp=4).build(devices=topo.devices)
+    x = jax.ShapeDtypeStruct(
+        (1, 8192, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), "sp", "tp", None)))
+    compiled, text = _compile(_grads(make_ring_attention_fn(mesh)), x, x, x)
+    assert "collective-permute" in text
+    _fits(compiled)

@@ -6,12 +6,14 @@ round-trips into a usable model).  Runs hermetically: the model is
 built from a config (no pretrained download).
 """
 
+import os
 import subprocess
 import sys
 
 import numpy as np
 import pytest
 
+_REPO_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCRIPT = """
 import numpy as np
@@ -51,9 +53,9 @@ print("TRANSFORMERS_TRAINER_OK")
 
 
 def test_transformers_trainer_end_to_end():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, _REPO_DIR)
     import __graft_entry__ as g
-    env = {**g.hermetic_cpu_env(), "PYTHONPATH": "/root/repo"}
+    env = g.hermetic_cpu_env()
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
