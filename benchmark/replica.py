@@ -1,0 +1,182 @@
+"""The replica the serve cells deploy: the program's ``LLMServer`` with the
+configuration's weights made on its device from the seed, and the
+benchmark's own instruments around it.
+
+Everything here runs in the worker that leased the chip.  The instruments
+read the program from outside: the engine's ``stats()``, the time a request
+enters and first yields, the profiler, the device's memory statistics.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import os
+import time
+
+from ray_tpu.serve.engine import EngineConfig, LLMServer
+
+from benchmark import spec, trace_reduce
+
+POLL_S = 0.1
+
+
+def seeded_key(seed: int):
+    """A key from any whole number up to a little over 2**31."""
+    import jax
+    return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
+                              seed >> 31)
+
+
+def device_report() -> dict:
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def memory_peak_bytes() -> int:
+    """Peak on the fullest device.  The TPU's allocator counts live arrays
+    (``bytes_in_use``) apart from what it sets aside for the programs'
+    temporaries (``bytes_reserved``), so the footprint is the larger of the
+    peak of the first and the first plus the peak of the second.  The CPU of
+    a rehearsal reports nothing."""
+    import jax
+    peaks = [0]
+    for device in jax.devices():
+        stats = device.memory_stats() or {}
+        peaks.append(max(stats.get("peak_bytes_in_use", 0),
+                         stats.get("bytes_in_use", 0)
+                         + stats.get("peak_bytes_reserved", 0)))
+    return max(peaks)
+
+
+def find_xplane(trace_dir: str) -> str:
+    for folder, _, files in os.walk(trace_dir):
+        for name in files:
+            if name.endswith(".xplane.pb"):
+                return os.path.join(folder, name)
+    raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+
+
+class BenchLLMServer(LLMServer):
+    def __init__(self, config: dict, seed: int, trace_dir: str):
+        import jax
+        started = time.perf_counter()
+        self._family = spec.load_part("families", config["family"])
+        self._config, self._seed = config, seed
+        self._trace_dir = trace_dir
+        engine = config["engine"]
+        self._model = self._family.program_config(
+            config, engine["max_prompt_len"] + engine["max_new_tokens"])
+        params = jax.jit(lambda key: self._family.init(key, self._model))(
+            seeded_key(seed))
+        super().__init__(EngineConfig(model=self._family.ENGINE_MODEL,
+                                      model_config=self._model, **engine),
+                         params=params)
+        jax.block_until_ready(self._engine._params)
+        self._phases = {"replica_init_s": time.perf_counter() - started}
+        self._seen = {}       # request id -> [entered, first yield]
+        self._polls = []      # (active, waiting)
+        self._traced = False
+        self._steps_at_start = 0
+
+    async def __call__(self, payload):
+        stamp = self._seen.setdefault(payload.get("id"),
+                                      [time.perf_counter(), None])
+        async for token in super().__call__(payload):
+            if stamp[1] is None:
+                stamp[1] = time.perf_counter()
+            yield token
+
+    def check_numerics(self) -> dict:
+        """Prefill and eight decode positions through the paged cache, by
+        the engine's own two programs, against the reference's full forward
+        on two seeded sequences.  Warms both programs up on the way.  The
+        engine's pools are left as they were."""
+        import jax
+        import numpy as np
+        eng, cfg = self._engine, self._engine.config
+        steps, errs = 8, []
+        started = time.perf_counter()
+        key = seeded_key(self._seed + 1)
+        lengths = (cfg.max_prompt_len // 8 + 5, cfg.max_prompt_len // 16 + 3)
+        reference = jax.jit(lambda p, t: self._family.reference_forward(
+            p, t, self._config))
+        for n, prompt_len in enumerate(lengths):
+            tokens = np.asarray(jax.random.randint(
+                jax.random.fold_in(key, n), (prompt_len + steps,), 0,
+                self._model.vocab_size), np.int32)
+            # one shape for both: causal, so what follows changes nothing
+            padded = np.zeros((1, max(lengths) + steps), np.int32)
+            padded[0, :len(tokens)] = tokens
+            want = np.asarray(reference(eng._params, padded)[0])
+            table = np.zeros((cfg.max_batch, eng._maxp), np.int32)
+            table[0] = np.arange(1, eng._maxp + 1)
+            padded = np.zeros((1, cfg.max_prompt_len), np.int32)
+            padded[0, :prompt_len] = tokens[:prompt_len]
+            logits, kp, vp = eng._prefill(
+                eng._params, padded, np.int32(prompt_len), eng._k_pages,
+                eng._v_pages, table[:1])
+            got = [np.asarray(logits[0])]
+            tok = np.zeros((cfg.max_batch,), np.int32)
+            pos = np.zeros((cfg.max_batch,), np.int32)
+            for i in range(steps):
+                tok[0], pos[0] = tokens[prompt_len + i], prompt_len + i
+                logits, kp, vp = eng._decode(eng._params, tok, pos, kp, vp,
+                                             table)
+                got.append(np.asarray(logits[0]))
+            want = want[prompt_len - 1:len(tokens)]
+            errs.append(float(np.linalg.norm(np.stack(got) - want)
+                              / np.linalg.norm(want)))
+            del kp, vp
+        self._phases["check_numerics_s"] = time.perf_counter() - started
+        # the tolerance is the configuration's, written there with its reason
+        rtol = self._config["numerics"]["logits_rtol"]
+        return {"device": device_report(), "logits_rel_err": errs,
+                "rtol": rtol, "positions": steps + 1,
+                "ok": max(errs) <= rtol}
+
+    async def observe(self, seconds: float, trace_after_s: float,
+                      trace_for_s: float) -> None:
+        """For a traced run: poll the engine's ``stats()`` through the
+        window, and profile ``trace_for_s`` seconds of it."""
+        import jax
+        loop = asyncio.get_running_loop()
+        self._steps_at_start = self._engine.stats()["steps"]
+        end = time.perf_counter() + seconds
+        trace_at = time.perf_counter() + trace_after_s
+        tracing = False
+        while time.perf_counter() < end:
+            stats = self._engine.stats()
+            self._polls.append((stats["active"], stats["waiting"]))
+            now = time.perf_counter()
+            if not tracing and not self._traced and now >= trace_at:
+                await loop.run_in_executor(
+                    None, jax.profiler.start_trace, self._trace_dir)
+                tracing = True
+            elif tracing and now >= trace_at + trace_for_s:
+                await loop.run_in_executor(None, self._stop_trace)
+                tracing = False
+            await asyncio.sleep(POLL_S)
+        if tracing:
+            await loop.run_in_executor(None, self._stop_trace)
+
+    def _stop_trace(self) -> None:
+        import jax
+        jax.profiler.stop_trace()
+        self._traced = True
+
+    def collect(self) -> dict:
+        """After the window: what the instruments gathered, the trace
+        reduced here so that reading it cost the window nothing."""
+        stats = self._engine.stats()
+        trace = trace_reduce.reduce_events(trace_reduce.read_xplane(
+            find_xplane(self._trace_dir))) if self._traced else {}
+        return {"memory_peak_bytes": memory_peak_bytes(),
+                "replica_ttft_s": {k: v[1] - v[0]
+                                   for k, v in self._seen.items()
+                                   if k is not None and v[1] is not None},
+                "polls": self._polls, "trace": trace,
+                "max_batch": self._engine.config.max_batch,
+                "decode_steps": stats["steps"] - self._steps_at_start,
+                "phases": self._phases}
