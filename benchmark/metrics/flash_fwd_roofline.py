@@ -1,0 +1,9 @@
+"""The flash-attention forward kernel's (``flash_fwd``, the calls that the
+backward pass recomputes included) share of its roofline: the least time
+the chip could take for its calls over their device time."""
+
+from benchmark import host_regions
+
+
+def read(run):
+    return host_regions.flash_kernel_roofline(run, "fwd")

@@ -391,6 +391,7 @@ class TaskExecutor:
             raise TypeError(
                 'num_returns="streaming" requires the task to return a '
                 f"generator or async generator, got {type(result).__name__}")
+        from ray_tpu.util import tracing
         self._streaming_calls.add(task_id_hex)
         refs = []
         i = 0
@@ -412,12 +413,19 @@ class TaskExecutor:
                 i += 1
                 oid = ObjectID.for_task_return(task_id, i)
                 entry = await self.core.store_return_value_async(oid, value)
+                sent = time.perf_counter()
                 try:
                     ack = await conn.request(
                         {"type": "stream_yield", "task_id": task_id_hex,
                          "index": i, "entry": entry}, timeout=60)
                 except Exception:
                     ack = {"ok": False}   # owner died/unreachable: stop
+                # The wait spans an await, so it rides as an attribute of
+                # a region entered and left when the ack arrives.
+                with tracing.region(
+                        "stream.yield", index=i,
+                        ack_us=int((time.perf_counter() - sent) * 1e6)):
+                    pass
                 if not ack.get("ok"):
                     try:
                         await close()

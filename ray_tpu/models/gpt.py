@@ -515,19 +515,29 @@ def gpt_loss(params, batch: Dict[str, jax.Array], cfg: GPTConfig,
     logits memory to one microbatch."""
     toks = batch["tokens"]
     targets = toks[:, 1:]
-    aux = jnp.zeros((), jnp.float32)
     if forward_fn is not None:
         logits = forward_fn(params, toks[:, :-1])
-    elif cfg.ce_block:
-        x, aux = gpt_hidden(params, toks[:, :-1], cfg, rules, mesh)
-        ll = blocked_ce_loglike_sum(x, params["wte"].astype(cfg.dtype),
-                                    targets, cfg.ce_block, "vd")
-        return -ll / targets.size + cfg.moe_aux_coef * aux
-    else:
-        logits, aux = gpt_forward_with_aux(params, toks[:, :-1], cfg, rules,
-                                           mesh, keep_dtype=True)
-    return -jnp.mean(token_loglikes(logits, targets)) \
-        + cfg.moe_aux_coef * aux
+        return -jnp.mean(token_loglikes(logits, targets))
+    x, aux = gpt_hidden(params, toks[:, :-1], cfg, rules, mesh)
+    ll = ce_head_loglike_sum(x, params["wte"].astype(cfg.dtype), targets,
+                             cfg.ce_block, "vd")
+    return -ll / targets.size + cfg.moe_aux_coef * aux
+
+
+def ce_head_loglike_sum(x: jax.Array, head: jax.Array, targets: jax.Array,
+                        block: int, head_layout: str) -> jax.Array:
+    """Sum of next-token loglikes from the final hidden states ``x``
+    [B, S, D]: the head product and the cross-entropy, blocked over the
+    sequence where ``block`` is set.  The ``ce_head`` scope names these
+    operations, forward and backward, in a profile.  The plain form keeps
+    its logits in the compute dtype: the fused loss upcasts inside its
+    reductions (see gpt_forward_with_aux)."""
+    with jax.named_scope("ce_head"):
+        if block:
+            return blocked_ce_loglike_sum(x, head, targets, block,
+                                          head_layout)
+        eq = "bsd,vd->bsv" if head_layout == "vd" else "bsd,dv->bsv"
+        return jnp.sum(token_loglikes(jnp.einsum(eq, x, head), targets))
 
 
 def blocked_ce_loglike_sum(x: jax.Array, head: jax.Array,
@@ -628,9 +638,10 @@ def make_train_step(cfg: GPTConfig, tx,
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
         import optax
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 

@@ -28,7 +28,7 @@ import numpy as np
 
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
-from ray_tpu.models.gpt import token_loglikes
+from ray_tpu.models.gpt import ce_head_loglike_sum
 from ray_tpu.parallel.sharding import (LogicalAxisRules,
                                        with_logical_constraint)
 
@@ -373,14 +373,11 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     ``token_loglikes`` core (and the blocked-CE head via ``cfg.ce_block``)
     with GPT."""
     toks = batch["tokens"]
-    if cfg.ce_block:
-        from ray_tpu.models.gpt import blocked_ce_loglike_sum
-        x = llama_hidden(params, toks[:, :-1], cfg, rules, mesh)
-        return -blocked_ce_loglike_sum(
-            x, params["lm_head"].astype(cfg.dtype), toks[:, 1:],
-            cfg.ce_block, "dv") / toks[:, 1:].size
-    logits = llama_forward(params, toks[:, :-1], cfg, rules, mesh)
-    return -jnp.mean(token_loglikes(logits, toks[:, 1:]))
+    targets = toks[:, 1:]
+    x = llama_hidden(params, toks[:, :-1], cfg, rules, mesh)
+    ll = ce_head_loglike_sum(x, params["lm_head"].astype(cfg.dtype),
+                             targets, cfg.ce_block, "dv")
+    return -ll / targets.size
 
 
 def make_train_step(cfg: LlamaConfig, tx,

@@ -203,6 +203,7 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, H), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return _unfold(of), lse[:, :, 0]
 
@@ -345,6 +346,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((B * N, S, H), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, H), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qf, kf, vf, dof, lse_l, delta_l)
 
     dkv_kernel = functools.partial(_dkv_kernel, causal=causal, sm_scale=scale)
@@ -372,6 +374,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
             pltpu.VMEM((block_k, H), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(kf, vf, qf, dof, lse_l, delta_l)
 
     return _unfold(dqf), _unfold(dkf), _unfold(dvf)

@@ -57,8 +57,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     S = maxp * page
 
     # Gather each sequence's pages: [NKV, B, maxp, page, H] -> [NKV, B, S, H]
-    k = k_pages[:, page_table].reshape(NKV, B, S, H)
-    v = v_pages[:, page_table].reshape(NKV, B, S, H)
+    with jax.named_scope("paged_read"):
+        k = k_pages[:, page_table].reshape(NKV, B, S, H)
+        v = v_pages[:, page_table].reshape(NKV, B, S, H)
 
     qg = q.reshape(B, NKV, rep, H)
     scores = jnp.einsum("bkrh,kbsh->bkrs", qg, k) * scale
@@ -81,13 +82,14 @@ def append_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
     all-zero page-table row and pos 0.
     """
     page = k_pages.shape[2]
-    pid = jnp.take_along_axis(page_table, (pos // page)[:, None],
-                              axis=1)[:, 0]                  # [B]
-    slot = pos % page
-    k_new = jnp.swapaxes(k_new, 0, 1).astype(k_pages.dtype)  # [NKV, B, H]
-    v_new = jnp.swapaxes(v_new, 0, 1).astype(v_pages.dtype)
-    return (k_pages.at[:, pid, slot].set(k_new),
-            v_pages.at[:, pid, slot].set(v_new))
+    with jax.named_scope("paged_append"):
+        pid = jnp.take_along_axis(page_table, (pos // page)[:, None],
+                                  axis=1)[:, 0]                  # [B]
+        slot = pos % page
+        k_new = jnp.swapaxes(k_new, 0, 1).astype(k_pages.dtype)  # [NKV,B,H]
+        v_new = jnp.swapaxes(v_new, 0, 1).astype(v_pages.dtype)
+        return (k_pages.at[:, pid, slot].set(k_new),
+                v_pages.at[:, pid, slot].set(v_new))
 
 
 def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, k_seq: jax.Array,
@@ -101,11 +103,12 @@ def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, k_seq: jax.Array,
     """
     page = k_pages.shape[2]
     S = k_seq.shape[1]
-    pos = jnp.arange(S)
-    pid = jnp.where(pos < length, page_table_row[pos // page], 0)
-    slot = pos % page
-    return (k_pages.at[:, pid, slot].set(k_seq.astype(k_pages.dtype)),
-            v_pages.at[:, pid, slot].set(v_seq.astype(v_pages.dtype)))
+    with jax.named_scope("paged_append"):
+        pos = jnp.arange(S)
+        pid = jnp.where(pos < length, page_table_row[pos // page], 0)
+        slot = pos % page
+        return (k_pages.at[:, pid, slot].set(k_seq.astype(k_pages.dtype)),
+                v_pages.at[:, pid, slot].set(v_seq.astype(v_pages.dtype)))
 
 
 def sharded_paged_attention(mesh, *, model_axis: str = "model",

@@ -23,6 +23,14 @@ decode runs the whole batch (fixed shape [max_batch]) with inactive
 slots parked on scratch page 0.  Both are jitted once; dispatches run on
 a single-thread executor so the actor's event loop keeps serving
 admissions and cancellations while XLA computes.
+
+Observability: every synchronous section of the per-token path is a
+``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
+``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
+profile beside the device's programs (``LLMServer.profile``); the two
+thread crossings of a step ride as the ``submit_us`` and ``resume_us``
+attributes of the region that follows them.  ``stats()`` carries the
+always-on counters of the same places.
 """
 
 from __future__ import annotations
@@ -39,6 +47,7 @@ import numpy as np
 
 from ray_tpu.serve import resilience
 from ray_tpu.serve.engine.kv_cache import PageAllocator, table_row
+from ray_tpu.util.tracing import region
 
 logger = logging.getLogger(__name__)
 
@@ -61,7 +70,7 @@ class EngineConfig:
 class _Sequence:
     __slots__ = ("prompt", "max_new", "pages", "row", "queue", "generated",
                  "pos", "last_token", "cancelled", "slot", "prefilled",
-                 "deadline")
+                 "deadline", "queued")
 
     def __init__(self, prompt: List[int], max_new: int,
                  deadline: Optional[float] = None):
@@ -77,6 +86,7 @@ class _Sequence:
         self.cancelled = False
         self.slot: Optional[int] = None
         self.prefilled = False
+        self.queued = time.perf_counter()   # generate() to prefill: the wait
 
 
 class InferenceEngine:
@@ -155,6 +165,14 @@ class InferenceEngine:
         self._wake = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         self._steps = 0
+        # Always-on counters, see stats().
+        self._admitted = 0
+        self._queue_wait_s = 0.0
+        self._prefill_tokens = 0
+        self._prefill_padded_tokens = 0
+        self._slot_steps = 0
+        self._retired = {"done": 0, "cancelled": 0, "expired": 0,
+                         "error": 0}
         # Single lane for XLA dispatches: the device serializes anyway,
         # and one lane keeps (k_pages, v_pages) updates ordered.
         self._exec = concurrent.futures.ThreadPoolExecutor(
@@ -209,8 +227,21 @@ class InferenceEngine:
             self._wake.set()
 
     def stats(self) -> Dict[str, Any]:
+        """Gauges (``active``, ``waiting``, ``free_pages``) and counters
+        since the engine started: ``steps`` decode steps and the
+        ``slot_steps`` live slots they carried (occupancy is
+        ``slot_steps / (steps * max_batch)``), ``admitted`` sequences and
+        the ``queue_wait_s`` they spent between ``generate()`` and their
+        prefill's dispatch, ``prefill_tokens`` of prompt against the
+        ``prefill_padded_tokens`` the padded program ran, and ``retired``
+        sequences by reason."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
+                "slot_steps": self._slot_steps, "admitted": self._admitted,
+                "queue_wait_s": self._queue_wait_s,
+                "prefill_tokens": self._prefill_tokens,
+                "prefill_padded_tokens": self._prefill_padded_tokens,
+                "retired": dict(self._retired),
                 "device": self._device,
                 "first_call_s": dict(self._first_call_s)}
 
@@ -263,16 +294,58 @@ class InferenceEngine:
             seq.row = table_row(seq.pages, self._maxp)
             seq.slot = self._free_slots.pop()
             self._active[seq.slot] = seq
+            self._admitted += 1
 
-    def _retire(self, seq: _Sequence, done: bool = True):
+    def _retire(self, seq: _Sequence, reason: str):
+        """Free the sequence's slot and pages; ``reason`` is its key in
+        ``stats()["retired"]``, and "done" also ends the caller's stream."""
         self._active.pop(seq.slot, None)
         self._free_slots.append(seq.slot)
         seq.slot = None
         if seq.pages:
             self._alloc.free(seq.pages)
             seq.pages = []
-        if done and not seq.cancelled:
+        self._retired[reason] += 1
+        if reason == "done":
             seq.queue.put_nowait(_DONE)
+
+    def _sweep(self):
+        for seq in [s for s in self._active.values() if s.cancelled]:
+            self._retire(seq, "cancelled")
+        # Deadline sweep: an expired sequence stops decoding NOW — its
+        # slot and KV pages free for live requests and the rest of the
+        # batch keeps stepping unharmed.
+        for seq in [s for s in self._active.values()
+                    if self._deadline_expired(s)]:
+            self._retire(seq, "expired")
+            if not seq.cancelled:
+                seq.queue.put_nowait(resilience.DeadlineExceeded(
+                    "deadline expired while decoding"))
+
+    def _decode_inputs(self):
+        """One batched decode step's host arrays over every live slot.
+        Inactive slots run token 0 at pos 0 against an all-zero table
+        row — their writes land in scratch page 0."""
+        cfg = self.config
+        token = np.zeros((cfg.max_batch,), np.int32)
+        pos = np.zeros((cfg.max_batch,), np.int32)
+        tables = np.zeros((cfg.max_batch, self._maxp), np.int32)
+        for slot, seq in self._active.items():
+            token[slot] = seq.last_token
+            pos[slot] = seq.pos
+            tables[slot] = seq.row
+        return token, pos, tables
+
+    def _deliver(self, tokens: Dict[int, int], returned: float):
+        """Push each slot's token to its caller and retire what finished;
+        ``returned`` is when the exec thread handed the tokens back."""
+        with region("engine.deliver", tokens=len(tokens),
+                    resume_us=int((time.perf_counter() - returned) * 1e6)):
+            for slot, token in tokens.items():
+                seq = self._active[slot]
+                if self._push(seq, token) or seq.cancelled:
+                    self._retire(seq, "cancelled" if seq.cancelled
+                                 else "done")
 
     def _push(self, seq: _Sequence, token: int) -> bool:
         """Deliver one token; returns True when the sequence is finished
@@ -292,18 +365,16 @@ class InferenceEngine:
         S = cfg.max_prompt_len
         while True:
             try:
-                for seq in [s for s in self._active.values() if s.cancelled]:
-                    self._retire(seq, done=False)
-                # Deadline sweep: an expired sequence stops decoding NOW —
-                # its slot and KV pages free for live requests and the
-                # rest of the batch keeps stepping unharmed.
-                for seq in [s for s in self._active.values()
-                            if self._deadline_expired(s)]:
-                    self._retire(seq, done=False)
-                    if not seq.cancelled:
-                        seq.queue.put_nowait(resilience.DeadlineExceeded(
-                            "deadline expired while decoding"))
-                self._admit()
+                with region("engine.schedule", active=len(self._active),
+                            waiting=len(self._waiting)):
+                    self._sweep()
+                    self._admit()
+                    fresh = [s for s in self._active.values()
+                             if not s.prefilled]
+                    # with nobody to prefill the batch is settled: its
+                    # arrays are part of the same stretch of host work
+                    batch = self._decode_inputs() \
+                        if self._active and not fresh else None
                 if not self._active:
                     if self._waiting:
                         continue   # admission makes progress every pass
@@ -315,24 +386,32 @@ class InferenceEngine:
                     continue
 
                 # Prefill new admissions one at a time (B=1, one shape).
-                for seq in [s for s in self._active.values()
-                            if not s.prefilled]:
+                for seq in fresh:
                     toks = np.zeros((1, S), np.int32)
                     toks[0, : len(seq.prompt)] = seq.prompt
-                    def _run(seq=seq, toks=toks):
+                    submitted = time.perf_counter()
+                    self._queue_wait_s += submitted - seq.queued
+                    self._prefill_tokens += len(seq.prompt)
+                    self._prefill_padded_tokens += S
+
+                    def _run(seq=seq, toks=toks, submitted=submitted):
                         t0 = time.perf_counter()
-                        logits, kp, vp = self._prefill(
-                            self._params, toks, np.int32(len(seq.prompt)),
-                            self._k_pages, self._v_pages, seq.row[None])
-                        tok = int(jnp.argmax(logits[0]))
+                        with region("engine.prefill",
+                                    prompt_len=len(seq.prompt), padded_len=S,
+                                    waited_us=int((t0 - seq.queued) * 1e6),
+                                    submit_us=int((t0 - submitted) * 1e6)):
+                            logits, kp, vp = self._prefill(
+                                self._params, toks,
+                                np.int32(len(seq.prompt)), self._k_pages,
+                                self._v_pages, seq.row[None])
+                            tok = int(jnp.argmax(logits[0]))
                         self._first_call_s.setdefault(
                             "prefill", time.perf_counter() - t0)
-                        return tok, kp, vp
-                    tok, self._k_pages, self._v_pages = \
+                        return tok, kp, vp, time.perf_counter()
+                    tok, self._k_pages, self._v_pages, returned = \
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
-                    if self._push(seq, tok) or seq.cancelled:
-                        self._retire(seq, done=not seq.cancelled)
+                    self._deliver({seq.slot: tok}, returned)
 
                 if not self._active:
                     continue
@@ -344,38 +423,41 @@ class InferenceEngine:
                 stall = fault_injection.stall_replica_decode_s()
                 if stall:
                     await asyncio.sleep(stall)
-                # One batched decode step over every live slot.  Inactive
-                # slots run token 0 at pos 0 against an all-zero table
-                # row — their writes land in scratch page 0.
-                token = np.zeros((cfg.max_batch,), np.int32)
-                pos = np.zeros((cfg.max_batch,), np.int32)
-                tables = np.zeros((cfg.max_batch, self._maxp), np.int32)
-                for slot, seq in self._active.items():
-                    token[slot] = seq.last_token
-                    pos[slot] = seq.pos
-                    tables[slot] = seq.row
+                if batch is None:   # the prefills changed the batch
+                    with region("engine.schedule", active=len(self._active),
+                                waiting=len(self._waiting)):
+                        batch = self._decode_inputs()
+                token, pos, tables = batch
+                active = len(self._active)
+                submitted = time.perf_counter()
+
                 def _step():
                     t0 = time.perf_counter()
-                    logits, kp, vp = self._decode(
-                        self._params, token, pos, self._k_pages,
-                        self._v_pages, tables)
-                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                    with region("engine.decode.dispatch", active=active,
+                                submit_us=int((t0 - submitted) * 1e6)):
+                        logits, kp, vp = self._decode(
+                            self._params, token, pos, self._k_pages,
+                            self._v_pages, tables)
+                        nxt = jnp.argmax(logits, axis=-1)
+                    with region("engine.decode.fetch"):
+                        nxt = np.asarray(nxt)
                     self._first_call_s.setdefault(
                         "decode", time.perf_counter() - t0)
-                    return nxt, kp, vp
-                nxt, self._k_pages, self._v_pages = \
+                    return nxt, kp, vp, time.perf_counter()
+                nxt, self._k_pages, self._v_pages, returned = \
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
-                for slot, seq in list(self._active.items()):
+                self._slot_steps += active
+                for seq in self._active.values():
                     seq.pos += 1
-                    if self._push(seq, int(nxt[slot])) or seq.cancelled:
-                        self._retire(seq, done=not seq.cancelled)
+                self._deliver({slot: int(nxt[slot])
+                               for slot in self._active}, returned)
             except asyncio.CancelledError:
                 raise
             except Exception as e:   # noqa: BLE001
                 logger.exception("inference engine step failed")
                 for seq in list(self._active.values()):
-                    self._retire(seq, done=False)
+                    self._retire(seq, "error")
                     seq.queue.put_nowait(e)
                 while self._waiting:
                     self._waiting.popleft().queue.put_nowait(e)
@@ -409,3 +491,23 @@ class LLMServer:
 
     def stats(self) -> Dict[str, Any]:
         return self._engine.stats()
+
+    async def profile(self, log_dir: str, seconds: float) -> str:
+        """Run the JAX profiler in this replica for ``seconds`` while it
+        keeps serving, and return the trace's path (an ``.xplane.pb``
+        under ``log_dir``): the device's programs and operations with the
+        engine's ``rt:`` regions beside them on one clock.  Call it as
+        ``handle.method("profile").remote(log_dir, seconds)``."""
+        import glob
+        import os
+
+        import jax
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(None, jax.profiler.start_trace, log_dir)
+        try:
+            await asyncio.sleep(seconds)
+        finally:
+            await loop.run_in_executor(None, jax.profiler.stop_trace)
+        traces = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                           recursive=True)
+        return max(traces, key=os.path.getmtime)

@@ -1,0 +1,56 @@
+"""A tiny engine decoding two sequences under the JAX profiler: the trace
+that ``test_tracing_regions.py`` checks region by region and that
+``tests/benchmark/test_host_regions.py`` feeds to the benchmark's reader.
+No cluster; one run a process, whoever asks first."""
+
+import asyncio
+import functools
+import glob
+import os
+import tempfile
+
+PROMPTS = ([5, 17, 3, 88, 41], [7, 8, 9])
+NEW_TOKENS = (6, 4)
+WARM_PROMPT, WARM_NEW = [1, 2, 3], 2
+MAX_PROMPT_LEN, MAX_BATCH = 16, 4
+
+
+@functools.lru_cache(maxsize=None)
+def run() -> dict:
+    """``{"path": the .xplane.pb, "stats": the engine's stats() at the
+    end, "tokens": what each sequence generated}``."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    trace_dir = tempfile.mkdtemp(prefix="rt_engine_trace_")
+    model = GPTConfig(vocab_size=97, max_seq_len=32, num_layers=2,
+                      num_heads=4, embed_dim=32, dtype=jnp.float32,
+                      attention="dense", remat=False)
+    config = EngineConfig(model="gpt", model_config=model, page_size=8,
+                          num_pages=32, max_batch=MAX_BATCH,
+                          max_prompt_len=MAX_PROMPT_LEN, max_new_tokens=8)
+
+    async def go():
+        engine = InferenceEngine(config)
+
+        async def consume(prompt, new):
+            return [t async for t in engine.generate(prompt, new)]
+
+        # both programs compile outside the trace
+        await consume(WARM_PROMPT, WARM_NEW)
+        jax.profiler.start_trace(trace_dir)
+        try:
+            tokens = await asyncio.gather(
+                *[consume(p, n) for p, n in zip(PROMPTS, NEW_TOKENS)])
+        finally:
+            jax.profiler.stop_trace()
+        stats = engine.stats()
+        engine.close()
+        return tokens, stats
+
+    tokens, stats = asyncio.run(go())
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return {"path": path, "stats": stats, "tokens": tokens}

@@ -462,6 +462,46 @@ def test_serve_streaming_end_to_end(serve_cluster):
         s.close()
 
 
+def test_replica_profile_holds_the_engines_and_the_streams_regions(
+        serve_cluster, tmp_path):
+    """``LLMServer.profile`` through the handle: the JAX profiler runs in
+    the replica while it serves, and the trace it returns holds the
+    engine's regions and the transport's, one ``rt:stream.yield`` a
+    streamed token with the ack's wait as its attribute."""
+    from jax.profiler import ProfileData
+    from ray_tpu.serve.engine import EngineConfig, LLMServer
+
+    ecfg = EngineConfig(model="gpt", model_config=_tiny_gpt(), page_size=8,
+                        num_pages=64, max_batch=8, max_prompt_len=32,
+                        max_new_tokens=16)
+    dep = serve.deployment(name="llm_profiled", max_concurrent_queries=16,
+                           ray_actor_options={"num_cpus": 0.1})(LLMServer)
+    handle = serve.run(dep.bind(ecfg))
+    payload = {"tokens": [5, 17, 3], "max_new_tokens": 8}
+    warm = [ray_tpu.get(r) for r in handle.remote_stream(payload)]
+    before = ray_tpu.get(handle.method("stats").remote(), timeout=60)
+    trace = handle.method("profile").remote(str(tmp_path), 3.0)
+    time.sleep(1.0)                      # the session has started
+    toks = [ray_tpu.get(r) for r in handle.remote_stream(payload)]
+    assert toks == warm
+    path = ray_tpu.get(trace, timeout=120)
+    assert path.startswith(str(tmp_path)) and path.endswith(".xplane.pb")
+    plane, = [p for p in ProfileData.from_file(path).planes
+              if p.name == "/host:CPU"]
+    regions = [(e.name, dict(e.stats)) for line in plane.lines
+               for e in line.events if e.name.startswith("rt:")]
+    names = [name for name, _ in regions]
+    assert names.count("rt:engine.prefill") == 1
+    assert names.count("rt:engine.decode.dispatch") == \
+        names.count("rt:engine.decode.fetch") == len(toks) - 1
+    yields = [stats for name, stats in regions if name == "rt:stream.yield"]
+    assert [y["index"] for y in yields] == list(range(1, len(toks) + 1))
+    assert all(y["ack_us"] >= 0 for y in yields)
+    after = ray_tpu.get(handle.method("stats").remote(), timeout=60)
+    assert after["retired"]["done"] == before["retired"]["done"] + 1
+    assert after["slot_steps"] == before["slot_steps"] + len(toks) - 1
+
+
 def test_http_client_disconnect_cancels_stream(serve_cluster):
     """A client that walks away mid-stream must cancel the replica-side
     generator (releasing engine slots/pages), not leave it producing into

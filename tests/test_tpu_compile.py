@@ -14,6 +14,7 @@ themselves, or steer ``_resolve`` where a model makes the call.
 import dataclasses
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
 
@@ -86,6 +87,22 @@ def _compile(fn, *args):
     return compiled, compiled.as_text()
 
 
+def _kernels(text: str) -> set:
+    """The flash kernel named by each instruction that is a Pallas kernel
+    (``%flash_dq.9``; ``%transpose_jvp_flash_dq__`` where ``jax.grad`` is
+    applied to the kernel's own ``custom_vjp``): what a profile shows."""
+    return {re.match(r"\s*(?:ROOT )?%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = ",
+                     line).group(1) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def _scoped(text: str, scope: str) -> bool:
+    """Whether some operation's ``op_name`` passes through the scope,
+    transformations (``transpose(jvp(scope))``) included."""
+    return any(re.search(rf"[/(]{scope}[/)]", name)
+               for name in re.findall(r'op_name="([^"]*)"', text))
+
+
 def _fits(compiled) -> int:
     m = compiled.memory_analysis()
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -140,6 +157,8 @@ def test_flash_kernel_compiles(topo, case, direction):
                               q, kv, kv)
     # forward is one kernel; backward runs it again, then dq and dk/dv
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    assert _kernels(text) == ({"flash_fwd"} if direction == "fwd" else
+                              {"flash_fwd", "flash_dq", "flash_dkv"})
     _fits(compiled)
 
 
@@ -174,7 +193,9 @@ def test_gpt2_small_train_step_compiles(topo, compiled_kernels, spec):
     with jax.sharding.set_mesh(mesh):
         compiled = make_train_step(GPT2, tx, rules).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text, "flash kernel missing from the step"
+    # the kernels by the names a profile reads, under shard_map too
+    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    assert _scoped(text, "ce_head") and _scoped(text, "optimizer")
     if spec.num_devices > 1:
         assert "all-reduce" in text or "reduce-scatter" in text
     _fits(compiled)
@@ -211,14 +232,16 @@ def test_paged_engine_program_compiles(topo, program):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
 
     if program == "prefill":
-        compiled, _ = _compile(
+        compiled, text = _compile(
             lambda p, *a: gpt_prefill(p, SERVE, *a),
             params, arg((1, MAX_PROMPT)), arg(()), kp, vp, arg((1, MAXP)))
     else:
-        compiled, _ = _compile(
+        compiled, text = _compile(
             lambda p, *a: gpt_decode_step(p, SERVE, *a),
             params, arg((MAX_BATCH,)), arg((MAX_BATCH,)), kp, vp,
             arg((MAX_BATCH, MAXP)))
+        assert _scoped(text, "paged_read")
+    assert _scoped(text, "paged_append")
     _fits(compiled)
 
 
