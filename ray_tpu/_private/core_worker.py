@@ -255,13 +255,19 @@ class CoreWorker:
         self.memory_store: Dict[str, Tuple[str, Any]] = {}
         self.object_events: Dict[str, asyncio.Event] = {}
         self.owned: set = set()
-        self._ref_lock = threading.Lock()
+        # Re-entrant because ObjectRef.__del__ takes it too, and a collector
+        # pass can run that finaliser on a thread that is inside one of
+        # the sections below (remove_local_ref then steps aside).
+        self._ref_lock = threading.RLock()
         self._local_refs: Dict[str, int] = {}
         # Distributed refcounting + lineage (reference: reference_count.h,
         # task_manager.h, object_recovery_manager.h):
         self._borrowing: set = set()            # oids we borrow (owner != us)
         self._borrowers: Dict[str, set] = {}    # oid -> borrower addresses
         self._borrow_acks: list = []            # in-flight borrow_add futures
+        # generator (return 0) oid -> oids of the yields it lists; each
+        # holds one local ref for as long as the generator is owned
+        self._listed_yields: Dict[str, set] = {}
         self._lineage: Dict[str, dict] = {}     # oid -> producing task record
         self._reconstructing: Dict[str, asyncio.Future] = {}
         # Task profile events, flushed to the GCS in batches (reference:
@@ -711,6 +717,14 @@ class CoreWorker:
                                  if not f.done()] + [fut]
 
     def remove_local_ref(self, oid: ObjectID, owner_address: str = ""):
+        if self._ref_lock._is_owned():
+            # Finalised by a collector pass in the middle of this thread's
+            # own add or remove: their counts are half written, so come
+            # back on the loop, after it.
+            if not self.loop.is_closed():
+                self.loop.call_soon_threadsafe(
+                    self.remove_local_ref, oid, owner_address)
+            return
         h = oid.hex()
         deregister = False
         with self._ref_lock:
@@ -739,6 +753,8 @@ class CoreWorker:
         with self._ref_lock:
             batch, self._free_queue = self._free_queue, []
             self._free_scheduled = False
+            # A ref may have been taken again since the free was queued.
+            batch = [oid for oid in batch if oid.hex() not in self._local_refs]
         for oid in batch:
             self._free_object(oid)
 
@@ -795,6 +811,8 @@ class CoreWorker:
             return  # a borrower keeps it alive; freed on last borrow_remove
         self.owned.discard(h)
         self._lineage.pop(h, None)
+        for y in self._listed_yields.pop(h, ()):
+            self.remove_local_ref(ObjectID.from_hex(y), self.address)
         entry = self.memory_store.pop(h, None)
         self.object_events.pop(h, None)
         if self.plasma is not None and (entry is None or entry[0] == "plasma"):
@@ -1062,11 +1080,23 @@ class CoreWorker:
                 raise rex.ObjectLostError(
                     f"object {h[:16]} lost: {detail} and no copies found")
             if owner == self.address or not owner:
+                self._check_not_freed(h, owner)
                 # We own it but it is not ready yet -> wait for task completion.
                 ev = self.object_events.setdefault(h, asyncio.Event())
                 await ev.wait()
                 ev.clear()
                 continue
+
+    def _check_not_freed(self, oid_hex: str, owner: str) -> None:
+        """An id this process owns is in `owned` from submission (or put,
+        or adoption) until it is freed: one that is in neither `owned`
+        nor the memory store was freed and no event will ever announce
+        it, so waiting for it would never end."""
+        if (owner == self.address and oid_hex not in self.owned
+                and oid_hex not in self.memory_store):
+            raise rex.ObjectLostError(
+                f"object {oid_hex[:16]} lost: this process owned it and "
+                f"freed it when its last reference went")
 
     async def _reconstruct(self, oid_hex: str) -> bool:
         """Owner-side object recovery: re-execute the producing task to
@@ -1264,6 +1294,7 @@ class CoreWorker:
                 except Exception:
                     await asyncio.sleep(0.5)
                 continue
+            self._check_not_freed(h, owner)
             ev = self.object_events.setdefault(h, asyncio.Event())
             await ev.wait()
             ev.clear()
@@ -1274,19 +1305,28 @@ class CoreWorker:
             for r in refs}
         ready: List[ObjectRef] = []
         deadline = None if timeout is None else time.monotonic() + timeout
-        while pending and len(ready) < num_returns:
-            t = None if deadline is None else max(0, deadline - time.monotonic())
-            done, _ = await asyncio.wait(pending.keys(), timeout=t,
-                                         return_when=asyncio.FIRST_COMPLETED)
-            if not done:
-                break
-            for fut in done:
-                ref = pending.pop(fut)
-                if fut.cancelled() or fut.exception() is not None:
-                    continue  # probe failed -> ref stays not-ready
-                ready.append(ref)
-        for fut in pending:
-            fut.cancel()
+        try:
+            while pending and len(ready) < num_returns:
+                t = (None if deadline is None
+                     else max(0, deadline - time.monotonic()))
+                done, _ = await asyncio.wait(
+                    pending.keys(), timeout=t,
+                    return_when=asyncio.FIRST_COMPLETED)
+                if not done:
+                    break
+                for fut in done:
+                    ref = pending.pop(fut)
+                    if fut.cancelled():
+                        continue
+                    exc = fut.exception()
+                    if isinstance(exc, rex.ObjectLostError):
+                        raise exc   # can never become ready
+                    if exc is not None:
+                        continue  # probe failed -> ref stays not-ready
+                    ready.append(ref)
+        finally:
+            for fut in pending:
+                fut.cancel()
         ready_set = set(ready[:num_returns])
         ordered_ready = [r for r in refs if r in ready_set]
         not_ready = [r for r in refs if r not in ready_set]
@@ -1964,7 +2004,18 @@ class CoreWorker:
                                          "object_id": oid_hex}),
                         loop=self.loop)
             entries = entries[:len(return_ids)]
-        for oid_hex, kind, data in entries[len(return_ids):]:
+        extras = entries[len(return_ids):]
+        if extras:
+            # Return 0 lists the yields: while it is owned each holds one
+            # local ref, or a borrower's release (or a queued free) could
+            # meet a zero count before the caller has deserialised the
+            # generator, and free what it is about to be handed.
+            listed = self._listed_yields.setdefault(return_ids[0].hex(),
+                                                    set())
+        for oid_hex, kind, data in extras:
+            if oid_hex not in listed:   # a reconstruction adopts again
+                listed.add(oid_hex)
+                self.add_local_ref(ObjectID.from_hex(oid_hex), self.address)
             self.owned.add(oid_hex)
             self._store_return_entry(oid_hex, kind, data)
         for (oid_hex, kind, data), oid in zip(entries, return_ids):

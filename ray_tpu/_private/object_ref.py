@@ -216,3 +216,20 @@ class ObjectRef:
         """concurrent.futures.Future resolving to the value."""
         from ray_tpu._private.worker import global_worker
         return global_worker.core_worker.as_future(self)
+
+
+class ListedRef(ObjectRef):
+    """Names an object in a message without holding it: counts no local
+    reference, so it registers no borrow with the owner, and pickles as
+    a plain ObjectRef.  An executor lists a generator task's yields with
+    these: it never reads them, and a borrow_add/borrow_remove pair from
+    it could reach the owner after the reply and free them."""
+
+    __slots__ = ()
+
+    def __init__(self, object_id: ObjectID, owner_address: str = ""):
+        self.id = object_id
+        self.owner_address = owner_address
+
+    def __del__(self):
+        pass

@@ -316,7 +316,7 @@ class TaskExecutor:
         listing their refs as return 0.  The reply carries every entry;
         the caller registers ownership of the extras on receipt."""
         from ray_tpu._private.ids import TaskID
-        from ray_tpu._private.object_ref import (ObjectRef,
+        from ray_tpu._private.object_ref import (ListedRef,
                                                  ObjectRefGenerator)
         task_id = TaskID(
             bytes.fromhex(spec.get("call_id") or spec["task_id"]))
@@ -328,7 +328,7 @@ class TaskExecutor:
             oid = ObjectID.for_task_return(task_id, i)
             entries.append(
                 await self.core.store_return_value_async(oid, value))
-            refs.append(ObjectRef(oid, owner))
+            refs.append(ListedRef(oid, owner))
         gen_oid = ObjectID.for_task_return(task_id, 0)
         entry0 = await self.core.store_return_value_async(
             gen_oid, ObjectRefGenerator(refs))

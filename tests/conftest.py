@@ -13,12 +13,134 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+
 import pytest  # noqa: E402
 
 if os.environ.get("RT_TEST_LOG_LEVEL"):
     import logging
     logging.basicConfig(level=os.environ["RT_TEST_LOG_LEVEL"])
     logging.getLogger("jax").setLevel(logging.WARNING)
+
+# Every test's setup, call and teardown each get this long (the longest
+# test takes 105 s on an idle 8-core machine); a test that truly needs
+# more says so with @pytest.mark.timeout_s(n).
+TEST_LIMIT_S = 300
+# A main thread that sits in a C call never runs the alarm's handler, and
+# a finaliser or a bare `except` can swallow what it raises: where the
+# phase has not ended this long after the limit, the worker dumps its
+# stacks and exits, and xdist replaces it (the run loses that test, not
+# its end).
+KILL_GRACE_S = 15
+
+_stacks_key = pytest.StashKey()
+
+
+def _stacks_file(config):
+    """This process's stack-dump file under the pytest temp root (with
+    xdist each worker's root is its own popen-gw<N>/), opened once."""
+    f = config.stash.get(_stacks_key, None)
+    if f is None:
+        path = config._tmp_path_factory.getbasetemp() / "timeout_stacks.txt"
+        f = config.stash[_stacks_key] = open(path, "a+")
+    return f
+
+
+def _in_flight(config):
+    """Where the xdist workers of one run each name the test they are in.
+    `--dist loadfile` hands a lost worker's file out again from the test it
+    died in, so whoever gets it has to know not to run that test again."""
+    shared = config._tmp_path_factory.getbasetemp().parent / "in_flight"
+    shared.mkdir(exist_ok=True)
+    return shared
+
+
+@contextlib.contextmanager
+def _time_limit(item, phase):
+    marker = item.get_closest_marker("timeout_s")
+    limit = marker.args[0] if marker else TEST_LIMIT_S
+    stacks = _stacks_file(item.config)
+    stacks.write(f"\n=== {item.nodeid} ({phase}), limit {limit} s\n")
+    stacks.flush()
+    at = stacks.tell()
+    mine = None
+    if "PYTEST_XDIST_WORKER" in os.environ:
+        mine = _in_flight(item.config) / str(os.getpid())
+        mine.write_text(item.nodeid)
+
+    def cut(signum, frame):
+        faulthandler.dump_traceback(stacks, all_threads=True)
+        stacks.seek(at)
+        pytest.fail(
+            f"{item.nodeid} ({phase}) was cut at its time limit of "
+            f"{limit} s; every thread's stack then (kept in "
+            f"{stacks.name}):\n{stacks.read()}", pytrace=False)
+
+    faulthandler.dump_traceback_later(limit + KILL_GRACE_S, exit=True,
+                                      file=stacks)
+    before = signal.signal(signal.SIGALRM, cut)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+        faulthandler.cancel_dump_traceback_later()
+        if mine is not None:
+            mine.write_text("")   # not unlinked: others are reading the files
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_setup(item):
+    with _time_limit(item, "setup"):
+        return (yield)
+
+
+@pytest.hookimpl(specname="pytest_runtest_setup", tryfirst=True)
+def pytest_refuse_a_test_that_lost_a_worker(item):
+    # Not in the wrapper above: the other plug-ins' wrappers have to be
+    # entered before a setup may fail, or their teardowns fail too.
+    if "PYTEST_XDIST_WORKER" not in os.environ:
+        return
+    shared = _in_flight(item.config)
+    if any(p.read_text() == item.nodeid for p in shared.iterdir()
+           if p.name != str(os.getpid())):
+        pytest.fail(
+            f"{item.nodeid} already cost the run a worker, which sat deaf "
+            f"to the alarm {KILL_GRACE_S} s past the time limit; its "
+            f"stacks are in a timeout_stacks.txt under {shared.parent}",
+            pytrace=False)
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_call(item):
+    with _time_limit(item, "call"):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_teardown(item):
+    with _time_limit(item, "teardown"):
+        return (yield)
+
+
+def _stop_cluster():
+    """ray_tpu.shutdown(); where the time limit cuts it (or it raises),
+    kill the daemons, so that the next file on this worker starts clean:
+    the raylet's workers leave with it, and with the daemons gone a
+    second shutdown() has nothing to wait for and resets the driver."""
+    import ray_tpu
+    try:
+        ray_tpu.shutdown()
+    except BaseException:
+        import psutil
+        for proc in psutil.Process().children(recursive=True):
+            with contextlib.suppress(psutil.NoSuchProcess):
+                proc.kill()
+        ray_tpu.shutdown()
+        raise
 
 
 @pytest.fixture(scope="module")
@@ -29,7 +151,7 @@ def ray_start():
     ray_tpu.init(num_cpus=16, _worker_env={"JAX_PLATFORMS": "cpu"},
                  log_level=os.environ.get("RT_TEST_LOG_LEVEL", "WARNING"))
     yield
-    ray_tpu.shutdown()
+    _stop_cluster()
 
 
 @pytest.fixture
@@ -38,4 +160,4 @@ def ray_start_fresh():
     import ray_tpu
     ray_tpu.init(num_cpus=4, _worker_env={"JAX_PLATFORMS": "cpu"})
     yield
-    ray_tpu.shutdown()
+    _stop_cluster()

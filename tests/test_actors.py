@@ -193,9 +193,10 @@ def test_actor_dynamic_num_returns(ray_start):
                 yield [i] * 2
 
     a = Gen.remote()
-    gen = ray_tpu.get(a.chunks.options(num_returns="dynamic").remote(3))
+    gen = ray_tpu.get(a.chunks.options(num_returns="dynamic").remote(3),
+                      timeout=60)
     assert len(gen) == 3
-    assert ray_tpu.get(list(gen)) == [[0, 0], [1, 1], [2, 2]]
+    assert ray_tpu.get(list(gen), timeout=60) == [[0, 0], [1, 1], [2, 2]]
 
 
 def test_concurrency_groups_isolate_slots(ray_start):

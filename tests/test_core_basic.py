@@ -143,16 +143,111 @@ def test_dynamic_num_returns_generator_task(ray_start):
         for i in range(n):
             yield i * i
 
-    gen = ray_tpu.get(splat.remote(5))
+    gen = ray_tpu.get(splat.remote(5), timeout=60)
     from ray_tpu import ObjectRefGenerator
     assert isinstance(gen, ObjectRefGenerator)
     assert len(gen) == 5
-    assert ray_tpu.get(list(gen)) == [0, 1, 4, 9, 16]
+    assert ray_tpu.get(list(gen), timeout=60) == [0, 1, 4, 9, 16]
     # Works with zero yields too.
-    assert len(ray_tpu.get(splat.remote(0))) == 0
+    assert len(ray_tpu.get(splat.remote(0), timeout=60)) == 0
     # Refs remain gettable individually (ownership registered).
-    g2 = ray_tpu.get(splat.remote(3))
-    assert ray_tpu.get(g2[2]) == 4
+    g2 = ray_tpu.get(splat.remote(3), timeout=60)
+    assert ray_tpu.get(g2[2], timeout=60) == 4
+
+
+@ray_tpu.remote(num_returns="dynamic")
+def f_squares(n):
+    for i in range(n):
+        yield i * i
+
+
+def test_dynamic_yields_survive_executor_borrow_roundtrip(ray_start):
+    """The yields of a num_returns="dynamic" task stay alive from the
+    task's reply to the caller's first reference to them.  Forced here:
+    a borrower's borrow_add and borrow_remove for a yield land on the
+    owner after the reply and before the generator is deserialised (the
+    order the executor's own per-yield refs used to produce on a loaded
+    machine), which freed the yield and left get() waiting for ever."""
+    from ray_tpu._private.ids import ObjectID
+    from ray_tpu._private.worker import get_core
+    core = get_core()
+    ref = f_squares.remote(4)
+    ready, _ = ray_tpu.wait([ref], timeout=60)
+    assert ready == [ref]   # reply stored: yields adopted, no ref to them yet
+    for i in range(1, 5):
+        msg = {"object_id": ObjectID.for_task_return(ref.id.task_id(),
+                                                     i).hex(),
+               "borrower": "late-borrower:1"}
+        assert core._run(core._h_borrow_add(msg), timeout=30) == {"ok": True}
+        core._run(core._h_borrow_remove(msg), timeout=30)
+    gen = ray_tpu.get(ref, timeout=60)
+    assert ray_tpu.get(list(gen), timeout=30) == [0, 1, 4, 9]
+
+
+def test_dynamic_generator_can_be_got_twice(ray_start):
+    """Return 0 lists the yields, so they live as long as it is owned: a
+    second get() of the generator ref, after every ref of the first was
+    dropped, still finds them."""
+    import gc
+    ref = f_squares.remote(3)
+    gen = ray_tpu.get(ref, timeout=60)
+    assert ray_tpu.get(list(gen), timeout=30) == [0, 1, 4]
+    del gen
+    gc.collect()
+    ray_tpu.get(f_identity.remote(0), timeout=60)  # a loop turn: frees flushed
+    assert ray_tpu.get(list(ray_tpu.get(ref, timeout=60)),
+                       timeout=30) == [0, 1, 4]
+
+
+def test_queued_free_spares_a_retaken_ref(ray_start):
+    """A free queued at count zero frees nothing if a ref was taken again
+    before the loop flushed it."""
+    from ray_tpu._private.worker import get_core
+    core = get_core()
+    ref = ray_tpu.put("kept")
+
+    async def drop_and_retake():   # one loop turn: the flush runs after it
+        core.remove_local_ref(ref.id, ref.owner_address)
+        core.add_local_ref(ref.id, ref.owner_address)
+
+    core._run(drop_and_retake(), timeout=30)
+    ray_tpu.get(f_identity.remote(0), timeout=60)
+    assert ray_tpu.get(ref, timeout=30) == "kept"
+
+
+@pytest.mark.timeout_s(30)
+def test_ref_finalised_inside_a_refcount_section_does_not_deadlock(ray_start):
+    """A collector pass can run ObjectRef.__del__ on a thread that is inside
+    add_local_ref or remove_local_ref (seen in test_actor_ordering's
+    submit loop on a loaded machine): the finaliser must not wait for the
+    lock its own thread holds, and the reference must still go."""
+    import time
+
+    from ray_tpu._private.worker import get_core
+    core = get_core()
+    ref = ray_tpu.put("dropped inside the section")
+    h = ref.hex()
+    with core._ref_lock:   # where add_local_ref is when the collector runs
+        del ref
+    deadline = time.monotonic() + 30
+    while h in core.owned and time.monotonic() < deadline:
+        ray_tpu.get(f_identity.remote(0), timeout=60)   # loop turns
+    assert h not in core.owned and h not in core._local_refs
+
+
+def test_get_and_wait_of_a_freed_owned_ref_raise_at_once(ray_start):
+    """A ref this process owns and no longer holds cannot become ready:
+    get() and wait() raise ObjectLostError instead of waiting."""
+    from ray_tpu._private.ids import ObjectID, TaskID
+    from ray_tpu._private.object_ref import ObjectRef
+    from ray_tpu._private.worker import get_core
+    from ray_tpu.exceptions import ObjectLostError
+    gone = ObjectRef(ObjectID.for_task_return(TaskID.from_random(), 1),
+                     get_core().address)
+    with pytest.raises(ObjectLostError):
+        ray_tpu.get(gone, timeout=30)
+    with pytest.raises(ObjectLostError):
+        ray_tpu.wait([gone], timeout=30)
 
 
 def test_get_runtime_context(ray_start):

@@ -410,18 +410,23 @@ def test_controller_crash_recovery(serve_cluster):
     h = serve.run(Echo.bind())
     _, pid_before = ray_tpu.get(h.remote(1))
     # Wait until a reconcile has actually persisted the KV snapshot with
-    # both replicas — the persist runs on the 0.5s reconcile loop, and a
-    # wall-clock sleep races it on a loaded box.
+    # THESE two replicas — the persist runs on the 0.5s reconcile loop, and
+    # on a loaded box the newest snapshot can still be the one that holds
+    # the two (since deleted) replicas of an earlier test's "Echo".
     import cloudpickle
 
     from ray_tpu._private.kv import kv_get
-    deadline = time.monotonic() + 30
+    from ray_tpu.util import state
+    deadline = time.monotonic() + 60
     while True:
+        live = {a["actor_id"] for a in state.list_actors()
+                if (a.get("name") or "").startswith("_serve:Echo:")
+                and a.get("state") == "ALIVE"}
         raw = kv_get(b"state", ns="serve")
-        if raw:
+        if raw and len(live) == 2:
             snap = cloudpickle.loads(raw)
-            if len(snap.get("deployments", {})
-                    .get("Echo", (None, 0, []))[2]) == 2:
+            if set(snap.get("deployments", {})
+                   .get("Echo", (None, 0, []))[2]) == live:
                 break
         assert time.monotonic() < deadline, \
             "controller never persisted its state snapshot"
@@ -432,19 +437,26 @@ def test_controller_crash_recovery(serve_cluster):
 
     # A fresh handle reaches the RESTARTED controller; requests still
     # serve and land on the pre-crash replica processes.
-    deadline = time.monotonic() + 60
+    # Until a request has landed on the pre-crash process, not one batch
+    # and a single look: on a loaded machine the restarted controller's
+    # first answers can come from one replica only.
+    deadline = time.monotonic() + 120
     pids = set()
-    while time.monotonic() < deadline:
+    while pid_before not in pids and time.monotonic() < deadline:
         try:
             h2 = serve.get_handle("Echo")
             for i in range(4):
                 _, pid = ray_tpu.get(h2.remote(i), timeout=20)
                 pids.add(pid)
-            break
         except Exception:
             time.sleep(0.5)
     assert pids, "no requests served after controller restart"
     assert pid_before in pids, "replicas were restarted, not re-adopted"
-    st = serve.status()
+    # The restarted controller answers status() from the moment it is up,
+    # with an empty view until its restore from the KV has finished.
+    deadline = time.monotonic() + 60
+    while "Echo" not in (st := serve.status()):
+        assert time.monotonic() < deadline, st
+        time.sleep(0.2)
     assert st["Echo"]["target"] == 2
     serve.delete("Echo")

@@ -374,6 +374,22 @@ def test_stream_failover_after_kill_is_bit_identical(serve_cluster):
     # 150ms/token -> ~6s of stream after the first token: the probe-and-
     # kill below lands mid-stream with seconds to spare.
     serve.run(_throttled_llm("fllm", 0.15, num_replicas=2))
+    # Both replicas have answered a request before the stream starts.
+    # serve.run() returns once the controller has created them, while
+    # their constructors still run; a first request then waits for the
+    # constructor and the compiles, on a loaded machine for longer than the
+    # ingress's stall limit (RT_SERVE_STALL_S, 30 s), the ingress counts
+    # the replica as wedged, and the stream runs out of replicas before
+    # the kill this test is about (PERF.md section 7).
+    deadline = time.monotonic() + 240
+    while len(replicas := _replica_actors("fllm")) < 2:
+        assert time.monotonic() < deadline, replicas
+        time.sleep(0.3)
+    for a in replicas:
+        assert ray_tpu.get(
+            ActorHandle(a["actor_id"], "Replica").handle_request.remote(
+                [{"tokens": prompt, "max_new_tokens": 2}], {}, None, None),
+            timeout=240) == _greedy_dense(prompt, 2)
     url = serve.start_http()
     s = _connect(url)
     try:

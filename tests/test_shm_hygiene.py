@@ -63,17 +63,24 @@ def test_sweeper_reaps_dead_pid_and_legacy_segments(tmp_path):
 
 
 def test_cluster_roundtrip_leaves_no_segments():
-    """A full init/shutdown must return /dev/shm to its prior state."""
-    before = _rt_segments()
+    """A full init/shutdown must leave none of its segments in /dev/shm.
+    Judged by the session's own segment (its raylet's pid and node id):
+    other sessions on the machine, and what a killed run left, come and
+    go between two listings of the directory."""
     code = (
-        "import ray_tpu;"
-        "ray_tpu.init(num_cpus=1, _worker_env={'JAX_PLATFORMS': 'cpu'});"
-        "import ray_tpu as rt;"
+        "import os, ray_tpu as rt;"
+        "info = rt.init(num_cpus=1, _worker_env={'JAX_PLATFORMS': 'cpu'});"
         "assert rt.get(rt.put(41)) == 41;"
+        "assert os.path.exists('/dev/shm' + info['store_name']), info;"
+        "print('SEGMENT', info['store_name'], flush=True);"
         "rt.shutdown()")
-    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
-    after = _rt_segments()
-    assert after - before == set(), f"leaked segments: {after - before}"
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         timeout=120, stdout=subprocess.PIPE, text=True)
+    name = re.search(r"^SEGMENT /(rt_\d+_[0-9a-f]{12})$", out.stdout,
+                     re.M).group(1)
+    node = name.rsplit("_", 1)[1]
+    leaked = {seg for seg in _rt_segments() if seg.endswith(node)}
+    assert leaked == set(), f"leaked segments: {leaked}"
 
 
 def test_sigkilled_raylet_segment_reaped_by_next_session():
