@@ -14,6 +14,15 @@ The reference has no model zoo of its own (its flagship benchmarks wrap
 torchvision/HF models); this family exists so Train/Tune/Serve have a
 modern-architecture model to exercise, matching
 ``release/air_tests/air_benchmarks``' role.
+
+With ``num_experts > 0`` the feed-forward is a mixture of SwiGLU experts
+routed top-k per token without capacity (``ops/moe.py``'s dropless path),
+and with ``qk_norm`` the q and k projections are RMS-normed over all heads
+before the rotation: together OLMoE's block.  Both are written once
+(``_ffn``, ``_qk``) and called from the training block, the paged prefill
+and the paged decode.  The expert model serves; it does not train here:
+``llama_loss`` refuses it, because the load-balancing loss, ``ep``
+sharding and the all-to-all are not written.
 """
 
 from __future__ import annotations
@@ -49,6 +58,10 @@ class LlamaConfig:
     remat_policy: str = "full"   # same menu as GPTConfig
     attention: str = "auto"          # "auto" | "dense" | "flash"
     ce_block: int = 0                # blocked-CE chunk (see GPTConfig)
+    num_experts: int = 0             # 0 = dense; else mlp_dim is an expert's
+    experts_per_token: int = 0       # top-k of the router's softmax
+    norm_topk_prob: bool = False     # renormalise the k gates to sum to 1
+    qk_norm: bool = False            # RMSNorm q and k over all heads
 
     @property
     def head_dim(self) -> int:
@@ -76,6 +89,22 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
     scale = 0.02
     rscale = scale / np.sqrt(2 * L)
+    E = cfg.num_experts
+    if E and not 0 < cfg.experts_per_token <= E:
+        raise ValueError(f"experts_per_token={cfg.experts_per_token} must "
+                         f"be in 1..num_experts={E}")
+    ex = (E,) if E else ()           # the experts' leading dim
+    # SwiGLU: gate and up projections fused on a leading 2-dim.
+    mlp = {"wgu": scale * jax.random.normal(k[4], (L, *ex, 2, D, M),
+                                            jnp.float32),
+           "wd": rscale * jax.random.normal(k[5], (L, *ex, M, D),
+                                            jnp.float32)}
+    if E:
+        mlp["router"] = scale * jax.random.normal(k[7], (L, D, E),
+                                                  jnp.float32)
+    norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
+             "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
+        if cfg.qk_norm else {}
     return {
         "wte": scale * jax.random.normal(k[0], (V, D), jnp.float32),
         "layers": {
@@ -87,15 +116,10 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
                                                  jnp.float32),
                 "wo": rscale * jax.random.normal(k[3], (L, nh, H, D),
                                                  jnp.float32),
+                **norms,
             },
             "ln2": {"scale": jnp.ones((L, D), jnp.float32)},
-            "mlp": {
-                # SwiGLU: gate and up projections fused on a leading 2-dim.
-                "wgu": scale * jax.random.normal(k[4], (L, 2, D, M),
-                                                 jnp.float32),
-                "wd": rscale * jax.random.normal(k[5], (L, M, D),
-                                                 jnp.float32),
-            },
+            "mlp": mlp,
         },
         "ln_f": {"scale": jnp.ones((D,), jnp.float32)},
         "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32),
@@ -104,7 +128,15 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
 
 def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Logical-axis annotations matching ``llama_init`` (same rule table
-    as GPT: heads/mlp -> tp, embed -> fsdp, layers -> pp)."""
+    as GPT: heads/mlp -> tp, embed -> fsdp, layers -> pp; experts carry
+    "expert" -> ep, the router stays replicated over them)."""
+    ex = ("expert",) if cfg.num_experts else ()
+    mlp = {"wgu": ("layers", *ex, None, "embed", "mlp"),
+           "wd": ("layers", *ex, "mlp", "embed")}
+    if cfg.num_experts:
+        mlp["router"] = ("layers", "embed", None)
+    norms = {"q_norm": ("layers", "heads", "kv"),
+             "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
     return {
         "wte": (None, "embed"),
         "layers": {
@@ -113,21 +145,19 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
                 "wq": ("layers", "embed", "heads", "kv"),
                 "wkv": ("layers", "embed", None, "heads", "kv"),
                 "wo": ("layers", "heads", "kv", "embed"),
+                **norms,
             },
             "ln2": {"scale": ("layers", "norm")},
-            "mlp": {
-                "wgu": ("layers", None, "embed", "mlp"),
-                "wd": ("layers", "mlp", "embed"),
-            },
+            "mlp": mlp,
         },
         "ln_f": {"scale": ("norm",)},
         "lm_head": ("embed", None),
     }
 
 
-def _rms_norm(x, scale, eps):
+def _rms_norm(x, scale, eps, axis=-1):
     x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True)
                             + eps)
     return (y * scale).astype(x.dtype)
 
@@ -167,8 +197,57 @@ def _dense_causal_attention_gqa(q, k, v, rep: int):
     return o.reshape(B, N, S, H)
 
 
+def _qk(cfg: LlamaConfig, p, q, k, cos, sin):
+    """What happens to the head-major q [B, N, ..., H] and k [B, NKV, ...,
+    H] between their projections and attention: with ``cfg.qk_norm`` an
+    RMSNorm with a learned scale over the WHOLE projection, all heads
+    together (OLMoE norms before it splits into heads), then the rotation
+    at ``cos``/``sin``'s positions."""
+    if cfg.qk_norm:
+        def norm(a, scale):          # scale [N, H], a's heads on axis 1
+            scale = scale.reshape(scale.shape[0], *(1,) * (a.ndim - 3), -1)
+            return _rms_norm(a, scale, cfg.rms_eps, axis=(1, -1))
+        q = norm(q, p["attn"]["q_norm"])
+        k = norm(k, p["attn"]["k_norm"])
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+
+
+def _scanned_layers(cfg: LlamaConfig, params):
+    """(what ``lax.scan`` slices a layer at a time, what the layers share).
+    A dense model's layers are all sliced.  An expert model's experts stay
+    whole and the layer gets its index in their place: a layer's slice of
+    the stack is a copy of every expert (1.6 GB at OLMoE's widths) ahead
+    of grouped matmuls that read a few of them, where they lie."""
+    layers = params["layers"]
+    if not cfg.num_experts:
+        return layers, None
+    return {**layers, "mlp": jnp.arange(cfg.num_layers)}, layers["mlp"]
+
+
+def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
+         experts=None):
+    """The block's feed-forward on the normed hidden ``h`` [..., D]:
+    SwiGLU, dense or (``cfg.num_experts``) top-k experts without capacity;
+    then ``p["mlp"]`` is the layer's index into ``experts``, the stacked
+    experts of all layers (``_scanned_layers``).  Returns (y [..., D],
+    load): ``load`` [E] int32 counts per expert the assignments of the
+    tokens that ``live`` [...] marks (all when None), and is None for a
+    dense model."""
+    dt = cfg.dtype
+    if cfg.num_experts:
+        from ray_tpu.ops.moe import moe_dropless
+        y, load = moe_dropless(
+            h.reshape(-1, h.shape[-1]), experts, layer=p["mlp"],
+            top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
+            live=None if live is None else live.reshape(-1))
+        return y.reshape(h.shape), load
+    gu = jnp.einsum("...d,cdm->c...m", h, p["mlp"]["wgu"].astype(dt))
+    a = lc(jax.nn.silu(gu[0]) * gu[1], ("batch", "seq", "mlp"))
+    return jnp.einsum("...m,md->...d", a, p["mlp"]["wd"].astype(dt)), None
+
+
 def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
-           attn_fn: Callable, cos, sin, x, p):
+           attn_fn: Callable, cos, sin, experts, x, p):
     lc = (lambda a, ax: with_logical_constraint(a, rules, ax)) if rules \
         else (lambda a, ax: a)
     dt = cfg.dtype
@@ -180,8 +259,7 @@ def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
     q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
     kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
     k, v = kv[:, 0], kv[:, 1]
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k = _qk(cfg, p, q, k, cos, sin)
     if rep > 1 and getattr(attn_fn, "_gqa_native", False):
         # Grouped dense path: fold the share-group dim into the einsum —
         # K/V stay at kv_heads width (no jnp.repeat materializing rep
@@ -200,10 +278,7 @@ def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
     x = lc(x, ("batch", "seq", "embed"))
 
     h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-    gu = jnp.einsum("bsd,cdm->cbsm", h, p["mlp"]["wgu"].astype(dt))
-    h = jax.nn.silu(gu[0]) * gu[1]
-    h = lc(h, ("batch", "seq", "mlp"))
-    x = x + jnp.einsum("bsm,md->bsd", h, p["mlp"]["wd"].astype(dt))
+    x = x + _ffn(cfg, p, h, lc=lc, experts=experts)[0]
     return lc(x, ("batch", "seq", "embed"))
 
 
@@ -234,7 +309,8 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
     if rules is not None:
         x = with_logical_constraint(x, rules, ("batch", "seq", "embed"))
 
-    block = functools.partial(_block, cfg, rules, attn_fn, cos, sin)
+    layers, experts = _scanned_layers(cfg, params)
+    block = functools.partial(_block, cfg, rules, attn_fn, cos, sin, experts)
     if cfg.remat:
         cp = jax.checkpoint_policies
         policy = {
@@ -246,8 +322,7 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
         }.get(cfg.remat_policy)
         block = jax.checkpoint(block, policy=policy)
 
-    x, _ = jax.lax.scan(lambda c, lp: (block(c, lp), None), x,
-                        params["layers"])
+    x, _ = jax.lax.scan(lambda c, lp: (block(c, lp), None), x, layers)
     return _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
 
 
@@ -283,6 +358,13 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
+def _paged_results(logits, k_pages, v_pages, load):
+    """(logits, pools) and, from an expert model, the experts' load."""
+    if load is None:
+        return logits, k_pages, v_pages
+    return logits, k_pages, v_pages, load
+
+
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
                   k_pages: jax.Array, v_pages: jax.Array,
@@ -291,13 +373,17 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     per-layer post-rope K/V scattered into the sequence's pages, f32
     next-token logits at position length-1.  ``tokens`` [1, S] with S a
     multiple of the page size; ``page_table`` [1, maxp];
-    ``k_pages``/``v_pages`` [L, NKV, P, page, H]."""
+    ``k_pages``/``v_pages`` [L, NKV, P, page, H].  An expert model returns a
+    fourth result, ``load`` [L, E] int32: per layer and expert, the
+    assignments of the prompt's real positions."""
     from ray_tpu.ops.paged_attention import prefill_kv
     dt = cfg.dtype
     rep = cfg.num_heads // cfg.num_kv_heads
     S = tokens.shape[1]
     cos, sin = rope_tables(S, cfg.head_dim, cfg.rope_theta)
     x = params["wte"].astype(dt)[tokens]
+    live = (jnp.arange(S) < length)[None]                # the real positions
+    layers, experts = _scanned_layers(cfg, params)
 
     def body(x, inp):
         p, kp, vp = inp
@@ -305,24 +391,21 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
         q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
         kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
         k, v = kv[:, 0], kv[:, 1]                        # [B, NKV, S, H]
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k = _qk(cfg, p, q, k, cos, sin)
         kp, vp = prefill_kv(kp, vp, k[0], v[0], length, page_table[0])
         o = _dense_causal_attention_gqa(q, k, v, rep)
         x = x + jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-        gu = jnp.einsum("bsd,cdm->cbsm", h, p["mlp"]["wgu"].astype(dt))
-        h = jax.nn.silu(gu[0]) * gu[1]
-        return x + jnp.einsum("bsm,md->bsd", h,
-                              p["mlp"]["wd"].astype(dt)), (kp, vp)
+        y, load = _ffn(cfg, p, h, live, experts=experts)
+        return x + y, (kp, vp, load)
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
+    x, (k_pages, v_pages, load) = jax.lax.scan(
+        body, x, (layers, k_pages, v_pages))
     x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,dv->v", last,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits[None], k_pages, v_pages
+    return _paged_results(logits[None], k_pages, v_pages, load)
 
 
 def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
@@ -333,13 +416,18 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     ``token``/``pos`` [B]; rope rotates q and the new K at each
     sequence's absolute position; the paged attention's GQA grouping
     keeps K/V at kv_heads width.  Inactive slots (pos 0, all-zero
-    page-table row) harmlessly churn scratch page 0."""
+    page-table row) harmlessly churn scratch page 0.  An expert model
+    returns a fourth result, ``load`` [L, E] int32: per layer and expert,
+    the assignments of the live slots (``pos > 0``: a sequence that decodes
+    has a prompt behind it)."""
     from ray_tpu.ops.paged_attention import append_kv, paged_attention
     dt = cfg.dtype
     cos_t, sin_t = rope_tables(cfg.max_seq_len, cfg.head_dim,
                                cfg.rope_theta)
     cos, sin = cos_t[pos][:, None], sin_t[pos][:, None]  # [B, 1, H/2]
     x = params["wte"].astype(dt)[token]
+    live = pos > 0
+    layers, experts = _scanned_layers(cfg, params)
 
     def body(x, inp):
         p, kp, vp = inp
@@ -347,23 +435,20 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
         q = jnp.einsum("bd,dnh->bnh", h, p["attn"]["wq"].astype(dt))
         kv = jnp.einsum("bd,dcnh->bcnh", h, p["attn"]["wkv"].astype(dt))
         k_new, v_new = kv[:, 0], kv[:, 1]                # [B, NKV, H]
-        q = apply_rope(q, cos, sin)
-        k_new = apply_rope(k_new, cos, sin)
+        q, k_new = _qk(cfg, p, q, k_new, cos, sin)
         kp, vp = append_kv(kp, vp, k_new, v_new, pos, page_table)
         o = paged_attention(q, kp, vp, pos + 1, page_table)
         x = x + jnp.einsum("bnh,nhd->bd", o, p["attn"]["wo"].astype(dt))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-        gu = jnp.einsum("bd,cdm->cbm", h, p["mlp"]["wgu"].astype(dt))
-        h = jax.nn.silu(gu[0]) * gu[1]
-        return x + jnp.einsum("bm,md->bd", h,
-                              p["mlp"]["wd"].astype(dt)), (kp, vp)
+        y, load = _ffn(cfg, p, h, live, experts=experts)
+        return x + y, (kp, vp, load)
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
+    x, (k_pages, v_pages, load) = jax.lax.scan(
+        body, x, (layers, k_pages, v_pages))
     x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
     logits = jnp.einsum("bd,dv->bv", x,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, k_pages, v_pages
+    return _paged_results(logits, k_pages, v_pages, load)
 
 
 def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
@@ -371,7 +456,13 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
                mesh=None) -> jax.Array:
     """Next-token CE over {"tokens": [B, S+1]} — shares the fused
     ``token_loglikes`` core (and the blocked-CE head via ``cfg.ce_block``)
-    with GPT."""
+    with GPT.  Refuses an expert model: without the load-balancing loss
+    (and ``ep`` sharding and the all-to-all) it would train a router that
+    collapses; those belong with the four-chip training path."""
+    if cfg.num_experts:
+        raise NotImplementedError(
+            "models/llama.py serves its expert model but does not train "
+            "it: the router's load-balancing loss is not written")
     toks = batch["tokens"]
     targets = toks[:, 1:]
     x = llama_hidden(params, toks[:, :-1], cfg, rules, mesh)

@@ -20,6 +20,24 @@ Shapes (S = tokens per batch row, E = experts, C = per-expert capacity):
 Tokens beyond an expert's capacity are dropped (their combine weight is 0 and
 the residual connection carries them through unchanged) — standard Switch
 behavior; raise ``capacity_factor`` to trade memory for fewer drops.
+
+Two paths live here, with different callers:
+
+* ``moe_router`` / ``moe_mlp``: the capacity path above.  It DROPS what
+  exceeds an expert's capacity, has GELU experts with biases, and is used
+  only by ``models/gpt.py`` (training, expert parallelism over ``ep``,
+  the GPipe pipeline).
+* ``moe_dropless``: token-choice top-k with NO capacity, SwiGLU experts
+  without biases.  Every assignment is computed: assignments are sorted
+  by expert and the experts run as grouped matmuls over the ragged groups
+  (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
+  grouped-matmul kernel: work goes with the assignments, not with
+  experts x tokens, and an expert nobody chose is not read).  Used by ``models/llama.py`` (``_ffn`` when
+  ``LlamaConfig.num_experts > 0``), and so by the paged serving engine,
+  whose padded prefill and idle decode slots would take capacity from real
+  tokens under the first path: only without a capacity are a token's
+  logits independent of what else is in the batch.  Single device: it has
+  no ``ep`` sharding and no auxiliary loss (inference only).
 """
 
 from __future__ import annotations
@@ -129,3 +147,99 @@ def moe_mlp(x, p, *, top_k: int, capacity_factor: float,
     # Expert-sharded -> data-sharded: the return all-to-all.
     y = jnp.einsum("ebcd,bsec->bsd", ye, combine.astype(dt))
     return lc(y, ("batch", "seq", "embed")), aux
+
+
+def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
+                 live: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless top-k expert FFN over flat tokens.
+
+    x [T, D]; p = {"router": [D, E], "wgu": [E, 2, D, M] (SwiGLU gate and
+    up), "wd": [E, M, D]}.  With ``layer`` (an index, traced or not) every
+    leaf of ``p`` has a leading layers dim and the layer's experts are read
+    out of the stack where they lie: a model that scans its layers hands
+    over the whole stack and the scan's index, because a layer's slice of
+    it would be a copy of every expert's weights ahead of each step.
+
+    The gates are the ``top_k`` largest of a float32 softmax over all E
+    experts, used as they are unless ``norm_topk_prob`` renormalises them
+    to sum to 1.  ``live`` [T] bool marks the tokens that are somebody's
+    (not padding, not an idle decode slot); all of them when None.  Every
+    token is computed whatever ``live`` says: it only selects what is
+    counted.
+
+    Assignments are sorted by expert and the experts run as two grouped
+    matmuls over the ragged groups: gate and up in one, over ``wgu`` seen
+    as 2E groups of [D, M] (each expert's rows twice), then down.  A group
+    without rows costs nothing, which is also how the other layers of a
+    stack are passed over.  The products run in the WEIGHTS' own type, the
+    activations (a few rows an expert) cast to it: casting the weights
+    instead moves all of them every step for the few that are read.
+
+    Returns (y [T, D] in x's type, load [E] int32: the live tokens'
+    assignments per expert).  The parts carry the scopes ``moe_router``,
+    ``moe_dispatch``, ``moe_experts`` and ``moe_combine`` for the profiler.
+    """
+    T, D = x.shape
+    A = T * top_k
+    if layer is None:               # one layer's experts: a stack of one
+        p, layer = jax.tree.map(lambda a: a[None], p), 0
+    L, E, _, _, M = p["wgu"].shape
+    wgu = p["wgu"].reshape(L * E * 2, D, M)
+    wd = p["wd"].reshape(L * E, M, D)
+
+    def in_stack(sizes):
+        """Group sizes of the whole stack: only this layer's have rows."""
+        return jax.lax.dynamic_update_slice(
+            jnp.zeros((L * sizes.shape[0],), jnp.int32), sizes,
+            (layer * sizes.shape[0],))
+
+    with jax.named_scope("moe_router"):
+        # float32 for real: on a TPU the default precision of a float32
+        # product is one bfloat16 pass, and the eighth and ninth expert
+        # of a token can be that close
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            p["router"][layer].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       top_k)                    # [T, k]
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    with jax.named_scope("moe_dispatch"):
+        flat = experts.reshape(A)                  # assignment -> expert
+        chosen = flat[:, None] == jnp.arange(E)[None, :]         # [A, E]
+        sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)         # [E]
+        if live is None:
+            load = sizes
+        else:
+            load = jnp.sum(chosen & jnp.repeat(live, top_k)[:, None],
+                           axis=0, dtype=jnp.int32)
+        order = jnp.argsort(flat, stable=True)     # sorted by expert
+        token = order // top_k                     # sorted row -> token
+        # Rows for the gate/up matmul: expert e's n_e rows for its gate
+        # group, the same rows again for its up group.  Row q of that
+        # layout lies in group g (found by counting the group ends it has
+        # passed) at rank r, and is sorted row start[e] + r.
+        start = jnp.cumsum(sizes) - sizes
+        sizes2 = jnp.repeat(sizes, 2)
+        ends2 = jnp.cumsum(sizes2)
+        q = jnp.arange(2 * A)
+        g = jnp.sum(q[:, None] >= ends2[None, :], axis=1)
+        row = start[g // 2] + q - (ends2 - sizes2)[g]
+        xs2 = x[token[row]].astype(wgu.dtype)                    # [2A, D]
+        # where sorted row j's gate and up results will be found
+        expert = flat[order]
+        gate_at = jnp.arange(A) + start[expert]
+        up_at = gate_at + sizes[expert]
+    with jax.named_scope("moe_experts"):
+        gu = jax.lax.ragged_dot(xs2, wgu, in_stack(sizes2))      # [2A, M]
+        hidden = jax.nn.silu(gu[gate_at]) * gu[up_at]            # [A, M]
+        ys = jax.lax.ragged_dot(hidden.astype(wd.dtype), wd,
+                                in_stack(sizes))                 # [A, D]
+    with jax.named_scope("moe_combine"):
+        # back to token order by the inverse permutation (a gather, not a
+        # scatter-add), then the gate-weighted sum of each token's k
+        back = ys[jnp.argsort(order)].reshape(T, top_k, D)
+        y = jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1)
+    return y.astype(x.dtype), load

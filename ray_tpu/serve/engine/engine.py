@@ -31,6 +31,17 @@ profile beside the device's programs (``LLMServer.profile``); the two
 thread crossings of a step ride as the ``submit_us`` and ``resume_us``
 attributes of the region that follows them.  ``stats()`` carries the
 always-on counters of the same places.
+
+A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
+``model="llama"`` like any other.  Its two programs return a fourth result,
+the live tokens' assignments per layer and expert; it reaches the host with
+the step's tokens, and what it says of the step (assignments, distinct
+experts touched summed over layers, the largest single-expert load summed
+over layers) is the attributes of an ``rt:engine.decode.moe`` or
+``rt:engine.prefill.moe`` region, beside the ``weight_itemsize`` the experts
+are stored in (what a step reads of a touched expert, for a roofline), and
+adds to the ``moe_*`` counters of ``stats()``.  A dense model's programs return three results and none of
+this runs.
 """
 
 from __future__ import annotations
@@ -149,8 +160,8 @@ class InferenceEngine:
         def _decode(params, token, pos, kp, vp, pt):
             return decode_fn(params, mc, token, pos, kp, vp, pt)
 
-        self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode)
+        self._prefill_program = jax.jit(_prefill)
+        self._decode_program = jax.jit(_decode)
         # What stats() says about where this engine runs: the device that
         # holds the KV pool, and how long each program's first dispatch
         # took to finish (trace + compile or cache load + run).
@@ -173,6 +184,8 @@ class InferenceEngine:
         self._slot_steps = 0
         self._retired = {"done": 0, "cancelled": 0, "expired": 0,
                          "error": 0}
+        self._moe = {"moe_assignments": 0, "moe_experts_hit": 0,
+                     "moe_load_max": 0}
         # Single lane for XLA dispatches: the device serializes anyway,
         # and one lane keeps (k_pages, v_pages) updates ordered.
         self._exec = concurrent.futures.ThreadPoolExecutor(
@@ -233,15 +246,21 @@ class InferenceEngine:
         ``slot_steps / (steps * max_batch)``), ``admitted`` sequences and
         the ``queue_wait_s`` they spent between ``generate()`` and their
         prefill's dispatch, ``prefill_tokens`` of prompt against the
-        ``prefill_padded_tokens`` the padded program ran, and ``retired``
-        sequences by reason."""
+        ``prefill_padded_tokens`` the padded program ran, ``retired``
+        sequences by reason, and of a model with experts the
+        ``moe_assignments`` of real tokens (token x layer x k), the
+        ``moe_experts_hit`` (distinct experts a step touched, summed over
+        layers and steps: over ``layers x num_experts`` a step, the share
+        of expert weights it had to read) and ``moe_load_max`` (the largest
+        single-expert load, summed likewise: over ``moe_assignments /
+        num_experts``, how uneven the routing was)."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
                 "queue_wait_s": self._queue_wait_s,
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
-                "retired": dict(self._retired),
+                "retired": dict(self._retired), **self._moe,
                 "device": self._device,
                 "first_call_s": dict(self._first_call_s)}
 
@@ -252,6 +271,15 @@ class InferenceEngine:
         self._exec.shutdown(wait=False)
 
     # ----------------------------------------------------------- internals
+
+    # (logits, k_pages, v_pages) of either program, for callers outside the
+    # loop; an expert model's fourth result stays behind.
+
+    def _prefill(self, *args):
+        return self._prefill_program(*args)[:3]
+
+    def _decode(self, *args):
+        return self._decode_program(*args)[:3]
 
     def _ensure_loop(self):
         if self._loop_task is None or self._loop_task.done():
@@ -347,6 +375,22 @@ class InferenceEngine:
                     self._retire(seq, "cancelled" if seq.cancelled
                                  else "done")
 
+    def _count_moe(self, program: str, load: Sequence[np.ndarray]):
+        """What an expert model's program said of its real tokens' routing
+        (``load`` [L, E], inside a list that is empty for a dense model):
+        into ``stats()`` and onto the profiler's timeline, there with the
+        bytes a parameter of the experts takes as the program stores them."""
+        for per_layer in load:
+            stored = self._params["layers"]["mlp"]["wd"].dtype.itemsize
+            step = {"assignments": int(per_layer.sum()),
+                    "experts_hit": int(np.count_nonzero(per_layer)),
+                    "load_max": int(per_layer.max(axis=1).sum())}
+            for key, value in step.items():
+                self._moe["moe_" + key] += value
+            with region(f"engine.{program}.moe", weight_itemsize=stored,
+                        **step):
+                pass
+
     def _push(self, seq: _Sequence, token: int) -> bool:
         """Deliver one token; returns True when the sequence is finished
         (EOS or max_new reached)."""
@@ -400,17 +444,19 @@ class InferenceEngine:
                                     prompt_len=len(seq.prompt), padded_len=S,
                                     waited_us=int((t0 - seq.queued) * 1e6),
                                     submit_us=int((t0 - submitted) * 1e6)):
-                            logits, kp, vp = self._prefill(
+                            logits, kp, vp, *load = self._prefill_program(
                                 self._params, toks,
                                 np.int32(len(seq.prompt)), self._k_pages,
                                 self._v_pages, seq.row[None])
                             tok = int(jnp.argmax(logits[0]))
+                            load = [np.asarray(a) for a in load]
                         self._first_call_s.setdefault(
                             "prefill", time.perf_counter() - t0)
-                        return tok, kp, vp, time.perf_counter()
-                    tok, self._k_pages, self._v_pages, returned = \
+                        return tok, kp, vp, load, time.perf_counter()
+                    tok, self._k_pages, self._v_pages, load, returned = \
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
+                    self._count_moe("prefill", load)
                     self._deliver({seq.slot: tok}, returned)
 
                 if not self._active:
@@ -435,19 +481,23 @@ class InferenceEngine:
                     t0 = time.perf_counter()
                     with region("engine.decode.dispatch", active=active,
                                 submit_us=int((t0 - submitted) * 1e6)):
-                        logits, kp, vp = self._decode(
+                        logits, kp, vp, *load = self._decode_program(
                             self._params, token, pos, self._k_pages,
                             self._v_pages, tables)
                         nxt = jnp.argmax(logits, axis=-1)
+                        for a in load:   # on its way beside the tokens
+                            a.copy_to_host_async()
                     with region("engine.decode.fetch"):
                         nxt = np.asarray(nxt)
+                        load = [np.asarray(a) for a in load]
                     self._first_call_s.setdefault(
                         "decode", time.perf_counter() - t0)
-                    return nxt, kp, vp, time.perf_counter()
-                nxt, self._k_pages, self._v_pages, returned = \
+                    return nxt, kp, vp, load, time.perf_counter()
+                nxt, self._k_pages, self._v_pages, load, returned = \
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
                 self._slot_steps += active
+                self._count_moe("decode", load)
                 for seq in self._active.values():
                     seq.pos += 1
                 self._deliver({slot: int(nxt[slot])

@@ -1,0 +1,292 @@
+"""The dropless expert path (``ops/moe.py::moe_dropless``) and its callers in
+``models/llama.py`` and the serving engine, at tiny widths on the CPU.
+
+Every assignment is computed whatever the load; a real token's result does
+not depend on what padding or idle slots hold; and what the engine counts
+of the routing agrees with a count made here in numpy.
+"""
+
+import asyncio
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.llama import (LlamaConfig, llama_decode_step,
+                                  llama_forward, llama_init,
+                                  llama_init_paged_cache, llama_loss,
+                                  llama_param_axes, llama_prefill)
+from ray_tpu.ops.moe import moe_dropless, moe_router
+
+D, M, E, K = 32, 16, 8, 3
+CFG = LlamaConfig(vocab_size=97, max_seq_len=48, num_layers=2, num_heads=4,
+                  num_kv_heads=4, embed_dim=D, mlp_dim=M, num_experts=E,
+                  experts_per_token=K, qk_norm=True, dtype=jnp.float32,
+                  attention="dense", remat=False)
+PAGE, PROMPT, BATCH = 8, 16, 4
+MAXP = CFG.max_seq_len // PAGE
+PREFILL = jax.jit(lambda p, *a: llama_prefill(p, CFG, *a))
+DECODE = jax.jit(lambda p, *a: llama_decode_step(p, CFG, *a))
+
+
+def experts_params(key, experts=E):
+    k = jax.random.split(key, 3)
+    return {"router": jax.random.normal(k[0], (D, experts), jnp.float32),
+            "wgu": 0.3 * jax.random.normal(k[1], (experts, 2, D, M),
+                                           jnp.float32),
+            "wd": 0.3 * jax.random.normal(k[2], (experts, M, D),
+                                          jnp.float32)}
+
+
+def all_experts(x, p, top_k, renorm=False):
+    """Every expert on every token, weighed by a [T, E] gate matrix."""
+    x, p = np.asarray(x, np.float64), jax.tree.map(
+        lambda a: np.asarray(a, np.float64), p)
+    logits = x @ p["router"]
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    kth = np.sort(probs, -1)[:, -top_k][:, None]
+    gates = np.where(probs >= kth, probs, 0.0)
+    if renorm:
+        gates /= gates.sum(-1, keepdims=True)
+    gate = np.einsum("td,edm->tem", x, p["wgu"][:, 0])
+    up = np.einsum("td,edm->tem", x, p["wgu"][:, 1])
+    each = np.einsum("tem,emd->ted", gate / (1 + np.exp(-gate)) * up,
+                     p["wd"])
+    return np.einsum("ted,te->td", each, gates), gates > 0
+
+
+@pytest.mark.parametrize("tokens", [1, 16, 67])
+@pytest.mark.parametrize("renorm", [False, True])
+def test_dropless_equals_every_expert_on_every_token(tokens, renorm):
+    p = experts_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, D))
+    y, load = moe_dropless(x, p, top_k=K, norm_topk_prob=renorm)
+    want, chosen = all_experts(x, p, K, renorm)
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load), chosen.sum(0))
+    assert int(load.sum()) == tokens * K
+
+
+def test_an_expert_with_eight_times_the_mean_load_drops_nothing():
+    """A router that sends every token to expert 0 first: of 16 experts at
+    2 a token it gets all 64 tokens where the mean load is 64 * 2 / 16 = 8,
+    and 54 more than the capacity path (factor 1.25: 10 slots) keeps."""
+    tokens, experts, top_k = 64, 16, 2
+    p = experts_params(jax.random.PRNGKey(1), experts)
+    x = jax.random.normal(jax.random.PRNGKey(2), (tokens, D))
+    x = x.at[:, 0].set(3.0)            # the direction the skew rides on
+    p["router"] = p["router"].at[0, 0].add(10.0)
+    y, load = moe_dropless(x, p, top_k=top_k)
+    want, chosen = all_experts(x, p, top_k)
+    load = np.asarray(load)
+    assert load[0] == tokens == 8 * load.mean()
+    assert load.sum() == tokens * top_k
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+    # the capacity path, on the same routing, loses tokens of expert 0
+    capacity = int(1.25 * tokens * top_k / experts)
+    dispatch, _, _ = moe_router(x[None], p["router"], top_k=top_k,
+                                capacity=capacity)
+    assert float(dispatch[0, :, 0].sum()) == capacity < tokens
+    assert float(dispatch.sum()) < tokens * top_k
+
+
+def test_live_selects_what_is_counted_and_nothing_else():
+    p = experts_params(jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (12, D))
+    live = jnp.arange(12) % 3 != 0
+    y_all, load_all = moe_dropless(x, p, top_k=K)
+    y, load = moe_dropless(x, p, top_k=K, live=live)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_all))
+    _, chosen = all_experts(x, p, K)
+    np.testing.assert_array_equal(np.asarray(load),
+                                  chosen[np.asarray(live)].sum(0))
+    assert int(load.sum()) == int(live.sum()) * K < int(load_all.sum())
+
+
+# ---------------------------------------------------------------- the model
+
+@pytest.fixture(scope="module")
+def params():
+    p = llama_init(jax.random.PRNGKey(0), CFG)
+    # the init's router is nearly flat and its norms are the identity
+    p["layers"]["mlp"]["router"] = p["layers"]["mlp"]["router"] * 30
+    for name, seed in (("q_norm", 5), ("k_norm", 6)):
+        scale = p["layers"]["attn"][name]
+        p["layers"]["attn"][name] = 1 + 0.2 * jax.random.normal(
+            jax.random.PRNGKey(seed), scale.shape)
+    return p
+
+
+def pools():
+    return llama_init_paged_cache(CFG, BATCH * MAXP + 1, PAGE)
+
+
+def table(rows):
+    t = np.zeros((rows, MAXP), np.int32)
+    t[0] = np.arange(1, MAXP + 1)
+    return t
+
+
+def test_init_and_axes_have_the_same_leaves(params):
+    axes = llama_param_axes(CFG)
+    assert jax.tree.structure(jax.tree.map(lambda a: 0, params)) == \
+        jax.tree.structure(jax.tree.map(
+            lambda a: 0, axes, is_leaf=lambda a: isinstance(a, tuple)))
+    mlp = params["layers"]["mlp"]
+    assert mlp["wgu"].shape == (2, E, 2, D, M)
+    assert mlp["wd"].shape == (2, E, M, D)
+    assert mlp["router"].shape == (2, D, E)
+    assert axes["layers"]["mlp"]["wgu"][1] == "expert"
+    assert params["layers"]["attn"]["q_norm"].shape == (2, 4, D // 4)
+    dense = llama_init(jax.random.PRNGKey(0), LlamaConfig.tiny())
+    assert set(dense["layers"]["mlp"]) == {"wgu", "wd"}
+    assert set(dense["layers"]["attn"]) == {"wq", "wkv", "wo"}
+
+
+def test_the_expert_model_is_not_trained_without_its_auxiliary_loss(params):
+    with pytest.raises(NotImplementedError, match="load-balancing"):
+        llama_loss(params, {"tokens": jnp.zeros((1, 9), jnp.int32)}, CFG)
+    with pytest.raises(ValueError, match="experts_per_token"):
+        llama_init(jax.random.PRNGKey(0),
+                   dataclasses.replace(CFG, experts_per_token=E + 1))
+
+
+@pytest.mark.parametrize("filler", [0, 13, 96])
+def test_prefill_of_real_tokens_ignores_the_padding(params, filler):
+    """Logits, the cached keys and values and the load of the prompt's real
+    positions are the same whatever the padded positions hold."""
+    prompt = np.arange(3, 14, dtype=np.int32)
+    kp, vp = pools()
+
+    def run(fill):
+        toks = np.full((1, PROMPT), fill, np.int32)
+        toks[0, :len(prompt)] = prompt
+        return PREFILL(params, toks, np.int32(len(prompt)), kp,
+                             vp, table(1))
+    logits, k1, v1, load = run(filler)
+    base_logits, k0, v0, base_load = run(1)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(base_logits))
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(base_load))
+    np.testing.assert_array_equal(np.asarray(k1[:, :, 1:2]),
+                                  np.asarray(k0[:, :, 1:2]))
+    assert load.shape == (2, E)
+    assert np.asarray(load).sum(1).tolist() == [len(prompt) * K] * 2
+    full = llama_forward(params, jnp.asarray(prompt)[None], CFG)
+    np.testing.assert_allclose(np.asarray(logits[0]),
+                               np.asarray(full[0, -1]), rtol=2e-4,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("idle_token", [0, 50])
+def test_decode_of_a_live_slot_ignores_the_idle_slots(params, idle_token):
+    """One live sequence among idle slots (position 0, scratch page 0):
+    its logits and the load are the same whatever token the idle slots
+    churn, and the load is that sequence's K experts a layer."""
+    prompt = np.arange(3, 14, dtype=np.int32)
+    kp, vp = pools()
+    toks = np.zeros((1, PROMPT), np.int32)
+    toks[0, :len(prompt)] = prompt
+    _, kp, vp, _ = PREFILL(params, toks, np.int32(len(prompt)), kp, vp,
+                           table(1))
+
+    def step(idle):
+        token = np.full((BATCH,), idle, np.int32)
+        pos = np.zeros((BATCH,), np.int32)
+        token[0], pos[0] = 21, len(prompt)
+        return DECODE(params, token, pos, kp, vp, table(BATCH))
+    logits, _, _, load = step(idle_token)
+    base_logits, _, _, base_load = step(7)
+    np.testing.assert_array_equal(np.asarray(logits[0]),
+                                  np.asarray(base_logits[0]))
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(base_load))
+    load = np.asarray(load)
+    assert load.shape == (2, E) and set(load.ravel()) == {0, 1}
+    assert load.sum(1).tolist() == [K, K]
+    full = llama_forward(
+        params, jnp.asarray(np.append(prompt, 21))[None], CFG)
+    np.testing.assert_allclose(np.asarray(logits[0]),
+                               np.asarray(full[0, -1]), rtol=2e-4,
+                               atol=2e-5)
+
+
+# --------------------------------------------------------------- the engine
+
+def routed_experts(params, tokens):
+    """[L, S, E] bool: each position's experts, from a forward pass written
+    here around the model's own attention (numpy routing on its hiddens)."""
+    from ray_tpu.models import llama
+    cos, sin = llama.rope_tables(len(tokens), CFG.head_dim, CFG.rope_theta)
+    x = params["wte"][jnp.asarray(tokens)][None]
+    out = []
+    for i in range(CFG.num_layers):
+        p = jax.tree.map(lambda a: a[i], params["layers"])
+        h = llama._rms_norm(x, p["ln1"]["scale"], CFG.rms_eps)
+        q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"])
+        kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"])
+        q, k = llama._qk(CFG, p, q, kv[:, 0], cos, sin)
+        o = llama._dense_causal_attention_gqa(q, k, kv[:, 1], 1)
+        x = x + jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"])
+        h = llama._rms_norm(x, p["ln2"]["scale"], CFG.rms_eps)
+        y, chosen = all_experts(h[0], p["mlp"], K)
+        out.append(chosen)
+        x = x + jnp.asarray(y, jnp.float32)[None]
+    return np.stack(out)
+
+
+def test_engine_counts_hits_and_loads_as_numpy_does(params):
+    """Two sequences through the engine, one after the other so that every
+    step's live tokens are known: its ``moe_*`` counters equal a count of
+    the experts a forward pass over the generated text chooses."""
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    config = EngineConfig(model="llama", model_config=CFG, page_size=PAGE,
+                          num_pages=BATCH * MAXP + 1, max_batch=BATCH,
+                          max_prompt_len=PROMPT, max_new_tokens=8)
+    prompts, new = ([5, 17, 3, 88, 41, 2, 9], [7, 8, 9]), (6, 4)
+
+    async def go():
+        engine = InferenceEngine(config, params=params)
+        tokens = [[t async for t in engine.generate(p, n)]
+                  for p, n in zip(prompts, new)]
+        stats = engine.stats()
+        engine.close()
+        return tokens, stats
+
+    tokens, stats = asyncio.run(go())
+    want = {"moe_assignments": 0, "moe_experts_hit": 0, "moe_load_max": 0}
+    for prompt, generated in zip(prompts, tokens):
+        # the last generated token is never fed back
+        chosen = routed_experts(params, prompt + generated[:-1])
+        steps = [chosen[:, :len(prompt)]] + [
+            chosen[:, i:i + 1] for i in range(len(prompt), chosen.shape[1])]
+        for step in steps:                     # [L, live tokens, E]
+            load = step.sum(1)
+            want["moe_assignments"] += int(load.sum())
+            want["moe_experts_hit"] += int((load > 0).sum())
+            want["moe_load_max"] += int(load.max(1).sum())
+    assert {k: stats[k] for k in want} == want
+    assert stats["moe_assignments"] == sum(
+        (len(p) + n - 1) * K * CFG.num_layers for p, n in zip(prompts, new))
+    assert stats["steps"] == sum(new) - len(new)
+
+
+def test_a_dense_model_counts_nothing():
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    config = EngineConfig(model="llama", page_size=PAGE, num_pages=16,
+                          max_batch=2, max_prompt_len=PROMPT,
+                          max_new_tokens=4)
+
+    async def go():
+        engine = InferenceEngine(config)
+        tokens = [t async for t in engine.generate([1, 2, 3], 3)]
+        stats = engine.stats()
+        engine.close()
+        return tokens, stats
+
+    tokens, stats = asyncio.run(go())
+    assert len(tokens) == 3
+    assert (stats["moe_assignments"], stats["moe_experts_hit"],
+            stats["moe_load_max"]) == (0, 0, 0)

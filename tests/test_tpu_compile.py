@@ -245,6 +245,60 @@ def test_paged_engine_program_compiles(topo, program):
     _fits(compiled)
 
 
+# OLMoE-1B-7B-0125-Instruct at its published widths, 4 of its 16 layers, with
+# the engine of benchmark/configs/olmoe-1b-7b-0125-4l.json: the dropless
+# expert path's grouped matmuls have to be the compiler's own kernels
+# (``ragged-dot``, a ``tpu_custom_call``) reading the f32 experts where they
+# lie (no bf16 copy of the stack: that alone is 3.2 GB), and the parameters
+# and the KV pool held twice have to leave a GiB of the chip.
+OLMOE_PROMPT, OLMOE_NEW = 512, 1024
+OLMOE_MAXP = (OLMOE_PROMPT + OLMOE_NEW) // PAGE
+OLMOE_BUDGET = 15 * 1024 ** 3
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_olmoe_engine_program_compiles(topo, program):
+    from ray_tpu.models.llama import (LlamaConfig, llama_decode_step,
+                                      llama_init, llama_init_paged_cache,
+                                      llama_prefill)
+    cfg = LlamaConfig(vocab_size=50304, num_layers=4, num_heads=16,
+                      num_kv_heads=16, embed_dim=2048, mlp_dim=1024,
+                      rope_theta=10000.0, rms_eps=1e-5, num_experts=64,
+                      experts_per_token=8, qk_norm=True,
+                      max_seq_len=OLMOE_PROMPT + OLMOE_NEW)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: llama_init(jax.random.PRNGKey(0), cfg)))
+    kp, vp = _on(one, jax.eval_shape(lambda: llama_init_paged_cache(
+        cfg, MAX_BATCH * OLMOE_MAXP + 1, PAGE)))
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    if program == "prefill":
+        compiled, text = _compile(
+            lambda p, *a: llama_prefill(p, cfg, *a), params,
+            arg((1, OLMOE_PROMPT)), arg(()), kp, vp, arg((1, OLMOE_MAXP)))
+    else:
+        compiled, text = _compile(
+            lambda p, *a: llama_decode_step(p, cfg, *a), params,
+            arg((MAX_BATCH,)), arg((MAX_BATCH,)), kp, vp,
+            arg((MAX_BATCH, OLMOE_MAXP)))
+        assert _scoped(text, "paged_read")
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "paged_append"):
+        assert _scoped(text, scope), scope
+    # gate/up and down: two grouped matmuls a layer, work by assignment,
+    # on the stacked f32 experts themselves
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
+    assert len(calls) == 2
+    assert all("f32[512,2048,1024]" in c or "f32[256,1024,2048]" in c
+               for c in calls)
+    assert "bf16[4,64," not in text
+    assert _fits(compiled) < OLMOE_BUDGET
+
+
 # ---------------------------------------------------------------- four chips
 
 def test_ring_attention_sp4_compiles(topo):
