@@ -416,6 +416,31 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
+def _cast_leaves(tree: Dict[str, Any], dtype, *names: str) -> Dict[str, Any]:
+    """``tree`` with the leaves ``names`` as ``dtype``, the rest as they are."""
+    return {**tree, **{n: tree[n].astype(dtype) for n in names}}
+
+
+def gpt_serving_params(params: Dict[str, Any],
+                       cfg: GPTConfig) -> Dict[str, Any]:
+    """``params`` with every leaf in the dtype ``gpt_prefill`` and
+    ``gpt_decode_step`` read it in, for a caller that keeps the tree between
+    calls: the leaves those two cast with ``.astype(cfg.dtype)`` (both
+    embedding tables, every projection and its bias) are cast here, once,
+    and the casts in the steps then cost nothing (``astype`` to an array's
+    own dtype returns the array).  The layer norms' scales and biases are
+    read in f32 and handed back as the caller's own arrays.  Casting twice
+    is casting once, so the steps return the same bits for this tree as for
+    ``params``."""
+    dt, layers = cfg.dtype, params["layers"]
+    return {**_cast_leaves(params, dt, "wte", "wpe"),
+            "layers": {**layers,
+                       "attn": _cast_leaves(layers["attn"], dt,
+                                            "wqkv", "wo", "bo"),
+                       "mlp": _cast_leaves(layers["mlp"], dt,
+                                           "wi", "bi", "wo", "bo")}}
+
+
 def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
                 length: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 page_table: jax.Array

@@ -37,7 +37,7 @@ import numpy as np
 
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
-from ray_tpu.models.gpt import ce_head_loglike_sum
+from ray_tpu.models.gpt import _cast_leaves, ce_head_loglike_sum
 from ray_tpu.parallel.sharding import (LogicalAxisRules,
                                        with_logical_constraint)
 
@@ -356,6 +356,28 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     shape = (cfg.num_layers, cfg.num_kv_heads, num_pages, page_size,
              cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+
+def llama_serving_params(params: Dict[str, Any],
+                         cfg: LlamaConfig) -> Dict[str, Any]:
+    """``params`` with every leaf in the dtype ``llama_prefill`` and
+    ``llama_decode_step`` read it in, for a caller that keeps the tree
+    between calls: the leaves those two cast with ``.astype(cfg.dtype)``
+    (embedding table, head, the attention projections, a dense model's
+    feed-forward) are cast here, once, and the casts in the steps then cost
+    nothing (``astype`` to an array's own dtype returns the array).  Every
+    other leaf is handed back as the caller's own array: the norms' scales,
+    the router and the stacked experts are read in f32 (``_rms_norm``;
+    ``moe_dropless`` casts the few rows of activations to the experts'
+    dtype, never the experts).  Casting twice is casting once, so the steps
+    return the same bits for this tree as for ``params``."""
+    dt, layers = cfg.dtype, params["layers"]
+    mlp = layers["mlp"] if cfg.num_experts else \
+        _cast_leaves(layers["mlp"], dt, "wgu", "wd")
+    return {**_cast_leaves(params, dt, "wte", "lm_head"),
+            "layers": {**layers, "mlp": mlp,
+                       "attn": _cast_leaves(layers["attn"], dt,
+                                            "wq", "wkv", "wo")}}
 
 
 def _paged_results(logits, k_pages, v_pages, load):

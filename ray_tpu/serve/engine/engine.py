@@ -24,6 +24,17 @@ slots parked on scratch page 0.  Both are jitted once; dispatches run on
 a single-thread executor so the actor's event loop keeps serving
 admissions and cancellations while XLA computes.
 
+The parameters are stored once in the dtype the two programs read them in
+(``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
+``.astype(cfg.dtype)`` they repeat): embedding tables, head, attention
+projections and a dense feed-forward in ``model_config.dtype``, so no step
+casts a weight again; norm scales, an expert model's router and its stacked
+experts in the f32 they arrive in, because the steps compute those in f32
+and read only the experts a step touches, where they lie.  The same bits
+come out as from the caller's tree.  The engine keeps no reference to that
+tree: once the caller drops it, a bf16 engine holds half the bytes
+(``stats()["weight_bytes"]``).
+
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
@@ -114,11 +125,12 @@ class InferenceEngine:
         if cfg.model == "gpt":
             from ray_tpu.models.gpt import (GPTConfig, gpt_decode_step,
                                             gpt_init, gpt_prefill,
+                                            gpt_serving_params,
                                             init_paged_cache)
             mc = cfg.model_config or GPTConfig.tiny(
                 seq=cfg.max_prompt_len + cfg.max_new_tokens)
-            init_fn, prefill_fn, decode_fn = \
-                gpt_init, gpt_prefill, gpt_decode_step
+            init_fn, stored_fn, prefill_fn, decode_fn = \
+                gpt_init, gpt_serving_params, gpt_prefill, gpt_decode_step
             cache_fn = lambda: init_paged_cache(   # noqa: E731
                 mc, cfg.num_pages, cfg.page_size, cfg.dtype)
         elif cfg.model == "llama":
@@ -126,11 +138,13 @@ class InferenceEngine:
                                               llama_decode_step,
                                               llama_init,
                                               llama_init_paged_cache,
-                                              llama_prefill)
+                                              llama_prefill,
+                                              llama_serving_params)
             mc = cfg.model_config or LlamaConfig.tiny(
                 seq=cfg.max_prompt_len + cfg.max_new_tokens)
-            init_fn, prefill_fn, decode_fn = \
-                llama_init, llama_prefill, llama_decode_step
+            init_fn, stored_fn, prefill_fn, decode_fn = \
+                llama_init, llama_serving_params, llama_prefill, \
+                llama_decode_step
             cache_fn = lambda: llama_init_paged_cache(   # noqa: E731
                 mc, cfg.num_pages, cfg.page_size, cfg.dtype)
         else:
@@ -142,8 +156,13 @@ class InferenceEngine:
 
         self.config = cfg
         self.model_config = mc
-        self._params = params if params is not None else \
-            init_fn(jax.random.PRNGKey(rng_seed), mc)
+        # Stored once as the two programs read them (module docstring); the
+        # caller's tree is not kept, so what was cast is the caller's to free.
+        self._params = stored_fn(
+            params if params is not None else
+            init_fn(jax.random.PRNGKey(rng_seed), mc), mc)
+        self._weight_bytes = sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self._params))
         self._k_pages, self._v_pages = cache_fn()
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
@@ -253,7 +272,8 @@ class InferenceEngine:
         layers and steps: over ``layers x num_experts`` a step, the share
         of expert weights it had to read) and ``moe_load_max`` (the largest
         single-expert load, summed likewise: over ``moe_assignments /
-        num_experts``, how uneven the routing was)."""
+        num_experts``, how uneven the routing was).  ``weight_bytes`` is
+        the size of the parameters as the engine stores them."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
@@ -261,7 +281,7 @@ class InferenceEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "retired": dict(self._retired), **self._moe,
-                "device": self._device,
+                "weight_bytes": self._weight_bytes, "device": self._device,
                 "first_call_s": dict(self._first_call_s)}
 
     def close(self):

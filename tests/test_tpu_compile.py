@@ -245,6 +245,61 @@ def test_paged_engine_program_compiles(topo, program):
     _fits(compiled)
 
 
+def _llama_engine_program(topo, cfg, program, prompt, new, num_pages):
+    """The engine's prefill or decode for ``cfg``, compiled from the shapes
+    of the tree the engine stores (``llama_serving_params``) and of its
+    pool: (those shapes, the executable, its text)."""
+    from ray_tpu.models.llama import (llama_decode_step, llama_init,
+                                      llama_init_paged_cache, llama_prefill,
+                                      llama_serving_params)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(lambda: llama_serving_params(
+        llama_init(jax.random.PRNGKey(0), cfg), cfg)))
+    kp, vp = _on(one, jax.eval_shape(lambda: llama_init_paged_cache(
+        cfg, num_pages, PAGE)))
+    maxp = (prompt + new) // PAGE
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    if program == "prefill":
+        return params, *_compile(
+            lambda p, *a: llama_prefill(p, cfg, *a), params,
+            arg((1, prompt)), arg(()), kp, vp, arg((1, maxp)))
+    return params, *_compile(
+        lambda p, *a: llama_decode_step(p, cfg, *a), params,
+        arg((MAX_BATCH,)), arg((MAX_BATCH,)), kp, vp, arg((MAX_BATCH, maxp)))
+
+
+# Mistral-7B-v0.3 at its published widths, 8 of its 32 layers, with the engine
+# of benchmark/configs/mistral-7b-v0.3-8l.json: from the stored tree no program
+# casts a parameter (from the caller's f32 tree both cast all six stacks and
+# the table, the feed-forward's as bf16[8,2,4096,14336] and bf16[8,14336,4096],
+# 8.5 GB read and written a call), and without the f32 arguments and the bf16
+# temporaries they need 7.3 and 6.6 GiB where the f32 tree's need 14.5 and 13.6.
+MISTRAL_BUDGET = int(9.5 * 1024 ** 3)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_mistral_engine_program_reads_stored_weights(topo, program):
+    from ray_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(vocab_size=32768, num_layers=8, num_heads=32,
+                      num_kv_heads=8, embed_dim=4096, mlp_dim=14336,
+                      rope_theta=1e6, rms_eps=1e-5, max_seq_len=2048 + 512)
+    params, compiled, text = _llama_engine_program(
+        topo, cfg, program, prompt=2048, new=512, num_pages=2561)
+    assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
+    assert params["layers"]["ln1"]["scale"].dtype == jnp.float32
+    # a program parameter is %p__<path>__; the f32 tree's text has six
+    # `bf16[...] convert(%p__...)` and the table's
+    assert "convert(%p__" not in text
+    made = [line for line in text.splitlines() if re.search(
+        r"= bf16\[8,(2,4096,14336|14336,4096)\]\S* (?!parameter|get-tuple)",
+        line)]
+    assert made == []
+    assert _fits(compiled) < MISTRAL_BUDGET
+
+
 # OLMoE-1B-7B-0125-Instruct at its published widths, 4 of its 16 layers, with
 # the engine of benchmark/configs/olmoe-1b-7b-0125-4l.json: the dropless
 # expert path's grouped matmuls have to be the compiler's own kernels
@@ -252,38 +307,22 @@ def test_paged_engine_program_compiles(topo, program):
 # lie (no bf16 copy of the stack: that alone is 3.2 GB), and the parameters
 # and the KV pool held twice have to leave a GiB of the chip.
 OLMOE_PROMPT, OLMOE_NEW = 512, 1024
-OLMOE_MAXP = (OLMOE_PROMPT + OLMOE_NEW) // PAGE
 OLMOE_BUDGET = 15 * 1024 ** 3
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_olmoe_engine_program_compiles(topo, program):
-    from ray_tpu.models.llama import (LlamaConfig, llama_decode_step,
-                                      llama_init, llama_init_paged_cache,
-                                      llama_prefill)
+    from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=50304, num_layers=4, num_heads=16,
                       num_kv_heads=16, embed_dim=2048, mlp_dim=1024,
                       rope_theta=10000.0, rms_eps=1e-5, num_experts=64,
                       experts_per_token=8, qk_norm=True,
                       max_seq_len=OLMOE_PROMPT + OLMOE_NEW)
-    one = SingleDeviceSharding(topo.devices[0])
-    params = _on(one, jax.eval_shape(
-        lambda: llama_init(jax.random.PRNGKey(0), cfg)))
-    kp, vp = _on(one, jax.eval_shape(lambda: llama_init_paged_cache(
-        cfg, MAX_BATCH * OLMOE_MAXP + 1, PAGE)))
-
-    def arg(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-
-    if program == "prefill":
-        compiled, text = _compile(
-            lambda p, *a: llama_prefill(p, cfg, *a), params,
-            arg((1, OLMOE_PROMPT)), arg(()), kp, vp, arg((1, OLMOE_MAXP)))
-    else:
-        compiled, text = _compile(
-            lambda p, *a: llama_decode_step(p, cfg, *a), params,
-            arg((MAX_BATCH,)), arg((MAX_BATCH,)), kp, vp,
-            arg((MAX_BATCH, OLMOE_MAXP)))
+    params, compiled, text = _llama_engine_program(
+        topo, cfg, program, OLMOE_PROMPT, OLMOE_NEW,
+        MAX_BATCH * (OLMOE_PROMPT + OLMOE_NEW) // PAGE + 1)
+    assert params["layers"]["mlp"]["wgu"].dtype == jnp.float32
+    if program == "decode":
         assert _scoped(text, "paged_read")
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "paged_append"):
