@@ -444,8 +444,6 @@ class GcsServer:
                         "objects_corrupted", "pull_retries",
                         "spill_fsync_ms", "gcs_reconnects",
                         "node_disconnects", "resync_objects_readvertised",
-                        "autotune_cache_hits", "autotune_cache_misses",
-                        "autotune_tune_ms",
                         "router_retries", "circuit_open",
                         "streams_resumed", "drain_handoffs",
                         "ctrl_reresolves",

@@ -504,14 +504,6 @@ class Raylet:
             "resync_objects_readvertised": self._resync_objects_readvertised,
         }
         try:
-            # Kernel-autotune counters (cache hits/misses, tune wall-clock)
-            # for THIS process; worker-process tuning reaches the dashboard
-            # via util.metrics aggregation instead.
-            from ray_tpu.autotune import metrics as _autotune_metrics
-            out.update(_autotune_metrics.stats())
-        except Exception:
-            pass
-        try:
             # Serve resilience counters (router retries, circuit-breaker
             # ejections, mid-stream failovers, drain handoffs) for THIS
             # process; the ingress/controller/handle worker processes

@@ -221,8 +221,6 @@ class DashboardHttpServer:
                          "spill_fsync_ms", "gcs_reconnects",
                          "node_disconnects",
                          "resync_objects_readvertised",
-                         "autotune_cache_hits", "autotune_cache_misses",
-                         "autotune_tune_ms",
                          "router_retries", "circuit_open",
                          "streams_resumed", "drain_handoffs",
                          "ctrl_reresolves",
@@ -244,12 +242,12 @@ class DashboardHttpServer:
         # raw records would emit duplicate series and drop histogram
         # buckets, and any per-endpoint renaming would give one metric two
         # series names depending on scrape point.
-        # Autotune, serve-resilience, and train-resilience counters flow
-        # through the user-metrics pipe (worker processes flush them like
-        # any Counter) but are SYSTEM series: split them out under the
-        # ray_tpu_ prefix so operators find cache hit rate, failover
-        # counts, and checkpoint health next to the other health
-        # series, not namespaced as user metrics.
+        # Serve-resilience and train-resilience counters flow through the
+        # user-metrics pipe (worker processes flush them like any
+        # Counter) but are SYSTEM series: split them out under the
+        # ray_tpu_ prefix so operators find failover counts and
+        # checkpoint health next to the other health series, not
+        # namespaced as user metrics.
         _SERVE_COUNTERS = ("router_retries", "circuit_open",
                            "streams_resumed", "drain_handoffs",
                            "ctrl_reresolves")
@@ -258,8 +256,7 @@ class DashboardHttpServer:
                            "ckpt_corrupt_skipped")
         agg = self.gcs.aggregated_metrics()
         system = [m for m in agg
-                  if str(m.get("name", "")).startswith("autotune_")
-                  or str(m.get("name", "")) in _SERVE_COUNTERS
+                  if str(m.get("name", "")) in _SERVE_COUNTERS
                   or str(m.get("name", "")) in _TRAIN_COUNTERS]
         user = [m for m in agg if m not in system]
         return "\n".join(lines) + "\n" + \

@@ -39,8 +39,8 @@ class GPTConfig:
     # only attention outputs (never re-runs the flash kernel in bwd);
     # "attn_dots" saves both (fastest when it fits HBM).
     remat_policy: str = "full"   # "full" | "dots" | "attn" | "attn_dots"
-    # "auto" picks flash at S>=1024 (the measured v5e crossover), dense
-    # below; explicit values pin the implementation.
+    # "auto" is resolved by resolve_attention (flash from S = 1024 on a
+    # TPU, dense otherwise); explicit values pin the implementation.
     attention: str = "auto"  # "auto"|"dense"|"flash"|"ring" (ring: sp>1)
     # Sequence-block size for the blocked cross-entropy head (0 = apply the
     # head over the full sequence).  With a block, head matmul + CE run per
@@ -178,29 +178,25 @@ def _layer_norm(x, scale, bias, eps=1e-5):
 
 
 def _flash_profitable(S: int) -> bool:
-    """attention="auto" crossover: the Pallas flash kernels win from
-    S>=1024 on v5e (20.9 vs 28.8 ms fwd+bwd at 1024; ~2x at 4096) while
-    XLA dense wins below — short sequences can't amortize the grid/DMA
-    overhead (VERDICT r3 weak #7: per-shape dispatch).  Mosaic also
-    rejects sub-8 blocks, which very short or odd S would hit."""
+    """Whether the Pallas flash kernels are expected to beat XLA's dense
+    attention at sequence length S, from what the code can observe: S and
+    the backend.  The kernels need S in whole 128-lane tiles and enough of
+    them to amortize the grid and the K/V stream; the interpreter on the
+    CPU never wins.  The crossover itself has no measurement on the chip
+    yet (ROADMAP Speed 10, Reach 6): both training cells of the benchmark
+    pin ``flash`` at S = 1024.  A measured crossover edits this function."""
     if S < 1024 or S % 128:
         return False
-    # flash only pays off on real TPU; CPU/interpret is dense's
     return jax.default_backend() != "cpu"
 
 
-def _auto_attention_variant(B: int, S: int, cfg) -> str:
-    """attention="auto" resolution: a measured crossover record from the
-    autotune cache (ray_tpu.autotune) wins when one exists for this
-    shape/backend; a cold cache inherits the static _flash_profitable
-    heuristic unchanged (RT_AUTOTUNE_ON_MISS=inline tunes instead).
-    Only flash/dense are selectable here — ring requires an explicit
-    mesh topology commitment (cfg.attention="ring")."""
-    from ray_tpu.autotune.dispatch import choose
-    v, rec = choose(B, S, cfg.num_heads, cfg.embed_dim // cfg.num_heads,
-                    cfg.dtype, causal=True, allowed=("flash", "dense"))
-    if rec is not None:
-        return v
+def resolve_attention(attention: str, S: int) -> str:
+    """The attention variant a model runs at sequence length S: a pinned
+    ``attention`` ("dense", "flash", "ring") as it is, "auto" as flash or
+    dense by `_flash_profitable`.  ``ring`` is never picked: it is a
+    commitment to a mesh with an ``sp`` axis."""
+    if attention != "auto":
+        return attention
     return "flash" if _flash_profitable(S) else "dense"
 
 
@@ -318,10 +314,8 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
     attention shard_mapped over the `sp` axis (KV rotating via ppermute).
     """
     dt = cfg.dtype
-    B, S = tokens.shape
-    attention = cfg.attention
-    if attention == "auto":
-        attention = _auto_attention_variant(B, S, cfg)
+    S = tokens.shape[1]
+    attention = resolve_attention(cfg.attention, S)
     if attention == "ring" and mesh is not None:
         from jax.sharding import PartitionSpec as P
         from ray_tpu.ops.ring_attention import ring_attention_sharded

@@ -37,7 +37,8 @@ import numpy as np
 
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
-from ray_tpu.models.gpt import _cast_leaves, ce_head_loglike_sum
+from ray_tpu.models.gpt import (_cast_leaves, ce_head_loglike_sum,
+                                resolve_attention)
 from ray_tpu.parallel.sharding import (LogicalAxisRules,
                                        with_logical_constraint)
 
@@ -289,12 +290,8 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
     """tokens [B, S] int32 -> final hidden [B, S, D] after rms_norm (compute
     dtype) — the trunk without the LM head (see gpt_hidden)."""
     dt = cfg.dtype
-    B, S = tokens.shape
-    attention = cfg.attention
-    if attention == "auto":
-        from ray_tpu.models.gpt import _auto_attention_variant
-        attention = _auto_attention_variant(B, S, cfg)
-    if attention == "flash":
+    S = tokens.shape[1]
+    if resolve_attention(cfg.attention, S) == "flash":
         from ray_tpu.models.gpt import _flash_attention_bnsh
         attn_fn = _flash_attention_bnsh(rules, mesh)
     else:

@@ -13,7 +13,6 @@ from ray_tpu.ops.paged_attention import (  # noqa: F401
     append_kv,
     paged_attention,
     prefill_kv,
-    sharded_paged_attention,
 )
 from ray_tpu.ops.ring_attention import (  # noqa: F401
     ring_attention,

@@ -36,7 +36,6 @@ kernels; this is the TPU-native replacement (SURVEY §5.7).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -50,40 +49,42 @@ _NEG_INF = -1e30
 _LANES = 8     # minor-dim width of the lse/D carrier tensors
 _SCR = 128     # lane width of VMEM scratch accumulators
 
-# (backend, B, S, N, H, dtype, causal) -> (block_q, block_k); filled by
-# tune_flash_blocks and consulted when callers pass block_q/block_k = None.
-_TUNED: dict = {}
+# The smallest block the TPU compiler tiles.
+_MIN_BLOCK = 8
 
 
-def _default_blocks(S: int, H: int, strict: bool = True) -> tuple:
-    """Heuristic block sizes: large blocks amortize the K/V stream and the
-    grid launch; 128-lane alignment keeps the MXU full.  Overridable via
-    RT_FLASH_BLOCK_Q / RT_FLASH_BLOCK_K or per-call arguments."""
-    # Swept on v5e (see round-3 notes): 1024x1024 wins at every S in
-    # {1024..8192} — the [bq,bk] f32 probability tile (4MB) still fits VMEM
-    # and larger tiles amortize the grid/DMA overhead.
-    bq = int(os.environ.get("RT_FLASH_BLOCK_Q", 0)) or 1024
-    bk = int(os.environ.get("RT_FLASH_BLOCK_K", 0)) or 1024
-    # Halve until the block divides S.  Mosaic rejects sub-tile (<8)
-    # blocks on real TPU with an opaque compile error, so fail loudly
-    # here instead: sequence lengths with small odd factors must be
-    # padded by the caller.
-    while S % bq:
-        bq //= 2
-    while S % bk:
-        bk //= 2
-    if strict and (bq < 8 or bk < 8):
-        # strict=False (interpret mode) permits sub-tile blocks: the
-        # interpreter has no Mosaic tiling constraint.
-        from ray_tpu.autotune.search import suggest_blocks
-        S_pad, sq, sk = suggest_blocks(S)
+def _default_blocks(S: int, strict: bool = True) -> tuple:
+    """The (block_q, block_k) a call without explicit blocks runs with:
+    1024, halved until it divides S.  (1024, 1024) is what both training
+    cells of the benchmark run (S = 1024; ``flash_fwd/dq/dkv_roofline``
+    18.0 / 22.3 / 21.6 in PERF_LEDGER.jsonl), and the [1024, 1024] f32
+    probability tile (4 MB) fits VMEM.  This is the one place the choice
+    is made: a sweep on the chip changes this table in this file."""
+    b = 1024
+    while S % b:
+        b //= 2
+    if strict and b < _MIN_BLOCK:
+        # Mosaic rejects sub-tile blocks with an opaque compile error, so
+        # fail loudly here: the caller pads such a sequence length.
+        # strict=False (interpret mode) permits them: the interpreter has
+        # no tiling constraint.
+        S_pad, pb = _suggest_blocks(S)
         raise ValueError(
             f"flash_attention: sequence length {S} only admits block sizes "
-            f"({bq}, {bk}) < 8, which the TPU compiler rejects. Pad the "
-            f"sequence to {S_pad} and use block_q={sq}, block_k={sk} "
-            f"(mask the tail), or pass explicit block_q/block_k >= 8 that "
-            f"divide {S}.")
-    return bq, bk
+            f"({b}, {b}) < {_MIN_BLOCK}, which the TPU compiler rejects. "
+            f"Pad the sequence to {S_pad} and use block_q={pb}, "
+            f"block_k={pb} (mask the tail), or pass explicit "
+            f"block_q/block_k >= {_MIN_BLOCK} that divide {S}.")
+    return b, b
+
+
+def _suggest_blocks(S: int) -> tuple:
+    """For an S no TPU-legal block divides: the nearest padded sequence
+    length and the largest block that divides it, (padded_S, block)."""
+    pad = 128 if S > 16 else _MIN_BLOCK
+    S_pad = -(-S // pad) * pad
+    return S_pad, max([b for b in (128, 256, 512, 1024)
+                       if b <= S_pad and S_pad % b == 0] or [pad])
 
 
 # ---------------------------------------------------------------- forward
@@ -406,53 +407,23 @@ def flash_attention(q, k, v, causal: bool = True,
     layout "bnsh": q,k,v [batch, heads, seq, head_dim] — the kernels'
     native view; models that produce attention inputs head-major skip
     the fold transposes entirely (~25% of attention time at short seq).
-    block_q/block_k default to a per-shape heuristic (see _default_blocks)
-    and honor any entry recorded by `tune_flash_blocks`.
+    block_q/block_k are the kernel's own parameters; left None they are
+    what `_default_blocks` gives for S.
     """
     out, _ = _fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
                   layout)
     return out
 
 
-# Shape keys whose autotune-cache consultation already happened (and was
-# counted): repeat _resolve calls for the same shape skip the counters so
-# the hot path doesn't inflate hit counts per kernel invocation.
-_CACHE_CONSULTED: set = set()
-
-
-def _cached_blocks(B, S, N, H, dtype, causal):
-    """Best (block_q, block_k) from the persistent autotune cache, or
-    None."""
-    from ray_tpu.autotune.cache import attention_key, get_cache
-    key = attention_key(B, S, N, H, dtype, causal)
-    first = key not in _CACHE_CONSULTED
-    if first:
-        _CACHE_CONSULTED.add(key)
-    rec = get_cache().lookup("flash_attention", key, count=first)
-    if rec:
-        cfg = rec.get("config") or {}
-        bq, bk = cfg.get("block_q"), cfg.get("block_k")
-        if bq and bk and S % int(bq) == 0 and S % int(bk) == 0:
-            return int(bq), int(bk)
-    return None
-
-
-def _resolve(q, causal, block_q, block_k, interpret, layout):
+def _resolve(q, block_q, block_k, interpret, layout):
     if interpret is None:
         # The interpreter is for the CPU backend, where the tests run.  On
         # any other backend the kernel is compiled, and a kernel that does
         # not compile there is an error, not a slower run.
         interpret = jax.default_backend() == "cpu"
     if block_q is None or block_k is None:
-        if layout == "bnsh":
-            B, N, S, H = q.shape
-        else:
-            B, S, N, H = q.shape
-        key = (jax.default_backend(), B, S, N, H, str(q.dtype), causal)
-        bqbk = (_TUNED.get(key)
-                or _cached_blocks(B, S, N, H, q.dtype, causal)
-                or _default_blocks(S, H, strict=not interpret))
-        bq, bk = bqbk
+        S = q.shape[2 if layout == "bnsh" else 1]
+        bq, bk = _default_blocks(S, strict=not interpret)
         block_q = block_q or bq
         block_k = block_k or bk
     return block_q, block_k, interpret
@@ -460,8 +431,7 @@ def _resolve(q, causal, block_q, block_k, interpret, layout):
 
 def _fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
          layout="bsnh"):
-    bq, bk, interp = _resolve(q, causal, block_q, block_k, interpret,
-                              layout)
+    bq, bk, interp = _resolve(q, block_q, block_k, interpret, layout)
     out, lse = _flash_fwd_impl(q, k, v, causal=causal, block_q=bq,
                                block_k=bk, sm_scale=sm_scale,
                                interpret=interp, layout=layout)
@@ -470,48 +440,10 @@ def _fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
 
 def _bwd(causal, block_q, block_k, sm_scale, interpret, layout, res, g):
     q, k, v, o, lse = res
-    bq, bk, interp = _resolve(q, causal, block_q, block_k, interpret,
-                              layout)
+    bq, bk, interp = _resolve(q, block_q, block_k, interpret, layout)
     return _flash_bwd_impl(q, k, v, o, lse, g, causal=causal, block_q=bq,
                            block_k=bk, sm_scale=sm_scale, interpret=interp,
                            layout=layout)
 
 
 flash_attention.defvjp(_fwd, _bwd)
-
-
-def tune_flash_blocks(B, S, N, H, dtype=jnp.bfloat16, causal=True,
-                      candidates=(128, 256, 512), steps=3):
-    """Thin shim over the autotune subsystem (ray_tpu.autotune): time
-    fwd+bwd for each (block_q, block_k) candidate pair on the live
-    backend, persist the winner to the shared autotune cache, and record
-    it in _TUNED for subsequent block_q=None calls in this process.
-
-    Returns ((block_q, block_k), best_seconds_per_step) —
-    best_seconds_per_step is None when the answer came from a cache
-    (process-local _TUNED or the persistent file) rather than a fresh
-    sweep, preserving the original contract.
-    """
-    from ray_tpu.autotune import search as _search
-    from ray_tpu.autotune.cache import attention_key, get_cache
-
-    key = (jax.default_backend(), B, S, N, H, str(jnp.dtype(dtype)), causal)
-    if key in _TUNED:
-        return _TUNED[key], None
-    ckey = attention_key(B, S, N, H, dtype, causal)
-    cached = get_cache().lookup("flash_attention", ckey) is not None
-    cands = [{"block_q": bq, "block_k": bk}
-             for bq in candidates for bk in candidates
-             if not (S % bq or S % bk or bq > S or bk > S)]
-    rec = _search.tune("flash_attention", ckey, candidates=cands,
-                       iters=steps) if cands else None
-    if rec is None:
-        best, best_t = _default_blocks(S, H), None
-    else:
-        cfg = rec.get("config") or {}
-        best = (int(cfg.get("block_q", 0)) or _default_blocks(S, H)[0],
-                int(cfg.get("block_k", 0)) or _default_blocks(S, H)[1])
-        best_t = None if cached or rec.get("ms") is None \
-            else rec["ms"] / 1e3
-    _TUNED[key] = best
-    return best, best_t

@@ -14,9 +14,8 @@ virtual memory for attention.  The layouts follow the TPU reference op:
     page_table       [B, maxp] int32     page ids per sequence
 
 KV-head-major pages make the GQA sharding trivial: shard dim 0 of the
-pools and the head dim of q over the model axis (SNIPPETS [1]'s
-``sharded_paged_attention``) and every chip decodes its head slice of
-ALL sequences with no cross-chip traffic.
+pools and the head dim of q over the model axis, and every chip decodes
+its head slice of ALL sequences with no cross-chip traffic.
 
 This file is the jnp reference implementation (gather + masked softmax
 — the decode working set is one token per sequence, so XLA's fused
@@ -29,7 +28,7 @@ paged-vs-dense CPU equivalence tests assert.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -109,29 +108,3 @@ def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, k_seq: jax.Array,
         slot = pos % page
         return (k_pages.at[:, pid, slot].set(k_seq.astype(k_pages.dtype)),
                 v_pages.at[:, pid, slot].set(v_seq.astype(v_pages.dtype)))
-
-
-def sharded_paged_attention(mesh, *, model_axis: str = "model",
-                            sm_scale: Optional[float] = None
-                            ) -> Callable[..., Any]:
-    """GQA paged attention shard_mapped over KV heads (SNIPPETS [1]):
-    q shards its head dim, the pools shard their leading KV-head dim,
-    lengths/page tables replicate — per-chip decode with zero collective
-    traffic (each output head needs only its own KV head group)."""
-    from jax.sharding import PartitionSpec as P
-
-    in_specs = (
-        P(None, model_axis, None),         # q [B, N, H]
-        P(model_axis, None, None, None),   # k_pages [NKV, P, page, H]
-        P(model_axis, None, None, None),   # v_pages
-        P(),                               # lengths
-        P(),                               # page_table
-    )
-    out_specs = P(None, model_axis, None)
-
-    def _paged(q, k_pages, v_pages, lengths, page_table):
-        return paged_attention(q, k_pages, v_pages, lengths, page_table,
-                               sm_scale=sm_scale)
-
-    return jax.jit(jax.shard_map(_paged, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False))

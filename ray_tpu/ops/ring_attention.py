@@ -126,11 +126,9 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True):
 def make_ring_attention_fn(mesh, axis_name: str = "sp",
                            batch_axes=("dp", "fsdp"),
                            head_axis: Optional[str] = "tp"):
-    """Autotune-dispatch hook: close over the mesh/axis topology once and
-    return an `(q, k, v) -> o` callable with the plain attention
-    signature the dispatcher (ray_tpu.autotune.dispatch) and the timing
-    harness expect.  Raises ValueError up front when the mesh cannot
-    carry a ring (no `axis_name` axis, or size 1 — a 1-wide ring is just
+    """Close over the mesh/axis topology once and return an
+    `(q, k, v) -> o` callable with the plain attention signature.  Raises
+    ValueError up front when the mesh cannot carry a ring (no `axis_name` axis, or size 1 — a 1-wide ring is just
     dense attention with extra collectives)."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     sp = sizes.get(axis_name, 1)

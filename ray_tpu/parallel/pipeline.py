@@ -325,24 +325,21 @@ def _make_loss_mb(cfg):
     return loss_mb
 
 
-def _attn_fn_for(cfg, mesh=None):
-    """Same head-major (bnsh) selections the non-pipelined block uses —
-    pipelined stages must not silently keep the relayout-paying path.
+def _attn_fn_for(cfg, S, mesh=None):
+    """Same head-major (bnsh) selections the non-pipelined block uses at
+    sequence length S — pipelined stages must not silently keep the
+    relayout-paying path.
     ``ring`` threads the sp axis through the stage body: stages see
     [mb, S/sp, ...] activation shards and the ring collective runs inside
     the same shard_map as the pipeline (VERDICT r3 #6)."""
     from ray_tpu.models.gpt import (_dense_causal_attention_bnsh,
-                                    _flash_profitable)
+                                    resolve_attention)
 
-    attention = cfg.attention
-    if attention == "auto":
-        attention = ("flash" if _flash_profitable(cfg.max_seq_len)
-                     else "dense")
+    attention = resolve_attention(cfg.attention, S)
     assert attention in ("dense", "flash", "ring"), (
         f"pipelined stages support dense/flash/ring attention, got "
         f"{attention!r}")
-    cfg = type(cfg)(**{**cfg.__dict__, "attention": attention})
-    if cfg.attention == "ring":
+    if attention == "ring":
         assert mesh is not None and mesh.shape.get("sp", 1) > 1, (
             "ring attention in a pipeline needs an sp mesh axis > 1")
         from ray_tpu.ops.ring_attention import ring_attention_sharded
@@ -350,7 +347,7 @@ def _attn_fn_for(cfg, mesh=None):
         def attn_fn(q, k, v):
             return ring_attention_sharded(q, k, v, axis_name="sp")
         return attn_fn
-    if cfg.attention == "flash":
+    if attention == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
 
         def attn_fn(q, k, v):
@@ -420,7 +417,7 @@ def gpt_forward_pipelined(params: Dict[str, Any], tokens, cfg, mesh, *,
     x_mbs = x.reshape(M, B // M, S, -1)
 
     use_ep = cfg.num_experts and mesh.shape.get("ep", 1) > 1
-    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, mesh),
+    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, S, mesh),
                               moe_ep_axis="ep" if use_ep else None)
     data = tuple(a for a in ("dp", "fsdp") if a in mesh.shape)
     use_sp = cfg.attention == "ring" and mesh.shape.get("sp", 1) > 1
@@ -466,7 +463,7 @@ def gpt_loss_pipelined(params, batch, cfg, mesh, *, num_microbatches: int):
     tgt_mbs = targets.reshape(M, B // M, S)
 
     use_ep = cfg.num_experts and mesh.shape.get("ep", 1) > 1
-    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, mesh),
+    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, S, mesh),
                               moe_ep_axis="ep" if use_ep else None)
 
     loss_mb = _make_loss_mb(cfg)
@@ -508,7 +505,7 @@ def gpt_loss_1f1b(params, batch, cfg, mesh, *, num_microbatches: int):
     gradients.  v1 scope: dense/flash stages, dp/fsdp data sharding (use
     the GPipe path for pp x ep MoE or sp ring stages).
     """
-    from ray_tpu.models.gpt import _block
+    from ray_tpu.models.gpt import _block, resolve_attention
 
     toks = batch["tokens"]
     tokens, targets = toks[:, :-1], toks[:, 1:]
@@ -517,15 +514,11 @@ def gpt_loss_1f1b(params, batch, cfg, mesh, *, num_microbatches: int):
     dsize = _check_pipeline_shapes(cfg, mesh, B, M)
     assert not (cfg.num_experts and mesh.shape.get("ep", 1) > 1), (
         "1F1B v1 does not compose with ep; use the GPipe path")
-    if cfg.attention == "auto":
-        from ray_tpu.models.gpt import _flash_profitable
-        cfg = type(cfg)(**{**cfg.__dict__, "attention": (
-            "flash" if _flash_profitable(cfg.max_seq_len) else "dense")})
-    assert cfg.attention in ("dense", "flash"), (
+    assert resolve_attention(cfg.attention, S) in ("dense", "flash"), (
         "1F1B v1 supports dense/flash stages; ring/sp uses the GPipe path")
     dt = cfg.dtype
 
-    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg),
+    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, S),
                               moe_ep_axis=None)
     loss_mb = _make_loss_mb(cfg)
 

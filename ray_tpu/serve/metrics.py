@@ -1,7 +1,6 @@
 """Serve resilience observability counters.
 
-Same dual-sink shape as ``ray_tpu.autotune.metrics`` — one ``bump()``
-feeds:
+Two sinks — one ``bump()`` feeds:
 
 * a plain in-process dict (``stats()``) — the raylet folds it into its
   node-stats report so head-side consumers (``state.serve_totals()``,

@@ -164,8 +164,7 @@ class LintConfig:
     # the exception-logging done callback themselves).
     spawn_helpers: Tuple[str, ...] = ("spawn", "spawn_logged")
     # rule 5: directories (path fragments) where jit purity is enforced.
-    jit_dirs: Tuple[str, ...] = ("ops/", "models/", "autotune/",
-                                 "train/", "parallel/")
+    jit_dirs: Tuple[str, ...] = ("ops/", "models/", "train/", "parallel/")
     # rule 6: role -> path suffix for the metrics pipeline files.
     metrics_roles: Dict[str, str] = field(default_factory=lambda: {
         "node_stats": "_private/raylet.py",
@@ -187,7 +186,6 @@ class LintConfig:
         "_private/object_transfer.py",
         "_private/gcs.py",
         "_private/daemon_main.py",
-        "autotune/cache.py",
         "workflow/api.py",
     )
     # rule 8 (cancellation-safety): path fragments where swallowing
@@ -207,7 +205,7 @@ class LintConfig:
     # are exempt.
     knob_docs: Tuple[str, ...] = (
         "docs/KNOBS.md", "docs/SERVE.md", "docs/TRAIN.md",
-        "docs/AUTOTUNE.md", "docs/LINT.md", "ARCHITECTURE.md",
+        "docs/LINT.md", "ARCHITECTURE.md",
     )
     knob_internal: Tuple[str, ...] = (
         "RT_ADDRESS", "RT_GCS_ADDRESS", "RT_RAYLET_ADDRESS",

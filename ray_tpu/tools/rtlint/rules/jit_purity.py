@@ -5,9 +5,9 @@ Functions handed to ``jax.jit`` / ``jax.pmap`` / ``shard_map`` /
 programs: Python side effects inside them run at *trace* time only (or
 not at all on cache hits), so ``print``, ``time.time``, host RNG, and
 global mutation are at best misleading and at worst nondeterminism
-that poisons the autotune cache (whose keys assume pure kernels).
+between a traced run and a compile-cache hit.
 
-Scope: files under ``config.jit_dirs`` (ops/, models/, autotune/).
+Scope: files under ``config.jit_dirs`` (ops/, models/, train/, parallel/).
 Jitted functions are found two ways:
 - decorator form: ``@jax.jit``, ``@jit``, ``@partial(jax.jit, ...)``,
   ``@functools.partial(shard_map, ...)``, ``@pl.pallas_call(...)``;

@@ -110,31 +110,6 @@ def control_plane_totals() -> Dict[str, Any]:
     return out
 
 
-def autotune_totals() -> Dict[str, Any]:
-    """Cluster-wide kernel-autotune counters: cache ``hits``/``misses``
-    and cumulative tuning wall-clock (``autotune_tune_ms``), combining
-    raylet-side counts ridden in over node stats (live + dead-node
-    carry-over) with the worker-process counters aggregated through the
-    user-metrics pipe (raylets never flush user metrics, so the two
-    sources never double count)."""
-    reply = _gcs_request({"type": "get_node_stats"}) or {}
-    stats = reply.get("nodes", {})
-    dead = reply.get("dead_totals", {})
-    out: Dict[str, Any] = {}
-    for k in ("autotune_cache_hits", "autotune_cache_misses",
-              "autotune_tune_ms"):
-        out[k] = dead.get(k, 0) + sum(s.get(k, 0) for s in stats.values())
-    try:
-        agg = _gcs_request({"type": "list_metrics"}) or []
-        for m in agg:
-            name = str(m.get("name", ""))
-            if name in out and m.get("type") == "counter":
-                out[name] += m.get("value", 0)
-    except Exception:
-        pass
-    return out
-
-
 def serve_totals() -> Dict[str, Any]:
     """Cluster-wide serve-resilience counters: requests re-routed after a
     retryable failure (``router_retries``), circuit-breaker ejections

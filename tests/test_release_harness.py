@@ -4,9 +4,13 @@ Reference analog: release/release_tests.yaml + ray_release runner (success
 criteria with hard pass/fail per workload).
 """
 
+import importlib.util
 import json
 import os
+import shlex
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "release"))
@@ -14,17 +18,33 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 from run_release_suite import load_suite, run_test  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SUITE = load_suite(os.path.join(REPO, "release", "release_tests.yaml"))
 
 
 def test_load_suite_parses_entries():
-    tests = load_suite(os.path.join(REPO, "release", "release_tests.yaml"))
+    tests = SUITE
     names = {t["name"] for t in tests}
-    assert {"microbenchmark", "train_gpt_bench",
+    assert {"microbenchmark", "train_ckpt_async_bench",
             "multichip_dryrun"} <= names
     mb = next(t for t in tests if t["name"] == "microbenchmark")
     assert "smoke" in mb["suite"]
     assert mb["timeout_s"] == 420
     assert mb["success_criteria"]["1_1_actor_calls_sync"]["min"] == 1500
+
+
+@pytest.mark.parametrize("row", SUITE, ids=[t["name"] for t in SUITE])
+def test_entrypoint_exists(row):
+    """What a row runs is in the tree: the script, or the ``-m`` module,
+    and every ``.py`` file handed to it (a row left behind by a deleted
+    benchmark or test file fails here, not in the nightly)."""
+    argv = shlex.split(row["entrypoint"])
+    assert argv[0] == "python", row["entrypoint"]
+    if argv[1] == "-m":
+        assert importlib.util.find_spec(argv[2]) is not None, argv[2]
+    files = [a for a in argv[1:] if a.endswith(".py")]
+    assert files or argv[1] == "-m", row["entrypoint"]
+    for path in files:
+        assert os.path.isfile(os.path.join(REPO, path)), path
 
 
 def test_run_test_evaluates_criteria(tmp_path):

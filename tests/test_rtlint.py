@@ -310,7 +310,7 @@ def test_jit_purity_clean_kernel(tmp_path):
 
 
 def test_jit_purity_mutable_static_default(tmp_path):
-    _write(tmp_path / "proj", "autotune/a.py", """
+    _write(tmp_path / "proj", "ops/a.py", """
         import jax
         @jax.jit
         def f(x, cfg=[1, 2]):

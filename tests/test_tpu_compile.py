@@ -78,8 +78,8 @@ def compiled_kernels(monkeypatch):
     real = fa._resolve
     monkeypatch.setattr(
         fa, "_resolve",
-        lambda q, causal, bq, bk, interpret, layout:
-            real(q, causal, bq, bk, False, layout))
+        lambda q, bq, bk, interpret, layout:
+            real(q, bq, bk, False, layout))
 
 
 def _compile(fn, *args):
