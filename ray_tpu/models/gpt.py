@@ -400,13 +400,13 @@ def gpt_forward(params: Dict[str, Any], tokens: jax.Array, cfg: GPTConfig,
 
 def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
                      dtype: Any = None) -> Tuple[jax.Array, jax.Array]:
-    """Zeroed per-layer K/V page pools, [L, N, P, page, H] (KV-head-major
-    within each layer, matching ops.paged_attention's layouts).  Page 0
-    is the scratch sink for padded/inactive writes — allocators must
-    never hand it out."""
+    """Zeroed K/V page pools of all layers, [L, P, page, N*H]
+    (token-major, matching ops.paged_attention's layouts).  Page 0 is the
+    scratch sink for padded/inactive writes — allocators must never hand
+    it out."""
     dt = dtype or cfg.dtype
-    shape = (cfg.num_layers, cfg.num_heads, num_pages, page_size,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_heads * cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
@@ -445,7 +445,8 @@ def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
 
     ``tokens`` [1, S] (S a multiple of the page size, S <= max_seq_len),
     ``length`` scalar int32 true length, ``page_table`` [1, maxp];
-    ``k_pages``/``v_pages`` [L, N, P, page, H].  Padding positions write
+    ``k_pages``/``v_pages`` [L, P, page, N*H], carried through the layer
+    scan and written in place.  Padding positions write
     to scratch page 0 (see ops.paged_attention.prefill_kv) and, being
     causal, never influence positions < length.  Returns
     (logits [1, V] f32, k_pages, v_pages)."""
@@ -455,12 +456,13 @@ def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
     x = params["wte"].astype(dt)[tokens] \
         + params["wpe"].astype(dt)[:S][None]
 
-    def body(x, inp):
-        p, kp, vp = inp
+    def body(carry, inp):
+        (x, kp, vp), (p, layer) = carry, inp
         h = _layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"])
         qkv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wqkv"].astype(dt))
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]        # [B, N, S, H]
-        kp, vp = prefill_kv(kp, vp, k[0], v[0], length, page_table[0])
+        kp, vp = prefill_kv(kp, vp, layer, k[0], v[0], length,
+                            page_table[0])
         o = _dense_causal_attention_bnsh(q, k, v)
         o = jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
         x = x + o + p["attn"]["bo"].astype(dt)
@@ -470,10 +472,11 @@ def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
         h = jax.nn.gelu(h)
         h = jnp.einsum("bsm,md->bsd", h, p["mlp"]["wo"].astype(dt)) \
             + p["mlp"]["bo"].astype(dt)
-        return x + h, (kp, vp)
+        return (x + h, kp, vp), None
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages),
+        (params["layers"], jnp.arange(cfg.num_layers)))
     x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,vd->v", last,
@@ -497,13 +500,13 @@ def gpt_decode_step(params: Dict[str, Any], cfg: GPTConfig,
     dt = cfg.dtype
     x = params["wte"].astype(dt)[token] + params["wpe"].astype(dt)[pos]
 
-    def body(x, inp):
-        p, kp, vp = inp
+    def body(carry, inp):
+        (x, kp, vp), (p, layer) = carry, inp
         h = _layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"])
         qkv = jnp.einsum("bd,dcnh->bcnh", h, p["attn"]["wqkv"].astype(dt))
         q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]   # [B, N, H]
-        kp, vp = append_kv(kp, vp, k_new, v_new, pos, page_table)
-        o = paged_attention(q, kp, vp, pos + 1, page_table)
+        kp, vp = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
+        o = paged_attention(q, kp, vp, layer, pos + 1, page_table)
         o = jnp.einsum("bnh,nhd->bd", o, p["attn"]["wo"].astype(dt))
         x = x + o + p["attn"]["bo"].astype(dt)
         h = _layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"])
@@ -512,10 +515,11 @@ def gpt_decode_step(params: Dict[str, Any], cfg: GPTConfig,
         h = jax.nn.gelu(h)
         h = jnp.einsum("bm,md->bd", h, p["mlp"]["wo"].astype(dt)) \
             + p["mlp"]["bo"].astype(dt)
-        return x + h, (kp, vp)
+        return (x + h, kp, vp), None
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["layers"], k_pages, v_pages))
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages),
+        (params["layers"], jnp.arange(cfg.num_layers)))
     x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     logits = jnp.einsum("bd,vd->bv", x,
                         params["wte"].astype(dt)).astype(jnp.float32)

@@ -335,8 +335,8 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 
 # ---------------------------------------------------------------------------
 # Paged KV-cache decode (serving path) — LLaMA variant of gpt.py's
-# init_paged_cache/gpt_prefill/gpt_decode_step.  GQA makes the pools
-# NKV-head-major (kv_heads, not heads), rope is applied at each token's
+# init_paged_cache/gpt_prefill/gpt_decode_step.  GQA keeps the pools at
+# kv_heads width (not heads), rope is applied at each token's
 # absolute position before the K is scattered (the pools hold POST-rope
 # keys, so decode attention is a plain dot against the cache), and the
 # math mirrors _block's grouped dense branch exactly — with
@@ -346,12 +346,12 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 
 def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                            page_size: int, dtype: Any = None):
-    """Zeroed per-layer K/V page pools, [L, NKV, P, page, H].  Page 0 is
-    the scratch sink for padded/inactive writes — allocators must never
-    hand it out."""
+    """Zeroed K/V page pools of all layers, [L, P, page, NKV*H]
+    (token-major: see ops.paged_attention).  Page 0 is the scratch sink
+    for padded/inactive writes — allocators must never hand it out."""
     dt = dtype or cfg.dtype
-    shape = (cfg.num_layers, cfg.num_kv_heads, num_pages, page_size,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
@@ -392,7 +392,8 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     per-layer post-rope K/V scattered into the sequence's pages, f32
     next-token logits at position length-1.  ``tokens`` [1, S] with S a
     multiple of the page size; ``page_table`` [1, maxp];
-    ``k_pages``/``v_pages`` [L, NKV, P, page, H].  An expert model returns a
+    ``k_pages``/``v_pages`` [L, P, page, NKV*H], carried through the layer
+    scan and written in place.  An expert model returns a
     fourth result, ``load`` [L, E] int32: per layer and expert, the
     assignments of the prompt's real positions."""
     from ray_tpu.ops.paged_attention import prefill_kv
@@ -404,22 +405,23 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     live = (jnp.arange(S) < length)[None]                # the real positions
     layers, experts = _scanned_layers(cfg, params)
 
-    def body(x, inp):
-        p, kp, vp = inp
+    def body(carry, inp):
+        (x, kp, vp), (p, layer) = carry, inp
         h = _rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
         q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
         kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
         k, v = kv[:, 0], kv[:, 1]                        # [B, NKV, S, H]
         q, k = _qk(cfg, p, q, k, cos, sin)
-        kp, vp = prefill_kv(kp, vp, k[0], v[0], length, page_table[0])
+        kp, vp = prefill_kv(kp, vp, layer, k[0], v[0], length,
+                            page_table[0])
         o = _dense_causal_attention_gqa(q, k, v, rep)
         x = x + jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
         y, load = _ffn(cfg, p, h, live, experts=experts)
-        return x + y, (kp, vp, load)
+        return (x + y, kp, vp), load
 
-    x, (k_pages, v_pages, load) = jax.lax.scan(
-        body, x, (layers, k_pages, v_pages))
+    (x, k_pages, v_pages), load = jax.lax.scan(
+        body, (x, k_pages, v_pages), (layers, jnp.arange(cfg.num_layers)))
     x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,dv->v", last,
@@ -448,22 +450,22 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     live = pos > 0
     layers, experts = _scanned_layers(cfg, params)
 
-    def body(x, inp):
-        p, kp, vp = inp
+    def body(carry, inp):
+        (x, kp, vp), (p, layer) = carry, inp
         h = _rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
         q = jnp.einsum("bd,dnh->bnh", h, p["attn"]["wq"].astype(dt))
         kv = jnp.einsum("bd,dcnh->bcnh", h, p["attn"]["wkv"].astype(dt))
         k_new, v_new = kv[:, 0], kv[:, 1]                # [B, NKV, H]
         q, k_new = _qk(cfg, p, q, k_new, cos, sin)
-        kp, vp = append_kv(kp, vp, k_new, v_new, pos, page_table)
-        o = paged_attention(q, kp, vp, pos + 1, page_table)
+        kp, vp = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
+        o = paged_attention(q, kp, vp, layer, pos + 1, page_table)
         x = x + jnp.einsum("bnh,nhd->bd", o, p["attn"]["wo"].astype(dt))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
         y, load = _ffn(cfg, p, h, live, experts=experts)
-        return x + y, (kp, vp, load)
+        return (x + y, kp, vp), load
 
-    x, (k_pages, v_pages, load) = jax.lax.scan(
-        body, x, (layers, k_pages, v_pages))
+    (x, k_pages, v_pages), load = jax.lax.scan(
+        body, (x, k_pages, v_pages), (layers, jnp.arange(cfg.num_layers)))
     x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
     logits = jnp.einsum("bd,dv->bv", x,
                         params["lm_head"].astype(dt)).astype(jnp.float32)

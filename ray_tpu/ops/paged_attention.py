@@ -6,16 +6,29 @@ is neither per-sequence-contiguous (internal fragmentation kills batch
 size) nor re-run-the-prefix (quadratic decode).  Instead K/V live in a
 pool of fixed-size **pages** shared by all sequences, and each sequence
 maps its positions to pages through a small **page table** — exactly
-virtual memory for attention.  The layouts follow the TPU reference op:
+virtual memory for attention.  The layouts:
 
-    q                [B, N, H]           one query token per sequence
-    k_pages, v_pages [NKV, P, page, H]   KV-head-major page pools
-    lengths          [B] int32           valid positions per sequence
-    page_table       [B, maxp] int32     page ids per sequence
+    q                [B, N, H]              one query token per sequence
+    k_pages, v_pages [L, P, page, NKV*H]    every layer's pool, token-major
+    layer            scalar int32           the layer a call reads or writes
+    lengths          [B] int32              valid positions per sequence
+    page_table       [B, maxp] int32        page ids per sequence
 
-KV-head-major pages make the GQA sharding trivial: shard dim 0 of the
-pools and the head dim of q over the model axis, and every chip decodes
-its head slice of ALL sequences with no cross-chip traffic.
+The pools of ALL layers are one array each, and the three functions take
+the layer's index: the models carry the pools through their layer scan and
+a step scatters one token a sequence into the whole pool (``append_kv``; a
+prompt's, ``prefill_kv``) and gathers ``(layer, page_table)`` from it.
+With the pools donated by the caller, the TPU compiler then updates them in
+place; handed to the scan a layer at a time (``xs`` in, ``ys`` out) every
+layer's pool was sliced out and copied back once a call.  Token-major with
+a token's heads folded into one axis of ``NKV*H`` lanes is the layout the
+scatter wants (its window trailing and contiguous) and the one that compiles
+without a relayout of the pool for every family: with the heads apart
+``[L, P, page, NKV, H]`` a head size under the TPU's 128 lanes (GPT-2's 64)
+makes the compiler carry the pool in another layout and copy it whole, a
+layer at a time (PERF.md, PR 29).  KV-head-major pools ``[L, NKV, P, page,
+H]`` compile to a whole-pool relayout before and after the scatter.  To
+shard the KV heads over a model axis, shard the last axis in whole heads.
 
 This file is the jnp reference implementation (gather + masked softmax
 — the decode working set is one token per sequence, so XLA's fused
@@ -36,18 +49,19 @@ import numpy as np
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    lengths: jax.Array, page_table: jax.Array, *,
+                    layer: jax.Array, lengths: jax.Array,
+                    page_table: jax.Array, *,
                     sm_scale: Optional[float] = None) -> jax.Array:
-    """Single-token decode attention against paged K/V.
+    """Single-token decode attention against one layer of the paged K/V.
 
-    ``q`` [B, N, H]; ``k_pages``/``v_pages`` [NKV, P, page, H];
-    ``lengths`` [B] (positions < length attend, so the current token's
-    K/V must already be written at position length-1); ``page_table``
-    [B, maxp].  GQA when N > NKV (N % NKV == 0).  Returns [B, N, H] in
-    q's dtype; softmax runs in f32.
+    ``q`` [B, N, H]; ``k_pages``/``v_pages`` [L, P, page, NKV*H];
+    ``layer`` scalar int32; ``lengths`` [B] (positions < length attend, so
+    the current token's K/V must already be written at position
+    length-1); ``page_table`` [B, maxp].  GQA when N > NKV (N % NKV == 0).
+    Returns [B, N, H] in q's dtype; softmax runs in f32.
     """
     B, N, H = q.shape
-    NKV, _P, page, _H = k_pages.shape
+    page, NKV = k_pages.shape[2], k_pages.shape[3] // H
     if N % NKV:
         raise ValueError(f"query heads {N} not a multiple of KV heads {NKV}")
     rep = N // NKV
@@ -55,50 +69,58 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     maxp = page_table.shape[1]
     S = maxp * page
 
-    # Gather each sequence's pages: [NKV, B, maxp, page, H] -> [NKV, B, S, H]
+    # Gather each sequence's pages of this layer, straight from the whole
+    # pool: [B, maxp, page, NKV*H] -> [B, S, NKV, H]
     with jax.named_scope("paged_read"):
-        k = k_pages[:, page_table].reshape(NKV, B, S, H)
-        v = v_pages[:, page_table].reshape(NKV, B, S, H)
+        k = k_pages[layer, page_table].reshape(B, S, NKV, H)
+        v = v_pages[layer, page_table].reshape(B, S, NKV, H)
 
     qg = q.reshape(B, NKV, rep, H)
-    scores = jnp.einsum("bkrh,kbsh->bkrs", qg, k) * scale
+    scores = jnp.einsum("bkrh,bskh->bkrs", qg, k) * scale
     valid = jnp.arange(S)[None] < lengths[:, None]          # [B, S]
     scores = jnp.where(valid[:, None, None],
                        scores.astype(jnp.float32), -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkrs,kbsh->bkrh", probs, v)
+    out = jnp.einsum("bkrs,bskh->bkrh", probs, v)
     return out.reshape(B, N, H)
 
 
-def append_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
-              v_new: jax.Array, pos: jax.Array, page_table: jax.Array):
-    """Scatter one token's K/V per sequence into the pools.
+def _folded(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """Tokens' K or V [T, NKV, H] as the pool stores a token: [T, NKV*H]."""
+    return x.reshape(x.shape[0], -1).astype(pool.dtype)
 
-    ``k_new``/``v_new`` [B, NKV, H]; ``pos`` [B] target positions;
-    ``page_table`` [B, maxp].  Sequences route through their own pages so
-    the scatter never conflicts; callers park inactive batch slots on
-    page 0 (the scratch sink the allocator reserves) by handing them an
-    all-zero page-table row and pos 0.
+
+def append_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,
+              k_new: jax.Array, v_new: jax.Array, pos: jax.Array,
+              page_table: jax.Array):
+    """Scatter one token's K/V per sequence into one layer of the pools.
+
+    ``k_new``/``v_new`` [B, NKV, H]; ``layer`` scalar int32; ``pos`` [B]
+    target positions; ``page_table`` [B, maxp].  Sequences route through
+    their own pages so the scatter never conflicts; callers park inactive
+    batch slots on page 0 (the scratch sink the allocator reserves) by
+    handing them an all-zero page-table row and pos 0.
     """
     page = k_pages.shape[2]
     with jax.named_scope("paged_append"):
         pid = jnp.take_along_axis(page_table, (pos // page)[:, None],
                                   axis=1)[:, 0]                  # [B]
         slot = pos % page
-        k_new = jnp.swapaxes(k_new, 0, 1).astype(k_pages.dtype)  # [NKV,B,H]
-        v_new = jnp.swapaxes(v_new, 0, 1).astype(v_pages.dtype)
-        return (k_pages.at[:, pid, slot].set(k_new),
-                v_pages.at[:, pid, slot].set(v_new))
+        return (k_pages.at[layer, pid, slot].set(_folded(k_new, k_pages)),
+                v_pages.at[layer, pid, slot].set(_folded(v_new, v_pages)))
 
 
-def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, k_seq: jax.Array,
-               v_seq: jax.Array, length: jax.Array, page_table_row):
-    """Scatter a whole (padded) prompt's K/V for ONE sequence.
+def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,
+               k_seq: jax.Array, v_seq: jax.Array, length: jax.Array,
+               page_table_row):
+    """Scatter a whole (padded) prompt's K/V for ONE sequence into one
+    layer of the pools.
 
-    ``k_seq``/``v_seq`` [NKV, S, H] with S a multiple of the page size;
-    ``length`` scalar int32 true length; ``page_table_row`` [maxp].
-    Positions >= length (padding) are routed to scratch page 0 so the
-    sequence only dirties the pages it reserved.
+    ``k_seq``/``v_seq`` [NKV, S, H] (head-major, as the dense attention
+    beside it takes them) with S a multiple of the page size; ``layer``
+    scalar int32; ``length`` scalar int32 true length; ``page_table_row``
+    [maxp].  Positions >= length (padding) are routed to scratch page 0 so
+    the sequence only dirties the pages it reserved.
     """
     page = k_pages.shape[2]
     S = k_seq.shape[1]
@@ -106,5 +128,7 @@ def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, k_seq: jax.Array,
         pos = jnp.arange(S)
         pid = jnp.where(pos < length, page_table_row[pos // page], 0)
         slot = pos % page
-        return (k_pages.at[:, pid, slot].set(k_seq.astype(k_pages.dtype)),
-                v_pages.at[:, pid, slot].set(v_seq.astype(v_pages.dtype)))
+        k_seq = _folded(jnp.swapaxes(k_seq, 0, 1), k_pages)
+        v_seq = _folded(jnp.swapaxes(v_seq, 0, 1), v_pages)
+        return (k_pages.at[layer, pid, slot].set(k_seq),
+                v_pages.at[layer, pid, slot].set(v_seq))

@@ -35,6 +35,21 @@ come out as from the caller's tree.  The engine keeps no reference to that
 tree: once the caller drops it, a bf16 engine holds half the bytes
 (``stats()["weight_bytes"]``).
 
+The KV pools are updated in place: both programs carry them through their
+layer scan (``models/``: a step scatters one token a sequence into the whole
+pool and gathers from it) and the loop's two calls donate them, so a call's
+result pools are its argument's buffers and no copy of a pool, whole or a
+layer's, is made or held.  Between a donating dispatch and the loop taking
+the result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single
+exec lane may touch the pools.  Callers outside the loop go through
+``_prefill_program`` / ``_decode_program`` (or the three-result ``_prefill`` /
+``_decode``), which hand the same executables a copy of the pools they are
+given and never consume their arguments.  A call that fails after it was
+given the pools costs the live sequences an error, and the loop makes fresh
+pools (no live sequence is left to own a page).  ``stats()`` says whether
+each program's first loop call did come back in its argument's buffers
+(``kv_pool_in_place``).
+
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
@@ -163,7 +178,9 @@ class InferenceEngine:
             init_fn(jax.random.PRNGKey(rng_seed), mc), mc)
         self._weight_bytes = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self._params))
+        self._new_pools = cache_fn
         self._k_pages, self._v_pages = cache_fn()
+        self._kv_pool_bytes = self._k_pages.nbytes + self._v_pages.nbytes
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
@@ -172,15 +189,17 @@ class InferenceEngine:
         # prefill, [max_batch] decode), so the steady-state loop never
         # re-traces.  The parameters are arguments, not closed over: as
         # constants they would be part of the program and of its
-        # compile-cache key, one copy per entry point.
+        # compile-cache key, one copy per entry point.  Both donate the
+        # pools (module docstring): these two are the loop's to call.
         def _prefill(params, tokens, length, kp, vp, pt):
             return prefill_fn(params, mc, tokens, length, kp, vp, pt)
 
         def _decode(params, token, pos, kp, vp, pt):
             return decode_fn(params, mc, token, pos, kp, vp, pt)
 
-        self._prefill_program = jax.jit(_prefill)
-        self._decode_program = jax.jit(_decode)
+        self._prefill_donating = jax.jit(_prefill, donate_argnums=(3, 4))
+        self._decode_donating = jax.jit(_decode, donate_argnums=(3, 4))
+        self._kv_in_place: Dict[str, bool] = {}
         # What stats() says about where this engine runs: the device that
         # holds the KV pool, and how long each program's first dispatch
         # took to finish (trace + compile or cache load + run).
@@ -273,7 +292,11 @@ class InferenceEngine:
         of expert weights it had to read) and ``moe_load_max`` (the largest
         single-expert load, summed likewise: over ``moe_assignments /
         num_experts``, how uneven the routing was).  ``weight_bytes`` is
-        the size of the parameters as the engine stores them."""
+        the size of the parameters as the engine stores them,
+        ``kv_pool_bytes`` that of the K and V pools, and
+        ``kv_pool_in_place`` says of each program ("prefill", "decode"),
+        once the loop has called it, whether that first call's result pools
+        lay in its arguments' buffers (the donation was used)."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
@@ -281,7 +304,10 @@ class InferenceEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "retired": dict(self._retired), **self._moe,
-                "weight_bytes": self._weight_bytes, "device": self._device,
+                "weight_bytes": self._weight_bytes,
+                "kv_pool_bytes": self._kv_pool_bytes,
+                "kv_pool_in_place": dict(self._kv_in_place),
+                "device": self._device,
                 "first_call_s": dict(self._first_call_s)}
 
     def close(self):
@@ -292,14 +318,44 @@ class InferenceEngine:
 
     # ----------------------------------------------------------- internals
 
-    # (logits, k_pages, v_pages) of either program, for callers outside the
-    # loop; an expert model's fourth result stays behind.
+    # The two programs for callers that run while the loop is idle (a
+    # numerics check before traffic, a test): the loop's own executables,
+    # handed a copy of the pools they are given, so the caller's arrays
+    # (the engine's pools among them) are left alive and as they were.
+
+    @staticmethod
+    def _on_copies(step, params, a, b, kp, vp, pt):
+        import jax.numpy as jnp
+        return step(params, a, b, jnp.copy(kp), jnp.copy(vp), pt)
+
+    def _prefill_program(self, *args):
+        return self._on_copies(self._prefill_donating, *args)
+
+    def _decode_program(self, *args):
+        return self._on_copies(self._decode_donating, *args)
+
+    # (logits, k_pages, v_pages) of either; an expert model's fourth result
+    # stays behind.
 
     def _prefill(self, *args):
         return self._prefill_program(*args)[:3]
 
     def _decode(self, *args):
         return self._decode_program(*args)[:3]
+
+    def _donate_pools(self, program: str, step, a, b, pt):
+        """One call of ``step`` (a donating program) on the engine's pools,
+        on the exec lane: its results, the pools among them for the loop to
+        take.  The first call of each program notes whether they came back
+        in the buffers that went in."""
+        kp, vp = self._k_pages, self._v_pages
+        if program in self._kv_in_place:
+            return step(self._params, a, b, kp, vp, pt)
+        before = [p.unsafe_buffer_pointer() for p in (kp, vp)]
+        out = step(self._params, a, b, kp, vp, pt)
+        self._kv_in_place[program] = before == [
+            p.unsafe_buffer_pointer() for p in out[1:3]]
+        return out
 
     def _ensure_loop(self):
         if self._loop_task is None or self._loop_task.done():
@@ -464,10 +520,9 @@ class InferenceEngine:
                                     prompt_len=len(seq.prompt), padded_len=S,
                                     waited_us=int((t0 - seq.queued) * 1e6),
                                     submit_us=int((t0 - submitted) * 1e6)):
-                            logits, kp, vp, *load = self._prefill_program(
-                                self._params, toks,
-                                np.int32(len(seq.prompt)), self._k_pages,
-                                self._v_pages, seq.row[None])
+                            logits, kp, vp, *load = self._donate_pools(
+                                "prefill", self._prefill_donating, toks,
+                                np.int32(len(seq.prompt)), seq.row[None])
                             tok = int(jnp.argmax(logits[0]))
                             load = [np.asarray(a) for a in load]
                         self._first_call_s.setdefault(
@@ -501,9 +556,9 @@ class InferenceEngine:
                     t0 = time.perf_counter()
                     with region("engine.decode.dispatch", active=active,
                                 submit_us=int((t0 - submitted) * 1e6)):
-                        logits, kp, vp, *load = self._decode_program(
-                            self._params, token, pos, self._k_pages,
-                            self._v_pages, tables)
+                        logits, kp, vp, *load = self._donate_pools(
+                            "decode", self._decode_donating, token, pos,
+                            tables)
                         nxt = jnp.argmax(logits, axis=-1)
                         for a in load:   # on its way beside the tokens
                             a.copy_to_host_async()
@@ -531,6 +586,10 @@ class InferenceEngine:
                     seq.queue.put_nowait(e)
                 while self._waiting:
                     self._waiting.popleft().queue.put_nowait(e)
+                if self._k_pages.is_deleted() or self._v_pages.is_deleted():
+                    # the call that failed had been given the pools; every
+                    # sequence that owned a page of them is retired above
+                    self._k_pages, self._v_pages = self._new_pools()
 
 
 class LLMServer:

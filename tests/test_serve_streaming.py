@@ -275,7 +275,8 @@ def test_llama_paged_decode_matches_dense():
                       remat=False)
     params = llama_init(jax.random.PRNGKey(0), cfg)
     kp, vp = llama_init_paged_cache(cfg, 32, 8)
-    assert kp.shape[1] == cfg.num_kv_heads   # GQA: pools at kv_heads width
+    # GQA: pools at kv_heads width
+    assert kp.shape[-1] == cfg.num_kv_heads * cfg.head_dim
     prompt = [5, 17, 3, 88, 41]
     toks = jnp.array([prompt + [0] * (8 - len(prompt))], jnp.int32)
     pt = jnp.array([[1, 2, 0, 0]], jnp.int32)
