@@ -23,6 +23,21 @@ before the rotation: together OLMoE's block.  Both are written once
 and the paged decode.  The expert model serves; it does not train here:
 ``llama_loss`` refuses it, because the load-balancing loss, ``ep``
 sharding and the all-to-all are not written.
+
+With ``ut_steps > 1`` the whole layer stack is run that many times over the
+same weights (Ouro's looped model): pass ``t`` starts from the last pass's
+output after the model's final norm, and when served keeps a cache of its
+own, so the pools hold ``ut_steps * num_layers`` layers and pass ``t``,
+layer ``l`` reads and writes pool layer ``t * num_layers + l``.  With
+``post_norm`` a sublayer's output is RMS-normed (``ln1_post``, ``ln2_post``)
+before it is added to the residual stream.  Both are written once
+(``_add_sublayer``, ``_passes``) and called from the training trunk, the
+paged prefill and the paged decode; with ``ut_steps == 1`` and no
+``post_norm`` neither adds an operation.  The looped model serves; it does
+not train here: ``llama_loss`` refuses it, because its published objective
+(an entropy-regularised expectation over exit steps) is not written, and
+neither are the exit gate's two leaves: at the published threshold of 1
+the gate never exits early.
 """
 
 from __future__ import annotations
@@ -63,6 +78,8 @@ class LlamaConfig:
     experts_per_token: int = 0       # top-k of the router's softmax
     norm_topk_prob: bool = False     # renormalise the k gates to sum to 1
     qk_norm: bool = False            # RMSNorm q and k over all heads
+    ut_steps: int = 1                # passes over the layer stack
+    post_norm: bool = False          # RMSNorm a sublayer's output too
 
     @property
     def head_dim(self) -> int:
@@ -106,6 +123,8 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
              "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
         if cfg.qk_norm else {}
+    post = {name: {"scale": jnp.ones((L, D), jnp.float32)}
+            for name in ("ln1_post", "ln2_post")} if cfg.post_norm else {}
     return {
         "wte": scale * jax.random.normal(k[0], (V, D), jnp.float32),
         "layers": {
@@ -121,6 +140,7 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             },
             "ln2": {"scale": jnp.ones((L, D), jnp.float32)},
             "mlp": mlp,
+            **post,
         },
         "ln_f": {"scale": jnp.ones((D,), jnp.float32)},
         "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32),
@@ -138,6 +158,8 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         mlp["router"] = ("layers", "embed", None)
     norms = {"q_norm": ("layers", "heads", "kv"),
              "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
+    post = {name: {"scale": ("layers", "norm")}
+            for name in ("ln1_post", "ln2_post")} if cfg.post_norm else {}
     return {
         "wte": (None, "embed"),
         "layers": {
@@ -150,6 +172,7 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             },
             "ln2": {"scale": ("layers", "norm")},
             "mlp": mlp,
+            **post,
         },
         "ln_f": {"scale": ("norm",)},
         "lm_head": ("embed", None),
@@ -211,6 +234,51 @@ def _qk(cfg: LlamaConfig, p, q, k, cos, sin):
         q = norm(q, p["attn"]["q_norm"])
         k = norm(k, p["attn"]["k_norm"])
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+
+
+def _add_sublayer(cfg: LlamaConfig, p, post: str, x, y):
+    """The residual stream ``x`` plus a sublayer's output ``y``: as it is,
+    or with ``cfg.post_norm`` RMS-normed first by the layer's ``post``
+    scale (``ln1_post`` after attention, ``ln2_post`` after the
+    feed-forward): the sandwich norm of a looped model, which keeps a
+    stream that runs the stack several times from growing."""
+    if cfg.post_norm:
+        with jax.named_scope("loop_norm"):
+            y = _rms_norm(y, p[post]["scale"], cfg.rms_eps)
+    return x + y
+
+
+def _passes(cfg: LlamaConfig, params, layers_pass, carry):
+    """``cfg.ut_steps`` passes over all layers, each followed by the
+    model's final norm, which is what the next pass starts from.
+    ``layers_pass(carry, t) -> (carry, ys)`` scans the layers once;
+    ``carry`` is a tuple that starts with the stream ``x`` (a served
+    model's pools follow it and are carried through both loops, so that
+    they are still updated in place); ``t`` is the pass, None where there
+    is one pass only and nothing is wrapped.  Returns the last carry and
+    every pass's ``ys`` along one leading [ut_steps * num_layers] axis."""
+    def final_norm(x):
+        return _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
+
+    if cfg.ut_steps == 1:
+        (x, *rest), ys = layers_pass(carry, None)
+        return (final_norm(x), *rest), ys
+
+    def one_pass(carry, t):
+        (x, *rest), ys = layers_pass(carry, t)
+        with jax.named_scope("loop_norm"):
+            x = final_norm(x)
+        return (x, *rest), ys
+
+    carry, ys = jax.lax.scan(one_pass, carry, jnp.arange(cfg.ut_steps))
+    return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+
+
+def _pool_layers(cfg: LlamaConfig, t):
+    """The pool layers that pass ``t`` (None: the only one) reads and
+    writes, by layer: ``t * num_layers + l``."""
+    layers = jnp.arange(cfg.num_layers)
+    return layers if t is None else t * cfg.num_layers + layers
 
 
 def _scanned_layers(cfg: LlamaConfig, params):
@@ -275,11 +343,13 @@ def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
         k = lc(k, ("batch", "heads", "seq", "kv"))
         v = lc(v, ("batch", "heads", "seq", "kv"))
         o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
-    x = x + jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
+    x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
+        "bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt)))
     x = lc(x, ("batch", "seq", "embed"))
 
     h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-    x = x + _ffn(cfg, p, h, lc=lc, experts=experts)[0]
+    x = _add_sublayer(cfg, p, "ln2_post", x,
+                      _ffn(cfg, p, h, lc=lc, experts=experts)[0])
     return lc(x, ("batch", "seq", "embed"))
 
 
@@ -319,8 +389,9 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
         }.get(cfg.remat_policy)
         block = jax.checkpoint(block, policy=policy)
 
-    x, _ = jax.lax.scan(lambda c, lp: (block(c, lp), None), x, layers)
-    return _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
+    (x,), _ = _passes(cfg, params, lambda carry, t: jax.lax.scan(
+        lambda c, lp: ((block(c[0], lp),), None), carry, layers), (x,))
+    return x
 
 
 def llama_forward(params: Dict[str, Any], tokens: jax.Array,
@@ -347,10 +418,11 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                            page_size: int, dtype: Any = None):
     """Zeroed K/V page pools of all layers, [L, P, page, NKV*H]
-    (token-major: see ops.paged_attention).  Page 0 is the scratch sink
-    for padded/inactive writes — allocators must never hand it out."""
+    (token-major: see ops.paged_attention), ``L`` a layer for every pass
+    of a looped model: ``ut_steps * num_layers``.  Page 0 is the scratch
+    sink for padded/inactive writes — allocators must never hand it out."""
     dt = dtype or cfg.dtype
-    shape = (cfg.num_layers, num_pages, page_size,
+    shape = (cfg.ut_steps * cfg.num_layers, num_pages, page_size,
              cfg.num_kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
@@ -415,14 +487,16 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
         kp, vp = prefill_kv(kp, vp, layer, k[0], v[0], length,
                             page_table[0])
         o = _dense_causal_attention_gqa(q, k, v, rep)
-        x = x + jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
+        x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
+            "bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt)))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
         y, load = _ffn(cfg, p, h, live, experts=experts)
-        return (x + y, kp, vp), load
+        return (_add_sublayer(cfg, p, "ln2_post", x, y), kp, vp), load
 
-    (x, k_pages, v_pages), load = jax.lax.scan(
-        body, (x, k_pages, v_pages), (layers, jnp.arange(cfg.num_layers)))
-    x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
+    (x, k_pages, v_pages), load = _passes(
+        cfg, params, lambda carry, t: jax.lax.scan(
+            body, carry, (layers, _pool_layers(cfg, t))),
+        (x, k_pages, v_pages))
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,dv->v", last,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
@@ -459,14 +533,16 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
         q, k_new = _qk(cfg, p, q, k_new, cos, sin)
         kp, vp = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
         o = paged_attention(q, kp, vp, layer, pos + 1, page_table)
-        x = x + jnp.einsum("bnh,nhd->bd", o, p["attn"]["wo"].astype(dt))
+        x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
+            "bnh,nhd->bd", o, p["attn"]["wo"].astype(dt)))
         h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
         y, load = _ffn(cfg, p, h, live, experts=experts)
-        return (x + y, kp, vp), load
+        return (_add_sublayer(cfg, p, "ln2_post", x, y), kp, vp), load
 
-    (x, k_pages, v_pages), load = jax.lax.scan(
-        body, (x, k_pages, v_pages), (layers, jnp.arange(cfg.num_layers)))
-    x = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
+    (x, k_pages, v_pages), load = _passes(
+        cfg, params, lambda carry, t: jax.lax.scan(
+            body, carry, (layers, _pool_layers(cfg, t))),
+        (x, k_pages, v_pages))
     logits = jnp.einsum("bd,dv->bv", x,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
@@ -479,7 +555,14 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     ``token_loglikes`` core (and the blocked-CE head via ``cfg.ce_block``)
     with GPT.  Refuses an expert model: without the load-balancing loss
     (and ``ep`` sharding and the all-to-all) it would train a router that
-    collapses; those belong with the four-chip training path."""
+    collapses; those belong with the four-chip training path.  Refuses a
+    looped model too: next-token CE on the last pass alone is not the
+    objective such a model is published with."""
+    if cfg.ut_steps > 1:
+        raise NotImplementedError(
+            "models/llama.py serves its looped model (ut_steps > 1) but "
+            "does not train it: the expectation over exit steps with its "
+            "entropy term, and the exit gate it trains, are not written")
     if cfg.num_experts:
         raise NotImplementedError(
             "models/llama.py serves its expert model but does not train "

@@ -14,7 +14,10 @@ virtual memory for attention.  The layouts:
     lengths          [B] int32              valid positions per sequence
     page_table       [B, maxp] int32        page ids per sequence
 
-The pools of ALL layers are one array each, and the three functions take
+``L`` counts pool layers: a model's layers or, for a looped model that runs
+its stack ``ut_steps`` times with a cache for every pass, ``ut_steps x
+layers``, pass ``t``'s layer ``l`` at ``t * layers + l``
+(``models/llama.py``).  The pools of ALL layers are one array each, and the three functions take
 the layer's index: the models carry the pools through their layer scan and
 a step scatters one token a sequence into the whole pool (``append_kv``; a
 prompt's, ``prefill_kv``) and gathers ``(layer, page_table)`` from it.

@@ -41,14 +41,30 @@ pool and gathers from it) and the loop's two calls donate them, so a call's
 result pools are its argument's buffers and no copy of a pool, whole or a
 layer's, is made or held.  Between a donating dispatch and the loop taking
 the result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single
-exec lane may touch the pools.  Callers outside the loop go through
-``_prefill_program`` / ``_decode_program`` (or the three-result ``_prefill`` /
-``_decode``), which hand the same executables a copy of the pools they are
-given and never consume their arguments.  A call that fails after it was
+exec lane may touch the pools.  Callers outside the loop, while it is idle,
+have two kinds of view of the same executables.  ``_prefill_program`` /
+``_decode_program`` hand them a copy of the pools they are given and never
+consume their arguments (a test, a tool that wants both).  The three-result
+``_prefill`` / ``_decode`` copy nothing, because a pool may be the largest
+thing on the chip (a looped model's is ``ut_steps`` times a plain one's) and
+then no second one fits: they CONSUME the pools they are given, and where
+those are the engine's own the engine keeps the result as its pools, so the
+names the caller gets back alias ``_k_pages`` / ``_v_pages`` and can go
+straight into the next such call.  A readiness check made of them holds one
+pool.  What it leaves in the pages it used is never read: a sequence writes
+a position before any step reads it.  A call that fails after it was
 given the pools costs the live sequences an error, and the loop makes fresh
 pools (no live sequence is left to own a page).  ``stats()`` says whether
 each program's first loop call did come back in its argument's buffers
 (``kv_pool_in_place``).
+
+``paged_attention`` gathers every page a slot may use, whatever the live
+length, once per pool layer.  ``stats()`` counts both sides of that:
+``kv_live_token_steps`` (positions the live sequences held, summed over
+decode steps) against ``kv_gathered_token_steps`` (``max_batch x maxp x
+page_size`` a step), with ``kv_bytes_per_token`` and ``kv_pool_layers`` to
+turn either into bytes; each ``rt:engine.decode.dispatch`` carries its
+step's two numbers as ``live_tokens`` and ``gathered_tokens``.
 
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
@@ -224,6 +240,8 @@ class InferenceEngine:
                          "error": 0}
         self._moe = {"moe_assignments": 0, "moe_experts_hit": 0,
                      "moe_load_max": 0}
+        self._kv_live_token_steps = 0
+        self._kv_gathered_token_steps = 0
         # Single lane for XLA dispatches: the device serializes anyway,
         # and one lane keeps (k_pages, v_pages) updates ordered.
         self._exec = concurrent.futures.ThreadPoolExecutor(
@@ -293,10 +311,17 @@ class InferenceEngine:
         single-expert load, summed likewise: over ``moe_assignments /
         num_experts``, how uneven the routing was).  ``weight_bytes`` is
         the size of the parameters as the engine stores them,
-        ``kv_pool_bytes`` that of the K and V pools, and
-        ``kv_pool_in_place`` says of each program ("prefill", "decode"),
-        once the loop has called it, whether that first call's result pools
-        lay in its arguments' buffers (the donation was used)."""
+        ``kv_pool_bytes`` that of the K and V pools, ``kv_pool_layers``
+        their leading dimension (a layer for every pass of a looped model)
+        and ``kv_bytes_per_token`` what one cached position takes in all of
+        them, and ``kv_pool_in_place`` says of each program ("prefill",
+        "decode"), once the loop has called it, whether that first call's
+        result pools lay in its arguments' buffers (the donation was used).
+        ``kv_live_token_steps`` sums over decode steps the positions the
+        live sequences held (``pos + 1`` each), ``kv_gathered_token_steps``
+        the positions the step's paged read gathered per pool layer
+        (``max_batch x maxp x page_size``): their ratio is the share of the
+        gather that was of use."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
@@ -306,6 +331,11 @@ class InferenceEngine:
                 "retired": dict(self._retired), **self._moe,
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
+                "kv_pool_layers": self._k_pages.shape[0],
+                "kv_bytes_per_token": self._kv_pool_bytes // (
+                    self.config.num_pages * self.config.page_size),
+                "kv_live_token_steps": self._kv_live_token_steps,
+                "kv_gathered_token_steps": self._kv_gathered_token_steps,
                 "kv_pool_in_place": dict(self._kv_in_place),
                 "device": self._device,
                 "first_call_s": dict(self._first_call_s)}
@@ -319,9 +349,9 @@ class InferenceEngine:
     # ----------------------------------------------------------- internals
 
     # The two programs for callers that run while the loop is idle (a
-    # numerics check before traffic, a test): the loop's own executables,
-    # handed a copy of the pools they are given, so the caller's arrays
-    # (the engine's pools among them) are left alive and as they were.
+    # test, a tool): the loop's own executables, handed a copy of the
+    # pools they are given, so the caller's arrays (the engine's pools
+    # among them) are left alive and as they were.
 
     @staticmethod
     def _on_copies(step, params, a, b, kp, vp, pt):
@@ -334,14 +364,29 @@ class InferenceEngine:
     def _decode_program(self, *args):
         return self._on_copies(self._decode_donating, *args)
 
-    # (logits, k_pages, v_pages) of either; an expert model's fourth result
+    # (logits, k_pages, v_pages) of either, with no copy (module
+    # docstring): the pools that go in are consumed, and the engine's own
+    # are replaced by what comes out.  An expert model's fourth result
     # stays behind.
 
+    def _consuming(self, step, params, a, b, kp, vp, pt):
+        own = kp is self._k_pages and vp is self._v_pages
+        try:
+            logits, kp, vp = step(params, a, b, kp, vp, pt)[:3]
+        except Exception:
+            if own and (self._k_pages.is_deleted()
+                        or self._v_pages.is_deleted()):
+                self._k_pages, self._v_pages = self._new_pools()
+            raise
+        if own:
+            self._k_pages, self._v_pages = kp, vp
+        return logits, kp, vp
+
     def _prefill(self, *args):
-        return self._prefill_program(*args)[:3]
+        return self._consuming(self._prefill_donating, *args)
 
     def _decode(self, *args):
-        return self._decode_program(*args)[:3]
+        return self._consuming(self._decode_donating, *args)
 
     def _donate_pools(self, program: str, step, a, b, pt):
         """One call of ``step`` (a donating program) on the engine's pools,
@@ -550,11 +595,16 @@ class InferenceEngine:
                         batch = self._decode_inputs()
                 token, pos, tables = batch
                 active = len(self._active)
+                # what the step's paged read is for, and what it gathers
+                live_tokens = int(pos.sum()) + active
+                gathered_tokens = tables.size * cfg.page_size
                 submitted = time.perf_counter()
 
                 def _step():
                     t0 = time.perf_counter()
                     with region("engine.decode.dispatch", active=active,
+                                live_tokens=live_tokens,
+                                gathered_tokens=gathered_tokens,
                                 submit_us=int((t0 - submitted) * 1e6)):
                         logits, kp, vp, *load = self._donate_pools(
                             "decode", self._decode_donating, token, pos,
@@ -572,6 +622,8 @@ class InferenceEngine:
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
                 self._slot_steps += active
+                self._kv_live_token_steps += live_tokens
+                self._kv_gathered_token_steps += gathered_tokens
                 self._count_moe("decode", load)
                 for seq in self._active.values():
                     seq.pos += 1

@@ -8,16 +8,20 @@ head against its own KV head: kept below as the reference); the views for
 callers outside the loop leave their arguments alive; ``stats()`` says the
 loop's calls came back in their arguments' buffers; and a call that fails
 after it was given the pools costs the live requests an error and nothing
-more.
+more.  The three-result views a readiness check is made of copy nothing and
+never have two pools alive; and the ``kv_*`` counters and the decode
+dispatch's ``live_tokens`` / ``gathered_tokens`` are a count of the steps.
 """
 
 import asyncio
+import gc
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_trace
 from ray_tpu.models import gpt, llama
 from test_engine_stored_weights import (BATCH, FAMILIES, build,
                                         program_args)
@@ -277,5 +281,149 @@ def test_a_failed_call_costs_its_requests_and_leaves_fresh_pools(
 
     try:
         asyncio.run(scenario())
+    finally:
+        engine.close()
+
+
+def pools_alive(engine):
+    """Live arrays of the engine's pool shape: two is one K and one V."""
+    kp = engine._k_pages
+    gc.collect()                # engines of earlier tests, in cycles
+    return sum(a.shape == kp.shape and a.dtype == kp.dtype
+               for a in jax.live_arrays())
+
+
+@pytest.mark.parametrize("family", ["llama-dense", "llama-experts"])
+def test_a_check_made_of_the_consuming_views_holds_one_pool(family):
+    """``benchmark/replica.py``'s ``check_numerics``: ``_prefill`` on the
+    engine's pools, ``_decode`` on what it returned, eight times, and all
+    of it again for a second sequence.  The engine's pools are replaced by
+    each result (the caller's names alias them), nothing is copied, and the
+    engine serves afterwards as one that was never checked."""
+    _, engine = build(family)
+    _, fresh = build(family)
+    try:
+        want = serve(fresh, [3, 1, 4, 1, 5])
+        fresh.close()
+        del fresh
+        assert pools_alive(engine) == 2
+        for _ in range(2):
+            params, tokens, length, _, _, row = program_args(
+                engine, "prefill", engine._params)
+            assert pools_alive(engine) == 2      # program_args' copy is gone
+            before = engine._k_pages
+            logits, kp, vp = engine._prefill(
+                params, tokens, length, engine._k_pages, engine._v_pages,
+                row)
+            assert before.is_deleted() and pools_alive(engine) == 2
+            assert kp is engine._k_pages and vp is engine._v_pages
+            table = np.zeros((BATCH, engine._maxp), np.int32)
+            table[0] = row[0]
+            token, pos = (np.zeros((BATCH,), np.int32) for _ in range(2))
+            for step in range(STEPS):
+                token[0], pos[0] = int(np.argmax(logits[0])), 11 + step
+                before = kp
+                logits, kp, vp = engine._decode(params, token, pos, kp, vp,
+                                                table)
+                assert before.is_deleted() and pools_alive(engine) == 2
+                assert kp is engine._k_pages and vp is engine._v_pages
+                assert len((logits, kp, vp)) == 3
+            del kp, vp, before
+        # the pages the check used hold its keys; no sequence reads them
+        assert np.asarray(engine._k_pages.astype(jnp.float32)).any()
+        assert serve(engine, [3, 1, 4, 1, 5]) == want
+        assert engine.stats()["kv_pool_in_place"] == {"prefill": True,
+                                                      "decode": True}
+        # the copying views still leave what they are given alive
+        args = program_args(engine, "prefill", engine._params)
+        engine._prefill_program(*args)
+        assert not engine._k_pages.is_deleted()
+        # and on pools that are not the engine's the consuming views consume
+        # them and leave the engine's alone
+        decode = program_args(engine, "decode", engine._params)
+        mine = engine._k_pages
+        engine._decode(*decode)
+        assert decode[3].is_deleted() and decode[4].is_deleted()
+        assert engine._k_pages is mine and not mine.is_deleted()
+    finally:
+        engine.close()
+
+
+def test_a_consuming_view_that_fails_leaves_the_engine_fresh_pools(
+        monkeypatch):
+    _, engine = build("llama-dense")
+    try:
+        real = engine._prefill_donating
+
+        def failing(*args):
+            real(*args)
+            raise RuntimeError("the device fell over")
+        monkeypatch.setattr(engine, "_prefill_donating", failing)
+        args = program_args(engine, "prefill", engine._params)
+        with pytest.raises(RuntimeError, match="fell over"):
+            engine._prefill(*args)
+        monkeypatch.undo()
+        assert not engine._k_pages.is_deleted()
+        assert not np.asarray(engine._k_pages.astype(jnp.float32)).any()
+        assert len(serve(engine, [3, 1, 4, 1, 5])) == 4
+    finally:
+        engine.close()
+
+
+def test_the_kv_counters_and_the_dispatch_attributes_count_the_steps():
+    """``engine_trace``'s run: a warm-up sequence, then two sequences under
+    the profiler.  Every decode step's paged read gathers every page a slot
+    may use (``max_batch x maxp x page``); of use are the positions the
+    live sequences hold once the step's token is written, ``pos + 1``."""
+    from jax.profiler import ProfileData
+    run = engine_trace.run()
+    stats = run["stats"]
+    page, maxp = 8, (engine_trace.MAX_PROMPT_LEN + 8) // 8
+    gathered = engine_trace.MAX_BATCH * maxp * page
+
+    def live(prompts, new):
+        """Per step, the positions the live sequences hold: a sequence's
+        first token comes from its prefill, each later one from a step."""
+        steps = np.zeros((max(new) - 1,), np.int64)
+        for prompt, n in zip(prompts, new):
+            steps[:n - 1] += len(prompt) + 1 + np.arange(n - 1)
+        return steps
+
+    warm = live([engine_trace.WARM_PROMPT], [engine_trace.WARM_NEW])
+    traced = live(engine_trace.PROMPTS, engine_trace.NEW_TOKENS)
+    assert stats["steps"] == len(warm) + len(traced)
+    assert stats["kv_live_token_steps"] == warm.sum() + traced.sum()
+    assert stats["kv_gathered_token_steps"] == stats["steps"] * gathered
+    # GPT-2 tiny: 2 layers of 4 heads of 8, f32
+    assert stats["kv_pool_layers"] == 2
+    assert stats["kv_bytes_per_token"] == 2 * 2 * 32 * 4
+    assert stats["kv_bytes_per_token"] * 32 * page == stats["kv_pool_bytes"]
+    plane, = [p for p in ProfileData.from_file(run["path"]).planes
+              if p.name == "/host:CPU"]
+    dispatches = sorted(
+        (e.start_ns, dict(e.stats)) for line in plane.lines
+        for e in line.events if e.name == "rt:engine.decode.dispatch")
+    assert [d["live_tokens"] for _, d in dispatches] == list(traced)
+    assert [d["gathered_tokens"] for _, d in dispatches] == \
+        [gathered] * len(traced)
+
+
+def test_a_looped_models_pool_counts_a_layer_for_every_pass():
+    import dataclasses
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from test_engine_stored_weights import LLAMA, NEW, PAGE, PROMPT
+    cfg = dataclasses.replace(LLAMA, ut_steps=3, post_norm=True)
+    engine = InferenceEngine(EngineConfig(
+        model="llama", model_config=cfg, page_size=PAGE,
+        num_pages=BATCH * (PROMPT + NEW) // PAGE + 1, max_batch=BATCH,
+        max_prompt_len=PROMPT, max_new_tokens=NEW))
+    try:
+        stats = engine.stats()
+        assert stats["kv_pool_layers"] == 3 * cfg.num_layers
+        assert stats["kv_bytes_per_token"] == \
+            3 * cfg.num_layers * 2 * cfg.num_kv_heads * cfg.head_dim * 2
+        assert len(serve(engine, [3, 1, 4, 1, 5])) == 4
+        assert engine.stats()["kv_pool_in_place"] == {"prefill": True,
+                                                      "decode": True}
     finally:
         engine.close()
