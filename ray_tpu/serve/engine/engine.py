@@ -18,11 +18,20 @@ transport half — serve's ``handle_stream`` + ``num_returns="streaming"``
 Admission reserves the worst case ``ceil((prompt + max_new) / page)``
 pages up front (see kv_cache.py), so a sequence admitted is a sequence
 that finishes: the loop never preempts and never OOMs mid-decode.
-Prefill runs one sequence per dispatch (B=1, fixed padded shape);
-decode runs the whole batch (fixed shape [max_batch]) with inactive
-slots parked on scratch page 0.  Both are jitted once; dispatches run on
-a single-thread executor so the actor's event loop keeps serving
-admissions and cancellations while XLA computes.
+Prefill runs one sequence per dispatch (B=1), padded to its prompt's
+**rung**: the least of a short ladder of lengths that holds the prompt
+(``prefill_rungs``: 128, 256, 512, ... below ``max_prompt_len``, each rounded
+up to whole pages, and ``max_prompt_len`` itself on top).  The ladder is
+derived from ``max_prompt_len`` and ``page_size`` alone, nothing configures
+it, and a ``max_prompt_len`` of 128 or less has the one rung.  Padding lies
+after the prompt under a causal mask and its K/V go to scratch page 0, so a
+shorter rung gives the logits and pages of a longer one.  Every rung's
+program is compiled while the engine is constructed, each on a thread of its
+own, and the loop admits nobody before all of them are there: no request
+meets a compile.  Decode runs the whole batch (fixed shape [max_batch]) with
+inactive slots parked on scratch page 0, jitted once.  Dispatches run on a
+single-thread executor so the actor's event loop keeps serving admissions
+and cancellations while XLA computes.
 
 The parameters are stored once in the dtype the two programs read them in
 (``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
@@ -89,12 +98,14 @@ this runs.
 from __future__ import annotations
 
 import asyncio
+import bisect
 import collections
 import concurrent.futures
 import dataclasses
 import logging
 import time
-from typing import Any, AsyncIterator, Dict, List, Optional, Sequence
+from typing import (Any, AsyncIterator, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -114,10 +125,33 @@ class EngineConfig:
     page_size: int = 8
     num_pages: int = 128               # pool size; page 0 is scratch
     max_batch: int = 8                 # decode slots per step
-    max_prompt_len: int = 64           # multiple of page_size
+    max_prompt_len: int = 64           # multiple of page_size; the top
+    #                                    rung of the prefill ladder, which
+    #                                    is derived from it (prefill_rungs)
     max_new_tokens: int = 32           # per-request cap
     eos_token: Optional[int] = None
     dtype: Any = None                  # KV pool dtype (default: model's)
+
+
+def prefill_rungs(max_prompt_len: int, page_size: int) -> Tuple[int, ...]:
+    """The padded lengths a prefill is compiled for, rising: 128 x 2^k, each
+    rounded up to whole pages, as far as they stay below ``max_prompt_len``,
+    and ``max_prompt_len`` itself.  A prompt runs at the least that holds it
+    (``rung_for``), so a short prompt does not pay for ``max_prompt_len``
+    positions; doubling keeps the programs few and the padding under half."""
+    rungs, width = [], 128
+    while True:
+        rung = -(-width // page_size) * page_size
+        if rung >= max_prompt_len:
+            return (*rungs, max_prompt_len)
+        if not rungs or rung > rungs[-1]:
+            rungs.append(rung)
+        width *= 2
+
+
+def rung_for(rungs: Sequence[int], prompt_len: int) -> int:
+    """The least rung that holds ``prompt_len`` positions."""
+    return rungs[bisect.bisect_left(rungs, prompt_len)]
 
 
 class _Sequence:
@@ -201,12 +235,12 @@ class InferenceEngine:
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
 
-        # One compile per entry point, shapes fixed ([1, max_prompt_len]
-        # prefill, [max_batch] decode), so the steady-state loop never
-        # re-traces.  The parameters are arguments, not closed over: as
-        # constants they would be part of the program and of its
-        # compile-cache key, one copy per entry point.  Both donate the
-        # pools (module docstring): these two are the loop's to call.
+        # Shapes fixed ([max_batch] decode, one compile; [1, rung] prefill,
+        # one compile a rung of the ladder derived from max_prompt_len),
+        # so the steady-state loop never re-traces.  The parameters are
+        # arguments, not closed over: as constants they would be part of the
+        # program and of its compile-cache key, one copy per entry point.
+        # Both donate the pools (module docstring).
         def _prefill(params, tokens, length, kp, vp, pt):
             return prefill_fn(params, mc, tokens, length, kp, vp, pt)
 
@@ -217,12 +251,29 @@ class InferenceEngine:
         self._decode_donating = jax.jit(_decode, donate_argnums=(3, 4))
         self._kv_in_place: Dict[str, bool] = {}
         # What stats() says about where this engine runs: the device that
-        # holds the KV pool, and how long each program's first dispatch
-        # took to finish (trace + compile or cache load + run).
+        # holds the KV pool, and how long each program took to be there
+        # (a prefill rung's trace + compile or cache load; decode's first
+        # dispatch, its run included).
         dev = next(iter(self._k_pages.devices()))
         self._device = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": jax.device_count()}
         self._first_call_s: Dict[str, float] = {}
+        # The loop's prefill programs: ``_prefill_donating`` compiled for
+        # every rung, now, a thread a rung (the compiles are the compiler's
+        # time, not the interpreter's), so whatever the caller does between
+        # construction and its first request hides them.  The views keep
+        # the jitted function, which takes any [1, S] and any tree.
+        self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            (self._params, self._k_pages, self._v_pages))
+        pool = concurrent.futures.ThreadPoolExecutor(
+            len(self._rungs), thread_name_prefix="rt-engine-compile")
+        self._rung_programs = {
+            rung: pool.submit(self._compile_rung, rung, *shapes)
+            for rung in self._rungs}
+        pool.shutdown(wait=False)    # the threads end with their compiles
+        self._prefill_shapes = dict.fromkeys(self._rungs, 0)
 
         self._waiting: collections.deque = collections.deque()
         self._active: Dict[int, _Sequence] = {}   # slot -> sequence
@@ -302,7 +353,8 @@ class InferenceEngine:
         ``slot_steps / (steps * max_batch)``), ``admitted`` sequences and
         the ``queue_wait_s`` they spent between ``generate()`` and their
         prefill's dispatch, ``prefill_tokens`` of prompt against the
-        ``prefill_padded_tokens`` the padded program ran, ``retired``
+        ``prefill_padded_tokens`` the padded programs ran (a prefill adds
+        its rung) and ``prefill_shapes``, the prefills by rung, ``retired``
         sequences by reason, and of a model with experts the
         ``moe_assignments`` of real tokens (token x layer x k), the
         ``moe_experts_hit`` (distinct experts a step touched, summed over
@@ -321,13 +373,17 @@ class InferenceEngine:
         live sequences held (``pos + 1`` each), ``kv_gathered_token_steps``
         the positions the step's paged read gathered per pool layer
         (``max_batch x maxp x page_size``): their ratio is the share of the
-        gather that was of use."""
+        gather that was of use.  ``first_call_s`` says how long each program
+        took to be there: ``prefill@<rung>`` that rung's trace and compile
+        (or load from the compile cache) at construction, beside the other
+        rungs', ``decode`` its first dispatch."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
                 "queue_wait_s": self._queue_wait_s,
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
+                "prefill_shapes": dict(self._prefill_shapes),
                 "retired": dict(self._retired), **self._moe,
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
@@ -387,6 +443,19 @@ class InferenceEngine:
 
     def _decode(self, *args):
         return self._consuming(self._decode_donating, *args)
+
+    def _compile_rung(self, rung: int, params, kp, vp):
+        """``_prefill_donating`` compiled for [1, ``rung``] tokens from the
+        shapes of the engine's tree and pools; on a thread of its own."""
+        import jax
+        import jax.numpy as jnp
+        t0 = time.perf_counter()
+        program = self._prefill_donating.lower(
+            params, jax.ShapeDtypeStruct((1, rung), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32), kp, vp,
+            jax.ShapeDtypeStruct((1, self._maxp), jnp.int32)).compile()
+        self._first_call_s[f"prefill@{rung}"] = time.perf_counter() - t0
+        return program
 
     def _donate_pools(self, program: str, step, a, b, pt):
         """One call of ``step`` (a donating program) on the engine's pools,
@@ -527,7 +596,12 @@ class InferenceEngine:
         import jax.numpy as jnp
         loop = asyncio.get_running_loop()
         cfg = self.config
-        S = cfg.max_prompt_len
+        # No request meets a compile: every rung's program is there before
+        # the first admission.  A rung that failed to compile raises where
+        # a prompt needs it, to the sequences of that pass.
+        await asyncio.gather(*map(asyncio.wrap_future,
+                                  self._rung_programs.values()),
+                             return_exceptions=True)
         while True:
             try:
                 with region("engine.schedule", active=len(self._active),
@@ -550,28 +624,31 @@ class InferenceEngine:
                         await self._wake.wait()
                     continue
 
-                # Prefill new admissions one at a time (B=1, one shape).
+                # Prefill new admissions one at a time (B=1), each padded
+                # to its prompt's rung.
                 for seq in fresh:
+                    S = rung_for(self._rungs, len(seq.prompt))
+                    program = self._rung_programs[S].result()
                     toks = np.zeros((1, S), np.int32)
                     toks[0, : len(seq.prompt)] = seq.prompt
                     submitted = time.perf_counter()
                     self._queue_wait_s += submitted - seq.queued
                     self._prefill_tokens += len(seq.prompt)
                     self._prefill_padded_tokens += S
+                    self._prefill_shapes[S] += 1
 
-                    def _run(seq=seq, toks=toks, submitted=submitted):
+                    def _run(seq=seq, S=S, program=program, toks=toks,
+                             submitted=submitted):
                         t0 = time.perf_counter()
                         with region("engine.prefill",
                                     prompt_len=len(seq.prompt), padded_len=S,
                                     waited_us=int((t0 - seq.queued) * 1e6),
                                     submit_us=int((t0 - submitted) * 1e6)):
                             logits, kp, vp, *load = self._donate_pools(
-                                "prefill", self._prefill_donating, toks,
+                                "prefill", program, toks,
                                 np.int32(len(seq.prompt)), seq.row[None])
                             tok = int(jnp.argmax(logits[0]))
                             load = [np.asarray(a) for a in load]
-                        self._first_call_s.setdefault(
-                            "prefill", time.perf_counter() - t0)
                         return tok, kp, vp, load, time.perf_counter()
                     tok, self._k_pages, self._v_pages, load, returned = \
                         await loop.run_in_executor(self._exec, _run)

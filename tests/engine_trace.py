@@ -12,7 +12,7 @@ import tempfile
 PROMPTS = ([5, 17, 3, 88, 41], [7, 8, 9])
 NEW_TOKENS = (6, 4)
 WARM_PROMPT, WARM_NEW = [1, 2, 3], 2
-MAX_PROMPT_LEN, MAX_BATCH = 16, 4
+MAX_PROMPT_LEN, MAX_BATCH, PAGE = 16, 4, 8
 
 
 @functools.lru_cache(maxsize=None)
@@ -28,7 +28,7 @@ def run() -> dict:
     model = GPTConfig(vocab_size=97, max_seq_len=32, num_layers=2,
                       num_heads=4, embed_dim=32, dtype=jnp.float32,
                       attention="dense", remat=False)
-    config = EngineConfig(model="gpt", model_config=model, page_size=8,
+    config = EngineConfig(model="gpt", model_config=model, page_size=PAGE,
                           num_pages=32, max_batch=MAX_BATCH,
                           max_prompt_len=MAX_PROMPT_LEN, max_new_tokens=8)
 

@@ -14,6 +14,7 @@ dispatch's ``live_tokens`` / ``gathered_tokens`` are a count of the steps.
 """
 
 import asyncio
+import concurrent.futures
 import gc
 
 import jax
@@ -255,7 +256,9 @@ def test_a_failed_call_costs_its_requests_and_leaves_fresh_pools(
         program, monkeypatch):
     _, engine = build("llama-dense")
     prompt, calls = [3, 1, 4, 1, 5], []
-    real = getattr(engine, f"_{program}_donating")
+    rung, = engine._rungs            # the loop's prefill: its rung's program
+    real = engine._rung_programs[rung].result() if program == "prefill" \
+        else engine._decode_donating
 
     def failing(*args):
         calls.append(1)
@@ -267,7 +270,12 @@ def test_a_failed_call_costs_its_requests_and_leaves_fresh_pools(
 
     async def scenario():            # one event loop: the engine lives on it
         want = await ask()
-        monkeypatch.setattr(engine, f"_{program}_donating", failing)
+        if program == "prefill":
+            fails = concurrent.futures.Future()
+            fails.set_result(failing)
+            monkeypatch.setitem(engine._rung_programs, rung, fails)
+        else:
+            monkeypatch.setattr(engine, "_decode_donating", failing)
         with pytest.raises(RuntimeError, match="fell over"):
             await ask()
         monkeypatch.undo()

@@ -276,11 +276,13 @@ def test_paged_engine_program_compiles(topo, program):
 
 
 def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
-                          max_batch=MAX_BATCH, gather_is_the_temporaries=True):
+                          max_batch=MAX_BATCH, gather_is_the_temporaries=True,
+                          rung=None):
     """The engine's prefill or decode for ``cfg``, compiled as the engine
     compiles it (pools donated) from the shapes of the tree the engine
     stores (``llama_serving_params``) and of its pool, and held to
-    ``_pools_in_place``: (those shapes, the executable, its text)."""
+    ``_pools_in_place``: (those shapes, the executable, its text).  The
+    prefill is that of the top rung, ``prompt``, or of ``rung``."""
     from ray_tpu.models.llama import (llama_decode_step, llama_init,
                                       llama_init_paged_cache, llama_prefill,
                                       llama_serving_params)
@@ -297,7 +299,8 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
     if program == "prefill":
         compiled, text = _compile(
             lambda p, *a: llama_prefill(p, cfg, *a), params,
-            arg((1, prompt)), arg(()), kp, vp, arg((1, maxp)), donate=POOLS)
+            arg((1, rung or prompt)), arg(()), kp, vp, arg((1, maxp)),
+            donate=POOLS)
     else:
         compiled, text = _compile(
             lambda p, *a: llama_decode_step(p, cfg, *a), params,
@@ -323,14 +326,18 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
 MISTRAL_BUDGET = int(9.5 * 1024 ** 3)
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
-def test_mistral_engine_program_reads_stored_weights(topo, program):
+def _mistral_engine_program(topo, program, rung=None):
     from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=32768, num_layers=8, num_heads=32,
                       num_kv_heads=8, embed_dim=4096, mlp_dim=14336,
                       rope_theta=1e6, rms_eps=1e-5, max_seq_len=2048 + 512)
-    params, compiled, text = _llama_engine_program(
-        topo, cfg, program, prompt=2048, new=512, num_pages=2561)
+    return _llama_engine_program(topo, cfg, program, prompt=2048, new=512,
+                                 num_pages=2561, rung=rung)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_mistral_engine_program_reads_stored_weights(topo, program):
+    params, compiled, text = _mistral_engine_program(topo, program)
     assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
     assert params["layers"]["ln1"]["scale"].dtype == jnp.float32
     # a program parameter is %p__<path>__; the f32 tree's text has six
@@ -340,6 +347,18 @@ def test_mistral_engine_program_reads_stored_weights(topo, program):
         r"= bf16\[8,(2,4096,14336|14336,4096)\]\S* (?!parameter|get-tuple)",
         line)]
     assert made == []
+    assert _fits(compiled) < MISTRAL_BUDGET
+
+
+@pytest.mark.parametrize("rung", [128, 256, 512, 1024])
+def test_mistral_prefill_compiles_at_every_lower_rung(topo, rung):
+    """The engine compiles a prefill for every rung of its ladder
+    (``prefill_rungs``): the ones under the top rung are new shapes for the
+    compiler, the page table as wide as ever."""
+    from ray_tpu.serve.engine.engine import prefill_rungs
+    assert rung in prefill_rungs(2048, PAGE)
+    _, compiled, text = _mistral_engine_program(topo, "prefill", rung=rung)
+    assert "convert(%p__" not in text
     assert _fits(compiled) < MISTRAL_BUDGET
 
 

@@ -7,9 +7,12 @@ import sys
 
 import pytest
 
+from ray_tpu.serve.engine.engine import prefill_rungs, rung_for
 from ray_tpu.util import tracing
 
 import engine_trace
+
+RUNGS = prefill_rungs(engine_trace.MAX_PROMPT_LEN, engine_trace.PAGE)
 
 
 def _host_regions(path):
@@ -128,7 +131,7 @@ def test_engine_regions_carry_their_attributes(engine_run):
     assert sorted(s["prompt_len"] for _, _, s in prefills) == \
         sorted(len(p) for p in engine_trace.PROMPTS)
     for _, _, stats in prefills:
-        assert stats["padded_len"] == engine_trace.MAX_PROMPT_LEN
+        assert stats["padded_len"] == rung_for(RUNGS, stats["prompt_len"])
         assert stats["waited_us"] >= stats["submit_us"] >= 0
     dispatches = by_name["rt:engine.decode.dispatch"]
     # both sequences decode until the shorter one is done
@@ -159,7 +162,10 @@ def test_engine_counters_add_up(engine_run):
     assert stats["admitted"] == len(prompts)
     assert stats["prefill_tokens"] == sum(len(p) for p in prompts)
     assert stats["prefill_padded_tokens"] == \
-        len(prompts) * engine_trace.MAX_PROMPT_LEN
+        sum(rung_for(RUNGS, len(p)) for p in prompts)
+    assert stats["prefill_shapes"] == {
+        rung: sum(rung_for(RUNGS, len(p)) == rung for p in prompts)
+        for rung in RUNGS}
     # a sequence's first token comes from its prefill, the rest one a step
     assert stats["slot_steps"] == sum(n - 1 for n in generated)
     assert stats["steps"] == (engine_trace.WARM_NEW - 1) + max(new) - 1
