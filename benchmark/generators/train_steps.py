@@ -141,6 +141,7 @@ def run(ctx: dict) -> dict:
     check = report["loss_check"]
     finite = [x for x in report["losses"] if math.isfinite(x)]
     return {**report,
+            "checks": {"loss_rel_err": [check["rel_err"], check["rtol"]]},
             "correct": bool(check["rel_err"] <= check["rtol"]
                             and len(finite) == len(report["losses"])),
             "attempted": report["steps"],

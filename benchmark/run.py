@@ -7,7 +7,9 @@ Starts a cluster with ``ray_tpu.init()``, lets the cell's generator drive
 the system through its public entry points, and prints as the last line
 of stdout one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
-per-layer metrics), ``device`` and, traced, ``breakdown``.
+per-layer metrics), ``device``, a serve cell's ``load`` (requests offered
+and finished inside the window), traced ``breakdown``, and last ``checks``:
+each number that ``correct`` compared, beside its limit.
 
 This process never initialises a JAX backend: the chips belong to the
 worker that leased them, and the device is what that worker reports.
@@ -89,11 +91,15 @@ def main() -> int:
             metrics[name] = {"value": value, "unit": unit}
     line = {"correct": run["correct"], "attempted": run["attempted"],
             "failed": run["failed"], "metrics": metrics, "device": device}
+    if run.get("load"):
+        line["load"] = run["load"]
     if args.trace and run["trace"]:
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         line["breakdown"] = trace_reduce.breakdown(run["trace"])
-    for key in ("numerics", "loss_check", "errors", "phases"):
+    line["checks"] = run["checks"]
+    for key in ("numerics", "loss_check", "errors", "phases", "gaps_ms",
+                "checks"):
         if run.get(key):
             print(json.dumps({key: run[key]}), file=sys.stderr)
     print(json.dumps(line), flush=True)

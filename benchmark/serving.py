@@ -14,11 +14,13 @@ from urllib.parse import urlparse
 
 import numpy as np
 
-from benchmark import spec
+from benchmark import spec, stats
 
 NAME = "llm"
 READY_DEADLINE_S = 300
 TRACE_AFTER_S, TRACE_FOR_S = 3.0, 5.0
+GAP_PERCENTILES = (50, 90, 95, 97, 98, 98.3, 98.5, 98.6, 98.7, 98.8, 98.9, 99,
+                   99.1, 99.2, 99.3, 99.4, 99.5, 99.75, 99.9)
 
 
 def sizes(plan: dict, count: int, rng: np.random.Generator) -> list:
@@ -177,16 +179,29 @@ class Session:
         replica = await loop.run_in_executor(None, self.call, "collect")
         vocab = self.config["vocab_size"]
         failed = [r for r in self.records if r["error"]]
-        exact = all(len(r["tokens"]) == r["asked"] and all(
-            isinstance(t, int) and 0 <= t < vocab for t in r["tokens"])
-            for r in self.records if not r["error"])
+        inexact = sum(1 for r in self.records if r["error"] or not (
+            len(r["tokens"]) == r["asked"] and all(
+                isinstance(t, int) and 0 <= t < vocab for t in r["tokens"])))
+        gaps = sorted(stats.token_gaps_ms(self.records))
         return {"device": {**self.device,
                            "memory_peak_bytes": replica["memory_peak_bytes"]},
-                "correct": bool(self.numerics["ok"] and exact
-                                and self.records and not failed),
+                # where the tail's plateaus lie (stderr only)
+                "gaps_ms": {"count": len(gaps), **{
+                    f"p{q:g}": stats.percentile(gaps, q)
+                    for q in GAP_PERCENTILES if gaps}},
+                "correct": bool(self.numerics["ok"] and self.records
+                                and not inexact),
                 "attempted": len(self.records), "failed": len(failed),
                 "errors": [r["error"] for r in failed][:3],
                 "numerics": self.numerics,
+                "checks": {"logits_rel_err": [
+                    max(self.numerics["logits_rel_err"]),
+                    self.numerics["rtol"]],
+                    "requests_failed_or_inexact": [inexact, 0]},
+                # what a knee is read from (tools/sweep.py)
+                "load": {"offered": len(self.records),
+                         "finished_in_window": stats.finished_by(
+                             self.records, self.start + seconds)},
                 "phases": {**self.phases, **replica["phases"]},
                 "window_start_epoch": window_start_epoch,
                 "window": (self.start, self.start + seconds),

@@ -24,6 +24,25 @@ def spread(values: Sequence[float]) -> float:
     return (q3 - q1) / statistics.median(values)
 
 
+def two_groups(values: Sequence[float]) -> tuple:
+    """The largest gap between neighbouring sorted readings as a share of
+    the median, and how many readings lie below it: far above the spread
+    where the readings are of two kinds, as when a percentile sits on the
+    step between two plateaus."""
+    ordered = sorted(values)
+    below = max(range(1, len(ordered)),
+                key=lambda i: ordered[i] - ordered[i - 1])
+    return ((ordered[below] - ordered[below - 1])
+            / statistics.median(ordered), below)
+
+
+def finished_by(requests: Iterable[dict], end: float) -> int:
+    """Requests whose last token had arrived at ``end``, none missing."""
+    return sum(1 for r in requests
+               if not r["error"] and len(r["arrivals"]) == r["asked"]
+               and r["arrivals"][-1] <= end)
+
+
 def ttfts_ms(requests: Iterable[dict], since: str = "due") -> list:
     """First token's arrival minus the time the request was due (or
     ``sent``), for every request that got a token."""

@@ -2,7 +2,9 @@
 """Run a cell as the contract asks before a bound is set: sets of runs with
 the same seeds in every set, each run its own process, and for each
 end-to-end metric each set's spread (distance between the quartiles over
-the median) and the bound that five times the widest gives.
+the median), the bound that five times the widest gives, whether a set
+spreads over half of that bound, and whether the readings fall in two
+separated groups (the largest gap between sorted readings over the median).
 
     python3 benchmark/tools/spreads.py serve-chat-steady [--sets 2] [--runs 6]
 
@@ -21,6 +23,29 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from benchmark import stats   # noqa: E402
+
+
+def verdict(name: str, sets: list) -> str:
+    """One metric's medians and spreads, the bound that five times the
+    widest gives inside the contract's 1% to 10%, and what makes a bound
+    unfit: a set that spreads over half of it, or readings of two kinds."""
+    spreads = [stats.spread(v) for v in sets if len(v) >= 2]
+    medians = [statistics.median(v) for v in sets if v]
+    bound = min(10.0, max(1.0, 500 * max(spreads, default=0)))
+    out = (f"{name}: medians {[round(m, 4) for m in medians]} spreads "
+           f"{[round(100 * x, 3) for x in spreads]}% -> bound {bound:.2f}%")
+    if any(200 * x > bound for x in spreads):
+        out += " OVER HALF THE BOUND: no admissible bound holds this cell"
+    pooled = sorted(v for one in sets for v in one)
+    if len(pooled) >= 4:
+        gap, cut = stats.two_groups(pooled)
+        out += f"; largest gap between sorted readings {100 * gap:.3f}%"
+        # one far-off run is a stalled machine's; two on each side of a gap
+        # wider than the admitted spread are a step between two plateaus
+        if 200 * gap > bound and 2 <= cut <= len(pooled) - 2:
+            out += (f" TWO GROUPS: {cut} readings up to {pooled[cut - 1]:.6g}"
+                    f", {len(pooled) - cut} from {pooled[cut]:.6g}")
+    return out
 
 
 def main() -> int:
@@ -68,11 +93,7 @@ def main() -> int:
         # the first run of the first set is the one that may compile
         if name == "setup_s":
             sets = [sets[0][1:]] + sets[1:]
-        spreads = [stats.spread(v) for v in sets if len(v) >= 2]
-        medians = [statistics.median(v) for v in sets if v]
-        print(f"{name}: medians {[round(m, 4) for m in medians]} spreads "
-              f"{[round(100 * x, 3) for x in spreads]}% -> bound "
-              f"{max(1.0, 500 * max(spreads, default=0)):.2f}%", flush=True)
+        print(verdict(name, sets), flush=True)
     return 0
 
 

@@ -60,3 +60,26 @@ def test_live_kv_tokens_per_step():
     # two decode steps read contexts of 11 and 12 positions
     assert stats.live_kv_tokens_per_step(requests, 2) == 11.5
     assert stats.live_kv_tokens_per_step(requests, 0) is None
+
+
+@pytest.mark.parametrize("values,gap,below", [
+    # PR 31's readings of chat's tail lay on two plateaus
+    ([23.992, 24.109, 24.507, 25.647, 28.886, 29.226], 28.886 - 25.647, 4),
+    ([100.0, 100.5, 101.0, 101.6], 0.6, 3),
+])
+def test_two_groups_is_the_largest_gap_over_the_median(values, gap, below):
+    import statistics
+    share, cut = stats.two_groups(values[::-1])
+    assert share == pytest.approx(gap / statistics.median(values))
+    assert cut == below
+
+
+def test_finished_by_counts_whole_answers_inside():
+    def asked(n, arrivals, error=None):
+        return {"asked": n, "arrivals": arrivals, "error": error}
+    requests = [asked(2, [1.0, 2.0]),            # whole and inside
+                asked(2, [1.0, 12.0]),           # its last token is late
+                asked(3, [1.0, 2.0]),            # a token short
+                asked(2, [1.0, 2.0], "closed")]  # failed
+    assert stats.finished_by(requests, 10.0) == 1
+    assert stats.finished_by(requests, 12.0) == 2
