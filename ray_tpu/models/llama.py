@@ -38,13 +38,32 @@ not train here: ``llama_loss`` refuses it, because its published objective
 (an entropy-regularised expectation over exit steps) is not written, and
 neither are the exit gate's two leaves: at the published threshold of 1
 the gate never exits early.
+
+With ``kv_lora_rank`` the attention is latent (MLA, DeepSeek-V2/V3's): the
+query goes through a normed bottleneck, keys and values are expanded per head
+from ONE normed ``kv_lora_rank``-wide vector a position, and the positions
+come from a ``qk_rope_dim``-wide key all heads share (YaRN tables with
+``rope_yarn``).  Served, a position's cache is that vector and that key, one
+page pool ``[L, P, page * (kv_lora_rank + qk_rope_dim)]`` and no V pool
+(``ops/paged_attention.py``'s latent kind): the prefill expands keys and
+values for its dense attention, the decode step absorbs ``wkv_b`` into the
+query and the output and reads the pool as it lies.  With
+``first_dense_layers`` the stack is two groups, dense feed-forwards of
+``dense_mlp_dim`` under ``params["dense_layers"]`` (few, and unrolled) ahead
+of the scanned expert layers.  With ``hc_mult`` the residual stream is
+``hc_mult`` rows wide and every sublayer reads a learned mix of the rows and
+writes back through a Sinkhorn-projected mixing matrix (manifold-constrained
+hyper-connections, arXiv:2512.24880; ``_sublayer``).  ``shared_experts``,
+``router_scoring``, ``router_bias`` and ``routed_scaling`` are
+``ops/moe.py``'s.  Such a model serves and runs ``llama_forward``; it does
+not train here.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +99,24 @@ class LlamaConfig:
     qk_norm: bool = False            # RMSNorm q and k over all heads
     ut_steps: int = 1                # passes over the layer stack
     post_norm: bool = False          # RMSNorm a sublayer's output too
+    kv_lora_rank: int = 0            # > 0: latent attention (MLA), and the
+    q_lora_rank: int = 0             #   width of the query's bottleneck,
+    qk_nope_dim: int = 0             #   a head's unrotated q/k width,
+    qk_rope_dim: int = 0             #   the shared rotated key's width,
+    v_head_dim: int = 0              #   a head's value width
+    # YaRN: (factor, original length, beta_fast, beta_slow, mscale,
+    # mscale_all_dim), DeepSeek-V3's reading of them
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    first_dense_layers: int = 0      # of num_layers, ahead of the experts'
+    dense_mlp_dim: int = 0           # their SwiGLU's width
+    shared_experts: int = 0          # a shared expert of this x mlp_dim
+    router_scoring: str = "softmax"  # or "sigmoid" (gates from the scores)
+    router_bias: bool = False        # added to the scores to SELECT only
+    routed_scaling: float = 1.0      # sigmoid routing: the gates' factor
+    hc_mult: int = 0                 # rows of a hyper-connected residual
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def head_dim(self) -> int:
@@ -96,84 +133,167 @@ class LlamaConfig:
                            mlp_dim=192)
 
 
-def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
-    """Params with per-layer weights stacked on a leading [L] dim."""
-    if cfg.num_heads % cfg.num_kv_heads:
+def _check(cfg: LlamaConfig) -> None:
+    if cfg.kv_lora_rank and not (cfg.q_lora_rank and cfg.qk_nope_dim
+                                 and cfg.qk_rope_dim and cfg.v_head_dim
+                                 and cfg.rope_yarn):
+        raise ValueError("latent attention needs q_lora_rank, qk_nope_dim, "
+                         "qk_rope_dim, v_head_dim and rope_yarn beside "
+                         "kv_lora_rank")
+    if not cfg.kv_lora_rank and cfg.num_heads % cfg.num_kv_heads:
         raise ValueError(f"num_heads={cfg.num_heads} must be divisible by "
                          f"num_kv_heads={cfg.num_kv_heads}")
+    if cfg.num_experts and not \
+            0 < cfg.experts_per_token <= cfg.num_experts:
+        raise ValueError(f"experts_per_token={cfg.experts_per_token} must "
+                         f"be in 1..num_experts={cfg.num_experts}")
+    if not 0 <= cfg.first_dense_layers < max(cfg.num_layers, 1) or (
+            cfg.first_dense_layers and not (cfg.num_experts
+                                            and cfg.dense_mlp_dim)):
+        raise ValueError("first_dense_layers are dense layers of "
+                         "dense_mlp_dim ahead of at least one expert layer")
+    if cfg.router_scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"router_scoring={cfg.router_scoring!r}")
+    if cfg.hc_mult and (cfg.post_norm or cfg.ut_steps > 1):
+        raise ValueError("a hyper-connected residual (hc_mult) is not "
+                         "written for post_norm or ut_steps > 1")
+
+
+def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
+                experts: int, M: int) -> Dict[str, Any]:
+    """``L`` layers of one kind stacked on a leading dim: ``experts`` of
+    width ``M`` each (0: one dense SwiGLU of ``M``)."""
     k = jax.random.split(rng, 8)
-    D, H, M, L, V = (cfg.embed_dim, cfg.head_dim, cfg.mlp_dim,
-                     cfg.num_layers, cfg.vocab_size)
+    D, H = cfg.embed_dim, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
     scale = 0.02
-    rscale = scale / np.sqrt(2 * L)
-    E = cfg.num_experts
-    if E and not 0 < cfg.experts_per_token <= E:
-        raise ValueError(f"experts_per_token={cfg.experts_per_token} must "
-                         f"be in 1..num_experts={E}")
-    ex = (E,) if E else ()           # the experts' leading dim
+    rscale = scale / np.sqrt(2 * cfg.num_layers)
+
+    def normal(key, shape, std=scale):
+        return std * jax.random.normal(key, shape, jnp.float32)
+
+    ex = (experts,) if experts else ()   # the experts' leading dim
     # SwiGLU: gate and up projections fused on a leading 2-dim.
-    mlp = {"wgu": scale * jax.random.normal(k[4], (L, *ex, 2, D, M),
-                                            jnp.float32),
-           "wd": rscale * jax.random.normal(k[5], (L, *ex, M, D),
-                                            jnp.float32)}
-    if E:
-        mlp["router"] = scale * jax.random.normal(k[7], (L, D, E),
-                                                  jnp.float32)
-    norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
-             "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
-        if cfg.qk_norm else {}
-    post = {name: {"scale": jnp.ones((L, D), jnp.float32)}
-            for name in ("ln1_post", "ln2_post")} if cfg.post_norm else {}
+    mlp = {"wgu": normal(k[4], (L, *ex, 2, D, M)),
+           "wd": normal(k[5], (L, *ex, M, D), rscale)}
+    extra = {}
+    if experts:
+        mlp["router"] = normal(k[7], (L, D, experts))
+        if cfg.router_bias:
+            mlp["router_bias"] = normal(jax.random.fold_in(k[7], 1),
+                                        (L, experts), 0.01)
+        if cfg.shared_experts:
+            Ms = cfg.shared_experts * M
+            extra["shared"] = {
+                "wgu": normal(jax.random.fold_in(k[4], 1), (L, 2, D, Ms)),
+                "wd": normal(jax.random.fold_in(k[5], 1), (L, Ms, D),
+                             rscale)}
+    if cfg.kv_lora_rank:
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        attn = {"wq_a": normal(k[1], (L, D, rq)),
+                "q_a_norm": jnp.ones((L, rq), jnp.float32),
+                "wq_b": normal(jax.random.fold_in(k[1], 1),
+                               (L, rq, nh, dn + dr)),
+                "wkv_a": normal(k[2], (L, D, rkv + dr)),
+                "kv_a_norm": jnp.ones((L, rkv), jnp.float32),
+                "wkv_b": normal(jax.random.fold_in(k[2], 1),
+                                (L, rkv, nh, dn + dv)),
+                "wo": normal(k[3], (L, nh, dv, D), rscale)}
+    else:
+        norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
+                 "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
+            if cfg.qk_norm else {}
+        attn = {"wq": normal(k[1], (L, D, nh, H)),
+                "wkv": normal(k[2], (L, D, 2, nkv, H)),
+                "wo": normal(k[3], (L, nh, H, D), rscale),
+                **norms}
+    for name in ("ln1_post", "ln2_post") if cfg.post_norm else ():
+        extra[name] = {"scale": jnp.ones((L, D), jnp.float32)}
+    n = cfg.hc_mult
+    for at, name in enumerate(("hc_attn", "hc_mlp") if n else ()):
+        # One projection for the three coefficients (pre | post | res) and
+        # their scalars and biases, all f32.  The mixing matrix starts
+        # near the identity: its bias favours the diagonal.
+        key = jax.random.fold_in(k[6], at)
+        bias = normal(jax.random.fold_in(key, 1), (L, 2 * n + n * n), 0.5)
+        extra[name] = {
+            "proj": normal(key, (L, n * D, 2 * n + n * n)),
+            "alpha": jnp.full((L, 3), 0.25, jnp.float32),
+            "bias": bias.at[:, 2 * n:].add(2.0 * jnp.eye(n).reshape(-1))}
+    return {"ln1": {"scale": jnp.ones((L, D), jnp.float32)},
+            "attn": attn,
+            "ln2": {"scale": jnp.ones((L, D), jnp.float32)},
+            "mlp": mlp, **extra}
+
+
+def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
+    """Params with per-layer weights stacked on a leading [L] dim; with
+    ``first_dense_layers`` those under ``dense_layers`` and the expert
+    layers that follow them under ``layers``."""
+    _check(cfg)
+    k = jax.random.split(rng, 8)
+    D, V, Ld = cfg.embed_dim, cfg.vocab_size, cfg.first_dense_layers
+    scale = 0.02
+    dense = {"dense_layers": _init_group(
+        jax.random.fold_in(rng, 1), cfg, Ld, 0, cfg.dense_mlp_dim)} \
+        if Ld else {}
     return {
         "wte": scale * jax.random.normal(k[0], (V, D), jnp.float32),
-        "layers": {
-            "ln1": {"scale": jnp.ones((L, D), jnp.float32)},
-            "attn": {
-                "wq": scale * jax.random.normal(k[1], (L, D, nh, H),
-                                                jnp.float32),
-                "wkv": scale * jax.random.normal(k[2], (L, D, 2, nkv, H),
-                                                 jnp.float32),
-                "wo": rscale * jax.random.normal(k[3], (L, nh, H, D),
-                                                 jnp.float32),
-                **norms,
-            },
-            "ln2": {"scale": jnp.ones((L, D), jnp.float32)},
-            "mlp": mlp,
-            **post,
-        },
+        **dense,
+        "layers": _init_group(rng, cfg, cfg.num_layers - Ld,
+                              cfg.num_experts, cfg.mlp_dim),
         "ln_f": {"scale": jnp.ones((D,), jnp.float32)},
         "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32),
     }
+
+
+def _group_axes(cfg: LlamaConfig, experts: bool) -> Dict[str, Any]:
+    ex = ("expert",) if experts else ()
+    mlp = {"wgu": ("layers", *ex, None, "embed", "mlp"),
+           "wd": ("layers", *ex, "mlp", "embed")}
+    extra = {}
+    if experts:
+        mlp["router"] = ("layers", "embed", None)
+        if cfg.router_bias:
+            mlp["router_bias"] = ("layers", None)
+        if cfg.shared_experts:
+            extra["shared"] = {"wgu": ("layers", None, "embed", "mlp"),
+                               "wd": ("layers", "mlp", "embed")}
+    if cfg.kv_lora_rank:
+        attn = {"wq_a": ("layers", "embed", None),
+                "q_a_norm": ("layers", "norm"),
+                "wq_b": ("layers", None, "heads", "kv"),
+                "wkv_a": ("layers", "embed", None),
+                "kv_a_norm": ("layers", "norm"),
+                "wkv_b": ("layers", None, "heads", "kv"),
+                "wo": ("layers", "heads", "kv", "embed")}
+    else:
+        norms = {"q_norm": ("layers", "heads", "kv"),
+                 "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
+        attn = {"wq": ("layers", "embed", "heads", "kv"),
+                "wkv": ("layers", "embed", None, "heads", "kv"),
+                "wo": ("layers", "heads", "kv", "embed"),
+                **norms}
+    for name in ("ln1_post", "ln2_post") if cfg.post_norm else ():
+        extra[name] = {"scale": ("layers", "norm")}
+    for name in ("hc_attn", "hc_mlp") if cfg.hc_mult else ():
+        extra[name] = {"proj": ("layers", None, None),
+                       "alpha": ("layers", None), "bias": ("layers", None)}
+    return {"ln1": {"scale": ("layers", "norm")}, "attn": attn,
+            "ln2": {"scale": ("layers", "norm")}, "mlp": mlp, **extra}
 
 
 def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Logical-axis annotations matching ``llama_init`` (same rule table
     as GPT: heads/mlp -> tp, embed -> fsdp, layers -> pp; experts carry
     "expert" -> ep, the router stays replicated over them)."""
-    ex = ("expert",) if cfg.num_experts else ()
-    mlp = {"wgu": ("layers", *ex, None, "embed", "mlp"),
-           "wd": ("layers", *ex, "mlp", "embed")}
-    if cfg.num_experts:
-        mlp["router"] = ("layers", "embed", None)
-    norms = {"q_norm": ("layers", "heads", "kv"),
-             "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
-    post = {name: {"scale": ("layers", "norm")}
-            for name in ("ln1_post", "ln2_post")} if cfg.post_norm else {}
+    dense = {"dense_layers": _group_axes(cfg, False)} \
+        if cfg.first_dense_layers else {}
     return {
         "wte": (None, "embed"),
-        "layers": {
-            "ln1": {"scale": ("layers", "norm")},
-            "attn": {
-                "wq": ("layers", "embed", "heads", "kv"),
-                "wkv": ("layers", "embed", None, "heads", "kv"),
-                "wo": ("layers", "heads", "kv", "embed"),
-                **norms,
-            },
-            "ln2": {"scale": ("layers", "norm")},
-            "mlp": mlp,
-            **post,
-        },
+        **dense,
+        "layers": _group_axes(cfg, bool(cfg.num_experts)),
         "ln_f": {"scale": ("norm",)},
         "lm_head": ("embed", None),
     }
@@ -199,6 +319,57 @@ def apply_rope(x, cos, sin):
     reshape-free implementations).  cos/sin broadcast over leading dims."""
     H = x.shape[-1]
     x1, x2 = x[..., : H // 2], x[..., H // 2:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+        axis=-1).astype(x.dtype)
+
+
+def yarn_rope_tables(S: int, H: int, theta: float, factor: float,
+                     original_len: float, beta_fast: float,
+                     beta_slow: float, mscale: float = 1.0,
+                     mscale_all_dim: float = 0.0) -> tuple:
+    """(cos, sin) [S, H/2] f32 tables with YaRN's frequencies, as
+    DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding`` makes them: the pairs
+    that turn more than ``beta_fast`` times over ``original_len`` positions
+    keep their frequency, those that turn fewer than ``beta_slow`` times
+    have it divided by ``factor``, a linear ramp between; both tables times
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
+    exponent = np.arange(0, H, 2, dtype=np.float64) / H
+    extra, inter = theta ** -exponent, theta ** -exponent / factor
+
+    def correction_dim(turns):
+        return H * np.log(original_len / (turns * 2 * np.pi)) \
+            / (2 * np.log(theta))
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), H - 1)
+    if low == high:
+        high += 0.001
+    keep = 1 - np.clip((np.arange(H // 2) - low) / (high - low), 0, 1)
+    freqs = np.outer(np.arange(S, dtype=np.float64),
+                     inter * (1 - keep) + extra * keep)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return (jnp.asarray(np.cos(freqs) * m, jnp.float32),
+            jnp.asarray(np.sin(freqs) * m, jnp.float32))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_tables(cfg: LlamaConfig, S: int) -> tuple:
+    """The model's (cos, sin) tables for ``S`` positions."""
+    if not cfg.kv_lora_rank:
+        return rope_tables(S, cfg.head_dim, cfg.rope_theta)
+    return yarn_rope_tables(S, cfg.qk_rope_dim, cfg.rope_theta,
+                            *cfg.rope_yarn)
+
+
+def apply_rope_pairs(x, cos, sin):
+    """Rotate the pairs (2i, 2i+1) of [..., H] (DeepSeek's interleaved
+    convention) and leave them de-interleaved: the first halves, then the
+    second.  A score is a dot product of two vectors rotated alike, so the
+    order they are left in is the cache's own business."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
         axis=-1).astype(x.dtype)
@@ -248,6 +419,160 @@ def _add_sublayer(cfg: LlamaConfig, p, post: str, x, y):
     return x + y
 
 
+def _sinkhorn(m, iters: int, eps: float):
+    """``iters`` rounds of dividing each row of [..., n, n] by its sum and
+    then each column by its: towards a doubly stochastic matrix."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def _hc_coeff(cfg: LlamaConfig, hp, x):
+    """A hyper-connection's coefficients for the stream x [..., n, C], all
+    float32: ``pre`` [..., n] in (0, 1), how much of each row the sublayer
+    reads; ``post`` [..., n] in (0, 2), how much of its output each row
+    receives; ``res`` [..., n, n], rows and columns summing to 1, how the
+    rows are mixed into the next stream.  All three are an affine function
+    of the RMS-normed stream (all n x C values together, no learned scale):
+    ``hp["proj"]`` [n*C, 2n + n*n] holds the three projections side by
+    side, ``hp["alpha"]`` [3] their scalars, ``hp["bias"]`` their biases."""
+    n = cfg.hc_mult
+    lo, hi = cfg.hc_clamp
+    with jax.named_scope("hc_coeff"):
+        flat = x.reshape(*x.shape[:-2], -1)
+        u = _rms_norm(flat.astype(jnp.float32), 1.0, cfg.rms_eps)
+        raw = jnp.einsum("...k,km->...m", u, hp["proj"],
+                         precision=jax.lax.Precision.HIGHEST)
+        raw = raw * jnp.repeat(hp["alpha"], np.array([n, n, n * n]),
+                               total_repeat_length=2 * n + n * n) \
+            + hp["bias"]
+        pre = jax.nn.sigmoid(raw[..., :n])
+        post = 2.0 * jax.nn.sigmoid(raw[..., n:2 * n])
+        res = raw[..., 2 * n:].reshape(*raw.shape[:-1], n, n)
+        res = _sinkhorn(jnp.exp(jnp.clip(res, lo, hi)),
+                        cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return pre, post, res
+
+
+def _hc_reduce(x):
+    """The stream's rows [..., n, C] summed into one, after the last
+    layer."""
+    return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
+
+
+def _sublayer(cfg: LlamaConfig, p, which: int, x, fn):
+    """One sublayer around the residual stream: ``which`` 0 is attention
+    (``ln1``, ``ln1_post``, ``hc_attn``), 1 the feed-forward.  ``fn`` takes
+    the normed input [..., D] and returns (the sublayer's output, anything
+    else the caller wants back).  Plain: ``x + fn(norm(x))``.  With
+    ``cfg.hc_mult`` the stream is [..., n, D]: the sublayer reads ``pre .
+    x``, and the next stream is ``res @ x + post (outer) y``, with the
+    coefficients of ``_hc_coeff``; the products are float32 on the stream's
+    own type and rounded to it once."""
+    ln, post, hc = (("ln1", "ln1_post", "hc_attn"),
+                    ("ln2", "ln2_post", "hc_mlp"))[which]
+    if not cfg.hc_mult:
+        y, rest = fn(_rms_norm(x, p[ln]["scale"], cfg.rms_eps))
+        return _add_sublayer(cfg, p, post, x, y), rest
+    h_pre, h_post, h_res = _hc_coeff(cfg, p[hc], x)
+    with jax.named_scope("hc_mix"):
+        x32 = x.astype(jnp.float32)
+        h = jnp.sum(h_pre[..., None] * x32, axis=-2).astype(x.dtype)
+    y, rest = fn(_rms_norm(h, p[ln]["scale"], cfg.rms_eps))
+    with jax.named_scope("hc_mix"):
+        mixed = jnp.sum(h_res[..., None] * x32[..., None, :, :], axis=-2)
+        out = mixed + h_post[..., None] \
+            * y.astype(jnp.float32)[..., None, :]
+    return out.astype(x.dtype), rest
+
+
+def _stream_axes(cfg: LlamaConfig) -> tuple:
+    """Logical axes of the residual stream [B, S, (n,) D]."""
+    return ("batch", "seq", None, "embed") if cfg.hc_mult else \
+        ("batch", "seq", "embed")
+
+
+def _embed(cfg: LlamaConfig, params, tokens):
+    """The tokens' rows of the table; hyper-connected, each repeated to the
+    stream's ``hc_mult`` rows."""
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    if cfg.hc_mult:
+        x = jnp.repeat(x[..., None, :], cfg.hc_mult, axis=-2)
+    return x
+
+
+def mla_softmax_scale(cfg: LlamaConfig) -> float:
+    """What latent attention's scores are multiplied by: the q/k head
+    (unrotated + rotated) to the -1/2 and, under YaRN, the square of
+    ``yarn_mscale(factor, mscale_all_dim)``, as DeepSeek-V3 has it."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_yarn[5]:
+        scale *= yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
+    return float(scale)
+
+
+def _mla_project(cfg: LlamaConfig, p, h, cos, sin):
+    """Latent attention's projections of the normed hidden h [..., D] at
+    the positions of ``cos``/``sin`` [..., qk_rope_dim/2]: the heads'
+    unrotated queries [..., N, qk_nope_dim] and rotated ones [..., N,
+    qk_rope_dim], and the position's cache row [..., kv_lora_rank +
+    qk_rope_dim]: the normed compressed key-value and the rotated key that
+    all heads share."""
+    a, dt = p["attn"], cfg.dtype
+    rank, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+    cq = _rms_norm(jnp.einsum("...d,dr->...r", h, a["wq_a"].astype(dt)),
+                   a["q_a_norm"], cfg.rms_eps)
+    q = jnp.einsum("...r,rnh->...nh", cq, a["wq_b"].astype(dt))
+    ckr = jnp.einsum("...d,dr->...r", h, a["wkv_a"].astype(dt))
+    c = _rms_norm(ckr[..., :rank], a["kv_a_norm"], cfg.rms_eps)
+    r = apply_rope_pairs(ckr[..., rank:], cos, sin)
+    q_rope = apply_rope_pairs(q[..., dn:], cos[..., None, :],
+                              sin[..., None, :])
+    return q[..., :dn], q_rope, jnp.concatenate([c, r], axis=-1)
+
+
+def _mla_expanded(cfg: LlamaConfig, p, q_nope, q_rope, latent):
+    """Causal latent attention over whole sequences with keys and values
+    expanded per head from the cache rows: q_nope [B, S, N, dn], q_rope
+    [B, S, N, dr], latent [B, S, rank + dr] -> [B, S, N, dv]."""
+    rank, dn, dt = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.dtype
+    S = latent.shape[1]
+    kv = jnp.einsum("bsc,cnh->bsnh", latent[..., :rank],
+                    p["attn"]["wkv_b"].astype(dt))
+    scores = (jnp.einsum("bqnh,bknh->bnqk", q_nope, kv[..., :dn])
+              + jnp.einsum("bqnh,bkh->bnqk", q_rope, latent[..., rank:])) \
+        * mla_softmax_scale(cfg)
+    mask = jnp.tril(jnp.ones((S, S), bool))
+    scores = jnp.where(mask[None, None], scores.astype(jnp.float32), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+    return jnp.einsum("bnqk,bknh->bqnh", probs, kv[..., dn:])
+
+
+def _mla_absorbed(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer,
+                  lengths, page_table):
+    """One query a sequence against the paged latent cache AS IT LIES:
+    ``wkv_b``'s key half goes into the query, all heads score against the
+    one cached row of a position, the probabilities weigh the compressed
+    rows themselves, and ``wkv_b``'s value half comes after.  The same
+    mathematics as ``_mla_expanded``; no per-head key or value of a cached
+    position is ever made.  q_nope [B, N, dn], q_rope [B, N, dr] -> [B, N,
+    dv]."""
+    from ray_tpu.ops.paged_attention import paged_latent_attention
+    dn, dt = cfg.qk_nope_dim, cfg.dtype
+    wkv_b = p["attn"]["wkv_b"].astype(dt)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bnh,cnh->bnc", q_nope, wkv_b[..., :dn])
+    o_rows = paged_latent_attention(
+        jnp.concatenate([q_lat, q_rope], axis=-1), pages, layer, lengths,
+        page_table, sm_scale=mla_softmax_scale(cfg))
+    with jax.named_scope("mla_absorb"):
+        # the attention comes back over whole cached rows: the value half
+        # meets the rotated key's columns with rows of zeros
+        wv = jnp.pad(wkv_b[..., dn:], ((0, cfg.qk_rope_dim), (0, 0), (0, 0)))
+        return jnp.einsum("bnw,wnh->bnh", o_rows, wv)
+
+
 def _passes(cfg: LlamaConfig, params, layers_pass, carry):
     """``cfg.ut_steps`` passes over all layers, each followed by the
     model's final norm, which is what the next pass starts from.
@@ -258,6 +583,8 @@ def _passes(cfg: LlamaConfig, params, layers_pass, carry):
     is one pass only and nothing is wrapped.  Returns the last carry and
     every pass's ``ys`` along one leading [ut_steps * num_layers] axis."""
     def final_norm(x):
+        if cfg.hc_mult:
+            x = _hc_reduce(x)
         return _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
 
     if cfg.ut_steps == 1:
@@ -290,25 +617,54 @@ def _scanned_layers(cfg: LlamaConfig, params):
     layers = params["layers"]
     if not cfg.num_experts:
         return layers, None
-    return {**layers, "mlp": jnp.arange(cfg.num_layers)}, layers["mlp"]
+    return {**layers, "mlp": jnp.arange(
+        cfg.num_layers - cfg.first_dense_layers)}, layers["mlp"]
+
+
+def _scan_layers(cfg: LlamaConfig, params, body, carry, t=None,
+                 served: bool = False):
+    """``body(experts, carry, xs) -> (carry, ys)`` over the layers in their
+    order: the leading dense layers (``params["dense_layers"]``, if the
+    model has them; they are few, and unrolled: a scan's slice of a stack
+    of one is a copy of it), then the rest, scanned.  ``xs`` is a layer's
+    slice of its group or, for a ``served`` model, that slice and the
+    layer's index into the pools in pass ``t`` (``_pool_layers``).  Returns
+    the last carry and the scanned layers' ``ys`` (the expert layers'
+    loads)."""
+    first = cfg.first_dense_layers
+    layers, experts = _scanned_layers(cfg, params)
+    pool_layers = _pool_layers(cfg, t) if served else None
+    if first:
+        dense = params["dense_layers"]
+        if served:
+            dense = (dense, pool_layers[:first])
+        for i in range(first):
+            carry, _ = body(None, carry, jax.tree.map(lambda a: a[i], dense))
+    if served:
+        layers = (layers, pool_layers[first:] if first else pool_layers)
+    return jax.lax.scan(functools.partial(body, experts), carry, layers)
 
 
 def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
          experts=None):
     """The block's feed-forward on the normed hidden ``h`` [..., D]:
-    SwiGLU, dense or (``cfg.num_experts``) top-k experts without capacity;
-    then ``p["mlp"]`` is the layer's index into ``experts``, the stacked
-    experts of all layers (``_scanned_layers``).  Returns (y [..., D],
+    SwiGLU, dense or (``experts``: the stacked experts of all expert
+    layers, ``_scanned_layers``) top-k experts without capacity; then
+    ``p["mlp"]`` is the layer's index into ``experts``, and ``p["shared"]``
+    the layer's shared expert if the model has one.  Returns (y [..., D],
     load): ``load`` [E] int32 counts per expert the assignments of the
     tokens that ``live`` [...] marks (all when None), and is None for a
-    dense model."""
+    dense layer."""
     dt = cfg.dtype
-    if cfg.num_experts:
+    if experts is not None:
         from ray_tpu.ops.moe import moe_dropless
         y, load = moe_dropless(
             h.reshape(-1, h.shape[-1]), experts, layer=p["mlp"],
             top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
-            live=None if live is None else live.reshape(-1))
+            live=None if live is None else live.reshape(-1),
+            scoring=cfg.router_scoring, routed_scaling=cfg.routed_scaling,
+            shared=_cast_leaves(p["shared"], dt, "wgu", "wd")
+            if cfg.shared_experts else None)
         return y.reshape(h.shape), load
     gu = jnp.einsum("...d,cdm->c...m", h, p["mlp"]["wgu"].astype(dt))
     a = lc(jax.nn.silu(gu[0]) * gu[1], ("batch", "seq", "mlp"))
@@ -320,37 +676,42 @@ def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
     lc = (lambda a, ax: with_logical_constraint(a, rules, ax)) if rules \
         else (lambda a, ax: a)
     dt = cfg.dtype
-    rep = cfg.num_heads // cfg.num_kv_heads
+    rep = 0 if cfg.kv_lora_rank else cfg.num_heads // cfg.num_kv_heads
 
-    h = _rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
-    # Head-major [B, N, S, H] throughout: native layout for the flash
-    # kernels, picked in the projection epilogue for free.
-    q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
-    kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
-    k, v = kv[:, 0], kv[:, 1]
-    q, k = _qk(cfg, p, q, k, cos, sin)
-    if rep > 1 and getattr(attn_fn, "_gqa_native", False):
-        # Grouped dense path: fold the share-group dim into the einsum —
-        # K/V stay at kv_heads width (no jnp.repeat materializing rep
-        # copies of the KV tensors in HBM).
-        o = _checkpoint_name(
-            _dense_causal_attention_gqa(q, k, v, rep), "attn_out")
-    else:
-        if rep > 1:   # flash kernel expects equal head counts
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        q = lc(q, ("batch", "heads", "seq", "kv"))
-        k = lc(k, ("batch", "heads", "seq", "kv"))
-        v = lc(v, ("batch", "heads", "seq", "kv"))
-        o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
-    x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
-        "bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt)))
-    x = lc(x, ("batch", "seq", "embed"))
+    def attention(h):
+        if cfg.kv_lora_rank:         # dense: the kernels want equal heads
+            o = _mla_expanded(cfg, p, *_mla_project(cfg, p, h, cos, sin))
+            return jnp.einsum("bsnh,nhd->bsd", o,
+                              p["attn"]["wo"].astype(dt)), None
+        # Head-major [B, N, S, H] throughout: native layout for the flash
+        # kernels, picked in the projection epilogue for free.
+        q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
+        kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
+        k, v = kv[:, 0], kv[:, 1]
+        q, k = _qk(cfg, p, q, k, cos, sin)
+        if rep > 1 and getattr(attn_fn, "_gqa_native", False):
+            # Grouped dense path: fold the share-group dim into the einsum
+            # — K/V stay at kv_heads width (no jnp.repeat materializing
+            # rep copies of the KV tensors in HBM).
+            o = _checkpoint_name(
+                _dense_causal_attention_gqa(q, k, v, rep), "attn_out")
+        else:
+            if rep > 1:   # flash kernel expects equal head counts
+                k = jnp.repeat(k, rep, axis=1)
+                v = jnp.repeat(v, rep, axis=1)
+            q = lc(q, ("batch", "heads", "seq", "kv"))
+            k = lc(k, ("batch", "heads", "seq", "kv"))
+            v = lc(v, ("batch", "heads", "seq", "kv"))
+            o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
+        return jnp.einsum("bnsh,nhd->bsd", o,
+                          p["attn"]["wo"].astype(dt)), None
 
-    h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-    x = _add_sublayer(cfg, p, "ln2_post", x,
-                      _ffn(cfg, p, h, lc=lc, experts=experts)[0])
-    return lc(x, ("batch", "seq", "embed"))
+    stream = _stream_axes(cfg)
+    x, _ = _sublayer(cfg, p, 0, x, attention)
+    x = lc(x, stream)
+    x, _ = _sublayer(cfg, p, 1, x, lambda h: (
+        _ffn(cfg, p, h, lc=lc, experts=experts)[0], None))
+    return lc(x, stream)
 
 
 def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
@@ -359,9 +720,9 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
                  mesh=None) -> jax.Array:
     """tokens [B, S] int32 -> final hidden [B, S, D] after rms_norm (compute
     dtype) — the trunk without the LM head (see gpt_hidden)."""
-    dt = cfg.dtype
     S = tokens.shape[1]
-    if resolve_attention(cfg.attention, S) == "flash":
+    if not cfg.kv_lora_rank and \
+            resolve_attention(cfg.attention, S) == "flash":
         from ray_tpu.models.gpt import _flash_attention_bnsh
         attn_fn = _flash_attention_bnsh(rules, mesh)
     else:
@@ -371,13 +732,12 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
             return _dense_causal_attention_bnsh(q, k, v)
         attn_fn._gqa_native = True
 
-    cos, sin = rope_tables(S, cfg.head_dim, cfg.rope_theta)
-    x = params["wte"].astype(dt)[tokens]
+    cos, sin = _rope_tables(cfg, S)
+    x = _embed(cfg, params, tokens)
     if rules is not None:
-        x = with_logical_constraint(x, rules, ("batch", "seq", "embed"))
+        x = with_logical_constraint(x, rules, _stream_axes(cfg))
 
-    layers, experts = _scanned_layers(cfg, params)
-    block = functools.partial(_block, cfg, rules, attn_fn, cos, sin, experts)
+    block = functools.partial(_block, cfg, rules, attn_fn, cos, sin)
     if cfg.remat:
         cp = jax.checkpoint_policies
         policy = {
@@ -389,8 +749,9 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
         }.get(cfg.remat_policy)
         block = jax.checkpoint(block, policy=policy)
 
-    (x,), _ = _passes(cfg, params, lambda carry, t: jax.lax.scan(
-        lambda c, lp: ((block(c[0], lp),), None), carry, layers), (x,))
+    (x,), _ = _passes(cfg, params, lambda carry, t: _scan_layers(
+        cfg, params, lambda experts, c, lp: ((block(experts, c[0], lp),),
+                                              None), carry), (x,))
     return x
 
 
@@ -412,18 +773,27 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 # keys, so decode attention is a plain dot against the cache), and the
 # math mirrors _block's grouped dense branch exactly — with
 # cfg.dtype=float32 paged greedy decode reproduces llama_forward's
-# token-by-token argmax, which the CPU equivalence tests assert.
+# token-by-token argmax, which the CPU equivalence tests assert.  A latent
+# (MLA) model has ONE pool, of latent pages, and ``None`` where the others
+# have their V pool: the steps take and return the pair either way.
 
 
 def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                            page_size: int, dtype: Any = None):
-    """Zeroed K/V page pools of all layers, [L, P, page, NKV*H]
-    (token-major: see ops.paged_attention), ``L`` a layer for every pass
-    of a looped model: ``ut_steps * num_layers``.  Page 0 is the scratch
-    sink for padded/inactive writes — allocators must never hand it out."""
+    """Zeroed page pools of all layers, of the kind the model's attention
+    caches (token-major: see ops.paged_attention): K and V pools ``[L, P,
+    page, NKV*H]`` each or, for latent attention, one pool of latent pages
+    ``[L, P, page * (kv_lora_rank + qk_rope_dim)]`` (a page's positions side
+    by side: ops.paged_attention says why) and None.  ``L`` is a
+    layer for every pass of a looped model: ``ut_steps * num_layers``.
+    Page 0 is the scratch sink for padded/inactive writes — allocators
+    must never hand it out."""
     dt = dtype or cfg.dtype
-    shape = (cfg.ut_steps * cfg.num_layers, num_pages, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
+    L = cfg.ut_steps * cfg.num_layers
+    if cfg.kv_lora_rank:
+        return jnp.zeros((L, num_pages, page_size * (
+            cfg.kv_lora_rank + cfg.qk_rope_dim)), dt), None
+    shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
@@ -432,21 +802,31 @@ def llama_serving_params(params: Dict[str, Any],
     """``params`` with every leaf in the dtype ``llama_prefill`` and
     ``llama_decode_step`` read it in, for a caller that keeps the tree
     between calls: the leaves those two cast with ``.astype(cfg.dtype)``
-    (embedding table, head, the attention projections, a dense model's
-    feed-forward) are cast here, once, and the casts in the steps then cost
-    nothing (``astype`` to an array's own dtype returns the array).  Every
-    other leaf is handed back as the caller's own array: the norms' scales,
-    the router and the stacked experts are read in f32 (``_rms_norm``;
+    (embedding table, head, the attention projections, a dense
+    feed-forward, a shared expert) are cast here, once, and the casts in
+    the steps then cost nothing (``astype`` to an array's own dtype returns
+    the array).  Every other leaf is handed back as the caller's own array:
+    the norms' scales, a hyper-connection's leaves, the router and the
+    stacked experts are read as they are stored (``_rms_norm``, ``_hc_coeff``;
     ``moe_dropless`` casts the few rows of activations to the experts'
-    dtype, never the experts).  Casting twice is casting once, so the steps
-    return the same bits for this tree as for ``params``."""
-    dt, layers = cfg.dtype, params["layers"]
-    mlp = layers["mlp"] if cfg.num_experts else \
-        _cast_leaves(layers["mlp"], dt, "wgu", "wd")
-    return {**_cast_leaves(params, dt, "wte", "lm_head"),
-            "layers": {**layers, "mlp": mlp,
-                       "attn": _cast_leaves(layers["attn"], dt,
-                                            "wq", "wkv", "wo")}}
+    dtype, never the experts: float32 experts stay float32, bfloat16 ones
+    bfloat16).  Casting twice is casting once, so the steps return the same
+    bits for this tree as for ``params``."""
+    dt = cfg.dtype
+    matrices = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo") \
+        if cfg.kv_lora_rank else ("wq", "wkv", "wo")
+
+    def group(layers, experts):
+        out = {**layers, "attn": _cast_leaves(layers["attn"], dt, *matrices),
+               "mlp": layers["mlp"] if experts else
+               _cast_leaves(layers["mlp"], dt, "wgu", "wd")}
+        if "shared" in layers:
+            out["shared"] = _cast_leaves(layers["shared"], dt, "wgu", "wd")
+        return out
+    dense = {"dense_layers": group(params["dense_layers"], False)} \
+        if cfg.first_dense_layers else {}
+    return {**_cast_leaves(params, dt, "wte", "lm_head"), **dense,
+            "layers": group(params["layers"], bool(cfg.num_experts))}
 
 
 def _paged_results(logits, k_pages, v_pages, load):
@@ -458,44 +838,55 @@ def _paged_results(logits, k_pages, v_pages, load):
 
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
-                  k_pages: jax.Array, v_pages: jax.Array,
+                  k_pages: jax.Array, v_pages: Optional[jax.Array],
                   page_table: jax.Array):
     """Prefill ONE padded sequence (see gpt_prefill): dense trunk,
     per-layer post-rope K/V scattered into the sequence's pages, f32
     next-token logits at position length-1.  ``tokens`` [1, S] with S a
     multiple of the page size; ``page_table`` [1, maxp];
     ``k_pages``/``v_pages`` [L, P, page, NKV*H], carried through the layer
-    scan and written in place.  An expert model returns a
-    fourth result, ``load`` [L, E] int32: per layer and expert, the
-    assignments of the prompt's real positions."""
-    from ray_tpu.ops.paged_attention import prefill_kv
+    scan and written in place; a latent model's ``k_pages`` is its pool of
+    latent pages and its ``v_pages`` None.  An expert model returns a
+    fourth result, ``load`` [expert layers, E] int32: per layer and expert,
+    the assignments of the prompt's real positions."""
+    from ray_tpu.ops.paged_attention import prefill_kv, prefill_latent
     dt = cfg.dtype
-    rep = cfg.num_heads // cfg.num_kv_heads
+    rep = 0 if cfg.kv_lora_rank else cfg.num_heads // cfg.num_kv_heads
     S = tokens.shape[1]
-    cos, sin = rope_tables(S, cfg.head_dim, cfg.rope_theta)
-    x = params["wte"].astype(dt)[tokens]
+    cos, sin = _rope_tables(cfg, S)
+    x = _embed(cfg, params, tokens)
     live = (jnp.arange(S) < length)[None]                # the real positions
-    layers, experts = _scanned_layers(cfg, params)
 
-    def body(carry, inp):
+    def body(experts, carry, inp):
         (x, kp, vp), (p, layer) = carry, inp
-        h = _rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
-        q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
-        kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
-        k, v = kv[:, 0], kv[:, 1]                        # [B, NKV, S, H]
-        q, k = _qk(cfg, p, q, k, cos, sin)
-        kp, vp = prefill_kv(kp, vp, layer, k[0], v[0], length,
-                            page_table[0])
-        o = _dense_causal_attention_gqa(q, k, v, rep)
-        x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
-            "bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt)))
-        h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-        y, load = _ffn(cfg, p, h, live, experts=experts)
-        return (_add_sublayer(cfg, p, "ln2_post", x, y), kp, vp), load
+
+        def attention(h):
+            if cfg.kv_lora_rank:
+                q_nope, q_rope, latent = _mla_project(cfg, p, h, cos, sin)
+                pools = prefill_latent(kp, layer, latent[0], length,
+                                       page_table[0]), vp
+                o = _mla_expanded(cfg, p, q_nope, q_rope, latent)
+                return jnp.einsum("bsnh,nhd->bsd", o,
+                                  p["attn"]["wo"].astype(dt)), pools
+            q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
+            kv = jnp.einsum("bsd,dcnh->bcnsh", h,
+                            p["attn"]["wkv"].astype(dt))
+            k, v = kv[:, 0], kv[:, 1]                    # [B, NKV, S, H]
+            q, k = _qk(cfg, p, q, k, cos, sin)
+            pools = prefill_kv(kp, vp, layer, k[0], v[0], length,
+                               page_table[0])
+            o = _dense_causal_attention_gqa(q, k, v, rep)
+            return jnp.einsum("bnsh,nhd->bsd", o,
+                              p["attn"]["wo"].astype(dt)), pools
+
+        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
+        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
+            cfg, p, h, live, experts=experts))
+        return (x, kp, vp), load
 
     (x, k_pages, v_pages), load = _passes(
-        cfg, params, lambda carry, t: jax.lax.scan(
-            body, carry, (layers, _pool_layers(cfg, t))),
+        cfg, params, lambda carry, t: _scan_layers(
+            cfg, params, body, carry, t, served=True),
         (x, k_pages, v_pages))
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,dv->v", last,
@@ -505,43 +896,58 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
 
 def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
                       token: jax.Array, pos: jax.Array,
-                      k_pages: jax.Array, v_pages: jax.Array,
+                      k_pages: jax.Array, v_pages: Optional[jax.Array],
                       page_table: jax.Array):
     """One decode step for a BATCH of sequences (see gpt_decode_step).
     ``token``/``pos`` [B]; rope rotates q and the new K at each
     sequence's absolute position; the paged attention's GQA grouping
-    keeps K/V at kv_heads width.  Inactive slots (pos 0, all-zero
-    page-table row) harmlessly churn scratch page 0.  An expert model
-    returns a fourth result, ``load`` [L, E] int32: per layer and expert,
-    the assignments of the live slots (``pos > 0``: a sequence that decodes
-    has a prompt behind it)."""
-    from ray_tpu.ops.paged_attention import append_kv, paged_attention
+    keeps K/V at kv_heads width; a latent model appends a position's
+    latent row and reads its one pool as it lies (``_mla_absorbed``).
+    Inactive slots (pos 0, all-zero page-table row) harmlessly churn
+    scratch page 0.  An expert model returns a fourth result, ``load``
+    [expert layers, E] int32: per layer and expert, the assignments of the
+    live slots (``pos > 0``: a sequence that decodes has a prompt behind
+    it)."""
+    from ray_tpu.ops.paged_attention import (append_kv, append_latent,
+                                             paged_attention)
     dt = cfg.dtype
-    cos_t, sin_t = rope_tables(cfg.max_seq_len, cfg.head_dim,
-                               cfg.rope_theta)
-    cos, sin = cos_t[pos][:, None], sin_t[pos][:, None]  # [B, 1, H/2]
-    x = params["wte"].astype(dt)[token]
+    cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
+    if cfg.kv_lora_rank:             # one rotated key for all heads
+        cos, sin = cos_t[pos], sin_t[pos]                # [B, dr/2]
+    else:
+        cos, sin = cos_t[pos][:, None], sin_t[pos][:, None]  # [B, 1, H/2]
+    x = _embed(cfg, params, token)
     live = pos > 0
-    layers, experts = _scanned_layers(cfg, params)
 
-    def body(carry, inp):
+    def body(experts, carry, inp):
         (x, kp, vp), (p, layer) = carry, inp
-        h = _rms_norm(x, p["ln1"]["scale"], cfg.rms_eps)
-        q = jnp.einsum("bd,dnh->bnh", h, p["attn"]["wq"].astype(dt))
-        kv = jnp.einsum("bd,dcnh->bcnh", h, p["attn"]["wkv"].astype(dt))
-        k_new, v_new = kv[:, 0], kv[:, 1]                # [B, NKV, H]
-        q, k_new = _qk(cfg, p, q, k_new, cos, sin)
-        kp, vp = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
-        o = paged_attention(q, kp, vp, layer, pos + 1, page_table)
-        x = _add_sublayer(cfg, p, "ln1_post", x, jnp.einsum(
-            "bnh,nhd->bd", o, p["attn"]["wo"].astype(dt)))
-        h = _rms_norm(x, p["ln2"]["scale"], cfg.rms_eps)
-        y, load = _ffn(cfg, p, h, live, experts=experts)
-        return (_add_sublayer(cfg, p, "ln2_post", x, y), kp, vp), load
+
+        def attention(h):
+            if cfg.kv_lora_rank:
+                q_nope, q_rope, latent = _mla_project(cfg, p, h, cos, sin)
+                pool = append_latent(kp, layer, latent, pos, page_table)
+                o = _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer,
+                                  pos + 1, page_table)
+                return jnp.einsum("bnh,nhd->bd", o,
+                                  p["attn"]["wo"].astype(dt)), (pool, vp)
+            q = jnp.einsum("bd,dnh->bnh", h, p["attn"]["wq"].astype(dt))
+            kv = jnp.einsum("bd,dcnh->bcnh", h,
+                            p["attn"]["wkv"].astype(dt))
+            k_new, v_new = kv[:, 0], kv[:, 1]            # [B, NKV, H]
+            q, k_new = _qk(cfg, p, q, k_new, cos, sin)
+            pools = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
+            o = paged_attention(q, *pools, layer, pos + 1, page_table)
+            return jnp.einsum("bnh,nhd->bd", o,
+                              p["attn"]["wo"].astype(dt)), pools
+
+        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
+        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
+            cfg, p, h, live, experts=experts))
+        return (x, kp, vp), load
 
     (x, k_pages, v_pages), load = _passes(
-        cfg, params, lambda carry, t: jax.lax.scan(
-            body, carry, (layers, _pool_layers(cfg, t))),
+        cfg, params, lambda carry, t: _scan_layers(
+            cfg, params, body, carry, t, served=True),
         (x, k_pages, v_pages))
     logits = jnp.einsum("bd,dv->bv", x,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
@@ -563,6 +969,11 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
             "models/llama.py serves its looped model (ut_steps > 1) but "
             "does not train it: the expectation over exit steps with its "
             "entropy term, and the exit gate it trains, are not written")
+    if cfg.hc_mult:
+        raise NotImplementedError(
+            "models/llama.py serves its hyper-connected model (hc_mult) "
+            "but does not train it: the widened stream's remat and "
+            "sharding are not written")
     if cfg.num_experts:
         raise NotImplementedError(
             "models/llama.py serves its expert model but does not train "

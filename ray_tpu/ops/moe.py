@@ -149,9 +149,36 @@ def moe_mlp(x, p, *, top_k: int, capacity_factor: float,
     return lc(y, ("batch", "seq", "embed")), aux
 
 
+def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
+           routed_scaling: float):
+    """A token's experts and their gates from the router's float32 logits
+    [T, E] -> (gates [T, k], experts [T, k]).  ``softmax``: the ``top_k``
+    largest of a softmax over all experts are the gates, as they are unless
+    ``norm_topk_prob`` renormalises them to sum to 1.  ``sigmoid``
+    (DeepSeek-V3's ``noaux_tc``): the scores are sigmoids; the experts are
+    the ``top_k`` largest of score + ``bias`` [E] (the balancing bias, which
+    only SELECTS); the gates are the chosen experts' scores WITHOUT it,
+    renormalised if ``norm_topk_prob`` and times ``routed_scaling``."""
+    if scoring == "softmax":
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       top_k)                    # [T, k]
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        return gates, experts
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(
+        scores if bias is None else scores + bias, top_k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return gates * routed_scaling, experts
+
+
 def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                  live: Optional[jax.Array] = None,
-                 layer: Optional[jax.Array] = None
+                 layer: Optional[jax.Array] = None,
+                 scoring: str = "softmax", routed_scaling: float = 1.0,
+                 shared: Optional[dict] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """Dropless top-k expert FFN over flat tokens.
 
@@ -162,12 +189,16 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     over the whole stack and the scan's index, because a layer's slice of
     it would be a copy of every expert's weights ahead of each step.
 
-    The gates are the ``top_k`` largest of a float32 softmax over all E
-    experts, used as they are unless ``norm_topk_prob`` renormalises them
-    to sum to 1.  ``live`` [T] bool marks the tokens that are somebody's
-    (not padding, not an idle decode slot); all of them when None.  Every
-    token is computed whatever ``live`` says: it only selects what is
-    counted.
+    The gates are ``_route``'s: the ``top_k`` largest of a float32 softmax
+    over all E experts, used as they are unless ``norm_topk_prob``
+    renormalises them to sum to 1, or with ``scoring="sigmoid"`` sigmoid
+    scores selected with ``p["router_bias"]`` [E] added (if the tree has
+    it) and scaled by ``routed_scaling``.  ``shared`` ({"wgu": [2, D, Ms],
+    "wd": [Ms, D]}, this layer's) is a SwiGLU expert that every token goes
+    through, ungated, added to the routed sum.  ``live`` [T] bool marks the
+    tokens that are somebody's (not padding, not an idle decode slot); all
+    of them when None.  Every token is computed whatever ``live`` says: it
+    only selects what is counted.
 
     Assignments are sorted by expert and the experts run as two grouped
     matmuls over the ragged groups: gate and up in one, over ``wgu`` seen
@@ -179,7 +210,8 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
 
     Returns (y [T, D] in x's type, load [E] int32: the live tokens'
     assignments per expert).  The parts carry the scopes ``moe_router``,
-    ``moe_dispatch``, ``moe_experts`` and ``moe_combine`` for the profiler.
+    ``moe_dispatch``, ``moe_experts``, ``moe_combine`` and ``moe_shared``
+    for the profiler.
     """
     T, D = x.shape
     A = T * top_k
@@ -202,10 +234,9 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             p["router"][layer].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                       top_k)                    # [T, k]
-        if norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        bias = p["router_bias"][layer] if "router_bias" in p else None
+        gates, experts = _route(logits, bias, top_k, scoring,
+                                norm_topk_prob, routed_scaling)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(A)                  # assignment -> expert
         chosen = flat[:, None] == jnp.arange(E)[None, :]         # [A, E]
@@ -242,4 +273,9 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         # scatter-add), then the gate-weighted sum of each token's k
         back = ys[jnp.argsort(order)].reshape(T, top_k, D)
         y = jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            gu = jnp.einsum("td,cdm->ctm", x, shared["wgu"])
+            y = y + jnp.einsum("tm,md->td", jax.nn.silu(gu[0]) * gu[1],
+                               shared["wd"]).astype(jnp.float32)
     return y.astype(x.dtype), load

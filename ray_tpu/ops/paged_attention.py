@@ -33,6 +33,31 @@ layer at a time (PERF.md, PR 29).  KV-head-major pools ``[L, NKV, P, page,
 H]`` compile to a whole-pool relayout before and after the scatter.  To
 shard the KV heads over a model axis, shard the last axis in whole heads.
 
+A second page kind, for latent attention (MLA; ``models/llama.py`` with
+``kv_lora_rank``): a position's cache is ONE row of ``W = rank + rope``
+values, the normed compressed key-value and the rotated key all heads share,
+so there is one pool and no V pool:
+
+    latent_pages     [L, P, page * W]    every layer's pool; a page's
+                                         positions side by side
+    q                [B, N, W]           a head's query with the key
+                                         expansion absorbed into it
+
+A page's positions are folded into its last axis because of what the TPU does
+with ``[L, P, page, 576]``: an array whose last axis is no multiple of the 128
+lanes is given another device layout (the page axis minor) and every program
+would copy the whole pool in and out of the layout it computes in (0.9 GB
+each way a step at Xing4.0's size; compiled for a described v5e, PERF.md PR
+34); 640 values a position would be aligned and 11% air.  ``page * W`` is a
+multiple of 128 for any even page size and holds exactly W values a position.
+``append_latent`` / ``prefill_latent`` scatter rows as ``append_kv`` /
+``prefill_kv`` do (a prompt's, a page at a time), and
+``paged_latent_attention`` scores every head against the one row of a
+position and weighs the rows: it returns attention over the COMPRESSED
+values (and, beside them, over the rotated key's columns, which mean
+nothing), which the caller expands.  Page 0, the page table
+and the layer index mean what they mean above.
+
 This file is the jnp reference implementation (gather + masked softmax
 — the decode working set is one token per sequence, so XLA's fused
 gather is adequate on CPU and fine on TPU at small batch; a Pallas
@@ -135,3 +160,65 @@ def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,
         v_seq = _folded(jnp.swapaxes(v_seq, 0, 1), v_pages)
         return (k_pages.at[layer, pid, slot].set(k_seq),
                 v_pages.at[layer, pid, slot].set(v_seq))
+
+
+def append_latent(pages: jax.Array, layer: jax.Array, new: jax.Array,
+                  pos: jax.Array, page_table: jax.Array) -> jax.Array:
+    """Scatter one position's latent row per sequence into one layer of the
+    pool: ``new`` [B, W]; ``pos`` [B]; ``page_table`` [B, maxp]; ``pages``
+    [L, P, page * W], the row of position ``pos`` at ``(pos % page) * W`` of
+    its page.  Inactive slots park on page 0, as in ``append_kv``."""
+    W = new.shape[1]
+    page = pages.shape[2] // W
+    with jax.named_scope("latent_append"):
+        pid = jnp.take_along_axis(page_table, (pos // page)[:, None],
+                                  axis=1)[:, 0]                  # [B]
+        at = jnp.stack([jnp.full_like(pid, layer), pid, (pos % page) * W],
+                       axis=1)
+        return jax.lax.scatter(
+            pages, at, new.astype(pages.dtype),
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(0, 1),
+                scatter_dims_to_operand_dims=(0, 1, 2)),
+            unique_indices=True)
+
+
+def prefill_latent(pages: jax.Array, layer: jax.Array, seq: jax.Array,
+                   length: jax.Array, page_table_row) -> jax.Array:
+    """Scatter a whole (padded) prompt's latent rows ``seq`` [S, W] of ONE
+    sequence into one layer of the pool, a page at a time (S is a multiple
+    of the page size).  Pages wholly past ``length`` go to scratch page 0;
+    the padding that shares the prompt's last page lands in the slots the
+    sequence's next positions will overwrite before any step reads them."""
+    S, W = seq.shape
+    page = pages.shape[2] // W
+    with jax.named_scope("latent_append"):
+        first = jnp.arange(S // page) * page        # a page's first position
+        pid = jnp.where(first < length, page_table_row[:S // page], 0)
+        return pages.at[layer, pid].set(
+            seq.reshape(S // page, page * W).astype(pages.dtype))
+
+
+def paged_latent_attention(q: jax.Array, pages: jax.Array, layer: jax.Array,
+                           lengths: jax.Array, page_table: jax.Array, *,
+                           sm_scale: float) -> jax.Array:
+    """Single-token decode attention against one layer of the latent pool,
+    read as it lies.  ``q`` [B, N, W] (W = rank + rope: the absorbed query
+    beside the rotated one); ``pages`` [L, P, page * W]; positions < length
+    attend.  Every head scores against the same row of a position and the
+    probabilities weigh the rows.  Returns [B, N, W] in q's dtype, attention
+    over WHOLE rows: the first ``rank`` columns are the compressed values'
+    and the caller's to expand, the rest (the rotated key's) mean nothing
+    and are the caller's to pass over, because a slice here, of the result
+    or of the rows, is turned by the compiler into a copy of every gathered
+    row.  Softmax runs in f32."""
+    B, _, W = q.shape
+    S = page_table.shape[1] * pages.shape[2] // W
+    with jax.named_scope("latent_read"):
+        rows = pages[layer, page_table].reshape(B, S, W)
+        scores = jnp.einsum("bnw,bsw->bns", q, rows) * sm_scale
+        valid = jnp.arange(S)[None] < lengths[:, None]           # [B, S]
+        scores = jnp.where(valid[:, None], scores.astype(jnp.float32),
+                           -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bns,bsw->bnw", probs, rows)

@@ -67,8 +67,17 @@ pools (no live sequence is left to own a page).  ``stats()`` says whether
 each program's first loop call did come back in its argument's buffers
 (``kv_pool_in_place``).
 
-``paged_attention`` gathers every page a slot may use, whatever the live
-length, once per pool layer.  ``stats()`` counts both sides of that:
+A model with latent attention (a ``LlamaConfig`` with ``kv_lora_rank``)
+caches one row a position a layer, so its pages are of another kind: ONE
+pool ``[L, P, page * (kv_lora_rank + qk_rope_dim)]``, and ``_v_pages`` is
+None.  The kind follows from the model (``llama_init_paged_cache``), nothing
+configures it; the programs take and return the pair of pools either way, so
+the loop, the donation, the views and the counters below are the same code
+for both kinds.  ``stats()["kv_page_kind"]`` says which.
+
+``paged_attention`` (a latent model's ``paged_latent_attention`` alike)
+gathers every page a slot may use, whatever the live length, once per pool
+layer.  ``stats()`` counts both sides of that:
 ``kv_live_token_steps`` (positions the live sequences held, summed over
 decode steps) against ``kv_gathered_token_steps`` (``max_batch x maxp x
 page_size`` a step), with ``kv_bytes_per_token`` and ``kv_pool_layers`` to
@@ -228,9 +237,11 @@ class InferenceEngine:
             init_fn(jax.random.PRNGKey(rng_seed), mc), mc)
         self._weight_bytes = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self._params))
+        # The model's kind of pages: K and V pools, or one pool of latent
+        # pages and None where the V pool would be (models/llama.py).
         self._new_pools = cache_fn
         self._k_pages, self._v_pages = cache_fn()
-        self._kv_pool_bytes = self._k_pages.nbytes + self._v_pages.nbytes
+        self._kv_pool_bytes = sum(p.nbytes for p in self._pools())
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
@@ -298,6 +309,13 @@ class InferenceEngine:
         self._exec = concurrent.futures.ThreadPoolExecutor(
             1, thread_name_prefix="rt-engine")
 
+    def _pools(self) -> List[Any]:
+        """The pool arrays the engine holds: two, or a latent model's one."""
+        return [p for p in (self._k_pages, self._v_pages) if p is not None]
+
+    def _pools_deleted(self) -> bool:
+        return any(p.is_deleted() for p in self._pools())
+
     # ------------------------------------------------------------- public
 
     async def generate(self, tokens: Sequence[int],
@@ -363,7 +381,9 @@ class InferenceEngine:
         single-expert load, summed likewise: over ``moe_assignments /
         num_experts``, how uneven the routing was).  ``weight_bytes`` is
         the size of the parameters as the engine stores them,
-        ``kv_pool_bytes`` that of the K and V pools, ``kv_pool_layers``
+        ``kv_pool_bytes`` that of the K and V pools or, where
+        ``kv_page_kind`` is "latent" and not "kv", of the one pool of latent
+        pages, ``kv_pool_layers``
         their leading dimension (a layer for every pass of a looped model)
         and ``kv_bytes_per_token`` what one cached position takes in all of
         them, and ``kv_pool_in_place`` says of each program ("prefill",
@@ -387,6 +407,8 @@ class InferenceEngine:
                 "retired": dict(self._retired), **self._moe,
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
+                "kv_page_kind": "kv" if self._v_pages is not None
+                else "latent",
                 "kv_pool_layers": self._k_pages.shape[0],
                 "kv_bytes_per_token": self._kv_pool_bytes // (
                     self.config.num_pages * self.config.page_size),
@@ -411,8 +433,9 @@ class InferenceEngine:
 
     @staticmethod
     def _on_copies(step, params, a, b, kp, vp, pt):
+        import jax
         import jax.numpy as jnp
-        return step(params, a, b, jnp.copy(kp), jnp.copy(vp), pt)
+        return step(params, a, b, *jax.tree.map(jnp.copy, (kp, vp)), pt)
 
     def _prefill_program(self, *args):
         return self._on_copies(self._prefill_donating, *args)
@@ -430,8 +453,7 @@ class InferenceEngine:
         try:
             logits, kp, vp = step(params, a, b, kp, vp, pt)[:3]
         except Exception:
-            if own and (self._k_pages.is_deleted()
-                        or self._v_pages.is_deleted()):
+            if own and self._pools_deleted():
                 self._k_pages, self._v_pages = self._new_pools()
             raise
         if own:
@@ -465,10 +487,10 @@ class InferenceEngine:
         kp, vp = self._k_pages, self._v_pages
         if program in self._kv_in_place:
             return step(self._params, a, b, kp, vp, pt)
-        before = [p.unsafe_buffer_pointer() for p in (kp, vp)]
+        before = [p.unsafe_buffer_pointer() for p in self._pools()]
         out = step(self._params, a, b, kp, vp, pt)
         self._kv_in_place[program] = before == [
-            p.unsafe_buffer_pointer() for p in out[1:3]]
+            p.unsafe_buffer_pointer() for p in out[1:3] if p is not None]
         return out
 
     def _ensure_loop(self):
@@ -715,7 +737,7 @@ class InferenceEngine:
                     seq.queue.put_nowait(e)
                 while self._waiting:
                     self._waiting.popleft().queue.put_nowait(e)
-                if self._k_pages.is_deleted() or self._v_pages.is_deleted():
+                if self._pools_deleted():
                     # the call that failed had been given the pools; every
                     # sequence that owned a page of them is retired above
                     self._k_pages, self._v_pages = self._new_pools()

@@ -30,8 +30,11 @@ pages.
 * *Mid-stream failover.*  The ingress records each live stream's request
   payload and the items already delivered to the client.  When the
   serving replica dies (ActorDiedError from the stream) or stalls past
-  ``RT_SERVE_STALL_S``, the ingress cancels the broken stream, picks a
-  healthy replica, and resumes: for token-generation payloads
+  ``RT_SERVE_STALL_S`` (a stream that has yielded nothing yet is left
+  alone past that, up to ``RT_SERVE_STREAM_IDLE_S``, only while its
+  replica says that requests wait for a decode slot and its engine takes
+  steps: queued, not stalled), the ingress cancels the broken stream,
+  picks a healthy replica, and resumes: for token-generation payloads
   (``{"tokens": [...], "max_new_tokens": N}``) it re-prefills
   ``prompt + delivered`` with the remaining token budget — under greedy
   decoding the resumed tail is bit-identical to an uninterrupted run —
@@ -300,6 +303,30 @@ class HTTPIngress:
                 f"deployment {name} has no routable replica "
                 "(all ejected or excluded)")
         return picked
+
+    async def _queued_behind_work(self, name: str, rid) -> bool:
+        """Whether replica ``rid``, whose stream has yielded nothing for a
+        stall window, holds that request in a queue that moves.  The layer
+        that owns the queue says: two readings of the handler's ``stats()``
+        a moment apart must show requests waiting for a decode slot and
+        the engine's step count rising.  A handler without such ``stats``,
+        a replica that does not answer, an empty queue or an engine that
+        stands still is a stall."""
+        replica = next((r for r in self._replicas.get(name) or ()
+                        if r._actor_id == rid), None)
+        if replica is None:
+            return False
+        try:
+            seen = []
+            for pause in (min(1.0, self._stall_s / 4), 0.0):
+                seen.append(await asyncio.wait_for(
+                    replica.handle_request.remote([], {}, "stats", None),
+                    min(5.0, self._stall_s)))
+                await asyncio.sleep(pause)
+            return seen[1]["waiting"] > 0 and \
+                seen[1]["steps"] > seen[0]["steps"]
+        except Exception:   # noqa: BLE001
+            return False
 
     def _expired(self, deadline: Optional[float]) -> bool:
         rem = resilience.deadline_remaining(deadline)
@@ -580,6 +607,8 @@ class HTTPIngress:
                 headers_sent = True
             resumed = bool(delivered)
             got_any = False
+            started = time.monotonic()
+            nxt = None      # the pending __anext__, kept across a timeout
             try:
                 while True:
                     rem = resilience.deadline_remaining(deadline)
@@ -589,7 +618,11 @@ class HTTPIngress:
                         # Each stream item is a per-yield ObjectRef (the
                         # generator owner side of num_returns="streaming");
                         # awaiting the ref materializes the token.
-                        item = await asyncio.wait_for(gen.__anext__(), wait)
+                        nxt = nxt or asyncio.ensure_future(gen.__anext__())
+                        done, _ = await asyncio.wait({nxt}, timeout=wait)
+                        if not done:
+                            raise asyncio.TimeoutError
+                        item, nxt = nxt.result(), None
                         item = await asyncio.wait_for(
                             _materialize(item), wait)
                     except StopAsyncIteration:
@@ -602,6 +635,17 @@ class HTTPIngress:
                             gen.cancel()
                             return await fail(
                                 504, "request deadline expired mid-stream")
+                        # Nothing yet, and the layer that owns the queue
+                        # says why: every decode slot taken, the engine
+                        # at work on other answers.  That ends when one
+                        # of them does, which can be many stall windows
+                        # away; the terminal bound still holds.
+                        if (nxt is not None and not got_any
+                                and time.monotonic() - started
+                                < self._stream_idle
+                                and await self._queued_behind_work(
+                                    target, rid)):
+                            continue
                         # Stalled replica: treat like a death and fail
                         # the stream over.
                         raise resilience.DecodeStalled(
@@ -647,6 +691,9 @@ class HTTPIngress:
                     target, rid[:8], e, len(delivered))
                 await asyncio.sleep(policy.next_backoff_s(deadline))
                 continue
+            finally:
+                if nxt is not None:
+                    nxt.cancel()
 
     async def _write_event(self, writer, event: Optional[str], data):
         payload = (f"event: {event}\n" if event else "") + \

@@ -290,3 +290,81 @@ def test_a_dense_model_counts_nothing():
     assert len(tokens) == 3
     assert (stats["moe_assignments"], stats["moe_experts_hit"],
             stats["moe_load_max"]) == (0, 0, 0)
+
+
+# ------------------------------------------- sigmoid routing, shared expert
+
+def sigmoid_experts(x, p, top_k, renorm, scale, shared=None):
+    """Every expert on every token under DeepSeek-V3's ``noaux_tc`` routing,
+    in float64: sigmoid scores, the ``top_k`` largest of score + bias chosen,
+    gates the chosen scores WITHOUT the bias (renormalised, scaled), and the
+    shared expert's output added ungated."""
+    x, p = np.asarray(x, np.float64), jax.tree.map(
+        lambda a: np.asarray(a, np.float64), p)
+    scores = 1 / (1 + np.exp(-(x @ p["router"])))
+    biased = scores + p.get("router_bias", 0.0)
+    kth = np.sort(biased, -1)[:, -top_k][:, None]
+    gates = np.where(biased >= kth, scores, 0.0)
+    if renorm:
+        gates /= gates.sum(-1, keepdims=True) + 1e-20
+    gates *= scale
+
+    def swiglu(wgu, wd):
+        gate, up = x @ wgu[0], x @ wgu[1]
+        return (gate / (1 + np.exp(-gate)) * up) @ wd
+    each = np.stack([swiglu(g, d) for g, d in zip(p["wgu"], p["wd"])], 1)
+    y = np.einsum("ted,te->td", each, gates)
+    if shared is not None:
+        y = y + swiglu(*(np.asarray(shared[k], np.float64)
+                         for k in ("wgu", "wd")))
+    return y, gates > 0
+
+
+@pytest.mark.parametrize("renorm,scale,bias,shared", [
+    (True, 2.0, True, True), (False, 1.0, True, False),
+    (True, 1.0, False, True), (False, 2.5, False, False)])
+def test_sigmoid_routing_with_bias_and_a_shared_expert(renorm, scale, bias,
+                                                       shared):
+    p = experts_params(jax.random.PRNGKey(20))
+    if bias:     # large enough to change which experts are chosen
+        p["router_bias"] = 0.3 * jax.random.normal(jax.random.PRNGKey(21),
+                                                   (E,))
+    extra = {"wgu": 0.3 * jax.random.normal(jax.random.PRNGKey(22),
+                                            (2, D, 2 * M)),
+             "wd": 0.3 * jax.random.normal(jax.random.PRNGKey(23),
+                                           (2 * M, D))} if shared else None
+    x = jax.random.normal(jax.random.PRNGKey(24), (23, D))
+    y, load = moe_dropless(x, p, top_k=K, norm_topk_prob=renorm,
+                           scoring="sigmoid", routed_scaling=scale,
+                           shared=extra)
+    want, chosen = sigmoid_experts(x, p, K, renorm, scale, extra)
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load), chosen.sum(0))
+    if bias:     # the bias selects, and is not in the gates
+        unbiased = {k: v for k, v in p.items() if k != "router_bias"}
+        _, plain = sigmoid_experts(x, unbiased, K, renorm, scale, extra)
+        assert (plain != chosen).any()
+    if renorm:   # the gates of a token sum to the scaling factor
+        _, load_one = moe_dropless(x[:1], p, top_k=K, scoring="sigmoid")
+        assert int(load_one.sum()) == K
+
+
+def test_bfloat16_experts_are_multiplied_as_they_are_stored():
+    """Stored in bfloat16 the experts are read in bfloat16 (the activations
+    cast to them, never the experts to the activations), and the result is
+    that of the rounded experts to bfloat16's precision."""
+    p = experts_params(jax.random.PRNGKey(30))
+    stored = {**p, "wgu": p["wgu"].astype(jnp.bfloat16),
+              "wd": p["wd"].astype(jnp.bfloat16)}
+    x = jax.random.normal(jax.random.PRNGKey(31), (19, D))
+    jaxpr = str(jax.make_jaxpr(
+        lambda x, p: moe_dropless(x, p, top_k=K))(x, stored))
+    dots = [line for line in jaxpr.splitlines()
+            if "= ragged_dot_general[" in line]
+    assert len(dots) == 2 and all(":bf16[" in d for d in dots)
+    y, load = moe_dropless(x, stored, top_k=K)
+    assert y.dtype == x.dtype
+    rounded = jax.tree.map(lambda a: a.astype(jnp.float32), stored)
+    want, chosen = all_experts(x, rounded, K)
+    assert np.linalg.norm(np.asarray(y) - want) / np.linalg.norm(want) < 2e-2
+    np.testing.assert_array_equal(np.asarray(load), chosen.sum(0))

@@ -224,6 +224,109 @@ def test_resume_payload_token_math():
     assert skip == 1
 
 
+class _Writer:
+    def __init__(self):
+        self.sent = b""
+
+    def write(self, data):
+        self.sent += data
+
+    async def drain(self):
+        pass
+
+
+class _Gen:
+    """Yields ``items`` after ``first`` seconds, ``gap`` apart."""
+    def __init__(self, items, first, gap):
+        self.items, self.first, self.gap = list(items), first, gap
+        self.cancelled = False
+
+    async def __anext__(self):
+        if not self.items:
+            raise StopAsyncIteration
+        await asyncio.sleep(self.first if self.first else self.gap)
+        self.first = 0
+        return self.items.pop(0)
+
+    def cancel(self):
+        self.cancelled = True
+
+
+class _Replica:
+    """A replica handle whose handler's ``stats()`` reads ``waiting``
+    queued requests and a step count that rises by ``pace`` a reading."""
+    def __init__(self, waiting, pace):
+        self._actor_id = "replica-1"
+        self.waiting, self.pace, self.steps = waiting, pace, 100
+        self.handle_request = self
+
+    def remote(self, args, kwargs, method, deadline):
+        assert method == "stats"
+        self.steps += self.pace
+
+        async def answer():
+            return {"waiting": self.waiting, "steps": self.steps}
+        return answer()
+
+
+def _stream_through_ingress(first, gap, replica):
+    """One stream of three items through an ingress with a stall window of
+    0.2 s, its one replica answering ``stats()`` as ``replica`` does:
+    (bytes sent to the client, the generators opened)."""
+    from ray_tpu.serve import http_ingress
+
+    async def run():
+        ing = HTTPIngress(stall_timeout_s=0.2, stream_idle_timeout_s=5.0)
+        ing._replicas["llm"] = [replica]
+        gens = []
+
+        async def call_stream(name, payload, deadline, exclude):
+            if exclude:
+                raise http_ingress._Unavailable("no other replica")
+            gens.append(_Gen([5, 6, 7], first, gap))
+            return gens[-1], replica._actor_id
+        ing._call_stream = call_stream
+        writer = _Writer()
+        await ing._dispatch_stream(writer, "llm", {"tokens": [1],
+                                                   "max_new_tokens": 3})
+        return writer.sent, gens
+    return asyncio.run(run())
+
+
+def test_a_queued_stream_is_not_a_stalled_one():
+    """A stream that has yielded nothing for a stall window is left alone
+    while its replica says that requests wait for a decode slot and its
+    engine takes steps: queued behind long answers, not wedged."""
+    sent, gens = _stream_through_ingress(      # queued 3.5 windows
+        first=0.7, gap=0.01, replica=_Replica(waiting=8, pace=3))
+    assert len(gens) == 1 and not gens[0].cancelled
+    assert sent.count(b"data: ") == 4 and b"event: end" in sent
+    assert b"event: error" not in sent
+
+
+@pytest.mark.parametrize("waiting,pace", [(8, 0), (0, 3)])
+def test_a_replica_wedged_before_its_first_token_is_failed_over(
+        waiting, pace):
+    """Nothing yielded for a stall window and the replica's engine stands
+    still (a wedged prefill or compile, whatever is queued behind it), or
+    nothing is queued there: a stall, failed over after ONE window, not
+    after the terminal idle bound."""
+    began = time.monotonic()
+    sent, gens = _stream_through_ingress(
+        first=3.0, gap=0.01, replica=_Replica(waiting, pace))
+    assert time.monotonic() - began < 1.5
+    assert gens[0].cancelled and b"event: error" in sent
+    assert b"data: 5" not in sent and b"event: end" not in sent
+
+
+def test_a_stream_quiet_between_items_is_failed_over():
+    """Between two items the stall window applies whatever is queued."""
+    sent, gens = _stream_through_ingress(
+        first=0.01, gap=0.7, replica=_Replica(waiting=8, pace=3))
+    assert gens[0].cancelled and b"event: error" in sent
+    assert b"data: 5" in sent and b"event: end" not in sent
+
+
 def test_ingress_controller_reresolve_backoff():
     """Controller loss backs off exponentially (capped) instead of
     hammering the GCS with a lookup per request."""

@@ -224,14 +224,16 @@ def test_moe_train_step_compiles(topo):
 POOLS = (3, 4)      # the pools among a paged program's arguments: donated
 
 
-def _pools_in_place(compiled, text, kp):
+def _pools_in_place(compiled, text, kp, pools=2):
     """The engine's program, compiled as the engine compiles it (pools
-    donated), updates the pools where they lie: both are aliased to the
-    results, and no instruction copies, slices or update-slices a buffer of
-    a whole pool's or one layer's pool's size (by its own opcode or as the
-    fusion the compiler names for it)."""
+    donated), updates the pools where they lie: both (a latent model's
+    one: ``pools``) are aliased to the results, and no instruction copies,
+    slices or update-slices a buffer of a whole pool's or one layer's
+    pool's size (by its own opcode or as the fusion the compiler names for
+    it)."""
     pool_bytes = kp.size * kp.dtype.itemsize
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        pools * pool_bytes
     moved = []
     for line in text.splitlines():
         found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]+)\]\S* "
@@ -242,9 +244,23 @@ def _pools_in_place(compiled, text, kp):
         count = math.prod(int(d) for d in dims.split(","))
         if count in (kp.size, kp.size // kp.shape[0]) and re.search(
                 r"copy|dynamic-slice|dynamic-update-slice",
-                name if opcode == "fusion" else opcode):
+                name if opcode == "fusion" else opcode) \
+                and not (pools == 1
+                         and _writes_a_sliver(text, line, opcode)):
             moved.append(line.strip()[:160])
     assert moved == []
+
+
+def _writes_a_sliver(text, line, opcode) -> bool:
+    """A ``dynamic-update-slice`` of the pool whose update is a position's
+    row (a latent model's append is a loop of them): written where the pool
+    lies, its result the pool's own buffer."""
+    update = re.search(r"dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+),",
+                       line)
+    made = update and opcode == "dynamic-update-slice" and re.search(
+        rf"%{re.escape(update.group(1))} = \w+\[([\d,]+)\]", text)
+    return bool(made) and math.prod(
+        int(d) for d in made.group(1).split(",")) <= 4096
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -464,6 +480,90 @@ def test_ouro_weights_are_made_as_the_stored_tree(topo):
     entry = text[text.index("\nENTRY "):]
     stacks = set(re.findall(r" = f32\[48,[\d,]*\]", entry))
     assert stacks == {" = f32[48,2048]"}, stacks
+
+
+# Xing4.0-29B-A4B at its published widths, the leading dense layer and five of
+# its 38 expert layers, with the engine of
+# benchmark/configs/xing4.0-29b-a4b-6l.json (32 slots of 4,096 positions): ONE
+# pool of latent pages [6, 8193, 16 x 576], 0.906 GB, beside 9.6 GB of stored
+# weights (bf16 matrices, the 64 routed experts among them).  Compiled sizes
+# (PERF.md, PR 34): decode 10.10 GiB, prefill at the 1024 rung 9.94 GiB.
+XING_PROMPT, XING_NEW, XING_BATCH = 1024, 3072, 32
+XING_BUDGET = int(10.5 * 1024 ** 3)
+
+
+def _xing():
+    from benchmark import spec
+    config = spec.load_json("configs", "xing4.0-29b-a4b-6l.json")
+    family = spec.load_part("families", "xing")
+    return family, family.program_config(config, XING_PROMPT + XING_NEW)
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill@256", "decode"])
+def test_xing_engine_program_compiles(topo, program):
+    from ray_tpu.models.llama import (llama_decode_step,
+                                      llama_init_paged_cache, llama_prefill)
+    family, cfg = _xing()
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: family.init(jax.random.PRNGKey(0), cfg)))
+    kp, vp = jax.eval_shape(lambda: llama_init_paged_cache(
+        cfg, XING_BATCH * 256 + 1, PAGE))
+    assert vp is None and kp.shape == (6, 8193, PAGE * 576)
+    kp = _on(one, kp)
+    maxp = (XING_PROMPT + XING_NEW) // PAGE
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    if program == "decode":
+        compiled, text = _compile(
+            lambda p, *a: llama_decode_step(p, cfg, *a), params,
+            arg((XING_BATCH,)), arg((XING_BATCH,)), kp, None,
+            arg((XING_BATCH, maxp)), donate=POOLS)
+    else:
+        rung = int(program.partition("@")[2] or XING_PROMPT)
+        compiled, text = _compile(
+            lambda p, *a: llama_prefill(p, cfg, *a), params,
+            arg((1, rung)), arg(()), kp, None, arg((1, maxp)), donate=POOLS)
+    _pools_in_place(compiled, text, kp, pools=1)
+    # the pool is the program's parameter in the layout it computes in: no
+    # relayout of it on the way in or out (a last axis of 576 had one each)
+    assert f"bf16[6,8193,{PAGE * 576}]{{2,1,0:" in text
+    assert "bf16[6,8193,16,576]" not in text
+    assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
+    assert params["layers"]["hc_mlp"]["proj"].dtype == jnp.float32
+    assert "convert(%p__" not in text
+    scopes = ["latent_append", "hc_coeff", "hc_mix", "moe_router",
+              "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"]
+    if program == "decode":
+        scopes += ["latent_read", "mla_absorb"]
+    for scope in scopes:
+        assert _scoped(text, scope), scope
+    # two grouped matmuls an expert layer, on the stacked bf16 experts of
+    # the five expert layers, where they lie
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
+    assert len(calls) == 2
+    assert all("bf16[640,3584,1024]" in c or "bf16[320,1024,3584]" in c
+               for c in calls)
+    assert _fits(compiled) < XING_BUDGET
+
+
+def test_xing_weights_are_made_as_the_stored_tree(topo):
+    """The family's ``init`` inside one jit: 9.6 GB come out and no f32
+    stack of matrices is held on the way (made f32 first they are 19 GB)."""
+    family, cfg = _xing()
+    one = SingleDeviceSharding(topo.devices[0])
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    compiled, _ = _compile(lambda k: family.init(k, cfg), key)
+    memory = compiled.memory_analysis()
+    stored = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                 for leaf in jax.tree.leaves(jax.eval_shape(
+                     lambda: family.init(jax.random.PRNGKey(0), cfg))))
+    assert 9.55e9 < stored < 9.65e9
+    assert memory.output_size_in_bytes < stored * 1.001
+    assert memory.temp_size_in_bytes < 64 * 1024 ** 2
 
 
 # ---------------------------------------------------------------- four chips
