@@ -65,6 +65,19 @@ HBM-resident kernel like flash_attention.py's is the upgrade path when
 pools outgrow VMEM).  It is exact: given identical page contents it
 reproduces dense attention bit-for-bit in f32, which is what the
 paged-vs-dense CPU equivalence tests assert.
+
+Both reads gather every column of the ``page_table`` they are given, for
+every sequence, and mask by ``lengths``: the table's width, not the lengths,
+sets their cost.  The caller chooses it: the serving engine hands a step the
+first ``W`` columns of its rows, ``W`` the least of a few compiled widths
+that holds the batch's longest sequence (``serve/engine/engine.py``,
+``decode_rungs``); the page of every ``pos`` a step appends at and of every
+position under ``lengths`` has to lie inside the table.  The columns left
+out held positions whose probability is exactly 0, so a narrower table gives
+a wider one's result up to the order of a shorter sum.  The bound is the
+longest sequence of the batch, not each sequence's own: that, and reading the
+rows where they lie with no relayout, is what a kernel that walks the page
+table would add.
 """
 
 from __future__ import annotations

@@ -26,12 +26,22 @@ derived from ``max_prompt_len`` and ``page_size`` alone, nothing configures
 it, and a ``max_prompt_len`` of 128 or less has the one rung.  Padding lies
 after the prompt under a causal mask and its K/V go to scratch page 0, so a
 shorter rung gives the logits and pages of a longer one.  Every rung's
-program is compiled while the engine is constructed, each on a thread of its
-own, and the loop admits nobody before all of them are there: no request
-meets a compile.  Decode runs the whole batch (fixed shape [max_batch]) with
-inactive slots parked on scratch page 0, jitted once.  Dispatches run on a
-single-thread executor so the actor's event loop keeps serving admissions
-and cancellations while XLA computes.
+program is compiled while the engine is constructed, a few at a time on
+threads of their own, and the loop admits nobody before all of them are
+there: no request meets a compile.  Decode runs the whole batch (fixed shape
+[max_batch]) with inactive slots parked on scratch page 0, against a page
+table as wide as the batch's longest live sequence needs and no wider:
+``[max_batch, W]`` with ``W`` the least rung of a second ladder
+(``decode_rungs``: multiples of ``ceil(maxp / 4)`` pages and ``maxp``, the
+reservation's width, on top) that holds the page of the largest ``pos``.
+That ladder follows from ``maxp`` alone; its programs are compiled beside
+the prefill rungs' and awaited with them.  A row keeps its pages in order, so its first ``W`` columns are the
+sequence's first ``W`` pages, and what a narrower table leaves out is
+positions that the step would have masked to a weight of exactly 0: a narrow
+rung gives the top rung's logits (to the rounding of a shorter sum) and
+pools.  A batch whose longest sequence fills its reservation takes the top
+rung.  Dispatches run on a single-thread executor so the actor's event loop
+keeps serving admissions and cancellations while XLA computes.
 
 The parameters are stored once in the dtype the two programs read them in
 (``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
@@ -76,13 +86,15 @@ the loop, the donation, the views and the counters below are the same code
 for both kinds.  ``stats()["kv_page_kind"]`` says which.
 
 ``paged_attention`` (a latent model's ``paged_latent_attention`` alike)
-gathers every page a slot may use, whatever the live length, once per pool
-layer.  ``stats()`` counts both sides of that:
+gathers every page of the table it is given, for every slot, once per pool
+layer: the step's rung, bounded by the longest live sequence and not by
+each.  ``stats()`` counts both sides of that:
 ``kv_live_token_steps`` (positions the live sequences held, summed over
-decode steps) against ``kv_gathered_token_steps`` (``max_batch x maxp x
-page_size`` a step), with ``kv_bytes_per_token`` and ``kv_pool_layers`` to
-turn either into bytes; each ``rt:engine.decode.dispatch`` carries its
-step's two numbers as ``live_tokens`` and ``gathered_tokens``.
+decode steps) against ``kv_gathered_token_steps`` (``max_batch x W x
+page_size`` a step, ``W`` the step's rung), with ``kv_bytes_per_token`` and
+``kv_pool_layers`` to turn either into bytes, and ``decode_shapes``, the
+steps by rung; each ``rt:engine.decode.dispatch`` carries its step's numbers
+as ``live_tokens``, ``gathered_tokens`` and ``width_pages``.
 
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
@@ -125,6 +137,12 @@ from ray_tpu.util.tracing import region
 logger = logging.getLogger(__name__)
 
 _DONE = object()
+# Threads that compile the rungs' programs at construction.  A program read
+# from the compile cache is mostly the interpreter's time (trace, lower), so
+# more threads than a few only contend: twelve programs of the largest model
+# served were there after 30.5 s on twelve threads, 12.7 s on three (PERF.md,
+# PR 35); cold, a few compiles side by side already fill the host's cores.
+_COMPILE_THREADS = 4
 
 
 @dataclasses.dataclass
@@ -158,9 +176,24 @@ def prefill_rungs(max_prompt_len: int, page_size: int) -> Tuple[int, ...]:
         width *= 2
 
 
-def rung_for(rungs: Sequence[int], prompt_len: int) -> int:
-    """The least rung that holds ``prompt_len`` positions."""
-    return rungs[bisect.bisect_left(rungs, prompt_len)]
+def decode_rungs(maxp: int) -> Tuple[int, ...]:
+    """The page-table widths, in pages, a decode step is compiled for,
+    rising: multiples of ``ceil(maxp / 4)`` below ``maxp``, and ``maxp``
+    itself (at most four programs).  A step runs at the least that holds
+    the batch's longest live sequence (``rung_for``), so its paged read does
+    not gather pages that nobody has reached.  Equal steps and not doubling:
+    the longest of many live sequences sits in the upper half of ``maxp``
+    most of the time, where doubling has one rung.  Four and not eight: a
+    rung is a program to trace, lower and load before the first admission,
+    about a second each for the largest model served (PERF.md, PR 35)."""
+    step = -(-maxp // 4)
+    return (*range(step, maxp, step), maxp)
+
+
+def rung_for(rungs: Sequence[int], need: int) -> int:
+    """The least rung that holds ``need`` (a prompt's positions on the
+    prefill ladder, a batch's pages on the decode ladder)."""
+    return rungs[bisect.bisect_left(rungs, need)]
 
 
 class _Sequence:
@@ -246,11 +279,12 @@ class InferenceEngine:
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
 
-        # Shapes fixed ([max_batch] decode, one compile; [1, rung] prefill,
-        # one compile a rung of the ladder derived from max_prompt_len),
-        # so the steady-state loop never re-traces.  The parameters are
-        # arguments, not closed over: as constants they would be part of the
-        # program and of its compile-cache key, one copy per entry point.
+        # Shapes fixed ([1, rung] prefill, one compile a rung of the ladder
+        # derived from max_prompt_len; [max_batch] decode with a table of
+        # [max_batch, rung], one compile a rung of the ladder derived from
+        # maxp), so the steady-state loop never re-traces.  The parameters
+        # are arguments, not closed over: as constants they would be part of
+        # the program and of its compile-cache key, one copy per entry point.
         # Both donate the pools (module docstring).
         def _prefill(params, tokens, length, kp, vp, pt):
             return prefill_fn(params, mc, tokens, length, kp, vp, pt)
@@ -263,28 +297,34 @@ class InferenceEngine:
         self._kv_in_place: Dict[str, bool] = {}
         # What stats() says about where this engine runs: the device that
         # holds the KV pool, and how long each program took to be there
-        # (a prefill rung's trace + compile or cache load; decode's first
-        # dispatch, its run included).
+        # (a rung's trace + compile or cache load).
         dev = next(iter(self._k_pages.devices()))
         self._device = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": jax.device_count()}
         self._first_call_s: Dict[str, float] = {}
         # The loop's prefill programs: ``_prefill_donating`` compiled for
-        # every rung, now, a thread a rung (the compiles are the compiler's
-        # time, not the interpreter's), so whatever the caller does between
-        # construction and its first request hides them.  The views keep
-        # the jitted function, which takes any [1, S] and any tree.
+        # every rung, now, on a few threads, so whatever the caller does
+        # between construction and its first request hides them.  The views
+        # keep the jitted function, which takes any [1, S] and any tree.
+        # The loop's decode programs likewise: ``_decode_donating``
+        # compiled for every width of the decode ladder.
         self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
+        self._decode_rungs = decode_rungs(self._maxp)
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self._params, self._k_pages, self._v_pages))
         pool = concurrent.futures.ThreadPoolExecutor(
-            len(self._rungs), thread_name_prefix="rt-engine-compile")
+            min(_COMPILE_THREADS, len(self._rungs) + len(self._decode_rungs)),
+            thread_name_prefix="rt-engine-compile")
         self._rung_programs = {
             rung: pool.submit(self._compile_rung, rung, *shapes)
             for rung in self._rungs}
+        self._decode_programs = {
+            width: pool.submit(self._compile_decode_rung, width, *shapes)
+            for width in self._decode_rungs}
         pool.shutdown(wait=False)    # the threads end with their compiles
         self._prefill_shapes = dict.fromkeys(self._rungs, 0)
+        self._decode_shapes = dict.fromkeys(self._decode_rungs, 0)
 
         self._waiting: collections.deque = collections.deque()
         self._active: Dict[int, _Sequence] = {}   # slot -> sequence
@@ -372,8 +412,10 @@ class InferenceEngine:
         the ``queue_wait_s`` they spent between ``generate()`` and their
         prefill's dispatch, ``prefill_tokens`` of prompt against the
         ``prefill_padded_tokens`` the padded programs ran (a prefill adds
-        its rung) and ``prefill_shapes``, the prefills by rung, ``retired``
-        sequences by reason, and of a model with experts the
+        its rung) and ``prefill_shapes``, the prefills by rung,
+        ``decode_shapes``, the decode steps by the width of their page table
+        in pages (a rung of ``decode_rungs``; they sum to ``steps``),
+        ``retired`` sequences by reason, and of a model with experts the
         ``moe_assignments`` of real tokens (token x layer x k), the
         ``moe_experts_hit`` (distinct experts a step touched, summed over
         layers and steps: over ``layers x num_experts`` a step, the share
@@ -392,11 +434,11 @@ class InferenceEngine:
         ``kv_live_token_steps`` sums over decode steps the positions the
         live sequences held (``pos + 1`` each), ``kv_gathered_token_steps``
         the positions the step's paged read gathered per pool layer
-        (``max_batch x maxp x page_size``): their ratio is the share of the
-        gather that was of use.  ``first_call_s`` says how long each program
-        took to be there: ``prefill@<rung>`` that rung's trace and compile
-        (or load from the compile cache) at construction, beside the other
-        rungs', ``decode`` its first dispatch."""
+        (``max_batch x W x page_size``, ``W`` the step's rung): their ratio
+        is the share of the gather that was of use.  ``first_call_s`` says
+        how long each program took to be there: ``prefill@<rung>`` and
+        ``decode@<pages>`` that rung's trace and compile (or load from the
+        compile cache) at construction, beside the other rungs'."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
@@ -404,6 +446,7 @@ class InferenceEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "prefill_shapes": dict(self._prefill_shapes),
+                "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
@@ -466,18 +509,35 @@ class InferenceEngine:
     def _decode(self, *args):
         return self._consuming(self._decode_donating, *args)
 
+    def _compiled(self, name: str, step, *shapes):
+        """``step`` (a donating program) lowered and compiled for ``shapes``,
+        on a compile thread; ``first_call_s[name]`` says how long it took."""
+        t0 = time.perf_counter()
+        program = step.lower(*shapes).compile()
+        self._first_call_s[name] = time.perf_counter() - t0
+        return program
+
     def _compile_rung(self, rung: int, params, kp, vp):
         """``_prefill_donating`` compiled for [1, ``rung``] tokens from the
-        shapes of the engine's tree and pools; on a thread of its own."""
+        shapes of the engine's tree and pools."""
         import jax
         import jax.numpy as jnp
-        t0 = time.perf_counter()
-        program = self._prefill_donating.lower(
-            params, jax.ShapeDtypeStruct((1, rung), jnp.int32),
+        return self._compiled(
+            f"prefill@{rung}", self._prefill_donating, params,
+            jax.ShapeDtypeStruct((1, rung), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32), kp, vp,
-            jax.ShapeDtypeStruct((1, self._maxp), jnp.int32)).compile()
-        self._first_call_s[f"prefill@{rung}"] = time.perf_counter() - t0
-        return program
+            jax.ShapeDtypeStruct((1, self._maxp), jnp.int32))
+
+    def _compile_decode_rung(self, width: int, params, kp, vp):
+        """``_decode_donating`` compiled for a page table of [max_batch,
+        ``width``] from the same shapes."""
+        import jax
+        import jax.numpy as jnp
+        slots = jax.ShapeDtypeStruct((self.config.max_batch,), jnp.int32)
+        return self._compiled(
+            f"decode@{width}", self._decode_donating, params, slots, slots,
+            kp, vp, jax.ShapeDtypeStruct((self.config.max_batch, width),
+                                         jnp.int32))
 
     def _donate_pools(self, program: str, step, a, b, pt):
         """One call of ``step`` (a donating program) on the engine's pools,
@@ -565,15 +625,21 @@ class InferenceEngine:
     def _decode_inputs(self):
         """One batched decode step's host arrays over every live slot.
         Inactive slots run token 0 at pos 0 against an all-zero table
-        row — their writes land in scratch page 0."""
+        row — their writes land in scratch page 0.  The table is as wide
+        as the decode ladder's least rung that holds the longest live
+        sequence: the step writes at ``pos`` and reads ``pos + 1``
+        positions, so the page that holds ``pos`` is the last it needs,
+        and a row's first pages are the sequence's first pages."""
         cfg = self.config
+        longest = max(seq.pos for seq in self._active.values())
+        width = rung_for(self._decode_rungs, longest // cfg.page_size + 1)
         token = np.zeros((cfg.max_batch,), np.int32)
         pos = np.zeros((cfg.max_batch,), np.int32)
-        tables = np.zeros((cfg.max_batch, self._maxp), np.int32)
+        tables = np.zeros((cfg.max_batch, width), np.int32)
         for slot, seq in self._active.items():
             token[slot] = seq.last_token
             pos[slot] = seq.pos
-            tables[slot] = seq.row
+            tables[slot] = seq.row[:width]
         return token, pos, tables
 
     def _deliver(self, tokens: Dict[int, int], returned: float):
@@ -618,11 +684,13 @@ class InferenceEngine:
         import jax.numpy as jnp
         loop = asyncio.get_running_loop()
         cfg = self.config
-        # No request meets a compile: every rung's program is there before
-        # the first admission.  A rung that failed to compile raises where
-        # a prompt needs it, to the sequences of that pass.
+        # No request meets a compile: every rung's program, prefill and
+        # decode, is there before the first admission.  A rung that failed
+        # to compile raises where a prompt or a step needs it, to the
+        # sequences of that pass.
         await asyncio.gather(*map(asyncio.wrap_future,
-                                  self._rung_programs.values()),
+                                  (*self._rung_programs.values(),
+                                   *self._decode_programs.values())),
                              return_exceptions=True)
         while True:
             try:
@@ -693,6 +761,8 @@ class InferenceEngine:
                                 waiting=len(self._waiting)):
                         batch = self._decode_inputs()
                 token, pos, tables = batch
+                width = tables.shape[1]
+                program = self._decode_programs[width].result()
                 active = len(self._active)
                 # what the step's paged read is for, and what it gathers
                 live_tokens = int(pos.sum()) + active
@@ -704,22 +774,21 @@ class InferenceEngine:
                     with region("engine.decode.dispatch", active=active,
                                 live_tokens=live_tokens,
                                 gathered_tokens=gathered_tokens,
+                                width_pages=width,
                                 submit_us=int((t0 - submitted) * 1e6)):
                         logits, kp, vp, *load = self._donate_pools(
-                            "decode", self._decode_donating, token, pos,
-                            tables)
+                            "decode", program, token, pos, tables)
                         nxt = jnp.argmax(logits, axis=-1)
                         for a in load:   # on its way beside the tokens
                             a.copy_to_host_async()
                     with region("engine.decode.fetch"):
                         nxt = np.asarray(nxt)
                         load = [np.asarray(a) for a in load]
-                    self._first_call_s.setdefault(
-                        "decode", time.perf_counter() - t0)
                     return nxt, kp, vp, load, time.perf_counter()
                 nxt, self._k_pages, self._v_pages, load, returned = \
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
+                self._decode_shapes[width] += 1
                 self._slot_steps += active
                 self._kv_live_token_steps += live_tokens
                 self._kv_gathered_token_steps += gathered_tokens

@@ -61,10 +61,13 @@ class PageAllocator:
 
 
 def table_row(pages: List[int], maxp: int) -> np.ndarray:
-    """A sequence's fixed-width page-table row: its reserved pages padded
-    with 0 (the scratch page) out to ``maxp`` — positions never reach the
-    padding, and if they somehow did, the write lands in scratch instead
-    of another sequence's cache."""
+    """A sequence's fixed-width page-table row: its reserved pages, in the
+    order of its positions, padded with 0 (the scratch page) out to ``maxp``
+    — positions never reach the padding, and if they somehow did, the write
+    lands in scratch instead of another sequence's cache.  ``maxp`` is the
+    reservation's width; a decode step takes the row's first ``W`` columns,
+    the sequence's first ``W`` pages, with ``W`` its rung of the engine's
+    decode ladder."""
     if len(pages) > maxp:
         raise ValueError(f"{len(pages)} pages exceed table width {maxp}")
     row = np.zeros((maxp,), np.int32)

@@ -14,7 +14,6 @@ dispatch's ``live_tokens`` / ``gathered_tokens`` are a count of the steps.
 """
 
 import asyncio
-import concurrent.futures
 import gc
 
 import jax
@@ -24,6 +23,7 @@ import pytest
 
 import engine_trace
 from ray_tpu.models import gpt, llama
+from test_engine_prefill_rungs import failing
 from test_engine_stored_weights import (BATCH, FAMILIES, build,
                                         program_args)
 
@@ -256,26 +256,18 @@ def test_a_failed_call_costs_its_requests_and_leaves_fresh_pools(
         program, monkeypatch):
     _, engine = build("llama-dense")
     prompt, calls = [3, 1, 4, 1, 5], []
-    rung, = engine._rungs            # the loop's prefill: its rung's program
-    real = engine._rung_programs[rung].result() if program == "prefill" \
-        else engine._decode_donating
-
-    def failing(*args):
-        calls.append(1)
-        real(*args)                  # consumes the pools it was given
-        raise RuntimeError("the device fell over")
+    # the loop's programs: its prefill rung's, and every decode rung's
+    ladder = engine._rung_programs if program == "prefill" \
+        else engine._decode_programs
 
     async def ask():
         return [t async for t in engine.generate(prompt, 4)]
 
     async def scenario():            # one event loop: the engine lives on it
         want = await ask()
-        if program == "prefill":
-            fails = concurrent.futures.Future()
-            fails.set_result(failing)
-            monkeypatch.setitem(engine._rung_programs, rung, fails)
-        else:
-            monkeypatch.setattr(engine, "_decode_donating", failing)
+        for rung, compiled in list(ladder.items()):
+            monkeypatch.setitem(ladder, rung,
+                                failing(compiled.result(), calls))
         with pytest.raises(RuntimeError, match="fell over"):
             await ask()
         monkeypatch.undo()
@@ -380,28 +372,42 @@ def test_a_consuming_view_that_fails_leaves_the_engine_fresh_pools(
 
 def test_the_kv_counters_and_the_dispatch_attributes_count_the_steps():
     """``engine_trace``'s run: a warm-up sequence, then two sequences under
-    the profiler.  Every decode step's paged read gathers every page a slot
-    may use (``max_batch x maxp x page``); of use are the positions the
-    live sequences hold once the step's token is written, ``pos + 1``."""
+    the profiler.  Every decode step's paged read gathers, for every slot,
+    the pages of the step's rung (``max_batch x W x page``: the least rung
+    of ``decode_rungs`` that holds the page the longest live sequence
+    writes); of use are the positions the live sequences hold once the
+    step's token is written, ``pos + 1``."""
     from jax.profiler import ProfileData
+    from ray_tpu.serve.engine.engine import decode_rungs, rung_for
     run = engine_trace.run()
     stats = run["stats"]
     page, maxp = 8, (engine_trace.MAX_PROMPT_LEN + 8) // 8
-    gathered = engine_trace.MAX_BATCH * maxp * page
+    rungs = decode_rungs(maxp)
+    assert rungs == (1, 2, 3)
 
     def live(prompts, new):
-        """Per step, the positions the live sequences hold: a sequence's
-        first token comes from its prefill, each later one from a step."""
+        """Per step, the positions the live sequences hold (a sequence's
+        first token comes from its prefill, each later one from a step),
+        and the width in pages of the step's table."""
         steps = np.zeros((max(new) - 1,), np.int64)
+        longest = np.zeros((max(new) - 1,), np.int64)
         for prompt, n in zip(prompts, new):
-            steps[:n - 1] += len(prompt) + 1 + np.arange(n - 1)
-        return steps
+            held = len(prompt) + 1 + np.arange(n - 1)
+            steps[:n - 1] += held
+            longest[:n - 1] = np.maximum(longest[:n - 1], held)
+        # the step writes at the last of the positions it then holds
+        return steps, [rung_for(rungs, (n - 1) // page + 1) for n in longest]
 
-    warm = live([engine_trace.WARM_PROMPT], [engine_trace.WARM_NEW])
-    traced = live(engine_trace.PROMPTS, engine_trace.NEW_TOKENS)
+    warm, warm_widths = live([engine_trace.WARM_PROMPT],
+                             [engine_trace.WARM_NEW])
+    traced, widths = live(engine_trace.PROMPTS, engine_trace.NEW_TOKENS)
+    assert warm_widths == [1] and widths == [1, 1, 1, 2, 2]
+    gathered = [engine_trace.MAX_BATCH * w * page for w in widths]
     assert stats["steps"] == len(warm) + len(traced)
+    assert stats["decode_shapes"] == {1: 4, 2: 2, 3: 0}
     assert stats["kv_live_token_steps"] == warm.sum() + traced.sum()
-    assert stats["kv_gathered_token_steps"] == stats["steps"] * gathered
+    assert stats["kv_gathered_token_steps"] == \
+        engine_trace.MAX_BATCH * page + sum(gathered)
     # GPT-2 tiny: 2 layers of 4 heads of 8, f32
     assert stats["kv_pool_layers"] == 2
     assert stats["kv_bytes_per_token"] == 2 * 2 * 32 * 4
@@ -412,8 +418,8 @@ def test_the_kv_counters_and_the_dispatch_attributes_count_the_steps():
         (e.start_ns, dict(e.stats)) for line in plane.lines
         for e in line.events if e.name == "rt:engine.decode.dispatch")
     assert [d["live_tokens"] for _, d in dispatches] == list(traced)
-    assert [d["gathered_tokens"] for _, d in dispatches] == \
-        [gathered] * len(traced)
+    assert [d["gathered_tokens"] for _, d in dispatches] == gathered
+    assert [d["width_pages"] for _, d in dispatches] == widths
 
 
 def test_a_looped_models_pool_counts_a_layer_for_every_pass():

@@ -21,6 +21,7 @@ import pytest
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+from test_engine_prefill_rungs import failing
 
 PAGE, PROMPT, NEW, BATCH = 8, 32, 16, 4
 MAXP = (PROMPT + NEW) // PAGE
@@ -96,8 +97,11 @@ def test_a_full_batch_with_waiting_callers_agrees_with_single_runs():
     assert stats["moe_assignments"] > 0
     # two of the three layers route: hits are counted for those alone
     assert stats["moe_experts_hit"] <= 2 * 8 * (stats["steps"] + 10)
-    assert stats["kv_gathered_token_steps"] == \
-        stats["steps"] * BATCH * MAXP * PAGE
+    # a step gathers its rung's pages for every slot (``decode_rungs``)
+    assert sum(stats["decode_shapes"].values()) == stats["steps"]
+    assert stats["kv_gathered_token_steps"] == sum(
+        steps * BATCH * width * PAGE
+        for width, steps in stats["decode_shapes"].items())
     assert 0 < stats["kv_live_token_steps"] < stats["kv_gathered_token_steps"]
 
     _, alone = build(num_pages=MAXP + 1, max_batch=1)
@@ -178,18 +182,15 @@ def test_a_shorter_rung_gives_the_logits_and_pages_of_the_top_rung():
 
 def test_a_failed_decode_leaves_a_fresh_pool(monkeypatch):
     _, engine = build()
-    real = engine._decode_donating
-
-    def failing(*args):
-        real(*args)                      # consumes the pool it was given
-        raise RuntimeError("the device fell over")
 
     async def ask():
         return [t async for t in engine.generate([3, 1, 4, 1, 5], 4)]
 
     async def scenario():
         want = await ask()
-        monkeypatch.setattr(engine, "_decode_donating", failing)
+        for width, compiled in list(engine._decode_programs.items()):
+            monkeypatch.setitem(engine._decode_programs, width,
+                                failing(compiled.result()))
         with pytest.raises(RuntimeError, match="fell over"):
             await ask()
         monkeypatch.undo()

@@ -11,6 +11,7 @@ and the counters say which rungs ran.
 """
 
 import asyncio
+import concurrent.futures
 import dataclasses
 
 import jax
@@ -56,6 +57,20 @@ def build(family, max_prompt_len=PROMPT, page=PAGE):
 
 def prompt_of(n):
     return [int(t) for t in (np.arange(n) * 7 + 3) % 97]
+
+
+def failing(real, calls=None):
+    """In place of a rung's compiled program (``engine._rung_programs`` /
+    ``engine._decode_programs``): a finished future holding a function that
+    runs ``real``, so the pools it was given are consumed, and then raises."""
+    def call(*args):
+        if calls is not None:
+            calls.append(1)
+        real(*args)
+        raise RuntimeError("the device fell over")
+    fails = concurrent.futures.Future()
+    fails.set_result(call)
+    return fails
 
 
 def serve(engine, prompts, new=NEW):
@@ -190,10 +205,12 @@ def test_nothing_compiles_after_the_first_admission():
                        np.full(BATCH, 9, np.int32), kp, vp, table)
         del kp, vp
         first = asyncio.run(run())
-        # the loop's decode is the views' (a rung's program hands the pools
-        # on as the jitted one does), its prefills are the rungs' own
+        # one entry each: the views' two calls above.  The loop's programs
+        # are the rungs' own, prefill and decode, compiled at construction
+        # (a rung's program hands the pools on as a jitted one does)
         assert first[1:] == (1, 1)
-        assert set(first[0]) == {"decode"} | {f"prefill@{r}" for r in rungs}
+        assert set(first[0]) == {f"prefill@{r}" for r in rungs} | {
+            f"decode@{w}" for w in engine._decode_rungs}
         assert all(seconds > 0 for seconds in first[0].values())
         assert compiled() == first
         stats = engine.stats()
@@ -215,7 +232,10 @@ def test_one_rung_is_the_one_program_and_the_counters_say_so():
         stats = engine.stats()
         assert stats["prefill_shapes"] == {64: 2}
         assert stats["prefill_padded_tokens"] == 128
-        assert set(stats["first_call_s"]) == {"decode", "prefill@64"}
+        # 64 + 8 positions are nine pages: decode rungs three pages apart
+        assert set(stats["first_call_s"]) == {"prefill@64"} | {
+            f"decode@{w}" for w in (3, 6, 9)}
+        assert engine._decode_donating._cache_size() == 0
     finally:
         engine.close()
 

@@ -291,6 +291,17 @@ def test_paged_engine_program_compiles(topo, program):
     _fits(compiled)
 
 
+_COMPILED = {}      # what a test of this file compiled, for its neighbours
+
+
+def _narrowest_is_no_larger(narrow, top):
+    """A decode step at the narrowest rung of ``decode_rungs`` against the
+    top rung's: it gathers fewer pages, so it may need no more room."""
+    narrow, top = narrow.memory_analysis(), top.memory_analysis()
+    assert narrow.temp_size_in_bytes <= top.temp_size_in_bytes
+    assert narrow.alias_size_in_bytes == top.alias_size_in_bytes
+
+
 def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
                           max_batch=MAX_BATCH, gather_is_the_temporaries=True,
                           rung=None):
@@ -298,7 +309,13 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
     compiles it (pools donated) from the shapes of the tree the engine
     stores (``llama_serving_params``) and of its pool, and held to
     ``_pools_in_place``: (those shapes, the executable, its text).  The
-    prefill is that of the top rung, ``prompt``, or of ``rung``."""
+    prefill is that of the top rung, ``prompt``, or of ``rung``; the decode
+    that of the top rung of the decode ladder (a page table of ``maxp``
+    columns) or, as ``decode@narrow``, of its narrowest, which is also held
+    to need no more room than the top rung's."""
+    key = (repr(cfg), program, prompt, new, num_pages, max_batch, rung)
+    if key in _COMPILED:
+        return _COMPILED[key]
     from ray_tpu.models.llama import (llama_decode_step, llama_init,
                                       llama_init_paged_cache, llama_prefill,
                                       llama_serving_params)
@@ -318,10 +335,13 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
             arg((1, rung or prompt)), arg(()), kp, vp, arg((1, maxp)),
             donate=POOLS)
     else:
+        from ray_tpu.serve.engine.engine import decode_rungs
+        width = maxp if program == "decode" else decode_rungs(maxp)[0]
+        assert program in ("decode", "decode@narrow") and 0 < width <= maxp
         compiled, text = _compile(
             lambda p, *a: llama_decode_step(p, cfg, *a), params,
             arg((max_batch,)), arg((max_batch,)), kp, vp,
-            arg((max_batch, maxp)), donate=POOLS)
+            arg((max_batch, width)), donate=POOLS)
     _pools_in_place(compiled, text, kp)
     if program == "decode" and gather_is_the_temporaries:
         # from the stored tree a step's temporaries are what it gathers of
@@ -330,6 +350,11 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
         # a layer at a time they were four layers' pools and more
         layer_bytes = kp.size * kp.dtype.itemsize // kp.shape[0]
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_bytes
+    if program == "decode@narrow":
+        _narrowest_is_no_larger(compiled, _llama_engine_program(
+            topo, cfg, "decode", prompt, new, num_pages, max_batch,
+            gather_is_the_temporaries)[1])
+    _COMPILED[key] = params, compiled, text
     return params, compiled, text
 
 
@@ -351,7 +376,7 @@ def _mistral_engine_program(topo, program, rung=None):
                                  num_pages=2561, rung=rung)
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
 def test_mistral_engine_program_reads_stored_weights(topo, program):
     params, compiled, text = _mistral_engine_program(topo, program)
     assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
@@ -389,7 +414,7 @@ OLMOE_PROMPT, OLMOE_NEW = 512, 1024
 OLMOE_BUDGET = 8 * 1024 ** 3
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
 def test_olmoe_engine_program_compiles(topo, program):
     from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=50304, num_layers=4, num_heads=16,
@@ -401,7 +426,7 @@ def test_olmoe_engine_program_compiles(topo, program):
         topo, cfg, program, OLMOE_PROMPT, OLMOE_NEW,
         MAX_BATCH * (OLMOE_PROMPT + OLMOE_NEW) // PAGE + 1)
     assert params["layers"]["mlp"]["wgu"].dtype == jnp.float32
-    if program == "decode":
+    if program != "prefill":
         assert _scoped(text, "paged_read")
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "paged_append"):
@@ -426,7 +451,8 @@ def test_olmoe_engine_program_compiles(topo, program):
 # prefill, 3.19 GiB: PERF.md section 7), which is why 16 slots (8.08 GB of
 # pool) compile to 15.68 GiB and 12 is what fits.
 OURO_PROMPT, OURO_NEW, OURO_BATCH = 128, 192, 12
-OURO_BUDGET = {"prefill": int(13.9 * 1024 ** 3), "decode": 12 * 1024 ** 3}
+OURO_BUDGET = {"prefill": int(13.9 * 1024 ** 3), "decode": 12 * 1024 ** 3,
+               "decode@narrow": 12 * 1024 ** 3}
 
 
 def _ouro():
@@ -437,7 +463,7 @@ def _ouro():
                        post_norm=True, max_seq_len=OURO_PROMPT + OURO_NEW)
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
 def test_ouro_engine_program_compiles(topo, program):
     cfg = _ouro()
     params, compiled, text = _llama_engine_program(
@@ -447,7 +473,7 @@ def test_ouro_engine_program_compiles(topo, program):
     assert params["layers"]["ln1_post"]["scale"].dtype == jnp.float32
     assert params["layers"]["mlp"]["wgu"].shape == (48, 2, 2048, 5632)
     assert "convert(%p__" not in text
-    if program == "decode":
+    if program != "prefill":
         assert _scoped(text, "paged_read")
     assert _scoped(text, "paged_append") and _scoped(text, "loop_norm")
     # a pool layer for every pass: 192 of them, 1.5 MiB a cached position
@@ -499,7 +525,8 @@ def _xing():
     return family, family.program_config(config, XING_PROMPT + XING_NEW)
 
 
-@pytest.mark.parametrize("program", ["prefill", "prefill@256", "decode"])
+@pytest.mark.parametrize("program", ["prefill", "prefill@256", "decode",
+                                     "decode@64"])
 def test_xing_engine_program_compiles(topo, program):
     from ray_tpu.models.llama import (llama_decode_step,
                                       llama_init_paged_cache, llama_prefill)
@@ -516,11 +543,22 @@ def test_xing_engine_program_compiles(topo, program):
     def arg(shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
 
-    if program == "decode":
-        compiled, text = _compile(
-            lambda p, *a: llama_decode_step(p, cfg, *a), params,
-            arg((XING_BATCH,)), arg((XING_BATCH,)), kp, None,
-            arg((XING_BATCH, maxp)), donate=POOLS)
+    if program.startswith("decode"):
+        # the top rung of the decode ladder, or its narrowest
+        from ray_tpu.serve.engine.engine import decode_rungs
+        width = int(program.partition("@")[2] or maxp)
+        assert width in (decode_rungs(maxp)[0], maxp)
+
+        def decode(width):
+            if ("xing", width) not in _COMPILED:
+                _COMPILED["xing", width] = _compile(
+                    lambda p, *a: llama_decode_step(p, cfg, *a), params,
+                    arg((XING_BATCH,)), arg((XING_BATCH,)), kp, None,
+                    arg((XING_BATCH, width)), donate=POOLS)
+            return _COMPILED["xing", width]
+        compiled, text = decode(width)
+        if width < maxp:
+            _narrowest_is_no_larger(compiled, decode(maxp)[0])
     else:
         rung = int(program.partition("@")[2] or XING_PROMPT)
         compiled, text = _compile(
@@ -536,7 +574,7 @@ def test_xing_engine_program_compiles(topo, program):
     assert "convert(%p__" not in text
     scopes = ["latent_append", "hc_coeff", "hc_mix", "moe_router",
               "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"]
-    if program == "decode":
+    if program.startswith("decode"):
         scopes += ["latent_read", "mla_absorb"]
     for scope in scopes:
         assert _scoped(text, scope), scope

@@ -139,6 +139,12 @@ def test_engine_regions_carry_their_attributes(engine_run):
     assert [s["active"] for _, _, s in dispatches] == \
         [2] * (short - 1) + [1] * (long - short)
     assert all(s["submit_us"] >= 0 for _, _, s in dispatches)
+    # the step's table is as wide as its longest sequence needs: the
+    # longer prompt (5) grows to 10 positions, over the first page's 8
+    assert [s["width_pages"] for _, _, s in dispatches] == [1, 1, 1, 2, 2]
+    assert all(s["gathered_tokens"] == engine_trace.MAX_BATCH
+               * s["width_pages"] * engine_trace.PAGE
+               for _, _, s in dispatches)
     delivers = by_name["rt:engine.deliver"]
     assert sum(s["tokens"] for _, _, s in delivers) == \
         sum(engine_trace.NEW_TOKENS)
