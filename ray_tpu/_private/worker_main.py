@@ -423,7 +423,7 @@ class TaskExecutor:
                 # The wait spans an await, so it rides as an attribute of
                 # a region entered and left when the ack arrives.
                 with tracing.region(
-                        "stream.yield", index=i,
+                        "stream.yield",
                         ack_us=int((time.perf_counter() - sent) * 1e6)):
                     pass
                 if not ack.get("ok"):

@@ -101,7 +101,24 @@ Observability: every synchronous section of the per-token path is a
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
 profile beside the device's programs (``LLMServer.profile``); the two
 thread crossings of a step ride as the ``submit_us`` and ``resume_us``
-attributes of the region that follows them.  ``stats()`` carries the
+attributes of the region that follows them.  A step's six boundaries
+(submitted on the loop thread; dispatch start, dispatch end and returned on
+the exec lane; ``_deliver``'s entry and exit on the loop thread again) are
+each read on up to three clocks (``_Clocks``: wall, the reading thread's
+CPU, the loop thread's CPU), and that one set of reads feeds both the
+regions' attributes (``tracing``'s module docstring has the convention:
+``dispatch_us`` / ``dispatch_cpu_us`` / ``dispatch_loop_cpu_us`` on
+``.decode.fetch``, ``fetch_loop_cpu_us`` and ``resume_loop_cpu_us`` on
+``.deliver``, ``step_us`` / ``step_loop_cpu_us`` on ``.decode.dispatch``)
+and the always-on sums ``stats()["host_s"]`` / ``["host_cpu_s"]``: wall less
+the exec lane's own CPU is what it spent not running (the GIL, a lock of
+the runtime), and the loop thread's CPU in the same interval says whether
+the loop ran against it.  The wall is read at every boundary of every call;
+the CPU clocks between a call's submission and its delivery are system
+calls, read for every call while a profiler session records and for one
+call in ``_CPU_EVERY`` otherwise.  The collector's passes are ``rt:gc``
+regions and
+``stats()["gc"]`` (``tracing.watch_gc``).  ``stats()`` carries the
 always-on counters of the same places.
 
 A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
@@ -124,14 +141,16 @@ import collections
 import concurrent.futures
 import dataclasses
 import logging
+import threading
 import time
-from typing import (Any, AsyncIterator, Dict, List, Optional, Sequence,
-                    Tuple)
+from typing import (Any, AsyncIterator, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
 from ray_tpu.serve import resilience
 from ray_tpu.serve.engine.kv_cache import PageAllocator, table_row
+from ray_tpu.util import tracing
 from ray_tpu.util.tracing import region
 
 logger = logging.getLogger(__name__)
@@ -143,6 +162,26 @@ _DONE = object()
 # served were there after 30.5 s on twelve threads, 12.7 s on three (PERF.md,
 # PR 35); cold, a few compiles side by side already fill the host's cores.
 _COMPILE_THREADS = 4
+# With no profiler session to carry them, one call in this many (a decode
+# step, a prefill) has its exec lane's boundaries read on the thread CPU
+# clocks too: seven system calls, 6 us each under the chip machine's
+# sandbox, which read at every step cost chat's 10 ms step ~0.5% (PERF.md
+# section 6, PR 36).  A session reads them at every call: the chip machine's
+# clocks tick in 10 ms steps, and five traced seconds need every tick.
+_CPU_EVERY = 16
+
+
+class _Clocks(NamedTuple):
+    """One boundary of a call (seconds); the CPU clocks ``None`` where the
+    call is not one of those sampled."""
+    wall: float                 # time.perf_counter()
+    cpu: Optional[float]        # the reading thread's CPU clock
+    loop_cpu: Optional[float]   # the actor loop thread's, from any thread
+
+
+def _us(seconds: float) -> int:
+    """An attribute of a region: whole microseconds."""
+    return int(seconds * 1e6)
 
 
 @dataclasses.dataclass
@@ -344,6 +383,16 @@ class InferenceEngine:
                      "moe_load_max": 0}
         self._kv_live_token_steps = 0
         self._kv_gathered_token_steps = 0
+        self._host_s = dict.fromkeys(
+            ("schedule", "submit", "dispatch", "fetch", "resume", "deliver"),
+            0.0)
+        self._host_cpu_s = dict.fromkeys(
+            ("exec_dispatch", "exec_fetch", "loop", "loop_in_dispatch",
+             "loop_in_fetch", "loop_in_resume"), 0.0)
+        self._calls = 0          # submissions to the exec lane
+        self._cpu_sampled = 0    # of them, those read on the CPU clocks too
+        self._loop_cpu_clock: Optional[int] = None   # set by _run_loop
+        tracing.watch_gc()
         # Single lane for XLA dispatches: the device serializes anyway,
         # and one lane keeps (k_pages, v_pages) updates ordered.
         self._exec = concurrent.futures.ThreadPoolExecutor(
@@ -438,7 +487,31 @@ class InferenceEngine:
         is the share of the gather that was of use.  ``first_call_s`` says
         how long each program took to be there: ``prefill@<rung>`` and
         ``decode@<pages>`` that rung's trace and compile (or load from the
-        compile cache) at construction, beside the other rungs'."""
+        compile cache) at construction, beside the other rungs'.
+        ``host_s`` is the wall seconds the per-token path has taken since the
+        engine started, by phase, the phases tiling the loop's busy time:
+        ``schedule`` (a delivery's end, or the loop's waking, to the next
+        ``run_in_executor``), ``submit`` (to the exec lane running),
+        ``dispatch`` and ``fetch`` (a decode step's two regions; a prefill's
+        whole call counts under ``fetch``, its lane sends and waits in one
+        region), ``resume`` (the lane's return to ``_deliver``) and
+        ``deliver``.  ``host_cpu_s`` is CPU seconds beside them:
+        ``loop`` the loop thread's from each decode step's submission (or
+        the loop's waking) to the next: everything a step costs the loop, so
+        ``loop`` over the sum of ``host_s`` is how full that thread is.  The
+        other five are sums over ``host_cpu_calls`` of the lane's calls
+        (decode steps and prefills: every one while a profiler session
+        records, one in ``_CPU_EVERY`` otherwise, ``steps`` + ``admitted`` in
+        all), so compare them per call: ``exec_dispatch`` and ``exec_fetch``
+        the exec lane's own (``host_s["dispatch"]`` a call less
+        ``exec_dispatch`` a sampled call is what the lane spent inside
+        ``dispatch`` not running: the GIL, a lock of the runtime;
+        ``host_s["fetch"]`` less ``exec_fetch`` its wait on the device),
+        ``loop_in_dispatch`` / ``loop_in_fetch`` / ``loop_in_resume`` the
+        actor loop thread's in those phases.  Plain additions on the loop
+        thread from the clock reads that feed the regions' attributes; no
+        lock.  ``gc`` is ``tracing.gc_stats()``: this process's collector
+        passes."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "slot_steps": self._slot_steps, "admitted": self._admitted,
@@ -459,7 +532,11 @@ class InferenceEngine:
                 "kv_gathered_token_steps": self._kv_gathered_token_steps,
                 "kv_pool_in_place": dict(self._kv_in_place),
                 "device": self._device,
-                "first_call_s": dict(self._first_call_s)}
+                "first_call_s": dict(self._first_call_s),
+                "host_s": dict(self._host_s),
+                "host_cpu_s": dict(self._host_cpu_s),
+                "host_cpu_calls": self._cpu_sampled,
+                "gc": tracing.gc_stats()}
 
     def close(self):
         if self._loop_task is not None:
@@ -642,16 +719,70 @@ class InferenceEngine:
             tables[slot] = seq.row[:width]
         return token, pos, tables
 
-    def _deliver(self, tokens: Dict[int, int], returned: float):
-        """Push each slot's token to its caller and retire what finished;
-        ``returned`` is when the exec thread handed the tokens back."""
-        with region("engine.deliver", tokens=len(tokens),
-                    resume_us=int((time.perf_counter() - returned) * 1e6)):
+    def _clocks(self, cpu: bool, on_loop: bool = False) -> _Clocks:
+        """Now: the wall first (what the regions' neighbours are held to),
+        then with ``cpu`` the two thread clocks.  ``on_loop`` says the
+        caller is the loop thread, whose own CPU clock is the third (one
+        system call, not two)."""
+        wall = time.perf_counter()
+        if not cpu:
+            return _Clocks(wall, None, None)
+        own = time.thread_time()
+        return _Clocks(wall, own, own if on_loop
+                       else time.clock_gettime(self._loop_cpu_clock))
+
+    def _submit(self, cpu: bool = False) -> Tuple[_Clocks, bool]:
+        """The loop thread hands a call to the exec lane: the boundary, with
+        the loop's CPU clock where ``cpu`` asks for it, and whether the
+        call's later boundaries read the CPU clocks (``_CPU_EVERY``)."""
+        sampled = tracing.recording() or self._calls % _CPU_EVERY == 0
+        self._calls += 1
+        submitted = self._clocks(cpu, on_loop=True)
+        self._host_s["schedule"] += submitted.wall - self._loop_free_at
+        return submitted, sampled
+
+    def _loop_woke(self):
+        """The loop thread starts a stretch of work: ``schedule`` and the
+        next decode step's ``step_us`` count from here."""
+        self._step_from = self._clocks(True, on_loop=True)
+        self._loop_free_at = self._step_from.wall
+
+    def _deliver(self, tokens: Dict[int, int], submitted: _Clocks,
+                 lane: Tuple[_Clocks, _Clocks, _Clocks]):
+        """Push each slot's token to its caller and retire what finished.
+        ``lane`` is the exec thread's three boundaries of the call that made
+        the tokens (dispatch start, dispatch end, returned; a prefill's
+        first two are one), ``submitted`` the loop's before it: the call's
+        phases are added to ``stats()``'s sums here, and those that no
+        region has carried yet ride on this one."""
+        start, sent, returned = lane
+        sampled = returned.cpu is not None
+        entry = self._clocks(sampled, on_loop=True)
+        wall, cpu = self._host_s, self._host_cpu_s
+        wall["submit"] += start.wall - submitted.wall
+        wall["dispatch"] += sent.wall - start.wall
+        wall["fetch"] += returned.wall - sent.wall
+        wall["resume"] += entry.wall - returned.wall
+        attrs = {"resume_us": _us(entry.wall - returned.wall)}
+        if sampled:
+            self._cpu_sampled += 1
+            cpu["exec_dispatch"] += sent.cpu - start.cpu
+            cpu["exec_fetch"] += returned.cpu - sent.cpu
+            cpu["loop_in_dispatch"] += sent.loop_cpu - start.loop_cpu
+            cpu["loop_in_fetch"] += returned.loop_cpu - sent.loop_cpu
+            cpu["loop_in_resume"] += entry.loop_cpu - returned.loop_cpu
+            attrs["fetch_loop_cpu_us"] = _us(
+                returned.loop_cpu - sent.loop_cpu)
+            attrs["resume_loop_cpu_us"] = _us(
+                entry.loop_cpu - returned.loop_cpu)
+        with region("engine.deliver", tokens=len(tokens), **attrs):
             for slot, token in tokens.items():
                 seq = self._active[slot]
                 if self._push(seq, token) or seq.cancelled:
                     self._retire(seq, "cancelled" if seq.cancelled
                                  else "done")
+        self._loop_free_at = time.perf_counter()
+        wall["deliver"] += self._loop_free_at - entry.wall
 
     def _count_moe(self, program: str, load: Sequence[np.ndarray]):
         """What an expert model's program said of its real tokens' routing
@@ -684,6 +815,8 @@ class InferenceEngine:
         import jax.numpy as jnp
         loop = asyncio.get_running_loop()
         cfg = self.config
+        self._loop_cpu_clock = time.pthread_getcpuclockid(
+            threading.get_ident())
         # No request meets a compile: every rung's program, prefill and
         # decode, is there before the first admission.  A rung that failed
         # to compile raises where a prompt or a step needs it, to the
@@ -692,6 +825,7 @@ class InferenceEngine:
                                   (*self._rung_programs.values(),
                                    *self._decode_programs.values())),
                              return_exceptions=True)
+        self._loop_woke()
         while True:
             try:
                 with region("engine.schedule", active=len(self._active),
@@ -712,6 +846,7 @@ class InferenceEngine:
                     # test above and the clear.
                     if not self._waiting:
                         await self._wake.wait()
+                        self._loop_woke()
                     continue
 
                 # Prefill new admissions one at a time (B=1), each padded
@@ -721,30 +856,32 @@ class InferenceEngine:
                     program = self._rung_programs[S].result()
                     toks = np.zeros((1, S), np.int32)
                     toks[0, : len(seq.prompt)] = seq.prompt
-                    submitted = time.perf_counter()
-                    self._queue_wait_s += submitted - seq.queued
+                    submitted, sampled = self._submit()
+                    self._queue_wait_s += submitted.wall - seq.queued
                     self._prefill_tokens += len(seq.prompt)
                     self._prefill_padded_tokens += S
                     self._prefill_shapes[S] += 1
 
                     def _run(seq=seq, S=S, program=program, toks=toks,
-                             submitted=submitted):
-                        t0 = time.perf_counter()
+                             submitted=submitted, sampled=sampled):
+                        start = self._clocks(sampled)
                         with region("engine.prefill",
                                     prompt_len=len(seq.prompt), padded_len=S,
-                                    waited_us=int((t0 - seq.queued) * 1e6),
-                                    submit_us=int((t0 - submitted) * 1e6)):
+                                    waited_us=_us(start.wall - seq.queued),
+                                    submit_us=_us(
+                                        start.wall - submitted.wall)):
                             logits, kp, vp, *load = self._donate_pools(
                                 "prefill", program, toks,
                                 np.int32(len(seq.prompt)), seq.row[None])
                             tok = int(jnp.argmax(logits[0]))
                             load = [np.asarray(a) for a in load]
-                        return tok, kp, vp, load, time.perf_counter()
-                    tok, self._k_pages, self._v_pages, load, returned = \
+                        return tok, kp, vp, load, \
+                            (start, start, self._clocks(sampled))
+                    tok, self._k_pages, self._v_pages, load, lane = \
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
                     self._count_moe("prefill", load)
-                    self._deliver({seq.slot: tok}, returned)
+                    self._deliver({seq.slot: tok}, submitted, lane)
 
                 if not self._active:
                     continue
@@ -767,25 +904,41 @@ class InferenceEngine:
                 # what the step's paged read is for, and what it gathers
                 live_tokens = int(pos.sum()) + active
                 gathered_tokens = tables.size * cfg.page_size
-                submitted = time.perf_counter()
+                submitted, sampled = self._submit(cpu=True)
+                # everything the step before cost the loop: its delivery,
+                # the streams' fan-out, schedule, the prefills between
+                step_s = submitted.wall - self._step_from.wall
+                step_loop_cpu_s = \
+                    submitted.loop_cpu - self._step_from.loop_cpu
+                self._host_cpu_s["loop"] += step_loop_cpu_s
+                self._step_from = submitted
 
                 def _step():
-                    t0 = time.perf_counter()
+                    start = self._clocks(sampled)
                     with region("engine.decode.dispatch", active=active,
                                 live_tokens=live_tokens,
                                 gathered_tokens=gathered_tokens,
                                 width_pages=width,
-                                submit_us=int((t0 - submitted) * 1e6)):
+                                submit_us=_us(start.wall - submitted.wall),
+                                step_us=_us(step_s),
+                                step_loop_cpu_us=_us(step_loop_cpu_s)):
                         logits, kp, vp, *load = self._donate_pools(
                             "decode", program, token, pos, tables)
                         nxt = jnp.argmax(logits, axis=-1)
                         for a in load:   # on its way beside the tokens
                             a.copy_to_host_async()
-                    with region("engine.decode.fetch"):
+                    sent = self._clocks(sampled)
+                    attrs = {"dispatch_us": _us(sent.wall - start.wall)}
+                    if sampled:
+                        attrs["dispatch_cpu_us"] = _us(sent.cpu - start.cpu)
+                        attrs["dispatch_loop_cpu_us"] = _us(
+                            sent.loop_cpu - start.loop_cpu)
+                    with region("engine.decode.fetch", **attrs):
                         nxt = np.asarray(nxt)
                         load = [np.asarray(a) for a in load]
-                    return nxt, kp, vp, load, time.perf_counter()
-                nxt, self._k_pages, self._v_pages, load, returned = \
+                    return nxt, kp, vp, load, \
+                        (start, sent, self._clocks(sampled))
+                nxt, self._k_pages, self._v_pages, load, lane = \
                     await loop.run_in_executor(self._exec, _step)
                 self._steps += 1
                 self._decode_shapes[width] += 1
@@ -796,7 +949,7 @@ class InferenceEngine:
                 for seq in self._active.values():
                     seq.pos += 1
                 self._deliver({slot: int(nxt[slot])
-                               for slot in self._active}, returned)
+                               for slot in self._active}, submitted, lane)
             except asyncio.CancelledError:
                 raise
             except Exception as e:   # noqa: BLE001
@@ -846,13 +999,22 @@ class LLMServer:
         keeps serving, and return the trace's path (an ``.xplane.pb``
         under ``log_dir``): the device's programs and operations with the
         engine's ``rt:`` regions beside them on one clock.  Call it as
-        ``handle.method("profile").remote(log_dir, seconds)``."""
+        ``handle.method("profile").remote(log_dir, seconds)``.  The
+        profiler's Python tracer is off: on by default, it makes every
+        Python call of every thread an event, which slowed the loop's
+        per-token work two to six times and a decode step by 3-14 ms, so
+        that the trace showed a host the untraced replica does not have
+        (PERF.md section 6, PR 36); the regions need only the host tracer."""
+        import functools
         import glob
         import os
 
         import jax
         loop = asyncio.get_running_loop()
-        await loop.run_in_executor(None, jax.profiler.start_trace, log_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        await loop.run_in_executor(None, functools.partial(
+            jax.profiler.start_trace, log_dir, profiler_options=options))
         try:
             await asyncio.sleep(seconds)
         finally:

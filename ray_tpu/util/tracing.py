@@ -22,12 +22,33 @@ each, on the wall clock).  The hot loops use ``region()`` instead: a
 named host interval written into the JAX profiler's own trace, so it is
 on the device trace's clock, live exactly while a profiler session runs
 in this process, and never sent to the GCS.
+
+The convention of a region's attributes, in this one place.  A region's
+attributes are fixed when it is entered, so the numbers of a phase that
+has just ended (a wait that spanned an ``await``, a thread crossing, the
+region before) ride on the region that FOLLOWS it, as ``<phase>_<clock>``:
+whole microseconds, integers.  ``<phase>_us`` is the wall clock
+(``time.perf_counter``), ``<phase>_cpu_us`` the CPU clock of the thread
+that ran the phase (``time.thread_time``: wall less CPU is what that thread
+spent not running, waiting for the GIL or a lock), ``<phase>_loop_cpu_us``
+the CPU clock of the actor's event-loop thread over the same interval,
+whichever thread read it (``time.pthread_getcpuclockid``).  A thread's CPU
+clock is a system call (0.3 us on plain Linux, 6 us under the chip
+machine's sandbox), so a hot path reads it for every region only while
+``recording()`` says a session would carry it, and for a sample otherwise.
+A host may tick its thread CPU clocks coarsely (the chip's machine does, in
+steps of 10 ms): one region then reads 0 or a whole tick, and only sums
+over many regions are readings.  ``watch_gc()``
+puts the collector's passes on the same timeline as ``rt:gc`` regions and
+counts them always (``gc_stats()``): the collector holds the GIL, so a pass
+on any thread is a stall of every thread.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import os
 import sys
 import time
@@ -74,6 +95,55 @@ def region(name: str, **attrs):
     if jax is None:
         return _NO_REGION
     return jax.profiler.TraceAnnotation("rt:" + name, **attrs)
+
+
+def recording() -> bool:
+    """Whether a profiler session is recording this process's regions right
+    now.  For a reading that costs something and that only a region would
+    carry (the engine's thread CPU clocks); ``region()`` itself needs no
+    such test."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+
+
+_gc = {"passes": [0, 0, 0], "pause_s": [0.0, 0.0, 0.0],   # by generation
+       "pause_max_s": 0.0}                                  # see gc_stats()
+_gc_open: Optional[tuple] = None   # (the pass's region, when it started)
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _gc_open
+    if phase == "start":
+        open_region = region("gc", generation=info["generation"])
+        open_region.__enter__()
+        _gc_open = (open_region, time.perf_counter())
+    elif _gc_open is not None:
+        (open_region, started), _gc_open = _gc_open, None
+        pause = time.perf_counter() - started
+        open_region.__exit__(None, None, None)
+        _gc["passes"][info["generation"]] += 1
+        _gc["pause_s"][info["generation"]] += pause
+        _gc["pause_max_s"] = max(_gc["pause_max_s"], pause)
+
+
+def watch_gc() -> None:
+    """From now on every pass of this process's collector is an ``rt:gc``
+    region with its ``generation`` (a no-op of a region where jax is not
+    imported, as ``region()``) and is counted in ``gc_stats()``.  In a
+    trace viewer ``generation`` tells the full pass (2: tens of
+    milliseconds with every thread stalled, what ``gc.freeze()`` would
+    shorten) from the young ones (tenths of a millisecond, many a second).
+    Calling it again changes nothing.  It costs a pass two Python calls."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_stats() -> Dict[str, Any]:
+    """Collector passes since ``watch_gc()``: ``passes`` and ``pause_s``
+    (wall seconds inside them) by generation 0, 1, 2, and ``pause_max_s``,
+    the longest single pass."""
+    return {"passes": list(_gc["passes"]), "pause_s": list(_gc["pause_s"]),
+            "pause_max_s": _gc["pause_max_s"]}
 
 
 def current_context() -> Optional[tuple]:

@@ -8,6 +8,7 @@ import functools
 import glob
 import os
 import tempfile
+import time
 
 PROMPTS = ([5, 17, 3, 88, 41], [7, 8, 9])
 NEW_TOKENS = (6, 4)
@@ -18,7 +19,12 @@ MAX_PROMPT_LEN, MAX_BATCH, PAGE = 16, 4, 8
 @functools.lru_cache(maxsize=None)
 def run() -> dict:
     """``{"path": the .xplane.pb, "stats": the engine's stats() at the
-    end, "tokens": what each sequence generated}``."""
+    end, "stats_before": those when the trace began (the engine idle after
+    its warm-up), "tokens": what each sequence generated, "wall_s": the engine's
+    lifetime}``.  The traced
+    stretch begins with one forced full pass of the collector."""
+    import gc
+
     import jax
     import jax.numpy as jnp
     from ray_tpu.models.gpt import GPTConfig
@@ -33,6 +39,7 @@ def run() -> dict:
                           max_prompt_len=MAX_PROMPT_LEN, max_new_tokens=8)
 
     async def go():
+        born = time.perf_counter()
         engine = InferenceEngine(config)
 
         async def consume(prompt, new):
@@ -40,17 +47,20 @@ def run() -> dict:
 
         # both programs compile outside the trace
         await consume(WARM_PROMPT, WARM_NEW)
+        before = engine.stats()
         jax.profiler.start_trace(trace_dir)
         try:
+            gc.collect()
             tokens = await asyncio.gather(
                 *[consume(p, n) for p, n in zip(PROMPTS, NEW_TOKENS)])
         finally:
             jax.profiler.stop_trace()
         stats = engine.stats()
         engine.close()
-        return tokens, stats
+        return tokens, before, stats, time.perf_counter() - born
 
-    tokens, stats = asyncio.run(go())
+    tokens, before, stats, wall_s = asyncio.run(go())
     path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
-    return {"path": path, "stats": stats, "tokens": tokens}
+    return {"path": path, "stats": stats, "stats_before": before,
+            "tokens": tokens, "wall_s": wall_s}
