@@ -43,6 +43,38 @@ pools.  A batch whose longest sequence fills its reservation takes the top
 rung.  Dispatches run on a single-thread executor so the actor's event loop
 keeps serving admissions and cancellations while XLA computes.
 
+The loop runs **one decode step ahead of its own tokens**.  The decode
+program chooses every slot's next token itself (the ``argmax`` of its logits,
+a last result ``int32[max_batch]``), and the next step takes that array where
+it lies, on the device, as its ``token``: everything else the host knows
+without it (a position advances by one at dispatch, a page table does not
+change, an end by ``max_new`` is a count).  So an iteration hands the exec
+lane ONE call that dispatches step N+1 and starts its results' copy to the
+host, and then fetches step N's tokens, which ``_deliver`` streams while the
+device runs N+1: the per-token host round trip (dispatch, the copy down, two
+thread crossings, delivery, schedule) runs while the device does, and its
+queue is never empty between two steps of a settled batch.  At most one step
+is in flight (``_Step``); it remembers its own ``{slot: sequence}``, and a
+token goes to the sequence that was stepped, never to whoever holds the slot
+now.  A sequence whose ``generated`` and token in flight make ``max_new`` is
+left out of the next dispatch (its slot parked on page 0 like an empty one):
+no step is dispatched for a sequence whose last token is coming.  What the
+host cannot foresee (an ``eos_token``, a cancellation, a deadline) retires the
+sequence when the loop learns of it; the step already in flight still
+computes its slot, a **stray** slot step: its token is dropped, its K/V row
+lands inside the sequence's own reservation (it had not reached
+``max_new``), and the pools thread through every call, so a later prefill
+into the freed pages is ordered after it on the device.  The pipe **drains**
+(the step in flight is fetched and delivered with nothing queued behind it,
+the order every step had before) when the batch about to be stepped is not
+the batch in flight less those that end: an admission, whose prefill runs
+alone and whose first token the host has to see; the last token of a batch;
+the chaos hook's stall.  The step after a drain takes its tokens from the
+host.  A drain costs the device what every step cost it before, no more, and
+nothing configures any of this.  A failure surfaces at a dispatch or at the
+fetch of the step in flight: every live and waiting caller gets it once, the
+step in flight is dropped (its slots counted stray).
+
 The parameters are stored once in the dtype the two programs read them in
 (``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
 ``.astype(cfg.dtype)`` they repeat): embedding tables, head, attention
@@ -61,7 +93,10 @@ result pools are its argument's buffers and no copy of a pool, whole or a
 layer's, is made or held.  Between a donating dispatch and the loop taking
 the result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single
 exec lane may touch the pools.  Callers outside the loop, while it is idle,
-have two kinds of view of the same executables.  ``_prefill_program`` /
+have two kinds of view of the same steps (the decode views' program is the
+loop's less its last result, the chosen tokens: ``_decode_donating``, the
+same function under ``jax.jit``, beside the loop's ``_decode_next_donating``).
+``_prefill_program`` /
 ``_decode_program`` hand them a copy of the pools they are given and never
 consume their arguments (a test, a tool that wants both).  The three-result
 ``_prefill`` / ``_decode`` copy nothing, because a pool may be the largest
@@ -100,15 +135,21 @@ Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
 profile beside the device's programs (``LLMServer.profile``); the two
-thread crossings of a step ride as the ``submit_us`` and ``resume_us``
-attributes of the region that follows them.  A step's six boundaries
+thread crossings of a call ride as the ``submit_us`` and ``resume_us``
+attributes of the region that follows them.  A call of the exec lane is a
+decode step's dispatch and, behind it, the fetch of the step before
+(``ahead`` 1 on the ``.decode.dispatch``), a dispatch alone on a drained pipe
+(``ahead`` 0), a drain's fetch alone, or a prefill; each ends in a
+``.deliver``, one that fetched nothing with no tokens.  A call's six boundaries
 (submitted on the loop thread; dispatch start, dispatch end and returned on
 the exec lane; ``_deliver``'s entry and exit on the loop thread again) are
 each read on up to three clocks (``_Clocks``: wall, the reading thread's
 CPU, the loop thread's CPU), and that one set of reads feeds both the
 regions' attributes (``tracing``'s module docstring has the convention:
 ``dispatch_us`` / ``dispatch_cpu_us`` / ``dispatch_loop_cpu_us`` on
-``.decode.fetch``, ``fetch_loop_cpu_us`` and ``resume_loop_cpu_us`` on
+``.decode.fetch``: the dispatch phases that ended on the lane since the fetch
+before, step N+1's beside step N's fetch, 0 on a drain's;
+``fetch_loop_cpu_us`` and ``resume_loop_cpu_us`` on
 ``.deliver``, ``step_us`` / ``step_loop_cpu_us`` on ``.decode.dispatch``)
 and the always-on sums ``stats()["host_s"]`` / ``["host_cpu_s"]``: wall less
 the exec lane's own CPU is what it spent not running (the GIL, a lock of
@@ -119,7 +160,8 @@ calls, read for every call while a profiler session records and for one
 call in ``_CPU_EVERY`` otherwise.  The collector's passes are ``rt:gc``
 regions and
 ``stats()["gc"]`` (``tracing.watch_gc``).  ``stats()`` carries the
-always-on counters of the same places.
+always-on counters of the same places, ``decode_ahead_steps`` and
+``stray_slot_steps`` among them.
 
 A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
 ``model="llama"`` like any other.  Its two programs return a fourth result,
@@ -182,6 +224,18 @@ class _Clocks(NamedTuple):
 def _us(seconds: float) -> int:
     """An attribute of a region: whole microseconds."""
     return int(seconds * 1e6)
+
+
+class _Step(NamedTuple):
+    """A decode step the device has been handed and the host has not fetched:
+    the one step the loop keeps in flight."""
+    seqs: Dict[int, "_Sequence"]   # slot -> the sequence that was stepped
+    nxt: Any                       # int32[max_batch] on the device: every
+    #                                slot's next token, the next step's input
+    load: List[Any]                # an expert model's assignments, there too
+    # its dispatch phase's two boundaries where no fetch followed it in its
+    # call (a step dispatched on a drained pipe): the next fetch carries them
+    alone: Optional[Tuple[_Clocks, _Clocks]]
 
 
 @dataclasses.dataclass
@@ -331,8 +385,21 @@ class InferenceEngine:
         def _decode(params, token, pos, kp, vp, pt):
             return decode_fn(params, mc, token, pos, kp, vp, pt)
 
+        # The loop's decode step: the same, and every slot's next token
+        # chosen where the logits are, so that the step after it can take
+        # it from the device.  Under the same name: the device's timeline
+        # and its readers know the program as ``jit__decode``.
+        def _decode_next(params, token, pos, kp, vp, pt):
+            import jax.numpy as jnp
+            logits, *rest = _decode(params, token, pos, kp, vp, pt)
+            return (logits, *rest,
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        _decode_next.__name__ = _decode.__name__
+
         self._prefill_donating = jax.jit(_prefill, donate_argnums=(3, 4))
         self._decode_donating = jax.jit(_decode, donate_argnums=(3, 4))
+        self._decode_next_donating = jax.jit(_decode_next,
+                                             donate_argnums=(3, 4))
         self._kv_in_place: Dict[str, bool] = {}
         # What stats() says about where this engine runs: the device that
         # holds the KV pool, and how long each program took to be there
@@ -345,7 +412,7 @@ class InferenceEngine:
         # every rung, now, on a few threads, so whatever the caller does
         # between construction and its first request hides them.  The views
         # keep the jitted function, which takes any [1, S] and any tree.
-        # The loop's decode programs likewise: ``_decode_donating``
+        # The loop's decode programs likewise: ``_decode_next_donating``
         # compiled for every width of the decode ladder.
         self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
         self._decode_rungs = decode_rungs(self._maxp)
@@ -371,7 +438,10 @@ class InferenceEngine:
         self._wake = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         self._steps = 0
+        self._flight: Optional[_Step] = None   # the decode step in flight
         # Always-on counters, see stats().
+        self._decode_ahead_steps = 0
+        self._stray_slot_steps = 0
         self._admitted = 0
         self._queue_wait_s = 0.0
         self._prefill_tokens = 0
@@ -455,9 +525,14 @@ class InferenceEngine:
 
     def stats(self) -> Dict[str, Any]:
         """Gauges (``active``, ``waiting``, ``free_pages``) and counters
-        since the engine started: ``steps`` decode steps and the
+        since the engine started: ``steps`` decode steps dispatched, of them
+        ``decode_ahead_steps`` while the step before was in flight (the
+        rest on a drained pipe: module docstring), and the
         ``slot_steps`` live slots they carried (occupancy is
-        ``slot_steps / (steps * max_batch)``), ``admitted`` sequences and
+        ``slot_steps / (steps * max_batch)``), of them ``stray_slot_steps``
+        whose token nobody got (the sequence was retired with the step in
+        flight, or the step was dropped by a failure: every other slot step
+        is a token delivered), ``admitted`` sequences and
         the ``queue_wait_s`` they spent between ``generate()`` and their
         prefill's dispatch, ``prefill_tokens`` of prompt against the
         ``prefill_padded_tokens`` the padded programs ran (a prefill adds
@@ -492,7 +567,12 @@ class InferenceEngine:
         engine started, by phase, the phases tiling the loop's busy time:
         ``schedule`` (a delivery's end, or the loop's waking, to the next
         ``run_in_executor``), ``submit`` (to the exec lane running),
-        ``dispatch`` and ``fetch`` (a decode step's two regions; a prefill's
+        ``dispatch`` and ``fetch`` (the two regions of a decode call:
+        ``dispatch`` sends step N+1, ``fetch`` is then the wait for step N's
+        tokens with N+1 queued behind it, so over a settled batch it is the
+        device's step less the host's other phases, and the device idles
+        only where the phases together outlast its step; on a drained pipe
+        a call has the one or the other; a prefill's
         whole call counts under ``fetch``, its lane sends and waits in one
         region), ``resume`` (the lane's return to ``_deliver``) and
         ``deliver``.  ``host_cpu_s`` is CPU seconds beside them:
@@ -500,8 +580,9 @@ class InferenceEngine:
         the loop's waking) to the next: everything a step costs the loop, so
         ``loop`` over the sum of ``host_s`` is how full that thread is.  The
         other five are sums over ``host_cpu_calls`` of the lane's calls
-        (decode steps and prefills: every one while a profiler session
-        records, one in ``_CPU_EVERY`` otherwise, ``steps`` + ``admitted`` in
+        (decode steps, drains and prefills: every one while a profiler
+        session records, one in ``_CPU_EVERY`` otherwise; ``steps`` +
+        ``admitted`` + a drain for every step not dispatched ahead in
         all), so compare them per call: ``exec_dispatch`` and ``exec_fetch``
         the exec lane's own (``host_s["dispatch"]`` a call less
         ``exec_dispatch`` a sampled call is what the lane spent inside
@@ -514,7 +595,10 @@ class InferenceEngine:
         passes."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
-                "slot_steps": self._slot_steps, "admitted": self._admitted,
+                "decode_ahead_steps": self._decode_ahead_steps,
+                "slot_steps": self._slot_steps,
+                "stray_slot_steps": self._stray_slot_steps,
+                "admitted": self._admitted,
                 "queue_wait_s": self._queue_wait_s,
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
@@ -606,15 +690,15 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((1, self._maxp), jnp.int32))
 
     def _compile_decode_rung(self, width: int, params, kp, vp):
-        """``_decode_donating`` compiled for a page table of [max_batch,
-        ``width``] from the same shapes."""
+        """``_decode_next_donating`` compiled for a page table of
+        [max_batch, ``width``] from the same shapes."""
         import jax
         import jax.numpy as jnp
         slots = jax.ShapeDtypeStruct((self.config.max_batch,), jnp.int32)
         return self._compiled(
-            f"decode@{width}", self._decode_donating, params, slots, slots,
-            kp, vp, jax.ShapeDtypeStruct((self.config.max_batch, width),
-                                         jnp.int32))
+            f"decode@{width}", self._decode_next_donating, params, slots,
+            slots, kp, vp,
+            jax.ShapeDtypeStruct((self.config.max_batch, width), jnp.int32))
 
     def _donate_pools(self, program: str, step, a, b, pt):
         """One call of ``step`` (a donating program) on the engine's pools,
@@ -699,24 +783,46 @@ class InferenceEngine:
                 seq.queue.put_nowait(resilience.DeadlineExceeded(
                     "deadline expired while decoding"))
 
-    def _decode_inputs(self):
-        """One batched decode step's host arrays over every live slot.
-        Inactive slots run token 0 at pos 0 against an all-zero table
-        row — their writes land in scratch page 0.  The table is as wide
-        as the decode ladder's least rung that holds the longest live
-        sequence: the step writes at ``pos`` and reads ``pos + 1``
+    def _steppable(self) -> Dict[int, _Sequence]:
+        """slot -> sequence of the next decode step: every live sequence but
+        those whose last token is already on its way (``generated`` and the
+        token in flight make ``max_new``).  The host counts; it needs no
+        token for that."""
+        flying = self._flight.seqs if self._flight is not None else {}
+        return {slot: seq for slot, seq in self._active.items()
+                if seq.generated + (flying.get(slot) is seq) < seq.max_new}
+
+    def _follows_flight(self, stepped: Dict[int, _Sequence]) -> bool:
+        """Whether ``stepped`` can be dispatched behind the step in flight,
+        its tokens taken from that step's result on the device: somebody is
+        stepped, and each of them was stepped by it, in the same slot."""
+        flying = self._flight.seqs
+        return bool(stepped) and all(
+            flying.get(slot) is seq for slot, seq in stepped.items())
+
+    def _decode_inputs(self, stepped: Dict[int, _Sequence]):
+        """One batched decode step's host arrays over the slots of
+        ``stepped``.  Every other slot runs at pos 0 against an all-zero
+        table row — its write lands in scratch page 0.  The table is as
+        wide as the decode ladder's least rung that holds the longest
+        stepped sequence: the step writes at ``pos`` and reads ``pos + 1``
         positions, so the page that holds ``pos`` is the last it needs,
-        and a row's first pages are the sequence's first pages."""
+        and a row's first pages are the sequence's first pages.  ``token``
+        is the sequences' last tokens as the host knows them or, with a
+        step in flight, that step's result where it lies on the device."""
         cfg = self.config
-        longest = max(seq.pos for seq in self._active.values())
+        longest = max(seq.pos for seq in stepped.values())
         width = rung_for(self._decode_rungs, longest // cfg.page_size + 1)
-        token = np.zeros((cfg.max_batch,), np.int32)
         pos = np.zeros((cfg.max_batch,), np.int32)
         tables = np.zeros((cfg.max_batch, width), np.int32)
-        for slot, seq in self._active.items():
-            token[slot] = seq.last_token
+        for slot, seq in stepped.items():
             pos[slot] = seq.pos
             tables[slot] = seq.row[:width]
+        if self._flight is not None:
+            return self._flight.nxt, pos, tables
+        token = np.zeros((cfg.max_batch,), np.int32)
+        for slot, seq in stepped.items():
+            token[slot] = seq.last_token
         return token, pos, tables
 
     def _clocks(self, cpu: bool, on_loop: bool = False) -> _Clocks:
@@ -747,14 +853,17 @@ class InferenceEngine:
         self._step_from = self._clocks(True, on_loop=True)
         self._loop_free_at = self._step_from.wall
 
-    def _deliver(self, tokens: Dict[int, int], submitted: _Clocks,
-                 lane: Tuple[_Clocks, _Clocks, _Clocks]):
-        """Push each slot's token to its caller and retire what finished.
-        ``lane`` is the exec thread's three boundaries of the call that made
-        the tokens (dispatch start, dispatch end, returned; a prefill's
-        first two are one), ``submitted`` the loop's before it: the call's
-        phases are added to ``stats()``'s sums here, and those that no
-        region has carried yet ride on this one."""
+    def _deliver(self, tokens: List[Tuple[_Sequence, int]],
+                 submitted: _Clocks, lane: Tuple[_Clocks, _Clocks, _Clocks]):
+        """Push each sequence's token to its caller and retire what
+        finished; every call of the exec lane ends here, one that fetched
+        nothing (a step dispatched on a drained pipe) with no tokens.
+        ``lane`` is the exec thread's three boundaries of the call
+        (dispatch start, dispatch end, returned; the first two are one
+        where it dispatched nothing, the last two where it fetched
+        nothing), ``submitted`` the loop's before it: the call's phases are
+        added to ``stats()``'s sums here, and those that no region has
+        carried yet ride on this one."""
         start, sent, returned = lane
         sampled = returned.cpu is not None
         entry = self._clocks(sampled, on_loop=True)
@@ -776,13 +885,121 @@ class InferenceEngine:
             attrs["resume_loop_cpu_us"] = _us(
                 entry.loop_cpu - returned.loop_cpu)
         with region("engine.deliver", tokens=len(tokens), **attrs):
-            for slot, token in tokens.items():
-                seq = self._active[slot]
+            for seq, token in tokens:
                 if self._push(seq, token) or seq.cancelled:
                     self._retire(seq, "cancelled" if seq.cancelled
                                  else "done")
         self._loop_free_at = time.perf_counter()
         wall["deliver"] += self._loop_free_at - entry.wall
+
+    def _fetch(self, step: _Step, dispatched, **crossing):
+        """On the exec lane: wait for ``step``'s tokens (and an expert
+        model's assignments), whose copy down began at its dispatch.  The
+        region carries the dispatch phases that ended on this lane since
+        the fetch before it: ``dispatched`` (start, end), the step this
+        call has just queued behind ``step``, and ``step``'s own where it
+        was dispatched alone; zeros where neither (the pipe drains)."""
+        phases = [p for p in (step.alone, dispatched) if p is not None]
+        attrs = {"dispatch_us": _us(sum(
+            sent.wall - start.wall for start, sent in phases))}
+        if all(start.cpu is not None for start, _ in phases):
+            attrs["dispatch_cpu_us"] = _us(sum(
+                sent.cpu - start.cpu for start, sent in phases))
+            attrs["dispatch_loop_cpu_us"] = _us(sum(
+                sent.loop_cpu - start.loop_cpu for start, sent in phases))
+        with region("engine.decode.fetch", **attrs, **crossing):
+            return np.asarray(step.nxt), [np.asarray(a) for a in step.load]
+
+    def _deliver_step(self, step: _Step, fetched, submitted: _Clocks,
+                      lane: Tuple[_Clocks, _Clocks, _Clocks]):
+        """``step``'s fetched tokens to the sequences it stepped.  One that
+        was retired while the step was in flight (an ``eos_token``, a
+        cancellation, a deadline: what the host could not foresee) gets
+        nothing, whoever holds its slot now: a stray slot step."""
+        nxt, load = fetched
+        self._count_moe("decode", load)
+        tokens = []
+        for slot, seq in step.seqs.items():
+            if self._active.get(slot) is seq:
+                tokens.append((seq, int(nxt[slot])))
+            else:
+                self._stray_slot_steps += 1
+        self._deliver(tokens, submitted, lane)
+
+    async def _drain(self, loop):
+        """Fetch and deliver the step in flight with nothing queued behind
+        it: the device is idle from its end to the next dispatch, as it was
+        after every step before the loop ran ahead."""
+        step, self._flight = self._flight, None
+        submitted, sampled = self._submit()
+
+        def _call():
+            start = self._clocks(sampled)
+            fetched = self._fetch(
+                step, None, submit_us=_us(start.wall - submitted.wall))
+            return fetched, (start, start, self._clocks(sampled))
+        fetched, lane = await loop.run_in_executor(self._exec, _call)
+        self._deliver_step(step, fetched, submitted, lane)
+
+    async def _decode_step(self, loop, stepped: Dict[int, _Sequence], batch):
+        """Dispatch one decode step over ``stepped``, whose host arrays are
+        ``batch`` (``_decode_inputs``).  With a step in
+        flight this one is queued behind it, its tokens that step's result
+        on the device, and the same call of the exec lane then fetches that
+        step's tokens, which are delivered while the device runs this one.
+        On a drained pipe the tokens are the host's and the call fetches
+        nothing.  Either way this step is the one in flight afterwards."""
+        cfg = self.config
+        prev = self._flight
+        token, pos, tables = batch
+        width = tables.shape[1]
+        program = self._decode_programs[width].result()
+        active = len(stepped)
+        # what the step's paged read is for, and what it gathers
+        live_tokens = int(pos.sum()) + active
+        gathered_tokens = tables.size * cfg.page_size
+        submitted, sampled = self._submit(cpu=True)
+        # everything the step before cost the loop: its delivery,
+        # the streams' fan-out, schedule, the prefills between
+        step_s = submitted.wall - self._step_from.wall
+        step_loop_cpu_s = submitted.loop_cpu - self._step_from.loop_cpu
+        self._host_cpu_s["loop"] += step_loop_cpu_s
+        self._step_from = submitted
+
+        def _call():
+            start = self._clocks(sampled)
+            with region("engine.decode.dispatch", active=active,
+                        live_tokens=live_tokens,
+                        gathered_tokens=gathered_tokens,
+                        width_pages=width, ahead=int(prev is not None),
+                        submit_us=_us(start.wall - submitted.wall),
+                        step_us=_us(step_s),
+                        step_loop_cpu_us=_us(step_loop_cpu_s)):
+                _, kp, vp, *load, nxt = self._donate_pools(
+                    "decode", program, token, pos, tables)
+                for a in (nxt, *load):   # on their way once the step ends
+                    a.copy_to_host_async()
+            sent = self._clocks(sampled)
+            if prev is None:
+                return _Step(stepped, nxt, load, (start, sent)), kp, vp, \
+                    None, (start, sent, sent)
+            fetched = self._fetch(prev, (start, sent))
+            return _Step(stepped, nxt, load, None), kp, vp, fetched, \
+                (start, sent, self._clocks(sampled))
+        self._flight, self._k_pages, self._v_pages, fetched, lane = \
+            await loop.run_in_executor(self._exec, _call)
+        self._steps += 1
+        self._decode_ahead_steps += prev is not None
+        self._decode_shapes[width] += 1
+        self._slot_steps += active
+        self._kv_live_token_steps += live_tokens
+        self._kv_gathered_token_steps += gathered_tokens
+        for seq in stepped.values():
+            seq.pos += 1
+        if prev is None:
+            self._deliver([], submitted, lane)
+        else:
+            self._deliver_step(prev, fetched, submitted, lane)
 
     def _count_moe(self, program: str, load: Sequence[np.ndarray]):
         """What an expert model's program said of its real tokens' routing
@@ -836,8 +1053,18 @@ class InferenceEngine:
                              if not s.prefilled]
                     # with nobody to prefill the batch is settled: its
                     # arrays are part of the same stretch of host work
-                    batch = self._decode_inputs() \
-                        if self._active and not fresh else None
+                    stepped = {} if fresh else self._steppable()
+                    drain = self._flight is not None \
+                        and not self._follows_flight(stepped)
+                    batch = self._decode_inputs(stepped) \
+                        if stepped and not drain else None
+                if drain:
+                    # An admission needs its prefill's token on the host
+                    # and changes the batch; or everybody in flight has
+                    # its last token coming.  What is delivered may free a
+                    # slot: schedule again.
+                    await self._drain(loop)
+                    continue
                 if not self._active:
                     if self._waiting:
                         continue   # admission makes progress every pass
@@ -850,7 +1077,7 @@ class InferenceEngine:
                     continue
 
                 # Prefill new admissions one at a time (B=1), each padded
-                # to its prompt's rung.
+                # to its prompt's rung; the pipe is drained.
                 for seq in fresh:
                     S = rung_for(self._rungs, len(seq.prompt))
                     program = self._rung_programs[S].result()
@@ -881,79 +1108,35 @@ class InferenceEngine:
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
                     self._count_moe("prefill", load)
-                    self._deliver({seq.slot: tok}, submitted, lane)
+                    self._deliver([(seq, tok)], submitted, lane)
 
                 if not self._active:
                     continue
                 # Chaos hook: a stalled decode (wedged device, stuck
                 # dispatch) is indistinguishable from a dead replica to
                 # the client — the ingress's stall detector must fail the
-                # stream over.  The hook injects exactly that.
+                # stream over.  The hook injects exactly that: the step in
+                # flight finishes and is delivered, and no other follows.
                 from ray_tpu.util import fault_injection
                 stall = fault_injection.stall_replica_decode_s()
                 if stall:
+                    if self._flight is not None:
+                        await self._drain(loop)
                     await asyncio.sleep(stall)
+                    continue   # (the hook fires once) schedule again
                 if batch is None:   # the prefills changed the batch
                     with region("engine.schedule", active=len(self._active),
                                 waiting=len(self._waiting)):
-                        batch = self._decode_inputs()
-                token, pos, tables = batch
-                width = tables.shape[1]
-                program = self._decode_programs[width].result()
-                active = len(self._active)
-                # what the step's paged read is for, and what it gathers
-                live_tokens = int(pos.sum()) + active
-                gathered_tokens = tables.size * cfg.page_size
-                submitted, sampled = self._submit(cpu=True)
-                # everything the step before cost the loop: its delivery,
-                # the streams' fan-out, schedule, the prefills between
-                step_s = submitted.wall - self._step_from.wall
-                step_loop_cpu_s = \
-                    submitted.loop_cpu - self._step_from.loop_cpu
-                self._host_cpu_s["loop"] += step_loop_cpu_s
-                self._step_from = submitted
-
-                def _step():
-                    start = self._clocks(sampled)
-                    with region("engine.decode.dispatch", active=active,
-                                live_tokens=live_tokens,
-                                gathered_tokens=gathered_tokens,
-                                width_pages=width,
-                                submit_us=_us(start.wall - submitted.wall),
-                                step_us=_us(step_s),
-                                step_loop_cpu_us=_us(step_loop_cpu_s)):
-                        logits, kp, vp, *load = self._donate_pools(
-                            "decode", program, token, pos, tables)
-                        nxt = jnp.argmax(logits, axis=-1)
-                        for a in load:   # on its way beside the tokens
-                            a.copy_to_host_async()
-                    sent = self._clocks(sampled)
-                    attrs = {"dispatch_us": _us(sent.wall - start.wall)}
-                    if sampled:
-                        attrs["dispatch_cpu_us"] = _us(sent.cpu - start.cpu)
-                        attrs["dispatch_loop_cpu_us"] = _us(
-                            sent.loop_cpu - start.loop_cpu)
-                    with region("engine.decode.fetch", **attrs):
-                        nxt = np.asarray(nxt)
-                        load = [np.asarray(a) for a in load]
-                    return nxt, kp, vp, load, \
-                        (start, sent, self._clocks(sampled))
-                nxt, self._k_pages, self._v_pages, load, lane = \
-                    await loop.run_in_executor(self._exec, _step)
-                self._steps += 1
-                self._decode_shapes[width] += 1
-                self._slot_steps += active
-                self._kv_live_token_steps += live_tokens
-                self._kv_gathered_token_steps += gathered_tokens
-                self._count_moe("decode", load)
-                for seq in self._active.values():
-                    seq.pos += 1
-                self._deliver({slot: int(nxt[slot])
-                               for slot in self._active}, submitted, lane)
+                        stepped = self._steppable()
+                        batch = self._decode_inputs(stepped)
+                await self._decode_step(loop, stepped, batch)
             except asyncio.CancelledError:
                 raise
             except Exception as e:   # noqa: BLE001
                 logger.exception("inference engine step failed")
+                if self._flight is not None:   # its tokens are nobody's
+                    self._stray_slot_steps += len(self._flight.seqs)
+                    self._flight = None
                 for seq in list(self._active.values()):
                     self._retire(seq, "error")
                     seq.queue.put_nowait(e)

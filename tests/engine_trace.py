@@ -1,7 +1,15 @@
 """A tiny engine decoding two sequences under the JAX profiler: the trace
 that ``test_tracing_regions.py`` checks region by region and that
 ``tests/benchmark/test_host_regions.py`` feeds to the benchmark's reader.
-No cluster; one run a process, whoever asks first."""
+No cluster; one run a process, whoever asks first.
+
+The second prompt arrives when the first sequence's first token does, so
+the trace holds both orders of the engine's loop: the first decode step is
+dispatched alone and, because somebody then waits for a prefill, fetched
+with nothing behind it (a drain: the order every step had before the loop
+ran ahead); from the second on each step is dispatched before the one
+before it is fetched, until the last token drains the pipe again.  The
+first sequence takes decode steps 1-5, the second steps 2-4."""
 
 import asyncio
 import functools
@@ -51,8 +59,11 @@ def run() -> dict:
         jax.profiler.start_trace(trace_dir)
         try:
             gc.collect()
-            tokens = await asyncio.gather(
-                *[consume(p, n) for p, n in zip(PROMPTS, NEW_TOKENS)])
+            first = engine.generate(PROMPTS[0], NEW_TOKENS[0])
+            head = await first.__anext__()
+            second = asyncio.ensure_future(
+                consume(PROMPTS[1], NEW_TOKENS[1]))
+            tokens = [[head] + [t async for t in first], await second]
         finally:
             jax.profiler.stop_trace()
         stats = engine.stats()

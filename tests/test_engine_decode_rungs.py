@@ -176,8 +176,8 @@ def test_the_rung_falls_when_a_long_sequence_retires_and_callers_wait():
     widths = []
     real = engine._decode_inputs
 
-    def watched():
-        batch = real()
+    def watched(stepped):
+        batch = real(stepped)
         widths.append(batch[2].shape[1])
         return batch
     engine._decode_inputs = watched

@@ -385,22 +385,26 @@ def test_the_kv_counters_and_the_dispatch_attributes_count_the_steps():
     rungs = decode_rungs(maxp)
     assert rungs == (1, 2, 3)
 
-    def live(prompts, new):
+    def live(prompts, new, joins):
         """Per step, the positions the live sequences hold (a sequence's
-        first token comes from its prefill, each later one from a step),
-        and the width in pages of the step's table."""
-        steps = np.zeros((max(new) - 1,), np.int64)
-        longest = np.zeros((max(new) - 1,), np.int64)
-        for prompt, n in zip(prompts, new):
+        first token comes from its prefill, each later one from a step;
+        ``joins`` is the step each sequence's first one is), and the width
+        in pages of the step's table."""
+        last = max(j + n - 1 for j, n in zip(joins, new))
+        steps = np.zeros((last,), np.int64)
+        longest = np.zeros((last,), np.int64)
+        for prompt, n, j in zip(prompts, new, joins):
             held = len(prompt) + 1 + np.arange(n - 1)
-            steps[:n - 1] += held
-            longest[:n - 1] = np.maximum(longest[:n - 1], held)
+            steps[j:j + n - 1] += held
+            longest[j:j + n - 1] = np.maximum(longest[j:j + n - 1], held)
         # the step writes at the last of the positions it then holds
         return steps, [rung_for(rungs, (n - 1) // page + 1) for n in longest]
 
     warm, warm_widths = live([engine_trace.WARM_PROMPT],
-                             [engine_trace.WARM_NEW])
-    traced, widths = live(engine_trace.PROMPTS, engine_trace.NEW_TOKENS)
+                             [engine_trace.WARM_NEW], [0])
+    # the second prompt arrives while the first sequence's first step runs
+    traced, widths = live(engine_trace.PROMPTS, engine_trace.NEW_TOKENS,
+                          [0, 1])
     assert warm_widths == [1] and widths == [1, 1, 1, 2, 2]
     gathered = [engine_trace.MAX_BATCH * w * page for w in widths]
     assert stats["steps"] == len(warm) + len(traced)

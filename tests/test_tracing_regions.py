@@ -117,21 +117,48 @@ def test_engine_regions_follow_the_decode_step(engine_run):
              and not (name == "rt:engine.schedule"
                       and stats["active"] == stats["waiting"] == 0)]
     steps = max(engine_trace.NEW_TOKENS) - 1
-    # a prefill is followed by the delivery of its first token; what is
-    # left is the decode steps
-    rest, i = [], 0
-    while i < len(names):
-        if names[i] == "prefill":
-            assert names[i + 1] == "deliver", names
-            i += 2
-        else:
-            rest.append(names[i])
-            i += 1
     assert names.count("prefill") == len(engine_trace.PROMPTS)
-    step = ["schedule", "decode.dispatch", "decode.fetch", "deliver"]
-    # the pass that admits and prefills builds the batch in a second
-    # ``schedule``; every later step has exactly one
-    assert rest == ["schedule"] + step * steps, names
+    assert names.count("decode.dispatch") == names.count("decode.fetch") \
+        == steps
+    # every call of the exec lane follows a ``schedule`` and ends in a
+    # ``deliver``.  A prefill's delivers its first token.  A step
+    # dispatched on a drained pipe has nothing to deliver yet (the pass
+    # that prefilled builds its batch in a ``schedule`` of its own); a
+    # step dispatched behind the one in flight is followed, in the same
+    # call, by the fetch of that one; a drain fetches with nothing
+    # dispatched.
+    admit = ["schedule", "prefill", "deliver"]
+    alone = ["schedule", "decode.dispatch", "deliver"]
+    ahead = ["schedule", "decode.dispatch", "decode.fetch", "deliver"]
+    drain = ["schedule", "decode.fetch", "deliver"]
+    # the second prompt arrives while step 1 is in flight: the pipe drains
+    # for its prefill, fills again, and drains at the last token
+    assert names == admit + alone + drain + admit + alone \
+        + ahead * (steps - 2) + drain, names
+
+
+def test_the_trace_holds_a_drained_stretch_and_an_ahead_stretch(engine_run):
+    """Drained: a dispatch, then its own fetch.  Ahead: step N+1's dispatch
+    ends before step N's fetch begins, on the one exec lane."""
+    dispatches = [(start, end, stats) for n, start, end, stats
+                  in engine_run["regions"] if n == D]
+    fetches = [(start, end) for n, start, end, _ in engine_run["regions"]
+               if n == F]
+    assert [stats["ahead"] for _, _, stats in dispatches] == [0, 0, 1, 1, 1]
+    # step N's fetch is the N-th: the lane fetches in order of dispatch
+    for n, (start, end, stats) in enumerate(dispatches):
+        before = sum(f_start < start for f_start, _ in fetches)
+        # a step behind another is dispatched with that one still unfetched
+        assert before == (n - 1 if stats["ahead"] else n), (n, before)
+        assert fetches[n][0] >= end
+    # step 1 is fetched before step 2 is dispatched (the drain for the
+    # second prompt's prefill); step 2 only after step 3's dispatch
+    assert fetches[0][1] <= dispatches[1][0]
+    assert dispatches[2][1] <= fetches[1][0]
+    grown = {k: engine_run["stats"][k] - engine_run["stats_before"][k]
+             for k in ("steps", "decode_ahead_steps", "stray_slot_steps")}
+    assert grown == {"steps": 5, "decode_ahead_steps": 3,
+                     "stray_slot_steps": 0}
 
 
 def test_engine_regions_carry_their_attributes(engine_run):
@@ -145,10 +172,11 @@ def test_engine_regions_carry_their_attributes(engine_run):
         assert stats["padded_len"] == rung_for(RUNGS, stats["prompt_len"])
         assert stats["waited_us"] >= stats["submit_us"] >= 0
     dispatches = by_name["rt:engine.decode.dispatch"]
-    # both sequences decode until the shorter one is done
+    # the first sequence alone, then both until the shorter one has its
+    # last token coming (a step is not dispatched for it), then the longer
     short, long = sorted(engine_trace.NEW_TOKENS)
     assert [s["active"] for _, _, s in dispatches] == \
-        [2] * (short - 1) + [1] * (long - short)
+        [1] + [2] * (short - 1) + [1] * (long - short - 1)
     assert all(s["submit_us"] >= 0 for _, _, s in dispatches)
     # the step's table is as wide as its longest sequence needs: the
     # longer prompt (5) grows to 10 positions, over the first page's 8
@@ -162,18 +190,26 @@ def test_engine_regions_carry_their_attributes(engine_run):
     assert all(s["resume_us"] >= 0 for _, _, s in delivers)
     schedules = [s for s in by_name["rt:engine.schedule"]
                  if s[2]["active"] or s[2]["waiting"]]
-    assert (schedules[0][2]["active"], schedules[0][2]["waiting"]) == (0, 2)
-    # the crossing into the exec thread starts where ``schedule`` ends
-    # (attributes are whole microseconds), and it is THIS step's: the step
-    # before was delivered before it
-    for i, ((start, _, stats), (_, sched_end, _)) in enumerate(
-            zip(dispatches, schedules[1:])):
+    assert (schedules[0][2]["active"], schedules[0][2]["waiting"]) == (0, 1)
+    # the crossing into the exec thread starts where the ``schedule``
+    # before it ends (attributes are whole microseconds).  A step on a
+    # drained pipe is submitted after the step before it was delivered; a
+    # step behind another before that one is even fetched.
+    fetches = by_name["rt:engine.decode.fetch"]
+    for i, (start, _, stats) in enumerate(dispatches):
         submitted = start - stats["submit_us"] * 1e3
+        sched_end = max(end for _, end, _ in schedules if end <= start)
         assert sched_end - 2e3 <= submitted <= sched_end + SUBMIT_SLACK_NS
-        if i:
+        if i and not stats["ahead"]:
             delivered = min(end for begun, end, _ in delivers
-                            if begun > dispatches[i - 1][0])
+                            if begun > fetches[i - 1][0])
             assert delivered <= submitted
+        elif i:
+            assert submitted <= fetches[i - 1][0]
+    # a drain's crossing rides on its fetch, the first region of its call
+    assert ["submit_us" in s for _, _, s in fetches] == \
+        [not following["ahead"] for _, _, following
+         in dispatches[1:]] + [True]
 
 
 def test_engine_counters_add_up(engine_run):
@@ -242,8 +278,8 @@ def test_step_us_tiles_the_wall_between_submissions(engine_run):
     between = sum(stats["step_us"] for _, stats in dispatches[1:])
     assert between * 1e3 == pytest.approx(submitted[-1] - submitted[0],
                                           abs=1e6)
-    # the first step counts from the loop's waking: the two prefills and
-    # their deliveries lie before it
+    # the first step counts from the loop's waking: the first prefill and
+    # its delivery lie before it
     first_prefill = min(start for n, start, _, _ in engine_run["regions"]
                         if n == "rt:engine.prefill")
     assert dispatches[0][1]["step_us"] * 1e3 >= submitted[0] - first_prefill
@@ -272,7 +308,8 @@ def test_host_sums_tile_no_more_than_the_engines_life(engine_run):
 
 
 @pytest.mark.parametrize("table, key, attributes", [
-    ("host_s", "submit", {D: "submit_us", "rt:engine.prefill": "submit_us"}),
+    ("host_s", "submit", {D: "submit_us", "rt:engine.prefill": "submit_us",
+                          F: "submit_us"}),
     ("host_s", "dispatch", {F: "dispatch_us"}),
     ("host_s", "resume", {DELIVER: "resume_us"}),
     ("host_cpu_s", "exec_dispatch", {F: "dispatch_cpu_us"}),
@@ -288,8 +325,11 @@ def test_the_regions_and_the_sums_come_from_one_set_of_reads(
     rounding down to whole microseconds."""
     grown = engine_run["stats"][table][key] \
         - engine_run["stats_before"][table][key]
+    # (a crossing rides on the first region of its call: of the fetches,
+    # on a drain's alone)
     carried = [stats[attr] for name, attr in attributes.items()
-               for stats in _stats_of(engine_run, name)]
+               for stats in _stats_of(engine_run, name)
+               if name != F or attr in stats]
     assert 0 <= grown * 1e6 - sum(carried) <= len(carried) + 1e-3
 
 
@@ -309,14 +349,19 @@ def test_the_cpu_clocks_are_read_for_a_sample_of_the_calls_untraced(
         engine_run):
     """With no session to carry them, one call in ``_CPU_EVERY`` reads the
     thread CPU clocks between its submission and its delivery; under a
-    session every call does, and ``host_cpu_calls`` says how many did."""
+    session every call does, and ``host_cpu_calls`` says how many did.  The
+    lane's calls are the prefills, the decode steps and the drains: one for
+    every step that was dispatched on a drained pipe."""
     from ray_tpu.serve.engine.engine import _CPU_EVERY
     before, after = engine_run["stats_before"], engine_run["stats"]
-    calls_before = before["steps"] + before["admitted"]
-    assert calls_before > 1
-    assert before["host_cpu_calls"] == -(-calls_before // _CPU_EVERY)
+
+    def calls(stats):
+        return stats["admitted"] + stats["steps"] \
+            + stats["steps"] - stats["decode_ahead_steps"]
+    assert calls(before) > 1
+    assert before["host_cpu_calls"] == -(-calls(before) // _CPU_EVERY)
     assert after["host_cpu_calls"] - before["host_cpu_calls"] == \
-        after["steps"] + after["admitted"] - calls_before
+        calls(after) - calls(before)
     # the wall is read at every call all the same
     assert all(v > 0 for v in before["host_s"].values()), before["host_s"]
 
