@@ -117,10 +117,16 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    head_size: int = 0               # a head's width; 0: embed_dim / heads
+    qk_norm_per_head: bool = False   # RMSNorm q and k, each head by itself
+    block_length: int = 0            # > 0: generation by diffusion over
+    denoise_steps: int = 0           #   blocks of this many positions, in
+    confidence_threshold: float = 0.0   # this many passes (0 = static:
+    mask_token: int = 0              #   the count alone), masks of this id
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.head_size or self.embed_dim // self.num_heads
 
     @staticmethod
     def llama_125m() -> "LlamaConfig":
@@ -157,6 +163,32 @@ def _check(cfg: LlamaConfig) -> None:
     if cfg.hc_mult and (cfg.post_norm or cfg.ut_steps > 1):
         raise ValueError("a hyper-connected residual (hc_mult) is not "
                          "written for post_norm or ut_steps > 1")
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        raise ValueError("qk_norm pools all heads, qk_norm_per_head each "
+                         "head by itself: one or the other")
+    if (cfg.head_size or cfg.qk_norm_per_head) and cfg.kv_lora_rank:
+        raise ValueError("latent attention has head widths of its own "
+                         "(qk_nope_dim, qk_rope_dim, v_head_dim) and norms "
+                         "its bottlenecks, not head_size or "
+                         "qk_norm_per_head")
+    if cfg.block_length:
+        if cfg.kv_lora_rank or cfg.ut_steps > 1 or cfg.hc_mult:
+            raise ValueError("generation by blocks (block_length) is not "
+                             "written for latent attention, ut_steps > 1 "
+                             "or hc_mult")
+        if not 1 <= cfg.denoise_steps <= cfg.block_length:
+            raise ValueError(f"denoise_steps={cfg.denoise_steps} must be "
+                             f"in 1..block_length={cfg.block_length}")
+        if not 0 <= cfg.mask_token < cfg.vocab_size:
+            raise ValueError(f"mask_token={cfg.mask_token} is not an id of "
+                             f"the vocabulary of {cfg.vocab_size}")
+        if not 0.0 <= cfg.confidence_threshold < 1.0:
+            raise ValueError("confidence_threshold is a probability under "
+                             f"1 (0: static), not "
+                             f"{cfg.confidence_threshold}")
+    elif cfg.denoise_steps or cfg.confidence_threshold or cfg.mask_token:
+        raise ValueError("denoise_steps, confidence_threshold and "
+                         "mask_token belong to a block_length")
 
 
 def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
@@ -204,6 +236,9 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
         norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
                  "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
             if cfg.qk_norm else {}
+        if cfg.qk_norm_per_head:     # one scale for every head's H values
+            norms = {"q_norm": jnp.ones((L, H), jnp.float32),
+                     "k_norm": jnp.ones((L, H), jnp.float32)}
         attn = {"wq": normal(k[1], (L, D, nh, H)),
                 "wkv": normal(k[2], (L, D, 2, nkv, H)),
                 "wo": normal(k[3], (L, nh, H, D), rscale),
@@ -271,6 +306,8 @@ def _group_axes(cfg: LlamaConfig, experts: bool) -> Dict[str, Any]:
     else:
         norms = {"q_norm": ("layers", "heads", "kv"),
                  "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
+        if cfg.qk_norm_per_head:
+            norms = {"q_norm": ("layers", "kv"), "k_norm": ("layers", "kv")}
         attn = {"wq": ("layers", "embed", "heads", "kv"),
                 "wkv": ("layers", "embed", None, "heads", "kv"),
                 "wo": ("layers", "heads", "kv", "embed"),
@@ -375,16 +412,22 @@ def apply_rope_pairs(x, cos, sin):
         axis=-1).astype(x.dtype)
 
 
-def _dense_causal_attention_gqa(q, k, v, rep: int):
+def _dense_causal_attention_gqa(q, k, v, rep: int, block: int = 0):
     """Head-major grouped-query dense attention: q [B, N, S, H] with
     N = G*rep query heads sharing k/v [B, G, S, H].  Scores/output keep
-    the (group, rep) split so K/V never replicate in memory."""
+    the (group, rep) split so K/V never replicate in memory.  Causal, or
+    with ``block`` causal over blocks of that many positions: a position
+    sees its whole block, both ways, and every block before it."""
     import numpy as _np
     B, N, S, H = q.shape
     G = N // rep
     qg = q.reshape(B, G, rep, S, H)
     scores = jnp.einsum("bgrqh,bgkh->bgrqk", qg, k) / _np.sqrt(H)
-    mask = jnp.tril(jnp.ones((S, S), bool))
+    if block:
+        at = jnp.arange(S) // block
+        mask = at[None, :] <= at[:, None]
+    else:
+        mask = jnp.tril(jnp.ones((S, S), bool))
     scores = jnp.where(mask[None, None, None],
                        scores.astype(jnp.float32), -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -396,8 +439,13 @@ def _qk(cfg: LlamaConfig, p, q, k, cos, sin):
     """What happens to the head-major q [B, N, ..., H] and k [B, NKV, ...,
     H] between their projections and attention: with ``cfg.qk_norm`` an
     RMSNorm with a learned scale over the WHOLE projection, all heads
-    together (OLMoE norms before it splits into heads), then the rotation
-    at ``cos``/``sin``'s positions."""
+    together (OLMoE norms before it splits into heads), with
+    ``cfg.qk_norm_per_head`` one over EACH HEAD's values with a scale [H]
+    all heads share (Qwen3's ``q_norm`` / ``k_norm``); then the rotation at
+    ``cos``/``sin``'s positions."""
+    if cfg.qk_norm_per_head:
+        q = _rms_norm(q, p["attn"]["q_norm"], cfg.rms_eps)
+        k = _rms_norm(k, p["attn"]["k_norm"], cfg.rms_eps)
     if cfg.qk_norm:
         def norm(a, scale):          # scale [N, H], a's heads on axis 1
             scale = scale.reshape(scale.shape[0], *(1,) * (a.ndim - 3), -1)
@@ -720,6 +768,12 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
                  mesh=None) -> jax.Array:
     """tokens [B, S] int32 -> final hidden [B, S, D] after rms_norm (compute
     dtype) — the trunk without the LM head (see gpt_hidden)."""
+    if cfg.block_length:
+        raise NotImplementedError(
+            "models/llama.py serves its block-diffusion model "
+            "(block_length) through llama_prefill / llama_block_step; the "
+            "training trunk's attention is causal and the mask-predict "
+            "objective is not written")
     S = tokens.shape[1]
     if not cfg.kv_lora_rank and \
             resolve_attention(cfg.attention, S) == "flash":
@@ -790,6 +844,10 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     must never hand it out."""
     dt = dtype or cfg.dtype
     L = cfg.ut_steps * cfg.num_layers
+    if cfg.block_length and page_size % cfg.block_length:
+        raise ValueError(f"page_size={page_size} must be a multiple of "
+                         f"block_length={cfg.block_length}: a block's "
+                         "positions lie in one page")
     if cfg.kv_lora_rank:
         return jnp.zeros((L, num_pages, page_size * (
             cfg.kv_lora_rank + cfg.qk_rope_dim)), dt), None
@@ -846,7 +904,10 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     multiple of the page size; ``page_table`` [1, maxp];
     ``k_pages``/``v_pages`` [L, P, page, NKV*H], carried through the layer
     scan and written in place; a latent model's ``k_pages`` is its pool of
-    latent pages and its ``v_pages`` None.  An expert model returns a
+    latent pages and its ``v_pages`` None.  With ``cfg.block_length`` the
+    mask is causal over blocks (``length`` is then a whole number of blocks:
+    the prompt's trailing part of a block joins the first generated block)
+    and the logits are empty, ``[1, 0]``.  An expert model returns a
     fourth result, ``load`` [expert layers, E] int32: per layer and expert,
     the assignments of the prompt's real positions."""
     from ray_tpu.ops.paged_attention import prefill_kv, prefill_latent
@@ -875,7 +936,7 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
             q, k = _qk(cfg, p, q, k, cos, sin)
             pools = prefill_kv(kp, vp, layer, k[0], v[0], length,
                                page_table[0])
-            o = _dense_causal_attention_gqa(q, k, v, rep)
+            o = _dense_causal_attention_gqa(q, k, v, rep, cfg.block_length)
             return jnp.einsum("bnsh,nhd->bsd", o,
                               p["attn"]["wo"].astype(dt)), pools
 
@@ -888,6 +949,11 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
         cfg, params, lambda carry, t: _scan_layers(
             cfg, params, body, carry, t, served=True),
         (x, k_pages, v_pages))
+    if cfg.block_length:
+        # no logits: a block model's first token comes from its first
+        # block (llama_block_step), not from the prompt's last position
+        return _paged_results(jnp.zeros((1, 0), jnp.float32), k_pages,
+                              v_pages, load)
     last = x[0, length - 1]                              # [D]
     logits = jnp.einsum("d,dv->v", last,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
@@ -952,6 +1018,126 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     logits = jnp.einsum("bd,dv->bv", x,
                         params["lm_head"].astype(dt)).astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
+
+
+def llama_block_step(params: Dict[str, Any], cfg: LlamaConfig,
+                     state, end: jax.Array, k_pages: jax.Array,
+                     v_pages: jax.Array, page_table: jax.Array):
+    """One pass over a block of ``B = cfg.block_length`` positions a
+    sequence, for a BATCH of sequences: the decode step of a model that
+    generates by diffusion over blocks.  ``state`` is ``(tokens [S, B]
+    int32, masked [S, B] bool, pos0 [S] int32, passes [S] int32)``: the
+    block as it stands (``cfg.mask_token`` where ``masked``), the position
+    of its first row, and the denoise passes it has had (``block_unmask``
+    reads the last two).  ``end`` [S] is the position a sequence's last block
+    ends at; a slot with ``pos0 >= end`` (an empty one: 0, 0) is parked on
+    scratch page 0.  Row ``i`` is rotated at ``pos0 + i``; the block's K/V
+    go to the sequence's OWN positions ``pos0 .. pos0 + B - 1`` at EVERY
+    pass, a later pass overwriting an earlier one's, and all B query rows
+    see every position under ``pos0 + B``: the block both ways with no mask
+    inside it, and everything committed before it.  So a pass on a block
+    with masks left is a denoise pass, whose K/V nobody keeps, and the
+    pass on a block without any is the commit pass: what it leaves in the
+    pages is the K/V of the finished tokens, and the next block starts
+    above them.  Returns ``(logits [S, B, V] float32, k_pages, v_pages)``
+    and, from an expert model, ``load``; ``logits[s, i]`` predicts the token
+    AT ``pos0 + i`` (mask-predict, no shift)."""
+    from ray_tpu.ops.paged_attention import (append_block_kv,
+                                             paged_block_attention)
+    dt = cfg.dtype
+    B = cfg.block_length
+    tokens, _, pos0, _ = state
+    live = pos0 < end
+    pos0 = jnp.where(live, pos0, 0)
+    at = pos0[:, None] + jnp.arange(B)                   # [S, B]
+    cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
+    cos, sin = cos_t[at][:, None], sin_t[at][:, None]    # [S, 1, B, H/2]
+    x = _embed(cfg, params, tokens)                      # [S, B, D]
+    rows = jnp.broadcast_to(live[:, None], tokens.shape)
+
+    def body(experts, carry, inp):
+        (x, kp, vp), (p, layer) = carry, inp
+
+        def attention(h):
+            q = jnp.einsum("sbd,dnh->snbh", h, p["attn"]["wq"].astype(dt))
+            kv = jnp.einsum("sbd,dcnh->scnbh", h,
+                            p["attn"]["wkv"].astype(dt))
+            k_new, v_new = kv[:, 0], kv[:, 1]            # [S, NKV, B, H]
+            q, k_new = _qk(cfg, p, q, k_new, cos, sin)
+            pools = append_block_kv(kp, vp, layer, k_new, v_new, pos0,
+                                    page_table)
+            o = paged_block_attention(q, *pools, layer, pos0 + B,
+                                      page_table)
+            return jnp.einsum("snbh,nhd->sbd", o,
+                              p["attn"]["wo"].astype(dt)), pools
+
+        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
+        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
+            cfg, p, h, rows, experts=experts))
+        return (x, kp, vp), load
+
+    (x, k_pages, v_pages), load = _passes(
+        cfg, params, lambda carry, t: _scan_layers(
+            cfg, params, body, carry, t, served=True),
+        (x, k_pages, v_pages))
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("sbd,dv->sbv", x, params["lm_head"].astype(
+            dt)).astype(jnp.float32)
+    return _paged_results(logits, k_pages, v_pages, load)
+
+
+def block_unmask(cfg: LlamaConfig, logits: jax.Array, state,
+                 end: jax.Array) -> Dict[str, Any]:
+    """What a pass's ``logits`` [S, B, V] do to the blocks of ``state``
+    (``llama_block_step``'s), greedy, on the device: the next state and what
+    the host is told of the pass.
+
+    A block with masks left was denoised: ``x0 = argmax(logits)``, its
+    confidence the softmax's value there (float32), and of the masked
+    positions the ``n_t = B // T + (t < B % T)`` most confident are unmasked
+    to their ``x0`` (``T = cfg.denoise_steps``, ``t`` the block's passes so
+    far; ties to the lower position; all that are left if fewer), or, with
+    a ``cfg.confidence_threshold`` (SDAR's ``low_confidence_dynamic``),
+    every masked position above it where those are at least ``n_t``.  Which
+    positions are masked is the state's own boolean, never a comparison
+    with ``cfg.mask_token``: an argmax or a prompt may hit that id.  A block
+    without masks was committed by this pass: its tokens are ``emitted``,
+    ``pos0`` moves a block on and a fresh block starts, all masks.  A parked
+    slot (``pos0 >= end``) keeps its state.  Returns ``{"state": the next
+    state, "live" [S], "committed" [S] bool, "emitted" [S, B] (the block as
+    it stood), "passes" [S] (the denoise passes a committed block took),
+    "by_threshold" / "by_count" [S] (positions this pass unmasked by either
+    rule)}``."""
+    B, T = cfg.block_length, cfg.denoise_steps
+    tokens, masked, pos0, passes = state
+    with jax.named_scope("block_unmask"):
+        live = pos0 < end
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # [S, B]
+        peak = jnp.max(logits, axis=-1, keepdims=True)
+        conf = 1.0 / jnp.sum(jnp.exp(logits - peak), axis=-1)
+        conf = jnp.where(masked, conf, -jnp.inf)
+        n_t = B // T + (passes < B % T)                          # [S]
+        order = jnp.argsort(-conf, axis=-1, stable=True)
+        rank = jnp.argsort(order, axis=-1, stable=True)
+        counted = masked & (rank < n_t[:, None])
+        high = masked & (conf > cfg.confidence_threshold) \
+            if cfg.confidence_threshold else jnp.zeros_like(masked)
+        dynamic = (jnp.sum(high, axis=-1) >= n_t) \
+            if cfg.confidence_threshold else jnp.zeros_like(live)
+        unmask = jnp.where(dynamic[:, None], high, counted)
+        commit = live & ~jnp.any(masked, axis=-1)
+        denoise = (live & ~commit)[:, None]
+        step = commit[:, None]
+        nxt = (jnp.where(step, cfg.mask_token,
+                         jnp.where(denoise & unmask, x0, tokens)),
+               jnp.where(step, True, masked & ~(denoise & unmask)),
+               pos0 + B * commit,
+               jnp.where(commit, 0, passes + (live & ~commit)))
+        done = jnp.sum(denoise & unmask, axis=-1, dtype=jnp.int32)
+        return {"state": nxt, "live": live, "committed": commit,
+                "emitted": tokens, "passes": passes,
+                "by_threshold": jnp.where(dynamic, done, 0),
+                "by_count": jnp.where(dynamic, 0, done)}
 
 
 def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,

@@ -58,6 +58,11 @@ values (and, beside them, over the rotated key's columns, which mean
 nothing), which the caller expands.  Page 0, the page table
 and the layer index mean what they mean above.
 
+A model that generates by diffusion over blocks (``models/llama.py`` with
+``block_length``) steps ``B`` positions a sequence: ``append_block_kv``
+writes a block's rows and ``paged_block_attention`` reads for its ``B``
+query rows at once; the pages are the K/V kind above, no third kind.
+
 This file is the jnp reference implementation (gather + masked softmax
 — the decode working set is one token per sequence, so XLA's fused
 gather is adequate on CPU and fine on TPU at small batch; a Pallas
@@ -149,6 +154,56 @@ def append_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,
         slot = pos % page
         return (k_pages.at[layer, pid, slot].set(_folded(k_new, k_pages)),
                 v_pages.at[layer, pid, slot].set(_folded(v_new, v_pages)))
+
+
+def append_block_kv(k_pages: jax.Array, v_pages: jax.Array,
+                    layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                    pos0: jax.Array, page_table: jax.Array):
+    """Scatter a block of ``B`` positions' K/V per sequence into one layer
+    of the pools: ``k_new``/``v_new`` [S, NKV, B, H] (head-major, as the
+    rotation leaves them) go to positions ``pos0 .. pos0 + B - 1``; ``pos0``
+    [S] is a multiple of ``B`` and the page size a multiple of ``B``, so a
+    block lies in ONE page; ``page_table`` [S, maxp].  Inactive slots park
+    on page 0 (an all-zero row and ``pos0`` 0), as in ``append_kv``."""
+    page = k_pages.shape[2]
+    S, _, B, _ = k_new.shape
+    with jax.named_scope("paged_append"):
+        pid = jnp.take_along_axis(page_table, (pos0 // page)[:, None],
+                                  axis=1)                        # [S, 1]
+        slot = (pos0 % page)[:, None] + jnp.arange(B)            # [S, B]
+
+        def rows(x, pool):           # [S, NKV, B, H] -> [S, B, NKV*H]
+            return jnp.swapaxes(x, 1, 2).reshape(S, B, -1).astype(pool.dtype)
+        return (k_pages.at[layer, pid, slot].set(rows(k_new, k_pages)),
+                v_pages.at[layer, pid, slot].set(rows(v_new, v_pages)))
+
+
+def paged_block_attention(q: jax.Array, k_pages: jax.Array,
+                          v_pages: jax.Array, layer: jax.Array,
+                          lengths: jax.Array, page_table: jax.Array
+                          ) -> jax.Array:
+    """``paged_attention`` for ``B`` query rows a sequence that all see the
+    same positions: ``q`` [S, N, B, H]; every row attends to the positions
+    under ``lengths`` [S] (a block's rows see the whole block, both ways,
+    and everything before it: no mask inside the block, so ``lengths`` is
+    the block's end and its K/V must already be written).  Returns [S, N,
+    B, H] in q's dtype; softmax in f32."""
+    S, N, B, H = q.shape
+    page, NKV = k_pages.shape[2], k_pages.shape[3] // H
+    if N % NKV:
+        raise ValueError(f"query heads {N} not a multiple of KV heads {NKV}")
+    T = page_table.shape[1] * page
+    with jax.named_scope("paged_read"):
+        k = k_pages[layer, page_table].reshape(S, T, NKV, H)
+        v = v_pages[layer, page_table].reshape(S, T, NKV, H)
+    qg = q.reshape(S, NKV, N // NKV, B, H)
+    scores = jnp.einsum("skrbh,stkh->skrbt", qg, k) / np.sqrt(H)
+    valid = jnp.arange(T)[None] < lengths[:, None]              # [S, T]
+    scores = jnp.where(valid[:, None, None, None],
+                       scores.astype(jnp.float32), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("skrbt,stkh->skrbh", probs, v)
+    return out.reshape(S, N, B, H)
 
 
 def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,

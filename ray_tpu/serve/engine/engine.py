@@ -75,6 +75,46 @@ nothing configures any of this.  A failure surfaces at a dispatch or at the
 fetch of the step in flight: every live and waiting caller gets it once, the
 step in flight is dropped (its slots counted stray).
 
+A model that generates by **diffusion over blocks** (a ``LlamaConfig`` with
+``block_length`` B) comes through the same loop, and its decode step is
+another program under the same name: ``llama_block_step`` runs B positions a
+sequence (the block as it stands, masks where nothing is chosen yet) and
+``block_unmask`` chooses on the device which masks the pass lifts, so **a
+step yields 0 or up to B tokens a sequence**: none while its block has
+masks left (a denoise pass), the whole block when the step was handed it
+without any (the commit pass).  What feeds back on the device from step N
+to N+1 is the blocks' state (``tokens [max_batch, B]``, the boolean
+``masked`` beside them, never a comparison with the mask token's id,
+``pos0``, the block's passes so far) where the one-token step has ``token``;
+the host sends the page table and each slot's ``end`` (the position its last
+block ends at; 0 parks the slot on page 0).  **Every pass writes its block's
+K/V into the sequence's OWN reserved positions** ``pos0 .. pos0 + B - 1``
+and reads every position under ``pos0 + B``: a later pass overwrites an
+earlier one's rows, "a sequence writes a position before any step reads it"
+holds, and a commit is just the pass after which ``pos0`` advances, so one
+program serves a batch in which some slots denoise and some commit.  The host
+fetches step N's ``(committed, emitted, state)`` while N+1 runs and keeps
+each sequence's block as of the last step it fetched (``_Sequence.block``,
+``.masked``, ``.pos``, ``.passes``): **it learns a step late which masks a
+pass lifted**, but a block without masks is committed by the pass it is
+handed to, so the host knows every ``pos0`` of the step it dispatches (the
+table's width, ``live_tokens``) and that a sequence's last block is being
+committed by the step in flight (no step is dispatched behind it).  A
+committed block reaches its caller as tokens of its own, in order: those at
+the positions the request asked for (the first block begins with the
+prompt's ``len % B`` trailing tokens, which the prefill leaves out; the last
+may end past ``max_new``: the dropped tail), as far as an ``eos_token``.  An
+admission drains the pipe as it always did, and the step after a drain takes
+the host's copy of the state.  ``stats()["block"]`` counts the slot steps by
+kind, the blocks by the passes they took and the tokens committed, dropped
+and unmasked by either rule; ``rt:engine.decode.dispatch`` carries
+``block_len`` beside ``live_tokens`` (positions held: what is committed and
+the block), and the ``rt:engine.deliver`` of a fetched step ``tokens``,
+``dropped_tail``, ``dropped_stray``, ``denoise_slots`` and ``commit_slots``:
+on the delivery and not the dispatch, because which slots commit is known
+when the step is fetched.  The page size is a multiple of B, so a block lies
+in one page.
+
 The parameters are stored once in the dtype the two programs read them in
 (``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
 ``.astype(cfg.dtype)`` they repeat): embedding tables, head, attention
@@ -232,6 +272,8 @@ class _Step(NamedTuple):
     seqs: Dict[int, "_Sequence"]   # slot -> the sequence that was stepped
     nxt: Any                       # int32[max_batch] on the device: every
     #                                slot's next token, the next step's input
+    #                                (a block model's: block_unmask's dict,
+    #                                its "state" the next step's input)
     load: List[Any]                # an expert model's assignments, there too
     # its dispatch phase's two boundaries where no fetch followed it in its
     # call (a step dispatched on a drained pipe): the next fetch carries them
@@ -292,10 +334,11 @@ def rung_for(rungs: Sequence[int], need: int) -> int:
 class _Sequence:
     __slots__ = ("prompt", "max_new", "pages", "row", "queue", "generated",
                  "pos", "last_token", "cancelled", "slot", "prefilled",
-                 "deadline", "queued")
+                 "deadline", "queued", "block", "masked", "passes", "end")
 
     def __init__(self, prompt: List[int], max_new: int,
-                 deadline: Optional[float] = None):
+                 deadline: Optional[float] = None, block: int = 0,
+                 mask_token: int = 0):
         self.prompt = prompt
         self.max_new = max_new
         self.deadline = deadline       # absolute epoch seconds, or None
@@ -304,6 +347,20 @@ class _Sequence:
         self.queue: asyncio.Queue = asyncio.Queue()
         self.generated = 0
         self.pos = len(prompt)         # next KV write position
+        if block:
+            # A block model's: ``pos`` is its current block's first
+            # position, and the block as the host last saw it (after the
+            # last step it fetched) follows: the prompt's trailing part of
+            # a block, then masks.
+            tail = len(prompt) % block
+            self.pos = len(prompt) - tail
+            self.block = np.full((block,), mask_token, np.int32)
+            self.block[:tail] = prompt[self.pos:]
+            self.masked = np.arange(block) >= tail
+            self.passes = 0
+            # where its last block ends: the first multiple of the block
+            # length at or after the last token asked for
+            self.end = -(-(len(prompt) + max_new) // block) * block
         self.last_token: Optional[int] = None
         self.cancelled = False
         self.slot: Optional[int] = None
@@ -345,6 +402,10 @@ class InferenceEngine:
             init_fn, stored_fn, prefill_fn, decode_fn = \
                 llama_init, llama_serving_params, llama_prefill, \
                 llama_decode_step
+            if mc.block_length:      # its decode step is a block's pass
+                from ray_tpu.models.llama import (block_unmask,
+                                                  llama_block_step)
+                decode_fn = llama_block_step
             cache_fn = lambda: llama_init_paged_cache(   # noqa: E731
                 mc, cfg.num_pages, cfg.page_size, cfg.dtype)
         else:
@@ -356,6 +417,8 @@ class InferenceEngine:
 
         self.config = cfg
         self.model_config = mc
+        # positions a decode step yields a sequence: 0 is one, by one token
+        self._block = block = getattr(mc, "block_length", 0)
         # Stored once as the two programs read them (module docstring); the
         # caller's tree is not kept, so what was cast is the caller's to free.
         self._params = stored_fn(
@@ -392,6 +455,11 @@ class InferenceEngine:
         def _decode_next(params, token, pos, kp, vp, pt):
             import jax.numpy as jnp
             logits, *rest = _decode(params, token, pos, kp, vp, pt)
+            if block:    # (not ``self``: a traced closure outlives the engine)
+                # ``token`` is the blocks' state and ``pos`` their ends;
+                # the unmasking happens where the logits are, and the
+                # [max_batch, B, V] logits are not a result
+                return (None, *rest, block_unmask(mc, logits, token, pos))
             return (logits, *rest,
                     jnp.argmax(logits, axis=-1).astype(jnp.int32))
         _decode_next.__name__ = _decode.__name__
@@ -451,6 +519,15 @@ class InferenceEngine:
                          "error": 0}
         self._moe = {"moe_assignments": 0, "moe_experts_hit": 0,
                      "moe_load_max": 0}
+        self._tree = jax.tree
+        self._block_stats = {
+            "slot_steps_denoise": 0, "slot_steps_commit": 0,
+            "blocks_committed": 0, "tokens_committed": 0,
+            "tokens_dropped_tail": 0, "tokens_dropped_stray": 0,
+            "denoise_passes_by_count": dict.fromkeys(
+                range(1, mc.denoise_steps + 1), 0),
+            "unmasked_by_threshold": 0, "unmasked_by_count": 0} \
+            if self._block else None
         self._kv_live_token_steps = 0
         self._kv_gathered_token_steps = 0
         self._host_s = dict.fromkeys(
@@ -497,7 +574,8 @@ class InferenceEngine:
         max_new = min(max_new_tokens or self.config.max_new_tokens,
                       self.config.max_new_tokens)
         self._ensure_loop()
-        seq = _Sequence(tokens, max_new, deadline)
+        seq = _Sequence(tokens, max_new, deadline, self._block,
+                        self._block and self.model_config.mask_token)
         self._waiting.append(seq)
         self._wake.set()
         try:
@@ -605,6 +683,10 @@ class InferenceEngine:
                 "prefill_shapes": dict(self._prefill_shapes),
                 "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
+                **({"block": {**self._block_stats, "denoise_passes_by_count":
+                              dict(self._block_stats[
+                                  "denoise_passes_by_count"])}}
+                   if self._block else {}),
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
                 "kv_page_kind": "kv" if self._v_pages is not None
@@ -695,8 +777,13 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
         slots = jax.ShapeDtypeStruct((self.config.max_batch,), jnp.int32)
+        token = slots
+        if self._block:              # a block's state in the tokens' place
+            rows = (self.config.max_batch, self._block)
+            token = (jax.ShapeDtypeStruct(rows, jnp.int32),
+                     jax.ShapeDtypeStruct(rows, jnp.bool_), slots, slots)
         return self._compiled(
-            f"decode@{width}", self._decode_next_donating, params, slots,
+            f"decode@{width}", self._decode_next_donating, params, token,
             slots, kp, vp,
             jax.ShapeDtypeStruct((self.config.max_batch, width), jnp.int32))
 
@@ -789,8 +876,48 @@ class InferenceEngine:
         token in flight make ``max_new``).  The host counts; it needs no
         token for that."""
         flying = self._flight.seqs if self._flight is not None else {}
+        if self._block:
+            # a block model's last tokens are coming when the step in
+            # flight was handed its last block whole: that step commits it
+            return {slot: seq for slot, seq in self._active.items()
+                    if not (flying.get(slot) is seq and not seq.masked.any()
+                            and seq.pos + self._block >= seq.end)}
         return {slot: seq for slot, seq in self._active.items()
                 if seq.generated + (flying.get(slot) is seq) < seq.max_new}
+
+    def _block_inputs(self, stepped: Dict[int, _Sequence]):
+        """``_decode_inputs`` of a block model: the blocks' state where the
+        one-token step has ``token`` (the host's mirror of it on a drained
+        pipe, the step in flight's result on the device otherwise), the
+        sequences' ends where it has ``pos`` (0 parks a slot), and the
+        tables, wide enough for the block each sequence is AT: the host
+        fetched the state the step in flight was handed, a block without
+        masks is committed by the pass it is handed to, so the host knows
+        every ``pos0`` of the step it dispatches though not yet its masks."""
+        cfg, B = self.config, self._block
+        flying = self._flight.seqs if self._flight is not None else {}
+        at = {slot: seq.pos + B * (flying.get(slot) is seq
+                                   and not seq.masked.any())
+              for slot, seq in stepped.items()}
+        width = rung_for(self._decode_rungs,
+                         (max(at.values()) + B - 1) // cfg.page_size + 1)
+        end = np.zeros((cfg.max_batch,), np.int32)
+        tables = np.zeros((cfg.max_batch, width), np.int32)
+        for slot, seq in stepped.items():
+            end[slot] = seq.end
+            tables[slot] = seq.row[:width]
+        # positions held: what is committed and the block
+        live_tokens = sum(at.values()) + B * len(at)
+        if self._flight is not None:
+            return self._flight.nxt["state"], end, tables, live_tokens
+        tokens = np.zeros((cfg.max_batch, B), np.int32)
+        masked = np.zeros((cfg.max_batch, B), np.bool_)
+        pos0 = np.zeros((cfg.max_batch,), np.int32)
+        passes = np.zeros((cfg.max_batch,), np.int32)
+        for slot, seq in stepped.items():
+            tokens[slot], masked[slot] = seq.block, seq.masked
+            pos0[slot], passes[slot] = seq.pos, seq.passes
+        return (tokens, masked, pos0, passes), end, tables, live_tokens
 
     def _follows_flight(self, stepped: Dict[int, _Sequence]) -> bool:
         """Whether ``stepped`` can be dispatched behind the step in flight,
@@ -809,8 +936,11 @@ class InferenceEngine:
         positions, so the page that holds ``pos`` is the last it needs,
         and a row's first pages are the sequence's first pages.  ``token``
         is the sequences' last tokens as the host knows them or, with a
-        step in flight, that step's result where it lies on the device."""
+        step in flight, that step's result where it lies on the device.
+        Last, the positions the stepped sequences hold (``live_tokens``)."""
         cfg = self.config
+        if self._block:
+            return self._block_inputs(stepped)
         longest = max(seq.pos for seq in stepped.values())
         width = rung_for(self._decode_rungs, longest // cfg.page_size + 1)
         pos = np.zeros((cfg.max_batch,), np.int32)
@@ -818,12 +948,14 @@ class InferenceEngine:
         for slot, seq in stepped.items():
             pos[slot] = seq.pos
             tables[slot] = seq.row[:width]
+        # what the step's paged read is for
+        live_tokens = int(pos.sum()) + len(stepped)
         if self._flight is not None:
-            return self._flight.nxt, pos, tables
+            return self._flight.nxt, pos, tables, live_tokens
         token = np.zeros((cfg.max_batch,), np.int32)
         for slot, seq in stepped.items():
             token[slot] = seq.last_token
-        return token, pos, tables
+        return token, pos, tables, live_tokens
 
     def _clocks(self, cpu: bool, on_loop: bool = False) -> _Clocks:
         """Now: the wall first (what the regions' neighbours are held to),
@@ -854,7 +986,8 @@ class InferenceEngine:
         self._loop_free_at = self._step_from.wall
 
     def _deliver(self, tokens: List[Tuple[_Sequence, int]],
-                 submitted: _Clocks, lane: Tuple[_Clocks, _Clocks, _Clocks]):
+                 submitted: _Clocks, lane: Tuple[_Clocks, _Clocks, _Clocks],
+                 **told):
         """Push each sequence's token to its caller and retire what
         finished; every call of the exec lane ends here, one that fetched
         nothing (a step dispatched on a drained pipe) with no tokens.
@@ -863,7 +996,10 @@ class InferenceEngine:
         where it dispatched nothing, the last two where it fetched
         nothing), ``submitted`` the loop's before it: the call's phases are
         added to ``stats()``'s sums here, and those that no region has
-        carried yet ride on this one."""
+        carried yet ride on this one.  A sequence may have several tokens
+        in the list (a block model's); what follows the one that finished
+        it is not pushed.  ``told`` is what a block model's fetched step
+        said of itself."""
         start, sent, returned = lane
         sampled = returned.cpu is not None
         entry = self._clocks(sampled, on_loop=True)
@@ -884,8 +1020,10 @@ class InferenceEngine:
                 returned.loop_cpu - sent.loop_cpu)
             attrs["resume_loop_cpu_us"] = _us(
                 entry.loop_cpu - returned.loop_cpu)
-        with region("engine.deliver", tokens=len(tokens), **attrs):
+        with region("engine.deliver", tokens=len(tokens), **attrs, **told):
             for seq, token in tokens:
+                if seq.slot is None:     # retired by a token before this
+                    continue
                 if self._push(seq, token) or seq.cancelled:
                     self._retire(seq, "cancelled" if seq.cancelled
                                  else "done")
@@ -908,7 +1046,9 @@ class InferenceEngine:
             attrs["dispatch_loop_cpu_us"] = _us(sum(
                 sent.loop_cpu - start.loop_cpu for start, sent in phases))
         with region("engine.decode.fetch", **attrs, **crossing):
-            return np.asarray(step.nxt), [np.asarray(a) for a in step.load]
+            # (a block model's ``nxt`` is a dict of arrays)
+            return self._tree.map(np.asarray, step.nxt), \
+                [np.asarray(a) for a in step.load]
 
     def _deliver_step(self, step: _Step, fetched, submitted: _Clocks,
                       lane: Tuple[_Clocks, _Clocks, _Clocks]):
@@ -918,6 +1058,8 @@ class InferenceEngine:
         nothing, whoever holds its slot now: a stray slot step."""
         nxt, load = fetched
         self._count_moe("decode", load)
+        if self._block:
+            return self._deliver_blocks(step, nxt, submitted, lane)
         tokens = []
         for slot, seq in step.seqs.items():
             if self._active.get(slot) is seq:
@@ -925,6 +1067,54 @@ class InferenceEngine:
             else:
                 self._stray_slot_steps += 1
         self._deliver(tokens, submitted, lane)
+
+    def _deliver_blocks(self, step: _Step, told, submitted: _Clocks,
+                        lane: Tuple[_Clocks, _Clocks, _Clocks]):
+        """A block model's fetched step: every stepped sequence's mirror
+        of its block brought up to the state the step left, and of a block
+        the step committed the tokens that are the caller's: those at the
+        positions the request asked for (the first block starts with the
+        prompt's tail, the last may end past ``max_new``: the dropped
+        tail), as far as an ``eos_token``."""
+        B, counts = self._block, self._block_stats
+        tokens, dropped = [], 0
+        state = told["state"]
+        denoise = commit = strayed = 0
+        for slot, seq in step.seqs.items():
+            committed = bool(told["committed"][slot])
+            # the block's first position that is not the prompt's
+            first = max(len(seq.prompt) - seq.pos, 0)
+            if self._active.get(slot) is not seq:
+                self._stray_slot_steps += 1
+                strayed += (B - first) * committed
+                continue
+            commit += committed
+            denoise += not committed
+            counts["unmasked_by_threshold"] += int(
+                told["by_threshold"][slot])
+            counts["unmasked_by_count"] += int(told["by_count"][slot])
+            if committed:
+                asked = min(B, len(seq.prompt) + seq.max_new - seq.pos)
+                mine = [int(t) for t in told["emitted"][slot][first:asked]]
+                eos = self.config.eos_token
+                if eos is not None and eos in mine:
+                    mine = mine[:mine.index(eos) + 1]
+                counts["blocks_committed"] += 1
+                counts["tokens_committed"] += B - first
+                counts["denoise_passes_by_count"][
+                    int(told["passes"][slot])] += 1
+                dropped += B - first - len(mine)
+                tokens += [(seq, t) for t in mine]
+            seq.block, seq.masked = state[0][slot], state[1][slot]
+            seq.pos, seq.passes = int(state[2][slot]), int(state[3][slot])
+        counts["slot_steps_denoise"] += denoise
+        counts["slot_steps_commit"] += commit
+        counts["tokens_dropped_tail"] += dropped
+        counts["tokens_dropped_stray"] += strayed
+        counts["tokens_committed"] += strayed
+        self._deliver(tokens, submitted, lane, dropped_tail=dropped,
+                      dropped_stray=strayed, denoise_slots=denoise,
+                      commit_slots=commit)
 
     async def _drain(self, loop):
         """Fetch and deliver the step in flight with nothing queued behind
@@ -951,13 +1141,13 @@ class InferenceEngine:
         nothing.  Either way this step is the one in flight afterwards."""
         cfg = self.config
         prev = self._flight
-        token, pos, tables = batch
+        token, pos, tables, live_tokens = batch
         width = tables.shape[1]
         program = self._decode_programs[width].result()
         active = len(stepped)
-        # what the step's paged read is for, and what it gathers
-        live_tokens = int(pos.sum()) + active
+        # what the step's paged read gathers
         gathered_tokens = tables.size * cfg.page_size
+        blocks = {"block_len": self._block} if self._block else {}
         submitted, sampled = self._submit(cpu=True)
         # everything the step before cost the loop: its delivery,
         # the streams' fan-out, schedule, the prefills between
@@ -974,10 +1164,11 @@ class InferenceEngine:
                         width_pages=width, ahead=int(prev is not None),
                         submit_us=_us(start.wall - submitted.wall),
                         step_us=_us(step_s),
-                        step_loop_cpu_us=_us(step_loop_cpu_s)):
+                        step_loop_cpu_us=_us(step_loop_cpu_s), **blocks):
                 _, kp, vp, *load, nxt = self._donate_pools(
                     "decode", program, token, pos, tables)
-                for a in (nxt, *load):   # on their way once the step ends
+                # on their way once the step ends
+                for a in self._tree.leaves((nxt, load)):
                     a.copy_to_host_async()
             sent = self._clocks(sampled)
             if prev is None:
@@ -994,8 +1185,9 @@ class InferenceEngine:
         self._slot_steps += active
         self._kv_live_token_steps += live_tokens
         self._kv_gathered_token_steps += gathered_tokens
-        for seq in stepped.values():
-            seq.pos += 1
+        if not self._block:   # a block's position moves when it commits
+            for seq in stepped.values():
+                seq.pos += 1
         if prev is None:
             self._deliver([], submitted, lane)
         else:
@@ -1079,10 +1271,13 @@ class InferenceEngine:
                 # Prefill new admissions one at a time (B=1), each padded
                 # to its prompt's rung; the pipe is drained.
                 for seq in fresh:
-                    S = rung_for(self._rungs, len(seq.prompt))
+                    # a block model prefills the prompt's whole blocks;
+                    # what is left of it is in its first block
+                    whole = seq.pos if self._block else len(seq.prompt)
+                    S = rung_for(self._rungs, whole)
                     program = self._rung_programs[S].result()
                     toks = np.zeros((1, S), np.int32)
-                    toks[0, : len(seq.prompt)] = seq.prompt
+                    toks[0, :whole] = seq.prompt[:whole]
                     submitted, sampled = self._submit()
                     self._queue_wait_s += submitted.wall - seq.queued
                     self._prefill_tokens += len(seq.prompt)
@@ -1090,7 +1285,8 @@ class InferenceEngine:
                     self._prefill_shapes[S] += 1
 
                     def _run(seq=seq, S=S, program=program, toks=toks,
-                             submitted=submitted, sampled=sampled):
+                             submitted=submitted, sampled=sampled,
+                             whole=whole):
                         start = self._clocks(sampled)
                         with region("engine.prefill",
                                     prompt_len=len(seq.prompt), padded_len=S,
@@ -1099,8 +1295,9 @@ class InferenceEngine:
                                         start.wall - submitted.wall)):
                             logits, kp, vp, *load = self._donate_pools(
                                 "prefill", program, toks,
-                                np.int32(len(seq.prompt)), seq.row[None])
-                            tok = int(jnp.argmax(logits[0]))
+                                np.int32(whole), seq.row[None])
+                            tok = None if self._block \
+                                else int(jnp.argmax(logits[0]))
                             load = [np.asarray(a) for a in load]
                         return tok, kp, vp, load, \
                             (start, start, self._clocks(sampled))
@@ -1108,7 +1305,8 @@ class InferenceEngine:
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
                     self._count_moe("prefill", load)
-                    self._deliver([(seq, tok)], submitted, lane)
+                    self._deliver([] if tok is None else [(seq, tok)],
+                                  submitted, lane)
 
                 if not self._active:
                     continue

@@ -36,7 +36,13 @@ whichever thread read it (``time.pthread_getcpuclockid``).  A thread's CPU
 clock is a system call (0.3 us on plain Linux, 6 us under the chip
 machine's sandbox), so a hot path reads it for every region only while
 ``recording()`` says a session would carry it, and for a sample otherwise.
-A host may tick its thread CPU clocks coarsely (the chip's machine does, in
+Counts follow the same rule: what a step turned out to be
+is known when it is fetched, so a block model's ``denoise_slots``,
+``commit_slots``, ``dropped_tail`` and ``dropped_stray`` ride on the
+``rt:engine.deliver`` that follows the fetch beside its ``tokens`` (0 to B a
+slot), while what the host knows when it dispatches (``active``,
+``block_len``, ``live_tokens``, ``width_pages``) is on the
+``rt:engine.decode.dispatch``.  A host may tick its thread CPU clocks coarsely (the chip's machine does, in
 steps of 10 ms): one region then reads 0 or a whole tick, and only sums
 over many regions are readings.  ``watch_gc()``
 puts the collector's passes on the same timeline as ``rt:gc`` regions and

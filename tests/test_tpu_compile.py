@@ -604,6 +604,95 @@ def test_xing_weights_are_made_as_the_stored_tree(topo):
     assert memory.temp_size_in_bytes < 64 * 1024 ** 2
 
 
+# SDAR-30B-A3B-Chat at its published widths, six of its 48 layers, with the
+# engine of benchmark/configs/sdar-30b-a3b-chat-6l.json (32 slots of 1,536
+# positions): K/V pools [6, 3073, 16, 512], 0.604 GB, beside 8.73 GB of stored
+# weights (bf16 matrices, the 128 experts of a layer among them).  Its decode
+# program is a block's pass: 4 positions a slot, 128 rows through the experts
+# and the head, the unmasking behind it.
+SDAR_PROMPT, SDAR_NEW, SDAR_BATCH = 512, 1024, 32
+SDAR_BUDGET = int(10.0 * 1024 ** 3)
+
+
+def _sdar():
+    from benchmark import spec
+    config = spec.load_json("configs", "sdar-30b-a3b-chat-6l.json")
+    family = spec.load_part("families", "sdar")
+    return family, family.program_config(config, SDAR_PROMPT + SDAR_NEW)
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill@128", "decode",
+                                     "decode@24"])
+def test_sdar_engine_program_compiles(topo, program):
+    from ray_tpu.models.llama import (block_unmask, llama_block_step,
+                                      llama_init_paged_cache, llama_prefill)
+    family, cfg = _sdar()
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: family.init(jax.random.PRNGKey(0), cfg)))
+    kp, vp = _on(one, jax.eval_shape(lambda: llama_init_paged_cache(
+        cfg, SDAR_BATCH * 96 + 1, PAGE)))
+    assert kp.shape == vp.shape == (6, 3073, PAGE, 4 * 128)
+    maxp = (SDAR_PROMPT + SDAR_NEW) // PAGE
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if program.startswith("decode"):
+        from ray_tpu.serve.engine.engine import decode_rungs
+        width = int(program.partition("@")[2] or maxp)
+        assert width in (decode_rungs(maxp)[0], maxp)
+        rows = (SDAR_BATCH, cfg.block_length)
+
+        def step(p, state, end, k, v, table):    # as the engine's loop has it
+            logits, k, v, load = llama_block_step(p, cfg, state, end, k, v,
+                                                  table)
+            return None, k, v, load, block_unmask(cfg, logits, state, end)
+        compiled, text = _compile(
+            step, params, (arg(rows), arg(rows, jnp.bool_),
+                           arg(rows[:1]), arg(rows[:1])), arg(rows[:1]),
+            kp, vp, arg((SDAR_BATCH, width)), donate=POOLS)
+        scopes = ["paged_append", "paged_read", "lm_head", "block_unmask"]
+    else:
+        rung = int(program.partition("@")[2] or SDAR_PROMPT)
+        compiled, text = _compile(
+            lambda p, *a: llama_prefill(p, cfg, *a), params,
+            arg((1, rung)), arg(()), kp, vp, arg((1, maxp)), donate=POOLS)
+        scopes = ["paged_append"]
+        # no logits: the head is no argument of the program at all
+        assert "lm_head" not in text
+    _pools_in_place(compiled, text, kp)
+    assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
+    assert "convert(%p__" not in text
+    for scope in scopes + ["moe_router", "moe_dispatch", "moe_experts",
+                           "moe_combine"]:
+        assert _scoped(text, scope), scope
+    # two grouped matmuls a layer, on the stacked bf16 experts of the six
+    # layers, where they lie
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
+    assert len(calls) == 2
+    assert all("bf16[1536,2048,768]" in c or "bf16[768,768,2048]" in c
+               for c in calls)
+    assert _fits(compiled) < SDAR_BUDGET
+
+
+def test_sdar_weights_are_made_as_the_stored_tree(topo):
+    """The family's ``init`` inside one jit: 8.7 GB come out and no f32
+    stack of matrices is held on the way (made f32 first they are 17 GB)."""
+    family, cfg = _sdar()
+    one = SingleDeviceSharding(topo.devices[0])
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    compiled, _ = _compile(lambda k: family.init(k, cfg), key)
+    memory = compiled.memory_analysis()
+    stored = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                 for leaf in jax.tree.leaves(jax.eval_shape(
+                     lambda: family.init(jax.random.PRNGKey(0), cfg))))
+    assert 8.70e9 < stored < 8.75e9
+    assert memory.output_size_in_bytes < stored * 1.001
+    assert memory.temp_size_in_bytes < 64 * 1024 ** 2
+
+
 # ---------------------------------------------------------------- four chips
 
 def test_ring_attention_sp4_compiles(topo):
