@@ -30,9 +30,11 @@ Two paths live here, with different callers:
 * ``moe_dropless``: token-choice top-k with NO capacity, SwiGLU experts
   without biases.  Every assignment is computed: assignments are sorted
   by expert and the experts run as grouped matmuls over the ragged groups
-  (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own
-  grouped-matmul kernel: work goes with the assignments, not with
-  experts x tokens, and an expert nobody chose is not read).  Used by ``models/llama.py`` (``_ffn`` when
+  (``ops/grouped_matmul.py``, a Pallas kernel that is handed one layer's
+  groups and finds that layer's experts in the stack of all layers by an
+  offset in its index map: work goes with the assignments, not with
+  experts x tokens, and an expert nobody chose is neither read nor
+  visited).  Used by ``models/llama.py`` (``_ffn`` when
   ``LlamaConfig.num_experts > 0``), and so by the paged serving engine,
   whose padded prefill and idle decode slots would take capacity from real
   tokens under the first path: only without a capacity are a token's
@@ -46,6 +48,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 
 def moe_router(x, router_w, *, top_k: int, capacity: int):
@@ -201,11 +205,19 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     only selects what is counted.
 
     Assignments are sorted by expert and the experts run as two grouped
-    matmuls over the ragged groups: gate and up in one, over ``wgu`` seen
-    as 2E groups of [D, M] (each expert's rows twice), then down.  A group
-    without rows costs nothing, which is also how the other layers of a
-    stack are passed over.  The products run in the WEIGHTS' own type, the
-    activations (a few rows an expert) cast to it: casting the weights
+    matmuls over the ragged groups (``grouped_matmul``): gate and up in
+    one, over ``wgu`` seen as 2E groups of [D, M] (each expert's rows
+    twice), then down.  The kernel is handed this layer's group sizes and
+    the stacks as they are stored, reshaped to [L * 2E, D, M] and
+    [L * E, M, D]; ``layer`` reaches it as a scalar that its index map adds
+    to the group, so the other layers are never walked, and a group
+    without rows is not in its grid: a touched expert's matrices cross
+    from HBM once, in whole tiles of megabytes, at 85-90% of the HBM's
+    rate from one to eight rows an expert (the compiler's ``ragged_dot``
+    kernel, which stood here, paid 2-8 us more than its bytes for every
+    group with rows: PERF.md section 6, PR 41).  The
+    products run in the WEIGHTS' own type, the activations (a few rows an
+    expert) cast to it, and accumulate in float32: casting the weights
     instead moves all of them every step for the few that are read.
 
     Returns (y [T, D] in x's type, load [E] int32: the live tokens'
@@ -220,12 +232,6 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     L, E, _, _, M = p["wgu"].shape
     wgu = p["wgu"].reshape(L * E * 2, D, M)
     wd = p["wd"].reshape(L * E, M, D)
-
-    def in_stack(sizes):
-        """Group sizes of the whole stack: only this layer's have rows."""
-        return jax.lax.dynamic_update_slice(
-            jnp.zeros((L * sizes.shape[0],), jnp.int32), sizes,
-            (layer * sizes.shape[0],))
 
     with jax.named_scope("moe_router"):
         # float32 for real: on a TPU the default precision of a float32
@@ -264,10 +270,10 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         gate_at = jnp.arange(A) + start[expert]
         up_at = gate_at + sizes[expert]
     with jax.named_scope("moe_experts"):
-        gu = jax.lax.ragged_dot(xs2, wgu, in_stack(sizes2))      # [2A, M]
+        gu = grouped_matmul(xs2, wgu, sizes2, layer)             # [2A, M]
         hidden = jax.nn.silu(gu[gate_at]) * gu[up_at]            # [A, M]
-        ys = jax.lax.ragged_dot(hidden.astype(wd.dtype), wd,
-                                in_stack(sizes))                 # [A, D]
+        ys = grouped_matmul(hidden.astype(wd.dtype), wd, sizes,
+                            layer)                               # [A, D]
     with jax.named_scope("moe_combine"):
         # back to token order by the inverse permutation (a gather, not a
         # scatter-add), then the gate-weighted sum of each token's k

@@ -349,6 +349,17 @@ def test_sigmoid_routing_with_bias_and_a_shared_expert(renorm, scale, bias,
         assert int(load_one.sum()) == K
 
 
+def kernel_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_calls(sub)
+    return found
+
+
 def test_bfloat16_experts_are_multiplied_as_they_are_stored():
     """Stored in bfloat16 the experts are read in bfloat16 (the activations
     cast to them, never the experts to the activations), and the result is
@@ -357,14 +368,141 @@ def test_bfloat16_experts_are_multiplied_as_they_are_stored():
     stored = {**p, "wgu": p["wgu"].astype(jnp.bfloat16),
               "wd": p["wd"].astype(jnp.bfloat16)}
     x = jax.random.normal(jax.random.PRNGKey(31), (19, D))
-    jaxpr = str(jax.make_jaxpr(
-        lambda x, p: moe_dropless(x, p, top_k=K))(x, stored))
-    dots = [line for line in jaxpr.splitlines()
-            if "= ragged_dot_general[" in line]
-    assert len(dots) == 2 and all(":bf16[" in d for d in dots)
+    jaxpr = jax.make_jaxpr(
+        lambda x, p: moe_dropless(x, p, top_k=K))(x, stored)
+    assert "ragged_dot" not in str(jaxpr)
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert len(calls) == 2
+    for call, weights in zip(calls, ((2 * E, D, M), (E, M, D))):
+        # the rows, the experts as they lie in the stack, the result
+        rows, experts = call.invars[-2:]
+        assert experts.aval.shape == weights
+        assert {rows.aval.dtype, experts.aval.dtype,
+                call.outvars[0].aval.dtype} == {jnp.dtype(jnp.bfloat16)}
     y, load = moe_dropless(x, stored, top_k=K)
     assert y.dtype == x.dtype
     rounded = jax.tree.map(lambda a: a.astype(jnp.float32), stored)
     want, chosen = all_experts(x, rounded, K)
     assert np.linalg.norm(np.asarray(y) - want) / np.linalg.norm(want) < 2e-2
     np.testing.assert_array_equal(np.asarray(load), chosen.sum(0))
+
+
+# ------------------------------------- the grouped matmul kernel in a stack
+
+LAYERS = 3
+IN_STACK = jax.jit(lambda x, p, layer, top_k: moe_dropless(
+    x, p, top_k=top_k, layer=layer), static_argnums=3)
+
+
+def stack_around(p, layer):
+    """A stack of LAYERS in which only ``layer`` holds ``p``: every other
+    layer's router and experts are NaN, so a read of a wrong layer shows."""
+    return {k: jnp.stack([v if i == layer else jnp.full_like(v, jnp.nan)
+                          for i in range(LAYERS)]) for k, v in p.items()}
+
+
+@pytest.mark.parametrize("stored", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tokens", [1, 19, 67])
+def test_a_traced_layer_reads_its_own_experts_in_the_stack(tokens, stored):
+    """``layer`` traced (one program for the three), picking each layer of
+    the stack in turn: 3, 57 and 201 assignments, so row counts that no tile
+    divides, and with 67 tokens the gate/up rows (402) span four row tiles
+    which groups share."""
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, D))
+    programs = set()
+    for layer in range(LAYERS):
+        p = experts_params(jax.random.PRNGKey(40 + layer))
+        p = {**p, "wgu": p["wgu"].astype(stored),
+             "wd": p["wd"].astype(stored)}
+        y, load = IN_STACK(x, stack_around(p, layer), jnp.int32(layer), K)
+        programs.add(IN_STACK._cache_size())
+        want, chosen = all_experts(x, p, K)
+        np.testing.assert_array_equal(np.asarray(load), chosen.sum(0))
+        if stored == "float32":
+            np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4,
+                                       atol=1e-5)
+        else:
+            assert np.linalg.norm(np.asarray(y) - want) \
+                < 2e-2 * np.linalg.norm(want)
+    assert len(programs) == 1
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+def test_an_expert_with_every_row_and_experts_with_none_in_the_stack(layer):
+    """Of 16 experts at 2 a token, expert 0 is every token's first choice
+    and the last eight are nobody's: one group holds half the rows (two row
+    tiles of the gate/up matmul and more), eight groups hold none and get
+    no visit, and the layers around are NaN."""
+    tokens, experts, top_k = 96, 16, 2
+    p = experts_params(jax.random.PRNGKey(50), experts)
+    x = jax.random.normal(jax.random.PRNGKey(51), (tokens, D))
+    x = x.at[:, 0].set(3.0)
+    p["router"] = p["router"].at[0, 0].add(10.0).at[0, 8:].add(-10.0)
+    y, load = IN_STACK(x, stack_around(p, layer), jnp.int32(layer), top_k)
+    want, chosen = all_experts(x, p, top_k)
+    load = np.asarray(load)
+    assert load[0] == tokens and not load[8:].any()
+    np.testing.assert_array_equal(load, chosen.sum(0))
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-5)
+
+
+def groups_product(lhs, rhs, sizes):
+    """Each group's rows times its matrix, in float64."""
+    out, at = [], 0
+    for g, n in enumerate(sizes):
+        out.append(np.asarray(lhs[at:at + n], np.float64)
+                   @ np.asarray(rhs[g], np.float64))
+        at += n
+    return np.concatenate(out)
+
+
+@pytest.mark.parametrize("sizes", [
+    [5, 0, 130, 1, 0, 64], [0, 0, 0, 0, 0, 7], [128, 128, 0, 0, 1, 0],
+    [300, 0, 0, 0, 0, 0]], ids=["ragged", "last-only", "whole-tiles", "one"])
+@pytest.mark.parametrize("tile_bytes", [None, 128 * 128 * 4])
+def test_grouped_matmul_against_each_groups_product(monkeypatch, sizes,
+                                                    tile_bytes):
+    """The kernel alone: groups that end inside a row tile, fill whole ones
+    or have no rows, the second layer of a stack of three, and (with the
+    weights' tile held to [128, 128]) N walked in two tiles."""
+    from ray_tpu.ops import grouped_matmul as gm
+    if tile_bytes:
+        monkeypatch.setattr(gm, "_WEIGHT_TILE_BYTES", tile_bytes)
+    G, Kd, N, m = len(sizes), 128, 256, sum(sizes)
+    rhs = jax.random.normal(jax.random.PRNGKey(60), (3 * G, Kd, N))
+    rhs = rhs.at[:G].set(jnp.nan).at[2 * G:].set(jnp.nan)
+    lhs = jax.random.normal(jax.random.PRNGKey(61), (m, Kd))
+    assert gm._tiles(m, Kd, N, 4, G)[1] == (128 if tile_bytes else 256)
+    out = gm.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
+                            jnp.int32(1))
+    assert out.shape == (m, N) and out.dtype == lhs.dtype
+    np.testing.assert_allclose(np.asarray(out),
+                               groups_product(lhs, rhs[G:2 * G], sizes),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case,tiles", [
+    # rows, K, N, bytes a parameter, groups -> (row tile, tile of N)
+    ("sdar gate/up", ((2048, 2048, 768, 2, 256), (128, 768))),
+    ("sdar down", ((1024, 768, 2048, 2, 128), (128, 2048))),
+    ("xing gate/up", ((256, 3584, 1024, 2, 128), (128, 1024))),
+    ("xing down", ((128, 1024, 3584, 2, 64), (128, 3584))),
+    ("olmoe gate/up", ((256, 2048, 1024, 4, 128), (128, 1024))),
+    ("olmoe down", ((128, 1024, 2048, 4, 64), (128, 2048))),
+    ("olmoe prefill 512", ((8192, 2048, 1024, 4, 128), (128, 1024))),
+    ("sdar prefill 2048", ((32768, 2048, 768, 2, 256), (128, 768))),
+    ("a wide float32 expert", ((64, 4096, 4096, 4, 8), (64, 512))),
+    ("many rows a group", ((65536, 2048, 1024, 2, 16), (512, 1024))),
+    ("one token", ((3, 32, 16, 4, 8), (8, 16))),
+    ("one token, bfloat16", ((3, 32, 16, 2, 8), (16, 16)))])
+def test_tiles_follow_from_the_shapes(case, tiles):
+    from ray_tpu.ops import grouped_matmul as gm
+    shapes, want = tiles
+    assert gm._tiles(*shapes) == want, case
+    tm, tn = want
+    _, Kd, N, itemsize, _ = shapes
+    assert N % tn == 0
+    # two buffers each of the weights' tile, the rows and the result, and
+    # the float32 product, inside what the call asks for
+    assert 2 * itemsize * (Kd * tn + tm * Kd + tm * tn) + 4 * tm * tn \
+        < gm._VMEM_LIMIT_BYTES

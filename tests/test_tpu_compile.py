@@ -35,6 +35,7 @@ from ray_tpu.parallel.sharding import logical_sharding
 
 # the module: ray_tpu.ops re-exports the function under the same name
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
+gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
 
 HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
 
@@ -83,6 +84,15 @@ def compiled_kernels(monkeypatch):
             real(q, bq, bk, False, layout))
 
 
+@pytest.fixture
+def compiled_experts(monkeypatch):
+    """``moe_dropless`` calls the grouped matmul with interpret=None; make
+    that mean the compiled kernel, as it does on the chip."""
+    real = gm._resolve
+    monkeypatch.setattr(gm, "_resolve",
+                        lambda *a: real(*a[:-1], False))
+
+
 def _compile(fn, *args, donate=()):
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     return compiled, compiled.as_text()
@@ -95,6 +105,22 @@ def _kernels(text: str) -> set:
     return {re.match(r"\s*(?:ROOT )?%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = ",
                      line).group(1) for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def _expert_kernels(text: str, *stacks: str) -> list:
+    """The program's instructions that are a Pallas kernel written under
+    the scope ``moe_experts`` (what the benchmark's readers find the
+    experts' device time by), each held to read one of ``stacks``: the
+    experts of every layer in their stored type and whole shape, as the
+    kernel's operand."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r'op_name="[^"]*[/(]moe_experts[/)]', line)]
+    for call in calls:
+        operands = call[call.index("operand_layout_constraints="):
+                        call.index("backend_config=")]
+        assert any(stack + "{" in operands for stack in stacks), operands
+    return calls
 
 
 def _scoped(text: str, scope: str) -> bool:
@@ -405,8 +431,9 @@ def test_mistral_prefill_compiles_at_every_lower_rung(topo, rung):
 
 # OLMoE-1B-7B-0125-Instruct at its published widths, 4 of its 16 layers, with
 # the engine of benchmark/configs/olmoe-1b-7b-0125-4l.json: the dropless
-# expert path's grouped matmuls have to be the compiler's own kernels
-# (``ragged-dot``, a ``tpu_custom_call``) reading the f32 experts where they
+# expert path's grouped matmuls have to be ``ops/grouped_matmul.py``'s kernel
+# (a ``tpu_custom_call`` under the scope ``moe_experts``, at the tiles its
+# ``_tiles`` picks for these shapes) reading the f32 experts where they
 # lie (no bf16 copy of the stack: that alone is 3.2 GB), and the parameters
 # and the KV pool, held once since the programs update it in place (7.3 GiB
 # compiled; 9.0 while the pool was held twice), have to leave half the chip.
@@ -415,7 +442,7 @@ OLMOE_BUDGET = 8 * 1024 ** 3
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
-def test_olmoe_engine_program_compiles(topo, program):
+def test_olmoe_engine_program_compiles(topo, compiled_experts, program):
     from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=50304, num_layers=4, num_heads=16,
                       num_kv_heads=16, embed_dim=2048, mlp_dim=1024,
@@ -433,13 +460,33 @@ def test_olmoe_engine_program_compiles(topo, program):
         assert _scoped(text, scope), scope
     # gate/up and down: two grouped matmuls a layer, work by assignment,
     # on the stacked f32 experts themselves
-    calls = [line for line in text.splitlines()
-             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
-    assert len(calls) == 2
-    assert all("f32[512,2048,1024]" in c or "f32[256,1024,2048]" in c
-               for c in calls)
+    assert len(_expert_kernels(text, "f32[512,2048,1024]",
+                               "f32[256,1024,2048]")) == 2
+    assert "ragged-dot" not in text
     assert "bf16[4,64," not in text
     assert _fits(compiled) < OLMOE_BUDGET
+
+
+def test_grouped_matmul_module_names_no_source(topo):
+    """The kernel's serialized module is part of the persistent compilation
+    cache's key, and Pallas writes each operation's source location into
+    it, calling frames included (this test's own, here): a checkout at
+    another path, or a line moved in a caller, would compile every expert
+    program again.  The module of ``ops/grouped_matmul.py`` names its
+    kernel and no file."""
+    import base64
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    text = jax.jit(lambda *a: gm.grouped_matmul(*a, interpret=False)).lower(
+        shape((256, 2048), jnp.float32),
+        shape((4 * 128, 2048, 1024), jnp.float32),
+        shape((128,), jnp.int32), shape((), jnp.int32)).as_text()
+    body, = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    module = base64.b64decode(body)
+    assert b"grouped_matmul" in module
+    assert b".py" not in module and os.getcwd().encode() not in module
 
 
 # Ouro-2.6B whole: published widths, all 48 layers run four times, with the
@@ -527,7 +574,7 @@ def _xing():
 
 @pytest.mark.parametrize("program", ["prefill", "prefill@256", "decode",
                                      "decode@64"])
-def test_xing_engine_program_compiles(topo, program):
+def test_xing_engine_program_compiles(topo, compiled_experts, program):
     from ray_tpu.models.llama import (llama_decode_step,
                                       llama_init_paged_cache, llama_prefill)
     family, cfg = _xing()
@@ -580,11 +627,9 @@ def test_xing_engine_program_compiles(topo, program):
         assert _scoped(text, scope), scope
     # two grouped matmuls an expert layer, on the stacked bf16 experts of
     # the five expert layers, where they lie
-    calls = [line for line in text.splitlines()
-             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
-    assert len(calls) == 2
-    assert all("bf16[640,3584,1024]" in c or "bf16[320,1024,3584]" in c
-               for c in calls)
+    assert len(_expert_kernels(text, "bf16[640,3584,1024]",
+                               "bf16[320,1024,3584]")) == 2
+    assert "ragged-dot" not in text
     assert _fits(compiled) < XING_BUDGET
 
 
@@ -623,7 +668,7 @@ def _sdar():
 
 @pytest.mark.parametrize("program", ["prefill", "prefill@128", "decode",
                                      "decode@24"])
-def test_sdar_engine_program_compiles(topo, program):
+def test_sdar_engine_program_compiles(topo, compiled_experts, program):
     from ray_tpu.models.llama import (block_unmask, llama_block_step,
                                       llama_init_paged_cache, llama_prefill)
     family, cfg = _sdar()
@@ -669,11 +714,9 @@ def test_sdar_engine_program_compiles(topo, program):
         assert _scoped(text, scope), scope
     # two grouped matmuls a layer, on the stacked bf16 experts of the six
     # layers, where they lie
-    calls = [line for line in text.splitlines()
-             if re.match(r"\s*%ragged-dot-none[\w.\-]* = ", line)]
-    assert len(calls) == 2
-    assert all("bf16[1536,2048,768]" in c or "bf16[768,768,2048]" in c
-               for c in calls)
+    assert len(_expert_kernels(text, "bf16[1536,2048,768]",
+                               "bf16[768,768,2048]")) == 2
+    assert "ragged-dot" not in text
     assert _fits(compiled) < SDAR_BUDGET
 
 
