@@ -6,10 +6,8 @@ events named ``rt:<name>`` on the ``/host:CPU`` plane of the run's
 the events' stats.  On the device it names its Pallas kernels
 (``flash_fwd``, ``flash_dq``, ``flash_dkv``) and scopes parts of its steps
 (``jax.named_scope``: ``ce_head``, ``optimizer``, ``paged_append``,
-``paged_read``).  This module reads the three:
+``paged_read``).  This module reads them:
 
-* ``gap_kinds``: every device-idle interval between consecutive programs of
-  the lowest-numbered device, split by what the host was doing in it;
 * ``rows``: the attributes of the regions of one name;
 * ``kernel`` and ``scope_ms``: own time of device operations by kernel
   name and by scope.
@@ -19,154 +17,28 @@ profiler keeps as the ``tf_op`` stat of the event's *metadata*;
 ``jax.profiler.ProfileData`` shows an event's own stats only, so
 ``op_names`` reads that one stat from the file's protobuf encoding itself.
 
-The device's events are on the host's clock only up to an error that is
-constant within a trace; ``device_lag`` measures it against the runtime's
-own enqueue events and ``read_profile`` moves the programs by it before
-anything is split or checked.
+What the host costs a decode step is ``host_threads.py``'s to read (the
+phases' clocks on the regions), with ``decode_ahead_share`` and
+``breakdown.idle_gaps``.
 
 Every reader gives ``None`` where there is nothing to read: no trace (a
-CPU rehearsal), a program without the regions, names or scopes (the parent
-of the PR that added them), or host events that fail the clock check.
+CPU rehearsal), or a program without the regions, names or scopes (the
+parent of the PR that added them).
 """
 
 from __future__ import annotations
 
-import bisect
 import functools
 import os
 import re
-import sys
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from benchmark import spec, trace_reduce
 
 Region = Tuple[str, float, float, dict]       # name, start, end, attributes
 Span = Tuple[float, float, str]               # start, end, name
-KINDS = ("fetch", "resume", "deliver", "schedule", "submit", "dispatch",
-         "unnamed")
 SCOPES = ("ce_head", "optimizer", "paged_append", "paged_read")
-DECODE, ARGMAX = "jit__decode", "jit__argmax"
-# The runtime's host thread marks each program it hands to the device
-# with this event.  The profiler converts device times to the host's clock
-# with an error that is constant within a trace: on two v5e machines the
-# device's programs read as starting 0.43 and 1.44 ms *before* the event
-# that enqueued them (PERF.md, PR 24 found).  ``device_lag`` is the least
-# shift that puts every ``jit__decode`` after its enqueue; what is left of
-# the error moves time between the two kinds that touch the device,
-# ``fetch`` at a gap's start and ``dispatch`` at its end, and leaves their
-# sum and the kinds between them alone.
-ENQUEUE = "DoEnqueueProgram"
-# How far the two timelines may still differ before they count as
-# different clocks (a runtime that has no such event is not shifted).
-CLOCK_SLACK_S = 2e-3
-
-
-# ------------------------------------------------------------ the host side
-
-def kind_intervals(regions: List[Region]) -> List[Span]:
-    """The regions of the engine's per-token path as intervals of a kind.
-    The two thread crossings are rebuilt from the attribute of the region
-    that follows them: ``resume`` ends where ``deliver`` starts and
-    ``submit`` where ``dispatch`` (or a prefill) starts.  A prefill's
-    whole region counts as ``dispatch``."""
-    out = []
-    for name, start, end, attrs in regions:
-        kind = {"rt:engine.decode.fetch": "fetch",
-                "rt:engine.deliver": "deliver",
-                "rt:engine.schedule": "schedule",
-                "rt:engine.decode.dispatch": "dispatch",
-                "rt:engine.prefill": "dispatch"}.get(name)
-        if kind is None:
-            continue
-        out.append((start, end, kind))
-        for attr, crossing in (("resume_us", "resume"),
-                               ("submit_us", "submit")):
-            if attr in attrs:
-                out.append((start - attrs[attr] * 1e-6, start, crossing))
-    return sorted(out)
-
-
-def program_gaps(programs: List[Span]) -> List[Tuple[float, float]]:
-    """The device-idle intervals between consecutive programs, as
-    ``trace_reduce.reduce_events`` counts them (``program_gap_s``)."""
-    programs = sorted(programs)
-    return [(end, programs[i + 1][0])
-            for i, (_, end, _) in enumerate(programs[:-1])
-            if programs[i + 1][0] > end]
-
-
-def split_gaps(gaps: List[Tuple[float, float]], intervals: List[Span]
-               ) -> Dict[str, float]:
-    """Seconds of the gaps by kind.  Where intervals overlap the one that
-    started last wins (the innermost), so an instant is counted once; what
-    no interval covers is ``unnamed``.  The kinds sum to the gaps."""
-    out = dict.fromkeys(KINDS, 0.0)
-    for gap_start, gap_end in gaps:
-        inside = [(max(s, gap_start), min(e, gap_end), k)
-                  for s, e, k in intervals if s < gap_end and e > gap_start]
-        cuts = sorted({gap_start, gap_end}
-                      | {t for s, e, _ in inside for t in (s, e)})
-        for a, b in zip(cuts, cuts[1:]):
-            covering = [(s, k) for s, e, k in inside if s <= a and e >= b]
-            out[max(covering)[1] if covering else "unnamed"] += b - a
-    return out
-
-
-def device_lag(enqueues: List[float], programs: List[Span]) -> float:
-    """Seconds by which the device's timeline runs ahead of the host's:
-    the most that a ``jit__decode`` reads as starting before the event
-    that enqueued it; 0 where none does.  A step's programs are enqueued
-    within a millisecond and steps are 40 ms apart, so a decode's event is
-    the nearest of those that follow 10 ms of silence."""
-    enqueues = sorted(enqueues)
-    first = [e for before, e in zip([float("-inf")] + enqueues, enqueues)
-             if e - before > 10e-3]
-    ahead = [0.0]
-    for start, _, name in programs:
-        at = bisect.bisect_left(first, start)
-        near = min(first[max(at - 1, 0):at + 1],
-                   key=lambda e: abs(e - start), default=None)
-        if name == DECODE and near is not None \
-                and abs(near - start) < 10e-3:
-            ahead.append(near - start)
-    return max(ahead)
-
-
-def clock_check(regions: List[Region], programs: List[Span]
-                ) -> Tuple[int, int]:
-    """(decode steps that can be checked, those on one clock).  A step on
-    the host is an ``rt:engine.decode.dispatch`` and the
-    ``rt:engine.decode.fetch`` after it.  On one clock exactly one
-    ``jit__decode`` starts on the device between the start of the first
-    and the end of the second, and the ``jit__argmax`` after it has ended
-    by then too; the device's timeline may differ from the host's by
-    ``CLOCK_SLACK_S``.  A step that the trace cut at either end (no fetch
-    after the dispatch, no program in it at the trace's edge) is not
-    counted."""
-    programs = sorted(programs)
-    decodes = []                    # (start of jit__decode, end of argmax)
-    for i, (start, end, name) in enumerate(programs):
-        if name == DECODE:
-            decodes.append((start, next(
-                (e for s, e, n in programs[i + 1:]
-                 if n == ARGMAX and s >= end), None)))
-    dispatches = [s for n, s, _, _ in regions
-                  if n == "rt:engine.decode.dispatch"]
-    fetches = sorted(e for n, _, e, _ in regions
-                     if n == "rt:engine.decode.fetch")
-    steps = in_order = 0
-    for i, start in enumerate(dispatches):
-        end = next((f for f in fetches if f > start), None)
-        inside = [(p, a) for p, a in decodes
-                  if start - CLOCK_SLACK_S <= p <= (end or start)]
-        at_edge = i in (0, len(dispatches) - 1)
-        if end is None or (at_edge and (
-                not inside or inside[0][1] is None)):
-            continue
-        steps += 1
-        in_order += len(inside) == 1 and inside[0][1] is not None \
-            and inside[0][1] <= end + CLOCK_SLACK_S
-    return steps, in_order
+DECODE = "jit__decode"
 
 
 # ------------------------------------------------- the file, read once
@@ -250,15 +122,11 @@ def scope_of(op_name: str) -> Optional[str]:
 
 @functools.lru_cache(maxsize=2)
 def read_profile(path: str) -> dict:
-    """``regions`` of the host plane; of the lowest-numbered device its
-    ``programs``, moved by ``lag_s`` onto the host's timeline, and the own
-    seconds of its operations by ``scopes``; and ``kinds``, the idle time
-    between those programs split by the regions, or None where the decode
-    steps' host events fail the clock check or there are none."""
+    """``regions`` of the host plane, and the own seconds of the
+    lowest-numbered device's operations by ``scopes``."""
     from jax.profiler import ProfileData
     regions: List[Region] = []
-    enqueues: List[float] = []
-    devices: Dict[int, Dict[str, List[Span]]] = {}
+    ops: Dict[int, List[Span]] = {}       # device number -> operations
     for plane in ProfileData.from_file(path).planes:
         device = re.fullmatch(r"/device:\w+:(\d+)", plane.name)
         for line in plane.lines:
@@ -269,40 +137,20 @@ def read_profile(path: str) -> dict:
                             e.name, e.start_ns * 1e-9,
                             (e.start_ns + e.duration_ns) * 1e-9,
                             dict(e.stats)))
-                    elif e.name == ENQUEUE:
-                        enqueues.append(e.start_ns * 1e-9)
-            elif device and line.name in (trace_reduce.MODULES,
-                                          trace_reduce.OPS):
-                devices.setdefault(int(device.group(1)), {})[line.name] = [
+            elif device and line.name == trace_reduce.OPS:
+                ops[int(device.group(1))] = [
                     (e.start_ns * 1e-9,
                      (e.start_ns + e.duration_ns) * 1e-9, e.name)
                     for e in line.events]
     regions.sort(key=lambda r: r[1])
-    first = devices[min(devices)] if devices else {}
-    programs = sorted((s, e, trace_reduce.program_name(n))
-                      for s, e, n in first.get(trace_reduce.MODULES, []))
-    lag = device_lag(enqueues, programs)
-    programs = [(s + lag, e + lag, n) for s, e, n in programs]
-    steps, in_order = clock_check(regions, programs)
-    kinds = None
-    if steps and in_order == steps:
-        kinds = split_gaps(program_gaps(programs), kind_intervals(regions))
-    if steps:
-        print(f"host_regions: device timeline moved {lag * 1e3:.3f} ms "
-              f"later; {in_order} of {steps} decode steps have their host "
-              "regions and device programs in order"
-              + ("" if kinds else ": the two are not on one clock, no "
-                 "host_gap_* is reported"), file=sys.stderr)
     scopes = dict.fromkeys(SCOPES, 0.0)
-    if first.get(trace_reduce.OPS):
+    if ops:
         names = op_names(path)
-        for seconds, text in trace_reduce.self_times(
-                first[trace_reduce.OPS]):
+        for seconds, text in trace_reduce.self_times(ops[min(ops)]):
             scope = scope_of(names.get(text, ""))
             if scope:
                 scopes[scope] += seconds
-    return {"regions": regions, "programs": programs, "lag_s": lag,
-            "kinds": kinds, "scopes": scopes}
+    return {"regions": regions, "scopes": scopes}
 
 
 # ------------------------------------------------- what the metrics call
@@ -330,20 +178,6 @@ def median_ms(run: dict, region: str, attr: str) -> Optional[float]:
     found = rows(run, region)
     return statistics.median(r[attr] for r in found) * 1e-3 \
         if found else None
-
-
-def gap_kinds(run: dict) -> Optional[Dict[str, float]]:
-    """Seconds of the window's device-idle time between programs, by kind;
-    None unless every decode step's host events pass the clock check."""
-    prof = profile(run)
-    return prof and prof["kinds"]
-
-
-def gap_ms(run: dict, kind: str) -> Optional[float]:
-    """``host_gap_<kind>_ms``: that kind's idle time per decode call."""
-    kinds = gap_kinds(run)
-    decode = run["trace"].get("programs", {}).get(DECODE)
-    return 1e3 * kinds[kind] / decode["calls"] if kinds and decode else None
 
 
 def scope_ms(run: dict, scopes: Tuple[str, ...], per: float

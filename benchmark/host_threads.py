@@ -1,26 +1,27 @@
 """What the engine's two threads did inside the phases of a decode step.
 
-``host_regions.gap_kinds`` places every idle millisecond of the device
-under a phase of the engine's per-token path.  Since PR 36 the engine also
-reads, at each boundary of a step, the CPU clock of the exec thread and of
-the actor's loop thread beside the wall clock, and hangs each phase's three
-numbers on the region that follows it (``ray_tpu/util/tracing.py`` has the
-convention): ``dispatch_us`` / ``dispatch_cpu_us`` / ``dispatch_loop_cpu_us``
+Since PR 36 the engine reads, at each boundary of a phase of its per-token
+path, the CPU clock of the exec thread and of the actor's loop thread
+beside the wall clock, and hangs each phase's three numbers on the region
+that follows it (``ray_tpu/util/tracing.py`` has the convention):
+``dispatch_us`` / ``dispatch_cpu_us`` / ``dispatch_loop_cpu_us``
 on ``rt:engine.decode.fetch``; ``fetch_loop_cpu_us`` and
 ``resume_loop_cpu_us`` on ``rt:engine.deliver``; ``step_us`` /
 ``step_loop_cpu_us`` (since the previous decode step's submission) on
 ``rt:engine.decode.dispatch``.  The collector's passes are ``rt:gc``
 regions.  This module sums those over the traced seconds: per
-``jit__decode`` call, as ``host_gap_*`` is, or as a share.
+``jit__decode`` call, or as a share.
 
 Every reader gives ``None`` where there is nothing to read: no trace, no
 decode call, or regions without the attribute (the program before PR 36; a
 call submitted before the session began, whose CPU clocks were not read).
-What the numbers are worth: the session that ``replica.observe`` starts
-leaves the profiler's Python tracer on, which slows the very threads these
-read (PERF.md section 6, PR 36), and the chip machine's thread CPU clocks
-tick in 10 ms steps, so a sum over five traced seconds is good to 10-18%
-and a difference of two sums may come out a little under 0.
+What the numbers are worth: the session that ``replica.observe`` starts has
+had the profiler's Python tracer off since PR 44 (on, it slowed the very
+threads these read 1.3-1.6 times: PERF.md section 6, PR 36), so they read
+the judged run's host to the 0.2-0.5 ms a step that the host tracer costs;
+the chip machine's thread CPU clocks tick in 10 ms steps, so a sum over
+five traced seconds is good to 10-18% and a difference of two sums may come
+out a little under 0.
 """
 
 from __future__ import annotations

@@ -10,12 +10,13 @@ enters and first yields, the profiler, the device's memory statistics.
 from __future__ import annotations
 
 import asyncio
+import functools
 import os
 import time
 
 from ray_tpu.serve.engine import EngineConfig, LLMServer
 
-from benchmark import spec, trace_reduce
+from benchmark import spec
 
 POLL_S = 0.1
 
@@ -139,7 +140,12 @@ class BenchLLMServer(LLMServer):
     async def observe(self, seconds: float, trace_after_s: float,
                       trace_for_s: float) -> None:
         """For a traced run: poll the engine's ``stats()`` through the
-        window, and profile ``trace_for_s`` seconds of it."""
+        window, and profile ``trace_for_s`` seconds of it.  The profiler's
+        Python tracer is off, as in ``LLMServer.profile``: on (the default),
+        it makes every Python call of every thread an event and slows a
+        serving step 1.3-1.6 times, so that the traced seconds show a host
+        the judged run does not have (PERF.md section 6, PR 36 and PR 44);
+        the ``rt:`` regions need only the host tracer."""
         import jax
         loop = asyncio.get_running_loop()
         self._steps_at_start = self._engine.stats()["steps"]
@@ -151,8 +157,11 @@ class BenchLLMServer(LLMServer):
             self._polls.append((stats["active"], stats["waiting"]))
             now = time.perf_counter()
             if not tracing and not self._traced and now >= trace_at:
-                await loop.run_in_executor(
-                    None, jax.profiler.start_trace, self._trace_dir)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                await loop.run_in_executor(None, functools.partial(
+                    jax.profiler.start_trace, self._trace_dir,
+                    profiler_options=options))
                 tracing = True
             elif tracing and now >= trace_at + trace_for_s:
                 await loop.run_in_executor(None, self._stop_trace)
@@ -167,16 +176,20 @@ class BenchLLMServer(LLMServer):
         self._traced = True
 
     def collect(self) -> dict:
-        """After the window: what the instruments gathered, the trace
-        reduced here so that reading it cost the window nothing."""
+        """After the window: what the instruments gathered, and where the
+        profile lies.  ``run.py`` reduces it, once the cluster is gone:
+        reduced here, a profile of a million device events held this
+        process for longer than the serve controller waits for a ping
+        (10 s), and the controller killed the replica under the call
+        (PERF.md section 6, PR 44's refusal)."""
         stats = self._engine.stats()
-        trace = trace_reduce.reduce_events(trace_reduce.read_xplane(
-            find_xplane(self._trace_dir))) if self._traced else {}
         return {"memory_peak_bytes": memory_peak_bytes(),
                 "replica_ttft_s": {k: v[1] - v[0]
                                    for k, v in self._seen.items()
                                    if k is not None and v[1] is not None},
-                "polls": self._polls, "trace": trace,
+                "polls": self._polls,
+                "profile": find_xplane(self._trace_dir)
+                if self._traced else None,
                 "max_batch": self._engine.config.max_batch,
                 "decode_steps": stats["steps"] - self._steps_at_start,
                 "phases": self._phases}

@@ -75,6 +75,15 @@ def main() -> int:
         raise SystemExit("the benchmark's own process initialised a JAX "
                          "backend")
 
+    if run.get("profile"):
+        # A serve cell hands back where its replica's profile lies, and it
+        # is reduced here, with the cluster gone: the replica has to answer
+        # the controller's pings, and this takes tens of seconds.
+        started = time.perf_counter()
+        run["trace"] = trace_reduce.reduce_events(
+            trace_reduce.read_xplane(run["profile"]))
+        run["phases"]["reduce_profile_s"] = time.perf_counter() - started
+
     device = dict(run["device"])
     peaks = spec.peaks_for(device["kind"])
     if device["platform"] != peaks["platform"] or \

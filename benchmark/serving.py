@@ -176,7 +176,10 @@ class Session:
                 loop.create_task(self._watch_loop_lag(seconds))]
         await drive(self)
         await asyncio.gather(*watchers)
+        drained = time.perf_counter()
         replica = await loop.run_in_executor(None, self.call, "collect")
+        self.phases.update(drain_s=drained - self.start - seconds,
+                           collect_s=time.perf_counter() - drained)
         vocab = self.config["vocab_size"]
         failed = [r for r in self.records if r["error"]]
         inexact = sum(1 for r in self.records if r["error"] or not (
@@ -206,7 +209,9 @@ class Session:
                 "window_start_epoch": window_start_epoch,
                 "window": (self.start, self.start + seconds),
                 "requests": self.records, "replica": replica,
-                "trace": replica["trace"], "loop_lag_ms": self.loop_lag_ms}
+                # run.py reduces the profile into ``trace``
+                "trace": {}, "profile": replica["profile"],
+                "loop_lag_ms": self.loop_lag_ms}
 
     async def _watch_loop_lag(self, seconds: float) -> None:
         """The LoopWatchdog reading of the replica's node, once a second."""

@@ -27,8 +27,8 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-KNEE_READS = ("itl_p99_ms", "engine_waiting_mean", "gen_late_p99_ms",
-              "ttft_p50_ms", "served_tokens_per_s")
+KNEE_READS = ("itl_mean_ms", "itl_p99_ms", "engine_waiting_mean",
+              "gen_late_p99_ms", "ttft_p50_ms", "served_tokens_per_s")
 
 
 def one_list(bench: dict, workload: str) -> None:

@@ -15,6 +15,7 @@ Times are seconds.
 
 from __future__ import annotations
 
+import functools
 import re
 from collections import defaultdict
 from typing import Dict, Iterable, List, Tuple
@@ -48,10 +49,13 @@ def program_name(name: str) -> str:
     return re.sub(r"\(\d+\)$", "", name)
 
 
+@functools.lru_cache(maxsize=None)
 def short_op(text: str) -> str:
     """``%fusion.1 = bf16[2,16]{1,0:T(8,128)} fusion(...)`` ->
     ``fusion.1 bf16[2,16] fusion``: instruction, result, kind.  The kind
-    of a Pallas kernel is its call target, ``tpu_custom_call``."""
+    of a Pallas kernel is its call target, ``tpu_custom_call``.  Kept by
+    text: a trace repeats its programs' few thousand instructions in a
+    million events."""
     name, _, rest = text.partition(" = ")
     kind = re.search(r"\s([a-z][a-z0-9_\-]*)\(", " " + rest)
     target = re.search(r'custom_call_target="(\w+)"', rest)

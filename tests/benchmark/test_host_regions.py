@@ -1,6 +1,7 @@
-"""The readers of what the program says of itself in a profile: the split
-of device-idle gaps by the host's regions, the clock check, and the
-protobuf reader of the operations' ``op_name``."""
+"""The readers of what the program says of itself in a profile: the host's
+regions with their attributes, the protobuf reader of the operations'
+``op_name``, the kernels by name, and that every per-layer reader of
+BENCHMARK.json gives ``None`` where the trace is empty."""
 
 import os
 import sys
@@ -14,117 +15,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))     # tests/engine_trace.py
 
 D, F = "rt:engine.decode.dispatch", "rt:engine.decode.fetch"
-MS = 1e-3
-
-
-def step(at, shift=0.0):
-    """One decode step as the engine marks it, the device running
-    ``jit__decode`` over [at + 1, at + 41] ms and ``jit__argmax`` right
-    after: (regions, programs), times in seconds."""
-    t = at * MS + shift
-    regions = [
-        ("rt:engine.deliver", t - 0.6 * MS, t - 0.5 * MS,
-         {"tokens": 2, "resume_us": 300}),
-        ("rt:engine.schedule", t - 0.5 * MS, t - 0.4 * MS,
-         {"active": 2, "waiting": 0}),
-        (D, t, t + 1.5 * MS, {"active": 2, "submit_us": 200}),
-        (F, t + 1.5 * MS, t + 43 * MS, {}),
-        ("rt:stream.yield", t + 2 * MS, t + 2 * MS,
-         {"index": 1, "ack_us": 900}),
-    ]
-    programs = [((at + 1) * MS, (at + 41) * MS, hr.DECODE),
-                ((at + 41) * MS, (at + 41.1) * MS, hr.ARGMAX)]
-    return regions, programs
-
-
-def steps(n, shift=0.0):
-    regions, programs = [], []
-    for i in range(n):
-        r, p = step(44 * i, shift)
-        regions += r
-        programs += p
-    return regions, programs
-
-
-def test_gaps_are_split_by_kind_and_sum_to_the_gaps():
-    regions, programs = steps(3)
-    gaps = hr.program_gaps(programs)
-    # after each argmax until the next decode: 44 - 41.1 + 1 ms
-    assert gaps == pytest.approx(
-        [(41.1 * MS, 45 * MS), (85.1 * MS, 89 * MS)])
-    kinds = hr.split_gaps(gaps, hr.kind_intervals(regions))
-    assert sum(kinds.values()) == pytest.approx(2 * 3.9 * MS)
-    per_gap = {k: v / 2 / MS for k, v in kinds.items()}
-    # fetch ends at 43, resume is the 0.3 before deliver at 43.4, deliver
-    # and schedule 0.1 each, submit the 0.2 before dispatch at 44, and
-    # dispatch until the program starts at 45; 43.0-43.1 and 43.6-43.8
-    # are nobody's
-    assert per_gap == pytest.approx({
-        "fetch": 1.9, "resume": 0.3, "deliver": 0.1, "schedule": 0.1,
-        "submit": 0.2, "dispatch": 1.0, "unnamed": 0.3})
-
-
-def test_overlap_is_counted_once_and_the_innermost_wins():
-    gaps = [(0.0, 10.0)]
-    intervals = [(1.0, 9.0, "dispatch"), (2.0, 4.0, "fetch"),
-                 (3.0, 6.0, "deliver")]
-    kinds = hr.split_gaps(gaps, intervals)
-    assert kinds["unnamed"] == 2.0
-    assert kinds["fetch"] == 1.0        # 2-3; from 3 on deliver is inside
-    assert kinds["deliver"] == 3.0
-    assert kinds["dispatch"] == 4.0     # 1-2 and 6-9
-    assert sum(kinds.values()) == 10.0
-
-
-def test_a_prefill_counts_as_dispatch_and_its_crossing_as_submit():
-    regions = [("rt:engine.prefill", 1.0, 3.0,
-                {"prompt_len": 5, "padded_len": 16, "waited_us": 600_000,
-                 "submit_us": 250_000})]
-    kinds = hr.split_gaps([(0.0, 2.0)], hr.kind_intervals(regions))
-    assert (kinds["submit"], kinds["dispatch"], kinds["unnamed"]) == \
-        (0.25, 1.0, 0.75)
-
-
-def test_clock_check_passes_on_one_clock_and_fails_when_shifted():
-    regions, programs = steps(5)
-    assert hr.clock_check(regions, programs) == (5, 5)
-    # a device timeline a little off the host's is still one clock
-    assert hr.clock_check(*steps(5, shift=1.2 * MS)) == (5, 5)
-    for shift in (-8 * MS, 5 * MS, 20 * MS, 1.0):
-        shifted, _ = steps(5, shift)
-        checked, in_order = hr.clock_check(shifted, programs)
-        assert in_order < max(checked, 1), shift
-    # no decode step in the trace: nothing to check, nothing reported
-    assert hr.clock_check([], programs) == (0, 0)
-
-
-def test_device_lag_is_the_most_a_decode_precedes_its_enqueue():
-    _, programs = steps(4)
-    # the runtime enqueues each decode 0.2-0.3 ms before it starts, and
-    # its argmax right after; then the device's timeline is read 1.5 ms
-    # early
-    enqueues = [p[0] - (0.2 + 0.05 * (i % 3)) * MS
-                for i, p in enumerate(programs) if p[2] == hr.DECODE]
-    enqueues += [e + 0.4 * MS for e in enqueues]
-    assert hr.device_lag(enqueues, programs) == 0.0
-    early = [(s - 1.5 * MS, e - 1.5 * MS, n) for s, e, n in programs]
-    lag = hr.device_lag(enqueues, early)
-    assert lag == pytest.approx(1.3 * MS)     # 1.5 less the least 0.2
-    moved = [(s + lag, e + lag, n) for s, e, n in early]
-    assert hr.device_lag(enqueues, moved) == pytest.approx(0.0, abs=1e-12)
-    assert hr.program_gaps(moved) == pytest.approx(
-        [(a + lag, b + lag) for a, b in hr.program_gaps(early)])
-    # a runtime without the event: nothing to go by, nothing moved
-    assert hr.device_lag([], early) == 0.0
-
-
-def test_a_step_cut_by_the_trace_is_not_counted():
-    regions, programs = steps(4)
-    # the trace began after the first dispatch and ended inside the last
-    # step: its decode ran, its argmax and fetch are missing
-    regions = [r for r in regions[3:]
-               if not (r[0] == F and r[1] > 3 * 44 * MS)]
-    assert hr.clock_check(regions, programs[:-1]) == (2, 2)
 
 
 # ------------------------------------------------------ the protobuf reader
@@ -194,25 +84,35 @@ def test_scope_of_an_op_name(op_name, scope):
 # ------------------------------------------------------ what metrics call
 
 def run_of(cell, trace):
-    bench = spec.load_benchmark()
-    return {"cell": spec.load_cell(bench, cell), "trace": trace,
-            "peaks": spec.peaks_for("TPU v5 lite")}
+    """A run of the cell as a generator hands it to the readers, with
+    nothing in it but ``trace``: no request, no poll, no step."""
+    cell = spec.load_cell(spec.load_benchmark(), cell)
+    return {"cell": cell, "trace": trace,
+            "peaks": spec.peaks_for("TPU v5 lite"), "requests": [],
+            "loop_lag_ms": [], "window": (0.0, 1.0),
+            "replica": {"replica_ttft_s": {}, "polls": [], "trace": trace,
+                        "decode_steps": 0, "max_batch": cell["config"].get(
+                            "engine", {}).get("max_batch")}}
 
 
-NEW_METRICS = [
-    *(f"host_gap_{kind}_ms" for kind in hr.KINDS), "stream_yield_ack_ms",
-    "prefill_useful_share.chat", "prefill_useful_share.batch",
-    "engine_queue_wait_ms", "flash_fwd_roofline", "flash_dq_roofline",
-    "flash_dkv_roofline", "ce_head_device_ms", "optimizer_device_ms",
-    "paged_kv_device_ms"]
+# computed from the window's steps, which a run always has: no reader of
+# the trace, and never None
+NOT_OF_THE_TRACE = {"train_mfu"}
+PER_LAYER = [(m["name"], cell) for m in spec.load_benchmark()["per_layer"]
+             if m["name"] not in NOT_OF_THE_TRACE for cell in m["workloads"]]
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
-def test_reader_gives_none_on_an_empty_trace(name):
-    metric, = [m for m in spec.load_benchmark()["per_layer"]
-               if m["name"] == name]
-    for cell in metric["workloads"]:
-        assert spec.metric_reader(name)(run_of(cell, {})) is None
+@pytest.mark.parametrize("name, cell", PER_LAYER)
+def test_reader_gives_none_on_an_empty_trace(name, cell):
+    """Every per-layer metric, in every cell that lists it: a reader that
+    finds nothing to read returns nothing (never 0, never an exception), so
+    that one which would raise on the chip is found here."""
+    assert spec.metric_reader(name)(run_of(cell, {})) is None
+
+
+def test_every_per_layer_metric_names_its_cells():
+    assert all(m.get("workloads")
+               for m in spec.load_benchmark()["per_layer"])
 
 
 def test_kernel_shares_read_the_named_kernels():
@@ -262,25 +162,6 @@ def test_the_engines_regions_are_read_with_their_attributes(engine_profile):
         sum(len(p) for p in engine_trace.PROMPTS)
     assert {p["padded_len"] for p in prefills} == \
         {engine_trace.MAX_PROMPT_LEN}
-    # the CPU has no device plane: no programs, no scopes, and so no gaps
-    assert profile["programs"] == [] and profile["kinds"] is None
-    assert profile["lag_s"] == 0.0
+    # the CPU has no device plane: no operations, so nothing under a scope
+    assert set(profile) == {"regions", "scopes"}
     assert set(profile["scopes"].values()) == {0.0}
-
-
-def test_the_engines_regions_split_a_gap_laid_over_them(engine_profile):
-    _, profile = engine_profile
-    regions = profile["regions"]
-    dispatches = [r for r in regions if r[0] == D]
-    fetches = [r for r in regions if r[0] == F]
-    # the stretch of host work between two steps, as if the device had
-    # finished when the first fetch began and started when the second
-    # dispatch ended
-    gap = (fetches[0][1], dispatches[1][2])
-    kinds = hr.split_gaps([gap], hr.kind_intervals(regions))
-    assert sum(kinds.values()) == pytest.approx(gap[1] - gap[0])
-    for kind in ("fetch", "resume", "deliver", "schedule", "submit",
-                 "dispatch"):
-        assert kinds[kind] > 0, kinds
-    # the regions and the two crossings cover the stretch
-    assert kinds["unnamed"] < 0.2 * (gap[1] - gap[0]), kinds

@@ -134,7 +134,7 @@ def test_the_benchmark_lists_each_quantity_for_chat_and_for_the_decode_cells():
     for name in QUANTITIES:
         plain, split = entries[name], entries[name + ".decode"]
         assert plain["workloads"] == [CHAT] and \
-            plain["moves"] == "itl_p99_ms"
+            plain["moves"] == "itl_mean_ms"
         assert split["workloads"] == DECODE_CELLS and \
             split["moves"] == "served_tokens_per_s"
         assert plain["source"] == split["source"] == "program_span"

@@ -156,4 +156,4 @@ def test_published_widths_of_xing_still_hold(bench):
     assert 0 < xing["numerics"]["logits_rtol"] < 0.05
     mine = [m["name"] for m in bench["per_layer"]
             if m.get("workloads") == ["serve-xing-reasoning-batch"]]
-    assert len(mine) == 21 and all(n.endswith(".xing") for n in mine)
+    assert len(mine) == 14 and all(n.endswith(".xing") for n in mine)
