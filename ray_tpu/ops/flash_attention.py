@@ -23,8 +23,20 @@ broadcast along a narrow minor dim) so they slice as native sublane column
 vectors — the same layout trick as jax.experimental.pallas.ops.tpu
 .flash_attention's l/m tensors, but 8 lanes wide instead of 128.
 
-Causal skipping: dead diagonal blocks are jumped with `pl.when`, so the
-wall-clock cost of the mask is ~half the non-causal kernel, not equal to it.
+Causal skipping, at two granularities.  A grid block wholly above the
+diagonal is jumped with `pl.when`.  Inside a live grid step each kernel
+walks its [block_q, block_k] block in [_SUB_TILE, _SUB_TILE] sub-tiles: a
+sub-tile above the diagonal is not computed, a run of sub-tiles below it is
+one product with no iota/compare/select, and only the sub-tiles the
+diagonal crosses are masked (`tile_plan` counts them: at S = 1024, where
+one grid block is the whole sequence, 10 of 16 computed and 4 masked).  The
+walk is static: Python loops over `_walk`'s pieces, so nothing is decided
+on the chip but which of a few walks a grid step runs (`_runs`).  Where the
+streamed grid dimension has one step (S <= 1024 with the default blocks)
+the accumulators are values of that step and no scratch is allocated;
+where it has several they are carried in scratch, and the forward, whose
+statistics pay for every update, takes a grid block below the diagonal as
+one piece.
 
 On the CPU backend the same kernels run in Pallas interpret mode, keeping
 CPU tests honest.
@@ -35,7 +47,9 @@ kernels; this is the TPU-native replacement (SURVEY §5.7).
 
 from __future__ import annotations
 
+import collections
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -52,14 +66,25 @@ _SCR = 128     # lane width of VMEM scratch accumulators
 # The smallest block the TPU compiler tiles.
 _MIN_BLOCK = 8
 
+# The edge of the sub-tiles a kernel walks its grid block in (see
+# "sub-tiles" below).  Like the grid blocks of `_default_blocks`, this is
+# the one place the choice is made; PERF.md section 6 (PR 45) has the chip
+# sweep at S = 1024, head size 64 that chose it for all three kernels.
+_SUB_TILE = 256
+
 
 def _default_blocks(S: int, strict: bool = True) -> tuple:
     """The (block_q, block_k) a call without explicit blocks runs with:
     1024, halved until it divides S.  (1024, 1024) is what both training
-    cells of the benchmark run (S = 1024; ``flash_fwd/dq/dkv_roofline``
-    18.0 / 22.3 / 21.6 in PERF_LEDGER.jsonl), and the [1024, 1024] f32
-    probability tile (4 MB) fits VMEM.  This is the one place the choice
-    is made: a sweep on the chip changes this table in this file."""
+    cells of the benchmark run (S = 1024): one grid step a head, its q, k,
+    v resident for the step and walked in `_SUB_TILE` sub-tiles
+    (``flash_fwd/dq/dkv_roofline`` 18.0 / 22.3 / 21.6 in PERF_LEDGER.jsonl
+    up to PR 44, when a step computed the whole masked [1024, 1024] tile;
+    PERF.md section 6, PR 45 has what the walk reads).  Smaller grid
+    blocks pay ~0.35 us a grid step against ~2.5 us of work a head.  This
+    is the one place the choice is made: a sweep on the chip
+    (``scripts/flash_sweep.py``, which refuses to time another backend)
+    changes this table in this file."""
     b = 1024
     while S % b:
         b //= 2
@@ -87,59 +112,210 @@ def _suggest_blocks(S: int) -> tuple:
                        if b <= S_pad and S_pad % b == 0] or [pad])
 
 
+# ------------------------------------------------------------- sub-tiles
+#
+# A grid block [block_q, block_k] is walked in sub-tiles [t_q, t_k].  With
+# ``rel`` = (first query row) - (first key column) of a tile, the causal
+# mask keeps s[r, c] where r + rel >= c, so a tile is
+#   dead    if rel + t_q - 1 < 0     (every column lies ahead of every row),
+#   plain   if rel >= t_k - 1        (no column lies ahead of any row),
+#   masked  otherwise                (the diagonal crosses it).
+# The same two tests sort the grid blocks, by their offset ``d = q_start -
+# k_start``: ``d`` takes few values where the diagonal crosses a block, so
+# each kernel is built with one static walk per such offset and one for the
+# blocks below the diagonal; which walk a grid step runs is a ``pl.when``
+# on its program ids.
+
+_DEAD = "dead"
+
+
+def _dead(rel, t_q: int):
+    return rel + t_q - 1 < 0
+
+
+def _plain(rel, t_k: int):
+    return rel >= t_k - 1
+
+
+def _sub_tiles(block_q: int, block_k: int) -> tuple:
+    """(t_q, t_k) a grid block is walked in: ``_SUB_TILE``, or the largest
+    power-of-two share of it that divides the block."""
+    return math.gcd(block_q, _SUB_TILE), math.gcd(block_k, _SUB_TILE)
+
+
+def _pieces(rels: list, t: int, t_q: int, t_k: int) -> list:
+    """What a kernel computes of one row or column of a grid block's
+    sub-tiles, ``rels`` their ``rel`` in order (None: nothing masked) and
+    ``t`` their edge along it: pieces (start, size, rel).  A dead sub-tile
+    is in no piece, a run of plain ones is ONE strip (rel None, one product
+    as wide as the run), a masked one a piece of its own with the ``rel``
+    its mask is made from."""
+    pieces = []
+    for i, rel in enumerate(rels):
+        if rel is not None and _dead(rel, t_q):
+            continue
+        if rel is not None and not _plain(rel, t_k):
+            pieces.append((i * t, t, rel))
+        elif pieces and pieces[-1][2] is None:
+            start, size, _ = pieces.pop()
+            pieces.append((start, size + t, None))
+        else:
+            pieces.append((i * t, t, None))
+    return pieces
+
+
+def _walk(d: Optional[int], block_q: int, block_k: int, by: str) -> list:
+    """The walk over a grid block at offset ``d`` (None: nothing masked):
+    ``by`` "row", per sub-tile row (start, size, its pieces along the key
+    columns); ``by`` "col", per sub-tile column (start, size, its pieces
+    along the query rows, from the diagonal down).  Rows or columns with
+    nothing to compute are left out."""
+    t_q, t_k = _sub_tiles(block_q, block_k)
+    nq, nk = block_q // t_q, block_k // t_k
+
+    def rel(a, b):
+        return None if d is None else d + a * t_q - b * t_k
+
+    if by == "row":
+        lines = [(a * t_q, t_q, _pieces([rel(a, b) for b in range(nk)],
+                                        t_k, t_q, t_k)) for a in range(nq)]
+    else:
+        lines = [(b * t_k, t_k, _pieces([rel(a, b) for a in range(nq)],
+                                        t_q, t_q, t_k)) for b in range(nk)]
+    return [line for line in lines if line[2]]
+
+
+def _walk_key(d: int, block_q: int, block_k: int, causal: bool):
+    """Which walk the grid block at offset ``d`` gets: None (nothing
+    masked), ``d`` itself (the diagonal crosses it) or ``_DEAD`` (none)."""
+    if not causal or _plain(d, block_k):
+        return None
+    return _DEAD if _dead(d, block_q) else d
+
+
+def _grid_walks(S: int, block_q: int, block_k: int, causal: bool) -> dict:
+    """{walk key: the grid blocks of one head that run it}, the dead blocks
+    left out: the walks a kernel over this grid is built with."""
+    walks = collections.Counter(
+        _walk_key(i * block_q - j * block_k, block_q, block_k, causal)
+        for i in range(S // block_q) for j in range(S // block_k))
+    walks.pop(_DEAD, None)
+    return dict(walks)
+
+
+def tile_plan(S: int, block_q: int, block_k: int, causal: bool) -> dict:
+    """What each of the three kernels does for one head at these grid
+    blocks: the sub-tile sizes and how many sub-tiles it computes, how many
+    of those it masks, and how many the [S, S] scores hold.  The kernels
+    run the same ``_walk`` of the same ``_grid_walks``."""
+    t_q, t_k = _sub_tiles(block_q, block_k)
+    computed = masked = 0
+    for d, blocks in _grid_walks(S, block_q, block_k, causal).items():
+        for _, _, pieces in _walk(d, block_q, block_k, "row"):
+            computed += blocks * sum(size // t_k for _, size, _ in pieces)
+            masked += blocks * sum(rel is not None for _, _, rel in pieces)
+    return {"sub_q": t_q, "sub_k": t_k, "computed": computed,
+            "masked": masked, "total": (S // t_q) * (S // t_k)}
+
+
+def _runs(key, d, block_k: int, causal: bool):
+    """Whether the grid block at offset ``d`` (traced, in a kernel) runs the
+    walk ``key``: for every block, what ``_walk_key(d) == key`` says."""
+    return (not causal or _plain(d, block_k)) if key is None else d == key
+
+
+def _run_walk(walk, keys, causal, d, block_k):
+    """Run ``walk(key)`` for the one of ``keys`` this grid step's block
+    runs, ``d`` its offset; none, for a block above the diagonal."""
+    for key in keys:
+        pl.when(_runs(key, d, block_k, causal))(functools.partial(walk, key))
+
+
+def _scores(q, k, sm_scale, rel):
+    """q @ k^T * scale in f32 (the inputs' type on the MXU), masked where
+    ``rel`` (static) says the diagonal crosses the tile."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+    if rel is not None:
+        rows = rel + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(rows >= cols, s, _NEG_INF)
+    return s
+
+
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, o_scr, *,
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, walks: tuple,
                 causal: bool, sm_scale: float):
     # q_ref: [block_q, H]; k_ref/v_ref: [block_k, H] (streamed on grid dim 2)
     # o_ref: [block_q, H]; lse_ref: [block_q, _LANES]
-    # scratch: m/l [block_q, _SCR], o [block_q, H] — all f32
+    # scratch, where the key grid dimension has several steps: m/l
+    # [block_q, _SCR], o [block_q, H], all f32, carried across them
     block_q, head_dim = q_ref.shape
     block_k = k_ref.shape[0]
     qi, kb = pl.program_id(1), pl.program_id(2)
     num_kb = pl.num_programs(2)
-    q_start, k_start = qi * block_q, kb * block_k
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[:] = jnp.full((block_q, _SCR), _NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros((block_q, _SCR), jnp.float32)
-        o_scr[:] = jnp.zeros((block_q, head_dim), jnp.float32)
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                            (m.shape[0], _LANES))
 
-    live = True if not causal else k_start <= q_start + block_q - 1
+    if scratch:
+        m_scr, l_scr, o_scr = scratch
 
-    @pl.when(live)
-    def _compute():
-        # matmuls run in the input dtype (bf16-native on the MXU) with f32
-        # accumulation; softmax statistics stay f32.
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m = m_scr[:, 0:1]
-        l = l_scr[:, 0:1]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_scr[:] = o_scr[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[:, 0:1] = m_new
-        l_scr[:, 0:1] = l_new
+        @pl.when(kb == 0)
+        def _init():
+            m_scr[:] = jnp.full((block_q, _SCR), _NEG_INF, jnp.float32)
+            l_scr[:] = jnp.zeros((block_q, _SCR), jnp.float32)
+            o_scr[:] = jnp.zeros((block_q, head_dim), jnp.float32)
 
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, 0:1], 1e-30)
-        o_ref[:] = (o_scr[:] / l).astype(o_ref.dtype)
-        lse = m_scr[:, 0:1] + jnp.log(l)
-        lse_ref[:] = jnp.broadcast_to(lse, (block_q, _LANES))
+    def update(q, pieces, old):
+        # One softmax update for these query rows: their scores by piece,
+        # the rows' maximum over all of them, then the sums.  The products
+        # run in the input dtype (bf16-native on the MXU) with f32
+        # accumulation; the statistics stay f32.
+        scores = [_scores(q, k_ref[pl.ds(c0, w), :], sm_scale, rel)
+                  for c0, w, rel in pieces]
+        m = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in scores])
+        if old is None:
+            l = acc = 0.0
+        else:
+            m = jnp.maximum(old[0], m)
+            alpha = jnp.exp(old[0] - m)
+            l, acc = old[1] * alpha, old[2] * alpha
+        for s, (c0, w, _) in zip(scores, pieces):
+            p = jnp.exp(s - m)
+            l = l + jnp.sum(p, axis=-1, keepdims=True)
+            v = v_ref[pl.ds(c0, w), :]
+            acc = acc + jnp.dot(p.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32)
+        return m, l, acc
+
+    def walk(d):
+        lines = _walk(d, block_q, block_k, "row")
+        if scratch and d is None:
+            # Carried statistics pay for every update (they go through
+            # the scratch), so a block with nothing to skip or mask is one
+            # piece, one update (PERF.md section 6, PR 45: S = 4096).
+            lines = [(0, block_q, [(0, block_k, None)])]
+        for r0, h, pieces in lines:
+            rows = pl.ds(r0, h)
+            if scratch:
+                old = m_scr[rows, 0:1], l_scr[rows, 0:1], o_scr[rows, :]
+                m_scr[rows, 0:1], l_scr[rows, 0:1], o_scr[rows, :] = update(
+                    q_ref[rows, :], pieces, old)
+            else:
+                finish(rows, *update(q_ref[rows, :], pieces, None))
+
+    _run_walk(walk, walks, causal, qi * block_q - kb * block_k, block_k)
+
+    if scratch:
+        @pl.when(kb == num_kb - 1)
+        def _finalize():
+            finish(slice(None), m_scr[:, 0:1], l_scr[:, 0:1], o_scr[:])
 
 
 def _layout_views(shape, layout):
@@ -164,6 +340,13 @@ def _layout_views(shape, layout):
     return B, N, S, H, fold, unfold
 
 
+def _kernel(body, S, block_q, block_k, causal, scale):
+    """A kernel body bound to its static walks over these grid blocks."""
+    return functools.partial(
+        body, walks=tuple(_grid_walks(S, block_q, block_k, causal)),
+        causal=causal, sm_scale=scale)
+
+
 def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
                     sm_scale: Optional[float], interpret: bool,
                     layout: str = "bsnh"):
@@ -181,9 +364,8 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
         f"seq {S} must divide blocks ({block_q},{block_k})")
 
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    kernel = functools.partial(_fwd_kernel, causal=causal, sm_scale=scale)
     of, lse = pl.pallas_call(
-        kernel,
+        _kernel(_fwd_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
@@ -198,11 +380,12 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((B * N, S, H), q.dtype),
             jax.ShapeDtypeStruct((B * N, S, _LANES), jnp.float32),
         ],
+        # the statistics are carried only over several key grid steps
         scratch_shapes=[
             pltpu.VMEM((block_q, _SCR), jnp.float32),
             pltpu.VMEM((block_q, _SCR), jnp.float32),
             pltpu.VMEM((block_q, H), jnp.float32),
-        ],
+        ] if S > block_k else [],
         interpret=interpret,
         name="flash_fwd",
     )(qf, kf, vf)
@@ -216,103 +399,107 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
 #   dV = P^T dO;   dP = dO V^T;   dS = P * (dP - D) * scale
 #   dQ = dS K;     dK = dS^T Q
 
+def _p_and_ds(q, k, v, do, lse, delta, sm_scale, rel):
+    """One sub-tile's probabilities and score gradients, [t_q, t_k] f32."""
+    p = jnp.exp(_scores(q, k, sm_scale, rel) - lse)
+    dp = lax.dot_general(                      # do @ v^T
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, causal: bool, sm_scale: float):
+               *scratch, walks: tuple, causal: bool, sm_scale: float):
     # q_ref/do_ref/dq_ref: [block_q, H]; k_ref/v_ref: [block_k, H] (streamed);
-    # lse_ref/delta_ref: [block_q, _LANES]; dq_scr: [block_q, H] f32
+    # lse_ref/delta_ref: [block_q, _LANES]; scratch, where the key grid
+    # dimension has several steps: dq [block_q, H] f32, carried across them
     block_q, head_dim = q_ref.shape
     block_k = k_ref.shape[0]
     qi, kb = pl.program_id(1), pl.program_id(2)
     num_kb = pl.num_programs(2)
-    q_start, k_start = qi * block_q, kb * block_k
 
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros((block_q, head_dim), jnp.float32)
+    if scratch:
+        dq_scr, = scratch
 
-    live = True if not causal else k_start <= q_start + block_q - 1
+        @pl.when(kb == 0)
+        def _init():
+            dq_scr[:] = jnp.zeros((block_q, head_dim), jnp.float32)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:, 0:1]
-        delta = delta_ref[:, 0:1]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = lax.dot_general(                       # q @ k^T
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        dp = lax.dot_general(                      # do @ v^T
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_scr[:] = dq_scr[:] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+    def walk(d):
+        for r0, h, pieces in _walk(d, block_q, block_k, "row"):
+            rows = pl.ds(r0, h)
+            q, do = q_ref[rows, :], do_ref[rows, :]
+            lse, delta = lse_ref[rows, 0:1], delta_ref[rows, 0:1]
+            dq = dq_scr[rows, :] if scratch else 0.0
+            for c0, w, rel in pieces:
+                k = k_ref[pl.ds(c0, w), :]
+                _, ds = _p_and_ds(q, k, v_ref[pl.ds(c0, w), :], do, lse,
+                                  delta, sm_scale, rel)
+                dq = dq + jnp.dot(ds.astype(k.dtype), k,
+                                  preferred_element_type=jnp.float32)
+            if scratch:
+                dq_scr[rows, :] = dq
+            else:
+                dq_ref[rows, :] = dq.astype(dq_ref.dtype)
 
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
-        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+    _run_walk(walk, walks, causal, qi * block_q - kb * block_k, block_k)
+
+    if scratch:
+        @pl.when(kb == num_kb - 1)
+        def _finalize():
+            dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, causal: bool, sm_scale: float):
+                dv_ref, *scratch, walks: tuple, causal: bool,
+                sm_scale: float):
     # k_ref/v_ref/dk_ref/dv_ref: [block_k, H]; q_ref/do_ref: [block_q, H]
-    # (streamed); lse_ref/delta_ref: [block_q, _LANES]
+    # (streamed); lse_ref/delta_ref: [block_q, _LANES]; scratch, where the
+    # query grid dimension has several steps: dk, dv [block_k, H] f32
     block_k, head_dim = k_ref.shape
     block_q = q_ref.shape[0]
     ki, jb = pl.program_id(1), pl.program_id(2)
     num_qb = pl.num_programs(2)
-    k_start, q_start = ki * block_k, jb * block_q
 
-    @pl.when(jb == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros((block_k, head_dim), jnp.float32)
-        dv_scr[:] = jnp.zeros((block_k, head_dim), jnp.float32)
+    if scratch:
+        dk_scr, dv_scr = scratch
 
-    live = True if not causal else q_start + block_q - 1 >= k_start
+        @pl.when(jb == 0)
+        def _init():
+            dk_scr[:] = jnp.zeros((block_k, head_dim), jnp.float32)
+            dv_scr[:] = jnp.zeros((block_k, head_dim), jnp.float32)
 
-    @pl.when(live)
-    def _compute():
-        k = k_ref[:]
-        v = v_ref[:]
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:, 0:1]
-        delta = delta_ref[:, 0:1]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        # dv += p^T @ do   (contract dim 0 of both: implicit transpose)
-        dv_scr[:] = dv_scr[:] + lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def walk(d):
+        for c0, w, pieces in _walk(d, block_q, block_k, "col"):
+            cols = pl.ds(c0, w)
+            k, v = k_ref[cols, :], v_ref[cols, :]
+            dk = dk_scr[cols, :] if scratch else 0.0
+            dv = dv_scr[cols, :] if scratch else 0.0
+            for r0, h, rel in pieces:
+                rows = pl.ds(r0, h)
+                q, do = q_ref[rows, :], do_ref[rows, :]
+                p, ds = _p_and_ds(q, k, v, do, lse_ref[rows, 0:1],
+                                  delta_ref[rows, 0:1], sm_scale, rel)
+                # contract dim 0 of both: implicit transposes
+                dv = dv + lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk = dk + lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            if scratch:
+                dk_scr[cols, :], dv_scr[cols, :] = dk, dv
+            else:
+                dk_ref[cols, :] = dk.astype(dk_ref.dtype)
+                dv_ref[cols, :] = dv.astype(dv_ref.dtype)
 
-    @pl.when(jb == num_qb - 1)
-    def _finalize():
-        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+    _run_walk(walk, walks, causal, jb * block_q - ki * block_k, block_k)
+
+    if scratch:
+        @pl.when(jb == num_qb - 1)
+        def _finalize():
+            dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
@@ -331,9 +518,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
     lse_l = jnp.broadcast_to(lse[:, :, None], (B * N, S, _LANES))
     delta_l = jnp.broadcast_to(delta[:, :, None], (B * N, S, _LANES))
 
-    dq_kernel = functools.partial(_dq_kernel, causal=causal, sm_scale=scale)
     dqf = pl.pallas_call(
-        dq_kernel,
+        _kernel(_dq_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
@@ -345,14 +531,14 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         ],
         out_specs=pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * N, S, H), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, H), jnp.float32)],
+        scratch_shapes=([pltpu.VMEM((block_q, H), jnp.float32)]
+                        if S > block_k else []),
         interpret=interpret,
         name="flash_dq",
     )(qf, kf, vf, dof, lse_l, delta_l)
 
-    dkv_kernel = functools.partial(_dkv_kernel, causal=causal, sm_scale=scale)
     dkf, dvf = pl.pallas_call(
-        dkv_kernel,
+        _kernel(_dkv_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_k, S // block_q),
         in_specs=[
             pl.BlockSpec((None, block_k, H), lambda b, i, j: (b, i, 0)),
@@ -373,7 +559,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         scratch_shapes=[
             pltpu.VMEM((block_k, H), jnp.float32),
             pltpu.VMEM((block_k, H), jnp.float32),
-        ],
+        ] if S > block_q else [],
         interpret=interpret,
         name="flash_dkv",
     )(kf, vf, qf, dof, lse_l, delta_l)

@@ -13,12 +13,17 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import importlib
+
 from ray_tpu.ops.flash_attention import (_default_blocks, _dense_reference,
-                                         flash_attention)
+                                         flash_attention, tile_plan)
 from ray_tpu.ops.ring_attention import (ring_attention,
                                         ring_attention_sharded,
                                         ulysses_attention)
 from ray_tpu.parallel import MeshSpec, make_mesh
+
+# the module: ray_tpu.ops re-exports the function under the same name
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 
 def _qkv(key=0, B=2, S=64, N=4, H=16):
@@ -26,64 +31,93 @@ def _qkv(key=0, B=2, S=64, N=4, H=16):
     return tuple(jax.random.normal(k, (B, S, N, H)) for k in ks)
 
 
-def test_flash_matches_dense_causal():
-    q, k, v = _qkv()
-    ref = _dense_reference(q, k, v, True, None)
-    out = flash_attention(q, k, v, block_q=16, block_k=16)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
+# (S, block_q, block_k, sub-tile edge or None for the module's own 256).
+# The first of each list is the grid-block-only case these tests began
+# with; in the others a grid block is walked in sub-tiles smaller than it:
+# square and not, block_q != block_k, a grid of several blocks (statistics
+# carried in scratch) and of one (not carried).
+CAUSAL_CASES = [
+    (64, 16, 16, None),
+    (64, 64, 64, 16),        # one grid block, 4 x 4 sub-tiles
+    (64, 32, 16, 8),         # unequal blocks: two offsets cross the diagonal
+    (64, 16, 32, 8),
+    (128, 64, 16, 32),       # sub-tiles (32, 16)
+    (128, 64, 64, 16),       # diagonal, plain and dead grid blocks
+    (512, None, None, None),  # the default blocks and the default sub-tile
+    (512, 256, 512, None),
+]
+NONCAUSAL_CASES = [
+    (64, 32, 16, None),
+    (64, 64, 64, 16),
+    (128, 64, 32, 16),
+    (512, None, None, None),
+]
 
 
-def test_flash_matches_dense_noncausal():
-    q, k, v = _qkv(1)
-    ref = _dense_reference(q, k, v, False, None)
-    out = flash_attention(q, k, v, False, 32, 16)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
-
-
-def test_flash_gradients():
-    q, k, v = _qkv(2, B=1, S=32, N=2, H=8)
-
+def _losses(causal, block_q, block_k, layout="bsnh"):
     def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True, 16, 16).sum()
+        return flash_attention(q, k, v, causal, block_q, block_k, None, None,
+                               layout).sum()
 
     def loss_dense(q, k, v):
-        return _dense_reference(q, k, v, True, None).sum()
+        return _dense_reference(q, k, v, causal, None).sum()
 
+    return loss_flash, loss_dense
+
+
+def _assert_grads_match(causal, S, block_q, block_k, key):
+    q, k, v = _qkv(key, B=1, S=S, N=2, H=8)
+    loss_flash, loss_dense = _losses(causal, block_q, block_k)
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.fixture
+def sub_tile(monkeypatch):
+    """Set the sub-tile edge a test's case names (None: leave the table's)."""
+    def set_edge(edge):
+        if edge is not None:
+            monkeypatch.setattr(fa, "_SUB_TILE", edge)
+    return set_edge
+
+
+@pytest.mark.parametrize("S, block_q, block_k, edge", CAUSAL_CASES)
+def test_flash_matches_dense_causal(S, block_q, block_k, edge, sub_tile):
+    sub_tile(edge)
+    q, k, v = _qkv(S=S, N=2 if S > 64 else 4)
+    ref = _dense_reference(q, k, v, True, None)
+    out = flash_attention(q, k, v, block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("S, block_q, block_k, edge", NONCAUSAL_CASES)
+def test_flash_matches_dense_noncausal(S, block_q, block_k, edge, sub_tile):
+    sub_tile(edge)
+    q, k, v = _qkv(1, S=S, N=2 if S > 64 else 4)
+    ref = _dense_reference(q, k, v, False, None)
+    out = flash_attention(q, k, v, False, block_q, block_k)
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("S, block_q, block_k, edge",
+                         [(32, 16, 16, None)] + CAUSAL_CASES[1:])
+def test_flash_gradients(S, block_q, block_k, edge, sub_tile):
+    sub_tile(edge)
+    _assert_grads_match(True, S, block_q, block_k, key=2)
 
 
 def test_flash_gradients_mixed_blocks():
     # uneven block_q/block_k exercise the diagonal masking in both bwd kernels
-    q, k, v = _qkv(7, B=1, S=64, N=2, H=8)
-
-    def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True, 32, 16).sum()
-
-    def loss_dense(q, k, v):
-        return _dense_reference(q, k, v, True, None).sum()
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, atol=2e-5)
+    _assert_grads_match(True, 64, 32, 16, key=7)
 
 
-def test_flash_gradients_noncausal():
-    q, k, v = _qkv(8, B=1, S=32, N=2, H=8)
-
-    def loss_flash(q, k, v):
-        return flash_attention(q, k, v, False, 16, 16).sum()
-
-    def loss_dense(q, k, v):
-        return _dense_reference(q, k, v, False, None).sum()
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, atol=2e-5)
+@pytest.mark.parametrize("S, block_q, block_k, edge",
+                         [(32, 16, 16, None)] + NONCAUSAL_CASES[1:])
+def test_flash_gradients_noncausal(S, block_q, block_k, edge, sub_tile):
+    sub_tile(edge)
+    _assert_grads_match(False, S, block_q, block_k, key=8)
 
 
 def test_flash_bwd_memory_is_linear_in_seq():
@@ -137,6 +171,115 @@ def test_strict_divisibility_error_suggests_padding():
         _default_blocks(100, strict=True)
     with pytest.raises(ValueError, match=r"Pad the sequence to 8"):
         _default_blocks(7, strict=True)
+
+
+# What the kernels do for one head: (S, block_q, block_k, causal) ->
+# sub-tiles (t_q, t_k), computed, masked, of how many.
+@pytest.mark.parametrize("S, block_q, block_k, causal, want", [
+    (1024, 1024, 1024, True, (256, 256, 10, 4, 16)),   # both training cells
+    (1024, 1024, 1024, False, (256, 256, 16, 0, 16)),
+    (4096, 1024, 1024, True, (256, 256, 136, 16, 256)),  # long-2x4096
+    (2048, 1024, 1024, True, (256, 256, 36, 8, 64)),   # 2 diagonal, 1 plain
+    (1024, 512, 512, True, (256, 256, 10, 4, 16)),     # the grid skips 1 of 4
+    (64, 32, 16, True, (32, 16, 6, 4, 8)),             # no sub-tiles: blocks
+    (64, 16, 32, True, (16, 32, 6, 4, 8)),
+    (512, 256, 512, True, (256, 256, 3, 2, 4)),
+])
+def test_tile_plan(S, block_q, block_k, causal, want):
+    plan = tile_plan(S, block_q, block_k, causal)
+    assert (plan["sub_q"], plan["sub_k"], plan["computed"], plan["masked"],
+            plan["total"]) == want
+
+
+# The one choice a kernel makes on the chip, which walk a grid step runs
+# (`_runs`, on the step's traced offset), against the static `_walk_key`
+# that `tile_plan` counts by: over every block of a grid, with one block a
+# head, several, unequal ones either way round, and no mask.
+@pytest.mark.parametrize("S, block_q, block_k, causal", [
+    (1024, 1024, 1024, True),
+    (4096, 1024, 1024, True),
+    (4096, 1024, 1024, False),
+    (64, 32, 16, True),
+    (64, 16, 32, True),
+    (7, 1, 1, True),                 # what the interpreter admits for S = 7
+])
+def test_a_grid_step_runs_the_walk_the_plan_counts(S, block_q, block_k,
+                                                   causal):
+    walks = fa._grid_walks(S, block_q, block_k, causal)
+    seen = dict.fromkeys(walks, 0)
+    for i in range(S // block_q):
+        for j in range(S // block_k):
+            d = i * block_q - j * block_k
+            key = fa._walk_key(d, block_q, block_k, causal)
+            ran = [k for k in walks if fa._runs(k, d, block_k, causal)]
+            assert ran == ([] if key == fa._DEAD else [key]), (i, j)
+            for k in ran:
+                seen[k] += 1
+    assert seen == walks
+    if not causal:
+        assert walks == {None: (S // block_q) * (S // block_k)}
+
+
+def _kernel_primitives(jaxpr, inside=False, scratch=None) -> list:
+    """Names of the primitives inside the Pallas kernels of a jaxpr, the
+    branches of their ``pl.when``s included; ``scratch``, if a list, gains
+    each kernel's number of scratch buffers."""
+    names = []
+    for eqn in jaxpr.eqns:
+        kernel = inside or eqn.primitive.name == "pallas_call"
+        if inside:
+            names.append(eqn.primitive.name)
+        elif kernel and scratch is not None:
+            scratch.append(eqn.params["grid_mapping"].num_scratch_operands)
+        for sub in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                names += _kernel_primitives(sub, kernel, scratch)
+    return names
+
+
+# Accumulators are carried in scratch only where the streamed grid
+# dimension has several steps; with one step a kernel allocates none.
+@pytest.mark.parametrize("S, block_q, block_k, want", [
+    (1024, None, None, [0, 0, 0]),        # both training cells
+    (2048, None, None, [3, 1, 2]),        # forward, dq, dkv
+    (64, 64, 16, [3, 1, 0]),              # dkv's query dimension: one step
+    (64, 16, 64, [0, 0, 2]),
+])
+def test_scratch_only_where_carried(S, block_q, block_k, want):
+    x = jax.ShapeDtypeStruct((1, S, 1, 64), jnp.bfloat16)
+    scratch = []
+    _kernel_primitives(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, True, block_q, block_k)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(x, x, x).jaxpr,
+        scratch=scratch)
+    assert scratch == want
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_kernels_are_built_from_the_tile_plan(causal):
+    """At the training cells' shape the three kernels hold one mask per
+    masked sub-tile of the plan and nothing for a dead one: the forward a
+    product pair per piece (7 causal: a strip and a masked sub-tile a row
+    but the first; 4 where nothing is masked), dq three and dkv four."""
+    x = jax.ShapeDtypeStruct((1, 1024, 1, 64), jnp.bfloat16)
+    plan = tile_plan(1024, 1024, 1024, causal)
+    pieces = 7 if causal else 4
+
+    def count(fn):
+        names = _kernel_primitives(jax.make_jaxpr(fn)(x, x, x).jaxpr)
+        return names.count("select_n"), names.count("dot_general")
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal)
+
+    assert count(fwd) == (plan["masked"], 2 * pieces)
+    # the backward pass: the forward again, then dq and dkv
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   argnums=(0, 1, 2))
+    assert count(bwd) == (3 * plan["masked"], (2 + 3 + 4) * pieces)
 
 
 def _flash_fwd_bwd_text():
@@ -205,22 +348,26 @@ def test_ulysses_matches_dense():
     np.testing.assert_allclose(out, ref, atol=2e-5)
 
 
-def test_flash_bnsh_layout_forward_and_grads():
+@pytest.mark.parametrize("S, block_q, block_k, edge", [
+    (32, 16, 16, None),
+    (64, 64, 32, 16),        # sub-tiles inside the grid blocks
+    (512, None, None, None),
+])
+def test_flash_bnsh_layout_forward_and_grads(S, block_q, block_k, edge,
+                                             sub_tile):
     """Head-major layout: forward AND gradients must match the bsnh path
     (the GPT block's default attention now runs through bnsh)."""
-    q, k, v = _qkv(10, B=2, S=32, N=4, H=8)
+    sub_tile(edge)
+    q, k, v = _qkv(10, B=2 if S < 512 else 1, S=S, N=4 if S < 512 else 2,
+                   H=8)
     qb, kb, vb = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
 
-    out_b = flash_attention(qb, kb, vb, True, 16, 16, None, None, "bnsh")
+    out_b = flash_attention(qb, kb, vb, True, block_q, block_k, None, None,
+                            "bnsh")
     ref = _dense_reference(q, k, v, True, None)
     np.testing.assert_allclose(out_b.transpose(0, 2, 1, 3), ref, atol=2e-5)
 
-    def loss_bnsh(q, k, v):
-        return flash_attention(q, k, v, True, 16, 16, None, None,
-                               "bnsh").sum()
-
-    def loss_dense(q, k, v):
-        return _dense_reference(q, k, v, True, None).sum()
+    loss_bnsh, loss_dense = _losses(True, block_q, block_k, "bnsh")
 
     g_b = jax.grad(loss_bnsh, argnums=(0, 1, 2))(qb, kb, vb)
     g_d = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)

@@ -98,13 +98,19 @@ def _compile(fn, *args, donate=()):
     return compiled, compiled.as_text()
 
 
-def _kernels(text: str) -> set:
+def _kernel_calls(text: str) -> list:
     """The flash kernel named by each instruction that is a Pallas kernel
     (``%flash_dq.9``; ``%transpose_jvp_flash_dq__`` where ``jax.grad`` is
-    applied to the kernel's own ``custom_vjp``): what a profile shows."""
-    return {re.match(r"\s*(?:ROOT )?%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = ",
-                     line).group(1) for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line}
+    applied to the kernel's own ``custom_vjp``): what a profile shows.  A
+    Pallas kernel of another name matches nothing and raises."""
+    return sorted(
+        re.match(r"\s*(?:ROOT )?%\w*?(flash_(?:fwd|dq|dkv))[\w.]* = ",
+                 line).group(1) for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def _kernels(text: str) -> set:
+    return set(_kernel_calls(text))
 
 
 def _expert_kernels(text: str, *stacks: str) -> list:
@@ -220,8 +226,13 @@ def test_gpt2_small_train_step_compiles(topo, compiled_kernels, spec):
     with jax.sharding.set_mesh(mesh):
         compiled = make_train_step(GPT2, tx, rules).lower(*args).compile()
     text = compiled.as_text()
-    # the kernels by the names a profile reads, under shard_map too
-    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    # the kernels by the names a profile reads, under shard_map too; and
+    # what benchmark/metrics/flash_roofline.py counts on: every Pallas
+    # kernel of the step is one of the three, dq and dkv once in the
+    # backward layer body, the forward in both bodies (remat_policy "dots"
+    # runs it again)
+    assert _kernel_calls(text) == ["flash_dkv", "flash_dq", "flash_fwd",
+                                   "flash_fwd"]
     assert _scoped(text, "ce_head") and _scoped(text, "optimizer")
     if spec.num_devices > 1:
         assert "all-reduce" in text or "reduce-scatter" in text
