@@ -182,9 +182,16 @@ def _flash_profitable(S: int) -> bool:
     attention at sequence length S, from what the code can observe: S and
     the backend.  The kernels need S in whole 128-lane tiles and enough of
     them to amortize the grid and the K/V stream; the interpreter on the
-    CPU never wins.  The crossover itself has no measurement on the chip
-    yet (ROADMAP Speed 10, Reach 6): both training cells of the benchmark
-    pin ``flash`` at S = 1024.  A measured crossover edits this function."""
+    CPU never wins.  Measured on the chip for the forward at head size 128
+    (``scripts/flash_sweep.py --fwd-only``, PERF.md section 6, PR 46: the
+    kernel against the dense function alone, ms a call at S = 512 / 1024 /
+    2048): 0.058 / 0.112 / 0.440 against 0.062 / 0.426 / 1.497 with 32
+    query heads on 8, 0.044 / 0.074 / 0.244 against 0.045 / 0.095 / 0.858
+    with 16 on 16: a tie at 512, the kernel from 1024 on.  Both training
+    cells of the benchmark pin ``flash`` at S = 1024; the served prefill
+    (``models/llama.py::llama_prefill``) asks here rung by rung.  The
+    backward kernels' crossover is not measured (ROADMAP Speed 10, Reach
+    6).  A measured crossover edits this function."""
     if S < 1024 or S % 128:
         return False
     return jax.default_backend() != "cpu"

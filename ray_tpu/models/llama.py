@@ -894,11 +894,33 @@ def _paged_results(logits, k_pages, v_pages, load):
     return logits, k_pages, v_pages, load
 
 
+def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
+    """The attention ``llama_prefill`` runs over a padded sequence of S
+    positions, "flash" or "dense": what ``resolve_attention`` says of
+    ``cfg.attention`` and S, as for ``llama_hidden``.  Two kinds of model
+    are dense at every S: a block model, whose mask is causal over blocks
+    (the kernel's sub-tile walk does not express it), and a latent one,
+    whose q/k heads are wider than its v heads; ``flash`` pinned on either
+    raises."""
+    unwritten = "block_length" if cfg.block_length else \
+        "kv_lora_rank" if cfg.kv_lora_rank else None
+    if unwritten and cfg.attention == "flash":
+        raise NotImplementedError(
+            f"llama_prefill: the flash kernel is not written for a model "
+            f"with {unwritten}; leave attention 'auto' or 'dense'")
+    if unwritten or resolve_attention(cfg.attention, S) != "flash":
+        return "dense"
+    return "flash"
+
+
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
                   k_pages: jax.Array, v_pages: Optional[jax.Array],
                   page_table: jax.Array):
-    """Prefill ONE padded sequence (see gpt_prefill): dense trunk,
+    """Prefill ONE padded sequence (see gpt_prefill): the trunk over the
+    whole padded length, its causal attention by the flash forward kernel
+    or dense as ``llama_prefill_attention`` says (the kernel reads grouped
+    k and v where they lie and writes no scores to memory),
     per-layer post-rope K/V scattered into the sequence's pages, f32
     next-token logits at position length-1.  ``tokens`` [1, S] with S a
     multiple of the page size; ``page_table`` [1, maxp];
@@ -910,10 +932,12 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     and the logits are empty, ``[1, 0]``.  An expert model returns a
     fourth result, ``load`` [expert layers, E] int32: per layer and expert,
     the assignments of the prompt's real positions."""
+    from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.ops.paged_attention import prefill_kv, prefill_latent
     dt = cfg.dtype
     rep = 0 if cfg.kv_lora_rank else cfg.num_heads // cfg.num_kv_heads
     S = tokens.shape[1]
+    flash = llama_prefill_attention(cfg, S) == "flash"
     cos, sin = _rope_tables(cfg, S)
     x = _embed(cfg, params, tokens)
     live = (jnp.arange(S) < length)[None]                # the real positions
@@ -936,7 +960,12 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
             q, k = _qk(cfg, p, q, k, cos, sin)
             pools = prefill_kv(kp, vp, layer, k[0], v[0], length,
                                page_table[0])
-            o = _dense_causal_attention_gqa(q, k, v, rep, cfg.block_length)
+            if flash:    # the padded tail lies after every real position
+                o = flash_attention(q, k, v, True, None, None, None, None,
+                                    "bnsh")
+            else:
+                o = _dense_causal_attention_gqa(q, k, v, rep,
+                                                cfg.block_length)
             return jnp.einsum("bnsh,nhd->bsd", o,
                               p["attn"]["wo"].astype(dt)), pools
 

@@ -38,6 +38,13 @@ where it has several they are carried in scratch, and the forward, whose
 statistics pay for every update, takes a grid block below the diagonal as
 one piece.
 
+Grouped queries, forward only: k and v may have G heads for q's N = G * rep,
+and a query head reads its group's through the BlockSpec index map (``b //
+rep``), so nothing is repeated in memory.  That is the served prefill's call
+(``models/llama.py::llama_prefill`` on the rungs where
+``models/gpt.py::resolve_attention`` says flash); a gradient through it
+raises.
+
 On the CPU backend the same kernels run in Pallas interpret mode, keeping
 CPU tests honest.
 
@@ -58,6 +65,12 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import kernel_source
+
+# The kernels' modules name no file (kernel_source.py says why): a serving
+# engine compiles the forward into its long prefill rungs at every start.
+kernel_source.exclude(__file__)
 
 _NEG_INF = -1e30
 _LANES = 8     # minor-dim width of the lse/D carrier tensors
@@ -347,6 +360,12 @@ def _kernel(body, S, block_q, block_k, causal, scale):
         causal=causal, sm_scale=scale)
 
 
+# jitted, and inlined where it is called (as ops/grouped_matmul.py's
+# ``_tiled``): a serving engine compiles the forward into each of its long
+# prefill rungs and runs the jitted prefill beside them, and traces the
+# kernel once a shape.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "block_q", "block_k", "sm_scale", "interpret", "layout"))
 def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
                     sm_scale: Optional[float], interpret: bool,
                     layout: str = "bsnh"):
@@ -355,22 +374,34 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
     [B*N, S, H] view is a FREE reshape; models that keep attention in
     bnsh (the GPT block does) skip ~25% of attention wall-clock that
     the bsnh relayouts cost at bench scale.
+    k and v may have fewer heads than q, G with N = G * rep (grouped
+    queries): a query head then reads its group's k and v through the
+    index map, ``b // rep`` of the folded [B*G, S, H], and no copy of
+    them is made.
     Returns (o in the input layout, lse [B*N, S] f32)."""
     B, N, S, H, _fold, _unfold = _layout_views(q.shape, layout)
+    _, G, _, _, _fold_kv, _ = _layout_views(k.shape, layout)
+    rep = N // G
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(H)
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     assert S % block_q == 0 and S % block_k == 0, (
         f"seq {S} must divide blocks ({block_q},{block_k})")
 
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    of, lse = pl.pallas_call(
+    def kv_block(b, i, j):
+        # lax.div, not ``//``: jnp's operators are jitted functions whose
+        # cached jaxprs keep the source locations of whoever traced them
+        # first in this process, and would carry those into the module
+        return (b if rep == 1 else lax.div(b, jnp.int32(rep))), j, 0
+
+    qf, kf, vf = _fold(q), _fold_kv(k), _fold_kv(v)
+    call = pl.pallas_call(
         _kernel(_fwd_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, H), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, H), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, block_k, H), kv_block),
+            pl.BlockSpec((None, block_k, H), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
@@ -388,7 +419,9 @@ def _flash_fwd_impl(q, k, v, *, causal: bool, block_q: int, block_k: int,
         ] if S > block_k else [],
         interpret=interpret,
         name="flash_fwd",
-    )(qf, kf, vf)
+    )
+    with kernel_source.nowhere():
+        of, lse = call(qf, kf, vf)
     return _unfold(of), lse[:, :, 0]
 
 
@@ -518,7 +551,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
     lse_l = jnp.broadcast_to(lse[:, :, None], (B * N, S, _LANES))
     delta_l = jnp.broadcast_to(delta[:, :, None], (B * N, S, _LANES))
 
-    dqf = pl.pallas_call(
+    dq_call = pl.pallas_call(
         _kernel(_dq_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_q, S // block_k),
         in_specs=[
@@ -535,9 +568,9 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
                         if S > block_k else []),
         interpret=interpret,
         name="flash_dq",
-    )(qf, kf, vf, dof, lse_l, delta_l)
+    )
 
-    dkf, dvf = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         _kernel(_dkv_kernel, S, block_q, block_k, causal, scale),
         grid=(B * N, S // block_k, S // block_q),
         in_specs=[
@@ -562,7 +595,10 @@ def _flash_bwd_impl(q, k, v, o, lse, g, *, causal: bool, block_q: int,
         ] if S > block_q else [],
         interpret=interpret,
         name="flash_dkv",
-    )(kf, vf, qf, dof, lse_l, delta_l)
+    )
+    with kernel_source.nowhere():
+        dqf = dq_call(qf, kf, vf, dof, lse_l, delta_l)
+        dkf, dvf = dkv_call(kf, vf, qf, dof, lse_l, delta_l)
 
     return _unfold(dqf), _unfold(dkf), _unfold(dvf)
 
@@ -595,10 +631,12 @@ def flash_attention(q, k, v, causal: bool = True,
     the fold transposes entirely (~25% of attention time at short seq).
     block_q/block_k are the kernel's own parameters; left None they are
     what `_default_blocks` gives for S.
+    k and v may have fewer heads than q (grouped queries: each run of
+    ``heads // kv_heads`` query heads shares one), forward only: the
+    served prefill's call.
     """
-    out, _ = _fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
-                  layout)
-    return out
+    return _forward(q, k, v, causal, block_q, block_k, sm_scale, interpret,
+                    layout)[0]
 
 
 def _resolve(q, block_q, block_k, interpret, layout):
@@ -615,12 +653,22 @@ def _resolve(q, block_q, block_k, interpret, layout):
     return block_q, block_k, interpret
 
 
+def _forward(q, k, v, causal, block_q, block_k, sm_scale, interpret, layout):
+    bq, bk, interp = _resolve(q, block_q, block_k, interpret, layout)
+    return _flash_fwd_impl(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                           sm_scale=sm_scale, interpret=interp,
+                           layout=layout)
+
+
 def _fwd(q, k, v, causal, block_q, block_k, sm_scale, interpret,
          layout="bsnh"):
-    bq, bk, interp = _resolve(q, block_q, block_k, interpret, layout)
-    out, lse = _flash_fwd_impl(q, k, v, causal=causal, block_q=bq,
-                               block_k=bk, sm_scale=sm_scale,
-                               interpret=interp, layout=layout)
+    if k.shape != q.shape:
+        raise NotImplementedError(
+            f"flash_attention: no gradient is written for grouped heads "
+            f"(q {list(q.shape)}, k/v {list(k.shape)}); repeat k and v up "
+            f"to the query heads, as models/llama.py's training trunk does")
+    out, lse = _forward(q, k, v, causal, block_q, block_k, sm_scale,
+                        interpret, layout)
     return out, (q, k, v, out, lse)
 
 

@@ -28,15 +28,14 @@ On the CPU backend the kernel runs in Pallas interpret mode.
 from __future__ import annotations
 
 import functools
-import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax._src import source_info_util
-from jax._src.lib import xla_client
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import kernel_source
 
 # Fast memory the kernel asks for (a v5e core has 128 MiB), and the most one
 # buffer of the weights' tile may take of it: the tile is double-buffered,
@@ -49,25 +48,8 @@ _LANES = 128
 _MAX_ROW_TILE = 512
 
 
-# The kernel's compiled module carries the source location of each of its
-# operations, every calling frame included, and the module's bytes are in the
-# key of the persistent compilation cache: with them a checkout at another
-# path, or a line moved in any caller, compiles every program again.  So the
-# kernel is traced as if written nowhere: this file's frames are not "user
-# code" to JAX, and its operations are given a traceback that holds no
-# others.
-source_info_util.register_exclusion(__file__)
-
-
-@functools.cache
-def _nowhere():
-    """A traceback of a thread that ran nothing but a line of this file."""
-    box = []
-    thread = threading.Thread(
-        target=lambda: box.append(xla_client.Traceback.get_traceback()))
-    thread.start()
-    thread.join()
-    return box[0]
+# The kernel's module names no file (kernel_source.py says why).
+kernel_source.exclude(__file__)
 
 
 def _tiles(m: int, K: int, N: int, itemsize: int, groups: int) -> tuple:
@@ -194,6 +176,6 @@ def _tiled(lhs, rhs, group_sizes, layer, *, tm: int, tn: int,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="grouped_matmul")
-    with source_info_util.user_context(_nowhere()):
+    with kernel_source.nowhere():
         out = call(offsets, group_ids, tile_ids, layer, lhs, rhs)
     return out[:m]

@@ -25,7 +25,12 @@ up to whole pages, and ``max_prompt_len`` itself on top).  The ladder is
 derived from ``max_prompt_len`` and ``page_size`` alone, nothing configures
 it, and a ``max_prompt_len`` of 128 or less has the one rung.  Padding lies
 after the prompt under a causal mask and its K/V go to scratch page 0, so a
-shorter rung gives the logits and pages of a longer one.  Every rung's
+shorter rung gives the logits and pages of a longer one.  A rung's causal
+attention is the flash forward kernel where the model's own selector says so
+(``models/llama.py::llama_prefill_attention``: on the chip the rungs of 1024
+and more of a model with K/V pages and a causal mask), else dense with its
+scores in memory; ``rt:engine.prefill`` names which as ``attention`` and
+``stats()["prefill"]`` counts the prefills by it.  Every rung's
 program is compiled while the engine is constructed, a few at a time on
 threads of their own, and the loop admits nobody before all of them are
 there: no request meets a compile.  Decode runs the whole batch (fixed shape
@@ -388,6 +393,7 @@ class InferenceEngine:
                 seq=cfg.max_prompt_len + cfg.max_new_tokens)
             init_fn, stored_fn, prefill_fn, decode_fn = \
                 gpt_init, gpt_serving_params, gpt_prefill, gpt_decode_step
+            attention_fn = lambda mc, rung: "dense"   # noqa: E731
             cache_fn = lambda: init_paged_cache(   # noqa: E731
                 mc, cfg.num_pages, cfg.page_size, cfg.dtype)
         elif cfg.model == "llama":
@@ -396,12 +402,14 @@ class InferenceEngine:
                                               llama_init,
                                               llama_init_paged_cache,
                                               llama_prefill,
+                                              llama_prefill_attention,
                                               llama_serving_params)
             mc = cfg.model_config or LlamaConfig.tiny(
                 seq=cfg.max_prompt_len + cfg.max_new_tokens)
             init_fn, stored_fn, prefill_fn, decode_fn = \
                 llama_init, llama_serving_params, llama_prefill, \
                 llama_decode_step
+            attention_fn = llama_prefill_attention
             if mc.block_length:      # its decode step is a block's pass
                 from ray_tpu.models.llama import (block_unmask,
                                                   llama_block_step)
@@ -483,6 +491,9 @@ class InferenceEngine:
         # The loop's decode programs likewise: ``_decode_next_donating``
         # compiled for every width of the decode ladder.
         self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
+        # what each rung's attention runs as ("flash": the kernel, "dense")
+        self._rung_attention = {rung: attention_fn(mc, rung)
+                                for rung in self._rungs}
         self._decode_rungs = decode_rungs(self._maxp)
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -498,6 +509,7 @@ class InferenceEngine:
             for width in self._decode_rungs}
         pool.shutdown(wait=False)    # the threads end with their compiles
         self._prefill_shapes = dict.fromkeys(self._rungs, 0)
+        self._prefill_attention = {"dense": 0, "flash": 0}
         self._decode_shapes = dict.fromkeys(self._decode_rungs, 0)
 
         self._waiting: collections.deque = collections.deque()
@@ -614,7 +626,9 @@ class InferenceEngine:
         the ``queue_wait_s`` they spent between ``generate()`` and their
         prefill's dispatch, ``prefill_tokens`` of prompt against the
         ``prefill_padded_tokens`` the padded programs ran (a prefill adds
-        its rung) and ``prefill_shapes``, the prefills by rung,
+        its rung), ``prefill_shapes``, the prefills by rung, and
+        ``prefill["attention"]``, the prefills by what their rung's
+        attention ran as ("flash": the kernel, "dense"),
         ``decode_shapes``, the decode steps by the width of their page table
         in pages (a rung of ``decode_rungs``; they sum to ``steps``),
         ``retired`` sequences by reason, and of a model with experts the
@@ -681,6 +695,7 @@ class InferenceEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "prefill_shapes": dict(self._prefill_shapes),
+                "prefill": {"attention": dict(self._prefill_attention)},
                 "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
                 **({"block": {**self._block_stats, "denoise_passes_by_count":
@@ -1283,13 +1298,16 @@ class InferenceEngine:
                     self._prefill_tokens += len(seq.prompt)
                     self._prefill_padded_tokens += S
                     self._prefill_shapes[S] += 1
+                    attention = self._rung_attention[S]
+                    self._prefill_attention[attention] += 1
 
                     def _run(seq=seq, S=S, program=program, toks=toks,
                              submitted=submitted, sampled=sampled,
-                             whole=whole):
+                             whole=whole, attention=attention):
                         start = self._clocks(sampled)
                         with region("engine.prefill",
                                     prompt_len=len(seq.prompt), padded_len=S,
+                                    attention=attention,
                                     waited_us=_us(start.wall - seq.queued),
                                     submit_us=_us(
                                         start.wall - submitted.wall)):

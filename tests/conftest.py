@@ -101,6 +101,10 @@ STALE_SNAPSHOTS = {
         "counts the benchmark's cells (7) and configurations (6) as PR 34 "
         "left them; PR 40 added one of each.  Its other assertions run as "
         "test_spec_sdar.py::test_published_widths_of_xing_still_hold",
+    "test_itl_mean.py::test_chats_layers_move_the_mean":
+        "lists chat's per-layer metrics as PR 44 left them; PR 46 added "
+        "prefill_flash_share.chat.  Its assertions run, with that name, as "
+        "test_prefill_flash_share.py::test_chats_layers_still_move_the_mean",
 }
 
 

@@ -373,3 +373,64 @@ def test_flash_bnsh_layout_forward_and_grads(S, block_q, block_k, edge,
     g_d = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_b, g_d):
         np.testing.assert_allclose(a.transpose(0, 2, 1, 3), b, atol=2e-5)
+
+
+# (rep, layout, S, block_q, block_k, sub-tile edge): one grid block of 4 x 4
+# sub-tiles (statistics not carried) and several grid blocks (carried), each
+# with k and v read by the index map alone (rep 1) and shared by four query
+# heads, in the served prefill's head-major layout and, once, in the other.
+GROUPED_CASES = [
+    (1, "bnsh", 64, 64, 64, 16),
+    (4, "bnsh", 64, 64, 64, 16),
+    (4, "bnsh", 128, 64, 32, 16),
+    (1, "bnsh", 128, 64, 32, 16),
+    (2, "bsnh", 64, 32, 32, 8),
+]
+
+
+@pytest.mark.parametrize("rep, layout, S, block_q, block_k, edge",
+                         GROUPED_CASES)
+def test_grouped_forward_matches_the_dense_grouped_function(
+        rep, layout, S, block_q, block_k, edge, sub_tile):
+    """Grouped queries: k and v with G heads for q's G * rep, never
+    repeated; against ``models/llama.py::_dense_causal_attention_gqa``,
+    the function the served prefill ran at every rung before, in f32."""
+    from ray_tpu.models.llama import _dense_causal_attention_gqa
+    sub_tile(edge)
+    B, G, H = 2, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (B, G * rep, S, H))
+    k, v = (jax.random.normal(key, (B, G, S, H)) for key in ks[1:])
+    want = _dense_causal_attention_gqa(q, k, v, rep)
+    if layout == "bsnh":
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    got = flash_attention(q, k, v, True, block_q, block_k, None, None, layout)
+    if layout == "bsnh":
+        got = got.transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_a_gradient_through_grouped_heads_raises():
+    """Forward only: the prefill takes no gradient, and the backward
+    kernels read k and v head for head."""
+    q = jnp.ones((1, 4, 32, 8))
+    kv = jnp.ones((1, 2, 32, 8))
+    with pytest.raises(NotImplementedError, match="grouped heads"):
+        jax.grad(lambda q: flash_attention(
+            q, kv, kv, True, None, None, None, None, "bnsh").sum())(q)
+
+
+def test_equal_heads_read_their_own_block():
+    """The training calls' index map is the one it was: block ``b`` of k
+    and v for head ``b``, no division traced into it."""
+    x = jax.ShapeDtypeStruct((2, 4, 64, 8), jnp.float32)
+    kv = jax.ShapeDtypeStruct((2, 2, 64, 8), jnp.float32)
+
+    def maps(k):
+        jaxpr = jax.make_jaxpr(lambda q, k, v: fa._forward(
+            q, k, v, True, None, None, None, None, "bnsh"))(x, k, k)
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return [str(m.index_map_jaxpr) for m in
+                call.params["grid_mapping"].block_mappings]
+    assert not any("div" in text for text in maps(x))
+    assert sum("div" in text for text in maps(kv)) == 2

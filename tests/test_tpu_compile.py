@@ -136,6 +136,17 @@ def _scoped(text: str, scope: str) -> bool:
                for name in re.findall(r'op_name="([^"]*)"', text))
 
 
+def _names_no_source(lowered, name: bytes) -> None:
+    """The one Pallas kernel of a lowered program: its serialized module
+    holds the kernel's ``name`` and no file's."""
+    import base64
+    body, = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                       lowered.as_text())
+    module = base64.b64decode(body)
+    assert name in module
+    assert b".py" not in module and os.getcwd().encode() not in module
+
+
 def _fits(compiled) -> int:
     m = compiled.memory_analysis()
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -404,11 +415,12 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
 MISTRAL_BUDGET = int(9.5 * 1024 ** 3)
 
 
-def _mistral_engine_program(topo, program, rung=None):
+def _mistral_engine_program(topo, program, rung=None, **overrides):
     from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=32768, num_layers=8, num_heads=32,
                       num_kv_heads=8, embed_dim=4096, mlp_dim=14336,
-                      rope_theta=1e6, rms_eps=1e-5, max_seq_len=2048 + 512)
+                      rope_theta=1e6, rms_eps=1e-5, max_seq_len=2048 + 512,
+                      **overrides)
     return _llama_engine_program(topo, cfg, program, prompt=2048, new=512,
                                  num_pages=2561, rung=rung)
 
@@ -438,6 +450,34 @@ def test_mistral_prefill_compiles_at_every_lower_rung(topo, rung):
     _, compiled, text = _mistral_engine_program(topo, "prefill", rung=rung)
     assert "convert(%p__" not in text
     assert _fits(compiled) < MISTRAL_BUDGET
+
+
+@pytest.mark.parametrize("rung", [1024, 2048])
+def test_mistral_prefill_runs_the_flash_kernel_on_its_long_rungs(
+        topo, compiled_kernels, rung):
+    """On the chip ``resolve_attention`` gives the rungs of 1024 and 2048
+    the flash forward kernel (here the backend is the CPU, so the test pins
+    it): one kernel in the layer scan, grouped k and v read where they lie
+    (no [1, 32, S, 128] copy of them), no [S, S] scores in memory, which
+    were 0.5 GiB of the dense program's temporaries at 2048; and the
+    kernel's module, part of the compile cache's key in every replica's
+    start, names no file (``test_grouped_matmul_module_names_no_source``)."""
+    _, compiled, text = _mistral_engine_program(topo, "prefill", rung=rung,
+                                                attention="flash")
+    assert _kernel_calls(text) == ["flash_fwd"]
+    assert f"8,4,{rung},{rung}]" not in text      # scores
+    assert f"bf16[1,32,{rung},128]" in text       # q and the output
+    assert not re.search(rf"bf16\[1,32,{rung},128\]\S* broadcast", text)
+    _, dense, _ = _mistral_engine_program(topo, "prefill", rung=rung)
+    saved = dense.memory_analysis().temp_size_in_bytes \
+        - compiled.memory_analysis().temp_size_in_bytes
+    assert saved > 32 * rung * rung * 2           # a bf16 copy of the scores
+    assert _fits(compiled) < MISTRAL_BUDGET
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 32, rung, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8, rung, 128), jnp.bfloat16, sharding=one)
+    _names_no_source(
+        jax.jit(lambda *a: _flash(*a, "bnsh")).lower(q, kv, kv), b"flash_fwd")
 
 
 # OLMoE-1B-7B-0125-Instruct at its published widths, 4 of its 16 layers, with
@@ -485,19 +525,16 @@ def test_grouped_matmul_module_names_no_source(topo):
     another path, or a line moved in a caller, would compile every expert
     program again.  The module of ``ops/grouped_matmul.py`` names its
     kernel and no file."""
-    import base64
     one = SingleDeviceSharding(topo.devices[0])
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
-    text = jax.jit(lambda *a: gm.grouped_matmul(*a, interpret=False)).lower(
-        shape((256, 2048), jnp.float32),
-        shape((4 * 128, 2048, 1024), jnp.float32),
-        shape((128,), jnp.int32), shape((), jnp.int32)).as_text()
-    body, = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
-    module = base64.b64decode(body)
-    assert b"grouped_matmul" in module
-    assert b".py" not in module and os.getcwd().encode() not in module
+    _names_no_source(
+        jax.jit(lambda *a: gm.grouped_matmul(*a, interpret=False)).lower(
+            shape((256, 2048), jnp.float32),
+            shape((4 * 128, 2048, 1024), jnp.float32),
+            shape((128,), jnp.int32), shape((), jnp.int32)),
+        b"grouped_matmul")
 
 
 # Ouro-2.6B whole: published widths, all 48 layers run four times, with the
