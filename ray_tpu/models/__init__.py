@@ -22,6 +22,10 @@ from ray_tpu.models.llama import (  # noqa: F401
     llama_loss,
     llama_param_axes,
 )
+from ray_tpu.models.serving import (  # noqa: F401
+    ServedModel,
+    serving_model,
+)
 from ray_tpu.models.mlp import (  # noqa: F401
     mlp_forward,
     mlp_init,

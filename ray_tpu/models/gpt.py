@@ -229,18 +229,8 @@ def _flash_attention_bnsh(rules: Optional[LogicalAxisRules], mesh=None):
     return attn_fn
 
 
-def _dense_causal_attention(q, k, v):
-    """[B,S,N,H] bf16 attention with causal mask; softmax in f32."""
-    S = q.shape[1]
-    scores = jnp.einsum("bqnh,bknh->bnqk", q, k) / np.sqrt(q.shape[-1])
-    mask = jnp.tril(jnp.ones((S, S), bool))
-    scores = jnp.where(mask[None, None], scores.astype(jnp.float32), -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bnqk,bknh->bqnh", probs, v)
-
-
 def _dense_causal_attention_bnsh(q, k, v):
-    """[B,N,S,H] (head-major) dense attention; same math, no relayouts."""
+    """[B,N,S,H] (head-major) dense causal attention; softmax in f32."""
     S = q.shape[2]
     scores = jnp.einsum("bnqh,bnkh->bnqk", q, k) / np.sqrt(q.shape[-1])
     mask = jnp.tril(jnp.ones((S, S), bool))
@@ -531,6 +521,17 @@ def gpt_decode_step(params: Dict[str, Any], cfg: GPTConfig,
     logits = jnp.einsum("bd,vd->bv", x,
                         params["wte"].astype(dt)).astype(jnp.float32)
     return logits, k_pages, v_pages
+
+
+def served(config: Optional[GPTConfig] = None, seq: int = 0):
+    """The serving engine's record of this model (``models/serving.py``)."""
+    from ray_tpu.models.serving import ServedModel, greedy
+    cfg = config or GPTConfig.tiny(seq=seq)
+    return ServedModel(
+        config=cfg, init=gpt_init, stored=gpt_serving_params,
+        new_pools=functools.partial(init_paged_cache, cfg),
+        prefill=gpt_prefill, step=gpt_decode_step,
+        prefill_attention=lambda cfg, rung: "dense", block=0, feed=greedy)
 
 
 def gpt_loss(params, batch: Dict[str, jax.Array], cfg: GPTConfig,

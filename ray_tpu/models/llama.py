@@ -10,6 +10,18 @@ runs dp/fsdp/tp/sp via the ``ray_tpu.parallel`` rule tables, per-layer
 ``jax.checkpoint`` with the same policy menu as GPT, and the same
 pluggable attention body (dense / Pallas flash).
 
+The layer is written ONCE (``_layer``: ``_attention`` and ``_ffn``, each a
+``_sublayer`` of the residual stream) and the training trunk,
+``llama_prefill``, ``llama_decode_step`` and ``llama_block_step`` all run it.
+They differ in what the attention does with its keys and values, and that
+alone is handed in, as an ``AttentionState``: ``_no_cache``,
+``_prefill_state``, ``_token_state``, ``_block_state``, for K/V pages and,
+where written, latent pages.  A cache of another kind (a window's ring, a
+recurrent row) is one more state, its pool's shape beside
+``llama_init_paged_cache`` and its two functions in
+``ops/paged_attention.py``: not the layer, and not the engine, which asks
+``served`` for this module's programs and knows none by name.
+
 The reference has no model zoo of its own (its flagship benchmarks wrap
 torchvision/HF models); this family exists so Train/Tune/Serve have a
 modern-architecture model to exercise, matching
@@ -18,9 +30,8 @@ modern-architecture model to exercise, matching
 With ``num_experts > 0`` the feed-forward is a mixture of SwiGLU experts
 routed top-k per token without capacity (``ops/moe.py``'s dropless path),
 and with ``qk_norm`` the q and k projections are RMS-normed over all heads
-before the rotation: together OLMoE's block.  Both are written once
-(``_ffn``, ``_qk``) and called from the training block, the paged prefill
-and the paged decode.  The expert model serves; it does not train here:
+before the rotation: together OLMoE's block (``_ffn``, ``_qk``).  The
+expert model serves; it does not train here:
 ``llama_loss`` refuses it, because the load-balancing loss, ``ep``
 sharding and the all-to-all are not written.
 
@@ -30,10 +41,9 @@ output after the model's final norm, and when served keeps a cache of its
 own, so the pools hold ``ut_steps * num_layers`` layers and pass ``t``,
 layer ``l`` reads and writes pool layer ``t * num_layers + l``.  With
 ``post_norm`` a sublayer's output is RMS-normed (``ln1_post``, ``ln2_post``)
-before it is added to the residual stream.  Both are written once
-(``_add_sublayer``, ``_passes``) and called from the training trunk, the
-paged prefill and the paged decode; with ``ut_steps == 1`` and no
-``post_norm`` neither adds an operation.  The looped model serves; it does
+before it is added to the residual stream (``_add_sublayer``,
+``_passes``); with ``ut_steps == 1`` and no ``post_norm`` neither adds an
+operation.  The looped model serves; it does
 not train here: ``llama_loss`` refuses it, because its published objective
 (an entropy-regularised expectation over exit steps) is not written, and
 neither are the exit gate's two leaves: at the published threshold of 1
@@ -63,7 +73,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,10 +137,6 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.head_size or self.embed_dim // self.num_heads
-
-    @staticmethod
-    def llama_125m() -> "LlamaConfig":
-        return LlamaConfig()
 
     @staticmethod
     def tiny(vocab: int = 256, seq: int = 128) -> "LlamaConfig":
@@ -719,47 +725,141 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
     return jnp.einsum("...m,md->...d", a, p["mlp"]["wd"].astype(dt)), None
 
 
-def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
-           attn_fn: Callable, cos, sin, experts, x, p):
-    lc = (lambda a, ax: with_logical_constraint(a, rules, ax)) if rules \
-        else (lambda a, ax: a)
-    dt = cfg.dtype
-    rep = 0 if cfg.kv_lora_rank else cfg.num_heads // cfg.num_kv_heads
+class AttentionState(NamedTuple):
+    """What a layer's attention does with its keys and values:
+    ``kv(p, layer, pools, q, k, v)`` for K/V pages, ``latent(p, layer, pools,
+    q_nope, q_rope, latent)`` for latent pages (None: not written for them).
+    Either writes, reads and returns ``(o, pools)``.  ``p`` is the layer's
+    parameters (a latent kind expands with its ``wkv_b``), ``layer`` its
+    index into ``pools``, and ``pools`` the kind's own: the trunk carries
+    them from layer to layer and never looks inside.  The projections come
+    in ``_attention``'s layouts."""
+    kv: Callable
+    latent: Optional[Callable] = None
 
-    def attention(h):
-        if cfg.kv_lora_rank:         # dense: the kernels want equal heads
-            o = _mla_expanded(cfg, p, *_mla_project(cfg, p, h, cos, sin))
-            return jnp.einsum("bsnh,nhd->bsd", o,
-                              p["attn"]["wo"].astype(dt)), None
-        # Head-major [B, N, S, H] throughout: native layout for the flash
-        # kernels, picked in the projection epilogue for free.
-        q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
-        kv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wkv"].astype(dt))
-        k, v = kv[:, 0], kv[:, 1]
-        q, k = _qk(cfg, p, q, k, cos, sin)
+
+def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
+    """The training trunk's: nothing is written, and whole sequences attend
+    to themselves by ``attn_fn`` (the flash kernels, or dense)."""
+    def kv(p, layer, pools, q, k, v):
+        rep = cfg.num_heads // cfg.num_kv_heads
         if rep > 1 and getattr(attn_fn, "_gqa_native", False):
             # Grouped dense path: fold the share-group dim into the einsum
             # — K/V stay at kv_heads width (no jnp.repeat materializing
             # rep copies of the KV tensors in HBM).
-            o = _checkpoint_name(
-                _dense_causal_attention_gqa(q, k, v, rep), "attn_out")
-        else:
-            if rep > 1:   # flash kernel expects equal head counts
-                k = jnp.repeat(k, rep, axis=1)
-                v = jnp.repeat(v, rep, axis=1)
-            q = lc(q, ("batch", "heads", "seq", "kv"))
-            k = lc(k, ("batch", "heads", "seq", "kv"))
-            v = lc(v, ("batch", "heads", "seq", "kv"))
-            o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
-        return jnp.einsum("bnsh,nhd->bsd", o,
-                          p["attn"]["wo"].astype(dt)), None
+            return _checkpoint_name(
+                _dense_causal_attention_gqa(q, k, v, rep), "attn_out"), pools
+        if rep > 1:   # flash kernel expects equal head counts
+            k = jnp.repeat(k, rep, axis=1)
+            v = jnp.repeat(v, rep, axis=1)
+        q = lc(q, ("batch", "heads", "seq", "kv"))
+        k = lc(k, ("batch", "heads", "seq", "kv"))
+        v = lc(v, ("batch", "heads", "seq", "kv"))
+        return _checkpoint_name(attn_fn(q, k, v), "attn_out"), pools
 
+    # a latent model's is dense: the kernels want equal heads
+    return AttentionState(kv, lambda p, layer, pools, *projected: (
+        _mla_expanded(cfg, p, *projected), pools))
+
+
+def _prefill_state(cfg: LlamaConfig, length, page_table,
+                   flash: bool) -> AttentionState:
+    """A sequence's prefill: its rows go to its pages (the padded tail to
+    scratch page 0) and it attends over what is in hand."""
+    def kv(p, layer, pools, q, k, v):
+        from ray_tpu.ops.flash_attention import flash_attention
+        from ray_tpu.ops.paged_attention import prefill_kv
+        pools = prefill_kv(*pools, layer, k[0], v[0], length, page_table[0])
+        if flash:    # the padded tail lies after every real position
+            return flash_attention(q, k, v, True, None, None, None, None,
+                                   "bnsh"), pools
+        return _dense_causal_attention_gqa(
+            q, k, v, cfg.num_heads // cfg.num_kv_heads,
+            cfg.block_length), pools
+
+    def latent(p, layer, pools, q_nope, q_rope, latent):
+        from ray_tpu.ops.paged_attention import prefill_latent
+        pool = prefill_latent(pools[0], layer, latent[0], length,
+                              page_table[0])
+        return _mla_expanded(cfg, p, q_nope, q_rope, latent), (pool, pools[1])
+    return AttentionState(kv, latent)
+
+
+def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
+    """One row a sequence: appended at ``pos``, and the pages read as far
+    as it; a latent pool as it lies (``_mla_absorbed``)."""
+    def kv(p, layer, pools, q, k, v):
+        from ray_tpu.ops.paged_attention import append_kv, paged_attention
+        pools = append_kv(*pools, layer, k, v, pos, page_table)
+        return paged_attention(q, *pools, layer, pos + 1, page_table), pools
+
+    def latent(p, layer, pools, q_nope, q_rope, latent):
+        from ray_tpu.ops.paged_attention import append_latent
+        pool = append_latent(pools[0], layer, latent, pos, page_table)
+        return _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer, pos + 1,
+                             page_table), (pool, pools[1])
+    return AttentionState(kv, latent)
+
+
+def _block_state(cfg: LlamaConfig, pos0, page_table) -> AttentionState:
+    """A block's rows: written at ``pos0 .. pos0 + B - 1`` at every pass,
+    and the pages read as far as the block's end, the block both ways."""
+    def kv(p, layer, pools, q, k, v):
+        from ray_tpu.ops.paged_attention import (append_block_kv,
+                                                 paged_block_attention)
+        pools = append_block_kv(*pools, layer, k, v, pos0, page_table)
+        return paged_block_attention(
+            q, *pools, layer, pos0 + cfg.block_length, page_table), pools
+    return AttentionState(kv)
+
+
+def _attention(cfg: LlamaConfig, p, h, cos, sin, state: AttentionState,
+               layer, pools):
+    """A layer's attention on the normed hidden ``h``: the projections,
+    ``_qk``, the ``state``'s write and read, the output projection.  An
+    ``h`` with a sequence axis, [B, S, D] (the training trunk, the prefill,
+    and the block step, whose rows a slot are that axis), goes head-major, q
+    [B, N, S, H] and k, v [B, NKV, S, H]: the flash kernels' native layout,
+    picked in the projection's epilogue for free.  The token step's [B, D]
+    gives [B, N, H].  Returns (the sublayer's output, the state's pools)."""
+    dt, a, rows = cfg.dtype, p["attn"], h.ndim == 3
+    if cfg.kv_lora_rank:
+        o, pools = state.latent(p, layer, pools,
+                                *_mla_project(cfg, p, h, cos, sin))
+        return jnp.einsum("bsnh,nhd->bsd" if rows else "bnh,nhd->bd", o,
+                          a["wo"].astype(dt)), pools
+    q = jnp.einsum("bsd,dnh->bnsh" if rows else "bd,dnh->bnh", h,
+                   a["wq"].astype(dt))
+    kv = jnp.einsum("bsd,dcnh->bcnsh" if rows else "bd,dcnh->bcnh", h,
+                    a["wkv"].astype(dt))
+    k, v = kv[:, 0], kv[:, 1]
+    q, k = _qk(cfg, p, q, k, cos, sin)
+    o, pools = state.kv(p, layer, pools, q, k, v)
+    return jnp.einsum("bnsh,nhd->bsd" if rows else "bnh,nhd->bd", o,
+                      a["wo"].astype(dt)), pools
+
+
+def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
+           pools, live=None, lc=lambda a, ax: a, experts=None):
+    """One layer, the same for every program: attention against ``state``
+    and the feed-forward, each a ``_sublayer`` of the residual stream ``x``.
+    Returns (x, the state's pools, the experts' load)."""
     stream = _stream_axes(cfg)
-    x, _ = _sublayer(cfg, p, 0, x, attention)
+    x, pools = _sublayer(cfg, p, 0, x, lambda h: _attention(
+        cfg, p, h, cos, sin, state, layer, pools))
     x = lc(x, stream)
-    x, _ = _sublayer(cfg, p, 1, x, lambda h: (
-        _ffn(cfg, p, h, lc=lc, experts=experts)[0], None))
-    return lc(x, stream)
+    x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
+        cfg, p, h, live, lc, experts))
+    return lc(x, stream), pools, load
+
+
+def _block(cfg: LlamaConfig, rules: Optional[LogicalAxisRules],
+           attn_fn: Callable, cos, sin, experts, x, p):
+    """The training trunk's layer: ``_layer`` with nothing cached."""
+    lc = (lambda a, ax: with_logical_constraint(a, rules, ax)) if rules \
+        else (lambda a, ax: a)
+    return _layer(cfg, p, x, cos, sin, _no_cache(cfg, attn_fn, lc), None,
+                  None, lc=lc, experts=experts)[0]
 
 
 def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
@@ -821,15 +921,14 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 
 # ---------------------------------------------------------------------------
 # Paged KV-cache decode (serving path) — LLaMA variant of gpt.py's
-# init_paged_cache/gpt_prefill/gpt_decode_step.  GQA keeps the pools at
-# kv_heads width (not heads), rope is applied at each token's
-# absolute position before the K is scattered (the pools hold POST-rope
-# keys, so decode attention is a plain dot against the cache), and the
-# math mirrors _block's grouped dense branch exactly — with
-# cfg.dtype=float32 paged greedy decode reproduces llama_forward's
-# token-by-token argmax, which the CPU equivalence tests assert.  A latent
-# (MLA) model has ONE pool, of latent pages, and ``None`` where the others
-# have their V pool: the steps take and return the pair either way.
+# init_paged_cache/gpt_prefill/gpt_decode_step: the training trunk's layer
+# against a state that keeps pages.  GQA keeps the pools at kv_heads width,
+# and rope is applied at each token's absolute position before the K is
+# scattered (the pools hold POST-rope keys) — with cfg.dtype=float32 paged
+# greedy decode reproduces llama_forward's token-by-token argmax, which the
+# CPU equivalence tests assert.  A latent (MLA) model has ONE pool, of latent
+# pages, and ``None`` where the others have their V pool: the steps take and
+# return the pair either way.
 
 
 def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
@@ -913,6 +1012,21 @@ def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
     return "flash"
 
 
+def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
+                  state: AttentionState, live, *pools):
+    """Every pass over every layer of a served program, ``pools`` carried
+    through both loops and written in place: ((x after the final norm,
+    *pools), the expert layers' load of the rows ``live`` marks)."""
+    def body(experts, carry, inp):
+        (x, *kept), (p, layer) = carry, inp
+        x, kept, load = _layer(cfg, p, x, cos, sin, state, layer, kept,
+                               live, experts=experts)
+        return (x, *kept), load
+
+    return _passes(cfg, params, lambda carry, t: _scan_layers(
+        cfg, params, body, carry, t, served=True), (x, *pools))
+
+
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
                   k_pages: jax.Array, v_pages: Optional[jax.Array],
@@ -932,60 +1046,23 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     and the logits are empty, ``[1, 0]``.  An expert model returns a
     fourth result, ``load`` [expert layers, E] int32: per layer and expert,
     the assignments of the prompt's real positions."""
-    from ray_tpu.ops.flash_attention import flash_attention
-    from ray_tpu.ops.paged_attention import prefill_kv, prefill_latent
-    dt = cfg.dtype
-    rep = 0 if cfg.kv_lora_rank else cfg.num_heads // cfg.num_kv_heads
     S = tokens.shape[1]
     flash = llama_prefill_attention(cfg, S) == "flash"
     cos, sin = _rope_tables(cfg, S)
     x = _embed(cfg, params, tokens)
     live = (jnp.arange(S) < length)[None]                # the real positions
-
-    def body(experts, carry, inp):
-        (x, kp, vp), (p, layer) = carry, inp
-
-        def attention(h):
-            if cfg.kv_lora_rank:
-                q_nope, q_rope, latent = _mla_project(cfg, p, h, cos, sin)
-                pools = prefill_latent(kp, layer, latent[0], length,
-                                       page_table[0]), vp
-                o = _mla_expanded(cfg, p, q_nope, q_rope, latent)
-                return jnp.einsum("bsnh,nhd->bsd", o,
-                                  p["attn"]["wo"].astype(dt)), pools
-            q = jnp.einsum("bsd,dnh->bnsh", h, p["attn"]["wq"].astype(dt))
-            kv = jnp.einsum("bsd,dcnh->bcnsh", h,
-                            p["attn"]["wkv"].astype(dt))
-            k, v = kv[:, 0], kv[:, 1]                    # [B, NKV, S, H]
-            q, k = _qk(cfg, p, q, k, cos, sin)
-            pools = prefill_kv(kp, vp, layer, k[0], v[0], length,
-                               page_table[0])
-            if flash:    # the padded tail lies after every real position
-                o = flash_attention(q, k, v, True, None, None, None, None,
-                                    "bnsh")
-            else:
-                o = _dense_causal_attention_gqa(q, k, v, rep,
-                                                cfg.block_length)
-            return jnp.einsum("bnsh,nhd->bsd", o,
-                              p["attn"]["wo"].astype(dt)), pools
-
-        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
-        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
-            cfg, p, h, live, experts=experts))
-        return (x, kp, vp), load
-
-    (x, k_pages, v_pages), load = _passes(
-        cfg, params, lambda carry, t: _scan_layers(
-            cfg, params, body, carry, t, served=True),
-        (x, k_pages, v_pages))
+    (x, k_pages, v_pages), load = _served_trunk(
+        cfg, params, x, cos, sin,
+        _prefill_state(cfg, length, page_table, flash), live, k_pages,
+        v_pages)
     if cfg.block_length:
         # no logits: a block model's first token comes from its first
         # block (llama_block_step), not from the prompt's last position
         return _paged_results(jnp.zeros((1, 0), jnp.float32), k_pages,
                               v_pages, load)
     last = x[0, length - 1]                              # [D]
-    logits = jnp.einsum("d,dv->v", last,
-                        params["lm_head"].astype(dt)).astype(jnp.float32)
+    logits = jnp.einsum("d,dv->v", last, params["lm_head"].astype(
+        cfg.dtype)).astype(jnp.float32)
     return _paged_results(logits[None], k_pages, v_pages, load)
 
 
@@ -1003,49 +1080,17 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     [expert layers, E] int32: per layer and expert, the assignments of the
     live slots (``pos > 0``: a sequence that decodes has a prompt behind
     it)."""
-    from ray_tpu.ops.paged_attention import (append_kv, append_latent,
-                                             paged_attention)
-    dt = cfg.dtype
     cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
     if cfg.kv_lora_rank:             # one rotated key for all heads
         cos, sin = cos_t[pos], sin_t[pos]                # [B, dr/2]
     else:
         cos, sin = cos_t[pos][:, None], sin_t[pos][:, None]  # [B, 1, H/2]
     x = _embed(cfg, params, token)
-    live = pos > 0
-
-    def body(experts, carry, inp):
-        (x, kp, vp), (p, layer) = carry, inp
-
-        def attention(h):
-            if cfg.kv_lora_rank:
-                q_nope, q_rope, latent = _mla_project(cfg, p, h, cos, sin)
-                pool = append_latent(kp, layer, latent, pos, page_table)
-                o = _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer,
-                                  pos + 1, page_table)
-                return jnp.einsum("bnh,nhd->bd", o,
-                                  p["attn"]["wo"].astype(dt)), (pool, vp)
-            q = jnp.einsum("bd,dnh->bnh", h, p["attn"]["wq"].astype(dt))
-            kv = jnp.einsum("bd,dcnh->bcnh", h,
-                            p["attn"]["wkv"].astype(dt))
-            k_new, v_new = kv[:, 0], kv[:, 1]            # [B, NKV, H]
-            q, k_new = _qk(cfg, p, q, k_new, cos, sin)
-            pools = append_kv(kp, vp, layer, k_new, v_new, pos, page_table)
-            o = paged_attention(q, *pools, layer, pos + 1, page_table)
-            return jnp.einsum("bnh,nhd->bd", o,
-                              p["attn"]["wo"].astype(dt)), pools
-
-        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
-        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
-            cfg, p, h, live, experts=experts))
-        return (x, kp, vp), load
-
-    (x, k_pages, v_pages), load = _passes(
-        cfg, params, lambda carry, t: _scan_layers(
-            cfg, params, body, carry, t, served=True),
-        (x, k_pages, v_pages))
-    logits = jnp.einsum("bd,dv->bv", x,
-                        params["lm_head"].astype(dt)).astype(jnp.float32)
+    (x, k_pages, v_pages), load = _served_trunk(
+        cfg, params, x, cos, sin, _token_state(cfg, pos, page_table),
+        pos > 0, k_pages, v_pages)
+    logits = jnp.einsum("bd,dv->bv", x, params["lm_head"].astype(
+        cfg.dtype)).astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
 
 
@@ -1071,47 +1116,19 @@ def llama_block_step(params: Dict[str, Any], cfg: LlamaConfig,
     above them.  Returns ``(logits [S, B, V] float32, k_pages, v_pages)``
     and, from an expert model, ``load``; ``logits[s, i]`` predicts the token
     AT ``pos0 + i`` (mask-predict, no shift)."""
-    from ray_tpu.ops.paged_attention import (append_block_kv,
-                                             paged_block_attention)
-    dt = cfg.dtype
-    B = cfg.block_length
     tokens, _, pos0, _ = state
     live = pos0 < end
     pos0 = jnp.where(live, pos0, 0)
-    at = pos0[:, None] + jnp.arange(B)                   # [S, B]
+    at = pos0[:, None] + jnp.arange(cfg.block_length)    # [S, B]
     cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
     cos, sin = cos_t[at][:, None], sin_t[at][:, None]    # [S, 1, B, H/2]
     x = _embed(cfg, params, tokens)                      # [S, B, D]
-    rows = jnp.broadcast_to(live[:, None], tokens.shape)
-
-    def body(experts, carry, inp):
-        (x, kp, vp), (p, layer) = carry, inp
-
-        def attention(h):
-            q = jnp.einsum("sbd,dnh->snbh", h, p["attn"]["wq"].astype(dt))
-            kv = jnp.einsum("sbd,dcnh->scnbh", h,
-                            p["attn"]["wkv"].astype(dt))
-            k_new, v_new = kv[:, 0], kv[:, 1]            # [S, NKV, B, H]
-            q, k_new = _qk(cfg, p, q, k_new, cos, sin)
-            pools = append_block_kv(kp, vp, layer, k_new, v_new, pos0,
-                                    page_table)
-            o = paged_block_attention(q, *pools, layer, pos0 + B,
-                                      page_table)
-            return jnp.einsum("snbh,nhd->sbd", o,
-                              p["attn"]["wo"].astype(dt)), pools
-
-        x, (kp, vp) = _sublayer(cfg, p, 0, x, attention)
-        x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
-            cfg, p, h, rows, experts=experts))
-        return (x, kp, vp), load
-
-    (x, k_pages, v_pages), load = _passes(
-        cfg, params, lambda carry, t: _scan_layers(
-            cfg, params, body, carry, t, served=True),
-        (x, k_pages, v_pages))
+    (x, k_pages, v_pages), load = _served_trunk(
+        cfg, params, x, cos, sin, _block_state(cfg, pos0, page_table),
+        jnp.broadcast_to(live[:, None], tokens.shape), k_pages, v_pages)
     with jax.named_scope("lm_head"):
         logits = jnp.einsum("sbd,dv->sbv", x, params["lm_head"].astype(
-            dt)).astype(jnp.float32)
+            cfg.dtype)).astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
 
 
@@ -1167,6 +1184,23 @@ def block_unmask(cfg: LlamaConfig, logits: jax.Array, state,
                 "emitted": tokens, "passes": passes,
                 "by_threshold": jnp.where(dynamic, done, 0),
                 "by_count": jnp.where(dynamic, 0, done)}
+
+
+def served(config: Optional[LlamaConfig] = None, seq: int = 0):
+    """The serving engine's record of this model (``models/serving.py``):
+    a block model's step is a block's pass, and what it feeds the next is
+    ``block_unmask``'s state, the [slots, B, V] logits no result."""
+    from ray_tpu.models.serving import ServedModel, greedy
+    cfg = config or LlamaConfig.tiny(seq=seq)
+    return ServedModel(
+        config=cfg, init=llama_init, stored=llama_serving_params,
+        new_pools=functools.partial(llama_init_paged_cache, cfg),
+        prefill=llama_prefill,
+        step=llama_block_step if cfg.block_length else llama_decode_step,
+        prefill_attention=llama_prefill_attention, block=cfg.block_length,
+        feed=(lambda cfg, logits, state, end: (
+            None, block_unmask(cfg, logits, state, end)))
+        if cfg.block_length else greedy)
 
 
 def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,

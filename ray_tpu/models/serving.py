@@ -1,0 +1,55 @@
+"""What the serving engine asks a model for: one record a model.
+
+``serve/engine/engine.py`` drives a paged cache and two programs and knows
+no model's entry points by name: ``serving_model(EngineConfig.model,
+model_config, seq)`` hands it a ``ServedModel``, which each model module
+builds from the functions it has (``gpt.served``, ``llama.served``).  The
+functions are looked up when the record is asked for, not when ``models/``
+is imported: one replaced on its module before an engine is constructed is
+the one that engine traces.
+"""
+
+from __future__ import annotations
+
+import importlib
+from typing import Any, Callable, NamedTuple
+
+import jax.numpy as jnp
+
+_MODULES = {"gpt": "ray_tpu.models.gpt", "llama": "ray_tpu.models.llama"}
+
+
+class ServedModel(NamedTuple):
+    config: Any                  # the model's (the module's tiny one if None)
+    init: Callable               # (rng, config) -> parameters
+    stored: Callable             # (parameters, config) -> the tree the two
+    #                              programs read, each leaf in its dtype
+    new_pools: Callable          # (num_pages, page_size, dtype) -> (k_pages,
+    #                              v_pages), zeroed; v_pages None: one pool
+    prefill: Callable            # (params, config, tokens [1, S], length,
+    #                              k_pages, v_pages, page_table) -> (logits,
+    #                              k_pages, v_pages[, the experts' load])
+    step: Callable               # the decode step, (params, config, token,
+    #                              pos, ...) likewise; a block model's takes
+    #                              the blocks' state and ends for token, pos
+    prefill_attention: Callable  # (config, rung) -> "flash" | "dense"
+    block: int                   # positions a step yields a sequence; 0:
+    #                              one, by one token
+    feed: Callable               # (config, logits, token, pos) -> (the
+    #                              logits the loop's step returns, or None;
+    #                              what the next step takes for ``token``)
+
+
+def greedy(config, logits, token, pos):
+    """A one-token step's ``feed``: every slot's next token is the argmax
+    of its logits, chosen where they are."""
+    return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def serving_model(model: str, config: Any = None,
+                  seq: int = 0) -> ServedModel:
+    """The record of ``model`` ("gpt" | "llama") for ``config``, or for the
+    module's tiny configuration of ``seq`` positions."""
+    if model not in _MODULES:
+        raise ValueError(f"unknown engine model '{model}'")
+    return importlib.import_module(_MODULES[model]).served(config, seq)

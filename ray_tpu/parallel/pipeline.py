@@ -51,68 +51,6 @@ def _stage_machinery(axis_name: str):
     return pp, idx, shift
 
 
-def gpipe_spmd(block_fn: Callable, local_params, x_mbs, *,
-               axis_name: str = "pp", aux_axes=None, remat: bool = True):
-    """Per-device GPipe loop (call inside ``shard_map`` over ``axis_name``).
-
-    block_fn:      (x, layer_params) -> (x, aux scalar), one block.
-    local_params:  this stage's stacked params, leading dim [L/pp].
-    x_mbs:         [M, mb, ...] microbatched activations (valid on stage 0;
-                   other stages' values are ignored).
-    aux_axes:      mesh axes the aux sum reduces over (defaults to just
-                   ``axis_name``; pass the data axes too when the batch is
-                   sharded, or each shard only reports its own aux).
-    Returns ([M, mb, ...] outputs, aux_sum) — outputs replicated across the
-    pp axis, aux summed over every REAL (stage, microbatch) pass (fill and
-    drain ticks processing garbage state are masked out).
-    """
-    pp, idx, shift = _stage_machinery(axis_name)
-    M = x_mbs.shape[0]
-    T = M + pp - 1
-
-    body = jax.checkpoint(block_fn) if remat else block_fn
-
-    def apply_stage(x):
-        def scan_body(c, lp):
-            y, aux = body(c, lp)
-            return y, aux
-        y, auxs = jax.lax.scan(scan_body, x, local_params)
-        return y, jnp.sum(auxs)
-
-    def tick(carry, t):
-        state, out, aux_acc = carry
-        # Fill: stage 0 ingests microbatch t (clamped once the pipe drains).
-        inp = jax.lax.dynamic_index_in_dim(
-            x_mbs, jnp.clip(t, 0, M - 1), 0, keepdims=False)
-        state = jnp.where(idx == 0, inp, state)
-        y, aux = apply_stage(state)
-        # This stage is processing microbatch t - idx; only count its aux
-        # when that's a real microbatch (not fill/drain garbage).
-        m_here = t - idx
-        aux_acc = aux_acc + jnp.where(
-            (m_here >= 0) & (m_here < M), aux, 0.0)
-        # Drain: the last stage emits microbatch t-(pp-1) once it's real.
-        m = t - (pp - 1)
-        write = (idx == pp - 1) & (m >= 0)
-        out = jnp.where(
-            write,
-            jax.lax.dynamic_update_index_in_dim(
-                out, y, jnp.clip(m, 0, M - 1), 0),
-            out)
-        state = jax.lax.ppermute(y, axis_name, shift)
-        return (state, out, aux_acc), None
-
-    init = (jnp.zeros_like(x_mbs[0]), jnp.zeros_like(x_mbs),
-            jnp.zeros((), jnp.float32))
-    (_, out, aux_acc), _ = jax.lax.scan(tick, init, jnp.arange(T))
-    # Non-final stages never wrote, so their buffers are zero: a psum both
-    # combines and replicates the result across the pp ring in one
-    # collective.  (Training avoids this full-buffer epilogue entirely —
-    # see gpipe_fused_loss_spmd.)
-    return (jax.lax.psum(out, axis_name),
-            jax.lax.psum(aux_acc, aux_axes or (axis_name,)))
-
-
 def gpipe_fused_loss_spmd(block_fn: Callable, loss_mb_fn: Callable,
                           local_params, head_params, x_mbs, tgt_mbs, *,
                           axis_name: str = "pp", all_axes, repl_factor: float,
@@ -183,8 +121,8 @@ def one_f_one_b_spmd(block_fn: Callable, loss_mb_fn: Callable,
     """1F1B pipeline schedule with the backward pass written OUT, not
     autodiffed: activation memory O(pp), not O(M).
 
-    GPipe-via-autodiff (``gpipe_spmd``) must keep every tick's carry alive
-    for the reverse sweep — O(M + pp) stage inputs per device.  Here each
+    GPipe-via-autodiff (``gpipe_fused_loss_spmd``) must keep every tick's
+    carry alive for the reverse sweep — O(M + pp) stage inputs per device.  Here each
     tick runs one forward AND one backward block application per stage
     (masked during fill/drain), with microbatch m's backward at stage i
     scheduled ``2(pp-1-i)`` ticks after its forward — so at most
@@ -395,50 +333,6 @@ def _check_pipeline_shapes(cfg, mesh, B, M):
             f"num_experts {cfg.num_experts} not divisible by "
             f"ep={mesh.shape['ep']}")
     return dsize
-
-
-def gpt_forward_pipelined(params: Dict[str, Any], tokens, cfg, mesh, *,
-                          num_microbatches: int):
-    """GPT forward (logits) with the block stack pipelined over ``pp``.
-
-    Embedding and LM head run outside the pipeline (replicated over pp).
-    Supports dense/flash attention and MoE stages; returns
-    (logits, aux_sum).  Training should use gpt_loss_pipelined, whose
-    fused epilogue avoids this function's full-output psum.
-    """
-    from ray_tpu.models.gpt import _block, _layer_norm
-
-    B, S = tokens.shape
-    M = num_microbatches
-    _check_pipeline_shapes(cfg, mesh, B, M)
-    dt = cfg.dtype
-
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[:S][None]
-    x_mbs = x.reshape(M, B // M, S, -1)
-
-    use_ep = cfg.num_experts and mesh.shape.get("ep", 1) > 1
-    block = functools.partial(_block, cfg, None, _attn_fn_for(cfg, S, mesh),
-                              moe_ep_axis="ep" if use_ep else None)
-    data = tuple(a for a in ("dp", "fsdp") if a in mesh.shape)
-    use_sp = cfg.attention == "ring" and mesh.shape.get("sp", 1) > 1
-    seq_axes = ("sp",) if use_sp else ()
-    spsize = mesh.shape.get("sp", 1) if use_sp else 1
-    mb_spec = P(None, data, "sp" if use_sp else None, None)
-    dsize = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
-    piped = jax.shard_map(
-        functools.partial(gpipe_spmd, block, remat=cfg.remat,
-                          aux_axes=("pp",) + data + seq_axes),
-        mesh=mesh, in_specs=(_layer_in_specs(cfg, mesh), mb_spec),
-        out_specs=(mb_spec, P()), check_vma=False)
-    y, aux = piped(params["layers"], x_mbs)
-
-    y = y.reshape(B, S, -1)
-    y = _layer_norm(y, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    logits = jnp.einsum("bsd,vd->bsv", y, params["wte"].astype(dt))
-    # Normalize the (stage, microbatch, shard)-summed aux to the same
-    # scale as gpt_forward_with_aux: sum over layers of full-batch means
-    # (seq shards contribute one local mean each under sp).
-    return logits.astype(jnp.float32), aux / (M * dsize * spsize)
 
 
 def gpt_loss_pipelined(params, batch, cfg, mesh, *, num_microbatches: int):

@@ -80,90 +80,84 @@ nothing configures any of this.  A failure surfaces at a dispatch or at the
 fetch of the step in flight: every live and waiting caller gets it once, the
 step in flight is dropped (its slots counted stray).
 
-A model that generates by **diffusion over blocks** (a ``LlamaConfig`` with
-``block_length`` B) comes through the same loop, and its decode step is
-another program under the same name: ``llama_block_step`` runs B positions a
-sequence (the block as it stands, masks where nothing is chosen yet) and
-``block_unmask`` chooses on the device which masks the pass lifts, so **a
-step yields 0 or up to B tokens a sequence**: none while its block has
-masks left (a denoise pass), the whole block when the step was handed it
-without any (the commit pass).  What feeds back on the device from step N
-to N+1 is the blocks' state (``tokens [max_batch, B]``, the boolean
-``masked`` beside them, never a comparison with the mask token's id,
-``pos0``, the block's passes so far) where the one-token step has ``token``;
-the host sends the page table and each slot's ``end`` (the position its last
-block ends at; 0 parks the slot on page 0).  **Every pass writes its block's
-K/V into the sequence's OWN reserved positions** ``pos0 .. pos0 + B - 1``
-and reads every position under ``pos0 + B``: a later pass overwrites an
-earlier one's rows, "a sequence writes a position before any step reads it"
-holds, and a commit is just the pass after which ``pos0`` advances, so one
-program serves a batch in which some slots denoise and some commit.  The host
-fetches step N's ``(committed, emitted, state)`` while N+1 runs and keeps
-each sequence's block as of the last step it fetched (``_Sequence.block``,
-``.masked``, ``.pos``, ``.passes``): **it learns a step late which masks a
-pass lifted**, but a block without masks is committed by the pass it is
-handed to, so the host knows every ``pos0`` of the step it dispatches (the
-table's width, ``live_tokens``) and that a sequence's last block is being
-committed by the step in flight (no step is dispatched behind it).  A
-committed block reaches its caller as tokens of its own, in order: those at
-the positions the request asked for (the first block begins with the
+What is a model's is asked of ``models/`` and named nowhere here:
+``models/serving.py::serving_model`` hands the constructor ONE record
+(``ServedModel``) for ``EngineConfig.model`` and ``model_config``, and the
+loop drives whatever it holds.  What a latent model's pages, an expert
+model's routing and a block model's pass ARE is ``models/llama.py``'s to
+say; below is what the loop does about each.
+
+A model whose ``step`` is a **block's pass** (the record's ``block`` = B > 0;
+``llama_block_step``, and ``block_unmask`` as its ``feed``) comes through the
+same loop, its decode program under the same name, and **a step yields 0 or up
+to B tokens a sequence**: none while its block has masks left (a denoise
+pass), the whole block when the step was handed it without any (the commit
+pass).  What feeds back on the device from step N to N+1 is the blocks' state
+(``tokens [max_batch, B]``, the boolean ``masked``, ``pos0``, the block's
+passes so far) where the one-token step has ``token``; the host sends the page
+table and each slot's ``end`` (the position its last block ends at; 0 parks
+the slot on page 0).  Every pass writes its block's K/V into the sequence's
+OWN reserved positions, so "a sequence writes a position before any step reads
+it" holds and one program serves a batch in which some slots denoise and some
+commit.  The host fetches step N's ``(committed, emitted, state)`` while N+1
+runs and keeps each sequence's block as of the last step it fetched
+(``_Sequence.block``, ``.masked``, ``.pos``, ``.passes``): **it learns a step
+late which masks a pass lifted**, but a block without masks is committed by
+the pass it is handed to, so the host knows every ``pos0`` of the step it
+dispatches (the table's width, ``live_tokens``) and that a sequence's last
+block is being committed by the step in flight (no step is dispatched behind
+it).  A committed block reaches its caller as tokens of its own, in order:
+those at the positions the request asked for (the first block begins with the
 prompt's ``len % B`` trailing tokens, which the prefill leaves out; the last
 may end past ``max_new``: the dropped tail), as far as an ``eos_token``.  An
 admission drains the pipe as it always did, and the step after a drain takes
 the host's copy of the state.  ``stats()["block"]`` counts the slot steps by
-kind, the blocks by the passes they took and the tokens committed, dropped
-and unmasked by either rule; ``rt:engine.decode.dispatch`` carries
-``block_len`` beside ``live_tokens`` (positions held: what is committed and
-the block), and the ``rt:engine.deliver`` of a fetched step ``tokens``,
-``dropped_tail``, ``dropped_stray``, ``denoise_slots`` and ``commit_slots``:
-on the delivery and not the dispatch, because which slots commit is known
-when the step is fetched.  The page size is a multiple of B, so a block lies
-in one page.
+kind, the blocks by the passes they took and the tokens committed, dropped and
+unmasked by either rule; ``rt:engine.decode.dispatch`` carries ``block_len``
+beside ``live_tokens`` (positions held: what is committed and the block), and
+the ``rt:engine.deliver`` of a fetched step ``tokens``, ``dropped_tail``,
+``dropped_stray``, ``denoise_slots`` and ``commit_slots``: on the delivery and
+not the dispatch, because which slots commit is known when the step is
+fetched.  The page size is a multiple of B, so a block lies in one page.
 
 The parameters are stored once in the dtype the two programs read them in
-(``gpt_serving_params`` / ``llama_serving_params``, beside the steps whose
-``.astype(cfg.dtype)`` they repeat): embedding tables, head, attention
-projections and a dense feed-forward in ``model_config.dtype``, so no step
-casts a weight again; norm scales, an expert model's router and its stacked
-experts in the f32 they arrive in, because the steps compute those in f32
-and read only the experts a step touches, where they lie.  The same bits
-come out as from the caller's tree.  The engine keeps no reference to that
-tree: once the caller drops it, a bf16 engine holds half the bytes
-(``stats()["weight_bytes"]``).
+(the record's ``stored``: ``gpt_serving_params`` / ``llama_serving_params``,
+which say leaf by leaf what is cast and what stays f32), so no step casts a
+weight again and the same bits come out as from the caller's tree.  The
+engine keeps no reference to that tree: once the caller drops it, a bf16
+engine holds half the bytes (``stats()["weight_bytes"]``).
 
 The KV pools are updated in place: both programs carry them through their
 layer scan (``models/``: a step scatters one token a sequence into the whole
 pool and gathers from it) and the loop's two calls donate them, so a call's
 result pools are its argument's buffers and no copy of a pool, whole or a
-layer's, is made or held.  Between a donating dispatch and the loop taking
-the result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single
-exec lane may touch the pools.  Callers outside the loop, while it is idle,
-have two kinds of view of the same steps (the decode views' program is the
-loop's less its last result, the chosen tokens: ``_decode_donating``, the
-same function under ``jax.jit``, beside the loop's ``_decode_next_donating``).
-``_prefill_program`` /
-``_decode_program`` hand them a copy of the pools they are given and never
-consume their arguments (a test, a tool that wants both).  The three-result
-``_prefill`` / ``_decode`` copy nothing, because a pool may be the largest
-thing on the chip (a looped model's is ``ut_steps`` times a plain one's) and
-then no second one fits: they CONSUME the pools they are given, and where
-those are the engine's own the engine keeps the result as its pools, so the
-names the caller gets back alias ``_k_pages`` / ``_v_pages`` and can go
-straight into the next such call.  A readiness check made of them holds one
-pool.  What it leaves in the pages it used is never read: a sequence writes
-a position before any step reads it.  A call that fails after it was
+layer's, is made or held.  Between a donating dispatch and the loop taking the
+result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single exec
+lane may touch the pools.  Callers outside the loop, while it is idle, have
+two kinds of view of the same steps (the decode views' program is the loop's
+less its last result, the chosen tokens: ``_decode_donating``, the same
+function under ``jax.jit``, beside the loop's ``_decode_next_donating``).
+``_prefill_program`` / ``_decode_program`` hand them a copy of the pools they
+are given and never consume their arguments (a test, a tool that wants both).
+The three-result ``_prefill`` / ``_decode`` copy nothing, because a pool may
+be the largest thing on the chip (a looped model's is ``ut_steps`` times a
+plain one's) and then no second one fits: they CONSUME the pools they are
+given, and where those are the engine's own the engine keeps the result as its
+pools, so the names the caller gets back alias ``_k_pages`` / ``_v_pages`` and
+can go straight into the next such call.  A readiness check made of them holds
+one pool.  What it leaves in the pages it used is never read: a sequence
+writes a position before any step reads it.  A call that fails after it was
 given the pools costs the live sequences an error, and the loop makes fresh
-pools (no live sequence is left to own a page).  ``stats()`` says whether
-each program's first loop call did come back in its argument's buffers
+pools (no live sequence is left to own a page).  ``stats()`` says whether each
+program's first loop call did come back in its argument's buffers
 (``kv_pool_in_place``).
 
-A model with latent attention (a ``LlamaConfig`` with ``kv_lora_rank``)
-caches one row a position a layer, so its pages are of another kind: ONE
-pool ``[L, P, page * (kv_lora_rank + qk_rope_dim)]``, and ``_v_pages`` is
-None.  The kind follows from the model (``llama_init_paged_cache``), nothing
-configures it; the programs take and return the pair of pools either way, so
-the loop, the donation, the views and the counters below are the same code
-for both kinds.  ``stats()["kv_page_kind"]`` says which.
+A model's pages may be of another kind (a latent model's: ONE pool, and
+``_v_pages`` is None).  The kind follows from the model (the record's
+``new_pools``), nothing configures it; the programs take and return the pair
+of pools either way, so the loop, the donation, the views and the counters
+below are the same code for both kinds.  ``stats()["kv_page_kind"]`` says
+which.
 
 ``paged_attention`` (a latent model's ``paged_latent_attention`` alike)
 gathers every page of the table it is given, for every slot, once per pool
@@ -179,46 +173,43 @@ as ``live_tokens``, ``gathered_tokens`` and ``width_pages``.
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
-profile beside the device's programs (``LLMServer.profile``); the two
-thread crossings of a call ride as the ``submit_us`` and ``resume_us``
-attributes of the region that follows them.  A call of the exec lane is a
-decode step's dispatch and, behind it, the fetch of the step before
-(``ahead`` 1 on the ``.decode.dispatch``), a dispatch alone on a drained pipe
-(``ahead`` 0), a drain's fetch alone, or a prefill; each ends in a
-``.deliver``, one that fetched nothing with no tokens.  A call's six boundaries
-(submitted on the loop thread; dispatch start, dispatch end and returned on
-the exec lane; ``_deliver``'s entry and exit on the loop thread again) are
-each read on up to three clocks (``_Clocks``: wall, the reading thread's
-CPU, the loop thread's CPU), and that one set of reads feeds both the
-regions' attributes (``tracing``'s module docstring has the convention:
-``dispatch_us`` / ``dispatch_cpu_us`` / ``dispatch_loop_cpu_us`` on
-``.decode.fetch``: the dispatch phases that ended on the lane since the fetch
-before, step N+1's beside step N's fetch, 0 on a drain's;
-``fetch_loop_cpu_us`` and ``resume_loop_cpu_us`` on
-``.deliver``, ``step_us`` / ``step_loop_cpu_us`` on ``.decode.dispatch``)
-and the always-on sums ``stats()["host_s"]`` / ``["host_cpu_s"]``: wall less
-the exec lane's own CPU is what it spent not running (the GIL, a lock of
-the runtime), and the loop thread's CPU in the same interval says whether
-the loop ran against it.  The wall is read at every boundary of every call;
-the CPU clocks between a call's submission and its delivery are system
-calls, read for every call while a profiler session records and for one
-call in ``_CPU_EVERY`` otherwise.  The collector's passes are ``rt:gc``
-regions and
-``stats()["gc"]`` (``tracing.watch_gc``).  ``stats()`` carries the
-always-on counters of the same places, ``decode_ahead_steps`` and
-``stray_slot_steps`` among them.
+profile beside the device's programs (``LLMServer.profile``); the two thread
+crossings of a call ride as the ``submit_us`` and ``resume_us`` attributes of
+the region that follows them.  A call of the exec lane is a decode step's
+dispatch and, behind it, the fetch of the step before (``ahead`` 1 on the
+``.decode.dispatch``), a dispatch alone on a drained pipe (``ahead`` 0), a
+drain's fetch alone, or a prefill; each ends in a ``.deliver``, one that
+fetched nothing with no tokens.  A call's six boundaries (submitted on the
+loop thread; dispatch start, dispatch end and returned on the exec lane;
+``_deliver``'s entry and exit on the loop thread again) are each read on up to
+three clocks (``_Clocks``: wall, the reading thread's CPU, the loop thread's
+CPU), and that one set of reads feeds both the regions' attributes
+(``tracing``'s module docstring has the convention: ``dispatch_us`` /
+``dispatch_cpu_us`` / ``dispatch_loop_cpu_us`` on ``.decode.fetch``: the
+dispatch phases that ended on the lane since the fetch before, step N+1's
+beside step N's fetch, 0 on a drain's; ``fetch_loop_cpu_us`` and
+``resume_loop_cpu_us`` on ``.deliver``, ``step_us`` / ``step_loop_cpu_us`` on
+``.decode.dispatch``) and the always-on sums ``stats()["host_s"]`` /
+``["host_cpu_s"]``: wall less the exec lane's own CPU is what it spent not
+running (the GIL, a lock of the runtime), and the loop thread's CPU in the
+same interval says whether the loop ran against it.  The wall is read at every
+boundary of every call; the CPU clocks between a call's submission and its
+delivery are system calls, read for every call while a profiler session
+records and for one call in ``_CPU_EVERY`` otherwise.  The collector's passes
+are ``rt:gc`` regions and ``stats()["gc"]`` (``tracing.watch_gc``).
+``stats()`` carries the always-on counters of the same places,
+``decode_ahead_steps`` and ``stray_slot_steps`` among them.
 
 A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
 ``model="llama"`` like any other.  Its two programs return a fourth result,
 the live tokens' assignments per layer and expert; it reaches the host with
-the step's tokens, and what it says of the step (assignments, distinct
-experts touched summed over layers, the largest single-expert load summed
-over layers) is the attributes of an ``rt:engine.decode.moe`` or
-``rt:engine.prefill.moe`` region, beside the ``weight_itemsize`` the experts
-are stored in (what a step reads of a touched expert, for a roofline), and
-adds to the ``moe_*`` counters of ``stats()``.  A dense model's programs return three results and none of
-this runs.
-"""
+the step's tokens, and what it says of the step (assignments, distinct experts
+touched summed over layers, the largest single-expert load summed over layers)
+is the attributes of an ``rt:engine.decode.moe`` or ``rt:engine.prefill.moe``
+region, beside the ``weight_itemsize`` the experts are stored in (what a step
+reads of a touched expert, for a roofline), and adds to the ``moe_*`` counters
+of ``stats()``.  A dense model's programs return three results and none of
+this runs."""
 
 from __future__ import annotations
 
@@ -379,45 +370,16 @@ class InferenceEngine:
     def __init__(self, config: EngineConfig, params: Any = None,
                  rng_seed: int = 0):
         import jax
+        from ray_tpu.models.serving import serving_model
 
         cfg = config
         if cfg.max_prompt_len % cfg.page_size:
             raise ValueError("max_prompt_len must be a multiple of "
                              f"page_size ({cfg.page_size})")
-        if cfg.model == "gpt":
-            from ray_tpu.models.gpt import (GPTConfig, gpt_decode_step,
-                                            gpt_init, gpt_prefill,
-                                            gpt_serving_params,
-                                            init_paged_cache)
-            mc = cfg.model_config or GPTConfig.tiny(
-                seq=cfg.max_prompt_len + cfg.max_new_tokens)
-            init_fn, stored_fn, prefill_fn, decode_fn = \
-                gpt_init, gpt_serving_params, gpt_prefill, gpt_decode_step
-            attention_fn = lambda mc, rung: "dense"   # noqa: E731
-            cache_fn = lambda: init_paged_cache(   # noqa: E731
-                mc, cfg.num_pages, cfg.page_size, cfg.dtype)
-        elif cfg.model == "llama":
-            from ray_tpu.models.llama import (LlamaConfig,
-                                              llama_decode_step,
-                                              llama_init,
-                                              llama_init_paged_cache,
-                                              llama_prefill,
-                                              llama_prefill_attention,
-                                              llama_serving_params)
-            mc = cfg.model_config or LlamaConfig.tiny(
-                seq=cfg.max_prompt_len + cfg.max_new_tokens)
-            init_fn, stored_fn, prefill_fn, decode_fn = \
-                llama_init, llama_serving_params, llama_prefill, \
-                llama_decode_step
-            attention_fn = llama_prefill_attention
-            if mc.block_length:      # its decode step is a block's pass
-                from ray_tpu.models.llama import (block_unmask,
-                                                  llama_block_step)
-                decode_fn = llama_block_step
-            cache_fn = lambda: llama_init_paged_cache(   # noqa: E731
-                mc, cfg.num_pages, cfg.page_size, cfg.dtype)
-        else:
-            raise ValueError(f"unknown engine model '{cfg.model}'")
+        # everything that is the model's: its programs, pools and config
+        served = serving_model(cfg.model, cfg.model_config,
+                               cfg.max_prompt_len + cfg.max_new_tokens)
+        mc = served.config
         if mc.max_seq_len < cfg.max_prompt_len + cfg.max_new_tokens:
             raise ValueError(
                 f"model max_seq_len {mc.max_seq_len} < max_prompt_len + "
@@ -426,18 +388,19 @@ class InferenceEngine:
         self.config = cfg
         self.model_config = mc
         # positions a decode step yields a sequence: 0 is one, by one token
-        self._block = block = getattr(mc, "block_length", 0)
+        self._block = served.block
         # Stored once as the two programs read them (module docstring); the
         # caller's tree is not kept, so what was cast is the caller's to free.
-        self._params = stored_fn(
+        self._params = served.stored(
             params if params is not None else
-            init_fn(jax.random.PRNGKey(rng_seed), mc), mc)
+            served.init(jax.random.PRNGKey(rng_seed), mc), mc)
         self._weight_bytes = sum(
             leaf.nbytes for leaf in jax.tree.leaves(self._params))
         # The model's kind of pages: K and V pools, or one pool of latent
         # pages and None where the V pool would be (models/llama.py).
-        self._new_pools = cache_fn
-        self._k_pages, self._v_pages = cache_fn()
+        self._new_pools = lambda: served.new_pools(
+            cfg.num_pages, cfg.page_size, cfg.dtype)
+        self._k_pages, self._v_pages = self._new_pools()
         self._kv_pool_bytes = sum(p.nbytes for p in self._pools())
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
@@ -451,25 +414,21 @@ class InferenceEngine:
         # the program and of its compile-cache key, one copy per entry point.
         # Both donate the pools (module docstring).
         def _prefill(params, tokens, length, kp, vp, pt):
-            return prefill_fn(params, mc, tokens, length, kp, vp, pt)
+            return served.prefill(params, mc, tokens, length, kp, vp, pt)
 
         def _decode(params, token, pos, kp, vp, pt):
-            return decode_fn(params, mc, token, pos, kp, vp, pt)
+            return served.step(params, mc, token, pos, kp, vp, pt)
 
-        # The loop's decode step: the same, and every slot's next token
-        # chosen where the logits are, so that the step after it can take
-        # it from the device.  Under the same name: the device's timeline
-        # and its readers know the program as ``jit__decode``.
+        # The loop's decode step: the same, and what the next step is fed
+        # (every slot's next token; a block model's next state, its [max_batch,
+        # B, V] logits then no result) chosen where the logits are, so that
+        # the step after it can take it from the device.  Under the same
+        # name: the device's timeline and its readers know the program as
+        # ``jit__decode``.
         def _decode_next(params, token, pos, kp, vp, pt):
-            import jax.numpy as jnp
             logits, *rest = _decode(params, token, pos, kp, vp, pt)
-            if block:    # (not ``self``: a traced closure outlives the engine)
-                # ``token`` is the blocks' state and ``pos`` their ends;
-                # the unmasking happens where the logits are, and the
-                # [max_batch, B, V] logits are not a result
-                return (None, *rest, block_unmask(mc, logits, token, pos))
-            return (logits, *rest,
-                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            logits, nxt = served.feed(mc, logits, token, pos)
+            return (logits, *rest, nxt)
         _decode_next.__name__ = _decode.__name__
 
         self._prefill_donating = jax.jit(_prefill, donate_argnums=(3, 4))
@@ -492,7 +451,7 @@ class InferenceEngine:
         # compiled for every width of the decode ladder.
         self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
         # what each rung's attention runs as ("flash": the kernel, "dense")
-        self._rung_attention = {rung: attention_fn(mc, rung)
+        self._rung_attention = {rung: served.prefill_attention(mc, rung)
                                 for rung in self._rungs}
         self._decode_rungs = decode_rungs(self._maxp)
         shapes = jax.tree.map(

@@ -1,0 +1,199 @@
+"""Whether two trees lower to the same programs: one ``sha256  configuration
+program@rung`` line for every program the engine compiles for the benchmark's
+serving configurations, and for a tiny llama train step.
+
+    JAX_PLATFORMS=cpu python scripts/lowered_texts.py [--tree DIR]
+        [--only mistral xing ...] [--texts OUT_DIR]
+
+A change that is said to leave the served programs alone is shown to by
+running this at the parent (``--tree`` a ``git archive`` of it) and at the
+change and comparing the lines (``diff``): an equal hash is an equal
+``jax.jit(...).lower(...).as_text()``, operation for operation; where a line
+differs, ``--texts`` keeps both texts to ``diff``.  Needs no chip and runs
+nothing: the model comes from ``benchmark/configs/*.json`` through the
+family's ``program_config``, the stored tree's and the pools' shapes from
+``jax.eval_shape``, every program is lowered with the pools donated for a
+DESCRIBED ``v5e:2x2`` device, and ``jax.default_backend()`` answers "tpu" to
+this repo's code for the length of the run, so that what asks it
+(``models/gpt.py::_flash_profitable``, the two kernels' ``_resolve``) decides
+as on the chip: Mistral's prefill rungs of 1024 and 2048 hold the flash
+forward kernel, the expert models' programs the grouped matmul, both as
+Mosaic modules (which name no source file: ``ops/kernel_source.py``).  Reads,
+never writes, under ``benchmark/``.  ~2 min a tree.
+
+The programs are the engine's own two (``serve/engine/engine.py``'s
+``_prefill`` at every rung of ``prefill_rungs`` and ``_decode_next``, the
+model's step and what feeds the next one, at every width of
+``decode_rungs``), rebuilt here from ``ray_tpu.models.serving_model``'s
+record, as the engine builds them; a tree from before PR 47 has no record and
+is read through the functions the record names.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import hashlib
+import json
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs under /tmp
+
+POOLS = (3, 4)
+
+
+def _programs(model: str, cfg):
+    """(prefill, decode) as the engine's loop has them, by those names (the
+    lowered module's name is the function's)."""
+    import jax.numpy as jnp
+    try:
+        from ray_tpu.models import serving_model
+    except ImportError:              # a tree from before PR 47
+        from ray_tpu.models import llama
+        prefill_fn = llama.llama_prefill
+        step_fn = llama.llama_block_step if cfg.block_length \
+            else llama.llama_decode_step
+
+        def feed(cfg, logits, token, pos):
+            if cfg.block_length:
+                return None, llama.block_unmask(cfg, logits, token, pos)
+            return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        served = serving_model(model, cfg)
+        prefill_fn, step_fn, feed = served.prefill, served.step, served.feed
+
+    def _prefill(params, tokens, length, kp, vp, pt):
+        return prefill_fn(params, cfg, tokens, length, kp, vp, pt)
+
+    def _decode(params, token, pos, kp, vp, pt):
+        logits, *rest = step_fn(params, cfg, token, pos, kp, vp, pt)
+        logits, nxt = feed(cfg, logits, token, pos)
+        return (logits, *rest, nxt)
+    return _prefill, _decode
+
+
+def _serving(name: str, device):
+    """(label, lowered text) of every engine program of one configuration."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark import spec
+    from ray_tpu.models.llama import (llama_init_paged_cache,
+                                      llama_serving_params)
+    from ray_tpu.serve.engine.engine import decode_rungs, prefill_rungs
+
+    config = spec.load_json("configs", name)
+    family = spec.load_part("families", config["family"])
+    eng = config["engine"]
+    page, batch = eng["page_size"], eng["max_batch"]
+    positions = eng["max_prompt_len"] + eng["max_new_tokens"]
+    cfg = family.program_config(config, positions)
+    maxp = -(-positions // page)
+    one = SingleDeviceSharding(device)
+
+    def on(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on(jax.eval_shape(lambda: llama_serving_params(
+        family.init(jax.random.PRNGKey(0), cfg), cfg)))
+    kp, vp = on(jax.eval_shape(lambda: llama_init_paged_cache(
+        cfg, eng["num_pages"], page, eng.get("dtype"))))
+    prefill, decode = _programs(family.ENGINE_MODEL, cfg)
+    for rung in prefill_rungs(eng["max_prompt_len"], page):
+        yield f"prefill@{rung}", jax.jit(prefill, donate_argnums=POOLS).lower(
+            params, arg((1, rung)), arg(()), kp, vp, arg((1, maxp))).as_text()
+    if cfg.block_length:             # a block's state and the slots' ends
+        rows = (batch, cfg.block_length)
+        token = (arg(rows), arg(rows, jnp.bool_), arg((batch,)),
+                 arg((batch,)))
+    else:
+        token = arg((batch,))
+    for width in decode_rungs(maxp):
+        yield f"decode@{width}", jax.jit(decode, donate_argnums=POOLS).lower(
+            params, token, arg((batch,)), kp, vp,
+            arg((batch, width))).as_text()
+
+
+def _train(device):
+    """The tiny llama's train step, its attention dense and by the flash
+    kernels (the two branches of the training trunk's attention)."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.models.llama import (LlamaConfig, llama_init,
+                                      make_train_step)
+
+    one = SingleDeviceSharding(device)
+    tx = optax.adamw(3e-4)
+    for attention in ("dense", "flash"):
+        cfg = dataclasses.replace(LlamaConfig.tiny(), attention=attention)
+        params = jax.eval_shape(
+            lambda: llama_init(jax.random.PRNGKey(0), cfg))
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            (params, jax.eval_shape(tx.init, params),
+             {"tokens": jax.ShapeDtypeStruct((2, 129), jnp.int32)}))
+        step = make_train_step(cfg, tx)
+        yield f"train_step@{attention}", step.lower(*args).as_text()
+
+
+def main(argv=None) -> int:
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--tree", default=here,
+                        help="the checkout whose ray_tpu/ and benchmark/ "
+                             "are read (default: this one)")
+    parser.add_argument("--only", nargs="*", default=None,
+                        help="configurations by a part of their file's "
+                             "name, and 'train'")
+    parser.add_argument("--texts", default=None,
+                        help="a directory to keep every lowered text in")
+    args = parser.parse_args(argv)
+    tree = os.path.abspath(args.tree)
+    sys.path.insert(0, tree)
+
+    import jax
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.default_backend = lambda: "tpu"   # what this repo's code is told
+    device = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+
+    def wanted(name):
+        return args.only is None or any(part in name for part in args.only)
+
+    def lines():
+        for path in sorted(glob.glob(
+                os.path.join(tree, "benchmark", "configs", "*.json"))):
+            name = os.path.basename(path)
+            with open(path) as f:
+                if "engine" not in json.load(f) or not wanted(name):
+                    continue
+            for label, text in _serving(name, device):
+                yield name[:-len(".json")], label, text
+        if wanted("train"):
+            for label, text in _train(device):
+                yield "llama-tiny", label, text
+
+    if args.texts:
+        os.makedirs(args.texts, exist_ok=True)
+    for name, label, text in lines():
+        print(hashlib.sha256(text.encode()).hexdigest(), name, label,
+              flush=True)
+        if args.texts:
+            with open(os.path.join(args.texts, f"{name}.{label}.txt"),
+                      "w") as f:
+                f.write(text)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
