@@ -396,11 +396,13 @@ def gpt_forward(params: Dict[str, Any], tokens: jax.Array, cfg: GPTConfig,
 
 
 def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
-                     dtype: Any = None) -> Tuple[jax.Array, jax.Array]:
+                     dtype: Any = None, slots: int = 0
+                     ) -> Tuple[jax.Array, jax.Array]:
     """Zeroed K/V page pools of all layers, [L, P, page, N*H]
     (token-major, matching ops.paged_attention's layouts).  Page 0 is the
     scratch sink for padded/inactive writes — allocators must never hand
-    it out."""
+    it out.  ``slots`` (the engine's decode slots) sizes nothing here: this
+    model keeps no row a slot."""
     dt = dtype or cfg.dtype
     shape = (cfg.num_layers, num_pages, page_size,
              cfg.num_heads * cfg.head_dim)
@@ -434,7 +436,7 @@ def gpt_serving_params(params: Dict[str, Any],
 
 def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
                 length: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                page_table: jax.Array
+                page_table: jax.Array, slot: jax.Array = 0
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Prefill ONE padded sequence: run the trunk densely, scatter every
     layer's K/V into the sequence's pages, and return the next-token
@@ -445,7 +447,9 @@ def gpt_prefill(params: Dict[str, Any], cfg: GPTConfig, tokens: jax.Array,
     ``k_pages``/``v_pages`` [L, P, page, N*H], carried through the layer
     scan and written in place.  Padding positions write
     to scratch page 0 (see ops.paged_attention.prefill_kv) and, being
-    causal, never influence positions < length.  Returns
+    causal, never influence positions < length.  ``slot`` (the decode slot
+    the sequence will be stepped in) is for a model that keeps a row a
+    slot; this one takes no notice of it.  Returns
     (logits [1, V] f32, k_pages, v_pages)."""
     from ray_tpu.ops.paged_attention import prefill_kv
     dt = cfg.dtype

@@ -16,11 +16,11 @@ The layer is written ONCE (``_layer``: ``_attention`` and ``_ffn``, each a
 They differ in what the attention does with its keys and values, and that
 alone is handed in, as an ``AttentionState``: ``_no_cache``,
 ``_prefill_state``, ``_token_state``, ``_block_state``, for K/V pages and,
-where written, latent pages.  A cache of another kind (a window's ring, a
-recurrent row) is one more state, its pool's shape beside
-``llama_init_paged_cache`` and its two functions in
-``ops/paged_attention.py``: not the layer, and not the engine, which asks
-``served`` for this module's programs and knows none by name.
+where written, latent pages and a linear layer's recurrent rows.  A cache of
+another kind (a window's ring) is one more state, its pool's shape beside
+``llama_init_paged_cache`` and its functions under ``ops/``: not the layer,
+and not the engine, which asks ``served`` for this module's programs and
+knows none by name.
 
 The reference has no model zoo of its own (its flagship benchmarks wrap
 torchvision/HF models); this family exists so Train/Tune/Serve have a
@@ -67,6 +67,27 @@ hyper-connections, arXiv:2512.24880; ``_sublayer``).  ``shared_experts``,
 ``router_scoring``, ``router_bias`` and ``routed_scaling`` are
 ``ops/moe.py``'s.  Such a model serves and runs ``llama_forward``; it does
 not train here.
+
+With a ``layer_pattern`` the stack is layers of TWO kinds in a repeating
+period (Olmo-Hybrid's: three "linear" then one "full"): a linear layer's
+attention is the gated delta rule (``_linear_attention``;
+``ops/linear_attention.py`` has the rule's chunked scan, its one-position
+step and the short causal convolution ahead of it), which keeps of its past
+one float32 matrix a head and the convolution's last inputs, not pages.
+``params["layers"]`` is then a tuple, one group a position of the period,
+each stacked over the periods, and the layer scan goes over periods
+(``_scan_periods``).  Served, the third kind of state (``recurrent``) lives
+beside the pages: ``llama_init_paged_cache`` makes K/V pages for the full
+layers alone and, where the V pool would be, ``RecurrentPools``: that pool
+and a state row and a convolution tail a decode SLOT for every linear layer,
+the state folded so that no lane of the TPU's tiles is air.  A prefill learns
+its slot (``llama_prefill``'s last argument) and leaves there what stands
+after the prompt's last real position, whatever the rung; the token step
+steps every live slot's rows where they lie.  ``pre_norm=False`` takes the
+norm off a sublayer's input (OLMo 2 norms the output only: ``post_norm``)
+and ``rope_theta=0`` rotates nothing.  Such a model serves and runs
+``llama_forward``; ``llama_loss`` refuses it (the scan's backward pass is
+not written) and so does the block step.
 """
 
 from __future__ import annotations
@@ -96,7 +117,7 @@ class LlamaConfig:
     num_kv_heads: int = 4            # GQA: kv_heads < heads shares K/V
     embed_dim: int = 768
     mlp_dim: int = 2048              # SwiGLU hidden (~8/3 * embed, /128 pad)
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0      # 0: nothing is rotated
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -133,6 +154,15 @@ class LlamaConfig:
     denoise_steps: int = 0           #   blocks of this many positions, in
     confidence_threshold: float = 0.0   # this many passes (0 = static:
     mask_token: int = 0              #   the count alone), masks of this id
+    pre_norm: bool = True            # RMSNorm a sublayer's input
+    # one period of the stack's layer kinds, "linear" | "full" (the stack
+    # repeats it; empty: every layer is full attention), and a linear layer's
+    layer_pattern: Tuple[str, ...] = ()
+    linear_heads: int = 0            #   heads (keys' and values' alike),
+    linear_key_dim: int = 0          #   a head's q/k width,
+    linear_value_dim: int = 0        #   a head's value width,
+    linear_conv: int = 4             #   convolution over this many positions
+    linear_neg_eigval: bool = False  #   beta in (0, 2) and not (0, 1)
 
     @property
     def head_dim(self) -> int:
@@ -166,9 +196,11 @@ def _check(cfg: LlamaConfig) -> None:
                          "dense_mlp_dim ahead of at least one expert layer")
     if cfg.router_scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"router_scoring={cfg.router_scoring!r}")
-    if cfg.hc_mult and (cfg.post_norm or cfg.ut_steps > 1):
+    if cfg.hc_mult and (cfg.post_norm or cfg.ut_steps > 1
+                        or not cfg.pre_norm):
         raise ValueError("a hyper-connected residual (hc_mult) is not "
-                         "written for post_norm or ut_steps > 1")
+                         "written for post_norm, ut_steps > 1 or no "
+                         "pre_norm")
     if cfg.qk_norm and cfg.qk_norm_per_head:
         raise ValueError("qk_norm pools all heads, qk_norm_per_head each "
                          "head by itself: one or the other")
@@ -177,6 +209,25 @@ def _check(cfg: LlamaConfig) -> None:
                          "(qk_nope_dim, qk_rope_dim, v_head_dim) and norms "
                          "its bottlenecks, not head_size or "
                          "qk_norm_per_head")
+    if cfg.layer_pattern:
+        kinds, others = set(cfg.layer_pattern), (
+            "num_experts", "kv_lora_rank", "hc_mult", "block_length",
+            "first_dense_layers")
+        if not kinds <= {"linear", "full"} or kinds == {"full"} \
+                or cfg.num_layers % len(cfg.layer_pattern):
+            raise ValueError(
+                f"layer_pattern={cfg.layer_pattern} is one period of "
+                "'linear' and 'full' layers, at least one of them linear, "
+                f"and num_layers={cfg.num_layers} whole periods")
+        if not (cfg.linear_heads and cfg.linear_key_dim
+                and cfg.linear_value_dim and cfg.linear_conv > 1):
+            raise ValueError("linear layers need linear_heads, "
+                             "linear_key_dim, linear_value_dim and a "
+                             "linear_conv of 2 or more")
+        if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others):
+            raise ValueError("a stack with linear layers (layer_pattern) is "
+                             "not written for ut_steps > 1 or "
+                             + ", ".join(others))
     if cfg.block_length:
         if cfg.kv_lora_rank or cfg.ut_steps > 1 or cfg.hc_mult:
             raise ValueError("generation by blocks (block_length) is not "
@@ -197,10 +248,20 @@ def _check(cfg: LlamaConfig) -> None:
                          "mask_token belong to a block_length")
 
 
+def _linear_layers(cfg: LlamaConfig) -> int:
+    """How many of the stack's layers are linear attention."""
+    if not cfg.layer_pattern:
+        return 0
+    return cfg.layer_pattern.count("linear") * (
+        cfg.num_layers // len(cfg.layer_pattern))
+
+
 def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
-                experts: int, M: int) -> Dict[str, Any]:
+                experts: int, M: int, linear: bool = False) -> Dict[str, Any]:
     """``L`` layers of one kind stacked on a leading dim: ``experts`` of
-    width ``M`` each (0: one dense SwiGLU of ``M``)."""
+    width ``M`` each (0: one dense SwiGLU of ``M``); with ``linear`` the
+    linear-attention leaves under ``"linear"`` where the others have
+    ``"attn"``."""
     k = jax.random.split(rng, 8)
     D, H = cfg.embed_dim, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -226,7 +287,30 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                 "wgu": normal(jax.random.fold_in(k[4], 1), (L, 2, D, Ms)),
                 "wd": normal(jax.random.fold_in(k[5], 1), (L, Ms, D),
                              rscale)}
-    if cfg.kv_lora_rank:
+    if linear:
+        # The gated delta rule's leaves (transformers' Qwen3NextGatedDeltaNet
+        # draws them so): the convolution as torch's Conv1d, uniform within
+        # fan_in^-1/2 = K^-1/2; A uniform over (0, 16); dt log-uniform over
+        # (0.001, 0.1) and kept as its inverse softplus.
+        N, dk, dv, K = (cfg.linear_heads, cfg.linear_key_dim,
+                        cfg.linear_value_dim, cfg.linear_conv)
+        C = N * (2 * dk + dv)            # q | k | v, the convolved channels
+        dt = jnp.exp(jax.random.uniform(
+            jax.random.fold_in(k[2], 2), (L, N), jnp.float32,
+            np.log(0.001), np.log(0.1)))
+        attn = {"wqkv": normal(k[1], (L, D, C)),
+                "wz": normal(jax.random.fold_in(k[1], 1), (L, D, N * dv)),
+                "wba": normal(k[2], (L, D, 2 * N)),       # beta's | alpha's
+                "conv": jax.random.uniform(
+                    jax.random.fold_in(k[2], 1), (L, K, C), jnp.float32,
+                    -K ** -0.5, K ** -0.5),
+                "A_log": jnp.log(jax.random.uniform(
+                    jax.random.fold_in(k[2], 3), (L, N), jnp.float32,
+                    1e-3, 16.0)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "norm": jnp.ones((L, dv), jnp.float32),
+                "wo": normal(k[3], (L, N, dv, D), rscale)}
+    elif cfg.kv_lora_rank:
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
         attn = {"wq_a": normal(k[1], (L, D, rq)),
@@ -262,16 +346,18 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
             "proj": normal(key, (L, n * D, 2 * n + n * n)),
             "alpha": jnp.full((L, 3), 0.25, jnp.float32),
             "bias": bias.at[:, 2 * n:].add(2.0 * jnp.eye(n).reshape(-1))}
-    return {"ln1": {"scale": jnp.ones((L, D), jnp.float32)},
-            "attn": attn,
-            "ln2": {"scale": jnp.ones((L, D), jnp.float32)},
-            "mlp": mlp, **extra}
+    for name in ("ln1", "ln2") if cfg.pre_norm else ():
+        extra[name] = {"scale": jnp.ones((L, D), jnp.float32)}
+    return {"linear" if linear else "attn": attn, "mlp": mlp, **extra}
 
 
 def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     """Params with per-layer weights stacked on a leading [L] dim; with
     ``first_dense_layers`` those under ``dense_layers`` and the expert
-    layers that follow them under ``layers``."""
+    layers that follow them under ``layers``; with a ``layer_pattern``
+    ``layers`` is a tuple, one group a POSITION of the pattern, each stacked
+    over the periods [num_layers / len(pattern)]: layer ``l`` is row ``l //
+    len(pattern)`` of group ``l % len(pattern)``."""
     _check(cfg)
     k = jax.random.split(rng, 8)
     D, V, Ld = cfg.embed_dim, cfg.vocab_size, cfg.first_dense_layers
@@ -279,17 +365,27 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     dense = {"dense_layers": _init_group(
         jax.random.fold_in(rng, 1), cfg, Ld, 0, cfg.dense_mlp_dim)} \
         if Ld else {}
+
+    def layers():        # (drawn after the table, as they always were)
+        if not cfg.layer_pattern:
+            return _init_group(rng, cfg, cfg.num_layers - Ld,
+                               cfg.num_experts, cfg.mlp_dim)
+        periods = cfg.num_layers // len(cfg.layer_pattern)
+        return tuple(
+            _init_group(jax.random.fold_in(rng, 16 + at), cfg, periods, 0,
+                        cfg.mlp_dim, kind == "linear")
+            for at, kind in enumerate(cfg.layer_pattern))
     return {
         "wte": scale * jax.random.normal(k[0], (V, D), jnp.float32),
         **dense,
-        "layers": _init_group(rng, cfg, cfg.num_layers - Ld,
-                              cfg.num_experts, cfg.mlp_dim),
+        "layers": layers(),
         "ln_f": {"scale": jnp.ones((D,), jnp.float32)},
         "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32),
     }
 
 
-def _group_axes(cfg: LlamaConfig, experts: bool) -> Dict[str, Any]:
+def _group_axes(cfg: LlamaConfig, experts: bool,
+                linear: bool = False) -> Dict[str, Any]:
     ex = ("expert",) if experts else ()
     mlp = {"wgu": ("layers", *ex, None, "embed", "mlp"),
            "wd": ("layers", *ex, "mlp", "embed")}
@@ -301,7 +397,15 @@ def _group_axes(cfg: LlamaConfig, experts: bool) -> Dict[str, Any]:
         if cfg.shared_experts:
             extra["shared"] = {"wgu": ("layers", None, "embed", "mlp"),
                                "wd": ("layers", "mlp", "embed")}
-    if cfg.kv_lora_rank:
+    if linear:
+        attn = {"wqkv": ("layers", "embed", "heads"),
+                "wz": ("layers", "embed", "heads"),
+                "wba": ("layers", "embed", None),
+                "conv": ("layers", None, "heads"),
+                "A_log": ("layers", None), "dt_bias": ("layers", None),
+                "norm": ("layers", "norm"),
+                "wo": ("layers", "heads", "kv", "embed")}
+    elif cfg.kv_lora_rank:
         attn = {"wq_a": ("layers", "embed", None),
                 "q_a_norm": ("layers", "norm"),
                 "wq_b": ("layers", None, "heads", "kv"),
@@ -323,8 +427,9 @@ def _group_axes(cfg: LlamaConfig, experts: bool) -> Dict[str, Any]:
     for name in ("hc_attn", "hc_mlp") if cfg.hc_mult else ():
         extra[name] = {"proj": ("layers", None, None),
                        "alpha": ("layers", None), "bias": ("layers", None)}
-    return {"ln1": {"scale": ("layers", "norm")}, "attn": attn,
-            "ln2": {"scale": ("layers", "norm")}, "mlp": mlp, **extra}
+    for name in ("ln1", "ln2") if cfg.pre_norm else ():
+        extra[name] = {"scale": ("layers", "norm")}
+    return {"linear" if linear else "attn": attn, "mlp": mlp, **extra}
 
 
 def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
@@ -333,10 +438,14 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     "expert" -> ep, the router stays replicated over them)."""
     dense = {"dense_layers": _group_axes(cfg, False)} \
         if cfg.first_dense_layers else {}
+    layers = _group_axes(cfg, bool(cfg.num_experts))
+    if cfg.layer_pattern:
+        layers = tuple(_group_axes(cfg, False, kind == "linear")
+                       for kind in cfg.layer_pattern)
     return {
         "wte": (None, "embed"),
         **dense,
-        "layers": _group_axes(cfg, bool(cfg.num_experts)),
+        "layers": layers,
         "ln_f": {"scale": ("norm",)},
         "lm_head": ("embed", None),
     }
@@ -400,7 +509,10 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def _rope_tables(cfg: LlamaConfig, S: int) -> tuple:
-    """The model's (cos, sin) tables for ``S`` positions."""
+    """The model's (cos, sin) tables for ``S`` positions; (None, None) for
+    a model that rotates nothing (``rope_theta`` 0)."""
+    if not cfg.rope_theta:
+        return None, None
     if not cfg.kv_lora_rank:
         return rope_tables(S, cfg.head_dim, cfg.rope_theta)
     return yarn_rope_tables(S, cfg.qk_rope_dim, cfg.rope_theta,
@@ -458,6 +570,8 @@ def _qk(cfg: LlamaConfig, p, q, k, cos, sin):
             return _rms_norm(a, scale, cfg.rms_eps, axis=(1, -1))
         q = norm(q, p["attn"]["q_norm"])
         k = norm(k, p["attn"]["k_norm"])
+    if cos is None:                  # a model that rotates nothing
+        return q, k
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
 
@@ -519,7 +633,9 @@ def _sublayer(cfg: LlamaConfig, p, which: int, x, fn):
     """One sublayer around the residual stream: ``which`` 0 is attention
     (``ln1``, ``ln1_post``, ``hc_attn``), 1 the feed-forward.  ``fn`` takes
     the normed input [..., D] and returns (the sublayer's output, anything
-    else the caller wants back).  Plain: ``x + fn(norm(x))``.  With
+    else the caller wants back).  Plain: ``x + fn(norm(x))``; without
+    ``cfg.pre_norm`` the input is the stream itself and the only norm is
+    ``_add_sublayer``'s, on the output (OLMo 2's order).  With
     ``cfg.hc_mult`` the stream is [..., n, D]: the sublayer reads ``pre .
     x``, and the next stream is ``res @ x + post (outer) y``, with the
     coefficients of ``_hc_coeff``; the products are float32 on the stream's
@@ -527,7 +643,8 @@ def _sublayer(cfg: LlamaConfig, p, which: int, x, fn):
     ln, post, hc = (("ln1", "ln1_post", "hc_attn"),
                     ("ln2", "ln2_post", "hc_mlp"))[which]
     if not cfg.hc_mult:
-        y, rest = fn(_rms_norm(x, p[ln]["scale"], cfg.rms_eps))
+        y, rest = fn(_rms_norm(x, p[ln]["scale"], cfg.rms_eps)
+                     if cfg.pre_norm else x)
         return _add_sublayer(cfg, p, post, x, y), rest
     h_pre, h_post, h_res = _hc_coeff(cfg, p[hc], x)
     with jax.named_scope("hc_mix"):
@@ -685,6 +802,8 @@ def _scan_layers(cfg: LlamaConfig, params, body, carry, t=None,
     layer's index into the pools in pass ``t`` (``_pool_layers``).  Returns
     the last carry and the scanned layers' ``ys`` (the expert layers'
     loads)."""
+    if cfg.layer_pattern:
+        return _scan_periods(cfg, params, body, carry, served)
     first = cfg.first_dense_layers
     layers, experts = _scanned_layers(cfg, params)
     pool_layers = _pool_layers(cfg, t) if served else None
@@ -697,6 +816,32 @@ def _scan_layers(cfg: LlamaConfig, params, body, carry, t=None,
     if served:
         layers = (layers, pool_layers[first:] if first else pool_layers)
     return jax.lax.scan(functools.partial(body, experts), carry, layers)
+
+
+def _scan_periods(cfg: LlamaConfig, params, body, carry, served: bool):
+    """``_scan_layers`` for a stack of two kinds of layer in a repeating
+    ``cfg.layer_pattern``: the scan goes over PERIODS with the pattern
+    unrolled inside; ``params["layers"]`` holds a group a position of the
+    pattern, each stacked over the periods, so every layer's weights are the
+    scan's own slice of a stack (a slice at any other index the compiler
+    answers with a re-laid-out copy of the whole stack, every call).  A
+    ``served`` layer gets its index among the layers of ITS KIND beside its
+    slice: the full layers' pages and the linear layers' state rows are
+    pools of their own."""
+    pattern = cfg.layer_pattern
+    counts = {kind: pattern.count(kind) for kind in set(pattern)}
+
+    def period(carry, xs):
+        groups, at = xs
+        seen = dict.fromkeys(counts, 0)
+        for kind, p in zip(pattern, groups):
+            layer = at * counts[kind] + seen[kind]
+            carry, _ = body(None, carry, (p, layer) if served else p)
+            seen[kind] += 1
+        return carry, None
+    periods = cfg.num_layers // len(pattern)
+    return jax.lax.scan(period, carry,
+                        (params["layers"], jnp.arange(periods)))
 
 
 def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
@@ -728,14 +873,76 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
 class AttentionState(NamedTuple):
     """What a layer's attention does with its keys and values:
     ``kv(p, layer, pools, q, k, v)`` for K/V pages, ``latent(p, layer, pools,
-    q_nope, q_rope, latent)`` for latent pages (None: not written for them).
-    Either writes, reads and returns ``(o, pools)``.  ``p`` is the layer's
-    parameters (a latent kind expands with its ``wkv_b``), ``layer`` its
-    index into ``pools``, and ``pools`` the kind's own: the trunk carries
-    them from layer to layer and never looks inside.  The projections come
-    in ``_attention``'s layouts."""
+    q_nope, q_rope, latent)`` for latent pages, ``recurrent(p, layer, pools,
+    qkv, g, beta)`` for a linear layer's state row (None: not written for
+    them).  Each writes, reads and returns ``(o, pools)``.  ``p`` is the
+    layer's parameters (a latent kind expands with its ``wkv_b``, a
+    recurrent one convolves with its ``conv``), ``layer`` its index into
+    ``pools`` among the layers of its kind, and ``pools`` the kind's own: the
+    trunk carries them from layer to layer and never looks inside.  The
+    projections come in ``_attention``'s and ``_linear_attention``'s
+    layouts."""
     kv: Callable
     latent: Optional[Callable] = None
+    recurrent: Optional[Callable] = None
+
+
+class RecurrentPools(NamedTuple):
+    """What a model with linear layers keeps where the others keep their V
+    pool: that pool (the full layers') and, a row a decode SLOT and not
+    pages, the linear layers' states ``[linear layers, slots, panels, dk,
+    lanes]`` float32 (``ops/linear_attention.py``'s folded layout) and the
+    last inputs of their convolutions ``[linear layers, slots, (K - 1) *
+    channels]``.  A slot's rows are overwritten whole by the next prefill
+    into it: nothing allocates or frees them."""
+    v_pages: jax.Array
+    state: jax.Array
+    conv: jax.Array
+
+
+def _pages(pools):
+    """The (k_pages, v_pages) that a ``kv`` state reads and writes."""
+    if pools is None or not isinstance(pools[1], RecurrentPools):
+        return pools
+    return pools[0], pools[1].v_pages
+
+
+def _with_pages(pools, pages):
+    """``pools`` with its (k_pages, v_pages) replaced by ``pages``."""
+    if pools is None or not isinstance(pools[1], RecurrentPools):
+        return pages
+    return pages[0], pools[1]._replace(v_pages=pages[1])
+
+
+def _linear_split(cfg: LlamaConfig, mixed):
+    """The convolved channels [..., C] as the rule's float32 heads: q, k
+    [..., N, dk], each of unit length (q times dk^-1/2 besides), and v [...,
+    N, dv]."""
+    from ray_tpu.ops.linear_attention import l2_normalise
+    N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    lead = mixed.shape[:-1]
+    q = l2_normalise(mixed[..., :N * dk].reshape(*lead, N, dk)) * dk ** -0.5
+    k = l2_normalise(mixed[..., N * dk:2 * N * dk].reshape(*lead, N, dk))
+    v = mixed[..., 2 * N * dk:].reshape(*lead, N, dv).astype(jnp.float32)
+    return q, k, v
+
+
+def _linear_sequence(cfg: LlamaConfig, p, qkv, g, beta, length=None):
+    """A linear layer over one whole sequence from an empty state, by the
+    chunked scan: qkv [S, C] as projected, g, beta [S, N].  Returns (o [S,
+    N, dv] float32, the state [N, dk, dv] and the convolution's last inputs
+    as they stand after position ``length - 1``; None: after the last)."""
+    from ray_tpu.ops.linear_attention import (causal_conv, conv_tail,
+                                              gated_delta_chunked)
+    w = p["linear"]["conv"]
+    with jax.named_scope("linear_conv"):
+        mixed = causal_conv(qkv, w)
+        tail = conv_tail(qkv, qkv.shape[0] if length is None else length,
+                         w.shape[0])
+    with jax.named_scope("linear_state"):
+        o, state = gated_delta_chunked(*_linear_split(cfg, mixed), g, beta,
+                                       length)
+    return o, state, tail
 
 
 def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
@@ -757,15 +964,21 @@ def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
         v = lc(v, ("batch", "heads", "seq", "kv"))
         return _checkpoint_name(attn_fn(q, k, v), "attn_out"), pools
 
+    def recurrent(p, layer, pools, qkv, g, beta):
+        return jax.vmap(lambda *row: _linear_sequence(cfg, p, *row)[0])(
+            qkv, g, beta), pools
+
     # a latent model's is dense: the kernels want equal heads
     return AttentionState(kv, lambda p, layer, pools, *projected: (
-        _mla_expanded(cfg, p, *projected), pools))
+        _mla_expanded(cfg, p, *projected), pools), recurrent)
 
 
 def _prefill_state(cfg: LlamaConfig, length, page_table,
-                   flash: bool) -> AttentionState:
+                   flash: bool, slot=0) -> AttentionState:
     """A sequence's prefill: its rows go to its pages (the padded tail to
-    scratch page 0) and it attends over what is in hand."""
+    scratch page 0) and it attends over what is in hand; a linear layer
+    scans the sequence from an empty state and leaves in the rows of decode
+    slot ``slot`` what stands after position ``length - 1``."""
     def kv(p, layer, pools, q, k, v):
         from ray_tpu.ops.flash_attention import flash_attention
         from ray_tpu.ops.paged_attention import prefill_kv
@@ -782,12 +995,28 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
         pool = prefill_latent(pools[0], layer, latent[0], length,
                               page_table[0])
         return _mla_expanded(cfg, p, q_nope, q_rope, latent), (pool, pools[1])
-    return AttentionState(kv, latent)
+
+    def recurrent(p, layer, pools, qkv, g, beta):
+        from ray_tpu.ops.linear_attention import fold_state
+        o, state, tail = _linear_sequence(cfg, p, qkv[0], g[0], beta[0],
+                                          length)
+        rows = pools[1]
+        with jax.named_scope("linear_state"):
+            rows = rows._replace(
+                state=jax.lax.dynamic_update_slice(
+                    rows.state, fold_state(state)[None, None],
+                    (layer, slot, 0, 0, 0)),
+                conv=jax.lax.dynamic_update_slice(
+                    rows.conv, tail.astype(rows.conv.dtype)[None, None],
+                    (layer, slot, 0)))
+        return o[None], (pools[0], rows)
+    return AttentionState(kv, latent, recurrent)
 
 
 def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
     """One row a sequence: appended at ``pos``, and the pages read as far
-    as it; a latent pool as it lies (``_mla_absorbed``)."""
+    as it; a latent pool as it lies (``_mla_absorbed``); a linear layer's
+    state row stepped one position where it lies."""
     def kv(p, layer, pools, q, k, v):
         from ray_tpu.ops.paged_attention import append_kv, paged_attention
         pools = append_kv(*pools, layer, k, v, pos, page_table)
@@ -798,7 +1027,29 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
         pool = append_latent(pools[0], layer, latent, pos, page_table)
         return _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer, pos + 1,
                              page_table), (pool, pools[1])
-    return AttentionState(kv, latent)
+
+    def recurrent(p, layer, pools, qkv, g, beta):
+        # a row a slot is the slot's own: row b of the batch is slot b.  A
+        # parked slot (pos 0) keeps what it holds.
+        from ray_tpu.ops.linear_attention import (causal_conv_step,
+                                                  gated_delta_step)
+        rows, live = pools[1], pos > 0
+        with jax.named_scope("linear_conv"):
+            mixed, tail = causal_conv_step(qkv, p["linear"]["conv"],
+                                           rows.conv[layer])
+            tail = jnp.where(live[:, None], tail, rows.conv[layer])
+        with jax.named_scope("linear_state"):
+            held = rows.state[layer]
+            o, state = gated_delta_step(*_linear_split(cfg, mixed), g, beta,
+                                        held)
+            state = jnp.where(live[:, None, None, None], state, held)
+            rows = rows._replace(
+                state=jax.lax.dynamic_update_index_in_dim(
+                    rows.state, state, layer, 0),
+                conv=jax.lax.dynamic_update_index_in_dim(
+                    rows.conv, tail, layer, 0))
+        return o, (pools[0], rows)
+    return AttentionState(kv, latent, recurrent)
 
 
 def _block_state(cfg: LlamaConfig, pos0, page_table) -> AttentionState:
@@ -834,9 +1085,45 @@ def _attention(cfg: LlamaConfig, p, h, cos, sin, state: AttentionState,
                     a["wkv"].astype(dt))
     k, v = kv[:, 0], kv[:, 1]
     q, k = _qk(cfg, p, q, k, cos, sin)
-    o, pools = state.kv(p, layer, pools, q, k, v)
+    o, pages = state.kv(p, layer, _pages(pools), q, k, v)
     return jnp.einsum("bnsh,nhd->bsd" if rows else "bnh,nhd->bd", o,
-                      a["wo"].astype(dt)), pools
+                      a["wo"].astype(dt)), _with_pages(pools, pages)
+
+
+def _linear_attention(cfg: LlamaConfig, p, h, state: AttentionState, layer,
+                      pools):
+    """A linear layer's attention on ``h`` [..., D] (the gated delta rule;
+    transformers' ``Qwen3NextGatedDeltaNet``): the projections (q | k | v
+    together, the channels the convolution runs over; the output's gate z;
+    the rule's two gates b | a), the ``state``'s convolution, rule and
+    write-back, then every head's values RMS-normed with one learned scale,
+    gated by ``silu(z)`` and projected out.  Returns (the sublayer's output,
+    the state's pools)."""
+    from ray_tpu.ops.linear_attention import decay_and_beta
+    if state.recurrent is None:
+        raise NotImplementedError(
+            "models/llama.py: this program's attention state is not "
+            "written for linear-attention layers (layer_pattern): the "
+            "block step keeps K/V pages only")
+    a, dt = p["linear"], cfg.dtype
+    N, dv = cfg.linear_heads, cfg.linear_value_dim
+    qkv = jnp.einsum("...d,dc->...c", h, a["wqkv"].astype(dt))
+    z = jnp.einsum("...d,dc->...c", h, a["wz"].astype(dt))
+    ba = jnp.einsum("...d,dc->...c", h, a["wba"].astype(dt))
+    g, beta = decay_and_beta(ba[..., N:], ba[..., :N], a["A_log"],
+                             a["dt_bias"], cfg.linear_neg_eigval)
+    o, pools = state.recurrent(p, layer, pools, qkv, g, beta)
+    y = _gated_norm(cfg, a["norm"], o, z.reshape(*z.shape[:-1], N, dv))
+    return jnp.einsum("...nv,nvd->...d", y, a["wo"].astype(dt)), pools
+
+
+def _gated_norm(cfg: LlamaConfig, scale, o, z):
+    """A linear layer's read-out o [..., N, dv] (float32) RMS-normed over
+    each head's values with the one learned ``scale`` [dv], times
+    ``silu(z)``, in the compute dtype."""
+    with jax.named_scope("linear_gate_norm"):
+        return (_rms_norm(o, scale, cfg.rms_eps)
+                * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
 def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
@@ -845,8 +1132,9 @@ def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
     and the feed-forward, each a ``_sublayer`` of the residual stream ``x``.
     Returns (x, the state's pools, the experts' load)."""
     stream = _stream_axes(cfg)
-    x, pools = _sublayer(cfg, p, 0, x, lambda h: _attention(
-        cfg, p, h, cos, sin, state, layer, pools))
+    x, pools = _sublayer(cfg, p, 0, x, (lambda h: _linear_attention(
+        cfg, p, h, state, layer, pools)) if "linear" in p else (
+            lambda h: _attention(cfg, p, h, cos, sin, state, layer, pools)))
     x = lc(x, stream)
     x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
         cfg, p, h, live, lc, experts))
@@ -932,7 +1220,8 @@ def llama_forward(params: Dict[str, Any], tokens: jax.Array,
 
 
 def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
-                           page_size: int, dtype: Any = None):
+                           page_size: int, dtype: Any = None,
+                           slots: int = 0):
     """Zeroed page pools of all layers, of the kind the model's attention
     caches (token-major: see ops.paged_attention): K and V pools ``[L, P,
     page, NKV*H]`` each or, for latent attention, one pool of latent pages
@@ -940,9 +1229,12 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     by side: ops.paged_attention says why) and None.  ``L`` is a
     layer for every pass of a looped model: ``ut_steps * num_layers``.
     Page 0 is the scratch sink for padded/inactive writes — allocators
-    must never hand it out."""
+    must never hand it out.  With a ``layer_pattern`` the pages are the
+    full layers' alone, and where the V pool would be come
+    ``RecurrentPools``: that pool, and the linear layers' state and
+    convolution rows for ``slots`` decode slots, zeroed (an empty state)."""
     dt = dtype or cfg.dtype
-    L = cfg.ut_steps * cfg.num_layers
+    L = cfg.ut_steps * cfg.num_layers - _linear_layers(cfg)
     if cfg.block_length and page_size % cfg.block_length:
         raise ValueError(f"page_size={page_size} must be a multiple of "
                          f"block_length={cfg.block_length}: a block's "
@@ -951,7 +1243,18 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
         return jnp.zeros((L, num_pages, page_size * (
             cfg.kv_lora_rank + cfg.qk_rope_dim)), dt), None
     shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
-    return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    if not cfg.layer_pattern:
+        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    from ray_tpu.ops.linear_attention import state_shape
+    if slots < 1:
+        raise ValueError("a model with linear layers keeps a state row a "
+                         "decode slot: say how many slots")
+    N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    rows = (_linear_layers(cfg), slots)
+    return jnp.zeros(shape, dt), RecurrentPools(
+        jnp.zeros(shape, dt),
+        jnp.zeros((*rows, *state_shape(N, dk, dv)), jnp.float32),
+        jnp.zeros((*rows, (cfg.linear_conv - 1) * N * (2 * dk + dv)), dt))
 
 
 def llama_serving_params(params: Dict[str, Any],
@@ -974,7 +1277,12 @@ def llama_serving_params(params: Dict[str, Any],
         if cfg.kv_lora_rank else ("wq", "wkv", "wo")
 
     def group(layers, experts):
-        out = {**layers, "attn": _cast_leaves(layers["attn"], dt, *matrices),
+        # a linear layer's mixer: its gates' A_log and dt_bias stay f32
+        mixer = {"linear": _cast_leaves(layers["linear"], dt, "wqkv", "wz",
+                                        "wba", "conv", "wo")} \
+            if "linear" in layers else \
+            {"attn": _cast_leaves(layers["attn"], dt, *matrices)}
+        out = {**layers, **mixer,
                "mlp": layers["mlp"] if experts else
                _cast_leaves(layers["mlp"], dt, "wgu", "wd")}
         if "shared" in layers:
@@ -983,7 +1291,9 @@ def llama_serving_params(params: Dict[str, Any],
     dense = {"dense_layers": group(params["dense_layers"], False)} \
         if cfg.first_dense_layers else {}
     return {**_cast_leaves(params, dt, "wte", "lm_head"), **dense,
-            "layers": group(params["layers"], bool(cfg.num_experts))}
+            "layers": tuple(group(g, False) for g in params["layers"])
+            if cfg.layer_pattern else group(params["layers"],
+                                            bool(cfg.num_experts))}
 
 
 def _paged_results(logits, k_pages, v_pages, load):
@@ -1030,7 +1340,7 @@ def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
                   k_pages: jax.Array, v_pages: Optional[jax.Array],
-                  page_table: jax.Array):
+                  page_table: jax.Array, slot: jax.Array = 0):
     """Prefill ONE padded sequence (see gpt_prefill): the trunk over the
     whole padded length, its causal attention by the flash forward kernel
     or dense as ``llama_prefill_attention`` says (the kernel reads grouped
@@ -1045,7 +1355,10 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     the prompt's trailing part of a block joins the first generated block)
     and the logits are empty, ``[1, 0]``.  An expert model returns a
     fourth result, ``load`` [expert layers, E] int32: per layer and expert,
-    the assignments of the prompt's real positions."""
+    the assignments of the prompt's real positions.  A model with linear
+    layers (``v_pages`` is then ``RecurrentPools``) leaves their state after
+    position ``length - 1`` in the rows of decode slot ``slot``, whatever the
+    rung; every other model takes no notice of ``slot``."""
     S = tokens.shape[1]
     flash = llama_prefill_attention(cfg, S) == "flash"
     cos, sin = _rope_tables(cfg, S)
@@ -1053,7 +1366,7 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     live = (jnp.arange(S) < length)[None]                # the real positions
     (x, k_pages, v_pages), load = _served_trunk(
         cfg, params, x, cos, sin,
-        _prefill_state(cfg, length, page_table, flash), live, k_pages,
+        _prefill_state(cfg, length, page_table, flash, slot), live, k_pages,
         v_pages)
     if cfg.block_length:
         # no logits: a block model's first token comes from its first
@@ -1079,9 +1392,12 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     scratch page 0.  An expert model returns a fourth result, ``load``
     [expert layers, E] int32: per layer and expert, the assignments of the
     live slots (``pos > 0``: a sequence that decodes has a prompt behind
-    it)."""
+    it).  A linear layer steps the state row of every live slot where it
+    lies: row ``b`` of the batch is decode slot ``b``."""
     cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
-    if cfg.kv_lora_rank:             # one rotated key for all heads
+    if cos_t is None:                # nothing is rotated
+        cos = sin = None
+    elif cfg.kv_lora_rank:           # one rotated key for all heads
         cos, sin = cos_t[pos], sin_t[pos]                # [B, dr/2]
     else:
         cos, sin = cos_t[pos][:, None], sin_t[pos][:, None]  # [B, 1, H/2]
@@ -1121,7 +1437,8 @@ def llama_block_step(params: Dict[str, Any], cfg: LlamaConfig,
     pos0 = jnp.where(live, pos0, 0)
     at = pos0[:, None] + jnp.arange(cfg.block_length)    # [S, B]
     cos_t, sin_t = _rope_tables(cfg, cfg.max_seq_len)
-    cos, sin = cos_t[at][:, None], sin_t[at][:, None]    # [S, 1, B, H/2]
+    cos, sin = (None, None) if cos_t is None else \
+        (cos_t[at][:, None], sin_t[at][:, None])         # [S, 1, B, H/2]
     x = _embed(cfg, params, tokens)                      # [S, B, D]
     (x, k_pages, v_pages), load = _served_trunk(
         cfg, params, x, cos, sin, _block_state(cfg, pos0, page_table),
@@ -1195,6 +1512,8 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
     return ServedModel(
         config=cfg, init=llama_init, stored=llama_serving_params,
         new_pools=functools.partial(llama_init_paged_cache, cfg),
+        slot_rows=(lambda k_pages, v_pages: (v_pages.state, v_pages.conv))
+        if cfg.layer_pattern else None,
         prefill=llama_prefill,
         step=llama_block_step if cfg.block_length else llama_decode_step,
         prefill_attention=llama_prefill_attention, block=cfg.block_length,
@@ -1213,6 +1532,11 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     collapses; those belong with the four-chip training path.  Refuses a
     looped model too: next-token CE on the last pass alone is not the
     objective such a model is published with."""
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            "models/llama.py serves its model with linear-attention layers "
+            "(layer_pattern) and runs llama_forward, but does not train "
+            "it: the chunked scan's backward pass is not written")
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             "models/llama.py serves its looped model (ut_steps > 1) but "

@@ -24,11 +24,14 @@ class ServedModel(NamedTuple):
     init: Callable               # (rng, config) -> parameters
     stored: Callable             # (parameters, config) -> the tree the two
     #                              programs read, each leaf in its dtype
-    new_pools: Callable          # (num_pages, page_size, dtype) -> (k_pages,
-    #                              v_pages), zeroed; v_pages None: one pool
+    new_pools: Callable          # (num_pages, page_size, dtype, slots) ->
+    #                              (k_pages, v_pages), zeroed; v_pages None:
+    #                              one pool; either may be a tree of arrays
     prefill: Callable            # (params, config, tokens [1, S], length,
-    #                              k_pages, v_pages, page_table) -> (logits,
-    #                              k_pages, v_pages[, the experts' load])
+    #                              k_pages, v_pages, page_table, slot) ->
+    #                              (logits, k_pages, v_pages[, the experts'
+    #                              load]); ``slot``: the decode slot the
+    #                              sequence will be stepped in
     step: Callable               # the decode step, (params, config, token,
     #                              pos, ...) likewise; a block model's takes
     #                              the blocks' state and ends for token, pos
@@ -38,6 +41,11 @@ class ServedModel(NamedTuple):
     feed: Callable               # (config, logits, token, pos) -> (the
     #                              logits the loop's step returns, or None;
     #                              what the next step takes for ``token``)
+    slot_rows: Any = None        # (k_pages, v_pages) -> the arrays among
+    #                              them that hold a row a decode SLOT and not
+    #                              pages (a recurrent state: written whole by
+    #                              a prefill, stepped where it lies, its
+    #                              leading dims [layers, slots]); None: none
 
 
 def greedy(config, logits, token, pos):

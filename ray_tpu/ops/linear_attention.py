@@ -1,0 +1,280 @@
+"""Linear attention by the gated delta rule (Gated DeltaNet,
+arXiv:2412.06464): what a layer keeps of its past is not pages that grow with
+the sequence but ONE matrix a head, ``S`` [dk, dv] in float32, and the last
+``K - 1`` inputs of a short causal convolution.  A position does
+
+    S' = alpha * S                      alpha in (0, 1]: the decay
+    S  = S' + beta * k (v - S'^T k)^T   the delta rule; beta in (0, 2)
+    o  = S^T q
+
+with ``k`` of unit length, so the write replaces what ``S'`` held along ``k``
+by a share ``beta`` of ``v`` (over 1 it flips the sign along ``k``: the
+negative eigenvalues of arXiv:2411.12537).  Three forms of the same
+arithmetic, all float32:
+
+``gated_delta_recurrent``  the lines above, a position at a time: what the
+    tests hold the other two to.
+``gated_delta_chunked``    a whole sequence in chunks of 64 (the WY form of
+    arXiv:2406.06484): inside a chunk the positions' writes are solved for
+    together with one triangular system and matrix products, and the state
+    moves a chunk at a time.  A prefill's form.  Positions from ``length`` on
+    (a rung's padded tail) get alpha = 1 and beta = 0 and leave the state
+    alone.
+``gated_delta_step``       one position for every slot of a decode batch,
+    on the state as the pool stores it.
+
+The pool stores a slot's state FOLDED (``fold_state``): the TPU tiles an
+array's last axis in 128 lanes, and a ``[dk, 192]`` matrix would lie in 256
+of them, a third of it air, read and written every step.  So the dv value
+columns of a head go into panels of 128 lanes: a head's whole 128s each a
+panel, and the columns left over (64 of 192) side by side with the next
+heads' in panels of their own, ``[panels, dk, 128]`` with nothing padded.
+Every column of ``S`` meets the delta rule alone (``u_j = sum_i S_ij k_i``,
+``S_ij += k_i w_j``), so the step runs on the panels as they lie: what
+belongs to a head (k, q, alpha, beta) is spread over its columns' lanes
+(``_keys_to_panels``, ``_values_to_panels``), and no state is unfolded.  Where
+dv's remainder does not divide 128, or the heads do not fill its panels, a
+head's dv columns are one panel and the layout is plain.
+
+The step reads the OLD state for both of its sums: ``S^T k`` and ``S^T q``
+in one pass, then ``o = alpha S^T q + (k . q) w`` with ``w = beta (v - alpha
+S^T k)``, which is ``S_new^T q`` written out; the second pass writes
+``alpha S + k w^T``.
+
+``causal_conv`` / ``causal_conv_step`` are the depthwise convolution over
+time ahead of the rule (width ``K``, no bias, then SiLU) for a sequence and
+for a decode batch with the ``K - 1`` inputs a slot keeps (``conv_tail``).
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+CHUNK = 64
+LANES = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ------------------------------------------------------------ convolution
+
+def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """SiLU of the causal depthwise convolution of x [S, C] over time with
+    w [K, C] (``w[K - 1]`` meets the position itself, zeros lie before the
+    sequence), computed in float32, in x's dtype."""
+    K, S = w.shape[0], x.shape[0]
+    xp = jnp.pad(x.astype(jnp.float32), ((K - 1, 0), (0, 0)))
+    y = sum(xp[i:i + S] * w[i].astype(jnp.float32) for i in range(K))
+    return jax.nn.silu(y).astype(x.dtype)
+
+
+def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
+    """The ``K - 1`` inputs before position ``length`` of x [S, C], oldest
+    first, side by side [(K - 1) * C]: what ``causal_conv_step`` needs to go
+    on from ``length`` (zeros where the sequence had not begun)."""
+    xp = jnp.pad(x, ((K - 1, 0), (0, 0)))
+    return jax.lax.dynamic_slice_in_dim(xp, length, K - 1, 0).reshape(-1)
+
+
+def causal_conv_step(x: jax.Array, w: jax.Array,
+                     tail: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One position a slot: x [B, C] after the ``tail`` [B, (K - 1) * C] of
+    ``conv_tail``.  Returns (the convolution's SiLU [B, C], the next tail)."""
+    K, C = w.shape
+    window = [tail[:, i * C:(i + 1) * C] for i in range(K - 1)] + [x]
+    y = sum(a.astype(jnp.float32) * w[i].astype(jnp.float32)
+            for i, a in enumerate(window))
+    return jax.nn.silu(y).astype(x.dtype), \
+        jnp.concatenate(window[1:], axis=-1).astype(tail.dtype)
+
+
+# ------------------------------------------------------- the folded state
+
+def _panel_plan(N: int, dv: int) -> Tuple[int, int, int]:
+    """(a panel's lanes, whole panels a head, heads side by side in a panel
+    of left-over columns; 0: none are left over)."""
+    rest = dv % LANES
+    if not rest:
+        return LANES, dv // LANES, 0
+    if dv < LANES or LANES % rest or N % (LANES // rest):
+        return dv, 1, 0                  # plain: a head's columns, one panel
+    return LANES, dv // LANES, LANES // rest
+
+
+def state_shape(N: int, dk: int, dv: int) -> Tuple[int, int, int]:
+    """A slot's folded state: [panels, dk, lanes], N * dk * dv values."""
+    W, whole, side = _panel_plan(N, dv)
+    return N * whole + (N // side if side else 0), dk, W
+
+
+def _values_to_panels(v: jax.Array, N: int, dv: int) -> jax.Array:
+    """[..., N, dv] -> [..., panels, lanes]: a head's whole panels, then the
+    left-over columns of ``side`` heads side by side."""
+    W, whole, side = _panel_plan(N, dv)
+    lead = v.shape[:-2]
+    full = v[..., :whole * W].reshape(*lead, N * whole, W)
+    if not side:
+        return full
+    return jnp.concatenate(
+        [full, v[..., whole * W:].reshape(*lead, N // side, W)], axis=-2)
+
+
+def _panels_to_values(o: jax.Array, N: int, dv: int) -> jax.Array:
+    """``_values_to_panels`` undone: [..., panels, lanes] -> [..., N, dv]."""
+    W, whole, side = _panel_plan(N, dv)
+    lead = o.shape[:-2]
+    full = o[..., :N * whole, :].reshape(*lead, N, whole * W)
+    if not side:
+        return full
+    return jnp.concatenate(
+        [full, o[..., N * whole:, :].reshape(*lead, N, W // side)], axis=-1)
+
+
+def _keys_to_panels(k: jax.Array, N: int, dv: int) -> jax.Array:
+    """[..., N, dk] -> [..., panels, dk, lanes]: each lane holds the key of
+    the head its column belongs to.  A select between broadcasts of small
+    arrays (a panel's first head, its second, ...), for the compiler to
+    fuse into what reads the state."""
+    W, whole, side = _panel_plan(N, dv)
+    full = jnp.repeat(k, whole, axis=-2) if whole > 1 else k
+    if not side:
+        return jnp.broadcast_to(full[..., None], (*full.shape, W))
+    # head ``m * side + at`` of left-over panel m holds lanes ``at * W / side``
+    # on; a whole panel is one head's throughout
+    paired = k.reshape(*k.shape[:-2], N // side, side, k.shape[-1])
+    heads = [jnp.concatenate([full, paired[..., at, :]], axis=-2)
+             for at in range(side)]
+    lane_head = jnp.arange(W) // (W // side)
+    out = jnp.broadcast_to(heads[0][..., None], (*heads[0].shape, W))
+    for at in range(1, side):
+        out = jnp.where(lane_head == at, heads[at][..., None], out)
+    return out
+
+
+def fold_state(S: jax.Array) -> jax.Array:
+    """[N, dk, dv] -> [panels, dk, lanes], the pool's layout of a slot."""
+    N, dk, dv = S.shape
+    return jnp.swapaxes(
+        _values_to_panels(jnp.swapaxes(S, 0, 1), N, dv), 0, 1)
+
+
+def unfold_state(P: jax.Array, N: int, dv: int) -> jax.Array:
+    """``fold_state`` undone."""
+    return jnp.swapaxes(
+        _panels_to_values(jnp.swapaxes(P, 0, 1), N, dv), 0, 1)
+
+
+# ------------------------------------------------------------- the rule
+
+def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """A head's vector over its length, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def gated_delta_recurrent(q, k, v, g, beta, state=None):
+    """The rule as it is written, a position at a time.  q, k [S, N, dk]
+    (k of unit length, q scaled), v [S, N, dv], g [S, N] = log alpha, beta
+    [S, N], all float32; ``state`` [N, dk, dv] or zeros.  Returns (o [S, N,
+    dv], the state after the last position)."""
+    N, dk, dv = q.shape[1], q.shape[2], v.shape[2]
+    if state is None:
+        state = jnp.zeros((N, dk, dv), jnp.float32)
+
+    def position(S, row):
+        q_t, k_t, v_t, g_t, b_t = row
+        S = S * jnp.exp(g_t)[:, None, None]
+        u = jnp.einsum("nij,ni->nj", S, k_t, precision=_HIGHEST)
+        S = S + jnp.einsum("ni,nj->nij", k_t, b_t[:, None] * (v_t - u),
+                           precision=_HIGHEST)
+        return S, jnp.einsum("nij,ni->nj", S, q_t, precision=_HIGHEST)
+
+    state, o = jax.lax.scan(position, state, (q, k, v, g, beta))
+    return o, state
+
+
+def gated_delta_chunked(q, k, v, g, beta, length=None, chunk: int = CHUNK):
+    """The rule over a whole sequence from an empty state, ``chunk``
+    positions at a time.  Arguments as ``gated_delta_recurrent``'s;
+    positions from ``length`` on (None: there are none) leave the state
+    alone.  Returns (o [S, N, dv], the state after position ``length - 1``
+    [N, dk, dv])."""
+    S_len, N, dk = q.shape
+    dv = v.shape[-1]
+    if length is not None:
+        real = (jnp.arange(S_len) < length)[:, None]
+        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    pad = -S_len % chunk
+    C = (S_len + pad) // chunk
+
+    def chunks(a):       # [S, N, ...] -> [N, C, chunk, ...]
+        a = jnp.pad(a, ((0, pad), *((0, 0),) * (a.ndim - 1)))
+        return jnp.moveaxis(a.reshape(C, chunk, *a.shape[1:]), 2, 0)
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=_HIGHEST)
+    gc = jnp.cumsum(g, axis=-1)                          # [N, C, c]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))                 # [N, C, c, c]
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    # the writes of a chunk's positions, each seeing those before it: the
+    # unit lower triangular system (I + A) W = [v beta | k beta alpha]
+    A = jnp.tril(mm("ncid,ncjd->ncij", kb, k) * decay, -1)
+    T = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(chunk), jnp.broadcast_to(jnp.eye(chunk), A.shape),
+        lower=True, unit_diagonal=True)
+    value = mm("ncij,ncjd->ncid", T, vb)
+    k_decayed = mm("ncij,ncjd->ncid", T, kb * jnp.exp(gc)[..., None])
+    within = jnp.where(lower, mm("ncid,ncjd->ncij", q, k) * decay, 0.0)
+
+    def one_chunk(S, xs):
+        q_c, k_c, value_c, kd_c, within_c, gc_c = xs     # [N, c, ...]
+        v_new = value_c - mm("nid,ndj->nij", kd_c, S)
+        o = mm("nid,ndj->nij", q_c * jnp.exp(gc_c)[..., None], S) \
+            + mm("nij,njd->nid", within_c, v_new)
+        last = gc_c[:, -1]
+        S = S * jnp.exp(last)[:, None, None] + mm(
+            "nid,nij->ndj", k_c * jnp.exp(last[:, None] - gc_c)[..., None],
+            v_new)
+        return S, o
+
+    state, o = jax.lax.scan(
+        one_chunk, jnp.zeros((N, dk, dv), jnp.float32),
+        jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0),
+                     (q, k, value, k_decayed, within, gc)))
+    # [C, N, c, dv] -> [S, N, dv]
+    o = jnp.moveaxis(o, 1, 2).reshape(C * chunk, N, dv)
+    return o[:S_len], state
+
+
+def gated_delta_step(q, k, v, g, beta, folded):
+    """One position for each slot on the folded states: q, k [B, N, dk], v
+    [B, N, dv], g, beta [B, N], ``folded`` [B, panels, dk, lanes].  Returns
+    (o [B, N, dv], the folded states after it)."""
+    N, dv = v.shape[-2], v.shape[-1]
+
+    def per_head(a):     # [B, N] -> its head's value for every column
+        return _values_to_panels(
+            jnp.broadcast_to(a[..., None], v.shape), N, dv)
+    Kp, Qp = _keys_to_panels(k, N, dv), _keys_to_panels(q, N, dv)
+    alpha, Bp = per_head(jnp.exp(g)), per_head(beta)
+    kq = per_head(jnp.sum(k * q, axis=-1))
+    r_k = jnp.sum(folded * Kp, axis=-2)                  # S^T k
+    r_q = jnp.sum(folded * Qp, axis=-2)                  # S^T q
+    w = Bp * (_values_to_panels(v, N, dv) - alpha * r_k)
+    o = alpha * r_q + kq * w
+    folded = alpha[..., None, :] * folded + Kp * w[..., None, :]
+    return _panels_to_values(o, N, dv), folded
+
+
+def decay_and_beta(a, b, A_log, dt_bias, neg_eigval: bool):
+    """The rule's two gates from their projections a, b [..., N] (float32
+    inside): g = log alpha = -exp(A_log) softplus(a + dt_bias), and beta =
+    sigmoid(b), doubled where the layer allows negative eigenvalues."""
+    g = -jnp.exp(A_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
+    beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    return g, 2.0 * beta if neg_eigval else beta

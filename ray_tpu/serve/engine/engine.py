@@ -159,6 +159,24 @@ of pools either way, so the loop, the donation, the views and the counters
 below are the same code for both kinds.  ``stats()["kv_page_kind"]`` says
 which.
 
+Either pool may be a TREE of arrays, and some of a model's arrays may hold
+**a row a decode slot** instead of pages (the record's ``slot_rows``; a
+recurrent layer's state, which does not grow with the sequence).  The loop
+hands them on, donates them and copies them as the trees they are and never
+looks inside.  What it does for such a model: the pools are made for
+``max_batch`` slots, and a prefill is told the slot its sequence will be
+stepped in, so that it leaves the rows there as they stand after the prompt's
+last real position, whatever its rung; a decode step's row ``b`` is slot
+``b``.  Nothing allocates or frees a slot's rows: the next prefill into the
+slot overwrites them whole, and the pools thread through every call, so that
+prefill is ordered after the last step that touched them.  The page
+reservation counts pages as before, which are then only some layers'.
+``stats()`` has the rows' bytes apart from the pages'
+(``recurrent_state_bytes``; ``kv_pool_bytes`` and ``kv_bytes_per_token``
+are the pages' alone), the prefills that wrote a slot's rows
+(``state_rows_written``) and the rows' share in what the decode steps read
+and wrote (``recurrent_step_bytes_share``).
+
 ``paged_attention`` (a latent model's ``paged_latent_attention`` alike)
 gathers every page of the table it is given, for every slot, once per pool
 layer: the step's rung, bounded by the longest live sequence and not by
@@ -387,6 +405,7 @@ class InferenceEngine:
 
         self.config = cfg
         self.model_config = mc
+        self._tree = jax.tree
         # positions a decode step yields a sequence: 0 is one, by one token
         self._block = served.block
         # Stored once as the two programs read them (module docstring); the
@@ -399,9 +418,15 @@ class InferenceEngine:
         # The model's kind of pages: K and V pools, or one pool of latent
         # pages and None where the V pool would be (models/llama.py).
         self._new_pools = lambda: served.new_pools(
-            cfg.num_pages, cfg.page_size, cfg.dtype)
+            cfg.num_pages, cfg.page_size, cfg.dtype, cfg.max_batch)
         self._k_pages, self._v_pages = self._new_pools()
-        self._kv_pool_bytes = sum(p.nbytes for p in self._pools())
+        # of the pools' arrays, those that hold a row a slot and not pages
+        self._slot_rows = served.slot_rows is not None
+        self._recurrent_state_bytes = sum(
+            a.nbytes for a in served.slot_rows(
+                self._k_pages, self._v_pages)) if self._slot_rows else 0
+        self._kv_pool_bytes = sum(p.nbytes for p in self._pools()) \
+            - self._recurrent_state_bytes
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
@@ -413,8 +438,9 @@ class InferenceEngine:
         # are arguments, not closed over: as constants they would be part of
         # the program and of its compile-cache key, one copy per entry point.
         # Both donate the pools (module docstring).
-        def _prefill(params, tokens, length, kp, vp, pt):
-            return served.prefill(params, mc, tokens, length, kp, vp, pt)
+        def _prefill(params, tokens, length, kp, vp, pt, slot=0):
+            return served.prefill(params, mc, tokens, length, kp, vp, pt,
+                                  slot)
 
         def _decode(params, token, pos, kp, vp, pt):
             return served.step(params, mc, token, pos, kp, vp, pt)
@@ -490,7 +516,6 @@ class InferenceEngine:
                          "error": 0}
         self._moe = {"moe_assignments": 0, "moe_experts_hit": 0,
                      "moe_load_max": 0}
-        self._tree = jax.tree
         self._block_stats = {
             "slot_steps_denoise": 0, "slot_steps_commit": 0,
             "blocks_committed": 0, "tokens_committed": 0,
@@ -501,6 +526,7 @@ class InferenceEngine:
             if self._block else None
         self._kv_live_token_steps = 0
         self._kv_gathered_token_steps = 0
+        self._state_rows_written = 0
         self._host_s = dict.fromkeys(
             ("schedule", "submit", "dispatch", "fetch", "resume", "deliver"),
             0.0)
@@ -517,8 +543,9 @@ class InferenceEngine:
             1, thread_name_prefix="rt-engine")
 
     def _pools(self) -> List[Any]:
-        """The pool arrays the engine holds: two, or a latent model's one."""
-        return [p for p in (self._k_pages, self._v_pages) if p is not None]
+        """The pool arrays the engine holds: two, a latent model's one, or
+        every array of a model whose pools are trees."""
+        return self._tree.leaves((self._k_pages, self._v_pages))
 
     def _pools_deleted(self) -> bool:
         return any(p.is_deleted() for p in self._pools())
@@ -606,6 +633,12 @@ class InferenceEngine:
         them, and ``kv_pool_in_place`` says of each program ("prefill",
         "decode"), once the loop has called it, whether that first call's
         result pools lay in its arguments' buffers (the donation was used).
+        A model with rows a decode slot (module docstring) adds
+        ``recurrent_state_bytes``, what those rows take for all slots,
+        ``state_rows_written``, the prefills that wrote a slot's, and
+        ``recurrent_step_bytes_share``: of the bytes the decode steps moved
+        so far (the weights once a step, the live positions' pages, the live
+        slots' rows read and written), the rows' share.
         ``kv_live_token_steps`` sums over decode steps the positions the
         live sequences held (``pos + 1`` each), ``kv_gathered_token_steps``
         the positions the step's paged read gathered per pool layer
@@ -671,12 +704,29 @@ class InferenceEngine:
                 "kv_live_token_steps": self._kv_live_token_steps,
                 "kv_gathered_token_steps": self._kv_gathered_token_steps,
                 "kv_pool_in_place": dict(self._kv_in_place),
+                **self._recurrent_stats(),
                 "device": self._device,
                 "first_call_s": dict(self._first_call_s),
                 "host_s": dict(self._host_s),
                 "host_cpu_s": dict(self._host_cpu_s),
                 "host_cpu_calls": self._cpu_sampled,
                 "gc": tracing.gc_stats()}
+
+    def _recurrent_stats(self) -> Dict[str, Any]:
+        """``stats()`` of a model with rows a slot (none of another)."""
+        if not self._slot_rows:
+            return {}
+        # a live slot's rows are read and written once a step
+        rows = 2 * self._recurrent_state_bytes // self.config.max_batch \
+            * self._slot_steps
+        pages = self._kv_pool_bytes // (
+            self.config.num_pages * self.config.page_size) \
+            * self._kv_live_token_steps
+        step_bytes = self._weight_bytes * self._steps + pages + rows
+        return {"recurrent_state_bytes": self._recurrent_state_bytes,
+                "state_rows_written": self._state_rows_written,
+                "recurrent_step_bytes_share":
+                    rows / step_bytes if step_bytes else 0.0}
 
     def close(self):
         if self._loop_task is not None:
@@ -692,10 +742,11 @@ class InferenceEngine:
     # among them) are left alive and as they were.
 
     @staticmethod
-    def _on_copies(step, params, a, b, kp, vp, pt):
+    def _on_copies(step, params, a, b, kp, vp, pt, *slot):
         import jax
         import jax.numpy as jnp
-        return step(params, a, b, *jax.tree.map(jnp.copy, (kp, vp)), pt)
+        return step(params, a, b, *jax.tree.map(jnp.copy, (kp, vp)), pt,
+                    *slot)
 
     def _prefill_program(self, *args):
         return self._on_copies(self._prefill_donating, *args)
@@ -708,10 +759,10 @@ class InferenceEngine:
     # are replaced by what comes out.  An expert model's fourth result
     # stays behind.
 
-    def _consuming(self, step, params, a, b, kp, vp, pt):
+    def _consuming(self, step, params, a, b, kp, vp, pt, *slot):
         own = kp is self._k_pages and vp is self._v_pages
         try:
-            logits, kp, vp = step(params, a, b, kp, vp, pt)[:3]
+            logits, kp, vp = step(params, a, b, kp, vp, pt, *slot)[:3]
         except Exception:
             if own and self._pools_deleted():
                 self._k_pages, self._v_pages = self._new_pools()
@@ -743,7 +794,8 @@ class InferenceEngine:
             f"prefill@{rung}", self._prefill_donating, params,
             jax.ShapeDtypeStruct((1, rung), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32), kp, vp,
-            jax.ShapeDtypeStruct((1, self._maxp), jnp.int32))
+            jax.ShapeDtypeStruct((1, self._maxp), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
 
     def _compile_decode_rung(self, width: int, params, kp, vp):
         """``_decode_next_donating`` compiled for a page table of
@@ -761,18 +813,18 @@ class InferenceEngine:
             slots, kp, vp,
             jax.ShapeDtypeStruct((self.config.max_batch, width), jnp.int32))
 
-    def _donate_pools(self, program: str, step, a, b, pt):
+    def _donate_pools(self, program: str, step, a, b, pt, *slot):
         """One call of ``step`` (a donating program) on the engine's pools,
         on the exec lane: its results, the pools among them for the loop to
         take.  The first call of each program notes whether they came back
         in the buffers that went in."""
         kp, vp = self._k_pages, self._v_pages
         if program in self._kv_in_place:
-            return step(self._params, a, b, kp, vp, pt)
+            return step(self._params, a, b, kp, vp, pt, *slot)
         before = [p.unsafe_buffer_pointer() for p in self._pools()]
-        out = step(self._params, a, b, kp, vp, pt)
+        out = step(self._params, a, b, kp, vp, pt, *slot)
         self._kv_in_place[program] = before == [
-            p.unsafe_buffer_pointer() for p in out[1:3] if p is not None]
+            p.unsafe_buffer_pointer() for p in self._tree.leaves(out[1:3])]
         return out
 
     def _ensure_loop(self):
@@ -1272,7 +1324,8 @@ class InferenceEngine:
                                         start.wall - submitted.wall)):
                             logits, kp, vp, *load = self._donate_pools(
                                 "prefill", program, toks,
-                                np.int32(whole), seq.row[None])
+                                np.int32(whole), seq.row[None],
+                                np.int32(seq.slot))
                             tok = None if self._block \
                                 else int(jnp.argmax(logits[0]))
                             load = [np.asarray(a) for a in load]
@@ -1281,6 +1334,7 @@ class InferenceEngine:
                     tok, self._k_pages, self._v_pages, load, lane = \
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
+                    self._state_rows_written += self._slot_rows
                     self._count_moe("prefill", load)
                     self._deliver([] if tok is None else [(seq, tok)],
                                   submitted, lane)

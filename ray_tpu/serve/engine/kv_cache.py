@@ -14,6 +14,12 @@ mid-decode OOM structurally impossible — a sequence that fits at
 admission always finishes.  That trades utilization for the property the
 continuous-batching loop leans on: retire is the only page-freeing
 event, so the loop never has to preempt.
+
+Pages are counted per position whatever the number of layers that keep them:
+a model some of whose layers keep a row a decode SLOT instead (a recurrent
+state; ``models/serving.py``'s ``slot_rows``) reserves the same pages, which
+are then only its other layers'.  A slot's rows need no free list: the slot
+is the allocation, and the next prefill into it overwrites them whole.
 """
 
 from __future__ import annotations

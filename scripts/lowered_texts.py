@@ -1,6 +1,7 @@
 """Whether two trees lower to the same programs: one ``sha256  configuration
 program@rung`` line for every program the engine compiles for the benchmark's
-serving configurations, and for a tiny llama train step.
+serving configurations, for the program that makes each one's weights
+(``init``), and for a tiny llama train step.
 
     JAX_PLATFORMS=cpu python scripts/lowered_texts.py [--tree DIR]
         [--only mistral xing ...] [--texts OUT_DIR]
@@ -25,8 +26,10 @@ The programs are the engine's own two (``serve/engine/engine.py``'s
 ``_prefill`` at every rung of ``prefill_rungs`` and ``_decode_next``, the
 model's step and what feeds the next one, at every width of
 ``decode_rungs``), rebuilt here from ``ray_tpu.models.serving_model``'s
-record, as the engine builds them; a tree from before PR 47 has no record and
-is read through the functions the record names.
+record, as the engine builds them (the prefill told its slot, the pools made
+for the engine's slots; an argument a program does not use is no part of its
+lowered text); a tree from before PR 47 has no record and is read through the
+functions the record names, one from before PR 48 takes neither.
 """
 
 from __future__ import annotations
@@ -34,6 +37,7 @@ from __future__ import annotations
 import argparse
 import glob
 import hashlib
+import inspect
 import json
 import os
 import sys
@@ -64,8 +68,12 @@ def _programs(model: str, cfg):
         served = serving_model(model, cfg)
         prefill_fn, step_fn, feed = served.prefill, served.step, served.feed
 
-    def _prefill(params, tokens, length, kp, vp, pt):
-        return prefill_fn(params, cfg, tokens, length, kp, vp, pt)
+    # (a tree from before PR 48 tells no prefill its slot)
+    takes_slot = "slot" in inspect.signature(prefill_fn).parameters
+
+    def _prefill(params, tokens, length, kp, vp, pt, slot=0):
+        return prefill_fn(params, cfg, tokens, length, kp, vp, pt,
+                          *((slot,) if takes_slot else ()))
 
     def _decode(params, token, pos, kp, vp, pt):
         logits, *rest = step_fn(params, cfg, token, pos, kp, vp, pt)
@@ -102,12 +110,20 @@ def _serving(name: str, device):
 
     params = on(jax.eval_shape(lambda: llama_serving_params(
         family.init(jax.random.PRNGKey(0), cfg), cfg)))
+    # a model with a row a decode slot sizes its pools by the slots too
+    slots = (batch,) if "slots" in inspect.signature(
+        llama_init_paged_cache).parameters else ()
     kp, vp = on(jax.eval_shape(lambda: llama_init_paged_cache(
-        cfg, eng["num_pages"], page, eng.get("dtype"))))
+        cfg, eng["num_pages"], page, eng.get("dtype"), *slots)))
+    # the replica's first program: the family's weights from the seed (a
+    # text that moves costs every cell of the family one cold compile)
+    yield "init", jax.jit(lambda key: family.init(key, cfg)).lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)).as_text()
     prefill, decode = _programs(family.ENGINE_MODEL, cfg)
     for rung in prefill_rungs(eng["max_prompt_len"], page):
         yield f"prefill@{rung}", jax.jit(prefill, donate_argnums=POOLS).lower(
-            params, arg((1, rung)), arg(()), kp, vp, arg((1, maxp))).as_text()
+            params, arg((1, rung)), arg(()), kp, vp, arg((1, maxp)),
+            arg(())).as_text()
     if cfg.block_length:             # a block's state and the slots' ends
         rows = (batch, cfg.block_length)
         token = (arg(rows), arg(rows, jnp.bool_), arg((batch,)),
