@@ -527,6 +527,15 @@ def gpt_decode_step(params: Dict[str, Any], cfg: GPTConfig,
     return logits, k_pages, v_pages
 
 
+def gpt_paged_read(cfg: GPTConfig, k_pages) -> str:
+    """What ``gpt_decode_step`` reads the pages with, "kernel" or "gather"
+    (``ops/paged_attention.py::paged_read_kind`` of its queries and the
+    pool; GPT-2's heads of 64 are no whole lane tile: the gather)."""
+    from ray_tpu.ops.paged_attention import paged_read_kind
+    return paged_read_kind(jax.ShapeDtypeStruct(
+        (1, cfg.num_heads, cfg.head_dim), cfg.dtype), k_pages)
+
+
 def served(config: Optional[GPTConfig] = None, seq: int = 0):
     """The serving engine's record of this model (``models/serving.py``)."""
     from ray_tpu.models.serving import ServedModel, greedy
@@ -535,7 +544,8 @@ def served(config: Optional[GPTConfig] = None, seq: int = 0):
         config=cfg, init=gpt_init, stored=gpt_serving_params,
         new_pools=functools.partial(init_paged_cache, cfg),
         prefill=gpt_prefill, step=gpt_decode_step,
-        prefill_attention=lambda cfg, rung: "dense", block=0, feed=greedy)
+        prefill_attention=lambda cfg, rung: "dense",
+        paged_read=gpt_paged_read, block=0, feed=greedy)
 
 
 def gpt_loss(params, batch: Dict[str, jax.Array], cfg: GPTConfig,

@@ -1322,6 +1322,18 @@ def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
     return "flash"
 
 
+def llama_paged_read(cfg: LlamaConfig, k_pages) -> str:
+    """What a step's programs read the pages with, "kernel" or "gather":
+    the token step's K/V pages as ``paged_read_kind`` says of its queries
+    and the pool; a block model's read (``paged_block_attention``) and a
+    latent one's (``paged_latent_attention``) are gathers."""
+    from ray_tpu.ops.paged_attention import paged_read_kind
+    if cfg.block_length or cfg.kv_lora_rank:
+        return "gather"
+    return paged_read_kind(jax.ShapeDtypeStruct(
+        (1, cfg.num_heads, cfg.head_dim), cfg.dtype), k_pages)
+
+
 def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
                   state: AttentionState, live, *pools):
     """Every pass over every layer of a served program, ``pools`` carried
@@ -1516,7 +1528,8 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         if cfg.layer_pattern else None,
         prefill=llama_prefill,
         step=llama_block_step if cfg.block_length else llama_decode_step,
-        prefill_attention=llama_prefill_attention, block=cfg.block_length,
+        prefill_attention=llama_prefill_attention,
+        paged_read=llama_paged_read, block=cfg.block_length,
         feed=(lambda cfg, logits, state, end: (
             None, block_unmask(cfg, logits, state, end)))
         if cfg.block_length else greedy)

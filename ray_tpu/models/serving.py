@@ -36,6 +36,9 @@ class ServedModel(NamedTuple):
     #                              pos, ...) likewise; a block model's takes
     #                              the blocks' state and ends for token, pos
     prefill_attention: Callable  # (config, rung) -> "flash" | "dense"
+    paged_read: Callable         # (config, k_pages) -> "kernel" |
+    #                              "gather": what the step's programs read
+    #                              the pages with (``ops/paged_attention.py``)
     block: int                   # positions a step yields a sequence; 0:
     #                              one, by one token
     feed: Callable               # (config, logits, token, pos) -> (the

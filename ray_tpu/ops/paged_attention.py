@@ -63,26 +63,36 @@ A model that generates by diffusion over blocks (``models/llama.py`` with
 writes a block's rows and ``paged_block_attention`` reads for its ``B``
 query rows at once; the pages are the K/V kind above, no third kind.
 
-This file is the jnp reference implementation (gather + masked softmax
-— the decode working set is one token per sequence, so XLA's fused
-gather is adequate on CPU and fine on TPU at small batch; a Pallas
-HBM-resident kernel like flash_attention.py's is the upgrade path when
-pools outgrow VMEM).  It is exact: given identical page contents it
-reproduces dense attention bit-for-bit in f32, which is what the
-paged-vs-dense CPU equivalence tests assert.
+This file is the jnp implementation (gather + masked softmax) of every
+read, and for the token step's read of K/V pages also the selector:
+``paged_attention`` runs as ``ops/paged_read.py``'s Pallas kernel where
+``paged_read_kind`` says so, from what it can observe: the backend is not
+the CPU, a head is a whole number of 128-lane tiles, a page's rows are whole
+sublane tiles of the pools' type (16 for bfloat16: every served
+configuration; the engine's default page of 8 in the CPU tests is not), and
+the queries are of the pools' type.  The kernel leaves the pools in HBM,
+copies for each sequence only the pages its own length reaches, each where
+it lies, and reads a head as a run of lanes of the copied rows: no gathered
+``[B, W x page, NKV*H]`` in HBM, no relayout of it by heads, no page of the
+rung that the sequence does not hold.  The gather is exact: given identical
+page contents it reproduces dense attention bit-for-bit in f32, which is
+what the paged-vs-dense CPU equivalence tests assert; it is the kernel's
+reference (``tests/test_paged_attention_kernel.py``) and what every other
+case runs: GPT-2's heads of 64, the CPU, and the two reads below that the
+kernel is not written for yet.
 
-Both reads gather every column of the ``page_table`` they are given, for
-every sequence, and mask by ``lengths``: the table's width, not the lengths,
-sets their cost.  The caller chooses it: the serving engine hands a step the
-first ``W`` columns of its rows, ``W`` the least of a few compiled widths
-that holds the batch's longest sequence (``serve/engine/engine.py``,
-``decode_rungs``); the page of every ``pos`` a step appends at and of every
-position under ``lengths`` has to lie inside the table.  The columns left
-out held positions whose probability is exactly 0, so a narrower table gives
-a wider one's result up to the order of a shorter sum.  The bound is the
-longest sequence of the batch, not each sequence's own: that, and reading the
-rows where they lie with no relayout, is what a kernel that walks the page
-table would add.
+``paged_block_attention`` and ``paged_latent_attention`` (and the token
+step's read where the kernel is not picked) gather every column of the
+``page_table`` they are given, for every sequence, and mask by ``lengths``:
+the table's width, not the lengths, sets their cost.  The caller chooses
+it: the serving engine hands a step the first ``W`` columns of its rows,
+``W`` the least of a few compiled widths that holds the batch's longest
+sequence (``serve/engine/engine.py``, ``decode_rungs``); the page of every
+``pos`` a step appends at and of every position under ``lengths`` has to lie
+inside the table.  The columns left out held positions whose probability is
+exactly 0, so a narrower table gives a wider one's result up to the order of
+a shorter sum.  Under the kernel the width bounds only the scalars it
+prefetches: each sequence reads as far as its own length.
 """
 
 from __future__ import annotations
@@ -92,6 +102,28 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops import paged_read
+
+
+def _kernel_backend() -> bool:
+    """Whether programs are being made for a backend the kernel is compiled
+    for: anything but the CPU, where it would run in the interpreter."""
+    return jax.default_backend() != "cpu"
+
+
+def paged_read_kind(q, k_pages) -> str:
+    """What ``paged_attention`` reads these pools for these queries with,
+    "kernel" or "gather", from what it can observe of them (arrays or their
+    shapes): the backend, and whether the kernel is written for the operands
+    (``paged_read.supported``: heads of whole 128-lane tiles, pages of whole
+    sublane tiles, one type).  Measured on the chip at the five served
+    shapes (``scripts/paged_read_sweep.py``; PERF.md section 6, PR 49); a
+    shape that reads slower through the kernel is named here."""
+    if _kernel_backend() and paged_read.supported(
+            q.shape, q.dtype, k_pages.shape, k_pages.dtype):
+        return "kernel"
+    return "gather"
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -112,6 +144,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         raise ValueError(f"query heads {N} not a multiple of KV heads {NKV}")
     rep = N // NKV
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(H)
+    if paged_read_kind(q, k_pages) == "kernel":
+        with jax.named_scope("paged_read"):
+            return paged_read.paged_read_attention(
+                q, k_pages, v_pages, layer, lengths, page_table,
+                sm_scale=scale)
     maxp = page_table.shape[1]
     S = maxp * page
 

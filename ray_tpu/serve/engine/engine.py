@@ -177,16 +177,24 @@ are the pages' alone), the prefills that wrote a slot's rows
 (``state_rows_written``) and the rows' share in what the decode steps read
 and wrote (``recurrent_step_bytes_share``).
 
-``paged_attention`` (a latent model's ``paged_latent_attention`` alike)
-gathers every page of the table it is given, for every slot, once per pool
+What a decode step's paged read FETCHES depends on what its program was
+compiled with (the record's ``paged_read``, asked once at construction:
+``ops/paged_attention.py::paged_read_kind``).  "gather" (a block model's
+and a latent model's read, GPT-2's heads of 64, every model on the CPU):
+every page of the table the step is given, for every slot, once per pool
 layer: the step's rung, bounded by the longest live sequence and not by
-each.  ``stats()`` counts both sides of that:
-``kv_live_token_steps`` (positions the live sequences held, summed over
-decode steps) against ``kv_gathered_token_steps`` (``max_batch x W x
-page_size`` a step, ``W`` the step's rung), with ``kv_bytes_per_token`` and
-``kv_pool_layers`` to turn either into bytes, and ``decode_shapes``, the
-steps by rung; each ``rt:engine.decode.dispatch`` carries its step's numbers
-as ``live_tokens``, ``gathered_tokens`` and ``width_pages``.
+each.  "kernel" (the token step's K/V pages on the chip): each slot's own
+pages as far as its position, a parked slot's one page of page 0; the rung
+then bounds only what the kernel is told, not what it reads.  ``stats()``
+counts both sides: ``kv_live_token_steps`` (positions the live sequences
+held, summed over decode steps) against ``kv_gathered_token_steps`` (what
+the steps fetched: ``max_batch x W x page_size`` a step under the gather,
+``W`` the step's rung; every slot's positions rounded up to whole pages
+under the kernel), with ``kv_bytes_per_token`` and ``kv_pool_layers`` to
+turn either into bytes, ``decode_shapes``, the steps by rung, and
+``decode["paged_read"]``, the steps by what read their pages; each
+``rt:engine.decode.dispatch`` carries its step's numbers as ``live_tokens``,
+``gathered_tokens``, ``width_pages`` and ``paged_read``.
 
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
@@ -480,6 +488,9 @@ class InferenceEngine:
         self._rung_attention = {rung: served.prefill_attention(mc, rung)
                                 for rung in self._rungs}
         self._decode_rungs = decode_rungs(self._maxp)
+        # what the decode programs read the pages with ("kernel": each
+        # sequence's own pages copied where they lie; "gather")
+        self._paged_read = served.paged_read(mc, self._k_pages)
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self._params, self._k_pages, self._v_pages))
@@ -496,6 +507,7 @@ class InferenceEngine:
         self._prefill_shapes = dict.fromkeys(self._rungs, 0)
         self._prefill_attention = {"dense": 0, "flash": 0}
         self._decode_shapes = dict.fromkeys(self._decode_rungs, 0)
+        self._decode_paged_read = {"gather": 0, "kernel": 0}
 
         self._waiting: collections.deque = collections.deque()
         self._active: Dict[int, _Sequence] = {}   # slot -> sequence
@@ -617,6 +629,8 @@ class InferenceEngine:
         attention ran as ("flash": the kernel, "dense"),
         ``decode_shapes``, the decode steps by the width of their page table
         in pages (a rung of ``decode_rungs``; they sum to ``steps``),
+        ``decode["paged_read"]``, the decode steps by what their program
+        reads the pages with ("kernel", "gather"),
         ``retired`` sequences by reason, and of a model with experts the
         ``moe_assignments`` of real tokens (token x layer x k), the
         ``moe_experts_hit`` (distinct experts a step touched, summed over
@@ -641,9 +655,11 @@ class InferenceEngine:
         slots' rows read and written), the rows' share.
         ``kv_live_token_steps`` sums over decode steps the positions the
         live sequences held (``pos + 1`` each), ``kv_gathered_token_steps``
-        the positions the step's paged read gathered per pool layer
-        (``max_batch x W x page_size``, ``W`` the step's rung): their ratio
-        is the share of the gather that was of use.  ``first_call_s`` says
+        the positions the step's paged read fetched per pool layer
+        (``max_batch x W x page_size``, ``W`` the step's rung, under the
+        gather; every slot's positions in whole pages under the kernel:
+        ``decode["paged_read"]`` counts the steps by which): their ratio
+        is the share of the read that was of use.  ``first_call_s`` says
         how long each program took to be there: ``prefill@<rung>`` and
         ``decode@<pages>`` that rung's trace and compile (or load from the
         compile cache) at construction, beside the other rungs'.
@@ -688,6 +704,7 @@ class InferenceEngine:
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "prefill_shapes": dict(self._prefill_shapes),
                 "prefill": {"attention": dict(self._prefill_attention)},
+                "decode": {"paged_read": dict(self._decode_paged_read)},
                 "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
                 **({"block": {**self._block_stats, "denoise_passes_by_count":
@@ -1171,8 +1188,12 @@ class InferenceEngine:
         width = tables.shape[1]
         program = self._decode_programs[width].result()
         active = len(stepped)
-        # what the step's paged read gathers
-        gathered_tokens = tables.size * cfg.page_size
+        # what the step's paged read fetches: the table whole, or under the
+        # kernel every slot's own pages (a parked slot's one of page 0)
+        paged_read = self._paged_read
+        gathered_tokens = cfg.page_size * (
+            int((pos // cfg.page_size).sum()) + cfg.max_batch
+            if paged_read == "kernel" else tables.size)
         blocks = {"block_len": self._block} if self._block else {}
         submitted, sampled = self._submit(cpu=True)
         # everything the step before cost the loop: its delivery,
@@ -1187,7 +1208,8 @@ class InferenceEngine:
             with region("engine.decode.dispatch", active=active,
                         live_tokens=live_tokens,
                         gathered_tokens=gathered_tokens,
-                        width_pages=width, ahead=int(prev is not None),
+                        width_pages=width, paged_read=paged_read,
+                        ahead=int(prev is not None),
                         submit_us=_us(start.wall - submitted.wall),
                         step_us=_us(step_s),
                         step_loop_cpu_us=_us(step_loop_cpu_s), **blocks):
@@ -1208,6 +1230,7 @@ class InferenceEngine:
         self._steps += 1
         self._decode_ahead_steps += prev is not None
         self._decode_shapes[width] += 1
+        self._decode_paged_read[paged_read] += 1
         self._slot_steps += active
         self._kv_live_token_steps += live_tokens
         self._kv_gathered_token_steps += gathered_tokens

@@ -105,11 +105,21 @@ STALE_SNAPSHOTS = {
         "lists chat's per-layer metrics as PR 44 left them; PR 46 added "
         "prefill_flash_share.chat.  Its assertions run, with that name, as "
         "test_prefill_flash_share.py::test_chats_layers_still_move_the_mean",
+    "test_prefill_flash_share.py::test_chats_layers_still_move_the_mean":
+        "lists chat's per-layer metrics as PR 46 left them; PR 49 added "
+        "paged_kernel_share.  Its assertions run, with that name, as "
+        "test_paged_read_metrics.py::test_chats_layers_still_move_the_mean",
     "test_spec_sdar.py::test_what_the_cell_adds_to_the_lists":
         "counts the benchmark's cells (8) and configurations (7) and takes "
         "the lists' last entries as PR 40 left them; PR 48 added one of "
         "each.  Its other assertions run as test_spec_olmo_hybrid.py::"
         "test_what_the_sdar_cell_added_still_stands",
+    "test_spec_olmo_hybrid.py::test_what_the_cell_adds_to_the_lists":
+        "counts the hybrid cell's per-layer entries (13) and takes the "
+        "list's last entries as PR 48 left them; PR 49 added two to the "
+        "cell and two to others.  Its other assertions run as "
+        "test_paged_read_metrics.py::"
+        "test_what_the_hybrid_cell_added_still_stands",
 }
 
 

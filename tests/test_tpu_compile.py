@@ -36,6 +36,8 @@ from ray_tpu.parallel.sharding import logical_sharding
 # the module: ray_tpu.ops re-exports the function under the same name
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
 gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
+pa = importlib.import_module("ray_tpu.ops.paged_attention")
+pr = importlib.import_module("ray_tpu.ops.paged_read")
 
 HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
 
@@ -91,6 +93,32 @@ def compiled_experts(monkeypatch):
     real = gm._resolve
     monkeypatch.setattr(gm, "_resolve",
                         lambda *a: real(*a[:-1], False))
+
+
+@pytest.fixture
+def compiled_paged_read(monkeypatch):
+    """``paged_attention`` asks the backend whether to read the pages
+    through ``ops/paged_read.py``'s kernel, and the kernel whether to run in
+    the interpreter; make both answer as they do on the chip."""
+    monkeypatch.setattr(pa, "_kernel_backend", lambda: True)
+    real = pr._resolve
+    monkeypatch.setattr(pr, "_resolve", lambda *a: real(*a[:-1], False))
+
+
+def _paged_read_kernels(text: str, pool: str) -> list:
+    """The program's instructions that are the Pallas kernel ``paged_read``
+    under the scope of that name (what the benchmark's readers find the
+    read's device time by), each held to take two pools of the whole shape
+    ``pool`` as operands: every layer's pages as they are stored."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r'op_name="[^"]*[/(]paged_read[/)]', line)]
+    for call in calls:
+        assert re.match(r"\s*%paged_read[\w.]* = ", call), call[:80]
+        operands = call[call.index("operand_layout_constraints="):
+                        call.index("backend_config=")]
+        assert operands.count(pool + "{") == 2, operands
+    return calls
 
 
 def _compile(fn, *args, donate=()):
@@ -361,7 +389,8 @@ def _llama_engine_program(topo, cfg, program, prompt, new, num_pages,
     that of the top rung of the decode ladder (a page table of ``maxp``
     columns) or, as ``decode@narrow``, of its narrowest, which is also held
     to need no more room than the top rung's."""
-    key = (repr(cfg), program, prompt, new, num_pages, max_batch, rung)
+    key = (repr(cfg), program, prompt, new, num_pages, max_batch, rung,
+           pa._kernel_backend())
     if key in _COMPILED:
         return _COMPILED[key]
     from ray_tpu.models.llama import (llama_decode_step, llama_init,
@@ -480,6 +509,39 @@ def test_mistral_prefill_runs_the_flash_kernel_on_its_long_rungs(
         jax.jit(lambda *a: _flash(*a, "bnsh")).lower(q, kv, kv), b"flash_fwd")
 
 
+def test_mistral_decode_reads_the_pages_through_the_kernel(
+        topo, compiled_paged_read):
+    """On the chip the token step reads K/V through the kernel that walks
+    the page table: one kernel in the layer scan, the pools its operands as
+    they are stored and still updated in place beside it
+    (``_pools_in_place``, inside ``_mistral_engine_program``), and nothing
+    gathered: the gather's [16 x 160 pages, 16, 1024] rows of K and of V
+    were the step's temporaries."""
+    _, compiled, text = _mistral_engine_program(topo, "decode")
+    assert len(_paged_read_kernels(text, "bf16[8,2561,16,1024]")) == 1
+    assert not re.search(r"bf16\[2560,16,1024\]", text)
+    _fits(compiled)
+
+
+def _gathering(monkeypatch, program):
+    """``program()`` with the token step reading through the gather."""
+    with monkeypatch.context() as patched:
+        patched.setattr(pa, "_kernel_backend", lambda: False)
+        return program()
+
+
+def test_mistral_decode_by_the_kernel_needs_less_room(
+        topo, compiled_paged_read, monkeypatch):
+    _, compiled, _ = _mistral_engine_program(topo, "decode")
+    _, gather, text = _gathering(
+        monkeypatch, lambda: _mistral_engine_program(topo, "decode"))
+    assert re.search(r"bf16\[2560,16,1024\]", text)
+    assert _paged_read_kernels(text, "bf16[8,2561,16,1024]") == []
+    saved = gather.memory_analysis().temp_size_in_bytes \
+        - compiled.memory_analysis().temp_size_in_bytes
+    assert saved >= 2560 * 16 * 1024 * 2       # the rows' buffer (K's, then V's)
+
+
 # OLMoE-1B-7B-0125-Instruct at its published widths, 4 of its 16 layers, with
 # the engine of benchmark/configs/olmoe-1b-7b-0125-4l.json: the dropless
 # expert path's grouped matmuls have to be ``ops/grouped_matmul.py``'s kernel
@@ -492,17 +554,21 @@ OLMOE_PROMPT, OLMOE_NEW = 512, 1024
 OLMOE_BUDGET = 8 * 1024 ** 3
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
-def test_olmoe_engine_program_compiles(topo, compiled_experts, program):
+def _olmoe_engine_program(topo, program):
     from ray_tpu.models.llama import LlamaConfig
     cfg = LlamaConfig(vocab_size=50304, num_layers=4, num_heads=16,
                       num_kv_heads=16, embed_dim=2048, mlp_dim=1024,
                       rope_theta=10000.0, rms_eps=1e-5, num_experts=64,
                       experts_per_token=8, qk_norm=True,
                       max_seq_len=OLMOE_PROMPT + OLMOE_NEW)
-    params, compiled, text = _llama_engine_program(
+    return _llama_engine_program(
         topo, cfg, program, OLMOE_PROMPT, OLMOE_NEW,
         MAX_BATCH * (OLMOE_PROMPT + OLMOE_NEW) // PAGE + 1)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "decode@narrow"])
+def test_olmoe_engine_program_compiles(topo, compiled_experts, program):
+    params, compiled, text = _olmoe_engine_program(topo, program)
     assert params["layers"]["mlp"]["wgu"].dtype == jnp.float32
     if program != "prefill":
         assert _scoped(text, "paged_read")
@@ -516,6 +582,23 @@ def test_olmoe_engine_program_compiles(topo, compiled_experts, program):
     assert "ragged-dot" not in text
     assert "bf16[4,64," not in text
     assert _fits(compiled) < OLMOE_BUDGET
+
+
+def test_olmoe_decode_by_the_kernel_copies_no_gathered_heads(
+        topo, compiled_experts, compiled_paged_read, monkeypatch):
+    """The gather's rows are copied head by head for the two einsums
+    (``bf16[.., 8, 16, 128]``: 16 heads as two sublane tiles, twice a
+    layer); the kernel reads a head as a run of lanes and copies nothing."""
+    def decode():
+        return _olmoe_engine_program(topo, "decode")
+    heads = r"= bf16\[\d{3,},8,16,128\]\S* (copy|fusion)\("
+    _, compiled, text = decode()
+    assert len(_paged_read_kernels(text, "bf16[4,1537,16,2048]")) == 1
+    assert not re.search(heads, text)
+    _, gather, text = _gathering(monkeypatch, decode)
+    assert re.search(heads, text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < gather.memory_analysis().temp_size_in_bytes
 
 
 def test_grouped_matmul_module_names_no_source(topo):
@@ -535,6 +618,27 @@ def test_grouped_matmul_module_names_no_source(topo):
             shape((4 * 128, 2048, 1024), jnp.float32),
             shape((128,), jnp.int32), shape((), jnp.int32)),
         b"grouped_matmul")
+
+
+def test_paged_read_module_names_no_source(topo):
+    """As ``test_grouped_matmul_module_names_no_source``, for the kernel
+    every decode rung of four served configurations holds: jnp functions
+    traced here first (their jitted bodies are cached with the source
+    locations of whoever traced them first) carry nothing into it."""
+    one = SingleDeviceSharding(topo.devices[0])
+    at = jnp.arange(8)
+    _ = at // 3, jnp.clip(at, 1, 3), jnp.where(at > 2, at, 0)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    pool = shape((3, 4609, 16, 3840), jnp.bfloat16)
+    _names_no_source(
+        jax.jit(lambda *a: pr.paged_read_attention(
+            *a, sm_scale=128 ** -0.5, interpret=False)).lower(
+            shape((48, 30, 128), jnp.bfloat16), pool, pool,
+            shape((), jnp.int32), shape((48,), jnp.int32),
+            shape((48, 96), jnp.int32)),
+        b"paged_read")
 
 
 # Ouro-2.6B whole: published widths, all 48 layers run four times, with the

@@ -12,7 +12,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from test_tpu_compile import _fits, no_persistent_cache, topo  # noqa: F401
+from test_tpu_compile import (_fits, _paged_read_kernels,  # noqa: F401
+                              compiled_paged_read, no_persistent_cache, pa,
+                              topo)
 
 GIB = 1024 ** 3
 
@@ -84,3 +86,32 @@ def test_the_hybrid_program_fits_and_copies_no_stack(topo, cell, program):
     assert made == []
     # the states are float32 and folded: 45 panels of 128 lanes, 96 deep
     assert "f32[9,48,45,96,128]" in text
+
+
+def test_the_hybrid_decode_reads_the_pages_through_the_kernel(
+        topo, cell, compiled_paged_read, monkeypatch):
+    """On the chip the three full layers read K/V through the kernel that
+    walks the page table, at the decode ladder's top rung here: one kernel
+    in the full layers' scan, both pools its operands as they are stored
+    and held once (aliased to the results, no operation of a pool's size
+    but the appends in place), and the gather's two costliest operations
+    gone with it: the rows gathered for every slot of the rung and their
+    relayout by heads, 30 K/V heads being no whole tile of 8 sublanes."""
+    _, pools, exe = compiled(topo, cell, "decode")
+    text = exe.as_text()
+    assert len(_paged_read_kernels(text, "bf16[3,4609,16,3840]")) == 1
+    gathered = r"bf16\[(48,1536,30,128|4608,16,3840)\]"
+    assert not re.search(gathered, text)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    memory = exe.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    # nothing makes an array of a pool's size but the appends in place
+    made = [line.strip()[:120] for line in text.splitlines() if re.search(
+        r"= bf16\[3,4609,16,3840\]\S* (?!parameter|get-tuple-element|"
+        r"bitcast|scatter|fusion)", line)]
+    assert made == []
+    monkeypatch.setattr(pa, "_kernel_backend", lambda: False)
+    _, _, gather = compiled(topo, cell, "decode")
+    assert re.search(gathered, gather.as_text())
+    assert memory.temp_size_in_bytes \
+        < gather.memory_analysis().temp_size_in_bytes / 4
