@@ -54,7 +54,8 @@ query goes through a normed bottleneck, keys and values are expanded per head
 from ONE normed ``kv_lora_rank``-wide vector a position, and the positions
 come from a ``qk_rope_dim``-wide key all heads share (YaRN tables with
 ``rope_yarn``).  Served, a position's cache is that vector and that key, one
-page pool ``[L, P, page * (kv_lora_rank + qk_rope_dim)]`` and no V pool
+page pool ``[L, P, page, Wp]`` (``Wp`` = ``kv_lora_rank + qk_rope_dim`` in
+whole 128-lane tiles, zeros past the values) and no V pool
 (``ops/paged_attention.py``'s latent kind): the prefill expands keys and
 values for its dense attention, the decode step absorbs ``wkv_b`` into the
 query and the output and reads the pool as it lies.  With
@@ -734,14 +735,11 @@ def _mla_absorbed(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer,
     wkv_b = p["attn"]["wkv_b"].astype(dt)
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum("bnh,cnh->bnc", q_nope, wkv_b[..., :dn])
-    o_rows = paged_latent_attention(
+    o_lat = paged_latent_attention(
         jnp.concatenate([q_lat, q_rope], axis=-1), pages, layer, lengths,
-        page_table, sm_scale=mla_softmax_scale(cfg))
+        page_table, sm_scale=mla_softmax_scale(cfg), rank=cfg.kv_lora_rank)
     with jax.named_scope("mla_absorb"):
-        # the attention comes back over whole cached rows: the value half
-        # meets the rotated key's columns with rows of zeros
-        wv = jnp.pad(wkv_b[..., dn:], ((0, cfg.qk_rope_dim), (0, 0), (0, 0)))
-        return jnp.einsum("bnw,wnh->bnh", o_rows, wv)
+        return jnp.einsum("bnc,cnh->bnh", o_lat, wkv_b[..., dn:])
 
 
 def _passes(cfg: LlamaConfig, params, layers_pass, carry):
@@ -1225,8 +1223,11 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     """Zeroed page pools of all layers, of the kind the model's attention
     caches (token-major: see ops.paged_attention): K and V pools ``[L, P,
     page, NKV*H]`` each or, for latent attention, one pool of latent pages
-    ``[L, P, page * (kv_lora_rank + qk_rope_dim)]`` (a page's positions side
-    by side: ops.paged_attention says why) and None.  ``L`` is a
+    ``[L, P, page, Wp]`` and None, ``Wp`` = ``kv_lora_rank + qk_rope_dim``
+    rounded up to whole 128-lane tiles (576 -> 640: a page of one layer is
+    then one run of whole tiles, which the read copies where it lies;
+    ops.paged_attention says why), on every backend; the columns past the
+    values are zero and stay zero.  ``L`` is a
     layer for every pass of a looped model: ``ut_steps * num_layers``.
     Page 0 is the scratch sink for padded/inactive writes — allocators
     must never hand it out.  With a ``layer_pattern`` the pages are the
@@ -1240,8 +1241,9 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                          f"block_length={cfg.block_length}: a block's "
                          "positions lie in one page")
     if cfg.kv_lora_rank:
-        return jnp.zeros((L, num_pages, page_size * (
-            cfg.kv_lora_rank + cfg.qk_rope_dim)), dt), None
+        from ray_tpu.ops.paged_attention import latent_width
+        return jnp.zeros((L, num_pages, page_size, latent_width(
+            cfg.kv_lora_rank, cfg.qk_rope_dim)), dt), None
     shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
     if not cfg.layer_pattern:
         return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
@@ -1324,14 +1326,17 @@ def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
 
 def llama_paged_read(cfg: LlamaConfig, k_pages) -> str:
     """What a step's programs read the pages with, "kernel" or "gather":
-    the token step's K/V pages as ``paged_read_kind`` says of its queries
-    and the pool; a block model's read (``paged_block_attention``) and a
-    latent one's (``paged_latent_attention``) are gathers."""
+    the token step's K/V pages, and a latent model's one pool
+    (``paged_latent_attention``), as ``paged_read_kind`` says of the step's
+    queries and the pool; a block model's read (``paged_block_attention``)
+    is a gather."""
     from ray_tpu.ops.paged_attention import paged_read_kind
-    if cfg.block_length or cfg.kv_lora_rank:
+    if cfg.block_length:
         return "gather"
+    # a latent model's queries are as wide as its pool's padded rows
+    head = k_pages.shape[3] if cfg.kv_lora_rank else cfg.head_dim
     return paged_read_kind(jax.ShapeDtypeStruct(
-        (1, cfg.num_heads, cfg.head_dim), cfg.dtype), k_pages)
+        (1, cfg.num_heads, head), cfg.dtype), k_pages)
 
 
 def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,

@@ -38,25 +38,34 @@ A second page kind, for latent attention (MLA; ``models/llama.py`` with
 values, the normed compressed key-value and the rotated key all heads share,
 so there is one pool and no V pool:
 
-    latent_pages     [L, P, page * W]    every layer's pool; a page's
-                                         positions side by side
+    latent_pages     [L, P, page, Wp]    every layer's pool, token-major;
+                                         Wp = W rounded up to whole 128-lane
+                                         tiles (``latent_width``), the
+                                         columns past W zero
     q                [B, N, W]           a head's query with the key
                                          expansion absorbed into it
 
-A page's positions are folded into its last axis because of what the TPU does
-with ``[L, P, page, 576]``: an array whose last axis is no multiple of the 128
-lanes is given another device layout (the page axis minor) and every program
-would copy the whole pool in and out of the layout it computes in (0.9 GB
-each way a step at Xing4.0's size; compiled for a described v5e, PERF.md PR
-34); 640 values a position would be aligned and 11% air.  ``page * W`` is a
-multiple of 128 for any even page size and holds exactly W values a position.
-``append_latent`` / ``prefill_latent`` scatter rows as ``append_kv`` /
-``prefill_kv`` do (a prompt's, a page at a time), and
+Why the padding.  An array whose last axis is no multiple of the 128 lanes
+(``[L, P, page, 576]``) is given another device layout on the TPU (the page
+axis minor) and every program would copy the whole pool in and out of the
+layout it computes in (0.9 GB each way a step at Xing4.0's size; compiled for
+a described v5e, PERF.md PR 34).  Until PR 50 a page's positions were folded
+into the last axis instead (``[L, P, page * W]``, exactly W values a
+position), which cured the layout and left a page ONE row of a tiled ``[P,
+page * W]`` array: sharing its packed tiles with fifteen other pages, no
+contiguous run, a position's values starting at a multiple of 64 lanes, so
+nothing could copy a page where it lay and the read had to gather the rung
+and lay the gathered rows out again.  At 640 columns for 576 a page of one
+layer is ``page x 5`` whole tiles in one run (20 KB), the kind of thing
+``ops/paged_read.py`` copies, for 11% of air in a read that no longer pays
+for the table's width.  The padding is zeros, written with every row and
+never with anything else, and a query is padded with zeros to match, so the
+scores are exact.  ``append_latent`` / ``prefill_latent`` scatter rows as
+``append_kv`` / ``prefill_kv`` do (a prompt's, whole pages at a time), and
 ``paged_latent_attention`` scores every head against the one row of a
-position and weighs the rows: it returns attention over the COMPRESSED
-values (and, beside them, over the rotated key's columns, which mean
-nothing), which the caller expands.  Page 0, the page table
-and the layer index mean what they mean above.
+position and weighs the rows' first ``rank`` columns: it returns attention
+over the COMPRESSED values, which the caller expands.  Page 0, the page
+table and the layer index mean what they mean above.
 
 A model that generates by diffusion over blocks (``models/llama.py`` with
 ``block_length``) steps ``B`` positions a sequence: ``append_block_kv``
@@ -64,35 +73,37 @@ writes a block's rows and ``paged_block_attention`` reads for its ``B``
 query rows at once; the pages are the K/V kind above, no third kind.
 
 This file is the jnp implementation (gather + masked softmax) of every
-read, and for the token step's read of K/V pages also the selector:
-``paged_attention`` runs as ``ops/paged_read.py``'s Pallas kernel where
-``paged_read_kind`` says so, from what it can observe: the backend is not
-the CPU, a head is a whole number of 128-lane tiles, a page's rows are whole
+read, and for the token step's two reads (K/V pages and latent pages) also
+the selector: ``paged_attention`` and ``paged_latent_attention`` run as
+``ops/paged_read.py``'s Pallas kernel where ``paged_read_kind`` says so, from
+what it can observe: the backend is not the CPU, a head (a latent query: the
+padded row) is a whole number of 128-lane tiles, a page's rows are whole
 sublane tiles of the pools' type (16 for bfloat16: every served
 configuration; the engine's default page of 8 in the CPU tests is not), and
 the queries are of the pools' type.  The kernel leaves the pools in HBM,
 copies for each sequence only the pages its own length reaches, each where
-it lies, and reads a head as a run of lanes of the copied rows: no gathered
-``[B, W x page, NKV*H]`` in HBM, no relayout of it by heads, no page of the
-rung that the sequence does not hold.  The gather is exact: given identical
-page contents it reproduces dense attention bit-for-bit in f32, which is
-what the paged-vs-dense CPU equivalence tests assert; it is the kernel's
-reference (``tests/test_paged_attention_kernel.py``) and what every other
-case runs: GPT-2's heads of 64, the CPU, and the two reads below that the
-kernel is not written for yet.
+it lies (a latent page ONCE, for both products), and reads a head as a run
+of lanes of the copied rows: no gathered ``[B, W x page, NKV*H]`` in HBM, no
+relayout of it by heads, no page of the rung that the sequence does not
+hold.  The gather is exact: given identical page contents it reproduces
+dense attention bit-for-bit in f32, which is what the paged-vs-dense CPU
+equivalence tests assert; it is the kernel's reference
+(``tests/test_paged_attention_kernel.py``) and what every other case runs:
+GPT-2's heads of 64, the CPU, and the one read that the kernel is not
+written for yet, the block step's.
 
-``paged_block_attention`` and ``paged_latent_attention`` (and the token
-step's read where the kernel is not picked) gather every column of the
-``page_table`` they are given, for every sequence, and mask by ``lengths``:
-the table's width, not the lengths, sets their cost.  The caller chooses
-it: the serving engine hands a step the first ``W`` columns of its rows,
-``W`` the least of a few compiled widths that holds the batch's longest
-sequence (``serve/engine/engine.py``, ``decode_rungs``); the page of every
-``pos`` a step appends at and of every position under ``lengths`` has to lie
-inside the table.  The columns left out held positions whose probability is
-exactly 0, so a narrower table gives a wider one's result up to the order of
-a shorter sum.  Under the kernel the width bounds only the scalars it
-prefetches: each sequence reads as far as its own length.
+``paged_block_attention`` (and the token step's reads where the kernel is
+not picked) gathers every column of the ``page_table`` it is given, for
+every sequence, and masks by ``lengths``: the table's width, not the
+lengths, sets its cost.  The caller chooses it: the serving engine hands a
+step the first ``W`` columns of its rows, ``W`` the least of a few compiled
+widths that holds the batch's longest sequence (``serve/engine/engine.py``,
+``decode_rungs``); the page of every ``pos`` a step appends at and of every
+position under ``lengths`` has to lie inside the table.  The columns left
+out held positions whose probability is exactly 0, so a narrower table gives
+a wider one's result up to the order of a shorter sum.  Under the kernel the
+width bounds only the scalars it prefetches: each sequence reads as far as
+its own length.
 """
 
 from __future__ import annotations
@@ -113,12 +124,14 @@ def _kernel_backend() -> bool:
 
 
 def paged_read_kind(q, k_pages) -> str:
-    """What ``paged_attention`` reads these pools for these queries with,
-    "kernel" or "gather", from what it can observe of them (arrays or their
-    shapes): the backend, and whether the kernel is written for the operands
-    (``paged_read.supported``: heads of whole 128-lane tiles, pages of whole
-    sublane tiles, one type).  Measured on the chip at the five served
-    shapes (``scripts/paged_read_sweep.py``; PERF.md section 6, PR 49); a
+    """What ``paged_attention`` (``paged_latent_attention``: ``q`` padded to
+    the pool's row, ``k_pages`` the one pool) reads these pools for these
+    queries with, "kernel" or "gather", from what it can observe of them
+    (arrays or their shapes): the backend, and whether the kernel is written
+    for the operands (``paged_read.supported``: heads of whole 128-lane
+    tiles, pages of whole sublane tiles, one type).  Measured on the chip at
+    the five served K/V shapes and at Xing's latent pages
+    (``scripts/paged_read_sweep.py``; PERF.md section 6, PR 49 and PR 50); a
     shape that reads slower through the kernel is named here."""
     if _kernel_backend() and paged_read.supported(
             q.shape, q.dtype, k_pages.shape, k_pages.dtype):
@@ -267,63 +280,79 @@ def prefill_kv(k_pages: jax.Array, v_pages: jax.Array, layer: jax.Array,
                 v_pages.at[layer, pid, slot].set(v_seq))
 
 
+def latent_width(rank: int, rope: int) -> int:
+    """Columns of a latent page's row: ``rank + rope`` rounded up to whole
+    128-lane tiles (576 -> 640), the columns past ``rank + rope`` zero."""
+    return -(-(rank + rope) // paged_read._LANES) * paged_read._LANES
+
+
+def _padded(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """Latent rows [.., W] as the pool stores them: [.., Wp], zeros past
+    W, in the pool's type."""
+    widths = [(0, 0)] * (x.ndim - 1) + [(0, pool.shape[3] - x.shape[-1])]
+    return jnp.pad(x.astype(pool.dtype), widths)
+
+
 def append_latent(pages: jax.Array, layer: jax.Array, new: jax.Array,
                   pos: jax.Array, page_table: jax.Array) -> jax.Array:
     """Scatter one position's latent row per sequence into one layer of the
     pool: ``new`` [B, W]; ``pos`` [B]; ``page_table`` [B, maxp]; ``pages``
-    [L, P, page * W], the row of position ``pos`` at ``(pos % page) * W`` of
-    its page.  Inactive slots park on page 0, as in ``append_kv``."""
-    W = new.shape[1]
-    page = pages.shape[2] // W
+    [L, P, page, Wp], the row of position ``pos`` at ``pos % page`` of its
+    page, padded with zeros to ``Wp``.  Inactive slots park on page 0, as in
+    ``append_kv``."""
+    page = pages.shape[2]
     with jax.named_scope("latent_append"):
         pid = jnp.take_along_axis(page_table, (pos // page)[:, None],
                                   axis=1)[:, 0]                  # [B]
-        at = jnp.stack([jnp.full_like(pid, layer), pid, (pos % page) * W],
-                       axis=1)
-        return jax.lax.scatter(
-            pages, at, new.astype(pages.dtype),
-            jax.lax.ScatterDimensionNumbers(
-                update_window_dims=(1,), inserted_window_dims=(0, 1),
-                scatter_dims_to_operand_dims=(0, 1, 2)),
-            unique_indices=True)
+        return pages.at[layer, pid, pos % page].set(_padded(new, pages))
 
 
 def prefill_latent(pages: jax.Array, layer: jax.Array, seq: jax.Array,
                    length: jax.Array, page_table_row) -> jax.Array:
     """Scatter a whole (padded) prompt's latent rows ``seq`` [S, W] of ONE
-    sequence into one layer of the pool, a page at a time (S is a multiple
-    of the page size).  Pages wholly past ``length`` go to scratch page 0;
-    the padding that shares the prompt's last page lands in the slots the
-    sequence's next positions will overwrite before any step reads them."""
-    S, W = seq.shape
-    page = pages.shape[2] // W
+    sequence into one layer of the pool ``[L, P, page, Wp]``, a page at a
+    time (S is a multiple of the page size).  Pages wholly past ``length``
+    go to scratch page 0; the padding that shares the prompt's last page
+    lands in the slots the sequence's next positions will overwrite before
+    any step reads them."""
+    S = seq.shape[0]
+    page, Wp = pages.shape[2:]
     with jax.named_scope("latent_append"):
         first = jnp.arange(S // page) * page        # a page's first position
         pid = jnp.where(first < length, page_table_row[:S // page], 0)
         return pages.at[layer, pid].set(
-            seq.reshape(S // page, page * W).astype(pages.dtype))
+            _padded(seq, pages).reshape(S // page, page, Wp))
 
 
 def paged_latent_attention(q: jax.Array, pages: jax.Array, layer: jax.Array,
                            lengths: jax.Array, page_table: jax.Array, *,
-                           sm_scale: float) -> jax.Array:
+                           sm_scale: float, rank: int) -> jax.Array:
     """Single-token decode attention against one layer of the latent pool,
     read as it lies.  ``q`` [B, N, W] (W = rank + rope: the absorbed query
-    beside the rotated one); ``pages`` [L, P, page * W]; positions < length
-    attend.  Every head scores against the same row of a position and the
-    probabilities weigh the rows.  Returns [B, N, W] in q's dtype, attention
-    over WHOLE rows: the first ``rank`` columns are the compressed values'
-    and the caller's to expand, the rest (the rotated key's) mean nothing
-    and are the caller's to pass over, because a slice here, of the result
-    or of the rows, is turned by the compiler into a copy of every gathered
-    row.  Softmax runs in f32."""
-    B, _, W = q.shape
-    S = page_table.shape[1] * pages.shape[2] // W
+    beside the rotated one); ``pages`` [L, P, page, Wp]; positions < length
+    attend.  Every head scores against the same row of a position (the
+    query padded with zeros to ``Wp``: the scores are exact) and the
+    probabilities weigh the rows.  Returns [B, N, rank] in q's dtype: the
+    attention over the compressed values, the rows' first ``rank`` columns,
+    which the caller expands.  Softmax runs in f32.
+
+    Where ``paged_read_kind`` says so of the padded queries and the pool it
+    is ``ops/paged_read.py``'s walk with ONE copy a page, from which both
+    products are taken; elsewhere (the CPU, a page that is no whole sublane
+    tiles) the gather below, the walk's reference."""
+    B, N, W = q.shape
+    page, Wp = pages.shape[2:]
     with jax.named_scope("latent_read"):
-        rows = pages[layer, page_table].reshape(B, S, W)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Wp - W)))
+        if paged_read_kind(q, pages) == "kernel":
+            return paged_read.paged_read_attention(
+                q, pages, None, layer, lengths, page_table,
+                sm_scale=sm_scale, columns=rank)[..., :rank]
+        S = page_table.shape[1] * page
+        rows = pages[layer, page_table].reshape(B, S, Wp)
         scores = jnp.einsum("bnw,bsw->bns", q, rows) * sm_scale
         valid = jnp.arange(S)[None] < lengths[:, None]           # [B, S]
         scores = jnp.where(valid[:, None], scores.astype(jnp.float32),
                            -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bns,bsw->bnw", probs, rows)
+        return jnp.einsum("bns,bsw->bnw", probs, rows)[..., :rank]

@@ -48,6 +48,17 @@ by where the probabilities are rounded (before the normalisation here, after
 it there), and by the scores, which the gather's einsum rounds to the pools'
 type and this keeps in float32.
 
+The latent kind (``ops/paged_attention.py::paged_latent_attention``; PR 50)
+is the same walk with its page kind read from the operands: handed ONE pool
+``[L, P, page, Wp]`` (``v_pages`` None) a page is copied once and both
+products are taken from the copy, ``scores = q [N, Wp] x block^T`` and
+``probabilities x block[:, :columns]``, the values being the rows' first
+``columns`` columns (whole lane tiles: the result comes out cut to them).
+There is one K/V head, so no block diagonal; the chain of copies, the online
+softmax and the tail's masks are the ones above.  A block is as many pages as
+their bytes say (``_resolve``): a latent page is small and the copies' issue,
+not their bytes, is what a block of them costs.
+
 On the CPU backend the kernel runs in Pallas interpret mode (the tests).
 """
 
@@ -71,7 +82,14 @@ _MASKED = -1e30
 # 256 and 512 positions and 2, 3 and 4 of them read within 4% of each other
 # where the bytes bind (85-87% of the live bytes' roofline at the hybrid
 # cell's shape); 256 x 3 is the best or within 1% of it at all five shapes.
+# Where a page is few bytes (a latent page of 16 rows is 20 KB, a twelfth of
+# the hybrid cell's K and V) it is the copies' issue and the loop's own time
+# a block that bind, and a block is doubled until its copies are
+# ``_BLOCK_BYTES``, the least the K/V shapes were measured at (Mistral's
+# 256 positions): 1,024 latent positions read 14-19% faster than 256 (PERF.md
+# section 6, PR 50).
 _BLOCK_TOKENS = 256
+_BLOCK_BYTES = 2 ** 20
 _BUFFERS = 3
 _VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
@@ -96,28 +114,45 @@ def supported(q_shape, q_dtype, pool_shape, pool_dtype) -> bool:
             and jnp.dtype(q_dtype) == jnp.dtype(pool_dtype))
 
 
-def _resolve(page, pages_per_block, interpret):
-    """(pages a compute block: ``_BLOCK_TOKENS`` positions unless the caller
-    says; whether to interpret)."""
+def _resolve(page, page_bytes, pages_per_block, interpret):
+    """(pages a compute block, unless the caller says: ``_BLOCK_TOKENS``
+    positions, doubled until the copies of a block, ``page_bytes`` a page
+    over all pools, are ``_BLOCK_BYTES``; whether to interpret)."""
     if interpret is None:
         # The interpreter is for the CPU backend, where the tests run.
         interpret = jax.default_backend() == "cpu"
-    return pages_per_block or max(1, _BLOCK_TOKENS // page), interpret
+    if not pages_per_block:
+        pages_per_block = max(1, _BLOCK_TOKENS // page)
+        while pages_per_block * page_bytes < _BLOCK_BYTES:
+            pages_per_block *= 2
+    return pages_per_block, interpret
 
 
 def paged_read_attention(q, k_pages, v_pages, layer, lengths, page_table, *,
                          sm_scale: float, interpret: Optional[bool] = None,
                          pages_per_block: Optional[int] = None,
-                         buffers: Optional[int] = None):
+                         buffers: Optional[int] = None,
+                         columns: Optional[int] = None):
     """``paged_attention``'s result by the kernel: ``q`` [B, N, H],
     ``k_pages`` / ``v_pages`` [L, P, page, NKV*H] of q's type, ``layer`` a
     scalar index, ``lengths`` [B] (at least 1 each), ``page_table`` [B,
-    maxp] -> [B, N, H] in q's type."""
-    if not supported(q.shape, q.dtype, k_pages.shape, k_pages.dtype):
+    maxp] -> [B, N, H] in q's type.
+
+    ``v_pages`` None is the latent kind (``paged_latent_attention``'s
+    result): ``k_pages`` [L, P, page, H] is the one pool, a row of it key
+    and value both, ``q`` [B, N, H] every head's query against the whole
+    row; ``columns`` (whole lane tiles, H if not given) is how many of a
+    row's leading columns are values -> [B, N, columns]."""
+    if not supported(q.shape, q.dtype, k_pages.shape, k_pages.dtype) or (
+            v_pages is None and k_pages.shape[3] != q.shape[2]):
         raise ValueError(
             f"no paged-read kernel for queries {q.dtype}{list(q.shape)} on "
             f"pools {k_pages.dtype}{list(k_pages.shape)}")
-    ppb, interpret = _resolve(k_pages.shape[2], pages_per_block, interpret)
+    page, D = k_pages.shape[2:]
+    ppb, interpret = _resolve(
+        page, page * D * k_pages.dtype.itemsize * (1 if v_pages is None
+                                                   else 2),
+        pages_per_block, interpret)
     # The table goes in flat and at ONE length whatever its width: the
     # pool's pages, the most that sequences can hold between them (a table
     # with more entries than that goes as it is).  Its width goes beside
@@ -130,19 +165,27 @@ def paged_read_attention(q, k_pages, v_pages, layer, lengths, page_table, *,
         table = jnp.pad(table, (0, k_pages.shape[1] - table.shape[0]))
     where = jnp.stack([jnp.asarray(layer, jnp.int32),
                        jnp.int32(page_table.shape[1])])
+    if v_pages is not None or not columns or columns % _LANES:
+        columns = q.shape[2]
     return _walk(q, k_pages, v_pages, where, lengths.astype(jnp.int32),
                  table, sm_scale=float(sm_scale), ppb=ppb,
-                 buffers=buffers or _BUFFERS, interpret=interpret)
+                 buffers=buffers or _BUFFERS, interpret=interpret,
+                 columns=columns)
 
 
 # jitted and inlined where it is called, as ``grouped_matmul._tiled`` is: a
 # model's programs trace the kernel once a shape, not once a call.
 @functools.partial(jax.jit, static_argnames=("sm_scale", "ppb", "buffers",
-                                             "interpret"), inline=True)
+                                             "interpret", "columns"),
+                   inline=True)
 def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
-          ppb: int, buffers: int, interpret: bool):
+          ppb: int, buffers: int, interpret: bool, columns: int):
     B, N, H = q.shape
     page, D = k_pages.shape[2:]
+    # the page kind, read from the operands: one pool is latent pages, a
+    # copied row key and value both (its first ``columns`` columns)
+    latent = v_pages is None
+    pools = (k_pages,) if latent else (k_pages, v_pages)
     NKV = D // H
     rep = N // NKV
     T = ppb * page
@@ -152,8 +195,10 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
     if Np != N:
         q = jnp.pad(q, ((0, 0), (0, Np - N), (0, 0)))
 
-    def kernel(where_ref, lengths_ref, table_ref, q_ref, k_hbm, v_hbm,
-               o_ref, kbuf, vbuf, sems, chain):
+    def kernel(where_ref, lengths_ref, table_ref, q_ref, *refs):
+        hbm, (o_ref, *bufs, sems, chain) = refs[:len(pools)], \
+            refs[len(pools):]
+        kbuf, vbuf = bufs[0], bufs[-1]      # a latent page's one copy is both
         # chain: blocks started, the (sequence, block) to start next, blocks
         # computed; a block's buffer is its number modulo ``buffers``
         STARTED, SEQ, BLOCK, DONE = range(4)
@@ -173,12 +218,10 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
             def one(j, _):
                 pid = table_ref[seq * W + block * ppb + j]
                 rows = pl.ds(pl.multiple_of(j * page, page), page)
-                act(pltpu.make_async_copy(
-                    k_hbm.at[layer, pid], kbuf.at[slot, rows],
-                    sems.at[0, slot]))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[layer, pid], vbuf.at[slot, rows],
-                    sems.at[1, slot]))
+                for at, (pool, buf) in enumerate(zip(hbm, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[layer, pid], buf.at[slot, rows],
+                        sems.at[at, slot]))
                 return _
             jax.lax.fori_loop(0, pages, one, None)
 
@@ -234,12 +277,17 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
             p = jnp.exp(scores - m_new)
             alpha = jnp.exp(m - m_new)
             l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            weighed = jnp.dot(p.astype(vbuf.dtype), vbuf[slot],
-                              preferred_element_type=jnp.float32)  # [Np, D]
-            weighed = jnp.where(own, weighed, 0.0)
-            for k in range(NKV):     # the diagonal blocks, side by side
-                acc = (alpha * acc if k == 0 else acc) \
-                    + weighed[:, k * H:(k + 1) * H]
+            if latent:      # every head weighs the one row's values
+                acc = alpha * acc + jnp.dot(
+                    p.astype(vbuf.dtype), vbuf[slot, :, :columns],
+                    preferred_element_type=jnp.float32)
+            else:
+                weighed = jnp.dot(p.astype(vbuf.dtype), vbuf[slot],
+                                  preferred_element_type=jnp.float32)
+                weighed = jnp.where(own, weighed, 0.0)          # [Np, D]
+                for k in range(NKV):     # the diagonal blocks, side by side
+                    acc = (alpha * acc if k == 0 else acc) \
+                        + weighed[:, k * H:(k + 1) * H]
             chain[DONE] += 1
             return m_new, l, acc
 
@@ -247,22 +295,22 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
             0, blocks_of(b), body,
             (jnp.full((Np, 1), _MASKED, jnp.float32),
              jnp.zeros((Np, 1), jnp.float32),
-             jnp.zeros((Np, H), jnp.float32)))
+             jnp.zeros((Np, columns), jnp.float32)))
         o_ref[0] = (acc / l).astype(o_ref.dtype)
 
     call = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, Np, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Np, columns), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[pl.BlockSpec((1, Np, H), lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, Np, H), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((buffers, T, D), k_pages.dtype),
-                            pltpu.VMEM((buffers, T, D), v_pages.dtype),
-                            pltpu.SemaphoreType.DMA((2, buffers)),
-                            pltpu.SMEM((4,), jnp.int32)],
+            in_specs=[pl.BlockSpec((1, Np, H), lambda b, *_: (b, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=pl.BlockSpec((1, Np, columns),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((buffers, T, D), pool.dtype)
+                            for pool in pools]
+            + [pltpu.SemaphoreType.DMA((len(pools), buffers)),
+               pltpu.SMEM((4,), jnp.int32)],
             grid=(B,)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -270,5 +318,5 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
         interpret=interpret,
         name="paged_read")
     with kernel_source.nowhere():
-        out = call(where, lengths, table, q, k_pages, v_pages)
+        out = call(where, lengths, table, q, *pools)
     return out[:, :N]

@@ -180,12 +180,13 @@ and wrote (``recurrent_step_bytes_share``).
 What a decode step's paged read FETCHES depends on what its program was
 compiled with (the record's ``paged_read``, asked once at construction:
 ``ops/paged_attention.py::paged_read_kind``).  "gather" (a block model's
-and a latent model's read, GPT-2's heads of 64, every model on the CPU):
+read, GPT-2's heads of 64, every model on the CPU):
 every page of the table the step is given, for every slot, once per pool
 layer: the step's rung, bounded by the longest live sequence and not by
-each.  "kernel" (the token step's K/V pages on the chip): each slot's own
-pages as far as its position, a parked slot's one page of page 0; the rung
-then bounds only what the kernel is told, not what it reads.  ``stats()``
+each.  "kernel" (the token step's K/V or latent pages on the chip): each
+slot's own pages as far as its position, a parked slot's one page of page
+0; the rung then bounds only what the kernel is told, not what it reads.
+``stats()``
 counts both sides: ``kv_live_token_steps`` (positions the live sequences
 held, summed over decode steps) against ``kv_gathered_token_steps`` (what
 the steps fetched: ``max_batch x W x page_size`` a step under the gather,

@@ -120,6 +120,21 @@ STALE_SNAPSHOTS = {
         "cell and two to others.  Its other assertions run as "
         "test_paged_read_metrics.py::"
         "test_what_the_hybrid_cell_added_still_stands",
+    "test_paged_read_metrics.py::test_the_benchmark_lists_them_last":
+        "takes the per-layer list's last four entries as PR 49 left them; PR "
+        "50 added paged_kernel_share.xing after them.  Its other assertions "
+        "run as test_paged_kernel_share_xing.py::"
+        "test_the_benchmark_lists_it_last_and_the_others_before_it",
+    "test_spec_sdar.py::test_published_widths_of_xing_still_hold":
+        "counts the Xing cell's own per-layer entries (14) as PR 40 found "
+        "them; PR 50 added paged_kernel_share.xing.  Its other assertions "
+        "run as test_paged_kernel_share_xing.py::"
+        "test_published_widths_of_xing_hold_with_the_cells_fifteen",
+    "test_xing_reference.py::test_absorbed_decode_is_expanded_decode":
+        "asserts the latent pool's folded shape [L, P, page * W] as PR 34 "
+        "left it; PR 50 stores a page [page, Wp] in whole lane tiles.  Its "
+        "other assertions run, on that shape, as test_xing_latent_pool.py::"
+        "test_absorbed_decode_is_expanded_decode_on_padded_pages",
 }
 
 

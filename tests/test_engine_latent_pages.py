@@ -1,7 +1,8 @@
 """The engine with latent pages (``models/llama.py`` with ``kv_lora_rank``):
-ONE pool ``[L, P, page * (rank + rope)]`` and no V pool, through the same
-loop, ladder, donation, views and counters as the K/V-page models, at tiny
-widths on the CPU.
+ONE pool ``[L, P, page, Wp]`` (``Wp`` = rank + rope rounded up to whole
+128-lane tiles, the columns past rank + rope zero) and no V pool, through
+the same loop, ladder, donation, views and counters as the K/V-page models,
+at tiny widths on the CPU.
 
 Admission, retirement and page reuse with more callers than slots and a full
 batch give each caller the tokens a single-sequence run gives; the pool is
@@ -25,7 +26,7 @@ from test_engine_prefill_rungs import failing
 
 PAGE, PROMPT, NEW, BATCH = 8, 32, 16, 4
 MAXP = (PROMPT + NEW) // PAGE
-W = 32 + 8
+W, WP = 32 + 8, 128         # a row's values, and as the pool pads them
 CFG = LlamaConfig(
     vocab_size=97, max_seq_len=PROMPT + NEW, num_layers=3, num_heads=4,
     num_kv_heads=4, embed_dim=64, mlp_dim=32, num_experts=8,
@@ -55,11 +56,11 @@ def test_the_pool_is_one_array_of_latent_pages():
     _, engine = build()
     try:
         assert engine._v_pages is None
-        assert engine._k_pages.shape == (3, BATCH * MAXP + 1, PAGE * W)
+        assert engine._k_pages.shape == (3, BATCH * MAXP + 1, PAGE, WP)
         stats = engine.stats()
         assert stats["kv_page_kind"] == "latent"
         assert stats["kv_pool_layers"] == 3
-        assert stats["kv_bytes_per_token"] == 3 * W * 4          # float32
+        assert stats["kv_bytes_per_token"] == 3 * WP * 4         # float32
         assert stats["kv_pool_bytes"] == engine._k_pages.nbytes
     finally:
         engine.close()
@@ -171,7 +172,9 @@ def test_a_shorter_rung_gives_the_logits_and_pages_of_the_top_rung():
                 engine._k_pages, engine._v_pages, table)
             # the pages the prompt holds (21 positions: three pages, the
             # third's last three slots are padding the decode overwrites)
-            held = np.asarray(kp[:, 1:4]).reshape(3, 3 * PAGE, W)[:, :21]
+            held = np.asarray(kp[:, 1:4]).reshape(3, 3 * PAGE, WP)[:, :21]
+            # written rows, their padding columns left zero
+            assert held[:, 0, :W].all() and not held[..., W:].any()
             out.append((np.asarray(logits), held, np.asarray(load)))
             assert not np.asarray(kp[:, 4:]).any()     # nothing past them
         for a, b in zip(*out):

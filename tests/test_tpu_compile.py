@@ -105,19 +105,20 @@ def compiled_paged_read(monkeypatch):
     monkeypatch.setattr(pr, "_resolve", lambda *a: real(*a[:-1], False))
 
 
-def _paged_read_kernels(text: str, pool: str) -> list:
+def _paged_read_kernels(text: str, pool: str, scope: str = "paged_read",
+                        pools: int = 2) -> list:
     """The program's instructions that are the Pallas kernel ``paged_read``
-    under the scope of that name (what the benchmark's readers find the
-    read's device time by), each held to take two pools of the whole shape
+    under the scope ``scope`` (what the benchmark's readers find the read's
+    device time by), each held to take ``pools`` pools of the whole shape
     ``pool`` as operands: every layer's pages as they are stored."""
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line
-             and re.search(r'op_name="[^"]*[/(]paged_read[/)]', line)]
+             and re.search(rf'op_name="[^"]*[/(]{scope}[/)]', line)]
     for call in calls:
         assert re.match(r"\s*%paged_read[\w.]* = ", call), call[:80]
         operands = call[call.index("operand_layout_constraints="):
                         call.index("backend_config=")]
-        assert operands.count(pool + "{") == 2, operands
+        assert operands.count(pool + "{") == pools, operands
     return calls
 
 
@@ -710,9 +711,11 @@ def test_ouro_weights_are_made_as_the_stored_tree(topo):
 # Xing4.0-29B-A4B at its published widths, the leading dense layer and five of
 # its 38 expert layers, with the engine of
 # benchmark/configs/xing4.0-29b-a4b-6l.json (32 slots of 4,096 positions): ONE
-# pool of latent pages [6, 8193, 16 x 576], 0.906 GB, beside 9.6 GB of stored
-# weights (bf16 matrices, the 64 routed experts among them).  Compiled sizes
-# (PERF.md, PR 34): decode 10.10 GiB, prefill at the 1024 rung 9.94 GiB.
+# pool of latent pages [6, 8193, 16, 640] (a position's 576 values in five
+# whole lane tiles), 1.007 GB, beside 9.6 GB of stored weights (bf16 matrices,
+# the 64 routed experts among them).  Compiled sizes (PERF.md, PR 34, the pool
+# folded [6, 8193, 16 x 576] and its read a gather): decode 10.10 GiB, prefill
+# at the 1024 rung 9.94 GiB.
 XING_PROMPT, XING_NEW, XING_BATCH = 1024, 3072, 32
 XING_BUDGET = int(10.5 * 1024 ** 3)
 
@@ -726,7 +729,8 @@ def _xing():
 
 @pytest.mark.parametrize("program", ["prefill", "prefill@256", "decode",
                                      "decode@64"])
-def test_xing_engine_program_compiles(topo, compiled_experts, program):
+def test_xing_engine_program_compiles(topo, compiled_experts,
+                                      compiled_paged_read, program):
     from ray_tpu.models.llama import (llama_decode_step,
                                       llama_init_paged_cache, llama_prefill)
     family, cfg = _xing()
@@ -735,7 +739,7 @@ def test_xing_engine_program_compiles(topo, compiled_experts, program):
         lambda: family.init(jax.random.PRNGKey(0), cfg)))
     kp, vp = jax.eval_shape(lambda: llama_init_paged_cache(
         cfg, XING_BATCH * 256 + 1, PAGE))
-    assert vp is None and kp.shape == (6, 8193, PAGE * 576)
+    assert vp is None and kp.shape == (6, 8193, PAGE, 640)
     kp = _on(one, kp)
     maxp = (XING_PROMPT + XING_NEW) // PAGE
 
@@ -766,7 +770,7 @@ def test_xing_engine_program_compiles(topo, compiled_experts, program):
     _pools_in_place(compiled, text, kp, pools=1)
     # the pool is the program's parameter in the layout it computes in: no
     # relayout of it on the way in or out (a last axis of 576 had one each)
-    assert f"bf16[6,8193,{PAGE * 576}]{{2,1,0:" in text
+    assert f"bf16[6,8193,{PAGE},640]{{3,2,1,0:" in text
     assert "bf16[6,8193,16,576]" not in text
     assert params["layers"]["mlp"]["wgu"].dtype == jnp.bfloat16
     assert params["layers"]["hc_mlp"]["proj"].dtype == jnp.float32
@@ -775,6 +779,15 @@ def test_xing_engine_program_compiles(topo, compiled_experts, program):
               "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"]
     if program.startswith("decode"):
         scopes += ["latent_read", "mla_absorb"]
+        # the read is the kernel that walks the page table, the one pool
+        # its operand as it is stored (once in the unrolled dense layer,
+        # once in the expert layers' scan), and nothing is gathered or laid
+        # out again: the gather's rows were [32 slots x W pages, 16 x 576]
+        assert len(_paged_read_kernels(text, "bf16[6,8193,16,640]",
+                                       "latent_read", pools=1)) == 2
+        assert "bf16[4096,9216]" not in text
+        assert "bf16[32,2048,576]" not in text
+        assert not re.search(r"bf16\[(8192|2048),16,640\]", text)
     for scope in scopes:
         assert _scoped(text, scope), scope
     # two grouped matmuls an expert layer, on the stacked bf16 experts of
