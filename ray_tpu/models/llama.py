@@ -89,6 +89,23 @@ norm off a sublayer's input (OLMo 2 norms the output only: ``post_norm``)
 and ``rope_theta=0`` rotates nothing.  Such a model serves and runs
 ``llama_forward``; ``llama_loss`` refuses it (the scan's backward pass is
 not written) and so does the block step.
+
+Such a stack's FULL layers may keep latent pages (``kv_lora_rank``): the pair
+of pools is then (latent pages, ``RecurrentPools`` with no V pool), and a
+program uses two of ``AttentionState``'s three kinds.  Latent attention
+without ``q_lora_rank`` projects its queries directly, and with ``rope_theta``
+0 (and no ``rope_yarn``) rotates nothing, the shared key as little as the
+queries.  With ``linear_gate_rank`` the linear layers are Kimi Delta
+Attention's (``_kda_gates_and_output``): the decay a KEY CHANNEL through a
+bottleneck, beta in (0, 1), the read-out gated by a sigmoid through another
+(``ops/linear_attention.py``'s ``kda_*``).  Behind ``first_dense_layers`` the
+stack's feed-forwards may be experts: the layers are then not one shape a
+position of the period, ``params["layers"]`` is a tuple with a group a LAYER
+(each a stack of one, an expert layer's experts under its own ``mlp``) and the
+layers run in a Python loop (``_unrolled_layers``).  With ``expert_share`` (i,
+n) the program holds share i of n of every expert layer's experts and computes
+their part of the routed sum alone (``ops/moe.py``, the held experts): Kimi
+Linear's block, one chip's share of it.
 """
 
 from __future__ import annotations
@@ -164,6 +181,12 @@ class LlamaConfig:
     linear_value_dim: int = 0        #   a head's value width,
     linear_conv: int = 4             #   convolution over this many positions
     linear_neg_eigval: bool = False  #   beta in (0, 2) and not (0, 1)
+    # > 0: Kimi Delta Attention: the decay a KEY CHANNEL, it and the output's
+    # gate (a sigmoid) through bottlenecks of this width
+    linear_gate_rank: int = 0
+    # (i, n): this program holds share i of n of every expert layer, experts
+    # i * num_experts / n on; the router keeps num_experts outputs
+    expert_share: Tuple[int, int] = (0, 1)
 
     @property
     def head_dim(self) -> int:
@@ -177,12 +200,15 @@ class LlamaConfig:
 
 
 def _check(cfg: LlamaConfig) -> None:
-    if cfg.kv_lora_rank and not (cfg.q_lora_rank and cfg.qk_nope_dim
-                                 and cfg.qk_rope_dim and cfg.v_head_dim
-                                 and cfg.rope_yarn):
-        raise ValueError("latent attention needs q_lora_rank, qk_nope_dim, "
-                         "qk_rope_dim, v_head_dim and rope_yarn beside "
-                         "kv_lora_rank")
+    if cfg.kv_lora_rank and not (cfg.qk_nope_dim and cfg.qk_rope_dim
+                                 and cfg.v_head_dim):
+        raise ValueError("latent attention needs qk_nope_dim, qk_rope_dim "
+                         "and v_head_dim beside kv_lora_rank (q_lora_rank "
+                         "0: the queries are projected directly)")
+    if cfg.kv_lora_rank and bool(cfg.rope_theta) != bool(cfg.rope_yarn):
+        raise ValueError("latent attention rotates by YaRN's tables "
+                         "(rope_yarn) or, with rope_theta 0 and no "
+                         "rope_yarn, rotates nothing")
     if not cfg.kv_lora_rank and cfg.num_heads % cfg.num_kv_heads:
         raise ValueError(f"num_heads={cfg.num_heads} must be divisible by "
                          f"num_kv_heads={cfg.num_kv_heads}")
@@ -190,6 +216,11 @@ def _check(cfg: LlamaConfig) -> None:
             0 < cfg.experts_per_token <= cfg.num_experts:
         raise ValueError(f"experts_per_token={cfg.experts_per_token} must "
                          f"be in 1..num_experts={cfg.num_experts}")
+    share, shares = cfg.expert_share
+    if not 0 <= share < shares or (shares > 1 and (
+            not cfg.num_experts or cfg.num_experts % shares)):
+        raise ValueError(f"expert_share={cfg.expert_share} is share i of n "
+                         f"equal shares of num_experts={cfg.num_experts}")
     if not 0 <= cfg.first_dense_layers < max(cfg.num_layers, 1) or (
             cfg.first_dense_layers and not (cfg.num_experts
                                             and cfg.dense_mlp_dim)):
@@ -211,9 +242,7 @@ def _check(cfg: LlamaConfig) -> None:
                          "its bottlenecks, not head_size or "
                          "qk_norm_per_head")
     if cfg.layer_pattern:
-        kinds, others = set(cfg.layer_pattern), (
-            "num_experts", "kv_lora_rank", "hc_mult", "block_length",
-            "first_dense_layers")
+        kinds, others = set(cfg.layer_pattern), ("hc_mult", "block_length")
         if not kinds <= {"linear", "full"} or kinds == {"full"} \
                 or cfg.num_layers % len(cfg.layer_pattern):
             raise ValueError(
@@ -225,10 +254,20 @@ def _check(cfg: LlamaConfig) -> None:
             raise ValueError("linear layers need linear_heads, "
                              "linear_key_dim, linear_value_dim and a "
                              "linear_conv of 2 or more")
-        if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others):
+        if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others) or (
+                cfg.num_experts and not cfg.first_dense_layers):
             raise ValueError("a stack with linear layers (layer_pattern) is "
-                             "not written for ut_steps > 1 or "
-                             + ", ".join(others))
+                             "not written for ut_steps > 1, "
+                             + ", ".join(others) + " or num_experts without "
+                             "first_dense_layers (experts inside the scan "
+                             "over periods); its full layers keep K/V or "
+                             "latent pages, and behind leading dense layers "
+                             "its feed-forwards may be experts")
+        if cfg.linear_gate_rank and cfg.linear_neg_eigval:
+            raise ValueError("a decay a key channel (linear_gate_rank) has "
+                             "beta in (0, 1): no linear_neg_eigval")
+    elif cfg.linear_gate_rank:
+        raise ValueError("linear_gate_rank belongs to a layer_pattern")
     if cfg.block_length:
         if cfg.kv_lora_rank or cfg.ut_steps > 1 or cfg.hc_mult:
             raise ValueError("generation by blocks (block_length) is not "
@@ -257,12 +296,24 @@ def _linear_layers(cfg: LlamaConfig) -> int:
         cfg.num_layers // len(cfg.layer_pattern))
 
 
+def _held_experts(cfg: LlamaConfig) -> int:
+    """The experts of a layer that this program holds: its share of the
+    ``num_experts`` the router scores (all of them: one share)."""
+    return cfg.num_experts // cfg.expert_share[1]
+
+
+def _layer_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
+    """Every layer's kind, in order: the pattern's periods end to end."""
+    return cfg.layer_pattern * (cfg.num_layers // len(cfg.layer_pattern))
+
+
 def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                 experts: int, M: int, linear: bool = False) -> Dict[str, Any]:
     """``L`` layers of one kind stacked on a leading dim: ``experts`` of
-    width ``M`` each (0: one dense SwiGLU of ``M``); with ``linear`` the
-    linear-attention leaves under ``"linear"`` where the others have
-    ``"attn"``."""
+    width ``M`` each (0: one dense SwiGLU of ``M``; of ``cfg.num_experts``
+    the program's share, ``_held_experts``: the router keeps them all);
+    with ``linear`` the linear-attention leaves under ``"linear"`` where the
+    others have ``"attn"``."""
     k = jax.random.split(rng, 8)
     D, H = cfg.embed_dim, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -278,10 +329,11 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
            "wd": normal(k[5], (L, *ex, M, D), rscale)}
     extra = {}
     if experts:
-        mlp["router"] = normal(k[7], (L, D, experts))
+        routed = experts * cfg.expert_share[1]       # the router's width
+        mlp["router"] = normal(k[7], (L, D, routed))
         if cfg.router_bias:
             mlp["router_bias"] = normal(jax.random.fold_in(k[7], 1),
-                                        (L, experts), 0.01)
+                                        (L, routed), 0.01)
         if cfg.shared_experts:
             Ms = cfg.shared_experts * M
             extra["shared"] = {
@@ -296,12 +348,21 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
         N, dk, dv, K = (cfg.linear_heads, cfg.linear_key_dim,
                         cfg.linear_value_dim, cfg.linear_conv)
         C = N * (2 * dk + dv)            # q | k | v, the convolved channels
+        r = cfg.linear_gate_rank     # Kimi Delta Attention: a dt a channel
         dt = jnp.exp(jax.random.uniform(
-            jax.random.fold_in(k[2], 2), (L, N), jnp.float32,
-            np.log(0.001), np.log(0.1)))
-        attn = {"wqkv": normal(k[1], (L, D, C)),
-                "wz": normal(jax.random.fold_in(k[1], 1), (L, D, N * dv)),
-                "wba": normal(k[2], (L, D, 2 * N)),       # beta's | alpha's
+            jax.random.fold_in(k[2], 2), (L, N * dk if r else N),
+            jnp.float32, np.log(0.001), np.log(0.1)))
+        # q | k | v over the convolved channels; then the output's gate z
+        # and the rule's two gates b | a, or under ``r`` their bottlenecks
+        wqkv, kz = normal(k[1], (L, D, C)), jax.random.fold_in(k[1], 1)
+        gates = {"wg_a": normal(kz, (L, D, r)),
+                 "wg_b": normal(jax.random.fold_in(kz, 1), (L, r, N * dv)),
+                 "wf_a": normal(jax.random.fold_in(k[2], 4), (L, D, r)),
+                 "wf_b": normal(jax.random.fold_in(k[2], 5), (L, r, N * dk)),
+                 "wb": normal(k[2], (L, D, N))} if r else \
+            {"wz": normal(kz, (L, D, N * dv)),
+             "wba": normal(k[2], (L, D, 2 * N))}          # beta's | alpha's
+        attn = {"wqkv": wqkv, **gates,
                 "conv": jax.random.uniform(
                     jax.random.fold_in(k[2], 1), (L, K, C), jnp.float32,
                     -K ** -0.5, K ** -0.5),
@@ -314,10 +375,12 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
     elif cfg.kv_lora_rank:
         rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-        attn = {"wq_a": normal(k[1], (L, D, rq)),
-                "q_a_norm": jnp.ones((L, rq), jnp.float32),
-                "wq_b": normal(jax.random.fold_in(k[1], 1),
-                               (L, rq, nh, dn + dr)),
+        query = {"wq_a": normal(k[1], (L, D, rq)),
+                 "q_a_norm": jnp.ones((L, rq), jnp.float32),
+                 "wq_b": normal(jax.random.fold_in(k[1], 1),
+                                (L, rq, nh, dn + dr))} if rq else \
+            {"wq": normal(k[1], (L, D, nh, dn + dr))}    # no bottleneck
+        attn = {**query,
                 "wkv_a": normal(k[2], (L, D, rkv + dr)),
                 "kv_a_norm": jnp.ones((L, rkv), jnp.float32),
                 "wkv_b": normal(jax.random.fold_in(k[2], 1),
@@ -358,19 +421,30 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     layers that follow them under ``layers``; with a ``layer_pattern``
     ``layers`` is a tuple, one group a POSITION of the pattern, each stacked
     over the periods [num_layers / len(pattern)]: layer ``l`` is row ``l //
-    len(pattern)`` of group ``l % len(pattern)``."""
+    len(pattern)`` of group ``l % len(pattern)``; with a ``layer_pattern``
+    AND ``first_dense_layers`` it is a tuple with a group a LAYER, each a
+    stack of one (the leading dense layers' feed-forwards and the others'
+    experts are not one shape to stack over periods; ``_unrolled_layers``),
+    an expert layer's experts and router under its own ``mlp``."""
     _check(cfg)
     k = jax.random.split(rng, 8)
     D, V, Ld = cfg.embed_dim, cfg.vocab_size, cfg.first_dense_layers
     scale = 0.02
     dense = {"dense_layers": _init_group(
         jax.random.fold_in(rng, 1), cfg, Ld, 0, cfg.dense_mlp_dim)} \
-        if Ld else {}
+        if Ld and not cfg.layer_pattern else {}
 
     def layers():        # (drawn after the table, as they always were)
         if not cfg.layer_pattern:
             return _init_group(rng, cfg, cfg.num_layers - Ld,
-                               cfg.num_experts, cfg.mlp_dim)
+                               _held_experts(cfg), cfg.mlp_dim)
+        if Ld:
+            return tuple(
+                _init_group(jax.random.fold_in(rng, 16 + at), cfg, 1,
+                            *((0, cfg.dense_mlp_dim) if at < Ld else
+                              (_held_experts(cfg), cfg.mlp_dim)),
+                            kind == "linear")
+                for at, kind in enumerate(_layer_kinds(cfg)))
         periods = cfg.num_layers // len(cfg.layer_pattern)
         return tuple(
             _init_group(jax.random.fold_in(rng, 16 + at), cfg, periods, 0,
@@ -399,17 +473,25 @@ def _group_axes(cfg: LlamaConfig, experts: bool,
             extra["shared"] = {"wgu": ("layers", None, "embed", "mlp"),
                                "wd": ("layers", "mlp", "embed")}
     if linear:
-        attn = {"wqkv": ("layers", "embed", "heads"),
-                "wz": ("layers", "embed", "heads"),
-                "wba": ("layers", "embed", None),
+        gates = {"wg_a": ("layers", "embed", None),
+                 "wg_b": ("layers", None, "heads"),
+                 "wf_a": ("layers", "embed", None),
+                 "wf_b": ("layers", None, "heads"),
+                 "wb": ("layers", "embed", None)} \
+            if cfg.linear_gate_rank else \
+            {"wz": ("layers", "embed", "heads"),
+             "wba": ("layers", "embed", None)}
+        attn = {"wqkv": ("layers", "embed", "heads"), **gates,
                 "conv": ("layers", None, "heads"),
                 "A_log": ("layers", None), "dt_bias": ("layers", None),
                 "norm": ("layers", "norm"),
                 "wo": ("layers", "heads", "kv", "embed")}
     elif cfg.kv_lora_rank:
-        attn = {"wq_a": ("layers", "embed", None),
-                "q_a_norm": ("layers", "norm"),
-                "wq_b": ("layers", None, "heads", "kv"),
+        query = {"wq_a": ("layers", "embed", None),
+                 "q_a_norm": ("layers", "norm"),
+                 "wq_b": ("layers", None, "heads", "kv")} \
+            if cfg.q_lora_rank else {"wq": ("layers", "embed", "heads", "kv")}
+        attn = {**query,
                 "wkv_a": ("layers", "embed", None),
                 "kv_a_norm": ("layers", "norm"),
                 "wkv_b": ("layers", None, "heads", "kv"),
@@ -438,9 +520,13 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     as GPT: heads/mlp -> tp, embed -> fsdp, layers -> pp; experts carry
     "expert" -> ep, the router stays replicated over them)."""
     dense = {"dense_layers": _group_axes(cfg, False)} \
-        if cfg.first_dense_layers else {}
+        if cfg.first_dense_layers and not cfg.layer_pattern else {}
     layers = _group_axes(cfg, bool(cfg.num_experts))
-    if cfg.layer_pattern:
+    if cfg.layer_pattern and cfg.first_dense_layers:     # a group a layer
+        layers = tuple(
+            _group_axes(cfg, at >= cfg.first_dense_layers, kind == "linear")
+            for at, kind in enumerate(_layer_kinds(cfg)))
+    elif cfg.layer_pattern:
         layers = tuple(_group_axes(cfg, False, kind == "linear")
                        for kind in cfg.layer_pattern)
     return {
@@ -679,25 +765,33 @@ def mla_softmax_scale(cfg: LlamaConfig) -> float:
     (unrotated + rotated) to the -1/2 and, under YaRN, the square of
     ``yarn_mscale(factor, mscale_all_dim)``, as DeepSeek-V3 has it."""
     scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    if cfg.rope_yarn[5]:
+    if cfg.rope_yarn and cfg.rope_yarn[5]:
         scale *= yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
     return float(scale)
 
 
 def _mla_project(cfg: LlamaConfig, p, h, cos, sin):
     """Latent attention's projections of the normed hidden h [..., D] at
-    the positions of ``cos``/``sin`` [..., qk_rope_dim/2]: the heads'
+    the positions of ``cos``/``sin`` [..., qk_rope_dim/2] (None: nothing is
+    rotated): the heads'
     unrotated queries [..., N, qk_nope_dim] and rotated ones [..., N,
     qk_rope_dim], and the position's cache row [..., kv_lora_rank +
     qk_rope_dim]: the normed compressed key-value and the rotated key that
     all heads share."""
     a, dt = p["attn"], cfg.dtype
     rank, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
-    cq = _rms_norm(jnp.einsum("...d,dr->...r", h, a["wq_a"].astype(dt)),
-                   a["q_a_norm"], cfg.rms_eps)
-    q = jnp.einsum("...r,rnh->...nh", cq, a["wq_b"].astype(dt))
+    if cfg.q_lora_rank:
+        cq = _rms_norm(jnp.einsum("...d,dr->...r", h, a["wq_a"].astype(dt)),
+                       a["q_a_norm"], cfg.rms_eps)
+        q = jnp.einsum("...r,rnh->...nh", cq, a["wq_b"].astype(dt))
+    else:                            # no bottleneck
+        q = jnp.einsum("...d,dnh->...nh", h, a["wq"].astype(dt))
     ckr = jnp.einsum("...d,dr->...r", h, a["wkv_a"].astype(dt))
     c = _rms_norm(ckr[..., :rank], a["kv_a_norm"], cfg.rms_eps)
+    if cos is None:                  # a model that rotates nothing: the
+        #                              shared key and its part of q as they are
+        return q[..., :dn], q[..., dn:], jnp.concatenate(
+            [c, ckr[..., rank:]], axis=-1)
     r = apply_rope_pairs(ckr[..., rank:], cos, sin)
     q_rope = apply_rope_pairs(q[..., dn:], cos[..., None, :],
                               sin[..., None, :])
@@ -801,7 +895,8 @@ def _scan_layers(cfg: LlamaConfig, params, body, carry, t=None,
     the last carry and the scanned layers' ``ys`` (the expert layers'
     loads)."""
     if cfg.layer_pattern:
-        return _scan_periods(cfg, params, body, carry, served)
+        return (_unrolled_layers if cfg.first_dense_layers
+                else _scan_periods)(cfg, params, body, carry, served)
     first = cfg.first_dense_layers
     layers, experts = _scanned_layers(cfg, params)
     pool_layers = _pool_layers(cfg, t) if served else None
@@ -842,6 +937,31 @@ def _scan_periods(cfg: LlamaConfig, params, body, carry, served: bool):
                         (params["layers"], jnp.arange(periods)))
 
 
+def _unrolled_layers(cfg: LlamaConfig, params, body, carry, served: bool):
+    """``_scan_layers`` for a stack of two kinds of layer whose feed-forwards
+    are not one shape either (leading dense layers, then experts): every
+    layer is its own group in ``params["layers"]``, a stack of one, and the
+    layers run in a Python loop (an index of 0 into a stack of one moves
+    nothing).  An expert layer's experts are its own ``mlp``, handed to
+    ``body`` whole beside the index 0 into them, as ``_scanned_layers`` does
+    for a scanned stack.  A ``served`` layer gets its index among the layers
+    of its kind.  Returns the last carry and the expert layers' ``ys``
+    stacked."""
+    seen, ys = {"linear": 0, "full": 0}, []
+    for kind, group in zip(_layer_kinds(cfg), params["layers"]):
+        experts = group["mlp"] if "router" in group["mlp"] else None
+        p = jax.tree.map(lambda a: a[0], {
+            name: leaves for name, leaves in group.items()
+            if experts is None or name != "mlp"})
+        if experts is not None:
+            p["mlp"] = 0             # the layer's index into its own stack
+        carry, y = body(experts, carry, (p, seen[kind]) if served else p)
+        seen[kind] += 1
+        if experts is not None:
+            ys.append(y)
+    return carry, None if None in ys else jnp.stack(ys)
+
+
 def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
          experts=None):
     """The block's feed-forward on the normed hidden ``h`` [..., D]:
@@ -861,7 +981,8 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
             live=None if live is None else live.reshape(-1),
             scoring=cfg.router_scoring, routed_scaling=cfg.routed_scaling,
             shared=_cast_leaves(p["shared"], dt, "wgu", "wd")
-            if cfg.shared_experts else None)
+            if cfg.shared_experts else None,
+            first_expert=cfg.expert_share[0] * _held_experts(cfg))
         return y.reshape(h.shape), load
     gu = jnp.einsum("...d,cdm->c...m", h, p["mlp"]["wgu"].astype(dt))
     a = lc(jax.nn.silu(gu[0]) * gu[1], ("batch", "seq", "mlp"))
@@ -876,10 +997,14 @@ class AttentionState(NamedTuple):
     them).  Each writes, reads and returns ``(o, pools)``.  ``p`` is the
     layer's parameters (a latent kind expands with its ``wkv_b``, a
     recurrent one convolves with its ``conv``), ``layer`` its index into
-    ``pools`` among the layers of its kind, and ``pools`` the kind's own: the
-    trunk carries them from layer to layer and never looks inside.  The
-    projections come in ``_attention``'s and ``_linear_attention``'s
-    layouts."""
+    ``pools`` among the layers of its kind, and ``pools`` the pair the trunk
+    carries from layer to layer and never looks inside: (K pages, V pages);
+    a latent model's (latent pages, None); with linear layers the second is
+    ``RecurrentPools``, beside K pages or beside latent pages.  A model may
+    use two of the three (full layers of either kind of page, and linear
+    ones).  The projections come in ``_attention``'s and
+    ``_linear_attention``'s layouts; ``g`` is a value a head or, for a decay
+    a key channel, ``[..., N, dk]``."""
     kv: Callable
     latent: Optional[Callable] = None
     recurrent: Optional[Callable] = None
@@ -887,13 +1012,14 @@ class AttentionState(NamedTuple):
 
 class RecurrentPools(NamedTuple):
     """What a model with linear layers keeps where the others keep their V
-    pool: that pool (the full layers') and, a row a decode SLOT and not
+    pool: that pool (the full layers'; None where those keep latent pages,
+    which have no V pool) and, a row a decode SLOT and not
     pages, the linear layers' states ``[linear layers, slots, panels, dk,
     lanes]`` float32 (``ops/linear_attention.py``'s folded layout) and the
     last inputs of their convolutions ``[linear layers, slots, (K - 1) *
     channels]``.  A slot's rows are overwritten whole by the next prefill
     into it: nothing allocates or frees them."""
-    v_pages: jax.Array
+    v_pages: Optional[jax.Array]
     state: jax.Array
     conv: jax.Array
 
@@ -931,15 +1057,16 @@ def _linear_sequence(cfg: LlamaConfig, p, qkv, g, beta, length=None):
     N, dv] float32, the state [N, dk, dv] and the convolution's last inputs
     as they stand after position ``length - 1``; None: after the last)."""
     from ray_tpu.ops.linear_attention import (causal_conv, conv_tail,
-                                              gated_delta_chunked)
+                                              gated_delta_chunked,
+                                              kda_chunked)
     w = p["linear"]["conv"]
     with jax.named_scope("linear_conv"):
         mixed = causal_conv(qkv, w)
         tail = conv_tail(qkv, qkv.shape[0] if length is None else length,
                          w.shape[0])
     with jax.named_scope("linear_state"):
-        o, state = gated_delta_chunked(*_linear_split(cfg, mixed), g, beta,
-                                       length)
+        rule = kda_chunked if cfg.linear_gate_rank else gated_delta_chunked
+        o, state = rule(*_linear_split(cfg, mixed), g, beta, length)
     return o, state, tail
 
 
@@ -1030,16 +1157,16 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
         # a row a slot is the slot's own: row b of the batch is slot b.  A
         # parked slot (pos 0) keeps what it holds.
         from ray_tpu.ops.linear_attention import (causal_conv_step,
-                                                  gated_delta_step)
+                                                  gated_delta_step, kda_step)
         rows, live = pools[1], pos > 0
+        step = kda_step if cfg.linear_gate_rank else gated_delta_step
         with jax.named_scope("linear_conv"):
             mixed, tail = causal_conv_step(qkv, p["linear"]["conv"],
                                            rows.conv[layer])
             tail = jnp.where(live[:, None], tail, rows.conv[layer])
         with jax.named_scope("linear_state"):
             held = rows.state[layer]
-            o, state = gated_delta_step(*_linear_split(cfg, mixed), g, beta,
-                                        held)
+            o, state = step(*_linear_split(cfg, mixed), g, beta, held)
             state = jnp.where(live[:, None, None, None], state, held)
             rows = rows._replace(
                 state=jax.lax.dynamic_update_index_in_dim(
@@ -1106,6 +1233,8 @@ def _linear_attention(cfg: LlamaConfig, p, h, state: AttentionState, layer,
     a, dt = p["linear"], cfg.dtype
     N, dv = cfg.linear_heads, cfg.linear_value_dim
     qkv = jnp.einsum("...d,dc->...c", h, a["wqkv"].astype(dt))
+    if cfg.linear_gate_rank:
+        return _kda_gates_and_output(cfg, p, h, qkv, state, layer, pools)
     z = jnp.einsum("...d,dc->...c", h, a["wz"].astype(dt))
     ba = jnp.einsum("...d,dc->...c", h, a["wba"].astype(dt))
     g, beta = decay_and_beta(ba[..., N:], ba[..., :N], a["A_log"],
@@ -1115,13 +1244,41 @@ def _linear_attention(cfg: LlamaConfig, p, h, state: AttentionState, layer,
     return jnp.einsum("...nv,nvd->...d", y, a["wo"].astype(dt)), pools
 
 
-def _gated_norm(cfg: LlamaConfig, scale, o, z):
+def _kda_gates_and_output(cfg: LlamaConfig, p, h, qkv, state, layer, pools):
+    """``_linear_attention`` from the projected channels ``qkv`` on for Kimi
+    Delta Attention (``modeling_kimi.py``'s ``KimiDeltaAttention``): the
+    decay a KEY CHANNEL, ``g = -exp(A_log) softplus((h Wf_a) Wf_b +
+    dt_bias)`` through a bottleneck (the second product and everything after
+    it float32), ``beta = sigmoid(h Wb)`` in (0, 1); the rule; the read-out
+    normed and gated by ``sigmoid((h Wg_a) Wg_b)``."""
+    from ray_tpu.ops.linear_attention import kda_gate
+    a, dt = p["linear"], cfg.dtype
+    N, dv = cfg.linear_heads, cfg.linear_value_dim
+    with jax.named_scope("kda_gate"):
+        f = jnp.einsum("...r,rc->...c",
+                       jnp.einsum("...d,dr->...r", h, a["wf_a"].astype(dt)),
+                       a["wf_b"].astype(dt),
+                       preferred_element_type=jnp.float32)
+        g = kda_gate(f, a["A_log"], a["dt_bias"])
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "...d,dn->...n", h, a["wb"].astype(dt)).astype(jnp.float32))
+    z = jnp.einsum("...r,rc->...c",
+                   jnp.einsum("...d,dr->...r", h, a["wg_a"].astype(dt)),
+                   a["wg_b"].astype(dt))
+    o, pools = state.recurrent(p, layer, pools, qkv, g, beta)
+    y = _gated_norm(cfg, a["norm"], o, z.reshape(*z.shape[:-1], N, dv),
+                    jax.nn.sigmoid)
+    return jnp.einsum("...nv,nvd->...d", y, a["wo"].astype(dt)), pools
+
+
+def _gated_norm(cfg: LlamaConfig, scale, o, z, gate=jax.nn.silu):
     """A linear layer's read-out o [..., N, dv] (float32) RMS-normed over
     each head's values with the one learned ``scale`` [dv], times
-    ``silu(z)``, in the compute dtype."""
+    ``gate(z)`` (``silu``; Kimi Delta Attention's is a sigmoid), in the
+    compute dtype."""
     with jax.named_scope("linear_gate_norm"):
         return (_rms_norm(o, scale, cfg.rms_eps)
-                * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+                * gate(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
 def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
@@ -1231,9 +1388,10 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     layer for every pass of a looped model: ``ut_steps * num_layers``.
     Page 0 is the scratch sink for padded/inactive writes — allocators
     must never hand it out.  With a ``layer_pattern`` the pages are the
-    full layers' alone, and where the V pool would be come
-    ``RecurrentPools``: that pool, and the linear layers' state and
-    convolution rows for ``slots`` decode slots, zeroed (an empty state)."""
+    full layers' alone, K/V or latent, and where the V pool would be come
+    ``RecurrentPools``: that pool (None beside latent pages), and the linear
+    layers' state and convolution rows for ``slots`` decode slots, zeroed
+    (an empty state)."""
     dt = dtype or cfg.dtype
     L = cfg.ut_steps * cfg.num_layers - _linear_layers(cfg)
     if cfg.block_length and page_size % cfg.block_length:
@@ -1242,19 +1400,21 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                          "positions lie in one page")
     if cfg.kv_lora_rank:
         from ray_tpu.ops.paged_attention import latent_width
-        return jnp.zeros((L, num_pages, page_size, latent_width(
+        pages = jnp.zeros((L, num_pages, page_size, latent_width(
             cfg.kv_lora_rank, cfg.qk_rope_dim)), dt), None
-    shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
+    else:
+        shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
+        pages = jnp.zeros(shape, dt), jnp.zeros(shape, dt)
     if not cfg.layer_pattern:
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+        return pages
     from ray_tpu.ops.linear_attention import state_shape
     if slots < 1:
         raise ValueError("a model with linear layers keeps a state row a "
                          "decode slot: say how many slots")
     N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
     rows = (_linear_layers(cfg), slots)
-    return jnp.zeros(shape, dt), RecurrentPools(
-        jnp.zeros(shape, dt),
+    return pages[0], RecurrentPools(
+        pages[1],
         jnp.zeros((*rows, *state_shape(N, dk, dv)), jnp.float32),
         jnp.zeros((*rows, (cfg.linear_conv - 1) * N * (2 * dk + dv)), dt))
 
@@ -1275,15 +1435,19 @@ def llama_serving_params(params: Dict[str, Any],
     bfloat16).  Casting twice is casting once, so the steps return the same
     bits for this tree as for ``params``."""
     dt = cfg.dtype
-    matrices = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo") \
+    matrices = ("wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo") \
         if cfg.kv_lora_rank else ("wq", "wkv", "wo")
+
+    def cast(tree, *names):          # those of ``names`` that it has
+        return _cast_leaves(tree, dt, *(n for n in names if n in tree))
 
     def group(layers, experts):
         # a linear layer's mixer: its gates' A_log and dt_bias stay f32
-        mixer = {"linear": _cast_leaves(layers["linear"], dt, "wqkv", "wz",
-                                        "wba", "conv", "wo")} \
+        mixer = {"linear": cast(
+            layers["linear"], "wqkv", "wz", "wba", "wg_a", "wg_b", "wf_a",
+            "wf_b", "wb", "conv", "wo")} \
             if "linear" in layers else \
-            {"attn": _cast_leaves(layers["attn"], dt, *matrices)}
+            {"attn": cast(layers["attn"], *matrices)}
         out = {**layers, **mixer,
                "mlp": layers["mlp"] if experts else
                _cast_leaves(layers["mlp"], dt, "wgu", "wd")}
@@ -1291,9 +1455,10 @@ def llama_serving_params(params: Dict[str, Any],
             out["shared"] = _cast_leaves(layers["shared"], dt, "wgu", "wd")
         return out
     dense = {"dense_layers": group(params["dense_layers"], False)} \
-        if cfg.first_dense_layers else {}
+        if "dense_layers" in params else {}
     return {**_cast_leaves(params, dt, "wte", "lm_head"), **dense,
-            "layers": tuple(group(g, False) for g in params["layers"])
+            "layers": tuple(group(g, "router" in g["mlp"])
+                            for g in params["layers"])
             if cfg.layer_pattern else group(params["layers"],
                                             bool(cfg.num_experts))}
 
@@ -1531,6 +1696,9 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         new_pools=functools.partial(llama_init_paged_cache, cfg),
         slot_rows=(lambda k_pages, v_pages: (v_pages.state, v_pages.conv))
         if cfg.layer_pattern else None,
+        page_kind="latent" if cfg.kv_lora_rank else "kv",
+        expert_stack=(lambda params: _expert_stack(cfg, params))
+        if cfg.num_experts else None,
         prefill=llama_prefill,
         step=llama_block_step if cfg.block_length else llama_decode_step,
         prefill_attention=llama_prefill_attention,
@@ -1538,6 +1706,15 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         feed=(lambda cfg, logits, state, end: (
             None, block_unmask(cfg, logits, state, end)))
         if cfg.block_length else greedy)
+
+
+def _expert_stack(cfg: LlamaConfig, params) -> Dict[str, Any]:
+    """One stack of routed experts as the tree stores it ({"wgu", "wd",
+    "router", ...}): all expert layers', or, where every layer is a group of
+    its own, the first expert layer's."""
+    if not cfg.layer_pattern:
+        return params["layers"]["mlp"]
+    return params["layers"][cfg.first_dense_layers]["mlp"]
 
 
 def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,

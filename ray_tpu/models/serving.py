@@ -49,6 +49,10 @@ class ServedModel(NamedTuple):
     #                              pages (a recurrent state: written whole by
     #                              a prefill, stepped where it lies, its
     #                              leading dims [layers, slots]); None: none
+    page_kind: str = "kv"        # what the pages hold: "kv" (two pools) or
+    #                              "latent" (one, no V pool)
+    expert_stack: Any = None     # (params) -> a stack of routed experts as
+    #                              stored ({"wd", ...}); None: a dense model
 
 
 def greedy(config, logits, token, pos):

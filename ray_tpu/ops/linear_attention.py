@@ -41,6 +41,30 @@ in one pass, then ``o = alpha S^T q + (k . q) w`` with ``w = beta (v - alpha
 S^T k)``, which is ``S_new^T q`` written out; the second pass writes
 ``alpha S + k w^T``.
 
+THE DECAY A KEY CHANNEL (Kimi Delta Attention, arXiv:2510.26692): ``alpha``
+is a vector over a head's dk key channels and scales the state's ROWS,
+
+    S' = Diag(alpha) S;   S = S' + beta k (v - S'^T k)^T;   o = S^T q
+
+with beta in (0, 1).  ``gated_delta_recurrent`` takes either decay; the
+other two forms, ``kda_chunked`` and ``kda_step``, stand beside the scalar
+ones (whose programs stay as they are; with every channel of a head equal the
+two rules agree).
+What changes is the chunked form.  Between positions j <= i of a chunk the
+decay is no longer one number that multiplies ``k_i . k_j`` but ``sum_c k_ic
+k_jc exp(G_ic - G_jc)`` (``G`` the running sum of ``log alpha`` a channel): a
+product of ``k exp(G)`` with ``k exp(-G)``, whose second factor overflows
+float32 within a chunk of 64 once a channel decays faster than ~0.25 a
+position.  So a chunk is cut into SUB-CHUNKS of 16 (the paper's secondary
+chunking, ``_decayed_products``): a sub-chunk's rows against the positions
+BEFORE it are one matrix product with both factors referred to the
+sub-chunk's start (``exp(G_i - G_start)`` and ``exp(G_start - G_j)``, both
+at most 1: nothing overflows, and what underflows was that small), and
+against the positions of the sub-chunk itself the exponents are taken pair
+by pair, 16 x 16 x dk of them, never above 0.  The step scales the OLD state
+by a key's alpha inside both of its sums and in the write-back, on the
+folded panels as they lie: alpha is spread over the lanes like the keys.
+
 ``causal_conv`` / ``causal_conv_step`` are the depthwise convolution over
 time ahead of the rule (width ``K``, no bias, then SiLU) for a sequence and
 for a decode batch with the ``K - 1`` inputs a slot keeps (``conv_tail``).
@@ -54,6 +78,8 @@ import jax
 import jax.numpy as jnp
 
 CHUNK = 64
+SUB_CHUNK = 16           # of a chunk, where the decay is a key channel's
+GROUP = 8                # chunks whose products are made together, there
 LANES = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -176,16 +202,17 @@ def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 def gated_delta_recurrent(q, k, v, g, beta, state=None):
     """The rule as it is written, a position at a time.  q, k [S, N, dk]
-    (k of unit length, q scaled), v [S, N, dv], g [S, N] = log alpha, beta
-    [S, N], all float32; ``state`` [N, dk, dv] or zeros.  Returns (o [S, N,
-    dv], the state after the last position)."""
+    (k of unit length, q scaled), v [S, N, dv], g = log alpha [S, N] or, a
+    value a key channel, [S, N, dk] (row i of a head's state then decays by
+    alpha_i), beta [S, N], all float32; ``state`` [N, dk, dv] or zeros.
+    Returns (o [S, N, dv], the state after the last position)."""
     N, dk, dv = q.shape[1], q.shape[2], v.shape[2]
     if state is None:
         state = jnp.zeros((N, dk, dv), jnp.float32)
 
     def position(S, row):
         q_t, k_t, v_t, g_t, b_t = row
-        S = S * jnp.exp(g_t)[:, None, None]
+        S = S * jnp.exp(g_t).reshape(N, -1, 1)
         u = jnp.einsum("nij,ni->nj", S, k_t, precision=_HIGHEST)
         S = S + jnp.einsum("ni,nj->nij", k_t, b_t[:, None] * (v_t - u),
                            precision=_HIGHEST)
@@ -278,3 +305,139 @@ def decay_and_beta(a, b, A_log, dt_bias, neg_eigval: bool):
     g = -jnp.exp(A_log) * jax.nn.softplus(a.astype(jnp.float32) + dt_bias)
     beta = jax.nn.sigmoid(b.astype(jnp.float32))
     return g, 2.0 * beta if neg_eigval else beta
+
+
+# ------------------------------------------- the rule, a decay a key channel
+
+def kda_gate(f, A_log, dt_bias):
+    """The per-channel decay's logarithm from its projection f [..., N * dk]
+    (float32 inside): g = -exp(A_log[head]) softplus(f + dt_bias) [..., N,
+    dk]; alpha = exp(g) in (0, 1)."""
+    N = A_log.shape[-1]
+    f = jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+    return -jnp.exp(A_log)[:, None] * f.reshape(*f.shape[:-1], N, -1)
+
+
+def _decayed_products(rows, k, G, sub: int):
+    """For each of ``rows``, ``M[i, j] = sum_c rows[i, c] k[j, c] exp(G[i,
+    c] - G[j, c])`` for the positions j <= i of a chunk and 0 for the
+    others: each of rows, k, G [..., c, dk] (G the inclusive running sum of
+    log alpha, so no exponent taken here is above 0) -> [..., c, c] each.
+    By sub-chunks of ``sub`` positions (module docstring): against the
+    positions before a sub-chunk one product with both factors referred to
+    the sub-chunk's first position's predecessor, inside it pair by pair;
+    the keys' factors and the pairs' exponents are made once for all
+    ``rows``."""
+    *lead, c, dk = k.shape
+    n = c // sub
+
+    def split(a):        # [..., c, dk] -> [..., n, sub, dk]
+        return a.reshape(*lead, n, sub, dk)
+    k_s, G_s = split(k), split(G)
+    # G just before each sub-chunk: the chunk's start (0) for the first
+    before = jnp.concatenate(
+        [jnp.zeros_like(G_s[..., :1, -1, :]), G_s[..., :-1, -1, :]],
+        axis=-2)                                         # [..., n, dk]
+    since = jnp.exp(G_s - before[..., None, :])
+    right = k[..., None, :, :] * jnp.exp(jnp.minimum(
+        before[..., None, :] - G[..., None, :, :], 0.0))  # [..., n, c, dk]
+    at = jnp.arange(c) // sub
+    past = at[None, None, :] < jnp.arange(n)[:, None, None]
+    # inside a sub-chunk: every pair's own exponents
+    pair = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    paired = k_s[..., None, :, :] * jnp.exp(jnp.where(
+        pair, G_s[..., :, None, :] - G_s[..., None, :, :], -jnp.inf))
+
+    def product(rows_s):
+        earlier = jnp.einsum("...aid,...ajd->...aij", rows_s * since, right,
+                             precision=_HIGHEST)         # [..., n, sub, c]
+        own = jnp.sum(rows_s[..., :, None, :] * paired, axis=-1)
+        own = own[..., :, :, None, :] * jnp.eye(n)[:, None, :, None]
+        return jnp.where(past, earlier, 0.0).reshape(*lead, c, c) \
+            + own.reshape(*lead, c, c)
+    return tuple(product(split(a)) for a in rows)
+
+
+def kda_chunked(q, k, v, g, beta, length=None, chunk: int = CHUNK,
+                sub: int = SUB_CHUNK, group: int = GROUP):
+    """``gated_delta_chunked`` with g [S, N, dk] = log alpha a key channel,
+    a chunk's two c x c matrices by sub-chunks (``_decayed_products``).
+    What a chunk needs before the state reaches it (the two matrices, the
+    triangular solve and its two products) is made for ``group`` chunks
+    together, one group after the other in the program's text: made for all
+    of a long sequence's chunks at once, the pair-by-pair exponents of the
+    sub-chunks are hundreds of megabytes that no longer stay in the chip's
+    fast memory (the 1024 rung's scan took four times the 512 rung's), and
+    made in a loop the groups' results are copied into its stacked output
+    (a loop of two groups cost more than it saved; PERF.md section 6, PR
+    51)."""
+    S_len, N, dk = q.shape
+    dv = v.shape[-1]
+    if length is not None:
+        real = jnp.arange(S_len) < length
+        g = jnp.where(real[:, None, None], g, 0.0)
+        beta = jnp.where(real[:, None], beta, 0.0)
+    pad = -S_len % chunk
+    C = (S_len + pad) // chunk
+
+    def chunks(a):       # [S, N, ...] -> [N, C, chunk, ...]
+        a = jnp.pad(a, ((0, pad), *((0, 0),) * (a.ndim - 1)))
+        return jnp.moveaxis(a.reshape(C, chunk, *a.shape[1:]), 2, 0)
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=_HIGHEST)
+
+    def prepared(q, k, v, g, beta):      # of some chunks, [N, chunks, c, ...]
+        G = jnp.cumsum(g, axis=-2)
+        kb, vb = k * beta[..., None], v * beta[..., None]
+        # (I + A) W = [v beta | k beta exp(G)], A strictly lower triangular
+        A, within = _decayed_products((kb, q), k, G, sub)
+        A = jnp.tril(A, -1)
+        T = jax.scipy.linalg.solve_triangular(
+            A + jnp.eye(chunk), jnp.broadcast_to(jnp.eye(chunk), A.shape),
+            lower=True, unit_diagonal=True)
+        return (mm("ncij,ncjd->ncid", T, vb),
+                mm("ncij,ncjd->ncid", T, kb * jnp.exp(G)), within, G)
+    value, k_decayed, within, G = (
+        jnp.concatenate(parts, axis=1) for parts in zip(*(
+            prepared(*(a[:, at:at + group] for a in (q, k, v, g, beta)))
+            for at in range(0, C, group))))
+
+    def one_chunk(S, xs):
+        q_c, k_c, value_c, kd_c, within_c, G_c = xs      # [N, c, ...]
+        v_new = value_c - mm("nid,ndj->nij", kd_c, S)
+        o = mm("nid,ndj->nij", q_c * jnp.exp(G_c), S) \
+            + mm("nij,njd->nid", within_c, v_new)
+        last = G_c[:, -1]                                # [N, dk]
+        S = S * jnp.exp(last)[:, :, None] + mm(
+            "nid,nij->ndj", k_c * jnp.exp(last[:, None] - G_c), v_new)
+        return S, o
+
+    state, o = jax.lax.scan(
+        one_chunk, jnp.zeros((N, dk, dv), jnp.float32),
+        jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0),
+                     (q, k, value, k_decayed, within, G)))
+    o = jnp.moveaxis(o, 1, 2).reshape(C * chunk, N, dv)
+    return o[:S_len], state
+
+
+def kda_step(q, k, v, g, beta, folded):
+    """``gated_delta_step`` with g [B, N, dk] = log alpha a key channel.
+    The old state's row i counts alpha_i in both sums and in the
+    write-back: ``S'^T k = S^T (alpha k)``, ``S'^T q = S^T (alpha q)``, then
+    ``o = S'^T q + (k . q) w`` with ``w = beta (v - S'^T k)`` and the state
+    ``Diag(alpha) S + k w^T``, on the panels as they lie."""
+    N, dv = v.shape[-2], v.shape[-1]
+
+    def per_head(a):     # [B, N] -> its head's value for every column
+        return _values_to_panels(
+            jnp.broadcast_to(a[..., None], v.shape), N, dv)
+    alpha = jnp.exp(g)
+    r_k = jnp.sum(folded * _keys_to_panels(alpha * k, N, dv), axis=-2)
+    r_q = jnp.sum(folded * _keys_to_panels(alpha * q, N, dv), axis=-2)
+    w = per_head(beta) * (_values_to_panels(v, N, dv) - r_k)
+    o = r_q + per_head(jnp.sum(k * q, axis=-1)) * w
+    folded = _keys_to_panels(alpha, N, dv) * folded \
+        + _keys_to_panels(k, N, dv) * w[..., None, :]
+    return _panels_to_values(o, N, dv), folded

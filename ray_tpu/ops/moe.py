@@ -182,7 +182,7 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                  live: Optional[jax.Array] = None,
                  layer: Optional[jax.Array] = None,
                  scoring: str = "softmax", routed_scaling: float = 1.0,
-                 shared: Optional[dict] = None
+                 shared: Optional[dict] = None, first_expert: int = 0
                  ) -> Tuple[jax.Array, jax.Array]:
     """Dropless top-k expert FFN over flat tokens.
 
@@ -220,8 +220,22 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     expert) cast to it, and accumulate in float32: casting the weights
     instead moves all of them every step for the few that are read.
 
+    THE HELD EXPERTS.  Where the router is wider than the stack (``router``
+    [D, R] with R > E), the stack holds experts ``first_expert ..
+    first_expert + E - 1`` of the R the router scores: one chip's share of a
+    layer whose experts are divided over several.  The routing is over all
+    R, gates and all; the assignments that fall on a held expert are
+    computed as above and the others add NOTHING here (they are another
+    chip's part of the sum: sorted behind the last group, where the grouped
+    matmuls visit no row, and left out of the combine), so the shares of all
+    the chips, with the shared expert counted once, add up to the uncut
+    layer (``tests/test_moe_held.py``).  No exchange runs and nothing stands
+    in for the absent chips.  ``load`` is then over the held experts, and
+    its sum against ``live tokens x top_k`` is the share of assignments
+    kept.  With every expert held (R == E) the program is what it was.
+
     Returns (y [T, D] in x's type, load [E] int32: the live tokens'
-    assignments per expert).  The parts carry the scopes ``moe_router``,
+    assignments per held expert).  The parts carry the scopes ``moe_router``,
     ``moe_dispatch``, ``moe_experts``, ``moe_combine`` and ``moe_shared``
     for the profiler.
     """
@@ -243,6 +257,11 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         bias = p["router_bias"][layer] if "router_bias" in p else None
         gates, experts = _route(logits, bias, top_k, scoring,
                                 norm_topk_prob, routed_scaling)
+    kept = None
+    if p["router"].shape[-1] != E or first_expert:
+        # held experts count from 0; E, one past them, is nobody's here
+        kept = (experts >= first_expert) & (experts < first_expert + E)
+        experts = jnp.where(kept, experts - first_expert, E)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(A)                  # assignment -> expert
         chosen = flat[:, None] == jnp.arange(E)[None, :]         # [A, E]
@@ -278,7 +297,10 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         # back to token order by the inverse permutation (a gather, not a
         # scatter-add), then the gate-weighted sum of each token's k
         back = ys[jnp.argsort(order)].reshape(T, top_k, D)
-        y = jnp.sum(back.astype(jnp.float32) * gates[:, :, None], axis=1)
+        weighed = back.astype(jnp.float32) * gates[:, :, None]
+        if kept is not None:     # a row no group visited holds anything
+            weighed = jnp.where(kept[:, :, None], weighed, 0.0)
+        y = jnp.sum(weighed, axis=1)
     if shared is not None:
         with jax.named_scope("moe_shared"):
             gu = jnp.einsum("td,cdm->ctm", x, shared["wgu"])

@@ -157,7 +157,8 @@ A model's pages may be of another kind (a latent model's: ONE pool, and
 ``new_pools``), nothing configures it; the programs take and return the pair
 of pools either way, so the loop, the donation, the views and the counters
 below are the same code for both kinds.  ``stats()["kv_page_kind"]`` says
-which.
+which (the record's ``page_kind``; a model may keep rows a decode slot
+beside latent pages, and then ``_v_pages`` holds those rows and no V pool).
 
 Either pool may be a TREE of arrays, and some of a model's arrays may hold
 **a row a decode slot** instead of pages (the record's ``slot_rows``; a
@@ -235,7 +236,12 @@ touched summed over layers, the largest single-expert load summed over layers)
 is the attributes of an ``rt:engine.decode.moe`` or ``rt:engine.prefill.moe``
 region, beside the ``weight_itemsize`` the experts are stored in (what a step
 reads of a touched expert, for a roofline), and adds to the ``moe_*`` counters
-of ``stats()``.  A dense model's programs return three results and none of
+of ``stats()``.  The experts are those the program HOLDS: where that is a
+share of what the router scores (one chip's part of a layer divided over
+several), ``assignments`` are those that fell on a held expert and
+``assignments_made`` every real token's, and ``stats()["moe_load"]`` is the
+held experts' load by layer.  A dense model's programs return three results
+and none of
 this runs."""
 
 from __future__ import annotations
@@ -431,6 +437,10 @@ class InferenceEngine:
         self._k_pages, self._v_pages = self._new_pools()
         # of the pools' arrays, those that hold a row a slot and not pages
         self._slot_rows = served.slot_rows is not None
+        self._page_kind = served.page_kind
+        # what a parameter of the routed experts takes as stored
+        self._expert_itemsize = served.expert_stack(
+            self._params)["wd"].dtype.itemsize if served.expert_stack else 0
         self._recurrent_state_bytes = sum(
             a.nbytes for a in served.slot_rows(
                 self._k_pages, self._v_pages)) if self._slot_rows else 0
@@ -527,8 +537,9 @@ class InferenceEngine:
         self._slot_steps = 0
         self._retired = {"done": 0, "cancelled": 0, "expired": 0,
                          "error": 0}
-        self._moe = {"moe_assignments": 0, "moe_experts_hit": 0,
-                     "moe_load_max": 0}
+        self._moe = {"moe_assignments": 0, "moe_assignments_made": 0,
+                     "moe_experts_hit": 0, "moe_load_max": 0}
+        self._moe_load: Optional[np.ndarray] = None   # [layers, held]
         self._block_stats = {
             "slot_steps_denoise": 0, "slot_steps_commit": 0,
             "blocks_committed": 0, "tokens_committed": 0,
@@ -638,7 +649,13 @@ class InferenceEngine:
         layers and steps: over ``layers x num_experts`` a step, the share
         of expert weights it had to read) and ``moe_load_max`` (the largest
         single-expert load, summed likewise: over ``moe_assignments /
-        num_experts``, how uneven the routing was).  ``weight_bytes`` is
+        num_experts``, how uneven the routing was); the experts are those
+        the program HOLDS (``LlamaConfig.expert_share``), so beside them
+        ``moe_assignments_made`` counts every real token's assignments,
+        held or not (their ratio is the share that fell on this program's
+        experts; 1 where it holds them all), and ``moe_load`` [expert
+        layers, held experts] is the held experts' load since the start.
+        ``weight_bytes`` is
         the size of the parameters as the engine stores them,
         ``kv_pool_bytes`` that of the K and V pools or, where
         ``kv_page_kind`` is "latent" and not "kv", of the one pool of latent
@@ -708,14 +725,15 @@ class InferenceEngine:
                 "decode": {"paged_read": dict(self._decode_paged_read)},
                 "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
+                **({} if self._moe_load is None else
+                   {"moe_load": self._moe_load.tolist()}),
                 **({"block": {**self._block_stats, "denoise_passes_by_count":
                               dict(self._block_stats[
                                   "denoise_passes_by_count"])}}
                    if self._block else {}),
                 "weight_bytes": self._weight_bytes,
                 "kv_pool_bytes": self._kv_pool_bytes,
-                "kv_page_kind": "kv" if self._v_pages is not None
-                else "latent",
+                "kv_page_kind": self._page_kind,
                 "kv_pool_layers": self._k_pages.shape[0],
                 "kv_bytes_per_token": self._kv_pool_bytes // (
                     self.config.num_pages * self.config.page_size),
@@ -1101,7 +1119,8 @@ class InferenceEngine:
         cancellation, a deadline: what the host could not foresee) gets
         nothing, whoever holds its slot now: a stray slot step."""
         nxt, load = fetched
-        self._count_moe("decode", load)
+        self._count_moe("decode", load,
+                        len(step.seqs) * max(self._block, 1))
         if self._block:
             return self._deliver_blocks(step, nxt, submitted, lane)
         tokens = []
@@ -1243,20 +1262,28 @@ class InferenceEngine:
         else:
             self._deliver_step(prev, fetched, submitted, lane)
 
-    def _count_moe(self, program: str, load: Sequence[np.ndarray]):
-        """What an expert model's program said of its real tokens' routing
-        (``load`` [L, E], inside a list that is empty for a dense model):
-        into ``stats()`` and onto the profiler's timeline, there with the
-        bytes a parameter of the experts takes as the program stores them."""
+    def _count_moe(self, program: str, load: Sequence[np.ndarray],
+                   tokens: int):
+        """What an expert model's program said of its ``tokens`` real
+        tokens' routing (``load`` [L, E] over the experts the program HOLDS,
+        inside a list that is empty for a dense model): into ``stats()`` and
+        onto the profiler's timeline, there with the bytes a parameter of the
+        experts takes as the program stores them.  ``assignments`` are those
+        that fell on a held expert, of the ``assignments_made`` (every
+        token's ``experts_per_token`` a layer): all of them where the
+        program holds every expert."""
         for per_layer in load:
-            stored = self._params["layers"]["mlp"]["wd"].dtype.itemsize
             step = {"assignments": int(per_layer.sum()),
+                    "assignments_made": tokens * per_layer.shape[0]
+                    * self.model_config.experts_per_token,
                     "experts_hit": int(np.count_nonzero(per_layer)),
                     "load_max": int(per_layer.max(axis=1).sum())}
             for key, value in step.items():
                 self._moe["moe_" + key] += value
-            with region(f"engine.{program}.moe", weight_itemsize=stored,
-                        **step):
+            self._moe_load = per_layer if self._moe_load is None \
+                else self._moe_load + per_layer
+            with region(f"engine.{program}.moe",
+                        weight_itemsize=self._expert_itemsize, **step):
                 pass
 
     def _push(self, seq: _Sequence, token: int) -> bool:
@@ -1359,7 +1386,7 @@ class InferenceEngine:
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
                     self._state_rows_written += self._slot_rows
-                    self._count_moe("prefill", load)
+                    self._count_moe("prefill", load, int(whole))
                     self._deliver([] if tok is None else [(seq, tok)],
                                   submitted, lane)
 

@@ -135,6 +135,16 @@ STALE_SNAPSHOTS = {
         "left it; PR 50 stores a page [page, Wp] in whole lane tiles.  Its "
         "other assertions run, on that shape, as test_xing_latent_pool.py::"
         "test_absorbed_decode_is_expanded_decode_on_padded_pages",
+    "test_paged_read_metrics.py::test_what_the_hybrid_cell_added_still_stands":
+        "counts the benchmark's cells (9) and configurations (8) and takes "
+        "the lists' last entries as PR 49 left them; PR 51 added one of "
+        "each.  Its other assertions run as test_spec_kimi_linear.py::"
+        "test_what_the_hybrid_cell_added_still_stands",
+    "test_paged_kernel_share_xing.py::"
+    "test_the_benchmark_lists_it_last_and_the_others_before_it":
+        "takes the per-layer list's last five entries as PR 50 left them; PR "
+        "51 added the Kimi cell's after them.  Its other assertions run as "
+        "test_spec_kimi_linear.py::test_the_paged_read_entries_still_stand",
 }
 
 
