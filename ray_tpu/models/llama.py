@@ -1156,22 +1156,17 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
     def recurrent(p, layer, pools, qkv, g, beta):
         # a row a slot is the slot's own: row b of the batch is slot b.  A
         # parked slot (pos 0) keeps what it holds.
-        from ray_tpu.ops.linear_attention import (causal_conv_step,
-                                                  gated_delta_step, kda_step)
+        from ray_tpu.ops.linear_attention import causal_conv_step, step_pool
         rows, live = pools[1], pos > 0
-        step = kda_step if cfg.linear_gate_rank else gated_delta_step
         with jax.named_scope("linear_conv"):
             mixed, tail = causal_conv_step(qkv, p["linear"]["conv"],
                                            rows.conv[layer])
             tail = jnp.where(live[:, None], tail, rows.conv[layer])
         with jax.named_scope("linear_state"):
-            held = rows.state[layer]
-            o, state = step(*_linear_split(cfg, mixed), g, beta, held)
-            state = jnp.where(live[:, None, None, None], state, held)
+            o, state = step_pool(*_linear_split(cfg, mixed), g, beta,
+                                 rows.state, layer, live)
             rows = rows._replace(
-                state=jax.lax.dynamic_update_index_in_dim(
-                    rows.state, state, layer, 0),
-                conv=jax.lax.dynamic_update_index_in_dim(
+                state=state, conv=jax.lax.dynamic_update_index_in_dim(
                     rows.conv, tail, layer, 0))
         return o, (pools[0], rows)
     return AttentionState(kv, latent, recurrent)
@@ -1504,6 +1499,15 @@ def llama_paged_read(cfg: LlamaConfig, k_pages) -> str:
         (1, cfg.num_heads, head), cfg.dtype), k_pages)
 
 
+def llama_linear_state(cfg: LlamaConfig, v_pages) -> str:
+    """What a token step's programs step the linear layers' states with,
+    "kernel" or "rule" (``ops/linear_attention.py::state_step_kind`` of the
+    rows' pool ``v_pages.state``)."""
+    from ray_tpu.ops.linear_attention import state_step_kind
+    return state_step_kind(v_pages.state, cfg.linear_heads,
+                           cfg.linear_value_dim)
+
+
 def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
                   state: AttentionState, live, *pools):
     """Every pass over every layer of a served program, ``pools`` carried
@@ -1702,7 +1706,9 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         prefill=llama_prefill,
         step=llama_block_step if cfg.block_length else llama_decode_step,
         prefill_attention=llama_prefill_attention,
-        paged_read=llama_paged_read, block=cfg.block_length,
+        paged_read=llama_paged_read,
+        linear_state=llama_linear_state if cfg.layer_pattern else None,
+        block=cfg.block_length,
         feed=(lambda cfg, logits, state, end: (
             None, block_unmask(cfg, logits, state, end)))
         if cfg.block_length else greedy)

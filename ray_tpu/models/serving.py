@@ -49,6 +49,10 @@ class ServedModel(NamedTuple):
     #                              pages (a recurrent state: written whole by
     #                              a prefill, stepped where it lies, its
     #                              leading dims [layers, slots]); None: none
+    linear_state: Any = None     # (config, v_pages) -> "kernel" | "rule":
+    #                              what the step's programs step the linear
+    #                              layers' states with (``ops/
+    #                              linear_attention.py``); None: no such layer
     page_kind: str = "kv"        # what the pages hold: "kv" (two pools) or
     #                              "latent" (one, no V pool)
     expert_stack: Any = None     # (params) -> a stack of routed experts as

@@ -21,7 +21,7 @@ arithmetic, all float32:
     (a rung's padded tail) get alpha = 1 and beta = 0 and leave the state
     alone.
 ``gated_delta_step``       one position for every slot of a decode batch,
-    on the state as the pool stores it.
+    on the states of one layer as the pool stores them.
 
 The pool stores a slot's state FOLDED (``fold_state``): the TPU tiles an
 array's last axis in 128 lanes, and a ``[dk, 192]`` matrix would lie in 256
@@ -36,10 +36,24 @@ belongs to a head (k, q, alpha, beta) is spread over its columns' lanes
 dv's remainder does not divide 128, or the heads do not fill its panels, a
 head's dv columns are one panel and the layout is plain.
 
-The step reads the OLD state for both of its sums: ``S^T k`` and ``S^T q``
-in one pass, then ``o = alpha S^T q + (k . q) w`` with ``w = beta (v - alpha
-S^T k)``, which is ``S_new^T q`` written out; the second pass writes
-``alpha S + k w^T``.
+The step reads the OLD state for both of its sums, ``S^T k`` and ``S^T q``,
+then ``o = alpha S^T q + (k . q) w`` with ``w = beta (v - alpha S^T k)``,
+which is ``S_new^T q`` written out, and the new state is ``alpha S + k w^T``.
+WHO RUNS IT (``step_pool``, the token step's one entry, on the pool of every
+linear layer's states ``[L, B, panels, dk, lanes]``; ``state_step_kind`` says
+which).  On the chip the Pallas kernel ``ops/linear_state.py``: a slot's
+panels of the layer come into fast memory once, both sums, ``w``, ``o`` and
+the new state are taken from that copy, and it goes back where it lay; the
+pool is the kernel's operand and its result in one buffer, no slab of a
+layer is sliced out, selected against or written back, and a parked slot's
+rows are copied through.  One body for both decays, a head's scalar being a
+head's key channels all equal.  On the CPU (and wherever the pool is not
+one the kernel is written for, or a caller has replaced one of this
+module's two steps) the jnp forms ``gated_delta_step`` / ``kda_step`` on the
+layer's slab, which are also what the kernel is tested against: one fusion
+takes the two sums, a second reads the states again and writes the new
+ones, and ``step_pool`` selects the parked slots' old rows back and writes
+the slab into the pool.
 
 THE DECAY A KEY CHANNEL (Kimi Delta Attention, arXiv:2510.26692): ``alpha``
 is a vector over a head's dk key channels and scales the state's ROWS,
@@ -61,9 +75,10 @@ BEFORE it are one matrix product with both factors referred to the
 sub-chunk's start (``exp(G_i - G_start)`` and ``exp(G_start - G_j)``, both
 at most 1: nothing overflows, and what underflows was that small), and
 against the positions of the sub-chunk itself the exponents are taken pair
-by pair, 16 x 16 x dk of them, never above 0.  The step scales the OLD state
-by a key's alpha inside both of its sums and in the write-back, on the
-folded panels as they lie: alpha is spread over the lanes like the keys.
+by pair, 16 x 16 x dk of them, never above 0.  The step (``kda_step``)
+scales the OLD state by a key's alpha inside both of its sums and in the
+write-back, on the folded panels as they lie: alpha is spread over the lanes
+like the keys.
 
 ``causal_conv`` / ``causal_conv_step`` are the depthwise convolution over
 time ahead of the rule (width ``K``, no bias, then SiLU) for a sequence and
@@ -76,6 +91,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import linear_state
 
 CHUNK = 64
 SUB_CHUNK = 16           # of a chunk, where the decay is a key channel's
@@ -441,3 +458,63 @@ def kda_step(q, k, v, g, beta, folded):
     folded = _keys_to_panels(alpha, N, dv) * folded \
         + _keys_to_panels(k, N, dv) * w[..., None, :]
     return _panels_to_values(o, N, dv), folded
+
+
+# ------------------------------------------------ the step, on the pool
+
+# the module's own two steps: a caller that replaces one (a numerics tool
+# planting a fault) gets its step run, on every backend
+_OWN_STEPS = (gated_delta_step, kda_step)
+
+
+def _kernel_backend() -> bool:
+    """Whether programs are being made for a backend the kernel is compiled
+    for: anything but the CPU, where it would run in the interpreter."""
+    return jax.default_backend() != "cpu"
+
+
+def state_step_kind(pool, N: int, dv: int) -> str:
+    """What ``step_pool`` steps the states of ``N`` heads of ``dv`` values
+    in ``pool`` (an array or its shape) with, "kernel" (``ops/
+    linear_state.py``) or "rule" (``gated_delta_step`` / ``kda_step`` on the
+    layer's slab), from what it can observe: the backend, whether the
+    kernel is written for the pool (``linear_state.supported``: float32,
+    folded into panels of 128 lanes, key channels of whole sublane tiles),
+    and whether this module's two steps are still its own.  Measured on the
+    chip at both published shapes (``scripts/linear_state_sweep.py``;
+    PERF.md section 6, PR 52)."""
+    _, whole, side = _panel_plan(N, dv)
+    if _kernel_backend() and (gated_delta_step, kda_step) == _OWN_STEPS \
+            and linear_state.supported(pool.shape, pool.dtype, N, whole,
+                                       side):
+        return "kernel"
+    return "rule"
+
+
+def step_pool(q, k, v, g, beta, pool, layer, live):
+    """One position for each slot on the states where the pool stores them:
+    q, k [B, N, dk], v [B, N, dv], beta [B, N], g = log alpha [B, N] (a
+    head's) or [B, N, dk] (a key channel's), ``pool`` [L, B, panels, dk,
+    lanes] float32 (every linear layer's folded states), ``layer`` the
+    layer's index into it, ``live`` [B] bool: a slot that is not keeps its
+    rows to the bit.  Returns (o [B, N, dv], the pool)."""
+    N, dv = v.shape[-2], v.shape[-1]
+    if state_step_kind(pool, N, dv) == "rule":
+        held = pool[layer]
+        step = kda_step if g.ndim == 3 else gated_delta_step
+        o, state = step(q, k, v, g, beta, held)
+        state = jnp.where(live[:, None, None, None], state, held)
+        return o, jax.lax.dynamic_update_index_in_dim(pool, state, layer, 0)
+    if g.ndim == 2:      # a head's decay: its key channels' all equal
+        g = jnp.broadcast_to(g[..., None], k.shape)
+
+    def per_head(a):     # [B, N] -> its head's value for every column
+        return _values_to_panels(
+            jnp.broadcast_to(a[..., None], v.shape), N, dv)
+    rows = jnp.stack([_values_to_panels(v, N, dv), per_head(beta),
+                      per_head(jnp.sum(k * q, axis=-1))], axis=1)
+    _, whole, side = _panel_plan(N, dv)
+    o, pool = linear_state.state_step(
+        pool, layer, live, linear_state.columns(jnp.exp(g), k, q), rows,
+        heads=N, whole=whole, side=side)
+    return _panels_to_values(o, N, dv), pool

@@ -198,6 +198,14 @@ turn either into bytes, ``decode_shapes``, the steps by rung, and
 ``rt:engine.decode.dispatch`` carries its step's numbers as ``live_tokens``,
 ``gathered_tokens``, ``width_pages`` and ``paged_read``.
 
+What a decode step steps a linear layer's states with is likewise its
+program's (``ops/linear_attention.py::state_step_kind``, asked once at
+construction): "kernel" on the chip (``ops/linear_state.py``: a slot's rows
+read once and written where they lie), "rule" on the CPU (the jnp step on
+the layer's slab).  ``stats()["decode"]["linear_state"]`` counts the steps
+by which, and each ``rt:engine.decode.dispatch`` of such a model carries it
+as ``linear_state``.
+
 Observability: every synchronous section of the per-token path is a
 ``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
 ``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
@@ -502,6 +510,10 @@ class InferenceEngine:
         # what the decode programs read the pages with ("kernel": each
         # sequence's own pages copied where they lie; "gather")
         self._paged_read = served.paged_read(mc, self._k_pages)
+        # what they step the linear layers' states with ("kernel", "rule";
+        # None: the model has no such layer)
+        self._linear_state = served.linear_state and served.linear_state(
+            mc, self._v_pages)
         shapes = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self._params, self._k_pages, self._v_pages))
@@ -519,6 +531,7 @@ class InferenceEngine:
         self._prefill_attention = {"dense": 0, "flash": 0}
         self._decode_shapes = dict.fromkeys(self._decode_rungs, 0)
         self._decode_paged_read = {"gather": 0, "kernel": 0}
+        self._decode_linear_state = {"kernel": 0, "rule": 0}
 
         self._waiting: collections.deque = collections.deque()
         self._active: Dict[int, _Sequence] = {}   # slot -> sequence
@@ -643,6 +656,10 @@ class InferenceEngine:
         in pages (a rung of ``decode_rungs``; they sum to ``steps``),
         ``decode["paged_read"]``, the decode steps by what their program
         reads the pages with ("kernel", "gather"),
+        ``decode["linear_state"]``, the decode steps by what their program
+        steps the linear layers' states with ("kernel": each slot's rows read
+        once and written where they lie; "rule": the jnp step on the layer's
+        slab; both 0 for a model with no linear layer),
         ``retired`` sequences by reason, and of a model with experts the
         ``moe_assignments`` of real tokens (token x layer x k), the
         ``moe_experts_hit`` (distinct experts a step touched, summed over
@@ -722,7 +739,8 @@ class InferenceEngine:
                 "prefill_padded_tokens": self._prefill_padded_tokens,
                 "prefill_shapes": dict(self._prefill_shapes),
                 "prefill": {"attention": dict(self._prefill_attention)},
-                "decode": {"paged_read": dict(self._decode_paged_read)},
+                "decode": {"paged_read": dict(self._decode_paged_read),
+                           "linear_state": dict(self._decode_linear_state)},
                 "decode_shapes": dict(self._decode_shapes),
                 "retired": dict(self._retired), **self._moe,
                 **({} if self._moe_load is None else
@@ -1214,7 +1232,10 @@ class InferenceEngine:
         gathered_tokens = cfg.page_size * (
             int((pos // cfg.page_size).sum()) + cfg.max_batch
             if paged_read == "kernel" else tables.size)
-        blocks = {"block_len": self._block} if self._block else {}
+        linear_state = self._linear_state
+        # what only some models' steps carry
+        more = {**({"block_len": self._block} if self._block else {}),
+                **({"linear_state": linear_state} if linear_state else {})}
         submitted, sampled = self._submit(cpu=True)
         # everything the step before cost the loop: its delivery,
         # the streams' fan-out, schedule, the prefills between
@@ -1232,7 +1253,7 @@ class InferenceEngine:
                         ahead=int(prev is not None),
                         submit_us=_us(start.wall - submitted.wall),
                         step_us=_us(step_s),
-                        step_loop_cpu_us=_us(step_loop_cpu_s), **blocks):
+                        step_loop_cpu_us=_us(step_loop_cpu_s), **more):
                 _, kp, vp, *load, nxt = self._donate_pools(
                     "decode", program, token, pos, tables)
                 # on their way once the step ends
@@ -1251,6 +1272,8 @@ class InferenceEngine:
         self._decode_ahead_steps += prev is not None
         self._decode_shapes[width] += 1
         self._decode_paged_read[paged_read] += 1
+        if linear_state:
+            self._decode_linear_state[linear_state] += 1
         self._slot_steps += active
         self._kv_live_token_steps += live_tokens
         self._kv_gathered_token_steps += gathered_tokens

@@ -78,6 +78,41 @@ def test_six_requests_through_four_slots_get_what_they_get_alone(
     assert stats["kv_bytes_per_token"] == 2 * 2 * 32 * 4
     assert 0.05 < stats["recurrent_step_bytes_share"] < 0.95
     assert stats["kv_pool_in_place"] == {"prefill": True, "decode": True}
+    # on this backend every step's program steps the states by the rule
+    assert stats["decode"]["linear_state"] == {"kernel": 0,
+                                               "rule": stats["steps"]}
+
+
+def test_the_engine_on_the_kernel_gives_the_same_tokens(params, monkeypatch):
+    """The decode programs traced as on the chip (ISSUE 52): the linear
+    layers' states stepped by ``ops/linear_state.py`` where the pool holds
+    them (here in the interpreter), slots parked beside live ones, a slot
+    reused; the tokens are those each sequence gets alone, and ``stats()``
+    and the dispatch regions count the steps under "kernel"."""
+    from ray_tpu.ops import linear_attention
+    monkeypatch.setattr(linear_attention, "_kernel_backend", lambda: True)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 97, n).tolist() for n in (7, 19, 11, 26, 5)]
+    news = [9, 4, 7, 6, 8]
+    eng = InferenceEngine(EngineConfig(
+        model="llama", model_config=CFG, page_size=PAGE,
+        num_pages=BATCH * (SEQ // PAGE) + 1, max_batch=BATCH,
+        max_prompt_len=PROMPT, max_new_tokens=NEW), params=params)
+    try:
+        async def main():
+            async def one(prompt, new):
+                return [t async for t in eng.generate(prompt, new)]
+            return await asyncio.gather(*map(one, prompts, news))
+        got = asyncio.run(main())
+        stats = eng.stats()
+    finally:
+        eng.close()
+    for prompt, new, tokens in zip(prompts, news, got):
+        assert tokens == alone(params, prompt, new)
+    assert stats["state_rows_written"] == 5
+    assert stats["steps"] > 0
+    assert stats["decode"]["linear_state"] == {"kernel": stats["steps"],
+                                               "rule": 0}
 
 
 def test_the_views_take_the_slot_last_and_default_to_the_first(engine):
@@ -129,5 +164,6 @@ def test_a_model_without_rows_reports_none():
         stats = eng.stats()
         assert "recurrent_state_bytes" not in stats
         assert "state_rows_written" not in stats
+        assert stats["decode"]["linear_state"] == {"kernel": 0, "rule": 0}
     finally:
         eng.close()

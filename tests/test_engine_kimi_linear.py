@@ -96,6 +96,9 @@ def test_six_requests_through_four_slots_get_what_they_get_alone(
         * PAGE * 128 * 4
     assert 0.0 < stats["recurrent_step_bytes_share"] < 0.95
     assert stats["kv_pool_in_place"] == {"prefill": True, "decode": True}
+    # on this backend every step's program steps the states by the rule
+    assert stats["decode"]["linear_state"] == {"kernel": 0,
+                                               "rule": stats["steps"]}
     # every real token makes 4 assignments in each of the 7 expert layers;
     # this program holds share 1 of 4 and keeps what falls there
     tokens = sum(PROMPTS) + stats["slot_steps"]
@@ -105,6 +108,54 @@ def test_six_requests_through_four_slots_get_what_they_get_alone(
     assert load.shape == (7, 4) and load.sum() == stats["moe_assignments"]
     assert 0.05 < stats["moe_assignments"] \
         / stats["moe_assignments_made"] < 0.6
+
+
+@pytest.mark.parametrize("kind", ["kernel", "rule"])
+def test_heads_of_whole_panels_step_by_the_kernel_to_the_same_tokens(
+        params, monkeypatch, kind):
+    """Heads of 128 values (whole panels, as published) and the decay a key
+    channel, the decode programs traced as on the chip (ISSUE 52; "kernel":
+    ``ops/linear_state.py`` in the interpreter) and as here ("rule"): three
+    requests get from the engine what the full forward gives them, and
+    ``stats()`` counts the steps by what stepped the states."""
+    from ray_tpu.ops import linear_attention
+    monkeypatch.setattr(linear_attention, "_kernel_backend",
+                        lambda: kind == "kernel")
+    cfg = dataclasses.replace(CFG, linear_value_dim=128)
+    # the state's read-out is eight values a head no more: another tree
+    tree = llama.llama_init(jax.random.PRNGKey(2), cfg)
+    tree = jax.tree_util.tree_map_with_path(
+        lambda path, a: 8.0 * a if getattr(path[-1], "key", "") in (
+            "wo", "wd") else a, tree)
+    forward = jax.jit(lambda t: llama.llama_forward(tree, t, cfg))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 97, n).tolist() for n in (6, 15, 10)]
+    news, alone = [5, 3, 4], []
+    for prompt, new in zip(prompts, news):
+        seq = list(prompt)
+        for _ in range(new):
+            padded = np.zeros((1, SEQ), np.int32)
+            padded[0, :len(seq)] = seq
+            seq.append(int(jnp.argmax(forward(padded)[0, len(seq) - 1])))
+        alone.append(seq[len(prompt):])
+    eng = InferenceEngine(EngineConfig(
+        model="llama", model_config=cfg, page_size=PAGE,
+        num_pages=BATCH * (SEQ // PAGE) + 1, max_batch=BATCH,
+        max_prompt_len=PROMPT, max_new_tokens=NEW), params=tree)
+    try:
+        async def main():
+            async def one(prompt, new):
+                return [t async for t in eng.generate(prompt, new)]
+            return await asyncio.gather(*map(one, prompts, news))
+        got = asyncio.run(main())
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert got == alone
+    other = "rule" if kind == "kernel" else "kernel"
+    assert stats["steps"] > 0
+    assert stats["decode"]["linear_state"] == {kind: stats["steps"],
+                                               other: 0}
 
 
 def test_a_prefill_that_writes_the_wrong_slots_rows_is_seen(

@@ -38,6 +38,8 @@ fa = importlib.import_module("ray_tpu.ops.flash_attention")
 gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
 pa = importlib.import_module("ray_tpu.ops.paged_attention")
 pr = importlib.import_module("ray_tpu.ops.paged_read")
+la = importlib.import_module("ray_tpu.ops.linear_attention")
+ls = importlib.import_module("ray_tpu.ops.linear_state")
 
 HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
 
@@ -105,9 +107,42 @@ def compiled_paged_read(monkeypatch):
     monkeypatch.setattr(pr, "_resolve", lambda *a: real(*a[:-1], False))
 
 
+@pytest.fixture
+def compiled_linear_state(monkeypatch):
+    """``step_pool`` asks the backend whether to step the states through
+    ``ops/linear_state.py``'s kernel, and the kernel whether to run in the
+    interpreter; make both answer as they do on the chip."""
+    monkeypatch.setattr(la, "_kernel_backend", lambda: True)
+    monkeypatch.setattr(ls, "_interpreted", lambda: False)
+
+
+def _linear_state_kernels(text: str, pool: str) -> list:
+    """The program's instructions that are the Pallas kernel ``linear_state``
+    under the scope ``linear_state`` (what the benchmark's readers find the
+    states' device time by), each held to take the whole pool ``pool`` as its
+    one operand of that shape, and to hand it back as a result in the same
+    buffer (``output_to_operand_aliasing``)."""
+    calls = _paged_read_kernels(text, pool, "linear_state", pools=1,
+                                kernel="linear_state")
+    for call in calls:
+        assert "output_to_operand_aliasing={{1}: (4, {})}" in call, call
+    return calls
+
+
+def made_of_shape(text: str, shape: str, but: str = "") -> list:
+    """The program's instructions that MAKE an array of ``shape`` (a fusion,
+    a copy, an update-slice: anything but a parameter, an element of a tuple,
+    a bitcast, a loop, or ``but``), each cut to its head."""
+    shape = re.escape(shape)
+    skip = "parameter|get-tuple-element|bitcast|while|tuple|conditional" + (
+        "|" + but if but else "")
+    return [line.strip()[:160] for line in text.splitlines() if re.search(
+        rf"= {shape}\S* (?!{skip})", line)]
+
+
 def _paged_read_kernels(text: str, pool: str, scope: str = "paged_read",
-                        pools: int = 2) -> list:
-    """The program's instructions that are the Pallas kernel ``paged_read``
+                        pools: int = 2, kernel: str = "paged_read") -> list:
+    """The program's instructions that are the Pallas kernel ``kernel``
     under the scope ``scope`` (what the benchmark's readers find the read's
     device time by), each held to take ``pools`` pools of the whole shape
     ``pool`` as operands: every layer's pages as they are stored."""
@@ -115,7 +150,7 @@ def _paged_read_kernels(text: str, pool: str, scope: str = "paged_read",
              if 'custom_call_target="tpu_custom_call"' in line
              and re.search(rf'op_name="[^"]*[/(]{scope}[/)]', line)]
     for call in calls:
-        assert re.match(r"\s*%paged_read[\w.]* = ", call), call[:80]
+        assert re.match(rf"\s*%{kernel}[\w.]* = ", call), call[:80]
         operands = call[call.index("operand_layout_constraints="):
                         call.index("backend_config=")]
         assert operands.count(pool + "{") == pools, operands
@@ -640,6 +675,34 @@ def test_paged_read_module_names_no_source(topo):
             shape((), jnp.int32), shape((48,), jnp.int32),
             shape((48, 96), jnp.int32)),
         b"paged_read")
+
+
+@pytest.mark.parametrize("heads,dk,dv,layers,slots", [
+    (30, 96, 192, 9, 48), (32, 128, 128, 6, 64)])
+def test_linear_state_compiles_and_its_module_names_no_source(
+        topo, heads, dk, dv, layers, slots):
+    """``ops/linear_state.py`` at the two published shapes (Olmo-Hybrid's 45
+    panels, 15 of them two heads side by side; Kimi-Linear's 32): Mosaic
+    takes the loop's dynamic rotation of the columns' tile, the lane slices
+    and broadcasts of the columns and the rows indexed by the panel, the pool donated is
+    the result's buffer with no temporary, and the module names its kernel
+    and no file (``test_grouped_matmul_module_names_no_source``)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    _, whole, side = la._panel_plan(heads, dv)
+    panels = la.state_shape(heads, dk, dv)[0]
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    lowered = jax.jit(
+        lambda *a: ls.state_step(*a, heads=heads, whole=whole, side=side,
+                                 interpret=False), donate_argnums=0).lower(
+        shape((layers, slots, panels, dk, 128)), shape((), jnp.int32),
+        shape((slots,), jnp.bool_), shape((slots, dk, 128)),
+        shape((slots, 3, panels, 128)))
+    _names_no_source(lowered, b"linear_state")
+    memory = lowered.compile().memory_analysis()
+    assert memory.alias_size_in_bytes == layers * slots * panels * dk * 512
+    assert memory.temp_size_in_bytes < 2 ** 20
 
 
 # Ouro-2.6B whole: published widths, all 48 layers run four times, with the

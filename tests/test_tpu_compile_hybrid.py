@@ -12,9 +12,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from test_tpu_compile import (_fits, _paged_read_kernels,  # noqa: F401
-                              compiled_paged_read, no_persistent_cache, pa,
-                              topo)
+from test_tpu_compile import (_fits, _linear_state_kernels,  # noqa: F401
+                              _paged_read_kernels, compiled_linear_state,
+                              compiled_paged_read, la, made_of_shape,
+                              no_persistent_cache, pa, topo)
 
 GIB = 1024 ** 3
 
@@ -115,3 +116,30 @@ def test_the_hybrid_decode_reads_the_pages_through_the_kernel(
     assert re.search(gathered, gather.as_text())
     assert memory.temp_size_in_bytes \
         < gather.memory_analysis().temp_size_in_bytes / 4
+
+
+def test_the_hybrid_decode_steps_the_states_through_the_kernel(
+        topo, cell, compiled_paged_read, compiled_linear_state, monkeypatch):
+    """On the chip the nine linear layers step their states through
+    ``ops/linear_state.py`` (ISSUE 52): one kernel a linear layer of the
+    scanned period, the whole pool its operand and its result in one buffer,
+    and NOTHING else in the program makes an array of the pool's shape (the
+    rule's program has three update-slice fusions of it a period) or of one
+    layer's slab; the temporaries are no larger than the rule's."""
+    pool, slab = "f32[9,48,45,96,128]", "f32[48,45,96,128]"
+    _, pools, exe = compiled(topo, cell, "decode")
+    text = exe.as_text()
+    assert len(_linear_state_kernels(text, pool)) == 3
+    assert made_of_shape(text, pool, but="custom-call") == []
+    assert made_of_shape(text, slab) == []
+    # nor spread keys of a slab's size, in either layout
+    assert not re.search(r"f32\[48,(45|30),96,(128|192)\]", text)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    memory = exe.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    monkeypatch.setattr(la, "_kernel_backend", lambda: False)
+    _, _, rule = compiled(topo, cell, "decode")
+    assert _linear_state_kernels(rule.as_text(), pool) == []
+    assert len(made_of_shape(rule.as_text(), pool)) >= 3
+    assert memory.temp_size_in_bytes \
+        <= rule.memory_analysis().temp_size_in_bytes

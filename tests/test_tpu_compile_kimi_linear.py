@@ -13,9 +13,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from test_tpu_compile import (_fits, _paged_read_kernels,  # noqa: F401
-                              compiled_experts, compiled_paged_read,
-                              no_persistent_cache, pa, topo)
+from test_tpu_compile import (_fits, _linear_state_kernels,  # noqa: F401
+                              _paged_read_kernels, compiled_experts,
+                              compiled_linear_state, compiled_paged_read, la,
+                              made_of_shape, no_persistent_cache, pa, topo)
 
 GIB = 1024 ** 3
 
@@ -86,6 +87,35 @@ def test_the_kimi_program_fits_and_keeps_its_pools_in_place(
     if program == "decode":          # one a latent layer, the one pool
         assert len(_paged_read_kernels(
             text, "bf16[2,16385,16,640]", "latent_read", pools=1)) == 2
+
+
+def test_the_kimi_decode_steps_the_states_through_the_kernel(
+        topo, cell, compiled_paged_read, compiled_experts,
+        compiled_linear_state, monkeypatch):
+    """On the chip the six KDA layers step their states through
+    ``ops/linear_state.py`` (ISSUE 52), the decay a key channel's: one
+    kernel a linear layer (with experts from the second layer on every layer
+    is a group of its own: six in the program's text), the whole pool its
+    operand and its result in one buffer, nothing else of the pool's shape
+    or of a layer's slab made, the temporaries the rule's and one layer's
+    columns operand."""
+    pool, slab = "f32[6,64,32,128,128]", "f32[64,32,128,128]"
+    _, pools, exe = compiled(topo, cell, "decode")
+    text = exe.as_text()
+    assert len(_linear_state_kernels(text, pool)) == 6
+    assert made_of_shape(text, pool, but="custom-call") == []
+    assert made_of_shape(text, slab) == []
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    memory = exe.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    monkeypatch.setattr(la, "_kernel_backend", lambda: False)
+    _, _, rule = compiled(topo, cell, "decode")
+    assert _linear_state_kernels(rule.as_text(), pool) == []
+    assert len(made_of_shape(rule.as_text(), pool)) >= 6
+    # the one temporary the kernel adds: a layer's columns operand (every
+    # head's decay, key and query a key channel a sublane: [64, 128, 128])
+    assert memory.temp_size_in_bytes \
+        <= rule.memory_analysis().temp_size_in_bytes + 64 * 128 * 128 * 4
 
 
 def test_the_family_builds_the_published_program(cell):
