@@ -50,7 +50,8 @@ from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.object_transfer import (ChecksumError, crc32_segments,
                                               fetch_object_into)
 from ray_tpu._private.plasma import PlasmaClient
-from ray_tpu._private.protocol import ConnectionLost, RpcConnection, RpcServer, connect
+from ray_tpu._private.protocol import (FLUSHED_AT, ConnectionLost, RpcConnection,
+                                       RpcServer, connect)
 from ray_tpu._private.serialization import get_context
 
 logger = logging.getLogger(__name__)
@@ -599,7 +600,13 @@ class CoreWorker:
         """Owner-side adoption of one in-flight yield.  A missing or
         cancelled stream refuses the yield — and frees the executor-side
         copy, which nobody will ever reference — telling the producer to
-        stop."""
+        stop.  A producer on this host may have its connection stamp the
+        yield when its frame leaves (``protocol.FLUSHED_AT``:
+        ``time.perf_counter`` is one clock for a host's processes), and the
+        ack then says what the yield met here: ``in_us`` from the stamp to
+        this handler's entry (the wire, and this loop before it ran the
+        handler) and ``held_us`` inside."""
+        began = time.perf_counter()
         st = self._streams.get(msg["task_id"])
         oid_hex, kind, data = msg["entry"]
         if st is None or st["cancelled"]:
@@ -613,7 +620,11 @@ class CoreWorker:
         ref = ObjectRef(ObjectID.from_hex(oid_hex), self.address)
         st["queue"].append(ref)
         st["event"].set()
-        return {"ok": True}
+        flushed = msg.get(FLUSHED_AT)
+        if flushed is None:
+            return {"ok": True}
+        return {"ok": True, "in_us": max(int((began - flushed) * 1e6), 0),
+                "held_us": int((time.perf_counter() - began) * 1e6)}
 
     async def stream_next_async(self, task_id_hex: str,
                                 timeout: Optional[float] = None):

@@ -46,6 +46,36 @@ class ConnectionLost(Exception):
     pass
 
 
+_tracing = None
+
+# The one key of a payload that the transport writes: see
+# ``RpcConnection.stamp_at_flush``.
+FLUSHED_AT = "_flushed_at"
+
+
+def _tracing_module():
+    """``ray_tpu.util.tracing`` (its always-on sums, whether a profiler
+    session records), found when the first connection is made: a
+    module-level import would be circular (ray_tpu.util -> placement_group
+    -> worker -> core_worker -> here)."""
+    global _tracing
+    if _tracing is None:
+        from ray_tpu.util import tracing
+        _tracing = tracing
+    return _tracing
+
+
+def _same_host(writer) -> bool:
+    """Whether the stream's other end is a process of this host (a unix
+    socket, or a TCP peer at the address this end has): where it is,
+    ``time.perf_counter`` is one clock for both ends."""
+    peer = writer.get_extra_info("peername")
+    own = writer.get_extra_info("sockname")
+    if not isinstance(peer, tuple) or not isinstance(own, tuple):
+        return isinstance(peer, (str, bytes))     # a unix socket's path
+    return peer[0] == own[0]
+
+
 # Fault-injection shim (chaos testing; see util/fault_injection.py):
 # when installed, the filter sees every outgoing frame BEFORE it reaches
 # the transport and returning True silently drops it — modeling a lossy
@@ -148,6 +178,16 @@ class RpcConnection:
         self._wire_v2 = wire.enabled()
         self.peer_wire_version = 1
         self._peer_fast = False
+        # Always on (``stats()["rpc"]`` of whoever asks this process): a
+        # frame's messages are counted together when it is packed or
+        # parsed, with two clock reads a frame.  Which kinds and types
+        # they are of (``msgs.out.request.stream_yield``, ``msgs.in.reply``)
+        # is a loop a message, made only while a profiler session records.
+        tracing = _tracing_module()
+        self._sums = tracing.accumulator()
+        self._recording = tracing.recording
+        self._stamped: list = []      # see stamp_at_flush
+        self.peer_is_local = _same_host(writer)
         _maybe_install_env_fault()
 
     def start(self):
@@ -196,24 +236,34 @@ class RpcConnection:
     def closed(self) -> bool:
         return self._closed
 
-    async def _send_frame(self, payload: bytes):
-        # No await between the two writes, so no interleaving is possible
-        # and no send lock is needed — and draining every frame costs an
-        # extra suspension per message on the hot actor-call path.  Small
-        # frames fold the header in (one syscall-side buffer append); bulk
-        # frames write separately to avoid copying megabytes per frame.
-        # Backpressure still applies: drain once >=1MB is outstanding since
-        # the last drain (bulk chunk transfers hit this every frame).
+    def _put_frame(self, payload: bytes) -> bool:
+        """Hand one frame to the transport, unless the chaos filter drops
+        it; True when enough is outstanding that the caller should drain.
+        No await between the two writes, so no interleaving is possible
+        and no send lock is needed.  Small frames fold the header in (one
+        syscall-side buffer append); bulk frames write separately to avoid
+        copying megabytes per frame."""
         if _frame_fault is not None and _frame_fault(self, payload):
-            return
+            return False
         if len(payload) < 65536:
             self.writer.write(_HEADER.pack(len(payload)) + payload)
         else:
             self.writer.write(_HEADER.pack(len(payload)))
             self.writer.write(payload)
+        self._sums["rpc.frames_out"] += 1
+        self._sums["rpc.bytes_out"] += _HEADER.size + len(payload)
         self._undrained += _HEADER.size + len(payload)
-        if self._undrained >= 1 << 20:
-            self._undrained = 0
+        if self._undrained < 1 << 20:
+            return False
+        self._undrained = 0
+        return True
+
+    async def _send_frame(self, payload: bytes):
+        # Draining every frame costs an extra suspension per message on the
+        # hot actor-call path.  Backpressure still applies: drain once
+        # >=1MB is outstanding since the last drain (bulk chunk transfers
+        # hit this every frame).
+        if self._put_frame(payload):
             async with self._send_lock:   # serialize concurrent drains
                 await self.writer.drain()
 
@@ -222,16 +272,7 @@ class RpcConnection:
         suspend (batch send / inline replies).  Same coalescing as
         _send_frame; over the backpressure threshold it schedules a drain
         task instead of awaiting one."""
-        if _frame_fault is not None and _frame_fault(self, payload):
-            return
-        if len(payload) < 65536:
-            self.writer.write(_HEADER.pack(len(payload)) + payload)
-        else:
-            self.writer.write(_HEADER.pack(len(payload)))
-            self.writer.write(payload)
-        self._undrained += _HEADER.size + len(payload)
-        if self._undrained >= 1 << 20:
-            self._undrained = 0
+        if self._put_frame(payload):
             spawn(self._drain(), name="rpc-drain", log=logger)
 
     async def _drain(self):
@@ -272,15 +313,57 @@ class RpcConnection:
             # outbox and no-ops.
             self._flush_outbox()
 
+    def stamp_at_flush(self, msg: dict) -> None:
+        """Have the frame that takes ``msg`` away write WHEN it leaves, on
+        this host's ``time.perf_counter``, into the message under
+        ``FLUSHED_AT``: for a message queued on this connection right
+        after this call, to a peer on this host (``peer_is_local``), whose
+        handler then knows how long the message was on its way.  What it
+        waited in the outbox, behind the tick's other callbacks, is this
+        loop's and not the wire's or the peer's.  The transport touches no
+        other key of any payload, and only of the messages handed to it
+        here."""
+        msg[FLUSHED_AT] = None
+        self._stamped.append(msg)
+
+    def _count_by_type(self, way: str, items) -> None:
+        """A frame's messages by kind and ``type`` into the process's sums:
+        ``msgs.out.request.<type>``, ``msgs.in.notify.<type>``, replies as
+        one (``msgs.in.reply``)."""
+        sums = self._sums
+        for kind, _rid, msg in items:
+            if kind == _REPLY:
+                sums[way + "reply"] += 1
+                continue
+            if msg.__class__ is wire.PreEncoded:
+                msg = msg.msg
+            what = msg.get("type") if msg.__class__ is dict else None
+            sums[f"{way}{'request' if kind == _REQUEST else 'notify'}"
+                 f".{what}"] += 1
+
     def _flush_outbox(self) -> None:
+        """The messages queued this tick leave as one frame; the wall spent
+        packing and writing it is ``rpc.out_s`` of the process's sums."""
         ob = self._outbox
-        if not ob or self._closed:
-            self._outbox = []
-            return
         self._outbox = []
+        if not ob or self._closed:
+            self._stamped = []
+            return
+        started = time.perf_counter()
+        self._sums["rpc.msgs_out"] += len(ob)
+        if self._stamped:
+            for msg in self._stamped:
+                msg[FLUSHED_AT] = started
+            self._stamped = []
+        if self._recording():
+            self._count_by_type("msgs.out.", ob)
         if self._wire_v2 and self.peer_wire_version >= 2 and self._peer_fast:
             self._flush_outbox_v2(ob)
-            return
+        else:
+            self._flush_outbox_legacy(ob)
+        self._sums["rpc.out_s"] += time.perf_counter() - started
+
+    def _flush_outbox_legacy(self, ob: list) -> None:
         try:
             if len(ob) == 1:
                 payload = pickle.dumps(ob[0], protocol=5)
@@ -414,6 +497,13 @@ class RpcConnection:
         try:
             while True:
                 frame = await self._read_frame()
+                # From here to the next read nothing awaits: the wall spent
+                # decoding the frame and handing its messages on is
+                # ``rpc.in_s`` of the process's sums.
+                started = time.perf_counter()
+                sums = self._sums
+                sums["rpc.frames_in"] += 1
+                sums["rpc.bytes_in"] += _HEADER.size + len(frame)
                 # First payload byte routes the framing: v2 frames start
                 # with the wire MAGIC, legacy pickle streams with the
                 # 0x80 PROTO opcode.  Both are always accepted.
@@ -421,30 +511,9 @@ class RpcConnection:
                     kind, rid, msg = wire.decode_frame(frame)
                 else:
                     kind, rid, msg = pickle.loads(frame)
-                if kind == _REQUEST:
-                    fh = self.fast_handler
-                    if fh is None or not fh(rid, msg):
-                        # per-request dispatch: _handle replies errors
-                        # itself; skip the done-callback tax on this path
-                        asyncio.get_running_loop().create_task(
-                            self._handle(rid, msg))  # rtlint: disable=orphan-task
-                elif kind == _REPLY:
-                    fut = self._pending.pop(rid, None)
-                    if fut is not None and not fut.done():
-                        ok, value = msg
-                        if ok:
-                            fut.set_result(value)
-                        else:
-                            fut.set_exception(value)
-                elif kind == _NOTIFY:
-                    if msg.__class__ is dict and \
-                            msg.get("type") == wire.HELLO_TYPE:
-                        self._apply_hello(msg)
-                        continue
-                    asyncio.get_running_loop().create_task(
-                        self._handle(None, msg))  # rtlint: disable=orphan-task
-                elif kind == _BATCH:
-                    self._dispatch_batch(msg)
+                self._dispatch_batch(msg if kind == _BATCH
+                                     else ((kind, rid, msg),))
+                sums["rpc.in_s"] += time.perf_counter() - started
         except (
             asyncio.IncompleteReadError,
             ConnectionResetError,
@@ -464,6 +533,9 @@ class RpcConnection:
         # pipeline full — serving a batch in one task was measured ~2x
         # slower on the actor-call hot path).
         loop = asyncio.get_running_loop()
+        self._sums["rpc.msgs_in"] += len(items)
+        if self._recording():
+            self._count_by_type("msgs.in.", items)
         for kind, rid, msg in items:
             if kind == _REPLY:
                 fut = self._pending.pop(rid, None)
@@ -476,7 +548,8 @@ class RpcConnection:
             elif kind == _REQUEST:
                 fh = self.fast_handler
                 if fh is None or not fh(rid, msg):
-                    # same hot-dispatch exemption as _serve above
+                    # per-request dispatch: _handle replies errors itself;
+                    # skip the done-callback tax on this path
                     loop.create_task(self._handle(rid, msg))  # rtlint: disable=orphan-task
             elif kind == _NOTIFY:
                 if msg.__class__ is dict and \

@@ -30,7 +30,7 @@ import cloudpickle
 from ray_tpu._private.async_utils import spawn
 from ray_tpu._private.core_worker import CoreWorker, _serialize_exception
 from ray_tpu._private.ids import ObjectID, TaskID
-from ray_tpu._private.protocol import connect
+from ray_tpu._private.protocol import FLUSHED_AT, connect
 
 logger = logging.getLogger(__name__)
 
@@ -395,8 +395,23 @@ class TaskExecutor:
         self._streaming_calls.add(task_id_hex)
         refs = []
         i = 0
+        # A yield's four stages on the wall clock: ``wait`` (the body's
+        # next value) and ``ack`` span an await; ``store`` and ``after``
+        # (from the ack's arrival to the next ``step()``: the reference,
+        # its borrow, the region) are this loop's own work, always summed
+        # (``tracing.sums("stream.")``: ``store_s``, ``after_s``,
+        # ``yields``).  While a profiler session records, all four ride on
+        # the yield's ``rt:stream.yield`` and the waits are summed too.
+        # ``after`` is over when the region is long entered, so it rides
+        # on the NEXT yield's.
+        sums = tracing.accumulator()
+        resumed, after = None, 0.0
         try:
             while True:
+                waiting = time.perf_counter()
+                if resumed is not None:
+                    after = waiting - resumed
+                    sums["stream.after_s"] += after
                 try:
                     value = await step()
                 except asyncio.CancelledError:
@@ -408,24 +423,56 @@ class TaskExecutor:
                     except Exception:
                         pass
                     raise
+                storing = time.perf_counter()
                 if value is sentinel:
                     break
                 i += 1
                 oid = ObjectID.for_task_return(task_id, i)
                 entry = await self.core.store_return_value_async(oid, value)
                 sent = time.perf_counter()
+                msg = {"type": "stream_yield", "task_id": task_id_hex,
+                       "index": i, "entry": entry}
+                # What only a region would carry is made only while a
+                # session records; and the owner's clock means something
+                # only where the owner is on this host.
+                recording = tracing.recording()
+                if recording and conn.peer_is_local:
+                    conn.stamp_at_flush(msg)
                 try:
-                    ack = await conn.request(
-                        {"type": "stream_yield", "task_id": task_id_hex,
-                         "index": i, "entry": entry}, timeout=60)
+                    ack = await conn.request(msg, timeout=60)
                 except Exception:
                     ack = {"ok": False}   # owner died/unreachable: stop
-                # The wait spans an await, so it rides as an attribute of
-                # a region entered and left when the ack arrives.
-                with tracing.region(
-                        "stream.yield",
-                        ack_us=int((time.perf_counter() - sent) * 1e6)):
-                    pass
+                resumed = time.perf_counter()
+                sums["stream.yields"] += 1
+                # a value too large to go inline is put in the object
+                # store behind awaits: no section of this loop's
+                aside = entry[1] == "plasma"
+                sums["stream.store_aside_s" if aside
+                     else "stream.store_s"] += sent - storing
+                if recording:
+                    # The waits span an await, so they ride as attributes
+                    # of a region entered and left when the ack arrives.
+                    # Of ``ack_us``: ``out_us`` in this process's outbox
+                    # until its frame was packed (the tick's other streams
+                    # come first), then what the owner's reply says,
+                    # ``in_us`` to its handler's entry and ``held_us``
+                    # inside (``_h_stream_yield``); what is left is the
+                    # way back and this loop before it resumed the stream.
+                    sums["stream.wait_s"] += storing - waiting
+                    sums["stream.ack_s"] += resumed - sent
+                    stages = {k: ack[k] for k in ("in_us", "held_us")
+                              if k in ack}
+                    flushed = msg.get(FLUSHED_AT)
+                    if flushed is not None:
+                        stages["out_us"] = int((flushed - sent) * 1e6)
+                    if not aside:
+                        stages["store_us"] = int((sent - storing) * 1e6)
+                    with tracing.region(
+                            "stream.yield",
+                            ack_us=int((resumed - sent) * 1e6),
+                            wait_us=int((storing - waiting) * 1e6),
+                            after_us=int(after * 1e6), **stages):
+                        pass
                 if not ack.get("ok"):
                     try:
                         await close()

@@ -232,7 +232,11 @@ same interval says whether the loop ran against it.  The wall is read at every
 boundary of every call; the CPU clocks between a call's submission and its
 delivery are system calls, read for every call while a profiler session
 records and for one call in ``_CPU_EVERY`` otherwise.  The collector's passes
-are ``rt:gc`` regions and ``stats()["gc"]`` (``tracing.watch_gc``).
+are ``rt:gc`` regions and ``stats()["gc"]`` (``tracing.watch_gc``).  What the
+loop thread did BESIDE the engine between two submissions (the streams'
+fan-out, the transport's frames: ``_BESIDE``) rides on the ``.decode.dispatch``
+as the growth of ``tracing``'s always-on sums, ``stats()["stream"]`` /
+``["rpc"]``.
 ``stats()`` carries the always-on counters of the same places,
 ``decode_ahead_steps`` and ``stray_slot_steps`` among them.
 
@@ -288,6 +292,20 @@ _COMPILE_THREADS = 4
 # section 6, PR 36).  A session reads them at every call: the chip machine's
 # clocks tick in 10 ms steps, and five traced seconds need every tick.
 _CPU_EVERY = 16
+# What else the actor's loop did between two decode steps' submissions, as
+# ``rt:engine.decode.dispatch`` carries it: the growth of ``tracing``'s
+# always-on sums (attribute -> key) over the interval of ``step_loop_cpu_us``.
+# The streams' fan-out (``_private/worker_main.py``: a yield's ``store`` and
+# ``after``) and the transport (``_private/protocol.py``: frames packed and
+# written, frames parsed and handed on) are synchronous sections of that
+# thread that nest neither in each other nor in the engine's regions, so with
+# ``rt:engine.deliver`` and ``rt:engine.schedule`` they add up to no more
+# than the step's loop time; the rest is the event loop itself.
+_BESIDE = {"yields": "stream.yields", "stream_store_us": "stream.store_s",
+           "stream_after_us": "stream.after_s", "rpc_out_us": "rpc.out_s",
+           "rpc_in_us": "rpc.in_s", "msgs_out": "rpc.msgs_out",
+           "msgs_in": "rpc.msgs_in", "frames_out": "rpc.frames_out",
+           "frames_in": "rpc.frames_in"}
 
 
 class _Clocks(NamedTuple):
@@ -301,6 +319,13 @@ class _Clocks(NamedTuple):
 def _us(seconds: float) -> int:
     """An attribute of a region: whole microseconds."""
     return int(seconds * 1e6)
+
+
+def _counts(sums: Dict[str, float]) -> Dict[str, Any]:
+    """``tracing.sums`` for ``stats()``: seconds (``*_s``) as they are, the
+    counts as integers."""
+    return {key: value if key.endswith("_s") else int(value)
+            for key, value in sums.items()}
 
 
 class _Step(NamedTuple):
@@ -573,6 +598,7 @@ class InferenceEngine:
         self._calls = 0          # submissions to the exec lane
         self._cpu_sampled = 0    # of them, those read on the CPU clocks too
         self._loop_cpu_clock: Optional[int] = None   # set by _run_loop
+        self._sums_from = self._beside_now()         # see _beside()
         tracing.watch_gc()
         # Single lane for XLA dispatches: the device serializes anyway,
         # and one lane keeps (k_pages, v_pages) updates ordered.
@@ -727,7 +753,23 @@ class InferenceEngine:
         actor loop thread's in those phases.  Plain additions on the loop
         thread from the clock reads that feed the regions' attributes; no
         lock.  ``gc`` is ``tracing.gc_stats()``: this process's collector
-        passes."""
+        passes.  ``stream`` and ``rpc`` are this PROCESS's always-on sums
+        (``tracing.sums``) of what the actor's loop does beside the engine:
+        ``stream`` the streamed ``yields`` and the wall seconds of the
+        loop's own work on them (``store_s``, and ``after_s`` from the ack's
+        arrival to the next step of the body; ``store_aside_s`` the stores
+        that went to the object store behind awaits), ``rpc`` the
+        transport's (``msgs_out`` / ``msgs_in``, ``frames_*``, ``bytes_*``;
+        ``out_s`` packing and writing frames, ``in_s`` parsing them and
+        handing their messages on).  Read twice, the growth of
+        ``store_s + after_s`` and of ``out_s + in_s`` over the growth of
+        ``steps`` is the streams' and the transport's share of
+        ``host_cpu_s["loop"]`` a step.  What costs a loop a message or
+        spans an await grows only while a profiler session records
+        (``LLMServer.profile``): ``stream``'s ``wait_s`` (for the body's
+        next value) and ``ack_s`` (for the owner's ack), and the messages
+        by kind and type under ``rpc``'s ``out`` and ``in``
+        (``request.stream_yield``, ``reply``)."""
         return {"active": len(self._active), "waiting": len(self._waiting),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "decode_ahead_steps": self._decode_ahead_steps,
@@ -764,7 +806,11 @@ class InferenceEngine:
                 "host_s": dict(self._host_s),
                 "host_cpu_s": dict(self._host_cpu_s),
                 "host_cpu_calls": self._cpu_sampled,
-                "gc": tracing.gc_stats()}
+                "gc": tracing.gc_stats(),
+                "stream": _counts(tracing.sums("stream.")),
+                "rpc": {**_counts(tracing.sums("rpc.")),
+                        "out": _counts(tracing.sums("msgs.out.")),
+                        "in": _counts(tracing.sums("msgs.in."))}}
 
     def _recurrent_stats(self) -> Dict[str, Any]:
         """``stats()`` of a model with rows a slot (none of another)."""
@@ -1064,6 +1110,20 @@ class InferenceEngine:
         next decode step's ``step_us`` count from here."""
         self._step_from = self._clocks(True, on_loop=True)
         self._loop_free_at = self._step_from.wall
+        self._sums_from = self._beside_now()
+
+    @staticmethod
+    def _beside_now() -> List[float]:
+        """The sums of ``_BESIDE`` as they stand, in its order."""
+        sums = tracing.accumulator()
+        return [sums.get(key, 0.0) for key in _BESIDE.values()]
+
+    def _beside(self) -> Dict[str, int]:
+        """``_BESIDE`` since the decode step's submission before (or the
+        loop's waking): whole microseconds and counts."""
+        before, self._sums_from = self._sums_from, self._beside_now()
+        return {attr: (_us if attr.endswith("_us") else int)(now - was)
+                for attr, now, was in zip(_BESIDE, self._sums_from, before)}
 
     def _deliver(self, tokens: List[Tuple[_Sequence, int]],
                  submitted: _Clocks, lane: Tuple[_Clocks, _Clocks, _Clocks],
@@ -1243,6 +1303,7 @@ class InferenceEngine:
         step_loop_cpu_s = submitted.loop_cpu - self._step_from.loop_cpu
         self._host_cpu_s["loop"] += step_loop_cpu_s
         self._step_from = submitted
+        more.update(self._beside())
 
         def _call():
             start = self._clocks(sampled)

@@ -48,10 +48,22 @@ over many regions are readings.  ``watch_gc()``
 puts the collector's passes on the same timeline as ``rt:gc`` regions and
 counts them always (``gc_stats()``): the collector holds the GIL, so a pass
 on any thread is a stall of every thread.
+
+Sums follow the rule too.  Work that is too fine for a region of its own (a
+streamed token's stages, a frame packed or parsed: thousands a second) is
+added, always, into one accumulator of this module (``accumulator`` / ``sums``:
+seconds as ``<what>_s``, counts bare), whether or not jax is imported; and
+what a sum GREW BY since the region before rides on the region that follows,
+as any phase does: a decode step's ``rt:engine.decode.dispatch`` carries the
+growth of the streams' and the transport's sums since the submission before
+it (``stream_store_us``, ``rpc_out_us``, ``yields``, ``msgs_out``, ...), over
+the very interval of its ``step_loop_cpu_us``.  The sections that feed such
+sums are synchronous and do not nest, so they add up beside each other.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import gc
@@ -107,9 +119,30 @@ def recording() -> bool:
     """Whether a profiler session is recording this process's regions right
     now.  For a reading that costs something and that only a region would
     carry (the engine's thread CPU clocks); ``region()`` itself needs no
-    such test."""
-    jax = sys.modules.get("jax")
-    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+    such test.  Asked from any thread at any time (the transport asks once
+    a frame): while another thread is still importing jax the module is
+    there without its ``profiler``, and nothing records yet."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return profiler is not None and profiler.TraceAnnotation.is_enabled()
+
+
+_sums: Dict[str, float] = collections.defaultdict(float)   # see accumulator()
+
+
+def accumulator() -> Dict[str, float]:
+    """This process's always-on sums, the dict itself (a ``defaultdict`` of
+    floats): seconds of a synchronous section (``stream.store_s``,
+    ``rpc.out_s``) or a count (``stream.yields``, ``rpc.msgs_out``).  A path
+    that adds thousands of times a second keeps the dict and adds in place,
+    ``acc[key] += amount``, from the process's loop thread."""
+    return _sums
+
+
+def sums(prefix: str = "") -> Dict[str, float]:
+    """The sums whose key starts with ``prefix``, the prefix cut off."""
+    cut = len(prefix)
+    return {key[cut:]: value for key, value in list(_sums.items())
+            if key.startswith(prefix)}
 
 
 _gc = {"passes": [0, 0, 0], "pause_s": [0.0, 0.0, 0.0],   # by generation
