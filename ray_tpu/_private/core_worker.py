@@ -583,6 +583,18 @@ class CoreWorker:
     # as the stream's completion marker: every yield ack completes before
     # the final reply is sent, so ref0 appearing in the memory store
     # strictly follows the last yield.
+    #
+    # A yield costs those two messages and no other.  This process, the
+    # owner, holds it from before the ack: `owned`, the value (or, for
+    # one too large to go inline, the name of the copy in the executor's
+    # node's store), and a counted ObjectRef of its own on the stream's
+    # queue.  After that whoever took that reference from the stream
+    # holds it, and it is freed like any owned object when the last
+    # local reference goes (a plasma copy through the GCS), mid-stream or
+    # after.  The executor only names its yields (ListedRef) for the list
+    # at return-index 0: it registers no borrow, so nothing here waits
+    # for the stream's end, and that list names the yields without
+    # holding them.
 
     def register_stream(self, task_id_hex: str, ref0_hex: str) -> None:
         """Create consumer state for a streaming call.  Called from the

@@ -82,12 +82,16 @@ class StreamingObjectRefGenerator:
 
     ``async for ref in gen`` works on any asyncio loop; plain ``for ref
     in gen`` works from any non-core-loop thread.  ``gen.completed()``
-    is the task's return-0 ref — it resolves to an ObjectRefGenerator of
-    every yielded ref once the producer finishes, or raises the task's
-    error.  ``gen.cancel()`` (also fired from ``__del__`` when the
-    handle is dropped mid-stream) stops the producer: its next yield is
-    refused by the owner, which closes the user generator so ``finally``
-    blocks run and release whatever the stream held.
+    is the task's return-0 ref — it resolves to an ObjectRefGenerator
+    that NAMES every yield once the producer finishes, or raises the
+    task's error.  A yield is an owned, counted object like any task
+    return: it lives as long as a reference taken from the stream does,
+    and is freed here, at its owner, when the last one goes, whether
+    the stream has ended or not.  ``gen.cancel()`` (also fired from
+    ``__del__`` when the handle is dropped mid-stream) stops the
+    producer: its next yield is refused by the owner, which closes the
+    user generator so ``finally`` blocks run and release whatever the
+    stream held.
 
     The handle is owner-local and deliberately unpicklable — forward the
     consumed values, not the stream."""
@@ -139,8 +143,12 @@ class StreamingObjectRefGenerator:
     # ---- lifecycle ----
 
     def completed(self) -> "ObjectRef":
-        """Ref of the task's terminal result: an ObjectRefGenerator of
-        all yielded refs on success, the task's error otherwise."""
+        """Ref of the task's terminal result: on success an
+        ObjectRefGenerator that names every yield in order, the task's
+        error otherwise.  The list names the yields and does not hold
+        them: to read one after the stream, keep the reference the
+        stream handed out; a listed yield whose references were all
+        dropped reads as ObjectLostError (freed by its owner)."""
         return self._ref0
 
     def task_id(self) -> str:
@@ -222,8 +230,12 @@ class ListedRef(ObjectRef):
     """Names an object in a message without holding it: counts no local
     reference, so it registers no borrow with the owner, and pickles as
     a plain ObjectRef.  An executor lists a generator task's yields with
-    these: it never reads them, and a borrow_add/borrow_remove pair from
-    it could reach the owner after the reply and free them."""
+    these, dynamic and streaming alike: it never reads them, the owner
+    holds them (a dynamic yield from the reply's adoption, a streamed
+    one from before its ``stream_yield`` ack), and a borrow_add /
+    borrow_remove pair from the executor would be two more requests a
+    yield that could reach the owner after the reply and free them, or
+    pin them there until the stream's end."""
 
     __slots__ = ()
 

@@ -347,9 +347,17 @@ class TaskExecutor:
         held.  The final reply stays shape-compatible with dynamic
         returns: an ObjectRefGenerator of all yielded refs at index 0,
         whose arrival in the owner's store doubles as the end-of-stream
-        marker (it strictly follows the last acked yield)."""
+        marker (it strictly follows the last acked yield).
+
+        Who holds a yield: the OWNER, from before its ack (``owned``, the
+        value, and a counted ObjectRef of its own on the stream's queue:
+        ``core_worker._h_stream_yield``), and after that whoever took
+        that reference from the stream.  This side only NAMES its yields
+        (``ListedRef``: no local count, no borrow registered with the
+        owner, none removed at the stream's end): it never reads one
+        again, and two messages a yield carry all the stream needs."""
         from ray_tpu._private.ids import TaskID
-        from ray_tpu._private.object_ref import (ObjectRef,
+        from ray_tpu._private.object_ref import (ListedRef,
                                                  ObjectRefGenerator)
         task_id_hex = spec.get("call_id") or spec["task_id"]
         task_id = TaskID(bytes.fromhex(task_id_hex))
@@ -397,11 +405,12 @@ class TaskExecutor:
         i = 0
         # A yield's four stages on the wall clock: ``wait`` (the body's
         # next value) and ``ack`` span an await; ``store`` and ``after``
-        # (from the ack's arrival to the next ``step()``: the reference,
-        # its borrow, the region) are this loop's own work, always summed
-        # (``tracing.sums("stream.")``: ``store_s``, ``after_s``,
-        # ``yields``).  While a profiler session records, all four ride on
-        # the yield's ``rt:stream.yield`` and the waits are summed too.
+        # (from the ack's arrival to the next ``step()``: the yield's
+        # name for the final list, the region) are this loop's own work,
+        # always summed (``tracing.sums("stream.")``: ``store_s``,
+        # ``after_s``, ``yields``).  While a profiler session records, all
+        # four ride on the yield's ``rt:stream.yield`` and the waits are
+        # summed too.
         # ``after`` is over when the region is long entered, so it rides
         # on the NEXT yield's.
         sums = tracing.accumulator()
@@ -412,17 +421,7 @@ class TaskExecutor:
                 if resumed is not None:
                     after = waiting - resumed
                     sums["stream.after_s"] += after
-                try:
-                    value = await step()
-                except asyncio.CancelledError:
-                    # ray_tpu.cancel() mid-stream: close the user body so
-                    # its finally blocks run, then let the cancel reply
-                    # path take over.
-                    try:
-                        await close()
-                    except Exception:
-                        pass
-                    raise
+                value = await step()
                 storing = time.perf_counter()
                 if value is sentinel:
                     break
@@ -479,7 +478,17 @@ class TaskExecutor:
                     except Exception:
                         pass
                     break
-                refs.append(ObjectRef(oid, owner))
+                refs.append(ListedRef(oid, owner))
+        except asyncio.CancelledError:
+            # ray_tpu.cancel() mid-stream, wherever it met this loop (the
+            # body's next value, the store, the ack's wait): close the
+            # user body so its finally blocks run before this call ends,
+            # then let the cancel reply path take over.
+            try:
+                await close()
+            except Exception:
+                pass
+            raise
         finally:
             self._streaming_calls.discard(task_id_hex)
         gen_oid = ObjectID.for_task_return(task_id, 0)

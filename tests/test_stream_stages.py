@@ -3,8 +3,10 @@ and message by message (ISSUE 53): the producer's ``rt:stream.yield`` with
 its stages and what the owner's ack told, the always-on sums behind them,
 the transport's counts (by kind and type under a profiler session), and a
 serving replica's decode steps carrying what the streams and the transport
-cost its loop."""
+cost its loop.  And who holds a yield (ISSUE 54): its owner and whoever
+took its reference from the stream; the producer only names it."""
 
+import asyncio
 import glob
 import os
 import time
@@ -14,6 +16,9 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
+from ray_tpu._private.core_worker import INLINE_MAX
+from ray_tpu._private.ids import ObjectID, TaskID
+from ray_tpu._private.worker import get_core
 
 N = 24
 
@@ -42,6 +47,31 @@ class Producer:
             # i has been added and nothing of yield i + 1 has
             self.seen.append(tracing.sums("stream."))
             yield i
+
+    async def gated(self, n, gate, early=1, size=0):
+        """``early`` yields, then the rest once the file ``gate`` is there:
+        the consumer decides how long the stream runs.  ``held`` is what
+        this process counted and borrowed when the body ran for each
+        yield; ``closed`` says the body's ``finally`` ran."""
+        self.held, self.closed = [], False
+        try:
+            for i in range(n):
+                while i >= early and not os.path.exists(gate):
+                    await asyncio.sleep(0.005)
+                self.held.append(self._held())
+                yield bytes(size) if size else i
+        finally:
+            self.closed = True
+
+    @staticmethod
+    def _held():
+        core = get_core()
+        return len(core._borrowing), len(core._local_refs)
+
+    def told(self):
+        """After a stream (the call waits its turn behind it): what the
+        body saw at each yield, what is held now, whether it was closed."""
+        return self.held, self._held(), self.closed
 
     def sums(self):
         from ray_tpu.util import tracing
@@ -78,13 +108,12 @@ class Producer:
 def _stream(producer, n=N):
     refs = list(producer.count.options(num_returns="streaming").remote(n))
     assert [ray_tpu.get(r, timeout=60) for r in refs] == list(range(n))
-    del refs                      # the borrows go with the references
 
 
 def _grown(producer, before, wanted, timeout=30):
     """The growth of the producer's sums once ``wanted(growth)`` holds:
-    what follows a stream's end (the borrows' removal) is not awaited by
-    the stream."""
+    a reply is counted when its frame is parsed, which the stream's
+    consumer does not wait for."""
     deadline = time.monotonic() + timeout
     while True:
         after = ray_tpu.get(producer.sums.remote(), timeout=60)
@@ -132,31 +161,31 @@ def test_a_yields_stages_ride_on_its_region_and_add_up_to_the_sums(
 def test_a_stream_of_n_yields_is_counted_message_by_message(cluster,
                                                             tmp_path):
     """N yields between two local processes: N ``stream_yield`` requests out
-    and their N replies in.  TODAY each token also costs a ``borrow_add``
-    and, when the consumer lets go of it, a ``borrow_remove`` (the producer
-    keeps a counted reference to an object the consumer owns): three
-    requests and three replies a token.  Pinned here so that the change
-    which sheds them shows.  The totals are always on; the kinds and types
-    are told apart while a profiler session records."""
+    and their N replies in, and nothing else a token.  Until ISSUE 54 each
+    token also cost a ``borrow_add`` and, at the stream's end, a
+    ``borrow_remove`` (the producer kept a counted reference to an object
+    the consumer owns): three requests and three replies.  The producer
+    now only names its yields.  The totals are always on; the kinds and
+    types are told apart while a profiler session records."""
     producer = Producer.remote()
     _stream(producer, 2)
     unrecorded = ray_tpu.get(producer.sums.remote(), timeout=60)
-    assert unrecorded["rpc.msgs_out"] >= 6 and \
+    assert unrecorded["rpc.msgs_out"] >= 2 and \
         not any(key.startswith("msgs.") for key in unrecorded)
     ray_tpu.get(producer.start_trace.remote(str(tmp_path)), timeout=120)
     before = ray_tpu.get(producer.sums.remote(), timeout=60)
     _stream(producer)
-    removed = "msgs.out.request.borrow_remove"
-    grown = _grown(producer, before, lambda g: g.get(removed) == N
-                   and g.get("msgs.in.reply", 0) >= 3 * N)
+    grown = _grown(producer, before,
+                   lambda g: g.get("msgs.in.reply", 0) >= N)
     ray_tpu.get(producer.stop_trace.remote(str(tmp_path)), timeout=120)
     assert grown["msgs.out.request.stream_yield"] == N
-    assert grown["msgs.out.request.borrow_add"] == N
-    assert grown[removed] == N
-    # the acks, the borrows' answers, and whatever else this worker asked
-    # of the cluster meanwhile
-    assert grown["msgs.in.reply"] >= 3 * N
-    assert grown["rpc.msgs_out"] >= 3 * N and \
+    assert not any("borrow" in key for key in grown), grown
+    # the acks, and whatever else this worker asked of the cluster
+    # meanwhile
+    assert grown["msgs.in.reply"] >= N
+    # the yields, the answers to the calls that read these very sums and
+    # started the stream, a heartbeat: well under a second message a token
+    assert N <= grown["rpc.msgs_out"] < 2 * N and \
         grown["rpc.msgs_in"] >= grown["msgs.in.reply"]
     # a frame holds at least one message, and packing it takes time
     assert 0 < grown["rpc.frames_out"] <= grown["rpc.msgs_out"]
@@ -180,6 +209,144 @@ def test_an_owner_on_another_host_is_not_asked_for_its_clock(cluster,
     assert len(yields) == 4
     for stats in yields:
         assert set(stats) == {"wait_us", "store_us", "ack_us", "after_us"}
+
+
+# ------------------------------------------------------ who holds a yield
+
+def _settled():
+    """Once the owner's loop (this process's) has run what was queued on it
+    so far: a dropped reference's free is queued there by the drop."""
+    core = get_core()
+    asyncio.run_coroutine_threadsafe(asyncio.sleep(0), core.loop).result(60)
+    return core
+
+
+def _ids(gen, n):
+    """The ids of a streaming call's returns: 0 the final list's, 1..n the
+    yields'."""
+    task = TaskID(bytes.fromhex(gen.task_id()))
+    return [ObjectID.for_task_return(task, i).hex() for i in range(n + 1)]
+
+
+def _kept(core, ids):
+    return [h for h in ids if h in core.owned or h in core.memory_store]
+
+
+def _arrived(core, oid_hex, timeout=60):
+    deadline = time.monotonic() + timeout
+    while oid_hex not in core.owned:
+        assert time.monotonic() < deadline, "the yield never arrived"
+        time.sleep(0.005)
+
+
+def test_the_producer_counts_and_borrows_none_of_its_yields(cluster,
+                                                            tmp_path):
+    producer = Producer.remote()
+    _stream(producer, 2)
+    gen = producer.gated.options(num_returns="streaming").remote(
+        N, str(tmp_path / "gate"), early=N)
+    refs = list(gen)
+    held, now, closed = ray_tpu.get(producer.told.remote(), timeout=60)
+    assert len(held) == N and closed
+    # what the body saw with i yields behind it is what it saw with none
+    assert {borrowed for borrowed, _ in held} == {0}
+    assert {counted for _, counted in held} == {held[0][1]}
+    assert now == held[0]
+    assert [ray_tpu.get(r, timeout=60) for r in refs] == list(range(N))
+
+
+def test_a_dropped_yield_is_freed_while_its_stream_runs(cluster, tmp_path):
+    core = get_core()
+    producer = Producer.remote()
+    gate = tmp_path / "gate"
+    gen = producer.gated.options(num_returns="streaming").remote(
+        4, str(gate), early=2)
+    final, first, second, *_ = _ids(gen, 4)
+    kept = next(gen)
+    dropped = next(gen)
+    assert [kept.hex(), dropped.hex()] == [first, second]
+    assert ray_tpu.get(dropped, timeout=60) == 1
+    assert second in core.owned and second in core.memory_store
+    del dropped
+    _settled()
+    assert _kept(core, [second]) == []
+    # the stream has not ended: its final list is not here, the body waits
+    assert final not in core.memory_store
+    assert _kept(core, [first]) == [first]
+    gate.touch()
+    assert [ray_tpu.get(r, timeout=60) for r in gen] == [2, 3]
+    assert ray_tpu.get(kept, timeout=60) == 0
+
+
+def test_a_held_yield_outlives_its_stream_and_the_list_names_them_all(
+        cluster, tmp_path):
+    producer = Producer.remote()
+    gen = producer.gated.options(num_returns="streaming").remote(
+        N, str(tmp_path / "gate"), early=N)
+    refs = list(gen)
+    yielded = [r.hex() for r in refs]
+    assert yielded == _ids(gen, N)[1:]
+    kept, gone = refs[3], yielded[4]
+    del refs
+    core = _settled()
+    listed = list(ray_tpu.get(gen.completed(), timeout=60))
+    assert [r.hex() for r in listed] == yielded
+    # naming a yield does not hold it, and does not bring it back
+    assert _kept(core, yielded) == [kept.hex()]
+    assert ray_tpu.get(kept, timeout=60) == 3
+    assert ray_tpu.get(listed[3], timeout=60) == 3
+    with pytest.raises(ray_tpu.exceptions.ObjectLostError, match="freed"):
+        ray_tpu.get(listed[4], timeout=60)
+    assert listed[4].hex() == gone
+    del listed, kept
+    assert _kept(_settled(), yielded) == []
+
+
+def test_a_cancelled_stream_runs_the_bodys_finally_and_leaves_nothing_owned(
+        cluster, tmp_path):
+    core = get_core()
+    producer = Producer.remote()
+    gate = tmp_path / "gate"
+    gen = producer.gated.options(num_returns="streaming").remote(
+        N, str(gate), early=4)
+    ids = _ids(gen, N)
+    taken = next(gen)
+    assert ray_tpu.get(taken, timeout=60) == 0
+    _arrived(core, ids[4])        # three more wait on the stream's queue
+    gen.cancel()
+    gate.touch()
+    held, _, closed = ray_tpu.get(producer.told.remote(), timeout=60)
+    assert closed and 4 <= len(held) < N
+    del taken, gen
+    _settled()
+    assert _kept(core, ids) == [] and ids[0] not in core._streams
+    assert not any(core._borrowers.get(h) for h in ids)
+
+
+def test_a_yield_too_large_to_go_inline_is_read_while_held_and_freed_after(
+        cluster, tmp_path):
+    core = get_core()
+    size = 2 * INLINE_MAX()
+    producer = Producer.remote()
+    before = ray_tpu.get(producer.sums.remote(), timeout=60)
+    gate = tmp_path / "gate"
+    gen = producer.gated.options(num_returns="streaming").remote(
+        2, str(gate), early=1, size=size)
+    large = next(gen)
+    oid = large.id
+    # the owner holds the name; the copy is in the producer's node's store
+    assert core.memory_store[large.hex()] == ("plasma", None)
+    assert ray_tpu.get(large, timeout=60) == bytes(size)
+    assert core.plasma.contains(oid)
+    del large
+    _settled()
+    # freed with the stream still running, the copy with it
+    assert _kept(core, [oid.hex()]) == [] and not core.plasma.contains(oid)
+    gate.touch()
+    assert [len(ray_tpu.get(r, timeout=60)) for r in gen] == [size]
+    grown = _grown(producer, before,
+                   lambda g: g.get("stream.store_aside_s", 0) > 0)
+    assert grown["stream.yields"] == 2 and grown["stream.store_aside_s"] > 0
 
 
 # ------------------------------------------------------ a serving replica
