@@ -530,7 +530,8 @@ def gpt_decode_step(params: Dict[str, Any], cfg: GPTConfig,
 def gpt_paged_read(cfg: GPTConfig, k_pages) -> str:
     """What ``gpt_decode_step`` reads the pages with, "kernel" or "gather"
     (``ops/paged_attention.py::paged_read_kind`` of its queries and the
-    pool; GPT-2's heads of 64 are no whole lane tile: the gather)."""
+    pool; GPT-2's EQUAL heads of 64 are no whole lane tile and share no K/V head: the
+    gather)."""
     from ray_tpu.ops.paged_attention import paged_read_kind
     return paged_read_kind(jax.ShapeDtypeStruct(
         (1, cfg.num_heads, cfg.head_dim), cfg.dtype), k_pages)

@@ -2,7 +2,8 @@
 
 Second flagship model family beside GPT-2 (``models/gpt.py``): RMSNorm
 pre-norm, rotary position embeddings (no learned positions), SwiGLU MLP,
-untied LM head, and grouped-query attention (kv_heads <= heads).  Same
+an LM head of its own (``tie_embeddings``: the table's transpose, one leaf
+fewer), and grouped-query attention (kv_heads <= heads).  Same
 TPU-first construction as GPT: bf16 compute / f32 params, layers stacked
 on a scanned [L, ...] dim (single XLA while-loop; the dim doubles as the
 pp shard axis), logical-axis annotations on every param so one definition
@@ -69,8 +70,9 @@ hyper-connections, arXiv:2512.24880; ``_sublayer``).  ``shared_experts``,
 ``ops/moe.py``'s.  Such a model serves and runs ``llama_forward``; it does
 not train here.
 
-With a ``layer_pattern`` the stack is layers of TWO kinds in a repeating
-period (Olmo-Hybrid's: three "linear" then one "full"): a linear layer's
+With a ``layer_pattern`` the stack is layers of two kinds in a repeating
+period, "full" and one of "linear" and "conv" (three kinds are written;
+Olmo-Hybrid's period is three "linear" then one "full"): a linear layer's
 attention is the gated delta rule (``_linear_attention``;
 ``ops/linear_attention.py`` has the rule's chunked scan, its one-position
 step and the short causal convolution ahead of it), which keeps of its past
@@ -106,6 +108,20 @@ layers run in a Python loop (``_unrolled_layers``).  With ``expert_share`` (i,
 n) the program holds share i of n of every expert layer's experts and computes
 their part of the routed sum alone (``ops/moe.py``, the held experts): Kimi
 Linear's block, one chip's share of it.
+
+The THIRD kind, "conv", is LFM2's gated short convolution (``_conv_operator``;
+transformers' ``Lfm2ShortConv``): ``[B | C | z] = h W_in``, ``u = B * z``, a
+causal depthwise convolution of ``u`` over ``linear_conv`` (3) positions with
+NO activation after it, ``y = C * conv(u)``, ``y W_out``.  Such a layer keeps
+of its past the last ``linear_conv - 1`` positions of ``u`` and nothing else:
+``RecurrentPools.state`` is None (no matrix, nothing that grows) and
+``RecurrentPools.conv`` holds a tail a decode slot for every conv layer, which
+a prefill leaves as it stands after the prompt's last real position, whatever
+the rung, zeros on the left of a prompt shorter than the tail.  Its stack is
+either dense feed-forwards scanned over periods or, behind
+``first_dense_layers``, experts (no shared one needed) in ``_unrolled_layers``:
+LFM2-24B-A2B's block with ``qk_norm_per_head``, heads of ``head_size`` 64,
+``tie_embeddings`` and sigmoid routing renormalised over ``router_norm_eps``.
 """
 
 from __future__ import annotations
@@ -162,6 +178,7 @@ class LlamaConfig:
     router_scoring: str = "softmax"  # or "sigmoid" (gates from the scores)
     router_bias: bool = False        # added to the scores to SELECT only
     routed_scaling: float = 1.0      # sigmoid routing: the gates' factor
+    router_norm_eps: float = 1e-20   #   and what their sum is renormalised over
     hc_mult: int = 0                 # rows of a hyper-connected residual
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -173,8 +190,11 @@ class LlamaConfig:
     confidence_threshold: float = 0.0   # this many passes (0 = static:
     mask_token: int = 0              #   the count alone), masks of this id
     pre_norm: bool = True            # RMSNorm a sublayer's input
-    # one period of the stack's layer kinds, "linear" | "full" (the stack
-    # repeats it; empty: every layer is full attention), and a linear layer's
+    # one period of the stack's layer kinds, "full" and one of "linear" |
+    # "conv" (the stack repeats it; empty: every layer is full attention; a
+    # "conv" layer is a gated short convolution over ``linear_conv``
+    # positions and takes none of the other linear_* fields), and a linear
+    # layer's
     layer_pattern: Tuple[str, ...] = ()
     linear_heads: int = 0            #   heads (keys' and values' alike),
     linear_key_dim: int = 0          #   a head's q/k width,
@@ -187,6 +207,7 @@ class LlamaConfig:
     # (i, n): this program holds share i of n of every expert layer, experts
     # i * num_experts / n on; the router keeps num_experts outputs
     expert_share: Tuple[int, int] = (0, 1)
+    tie_embeddings: bool = False     # logits from the table: no lm_head leaf
 
     @property
     def head_dim(self) -> int:
@@ -243,21 +264,32 @@ def _check(cfg: LlamaConfig) -> None:
                          "qk_norm_per_head")
     if cfg.layer_pattern:
         kinds, others = set(cfg.layer_pattern), ("hc_mult", "block_length")
-        if not kinds <= {"linear", "full"} or kinds == {"full"} \
+        if not (kinds <= {"linear", "full"} or kinds <= {"conv", "full"}) \
+                or kinds == {"full"} \
                 or cfg.num_layers % len(cfg.layer_pattern):
             raise ValueError(
-                f"layer_pattern={cfg.layer_pattern} is one period of "
-                "'linear' and 'full' layers, at least one of them linear, "
-                f"and num_layers={cfg.num_layers} whole periods")
-        if not (cfg.linear_heads and cfg.linear_key_dim
-                and cfg.linear_value_dim and cfg.linear_conv > 1):
+                f"layer_pattern={cfg.layer_pattern} is one period of 'full' "
+                "layers and layers of ONE other kind, at least one of them "
+                "linear (the delta rule) or at least one a 'conv' (a gated "
+                f"short convolution), and num_layers={cfg.num_layers} whole "
+                "periods")
+        if cfg.linear_conv < 2 or ("linear" in kinds and not (
+                cfg.linear_heads and cfg.linear_key_dim
+                and cfg.linear_value_dim)):
             raise ValueError("linear layers need linear_heads, "
-                             "linear_key_dim, linear_value_dim and a "
-                             "linear_conv of 2 or more")
+                             "linear_key_dim and linear_value_dim, and they "
+                             "and conv layers a linear_conv of 2 or more")
+        if "conv" in kinds and (cfg.kv_lora_rank or cfg.linear_gate_rank
+                                or cfg.linear_heads):
+            raise ValueError("a stack with conv layers is written with K/V "
+                             "pages for its full layers (no kv_lora_rank) "
+                             "and takes of the linear_* fields linear_conv "
+                             "alone")
         if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others) or (
                 cfg.num_experts and not cfg.first_dense_layers):
-            raise ValueError("a stack with linear layers (layer_pattern) is "
-                             "not written for ut_steps > 1, "
+            raise ValueError("a stack with linear or conv layers "
+                             "(layer_pattern) is not written for "
+                             "ut_steps > 1, "
                              + ", ".join(others) + " or num_experts without "
                              "first_dense_layers (experts inside the scan "
                              "over periods); its full layers keep K/V or "
@@ -288,11 +320,13 @@ def _check(cfg: LlamaConfig) -> None:
                          "mask_token belong to a block_length")
 
 
-def _linear_layers(cfg: LlamaConfig) -> int:
-    """How many of the stack's layers are linear attention."""
+def _slot_layers(cfg: LlamaConfig) -> int:
+    """How many of the stack's layers are not full attention (linear
+    attention or a short convolution: those that keep rows a slot, no
+    pages)."""
     if not cfg.layer_pattern:
         return 0
-    return cfg.layer_pattern.count("linear") * (
+    return (len(cfg.layer_pattern) - cfg.layer_pattern.count("full")) * (
         cfg.num_layers // len(cfg.layer_pattern))
 
 
@@ -308,12 +342,12 @@ def _layer_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
 
 
 def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
-                experts: int, M: int, linear: bool = False) -> Dict[str, Any]:
-    """``L`` layers of one kind stacked on a leading dim: ``experts`` of
+                experts: int, M: int, kind: str = "full") -> Dict[str, Any]:
+    """``L`` layers of one ``kind`` stacked on a leading dim: ``experts`` of
     width ``M`` each (0: one dense SwiGLU of ``M``; of ``cfg.num_experts``
     the program's share, ``_held_experts``: the router keeps them all);
-    with ``linear`` the linear-attention leaves under ``"linear"`` where the
-    others have ``"attn"``."""
+    a "linear" kind's leaves under ``"linear"`` and a "conv" kind's under
+    ``"conv"`` where the others have ``"attn"``."""
     k = jax.random.split(rng, 8)
     D, H = cfg.embed_dim, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -340,7 +374,17 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                 "wgu": normal(jax.random.fold_in(k[4], 1), (L, 2, D, Ms)),
                 "wd": normal(jax.random.fold_in(k[5], 1), (L, Ms, D),
                              rscale)}
-    if linear:
+    if kind == "conv":
+        # LFM2's gated short convolution: B | C | z in one projection, the
+        # taps as torch's Conv1d draws them (uniform within K^-1/2,
+        # ``taps[K - 1]`` meets the position itself), the way out
+        K = cfg.linear_conv
+        attn = {"win": normal(k[1], (L, D, 3 * D)),
+                "taps": jax.random.uniform(
+                    jax.random.fold_in(k[2], 1), (L, K, D), jnp.float32,
+                    -K ** -0.5, K ** -0.5),
+                "wout": normal(k[3], (L, D, D), rscale)}
+    elif kind == "linear":
         # The gated delta rule's leaves (transformers' Qwen3NextGatedDeltaNet
         # draws them so): the convolution as torch's Conv1d, uniform within
         # fan_in^-1/2 = K^-1/2; A uniform over (0, 16); dt log-uniform over
@@ -412,7 +456,7 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
             "bias": bias.at[:, 2 * n:].add(2.0 * jnp.eye(n).reshape(-1))}
     for name in ("ln1", "ln2") if cfg.pre_norm else ():
         extra[name] = {"scale": jnp.ones((L, D), jnp.float32)}
-    return {"linear" if linear else "attn": attn, "mlp": mlp, **extra}
+    return {kind if kind != "full" else "attn": attn, "mlp": mlp, **extra}
 
 
 def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
@@ -442,25 +486,25 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             return tuple(
                 _init_group(jax.random.fold_in(rng, 16 + at), cfg, 1,
                             *((0, cfg.dense_mlp_dim) if at < Ld else
-                              (_held_experts(cfg), cfg.mlp_dim)),
-                            kind == "linear")
+                              (_held_experts(cfg), cfg.mlp_dim)), kind)
                 for at, kind in enumerate(_layer_kinds(cfg)))
         periods = cfg.num_layers // len(cfg.layer_pattern)
         return tuple(
             _init_group(jax.random.fold_in(rng, 16 + at), cfg, periods, 0,
-                        cfg.mlp_dim, kind == "linear")
+                        cfg.mlp_dim, kind)
             for at, kind in enumerate(cfg.layer_pattern))
     return {
         "wte": scale * jax.random.normal(k[0], (V, D), jnp.float32),
         **dense,
         "layers": layers(),
         "ln_f": {"scale": jnp.ones((D,), jnp.float32)},
-        "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32),
+        **({} if cfg.tie_embeddings else {
+            "lm_head": scale * jax.random.normal(k[6], (D, V), jnp.float32)}),
     }
 
 
 def _group_axes(cfg: LlamaConfig, experts: bool,
-                linear: bool = False) -> Dict[str, Any]:
+                kind: str = "full") -> Dict[str, Any]:
     ex = ("expert",) if experts else ()
     mlp = {"wgu": ("layers", *ex, None, "embed", "mlp"),
            "wd": ("layers", *ex, "mlp", "embed")}
@@ -472,7 +516,11 @@ def _group_axes(cfg: LlamaConfig, experts: bool,
         if cfg.shared_experts:
             extra["shared"] = {"wgu": ("layers", None, "embed", "mlp"),
                                "wd": ("layers", "mlp", "embed")}
-    if linear:
+    if kind == "conv":
+        attn = {"win": ("layers", "embed", "heads"),
+                "taps": ("layers", None, "heads"),
+                "wout": ("layers", "heads", "embed")}
+    elif kind == "linear":
         gates = {"wg_a": ("layers", "embed", None),
                  "wg_b": ("layers", None, "heads"),
                  "wf_a": ("layers", "embed", None),
@@ -512,7 +560,7 @@ def _group_axes(cfg: LlamaConfig, experts: bool,
                        "alpha": ("layers", None), "bias": ("layers", None)}
     for name in ("ln1", "ln2") if cfg.pre_norm else ():
         extra[name] = {"scale": ("layers", "norm")}
-    return {"linear" if linear else "attn": attn, "mlp": mlp, **extra}
+    return {kind if kind != "full" else "attn": attn, "mlp": mlp, **extra}
 
 
 def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
@@ -524,17 +572,17 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     layers = _group_axes(cfg, bool(cfg.num_experts))
     if cfg.layer_pattern and cfg.first_dense_layers:     # a group a layer
         layers = tuple(
-            _group_axes(cfg, at >= cfg.first_dense_layers, kind == "linear")
+            _group_axes(cfg, at >= cfg.first_dense_layers, kind)
             for at, kind in enumerate(_layer_kinds(cfg)))
     elif cfg.layer_pattern:
-        layers = tuple(_group_axes(cfg, False, kind == "linear")
+        layers = tuple(_group_axes(cfg, False, kind)
                        for kind in cfg.layer_pattern)
     return {
         "wte": (None, "embed"),
         **dense,
         "layers": layers,
         "ln_f": {"scale": ("norm",)},
-        "lm_head": ("embed", None),
+        **({} if cfg.tie_embeddings else {"lm_head": ("embed", None)}),
     }
 
 
@@ -947,7 +995,7 @@ def _unrolled_layers(cfg: LlamaConfig, params, body, carry, served: bool):
     for a scanned stack.  A ``served`` layer gets its index among the layers
     of its kind.  Returns the last carry and the expert layers' ``ys``
     stacked."""
-    seen, ys = {"linear": 0, "full": 0}, []
+    seen, ys = dict.fromkeys(cfg.layer_pattern, 0), []
     for kind, group in zip(_layer_kinds(cfg), params["layers"]):
         experts = group["mlp"] if "router" in group["mlp"] else None
         p = jax.tree.map(lambda a: a[0], {
@@ -982,7 +1030,8 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
             scoring=cfg.router_scoring, routed_scaling=cfg.routed_scaling,
             shared=_cast_leaves(p["shared"], dt, "wgu", "wd")
             if cfg.shared_experts else None,
-            first_expert=cfg.expert_share[0] * _held_experts(cfg))
+            first_expert=cfg.expert_share[0] * _held_experts(cfg),
+            norm_eps=cfg.router_norm_eps)
         return y.reshape(h.shape), load
     gu = jnp.einsum("...d,cdm->c...m", h, p["mlp"]["wgu"].astype(dt))
     a = lc(jax.nn.silu(gu[0]) * gu[1], ("batch", "seq", "mlp"))
@@ -994,7 +1043,9 @@ class AttentionState(NamedTuple):
     ``kv(p, layer, pools, q, k, v)`` for K/V pages, ``latent(p, layer, pools,
     q_nope, q_rope, latent)`` for latent pages, ``recurrent(p, layer, pools,
     qkv, g, beta)`` for a linear layer's state row (None: not written for
-    them).  Each writes, reads and returns ``(o, pools)``.  ``p`` is the
+    them), ``conv(p, layer, pools, u)`` for a conv layer's tail (the
+    convolution of ``u`` over time and, served, the hand-over of its last
+    inputs).  Each writes, reads and returns ``(o, pools)``.  ``p`` is the
     layer's parameters (a latent kind expands with its ``wkv_b``, a
     recurrent one convolves with its ``conv``), ``layer`` its index into
     ``pools`` among the layers of its kind, and ``pools`` the pair the trunk
@@ -1008,19 +1059,21 @@ class AttentionState(NamedTuple):
     kv: Callable
     latent: Optional[Callable] = None
     recurrent: Optional[Callable] = None
+    conv: Optional[Callable] = None
 
 
 class RecurrentPools(NamedTuple):
-    """What a model with linear layers keeps where the others keep their V
-    pool: that pool (the full layers'; None where those keep latent pages,
-    which have no V pool) and, a row a decode SLOT and not
+    """What a model with linear or conv layers keeps where the others keep
+    their V pool: that pool (the full layers'; None where those keep latent
+    pages, which have no V pool) and, a row a decode SLOT and not
     pages, the linear layers' states ``[linear layers, slots, panels, dk,
-    lanes]`` float32 (``ops/linear_attention.py``'s folded layout) and the
-    last inputs of their convolutions ``[linear layers, slots, (K - 1) *
-    channels]``.  A slot's rows are overwritten whole by the next prefill
-    into it: nothing allocates or frees them."""
+    lanes]`` float32 (``ops/linear_attention.py``'s folded layout; None for a
+    stack of conv layers, which keep no state matrix) and the
+    last inputs of their convolutions ``[linear or conv layers, slots, (K -
+    1) * channels]``.  A slot's rows are overwritten whole by the next
+    prefill into it: nothing allocates or frees them."""
     v_pages: Optional[jax.Array]
-    state: jax.Array
+    state: Optional[jax.Array]
     conv: jax.Array
 
 
@@ -1093,9 +1146,14 @@ def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
         return jax.vmap(lambda *row: _linear_sequence(cfg, p, *row)[0])(
             qkv, g, beta), pools
 
+    def conv(p, layer, pools, u):
+        from ray_tpu.ops.linear_attention import causal_conv
+        return jax.vmap(lambda row: causal_conv(
+            row, p["conv"]["taps"], silu=False))(u), pools
+
     # a latent model's is dense: the kernels want equal heads
     return AttentionState(kv, lambda p, layer, pools, *projected: (
-        _mla_expanded(cfg, p, *projected), pools), recurrent)
+        _mla_expanded(cfg, p, *projected), pools), recurrent, conv)
 
 
 def _prefill_state(cfg: LlamaConfig, length, page_table,
@@ -1135,7 +1193,18 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
                     rows.conv, tail.astype(rows.conv.dtype)[None, None],
                     (layer, slot, 0)))
         return o[None], (pools[0], rows)
-    return AttentionState(kv, latent, recurrent)
+
+    def conv(p, layer, pools, u):
+        # the tail is the last inputs before position ``length``, zeros on
+        # the left of a prompt shorter than it: whatever the rung pads
+        from ray_tpu.ops.linear_attention import causal_conv, conv_tail
+        w, rows = p["conv"]["taps"], pools[1]
+        tail = conv_tail(u[0], length, w.shape[0])
+        rows = rows._replace(conv=jax.lax.dynamic_update_slice(
+            rows.conv, tail.astype(rows.conv.dtype)[None, None],
+            (layer, slot, 0)))
+        return causal_conv(u[0], w, silu=False)[None], (pools[0], rows)
+    return AttentionState(kv, latent, recurrent, conv)
 
 
 def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
@@ -1169,7 +1238,18 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
                 state=state, conv=jax.lax.dynamic_update_index_in_dim(
                     rows.conv, tail, layer, 0))
         return o, (pools[0], rows)
-    return AttentionState(kv, latent, recurrent)
+
+    def conv(p, layer, pools, u):
+        # row b of the batch is slot b; a parked slot keeps its tail
+        from ray_tpu.ops.linear_attention import causal_conv_step
+        rows = pools[1]
+        c, tail = causal_conv_step(u, p["conv"]["taps"], rows.conv[layer],
+                                   silu=False)
+        tail = jnp.where((pos > 0)[:, None], tail, rows.conv[layer])
+        return c, (pools[0], rows._replace(
+            conv=jax.lax.dynamic_update_index_in_dim(
+                rows.conv, tail, layer, 0)))
+    return AttentionState(kv, latent, recurrent, conv)
 
 
 def _block_state(cfg: LlamaConfig, pos0, page_table) -> AttentionState:
@@ -1276,15 +1356,46 @@ def _gated_norm(cfg: LlamaConfig, scale, o, z, gate=jax.nn.silu):
                 * gate(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
+def _conv_operator(cfg: LlamaConfig, p, h, state: AttentionState, layer,
+                   pools):
+    """A conv layer's operator on ``h`` [..., D] (LFM2's gated short
+    convolution; transformers' ``Lfm2ShortConv``): ``[B | C | z] = h W_in``
+    cut in that order into three of D, ``u = B * z``, the ``state``'s causal
+    depthwise convolution of ``u`` over ``linear_conv`` positions (no bias,
+    NO activation; served, the tail's hand-over), ``y = C * conv(u)``, ``y
+    W_out``.  Returns (the sublayer's output, the state's pools)."""
+    if state.conv is None:
+        raise NotImplementedError(
+            "models/llama.py: this program's attention state is not "
+            "written for conv layers (layer_pattern): the block step keeps "
+            "K/V pages only")
+    a, dt, D = p["conv"], cfg.dtype, cfg.embed_dim
+    with jax.named_scope("conv_in"):
+        bcz = jnp.einsum("...d,dc->...c", h, a["win"].astype(dt))
+        u = bcz[..., :D] * bcz[..., 2 * D:]
+    with jax.named_scope("conv_mix"):
+        c, pools = state.conv(p, layer, pools, u)
+        y = bcz[..., D:2 * D] * c
+    with jax.named_scope("conv_out"):
+        return jnp.einsum("...c,cd->...d", y, a["wout"].astype(dt)), pools
+
+
 def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
            pools, live=None, lc=lambda a, ax: a, experts=None):
     """One layer, the same for every program: attention against ``state``
     and the feed-forward, each a ``_sublayer`` of the residual stream ``x``.
     Returns (x, the state's pools, the experts' load)."""
     stream = _stream_axes(cfg)
-    x, pools = _sublayer(cfg, p, 0, x, (lambda h: _linear_attention(
-        cfg, p, h, state, layer, pools)) if "linear" in p else (
-            lambda h: _attention(cfg, p, h, cos, sin, state, layer, pools)))
+    if "linear" in p:                # the layer's kind, by its leaves
+        def mixer(h):
+            return _linear_attention(cfg, p, h, state, layer, pools)
+    elif "conv" in p:
+        def mixer(h):
+            return _conv_operator(cfg, p, h, state, layer, pools)
+    else:
+        def mixer(h):
+            return _attention(cfg, p, h, cos, sin, state, layer, pools)
+    x, pools = _sublayer(cfg, p, 0, x, mixer)
     x = lc(x, stream)
     x, load = _sublayer(cfg, p, 1, x, lambda h: _ffn(
         cfg, p, h, live, lc, experts))
@@ -1347,14 +1458,23 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
     return x
 
 
+def _head(cfg: LlamaConfig, params, x, rows: str = "bs"):
+    """Logits of the final hidden ``x`` [*rows, D] in the compute dtype: by
+    the head's own matrix, or with ``cfg.tie_embeddings`` by the table."""
+    if cfg.tie_embeddings:
+        return jnp.einsum(f"{rows}d,vd->{rows}v", x,
+                          params["wte"].astype(cfg.dtype))
+    return jnp.einsum(f"{rows}d,dv->{rows}v", x,
+                      params["lm_head"].astype(cfg.dtype))
+
+
 def llama_forward(params: Dict[str, Any], tokens: jax.Array,
                   cfg: LlamaConfig,
                   rules: Optional[LogicalAxisRules] = None,
                   mesh=None) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, V] (compute dtype; the fused
     loss upcasts inside its reductions, same contract as gpt_forward)."""
-    x = llama_hidden(params, tokens, cfg, rules, mesh)
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype))
+    return _head(cfg, params, llama_hidden(params, tokens, cfg, rules, mesh))
 
 
 # ---------------------------------------------------------------------------
@@ -1386,9 +1506,10 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     full layers' alone, K/V or latent, and where the V pool would be come
     ``RecurrentPools``: that pool (None beside latent pages), and the linear
     layers' state and convolution rows for ``slots`` decode slots, zeroed
-    (an empty state)."""
+    (an empty state); conv layers keep a convolution tail a slot (the last
+    ``linear_conv - 1`` positions of D channels) and no state: None."""
     dt = dtype or cfg.dtype
-    L = cfg.ut_steps * cfg.num_layers - _linear_layers(cfg)
+    L = cfg.ut_steps * cfg.num_layers - _slot_layers(cfg)
     if cfg.block_length and page_size % cfg.block_length:
         raise ValueError(f"page_size={page_size} must be a multiple of "
                          f"block_length={cfg.block_length}: a block's "
@@ -1406,8 +1527,11 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     if slots < 1:
         raise ValueError("a model with linear layers keeps a state row a "
                          "decode slot: say how many slots")
+    rows = (_slot_layers(cfg), slots)
+    if "conv" in cfg.layer_pattern:
+        return pages[0], RecurrentPools(pages[1], None, jnp.zeros(
+            (*rows, (cfg.linear_conv - 1) * cfg.embed_dim), dt))
     N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
-    rows = (_linear_layers(cfg), slots)
     return pages[0], RecurrentPools(
         pages[1],
         jnp.zeros((*rows, *state_shape(N, dk, dv)), jnp.float32),
@@ -1442,6 +1566,8 @@ def llama_serving_params(params: Dict[str, Any],
             layers["linear"], "wqkv", "wz", "wba", "wg_a", "wg_b", "wf_a",
             "wf_b", "wb", "conv", "wo")} \
             if "linear" in layers else \
+            {"conv": cast(layers["conv"], "win", "taps", "wout")} \
+            if "conv" in layers else \
             {"attn": cast(layers["attn"], *matrices)}
         out = {**layers, **mixer,
                "mlp": layers["mlp"] if experts else
@@ -1451,7 +1577,7 @@ def llama_serving_params(params: Dict[str, Any],
         return out
     dense = {"dense_layers": group(params["dense_layers"], False)} \
         if "dense_layers" in params else {}
-    return {**_cast_leaves(params, dt, "wte", "lm_head"), **dense,
+    return {**cast(params, "wte", "lm_head"), **dense,
             "layers": tuple(group(g, "router" in g["mlp"])
                             for g in params["layers"])
             if cfg.layer_pattern else group(params["layers"],
@@ -1560,8 +1686,7 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
         return _paged_results(jnp.zeros((1, 0), jnp.float32), k_pages,
                               v_pages, load)
     last = x[0, length - 1]                              # [D]
-    logits = jnp.einsum("d,dv->v", last, params["lm_head"].astype(
-        cfg.dtype)).astype(jnp.float32)
+    logits = _head(cfg, params, last, "").astype(jnp.float32)
     return _paged_results(logits[None], k_pages, v_pages, load)
 
 
@@ -1591,8 +1716,7 @@ def llama_decode_step(params: Dict[str, Any], cfg: LlamaConfig,
     (x, k_pages, v_pages), load = _served_trunk(
         cfg, params, x, cos, sin, _token_state(cfg, pos, page_table),
         pos > 0, k_pages, v_pages)
-    logits = jnp.einsum("bd,dv->bv", x, params["lm_head"].astype(
-        cfg.dtype)).astype(jnp.float32)
+    logits = _head(cfg, params, x, "b").astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
 
 
@@ -1630,8 +1754,7 @@ def llama_block_step(params: Dict[str, Any], cfg: LlamaConfig,
         cfg, params, x, cos, sin, _block_state(cfg, pos0, page_table),
         jnp.broadcast_to(live[:, None], tokens.shape), k_pages, v_pages)
     with jax.named_scope("lm_head"):
-        logits = jnp.einsum("sbd,dv->sbv", x, params["lm_head"].astype(
-            cfg.dtype)).astype(jnp.float32)
+        logits = _head(cfg, params, x, "sb").astype(jnp.float32)
     return _paged_results(logits, k_pages, v_pages, load)
 
 
@@ -1698,7 +1821,10 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
     return ServedModel(
         config=cfg, init=llama_init, stored=llama_serving_params,
         new_pools=functools.partial(llama_init_paged_cache, cfg),
-        slot_rows=(lambda k_pages, v_pages: (v_pages.state, v_pages.conv))
+        slot_rows=(lambda k_pages, v_pages: tuple(
+            a for a in (v_pages.state, v_pages.conv) if a is not None))
+        if cfg.layer_pattern else None,
+        conv_tails=(lambda k_pages, v_pages: v_pages.conv)
         if cfg.layer_pattern else None,
         page_kind="latent" if cfg.kv_lora_rank else "kv",
         expert_stack=(lambda params: _expert_stack(cfg, params))
@@ -1707,7 +1833,8 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         step=llama_block_step if cfg.block_length else llama_decode_step,
         prefill_attention=llama_prefill_attention,
         paged_read=llama_paged_read,
-        linear_state=llama_linear_state if cfg.layer_pattern else None,
+        linear_state=llama_linear_state
+        if "linear" in cfg.layer_pattern else None,
         block=cfg.block_length,
         feed=(lambda cfg, logits, state, end: (
             None, block_unmask(cfg, logits, state, end)))
@@ -1735,9 +1862,10 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     objective such a model is published with."""
     if cfg.layer_pattern:
         raise NotImplementedError(
-            "models/llama.py serves its model with linear-attention layers "
-            "(layer_pattern) and runs llama_forward, but does not train "
-            "it: the chunked scan's backward pass is not written")
+            "models/llama.py serves its model with linear-attention or conv "
+            "layers (layer_pattern) and runs llama_forward, but does not "
+            "train it: the chunked scan's backward pass is not written, and "
+            "neither is a pattern stack's remat and sharding")
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             "models/llama.py serves its looped model (ut_steps > 1) but "
@@ -1755,8 +1883,10 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     toks = batch["tokens"]
     targets = toks[:, 1:]
     x = llama_hidden(params, toks[:, :-1], cfg, rules, mesh)
-    ll = ce_head_loglike_sum(x, params["lm_head"].astype(cfg.dtype),
-                             targets, cfg.ce_block, "dv")
+    ll = ce_head_loglike_sum(
+        x, params["wte" if cfg.tie_embeddings else "lm_head"].astype(
+            cfg.dtype), targets, cfg.ce_block,
+        "vd" if cfg.tie_embeddings else "dv")
     return -ll / targets.size
 
 

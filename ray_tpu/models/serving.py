@@ -49,6 +49,10 @@ class ServedModel(NamedTuple):
     #                              pages (a recurrent state: written whole by
     #                              a prefill, stepped where it lies, its
     #                              leading dims [layers, slots]); None: none
+    conv_tails: Any = None       # (k_pages, v_pages) -> the one of
+    #                              ``slot_rows``' arrays that holds the last
+    #                              inputs of short convolutions (a model may
+    #                              keep those alone: no state matrix)
     linear_state: Any = None     # (config, v_pages) -> "kernel" | "rule":
     #                              what the step's programs step the linear
     #                              layers' states with (``ops/

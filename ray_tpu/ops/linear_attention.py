@@ -81,8 +81,13 @@ write-back, on the folded panels as they lie: alpha is spread over the lanes
 like the keys.
 
 ``causal_conv`` / ``causal_conv_step`` are the depthwise convolution over
-time ahead of the rule (width ``K``, no bias, then SiLU) for a sequence and
-for a decode batch with the ``K - 1`` inputs a slot keeps (``conv_tail``).
+time (width ``K``, no bias) for a sequence and for a decode batch with the
+``K - 1`` inputs a slot keeps (``conv_tail``), in its two forms: ahead of the
+rule, over the fused q | k | v channels and followed by SiLU (width 4 in both
+published models), and, with ``silu=False``, the convolution as it is, which
+is the whole of a gated short-convolution layer's mixing over time (LFM2's
+``Lfm2ShortConv``: width 3 over ``B * z``, ``models/llama.py::
+_conv_operator``); such a layer keeps of its past the tail alone, no state.
 """
 
 from __future__ import annotations
@@ -103,14 +108,15 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 # ------------------------------------------------------------ convolution
 
-def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+def causal_conv(x: jax.Array, w: jax.Array, silu: bool = True) -> jax.Array:
     """SiLU of the causal depthwise convolution of x [S, C] over time with
     w [K, C] (``w[K - 1]`` meets the position itself, zeros lie before the
-    sequence), computed in float32, in x's dtype."""
+    sequence), computed in float32, in x's dtype; without ``silu`` the
+    convolution itself."""
     K, S = w.shape[0], x.shape[0]
     xp = jnp.pad(x.astype(jnp.float32), ((K - 1, 0), (0, 0)))
     y = sum(xp[i:i + S] * w[i].astype(jnp.float32) for i in range(K))
-    return jax.nn.silu(y).astype(x.dtype)
+    return (jax.nn.silu(y) if silu else y).astype(x.dtype)
 
 
 def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
@@ -121,15 +127,16 @@ def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
     return jax.lax.dynamic_slice_in_dim(xp, length, K - 1, 0).reshape(-1)
 
 
-def causal_conv_step(x: jax.Array, w: jax.Array,
-                     tail: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def causal_conv_step(x: jax.Array, w: jax.Array, tail: jax.Array,
+                     silu: bool = True) -> Tuple[jax.Array, jax.Array]:
     """One position a slot: x [B, C] after the ``tail`` [B, (K - 1) * C] of
-    ``conv_tail``.  Returns (the convolution's SiLU [B, C], the next tail)."""
+    ``conv_tail``.  Returns (the convolution's SiLU [B, C], or without
+    ``silu`` the convolution itself; the next tail)."""
     K, C = w.shape
     window = [tail[:, i * C:(i + 1) * C] for i in range(K - 1)] + [x]
     y = sum(a.astype(jnp.float32) * w[i].astype(jnp.float32)
             for i, a in enumerate(window))
-    return jax.nn.silu(y).astype(x.dtype), \
+    return (jax.nn.silu(y) if silu else y).astype(x.dtype), \
         jnp.concatenate(window[1:], axis=-1).astype(tail.dtype)
 
 

@@ -154,7 +154,7 @@ def moe_mlp(x, p, *, top_k: int, capacity_factor: float,
 
 
 def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
-           routed_scaling: float):
+           routed_scaling: float, norm_eps: float = 1e-20):
     """A token's experts and their gates from the router's float32 logits
     [T, E] -> (gates [T, k], experts [T, k]).  ``softmax``: the ``top_k``
     largest of a softmax over all experts are the gates, as they are unless
@@ -162,7 +162,9 @@ def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
     (DeepSeek-V3's ``noaux_tc``): the scores are sigmoids; the experts are
     the ``top_k`` largest of score + ``bias`` [E] (the balancing bias, which
     only SELECTS); the gates are the chosen experts' scores WITHOUT it,
-    renormalised if ``norm_topk_prob`` and times ``routed_scaling``."""
+    renormalised if ``norm_topk_prob`` (divided by their sum + ``norm_eps``:
+    DeepSeek-V3's and Kimi's 1e-20, LFM2's 1e-6) and times
+    ``routed_scaling``."""
     if scoring == "softmax":
         gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
                                        top_k)                    # [T, k]
@@ -174,7 +176,7 @@ def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
         scores if bias is None else scores + bias, top_k)
     gates = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + norm_eps)
     return gates * routed_scaling, experts
 
 
@@ -182,8 +184,8 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                  live: Optional[jax.Array] = None,
                  layer: Optional[jax.Array] = None,
                  scoring: str = "softmax", routed_scaling: float = 1.0,
-                 shared: Optional[dict] = None, first_expert: int = 0
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 shared: Optional[dict] = None, first_expert: int = 0,
+                 norm_eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """Dropless top-k expert FFN over flat tokens.
 
     x [T, D]; p = {"router": [D, E], "wgu": [E, 2, D, M] (SwiGLU gate and
@@ -197,7 +199,8 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     over all E experts, used as they are unless ``norm_topk_prob``
     renormalises them to sum to 1, or with ``scoring="sigmoid"`` sigmoid
     scores selected with ``p["router_bias"]`` [E] added (if the tree has
-    it) and scaled by ``routed_scaling``.  ``shared`` ({"wgu": [2, D, Ms],
+    it), renormalised over their sum + ``norm_eps`` and scaled by
+    ``routed_scaling``.  ``shared`` ({"wgu": [2, D, Ms],
     "wd": [Ms, D]}, this layer's) is a SwiGLU expert that every token goes
     through, ungated, added to the routed sum.  ``live`` [T] bool marks the
     tokens that are somebody's (not padding, not an idle decode slot); all
@@ -255,8 +258,11 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                             p["router"][layer].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
         bias = p["router_bias"][layer] if "router_bias" in p else None
-        gates, experts = _route(logits, bias, top_k, scoring,
-                                norm_topk_prob, routed_scaling)
+        # (the epsilon by name and only where it is not the default's: the
+        # numerics tools plant routers of the six-argument form)
+        gates, experts = _route(
+            logits, bias, top_k, scoring, norm_topk_prob, routed_scaling,
+            **({} if norm_eps == 1e-20 else {"norm_eps": norm_eps}))
     kept = None
     if p["router"].shape[-1] != E or first_expert:
         # held experts count from 0; E, one past them, is nobody's here

@@ -77,7 +77,8 @@ read, and for the token step's two reads (K/V pages and latent pages) also
 the selector: ``paged_attention`` and ``paged_latent_attention`` run as
 ``ops/paged_read.py``'s Pallas kernel where ``paged_read_kind`` says so, from
 what it can observe: the backend is not the CPU, a head (a latent query: the
-padded row) is a whole number of 128-lane tiles, a page's rows are whole
+padded row) is a whole number of 128-lane tiles or, with several query heads
+a K/V head in rows of whole tiles, half a tile (LFM2's 64), a page's rows are whole
 sublane tiles of the pools' type (16 for bfloat16: every served
 configuration; the engine's default page of 8 in the CPU tests is not), and
 the queries are of the pools' type.  The kernel leaves the pools in HBM,
@@ -88,9 +89,9 @@ relayout of it by heads, no page of the rung that the sequence does not
 hold.  The gather is exact: given identical page contents it reproduces
 dense attention bit-for-bit in f32, which is what the paged-vs-dense CPU
 equivalence tests assert; it is the kernel's reference
-(``tests/test_paged_attention_kernel.py``) and what every other case runs:
-GPT-2's heads of 64, the CPU, and the one read that the kernel is not
-written for yet, the block step's.
+(``tests/test_paged_attention_kernel.py``, ``tests/test_paged_read_h64.py``)
+and what every other case runs: GPT-2's equal heads of 64, the CPU, and the
+one read that the kernel is not written for yet, the block step's.
 
 ``paged_block_attention`` (and the token step's reads where the kernel is
 not picked) gathers every column of the ``page_table`` it is given, for
@@ -129,9 +130,11 @@ def paged_read_kind(q, k_pages) -> str:
     queries with, "kernel" or "gather", from what it can observe of them
     (arrays or their shapes): the backend, and whether the kernel is written
     for the operands (``paged_read.supported``: heads of whole 128-lane
-    tiles, pages of whole sublane tiles, one type).  Measured on the chip at
+    tiles, or grouped heads of half a tile in rows of whole tiles, pages of
+    whole sublane tiles, one type).  Measured on the chip at
     the five served K/V shapes and at Xing's latent pages
-    (``scripts/paged_read_sweep.py``; PERF.md section 6, PR 49 and PR 50); a
+    (``scripts/paged_read_sweep.py``; PERF.md section 6, PR 49 and PR 50)
+    and at LFM2's 32 / 8 heads of 64 in its cell's traced run (PR 55); a
     shape that reads slower through the kernel is named here."""
     if _kernel_backend() and paged_read.supported(
             q.shape, q.dtype, k_pages.shape, k_pages.dtype):

@@ -59,6 +59,18 @@ softmax and the tail's masks are the ones above.  A block is as many pages as
 their bytes say (``_resolve``): a latent page is small and the copies' issue,
 not their bytes, is what a block of them costs.
 
+A HEAD OF HALF A LANE TILE (H = 64: LFM2's 32 / 8 heads; two K/V heads a
+tile, a row of 8 x 64 = 512 columns = 4 tiles) takes the same walk.  What
+would cut a tile inside the kernel is done outside it, in jnp, where the
+compiler lays it out: the block-diagonal queries ``[B, N, NKV*H]`` are made
+before the call (the kernel's ``spread`` is then its operand as it comes) and
+the accumulator keeps the whole row ``[N, NKV*H]``, every head's columns
+masked to its own K/V head's, so that the result's diagonal blocks are cut
+out after the call (a sum over the K/V heads of which one term is not zero:
+exact).  Inside, every operand, product and mask is whole lane tiles, as at
+128.  The copies, the chain, the online softmax and the tail's masks are the
+ones above, and a head of whole tiles traces what it traced before.
+
 On the CPU backend the kernel runs in Pallas interpret mode (the tests).
 """
 
@@ -104,12 +116,18 @@ def sublanes(dtype) -> int:
 
 def supported(q_shape, q_dtype, pool_shape, pool_dtype) -> bool:
     """Whether the kernel is written for these operands: heads of whole
-    128-lane tiles, a page of whole sublane tiles of the pool's type (a copy
-    lands on whole tiles of the buffer), queries a whole number a K/V head
-    and of the pool's type (the products take both as they are)."""
+    128-lane tiles, or of half a tile (64) in rows of whole tiles with
+    several query heads a K/V head (LFM2's 32 / 8; GPT-2's equal heads of 64
+    stay with the gather: the block-diagonal row of N = NKV heads is N times
+    the work for nothing shared, and no cell has measured it), a page of
+    whole sublane tiles of the pool's type (a copy lands on whole tiles of
+    the buffer), queries a whole number a K/V head and of the pool's type
+    (the products take both as they are)."""
     _, N, H = q_shape
     page, D = pool_shape[2:]
-    return (H % _LANES == 0 and D % H == 0 and N % (D // H) == 0
+    half = 2 * H == _LANES and D % _LANES == 0 and N > D // H
+    return ((H % _LANES == 0 or half)
+            and D % H == 0 and N % (D // H) == 0
             and page % sublanes(pool_dtype) == 0
             and jnp.dtype(q_dtype) == jnp.dtype(pool_dtype))
 
@@ -189,6 +207,14 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
     NKV = D // H
     rep = N // NKV
     T = ppb * page
+    # a head of half a lane tile: the queries come block-diagonal, the
+    # result goes out a whole row wide (module docstring)
+    half = bool(H % _LANES)
+    if half:
+        own = (jnp.arange(N)[:, None] // rep) == (jnp.arange(D)[None] // H)
+        q = jnp.where(own[None], jnp.tile(q, (1, 1, NKV)),
+                      jnp.zeros((), q.dtype))
+        columns = D
     # whole sublane tiles of query rows; the rows added score against
     # nothing (no K/V head is theirs) and are cut off the result
     Np = -(-N // sublanes(q.dtype)) * sublanes(q.dtype)
@@ -252,8 +278,11 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
                           rep) == jax.lax.div(
             jax.lax.broadcasted_iota(jnp.int32, (Np, D), 1), H)
         # the queries, block-diagonal
-        spread = jnp.concatenate([q_ref[0]] * NKV, axis=1)
-        spread = jnp.where(own, spread, jnp.zeros_like(spread))
+        if half:
+            spread = q_ref[0]
+        else:
+            spread = jnp.concatenate([q_ref[0]] * NKV, axis=1)
+            spread = jnp.where(own, spread, jnp.zeros_like(spread))
 
         def body(i, carry):
             m, l, acc = carry
@@ -285,9 +314,12 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
                 weighed = jnp.dot(p.astype(vbuf.dtype), vbuf[slot],
                                   preferred_element_type=jnp.float32)
                 weighed = jnp.where(own, weighed, 0.0)          # [Np, D]
-                for k in range(NKV):     # the diagonal blocks, side by side
-                    acc = (alpha * acc if k == 0 else acc) \
-                        + weighed[:, k * H:(k + 1) * H]
+                acc = alpha * acc
+                if half:                 # the row as it is, cut outside
+                    acc = acc + weighed
+                for k in range(0 if half else NKV):
+                    # the diagonal blocks, side by side
+                    acc = acc + weighed[:, k * H:(k + 1) * H]
             chain[DONE] += 1
             return m_new, l, acc
 
@@ -303,7 +335,8 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
         out_shape=jax.ShapeDtypeStruct((B, Np, columns), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[pl.BlockSpec((1, Np, H), lambda b, *_: (b, 0, 0))]
+            in_specs=[pl.BlockSpec((1, Np, q.shape[2]),
+                                   lambda b, *_: (b, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
             out_specs=pl.BlockSpec((1, Np, columns),
                                    lambda b, *_: (b, 0, 0)),
@@ -319,4 +352,6 @@ def _walk(q, k_pages, v_pages, where, lengths, table, *, sm_scale: float,
         name="paged_read")
     with kernel_source.nowhere():
         out = call(where, lengths, table, q, *pools)
+    if half:     # head n's columns of its own K/V head; the others are 0
+        return jnp.sum(out[:, :N].reshape(B, N, NKV, H), axis=2)
     return out[:, :N]

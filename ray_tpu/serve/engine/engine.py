@@ -162,7 +162,9 @@ beside latent pages, and then ``_v_pages`` holds those rows and no V pool).
 
 Either pool may be a TREE of arrays, and some of a model's arrays may hold
 **a row a decode slot** instead of pages (the record's ``slot_rows``; a
-recurrent layer's state, which does not grow with the sequence).  The loop
+recurrent layer's state, which does not grow with the sequence, or a short
+convolution's last inputs: a model of conv layers keeps those tails alone and
+no state matrix, the record's ``conv_tails`` says which array they are).  The loop
 hands them on, donates them and copies them as the trees they are and never
 looks inside.  What it does for such a model: the pools are made for
 ``max_batch`` slots, and a prefill is told the slot its sequence will be
@@ -173,7 +175,9 @@ slot overwrites them whole, and the pools thread through every call, so that
 prefill is ordered after the last step that touched them.  The page
 reservation counts pages as before, which are then only some layers'.
 ``stats()`` has the rows' bytes apart from the pages'
-(``recurrent_state_bytes``; ``kv_pool_bytes`` and ``kv_bytes_per_token``
+(``recurrent_state_bytes``, of which ``conv_tail_bytes`` are the
+convolutions' tails and ``recurrent_matrix_bytes`` the state matrices;
+``kv_pool_bytes`` and ``kv_bytes_per_token``
 are the pages' alone), the prefills that wrote a slot's rows
 (``state_rows_written``) and the rows' share in what the decode steps read
 and wrote (``recurrent_step_bytes_share``).
@@ -181,7 +185,7 @@ and wrote (``recurrent_step_bytes_share``).
 What a decode step's paged read FETCHES depends on what its program was
 compiled with (the record's ``paged_read``, asked once at construction:
 ``ops/paged_attention.py::paged_read_kind``).  "gather" (a block model's
-read, GPT-2's heads of 64, every model on the CPU):
+read, GPT-2's equal heads of 64, every model on the CPU):
 every page of the table the step is given, for every slot, once per pool
 layer: the step's rung, bounded by the longest live sequence and not by
 each.  "kernel" (the token step's K/V or latent pages on the chip): each
@@ -479,6 +483,10 @@ class InferenceEngine:
                 self._k_pages, self._v_pages)) if self._slot_rows else 0
         self._kv_pool_bytes = sum(p.nbytes for p in self._pools()) \
             - self._recurrent_state_bytes
+        # of the rows, the short convolutions' last inputs (a model of conv
+        # layers keeps those alone)
+        self._conv_tail_bytes = served.conv_tails(
+            self._k_pages, self._v_pages).nbytes if served.conv_tails else 0
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
@@ -709,7 +717,10 @@ class InferenceEngine:
         "decode"), once the loop has called it, whether that first call's
         result pools lay in its arguments' buffers (the donation was used).
         A model with rows a decode slot (module docstring) adds
-        ``recurrent_state_bytes``, what those rows take for all slots,
+        ``recurrent_state_bytes``, what those rows take for all slots
+        (``conv_tail_bytes`` of it the short convolutions' last inputs and
+        ``recurrent_matrix_bytes`` the state matrices: 0 for a model of conv
+        layers, whose slots keep tails alone),
         ``state_rows_written``, the prefills that wrote a slot's, and
         ``recurrent_step_bytes_share``: of the bytes the decode steps moved
         so far (the weights once a step, the live positions' pages, the live
@@ -824,6 +835,9 @@ class InferenceEngine:
             * self._kv_live_token_steps
         step_bytes = self._weight_bytes * self._steps + pages + rows
         return {"recurrent_state_bytes": self._recurrent_state_bytes,
+                "conv_tail_bytes": self._conv_tail_bytes,
+                "recurrent_matrix_bytes": self._recurrent_state_bytes
+                - self._conv_tail_bytes,
                 "state_rows_written": self._state_rows_written,
                 "recurrent_step_bytes_share":
                     rows / step_bytes if step_bytes else 0.0}

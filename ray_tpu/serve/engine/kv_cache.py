@@ -17,7 +17,8 @@ event, so the loop never has to preempt.
 
 Pages are counted per position whatever the number of layers that keep them:
 a model some of whose layers keep a row a decode SLOT instead (a recurrent
-state; ``models/serving.py``'s ``slot_rows``) reserves the same pages, which
+state, or a short convolution's tail and nothing else;
+``models/serving.py``'s ``slot_rows``) reserves the same pages, which
 are then only its other layers'.  A slot's rows need no free list: the slot
 is the allocation, and the next prefill into it overwrites them whole.
 """

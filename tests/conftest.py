@@ -145,6 +145,18 @@ STALE_SNAPSHOTS = {
         "takes the per-layer list's last five entries as PR 50 left them; PR "
         "51 added the Kimi cell's after them.  Its other assertions run as "
         "test_spec_kimi_linear.py::test_the_paged_read_entries_still_stand",
+    "test_spec_kimi_linear.py::test_what_the_hybrid_cell_added_still_stands":
+        "takes served_tokens_per_s's cells past the hybrid's as PR 51 left "
+        "them (the Kimi cell alone); PR 55 appended "
+        "serve-lfm2-longprompt-wide.  Its other assertions run as "
+        "test_spec_lfm2_moe.py::test_what_the_hybrid_cell_added_still_stands",
+    "test_loop_split.py::"
+    "test_the_unlisted_readers_are_in_no_entry_and_all_are_found":
+        "takes the files of benchmark/metrics/unlisted/ as PR 53 left them "
+        "(eleven); PR 55 added seven readers of the prefill's scopes there, "
+        "per_layer still being full.  Its other assertions run as "
+        "test_prefill_scopes.py::"
+        "test_the_readers_are_files_with_no_entry_and_the_tool_finds_them",
 }
 
 
