@@ -1019,7 +1019,9 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
     the layer's shared expert if the model has one.  Returns (y [..., D],
     load): ``load`` [E] int32 counts per expert the assignments of the
     tokens that ``live`` [...] marks (all when None), and is None for a
-    dense layer."""
+    dense layer.  A row that ``live`` does not mark (padding, an idle
+    slot) is routed to no expert: the experts' products skip it and the
+    routed part of its ``y`` is zero, which no live row can tell."""
     dt = cfg.dtype
     if experts is not None:
         from ray_tpu.ops.moe import moe_dropless

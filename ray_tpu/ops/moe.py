@@ -28,18 +28,20 @@ Two paths live here, with different callers:
   only by ``models/gpt.py`` (training, expert parallelism over ``ep``,
   the GPipe pipeline).
 * ``moe_dropless``: token-choice top-k with NO capacity, SwiGLU experts
-  without biases.  Every assignment is computed: assignments are sorted
-  by expert and the experts run as grouped matmuls over the ragged groups
+  without biases.  Every assignment of a token that is somebody's is
+  computed: assignments are sorted by expert and the experts run as
+  grouped matmuls over the ragged groups
   (``ops/grouped_matmul.py``, a Pallas kernel that is handed one layer's
   groups and finds that layer's experts in the stack of all layers by an
-  offset in its index map: work goes with the assignments, not with
+  offset where it copies them from: work goes with the assignments, not with
   experts x tokens, and an expert nobody chose is neither read nor
   visited).  Used by ``models/llama.py`` (``_ffn`` when
   ``LlamaConfig.num_experts > 0``), and so by the paged serving engine,
   whose padded prefill and idle decode slots would take capacity from real
   tokens under the first path: only without a capacity are a token's
-  logits independent of what else is in the batch.  Single device: it has
-  no ``ep`` sharding and no auxiliary loss (inference only).
+  logits independent of what else is in the batch (which is also why
+  those rows can be routed nowhere and cost the experts nothing).  Single
+  device: it has no ``ep`` sharding and no auxiliary loss (inference only).
 """
 
 from __future__ import annotations
@@ -204,17 +206,22 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     "wd": [Ms, D]}, this layer's) is a SwiGLU expert that every token goes
     through, ungated, added to the routed sum.  ``live`` [T] bool marks the
     tokens that are somebody's (not padding, not an idle decode slot); all
-    of them when None.  Every token is computed whatever ``live`` says: it
-    only selects what is counted.
+    of them when None.  A row that is not live is routed NOWHERE: its
+    assignments are sorted behind the last group, where the grouped matmuls
+    visit no row, are in no group's size and add nothing in the combine, so
+    the routed part of its output is exactly zero (the shared expert, a
+    dense product, still runs on every row).  Each token's experts are its
+    own, so a live row's result is the same bits with ``live`` and without.
 
     Assignments are sorted by expert and the experts run as two grouped
     matmuls over the ragged groups (``grouped_matmul``): gate and up in
     one, over ``wgu`` seen as 2E groups of [D, M] (each expert's rows
     twice), then down.  The kernel is handed this layer's group sizes and
     the stacks as they are stored, reshaped to [L * 2E, D, M] and
-    [L * E, M, D]; ``layer`` reaches it as a scalar that its index map adds
-    to the group, so the other layers are never walked, and a group
-    without rows is not in its grid: a touched expert's matrices cross
+    [L * E, M, D]; ``layer`` reaches it as a scalar that it adds to the
+    group where it copies the weights from, so the other layers are never
+    walked, and a group without rows is not in its grid: a touched
+    expert's matrices cross
     from HBM once, in whole tiles of megabytes, at 85-90% of the HBM's
     rate from one to eight rows an expert (the compiler's ``ragged_dot``
     kernel, which stood here, paid 2-8 us more than its bytes for every
@@ -235,12 +242,12 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     layer (``tests/test_moe_held.py``).  No exchange runs and nothing stands
     in for the absent chips.  ``load`` is then over the held experts, and
     its sum against ``live tokens x top_k`` is the share of assignments
-    kept.  With every expert held (R == E) the program is what it was.
+    kept.  A dead row's assignments go the same way, whatever their expert.
 
-    Returns (y [T, D] in x's type, load [E] int32: the live tokens'
-    assignments per held expert).  The parts carry the scopes ``moe_router``,
-    ``moe_dispatch``, ``moe_experts``, ``moe_combine`` and ``moe_shared``
-    for the profiler.
+    Returns (y [T, D] in x's type, load [E] int32: the kept assignments per
+    held expert, which are the groups' sizes).  The parts carry the scopes
+    ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine`` and
+    ``moe_shared`` for the profiler.
     """
     T, D = x.shape
     A = T * top_k
@@ -263,20 +270,20 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
         gates, experts = _route(
             logits, bias, top_k, scoring, norm_topk_prob, routed_scaling,
             **({} if norm_eps == 1e-20 else {"norm_eps": norm_eps}))
-    kept = None
+    # An assignment is KEPT where its row is somebody's and its expert is
+    # held; the others get E, one past the held experts (which count from
+    # 0): nobody's here.
+    kept = None if live is None else \
+        jnp.broadcast_to(live[:, None], experts.shape)
     if p["router"].shape[-1] != E or first_expert:
-        # held experts count from 0; E, one past them, is nobody's here
-        kept = (experts >= first_expert) & (experts < first_expert + E)
+        held = (experts >= first_expert) & (experts < first_expert + E)
+        kept = held if kept is None else kept & held
+    if kept is not None:
         experts = jnp.where(kept, experts - first_expert, E)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(A)                  # assignment -> expert
-        chosen = flat[:, None] == jnp.arange(E)[None, :]         # [A, E]
-        sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)         # [E]
-        if live is None:
-            load = sizes
-        else:
-            load = jnp.sum(chosen & jnp.repeat(live, top_k)[:, None],
-                           axis=0, dtype=jnp.int32)
+        sizes = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
+                        dtype=jnp.int32)                         # [E]
         order = jnp.argsort(flat, stable=True)     # sorted by expert
         token = order // top_k                     # sorted row -> token
         # Rows for the gate/up matmul: expert e's n_e rows for its gate
@@ -312,4 +319,4 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
             gu = jnp.einsum("td,cdm->ctm", x, shared["wgu"])
             y = y + jnp.einsum("tm,md->td", jax.nn.silu(gu[0]) * gu[1],
                                shared["wd"]).astype(jnp.float32)
-    return y.astype(x.dtype), load
+    return y.astype(x.dtype), sizes

@@ -256,9 +256,12 @@ of ``stats()``.  The experts are those the program HOLDS: where that is a
 share of what the router scores (one chip's part of a layer divided over
 several), ``assignments`` are those that fell on a held expert and
 ``assignments_made`` every real token's, and ``stats()["moe_load"]`` is the
-held experts' load by layer.  A dense model's programs return three results
-and none of
-this runs."""
+held experts' load by layer.  ``rows_offered`` beside them counts the
+assignments of every row the program's shape offered, padding and idle slots
+too: those rows are routed to no expert (``ops/moe.py::moe_dropless``'s
+``live``), so ``assignments_made / rows_offered`` is the share of the rows
+that the experts' products multiplied.  A dense model's programs return
+three results and none of this runs."""
 
 from __future__ import annotations
 
@@ -584,7 +587,8 @@ class InferenceEngine:
         self._retired = {"done": 0, "cancelled": 0, "expired": 0,
                          "error": 0}
         self._moe = {"moe_assignments": 0, "moe_assignments_made": 0,
-                     "moe_experts_hit": 0, "moe_load_max": 0}
+                     "moe_rows_offered": 0, "moe_experts_hit": 0,
+                     "moe_load_max": 0}
         self._moe_load: Optional[np.ndarray] = None   # [layers, held]
         self._block_stats = {
             "slot_steps_denoise": 0, "slot_steps_commit": 0,
@@ -704,7 +708,11 @@ class InferenceEngine:
         the program HOLDS (``LlamaConfig.expert_share``), so beside them
         ``moe_assignments_made`` counts every real token's assignments,
         held or not (their ratio is the share that fell on this program's
-        experts; 1 where it holds them all), and ``moe_load`` [expert
+        experts; 1 where it holds them all), ``moe_rows_offered`` the same
+        count for every row the programs' shapes offered (a prefill's rung,
+        a step's slots: ``moe_assignments_made`` over it is the share of
+        rows the experts' products saw, the rest padding and idle slots
+        routed nowhere), and ``moe_load`` [expert
         layers, held experts] is the held experts' load since the start.
         ``weight_bytes`` is
         the size of the parameters as the engine stores them,
@@ -1212,7 +1220,8 @@ class InferenceEngine:
         nothing, whoever holds its slot now: a stray slot step."""
         nxt, load = fetched
         self._count_moe("decode", load,
-                        len(step.seqs) * max(self._block, 1))
+                        len(step.seqs) * max(self._block, 1),
+                        self.config.max_batch * max(self._block, 1))
         if self._block:
             return self._deliver_blocks(step, nxt, submitted, lane)
         tokens = []
@@ -1361,7 +1370,7 @@ class InferenceEngine:
             self._deliver_step(prev, fetched, submitted, lane)
 
     def _count_moe(self, program: str, load: Sequence[np.ndarray],
-                   tokens: int):
+                   tokens: int, rows: int):
         """What an expert model's program said of its ``tokens`` real
         tokens' routing (``load`` [L, E] over the experts the program HOLDS,
         inside a list that is empty for a dense model): into ``stats()`` and
@@ -1369,11 +1378,16 @@ class InferenceEngine:
         experts takes as the program stores them.  ``assignments`` are those
         that fell on a held expert, of the ``assignments_made`` (every
         token's ``experts_per_token`` a layer): all of them where the
-        program holds every expert."""
+        program holds every expert.  ``rows_offered`` is the same count for
+        the ``rows`` the program's shape offered (the prefill's rung, the
+        step's slots, a block's rows for each): ``assignments_made`` over it
+        is the share of rows that were somebody's, and the rest is padding
+        and idle slots, which the experts' products do not multiply."""
         for per_layer in load:
+            per_row = per_layer.shape[0] * self.model_config.experts_per_token
             step = {"assignments": int(per_layer.sum()),
-                    "assignments_made": tokens * per_layer.shape[0]
-                    * self.model_config.experts_per_token,
+                    "assignments_made": tokens * per_row,
+                    "rows_offered": rows * per_row,
                     "experts_hit": int(np.count_nonzero(per_layer)),
                     "load_max": int(per_layer.max(axis=1).sum())}
             for key, value in step.items():
@@ -1484,7 +1498,7 @@ class InferenceEngine:
                         await loop.run_in_executor(self._exec, _run)
                     seq.prefilled = True
                     self._state_rows_written += self._slot_rows
-                    self._count_moe("prefill", load, int(whole))
+                    self._count_moe("prefill", load, int(whole), S)
                     self._deliver([] if tok is None else [(seq, tok)],
                                   submitted, lane)
 

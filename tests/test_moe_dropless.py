@@ -1,9 +1,10 @@
 """The dropless expert path (``ops/moe.py::moe_dropless``) and its callers in
 ``models/llama.py`` and the serving engine, at tiny widths on the CPU.
 
-Every assignment is computed whatever the load; a real token's result does
-not depend on what padding or idle slots hold; and what the engine counts
-of the routing agrees with a count made here in numpy.
+Every assignment of a real token is computed whatever the load; a real
+token's result does not depend on what padding or idle slots hold (which are
+routed to no expert: ``tests/test_moe_dead_rows.py``); and what the engine
+counts of the routing agrees with a count made here in numpy.
 """
 
 import asyncio
@@ -94,15 +95,20 @@ def test_an_expert_with_eight_times_the_mean_load_drops_nothing():
 
 
 def test_live_selects_what_is_counted_and_nothing_else():
+    """A live row is the same bits with ``live`` and without; the routed part
+    of a dead row is exactly zero; ``load`` counts the live rows' alone."""
     p = experts_params(jax.random.PRNGKey(3))
     x = jax.random.normal(jax.random.PRNGKey(4), (12, D))
     live = jnp.arange(12) % 3 != 0
     y_all, load_all = moe_dropless(x, p, top_k=K)
     y, load = moe_dropless(x, p, top_k=K, live=live)
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_all))
+    alive = np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(y)[alive],
+                                  np.asarray(y_all)[alive])
+    np.testing.assert_array_equal(np.asarray(y)[~alive], 0.0)
+    assert float(jnp.abs(y_all[~alive]).min()) > 0
     _, chosen = all_experts(x, p, K)
-    np.testing.assert_array_equal(np.asarray(load),
-                                  chosen[np.asarray(live)].sum(0))
+    np.testing.assert_array_equal(np.asarray(load), chosen[alive].sum(0))
     assert int(load.sum()) == int(live.sum()) * K < int(load_all.sum())
 
 
@@ -472,7 +478,7 @@ def test_grouped_matmul_against_each_groups_product(monkeypatch, sizes,
     rhs = jax.random.normal(jax.random.PRNGKey(60), (3 * G, Kd, N))
     rhs = rhs.at[:G].set(jnp.nan).at[2 * G:].set(jnp.nan)
     lhs = jax.random.normal(jax.random.PRNGKey(61), (m, Kd))
-    assert gm._tiles(m, Kd, N, 4, G)[1] == (128 if tile_bytes else 256)
+    assert gm._tiles(m, Kd, N, 4)[1] == (128 if tile_bytes else 256)
     out = gm.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
                             jnp.int32(1))
     assert out.shape == (m, N) and out.dtype == lhs.dtype
@@ -482,25 +488,30 @@ def test_grouped_matmul_against_each_groups_product(monkeypatch, sizes,
 
 
 @pytest.mark.parametrize("case,tiles", [
-    # rows, K, N, bytes a parameter, groups -> (row tile, tile of N)
-    ("sdar gate/up", ((2048, 2048, 768, 2, 256), (128, 768))),
-    ("sdar down", ((1024, 768, 2048, 2, 128), (128, 2048))),
-    ("xing gate/up", ((256, 3584, 1024, 2, 128), (128, 1024))),
-    ("xing down", ((128, 1024, 3584, 2, 64), (128, 3584))),
-    ("olmoe gate/up", ((256, 2048, 1024, 4, 128), (128, 1024))),
-    ("olmoe down", ((128, 1024, 2048, 4, 64), (128, 2048))),
-    ("olmoe prefill 512", ((8192, 2048, 1024, 4, 128), (128, 1024))),
-    ("sdar prefill 2048", ((32768, 2048, 768, 2, 256), (128, 768))),
-    ("a wide float32 expert", ((64, 4096, 4096, 4, 8), (64, 512))),
-    ("many rows a group", ((65536, 2048, 1024, 2, 16), (512, 1024))),
-    ("one token", ((3, 32, 16, 4, 8), (8, 16))),
-    ("one token, bfloat16", ((3, 32, 16, 2, 8), (16, 16)))])
+    # rows, K, N, bytes a parameter -> (row tile, tile of N)
+    ("sdar gate/up", ((2048, 2048, 768, 2), (128, 768))),
+    ("sdar down", ((1024, 768, 2048, 2), (128, 2048))),
+    ("xing gate/up", ((256, 3584, 1024, 2), (128, 1024))),
+    ("xing down", ((128, 1024, 3584, 2), (128, 3584))),
+    ("olmoe gate/up", ((256, 2048, 1024, 4), (128, 1024))),
+    ("olmoe down", ((128, 1024, 2048, 4), (128, 2048))),
+    ("olmoe prefill 512", ((8192, 2048, 1024, 4), (128, 1024))),
+    ("sdar prefill 2048", ((32768, 2048, 768, 2), (128, 768))),
+    ("a wide float32 expert", ((64, 4096, 4096, 4), (64, 512))),
+    # the row tile does not grow with the rows (it was 512 here)
+    ("many rows a group", ((65536, 2048, 1024, 2), (128, 1024))),
+    ("lfm2 gate/up at rung 2048", ((16384, 2048, 1536, 2), (128, 1536))),
+    ("lfm2 down at rung 2048", ((8192, 1536, 2048, 2), (128, 2048))),
+    ("lfm2 gate/up at rung 4096", ((32768, 2048, 1536, 2), (128, 1536))),
+    ("lfm2 down at rung 4096", ((16384, 1536, 2048, 2), (128, 2048))),
+    ("one token", ((3, 32, 16, 4), (8, 16))),
+    ("one token, bfloat16", ((3, 32, 16, 2), (16, 16)))])
 def test_tiles_follow_from_the_shapes(case, tiles):
     from ray_tpu.ops import grouped_matmul as gm
     shapes, want = tiles
     assert gm._tiles(*shapes) == want, case
     tm, tn = want
-    _, Kd, N, itemsize, _ = shapes
+    _, Kd, N, itemsize = shapes
     assert N % tn == 0
     # two buffers each of the weights' tile, the rows and the result, and
     # the float32 product, inside what the call asks for
