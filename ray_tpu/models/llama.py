@@ -122,6 +122,36 @@ either dense feed-forwards scanned over periods or, behind
 ``first_dense_layers``, experts (no shared one needed) in ``_unrolled_layers``:
 LFM2-24B-A2B's block with ``qk_norm_per_head``, heads of ``head_size`` 64,
 ``tie_embeddings`` and sigmoid routing renormalised over ``router_norm_eps``.
+
+With ``index_heads`` latent attention is SPARSE (DeepSeek-V3.2's: a lightning
+indexer beside it).  ``_index_project`` makes, from the layer's input and the
+same normed query bottleneck that ``wq_b`` reads, ``index_heads`` queries of
+``index_head_dim``, a weight a head and ONE LayerNormed key a position (the
+first ``qk_rope_dim`` columns of queries and key rotated by the attention's
+tables); ``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . k[s])`` scores every causal
+position for a query, and the attention's softmax runs over the
+``index_topk`` positions that score highest, exactly those.  Served, a
+position keeps a SECOND row, the indexer's key, in a pool of its own where
+the others have their V pool (``llama_init_paged_cache``: ``(latent pages,
+key pages)``, one page table for both).  The token step scores a sequence's
+live positions from the key pool, takes their top ``index_topk`` and reads
+those latent rows alone (``_mla_absorbed`` with ``selected``); the scopes
+``dsa_index``, ``dsa_select`` and ``dsa_read`` name the three parts in both
+programs.  Up to ``index_topk`` positions of context nothing is deselected
+and the layer is plain latent attention.  ``expert_groups`` (n, k) chooses a
+token's experts inside its k best of n groups (``ops/moe.py::_route``).
+
+A model whose whole past is addressable by position in latent pages
+(``llama_prefill_chunks``) takes a prompt IN CHUNKS: ``llama_prefill`` with a
+``start`` runs the positions ``start ..`` of the prompt, writes their rows
+to the sequence's pages and attends over the PAGES (``_mla_blocked``: blocks
+of keys gathered through the page table, an online softmax, the selection a
+mask a query that all heads share), so no call is wider than the widest
+compiled and the scores ``[heads, S, S]`` never exist (8.6 GB at 128 heads
+and 4,096 positions).  An indexer model's prefill always runs so (``start`` 0
+where none is given); every other model without a ``start`` keeps
+``_mla_expanded`` or the flash kernel.  Such a model serves; the training
+trunk refuses the indexer.
 """
 
 from __future__ import annotations
@@ -208,6 +238,16 @@ class LlamaConfig:
     # i * num_experts / n on; the router keeps num_experts outputs
     expert_share: Tuple[int, int] = (0, 1)
     tie_embeddings: bool = False     # logits from the table: no lm_head leaf
+    # > 0: a lightning indexer beside latent attention (DeepSeek-V3.2's
+    # sparse attention): ``index_heads`` heads of ``index_head_dim`` score
+    # every causal position for a query, and the attention's softmax runs
+    # over the ``index_topk`` positions that score highest
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # (n, k): the routed experts lie in n equal groups and a token's experts
+    # are chosen inside its k best groups (``ops/moe.py::_route``)
+    expert_groups: Tuple[int, int] = (1, 1)
 
     @property
     def head_dim(self) -> int:
@@ -249,6 +289,37 @@ def _check(cfg: LlamaConfig) -> None:
                          "dense_mlp_dim ahead of at least one expert layer")
     if cfg.router_scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"router_scoring={cfg.router_scoring!r}")
+    groups, best = cfg.expert_groups
+    if not 1 <= best <= groups or (groups > 1 and (
+            cfg.router_scoring != "sigmoid" or cfg.num_experts % groups
+            or best * (cfg.num_experts // groups) < cfg.experts_per_token)):
+        raise ValueError(
+            f"expert_groups={cfg.expert_groups} is (n, k): num_experts="
+            f"{cfg.num_experts} in n equal groups of which a token's k best "
+            "hold at least experts_per_token experts, under sigmoid routing "
+            "(the one scoring the group limit is written for)")
+    if cfg.index_heads:
+        unwritten = [name for name in ("layer_pattern", "block_length",
+                                       "hc_mult") if getattr(cfg, name)]
+        if cfg.ut_steps > 1:
+            unwritten.append("ut_steps > 1")
+        if not (cfg.kv_lora_rank and cfg.q_lora_rank and cfg.rope_yarn):
+            unwritten.append("attention that is not latent with a query "
+                             "bottleneck and YaRN's tables (kv_lora_rank, "
+                             "q_lora_rank, rope_yarn)")
+        if unwritten:
+            raise ValueError("the indexer (index_heads) reads the latent "
+                             "attention's normed query bottleneck and keeps "
+                             "a key a position beside the latent pages; it "
+                             "is not written for " + ", ".join(unwritten))
+        if not (cfg.index_topk > 0
+                and cfg.qk_rope_dim <= cfg.index_head_dim):
+            raise ValueError("the indexer needs index_topk positions to "
+                             "keep and heads of index_head_dim, of which "
+                             "the first qk_rope_dim columns are rotated")
+    elif cfg.index_head_dim or cfg.index_topk:
+        raise ValueError("index_head_dim and index_topk belong to "
+                         "index_heads")
     if cfg.hc_mult and (cfg.post_norm or cfg.ut_steps > 1
                         or not cfg.pre_norm):
         raise ValueError("a hyper-connected residual (hc_mult) is not "
@@ -430,6 +501,17 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                 "wkv_b": normal(jax.random.fold_in(k[2], 1),
                                 (L, rkv, nh, dn + dv)),
                 "wo": normal(k[3], (L, nh, dv, D), rscale)}
+        if cfg.index_heads:
+            # the lightning indexer: queries from the query bottleneck, one
+            # key a position (LayerNorm with scale and bias), a weight a head
+            ki = jax.random.split(jax.random.fold_in(k[1], 2), 3)
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            attn.update({
+                "index_wq": normal(ki[0], (L, rq, hi, di)),
+                "index_wk": normal(ki[1], (L, D, di)),
+                "index_k_norm": jnp.ones((L, di), jnp.float32),
+                "index_k_bias": jnp.zeros((L, di), jnp.float32),
+                "index_w": normal(ki[2], (L, D, hi))})
     else:
         norms = {"q_norm": jnp.ones((L, nh, H), jnp.float32),
                  "k_norm": jnp.ones((L, nkv, H), jnp.float32)} \
@@ -544,6 +626,12 @@ def _group_axes(cfg: LlamaConfig, experts: bool,
                 "kv_a_norm": ("layers", "norm"),
                 "wkv_b": ("layers", None, "heads", "kv"),
                 "wo": ("layers", "heads", "kv", "embed")}
+        if cfg.index_heads:
+            attn.update({"index_wq": ("layers", None, "heads", "kv"),
+                         "index_wk": ("layers", "embed", None),
+                         "index_k_norm": ("layers", "norm"),
+                         "index_k_bias": ("layers", "norm"),
+                         "index_w": ("layers", "embed", "heads")})
     else:
         norms = {"q_norm": ("layers", "heads", "kv"),
                  "k_norm": ("layers", "heads", "kv")} if cfg.qk_norm else {}
@@ -825,7 +913,9 @@ def _mla_project(cfg: LlamaConfig, p, h, cos, sin):
     unrotated queries [..., N, qk_nope_dim] and rotated ones [..., N,
     qk_rope_dim], and the position's cache row [..., kv_lora_rank +
     qk_rope_dim]: the normed compressed key-value and the rotated key that
-    all heads share."""
+    all heads share.  A model with an indexer gets a fourth result, what
+    ``_index_project`` makes of ``h`` and the SAME normed query bottleneck
+    that ``wq_b`` reads."""
     a, dt = p["attn"], cfg.dtype
     rank, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
     if cfg.q_lora_rank:
@@ -843,7 +933,115 @@ def _mla_project(cfg: LlamaConfig, p, h, cos, sin):
     r = apply_rope_pairs(ckr[..., rank:], cos, sin)
     q_rope = apply_rope_pairs(q[..., dn:], cos[..., None, :],
                               sin[..., None, :])
-    return q[..., :dn], q_rope, jnp.concatenate([c, r], axis=-1)
+    projected = q[..., :dn], q_rope, jnp.concatenate([c, r], axis=-1)
+    if cfg.index_heads:
+        with jax.named_scope("dsa_index"):
+            projected += (_index_project(cfg, p, h, cq, cos, sin),)
+    return projected
+
+
+def _index_project(cfg: LlamaConfig, p, h, cq, cos, sin):
+    """The lightning indexer's projections of the normed hidden h [..., D]
+    and the normed query bottleneck cq [..., q_lora_rank] (DeepSeek-V3.2's):
+    (queries [..., Hi, di], a weight a head [..., Hi] float32, the
+    position's key [..., di]).  The key is LayerNormed (scale and bias);
+    the first ``qk_rope_dim`` columns of queries and key are rotated by the
+    attention's own tables, the others are not; the weights carry
+    ``Hi^-1/2 x di^-1/2``.  The release's Hadamard rotation of both sides
+    and their FP8 quantisation are left out (an orthogonal map of both
+    sides leaves every dot product as it was)."""
+    a, dt, dr = p["attn"], cfg.dtype, cfg.qk_rope_dim
+
+    def rotated(x, cos, sin):
+        return jnp.concatenate([apply_rope_pairs(x[..., :dr], cos, sin),
+                                x[..., dr:]], axis=-1)
+    q = jnp.einsum("...r,rnh->...nh", cq, a["index_wq"].astype(dt))
+    # the key's and the weights' products are kept in float32 as they are
+    # accumulated (two narrow matrices): what LayerNorm norms is not
+    # rounded first, and the weights never are
+    k = jnp.einsum("...d,dh->...h", h, a["index_wk"].astype(dt),
+                   preferred_element_type=jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                          + cfg.rms_eps)
+    k = (k * a["index_k_norm"] + a["index_k_bias"]).astype(dt)
+    w = jnp.einsum("...d,dn->...n", h, a["index_w"].astype(dt),
+                   preferred_element_type=jnp.float32) \
+        * (cfg.index_heads * cfg.index_head_dim) ** -0.5
+    return (rotated(q, cos[..., None, :], sin[..., None, :]), w,
+            rotated(k, cos, sin))
+
+
+def _mla_blocked(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer, row,
+                 start, length, keep=None):
+    """Causal latent attention of a CHUNK of one sequence against its own
+    pages, a block of keys at a time with an online softmax: q_nope [S, N,
+    dn], q_rope [S, N, dr] are the queries of positions ``start .. start + S
+    - 1`` (``length`` of them real), ``row`` [maxp] the sequence's page
+    table, whose pages already hold every position under ``start + length``
+    (the chunk's own, just written, among them).  A block of the table's
+    pages is gathered, its keys and values expanded per head from the
+    latent rows as ``_mla_expanded`` expands a whole sequence's, and every
+    block of queries that may see it is scored against it; the scores
+    ``[N, S, S]`` never exist (8.6 GB at 128 heads and 4,096 positions).
+    Blocks past ``start + length`` are not visited.  ``keep`` [S, maxp x
+    page] bool is the selection, a mask a QUERY that all heads share and
+    that already holds the causal bound; None: every causal position.
+    Returns [S, N, dv]."""
+    from ray_tpu.ops.paged_attention import block_size, paged_rows
+    rank, dn, dv, dt = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim,
+                        cfg.dtype)
+    S, N = q_nope.shape[:2]
+    page = pages.shape[2]
+    per = block_size(row.shape[0], max(1024 // page, 1))   # pages a block
+    kb, qb = per * page, block_size(S, 512)
+    wkv_b = p["attn"]["wkv_b"].astype(dt)
+    scale = mla_softmax_scale(cfg)
+
+    def keys(j, carry):
+        rows = paged_rows(pages, layer, row, j * per, per)       # [kb, Wp]
+        kv = jnp.einsum("sc,cnh->snh", rows[:, :rank], wkv_b)
+        k_rope = rows[:, rank:rank + cfg.qk_rope_dim]
+        kpos = j * kb + jnp.arange(kb)
+
+        def queries(i, carry):
+            m, l, acc = carry
+            at = i * qb
+            qn = jax.lax.dynamic_slice_in_dim(q_nope, at, qb)
+            qr = jax.lax.dynamic_slice_in_dim(q_rope, at, qb)
+            scores = (jnp.einsum("qnh,knh->nqk", qn, kv[..., :dn])
+                      + jnp.einsum("qnh,kh->nqk", qr, k_rope)) * scale
+            if keep is None:
+                seen = kpos[None] <= (start + at + jnp.arange(qb))[:, None]
+            else:
+                seen = jax.lax.dynamic_slice(keep, (at, j * kb), (qb, kb))
+            scores = jnp.where(seen[None], scores.astype(jnp.float32),
+                               -1e30)
+            m_old = jax.lax.dynamic_slice_in_dim(m, at, qb, axis=1)
+            m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1))
+            # (a query that sees nothing of this block adds exactly nothing)
+            probs = jnp.where(seen[None],
+                              jnp.exp(scores - m_new[..., None]), 0.0)
+            fade = jnp.exp(m_old - m_new)
+            l_new = jax.lax.dynamic_slice_in_dim(l, at, qb, axis=1) * fade \
+                + jnp.sum(probs, axis=-1)
+            acc_new = jax.lax.dynamic_slice_in_dim(acc, at, qb, axis=1) \
+                * fade[..., None] + jnp.einsum(
+                    "nqk,knh->nqh", probs.astype(dt), kv[..., dn:],
+                    preferred_element_type=jnp.float32)
+            return (jax.lax.dynamic_update_slice_in_dim(m, m_new, at, 1),
+                    jax.lax.dynamic_update_slice_in_dim(l, l_new, at, 1),
+                    jax.lax.dynamic_update_slice_in_dim(acc, acc_new, at, 1))
+        # the first block of queries that reaches this block of keys
+        first = jnp.maximum(j * kb - start, 0) // qb
+        return jax.lax.fori_loop(first, S // qb, queries, carry)
+
+    blocks = (start + length + kb - 1) // kb
+    m, l, acc = jax.lax.fori_loop(0, blocks, keys, (
+        jnp.full((N, S), -1e30, jnp.float32), jnp.zeros((N, S), jnp.float32),
+        jnp.zeros((N, S, dv), jnp.float32)))
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.swapaxes(o, 0, 1).astype(dt)
 
 
 def _mla_expanded(cfg: LlamaConfig, p, q_nope, q_rope, latent):
@@ -864,22 +1062,32 @@ def _mla_expanded(cfg: LlamaConfig, p, q_nope, q_rope, latent):
 
 
 def _mla_absorbed(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer,
-                  lengths, page_table):
+                  lengths, page_table, selected=None):
     """One query a sequence against the paged latent cache AS IT LIES:
     ``wkv_b``'s key half goes into the query, all heads score against the
     one cached row of a position, the probabilities weigh the compressed
     rows themselves, and ``wkv_b``'s value half comes after.  The same
     mathematics as ``_mla_expanded``; no per-head key or value of a cached
     position is ever made.  q_nope [B, N, dn], q_rope [B, N, dr] -> [B, N,
-    dv]."""
-    from ray_tpu.ops.paged_attention import paged_latent_attention
+    dv].  ``selected`` (an indexer's ``select_positions``: positions and
+    which of them count) limits the read to those rows, gathered through the
+    page table, and nothing else of the pool is read."""
+    from ray_tpu.ops.paged_attention import (paged_latent_attention,
+                                             paged_latent_attention_selected)
     dn, dt = cfg.qk_nope_dim, cfg.dtype
     wkv_b = p["attn"]["wkv_b"].astype(dt)
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum("bnh,cnh->bnc", q_nope, wkv_b[..., :dn])
-    o_lat = paged_latent_attention(
-        jnp.concatenate([q_lat, q_rope], axis=-1), pages, layer, lengths,
-        page_table, sm_scale=mla_softmax_scale(cfg), rank=cfg.kv_lora_rank)
+    q_lat = jnp.concatenate([q_lat, q_rope], axis=-1)
+    if selected is not None:
+        with jax.named_scope("dsa_read"):
+            o_lat = paged_latent_attention_selected(
+                q_lat, pages, layer, *selected, page_table,
+                sm_scale=mla_softmax_scale(cfg), rank=cfg.kv_lora_rank)
+    else:
+        o_lat = paged_latent_attention(
+            q_lat, pages, layer, lengths, page_table,
+            sm_scale=mla_softmax_scale(cfg), rank=cfg.kv_lora_rank)
     with jax.named_scope("mla_absorb"):
         return jnp.einsum("bnc,cnh->bnh", o_lat, wkv_b[..., dn:])
 
@@ -1033,7 +1241,7 @@ def _ffn(cfg: LlamaConfig, p, h, live=None, lc=lambda a, ax: a,
             shared=_cast_leaves(p["shared"], dt, "wgu", "wd")
             if cfg.shared_experts else None,
             first_expert=cfg.expert_share[0] * _held_experts(cfg),
-            norm_eps=cfg.router_norm_eps)
+            norm_eps=cfg.router_norm_eps, groups=cfg.expert_groups)
         return y.reshape(h.shape), load
     gu = jnp.einsum("...d,cdm->c...m", h, p["mlp"]["wgu"].astype(dt))
     a = lc(jax.nn.silu(gu[0]) * gu[1], ("batch", "seq", "mlp"))
@@ -1159,11 +1367,15 @@ def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
 
 
 def _prefill_state(cfg: LlamaConfig, length, page_table,
-                   flash: bool, slot=0) -> AttentionState:
+                   flash: bool, slot=0, start=None) -> AttentionState:
     """A sequence's prefill: its rows go to its pages (the padded tail to
     scratch page 0) and it attends over what is in hand; a linear layer
     scans the sequence from an empty state and leaves in the rows of decode
-    slot ``slot`` what stands after position ``length - 1``."""
+    slot ``slot`` what stands after position ``length - 1``.  With ``start``
+    (latent pages alone) the rows are a chunk of the prompt from that
+    position on, and they attend over the sequence's PAGES, what earlier
+    chunks left there and their own rows (``_mla_blocked``); an indexer's
+    keys go to their own pool likewise and its selection is the mask."""
     def kv(p, layer, pools, q, k, v):
         from ray_tpu.ops.flash_attention import flash_attention
         from ray_tpu.ops.paged_attention import prefill_kv
@@ -1175,11 +1387,32 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
             q, k, v, cfg.num_heads // cfg.num_kv_heads,
             cfg.block_length), pools
 
-    def latent(p, layer, pools, q_nope, q_rope, latent):
-        from ray_tpu.ops.paged_attention import prefill_latent
+    def latent(p, layer, pools, q_nope, q_rope, latent, index=None):
+        from ray_tpu.ops.paged_attention import (index_scores,
+                                                 paged_rows, prefill_latent,
+                                                 select_mask)
         pool = prefill_latent(pools[0], layer, latent[0], length,
-                              page_table[0])
-        return _mla_expanded(cfg, p, q_nope, q_rope, latent), (pool, pools[1])
+                              page_table[0], start)
+        if start is None:
+            return _mla_expanded(cfg, p, q_nope, q_rope, latent), \
+                (pool, pools[1])
+        row, keep, keys = page_table[0], None, pools[1]
+        if index is not None:
+            q, w, k = index
+            S = latent.shape[1]
+            with jax.named_scope("dsa_index"):
+                keys = prefill_latent(keys, layer, k[0], length, row, start)
+                scores = index_scores(q[0], w[0], paged_rows(
+                    keys, layer, row, 0, row.shape[0]))
+            with jax.named_scope("dsa_select"):
+                seen = jnp.arange(scores.shape[1])[None] \
+                    <= (start + jnp.arange(S))[:, None]
+                keep = select_mask(scores, seen, cfg.index_topk)
+        with jax.named_scope("dsa_read" if index is not None
+                             else "latent_read"):
+            o = _mla_blocked(cfg, p, q_nope[0], q_rope[0], pool, layer, row,
+                             start, length, keep)
+        return o[None], (pool, keys)
 
     def recurrent(p, layer, pools, qkv, g, beta):
         from ray_tpu.ops.linear_attention import fold_state
@@ -1218,11 +1451,22 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
         pools = append_kv(*pools, layer, k, v, pos, page_table)
         return paged_attention(q, *pools, layer, pos + 1, page_table), pools
 
-    def latent(p, layer, pools, q_nope, q_rope, latent):
-        from ray_tpu.ops.paged_attention import append_latent
+    def latent(p, layer, pools, q_nope, q_rope, latent, index=None):
+        from ray_tpu.ops.paged_attention import (append_latent,
+                                                 paged_index_scores,
+                                                 select_positions)
         pool = append_latent(pools[0], layer, latent, pos, page_table)
+        if index is None:
+            return _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer,
+                                 pos + 1, page_table), (pool, pools[1])
+        q, w, k = index
+        with jax.named_scope("dsa_index"):
+            keys = append_latent(pools[1], layer, k, pos, page_table)
+            scores = paged_index_scores(q, w, keys, layer, page_table)
+        with jax.named_scope("dsa_select"):
+            selected = select_positions(scores, pos + 1, cfg.index_topk)
         return _mla_absorbed(cfg, p, q_nope, q_rope, pool, layer, pos + 1,
-                             page_table), (pool, pools[1])
+                             page_table, selected), (pool, keys)
 
     def recurrent(p, layer, pools, qkv, g, beta):
         # a row a slot is the slot's own: row b of the batch is slot b.  A
@@ -1425,6 +1669,12 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
             "(block_length) through llama_prefill / llama_block_step; the "
             "training trunk's attention is causal and the mask-predict "
             "objective is not written")
+    if cfg.index_heads:
+        raise NotImplementedError(
+            "models/llama.py serves its indexer model (index_heads) through "
+            "llama_prefill / llama_decode_step, whose attention reads the "
+            "selected positions from the pages; the training trunk's "
+            "whole-sequence attention is not written for a selection")
     S = tokens.shape[1]
     if not cfg.kv_lora_rank and \
             resolve_attention(cfg.attention, S) == "flash":
@@ -1509,7 +1759,10 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     ``RecurrentPools``: that pool (None beside latent pages), and the linear
     layers' state and convolution rows for ``slots`` decode slots, zeroed
     (an empty state); conv layers keep a convolution tail a slot (the last
-    ``linear_conv - 1`` positions of D channels) and no state: None."""
+    ``linear_conv - 1`` positions of D channels) and no state: None.  A
+    latent model with an indexer (``index_heads``) has TWO pools a position:
+    the latent pages and, where the V pool would be, the indexer's keys
+    ``[L, P, page, index_head_dim]``, addressed by the same page table."""
     dt = dtype or cfg.dtype
     L = cfg.ut_steps * cfg.num_layers - _slot_layers(cfg)
     if cfg.block_length and page_size % cfg.block_length:
@@ -1518,8 +1771,12 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
                          "positions lie in one page")
     if cfg.kv_lora_rank:
         from ray_tpu.ops.paged_attention import latent_width
+        # an indexer keeps a second row a position, its key, in a pool of
+        # its own where the others have their V pool
         pages = jnp.zeros((L, num_pages, page_size, latent_width(
-            cfg.kv_lora_rank, cfg.qk_rope_dim)), dt), None
+            cfg.kv_lora_rank, cfg.qk_rope_dim)), dt), \
+            jnp.zeros((L, num_pages, page_size, cfg.index_head_dim), dt) \
+            if cfg.index_heads else None
     else:
         shape = (L, num_pages, page_size, cfg.num_kv_heads * cfg.head_dim)
         pages = jnp.zeros(shape, dt), jnp.zeros(shape, dt)
@@ -1556,7 +1813,8 @@ def llama_serving_params(params: Dict[str, Any],
     bfloat16).  Casting twice is casting once, so the steps return the same
     bits for this tree as for ``params``."""
     dt = cfg.dtype
-    matrices = ("wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo") \
+    matrices = ("wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "index_wq",
+                "index_wk", "index_w") \
         if cfg.kv_lora_rank else ("wq", "wkv", "wo")
 
     def cast(tree, *names):          # those of ``names`` that it has
@@ -1612,14 +1870,25 @@ def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
     return "flash"
 
 
+def llama_prefill_chunks(cfg: LlamaConfig) -> bool:
+    """Whether ``llama_prefill`` takes a ``start``: a model whose whole past
+    is addressable by position in latent pages (with or without an indexer's
+    keys beside them).  Not one with a recurrent state or a convolution's
+    tail, whose scans take no initial row; not K/V pages, for which the read
+    of earlier positions is not written; not a block model."""
+    return bool(cfg.kv_lora_rank) and not cfg.layer_pattern \
+        and not cfg.block_length and cfg.ut_steps == 1
+
+
 def llama_paged_read(cfg: LlamaConfig, k_pages) -> str:
     """What a step's programs read the pages with, "kernel" or "gather":
     the token step's K/V pages, and a latent model's one pool
     (``paged_latent_attention``), as ``paged_read_kind`` says of the step's
     queries and the pool; a block model's read (``paged_block_attention``)
-    is a gather."""
+    is a gather, and so is an indexer model's (its keys through the table,
+    then the selected latent rows)."""
     from ray_tpu.ops.paged_attention import paged_read_kind
-    if cfg.block_length:
+    if cfg.block_length or cfg.index_heads:  # (the selected rows: a gather)
         return "gather"
     # a latent model's queries are as wide as its pool's padded rows
     head = k_pages.shape[3] if cfg.kv_lora_rank else cfg.head_dim
@@ -1654,7 +1923,8 @@ def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
 def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, length: jax.Array,
                   k_pages: jax.Array, v_pages: Optional[jax.Array],
-                  page_table: jax.Array, slot: jax.Array = 0):
+                  page_table: jax.Array, slot: jax.Array = 0,
+                  start: Optional[jax.Array] = None):
     """Prefill ONE padded sequence (see gpt_prefill): the trunk over the
     whole padded length, its causal attention by the flash forward kernel
     or dense as ``llama_prefill_attention`` says (the kernel reads grouped
@@ -1672,16 +1942,41 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     the assignments of the prompt's real positions.  A model with linear
     layers (``v_pages`` is then ``RecurrentPools``) leaves their state after
     position ``length - 1`` in the rows of decode slot ``slot``, whatever the
-    rung; every other model takes no notice of ``slot``."""
+    rung; every other model takes no notice of ``slot``.
+
+    With ``start`` (a multiple of the page size; ``llama_prefill_chunks``
+    says which models take one) ``tokens`` is a CHUNK of the prompt,
+    positions ``start .. start + S - 1`` of which ``length`` are real: its
+    rows go to the pages of those positions and its queries attend over the
+    sequence's pages, everything under ``start`` that earlier calls left
+    there and the chunk itself; the logits are those of the chunk's last
+    real position.  A prompt of any length is then a row of such calls, each
+    no wider than the widest compiled, and a call with ``start`` 0 that
+    holds the whole prompt gives what the call without ``start`` gives, to
+    the rounding of a blocked softmax.  A model with an indexer always runs
+    this way (``start`` 0 where none is given): its selection is a read of
+    the pages."""
     S = tokens.shape[1]
     flash = llama_prefill_attention(cfg, S) == "flash"
-    cos, sin = _rope_tables(cfg, S)
+    if start is None and cfg.index_heads:
+        start = jnp.int32(0)
+    if start is not None and not llama_prefill_chunks(cfg):
+        raise NotImplementedError(
+            "llama_prefill: a prefill that starts at a position other than "
+            "0 reads what lies before it from latent pages; K/V pages are "
+            "not read so yet, and a recurrent state or a convolution's tail "
+            "cannot be (their scans take no initial row)")
+    if start is None:
+        cos, sin = _rope_tables(cfg, S)
+    else:                            # the chunk's rows of the whole table
+        cos, sin = (jax.lax.dynamic_slice_in_dim(t, start, S)
+                    for t in _rope_tables(cfg, cfg.max_seq_len))
     x = _embed(cfg, params, tokens)
     live = (jnp.arange(S) < length)[None]                # the real positions
     (x, k_pages, v_pages), load = _served_trunk(
         cfg, params, x, cos, sin,
-        _prefill_state(cfg, length, page_table, flash, slot), live, k_pages,
-        v_pages)
+        _prefill_state(cfg, length, page_table, flash, slot, start), live,
+        k_pages, v_pages)
     if cfg.block_length:
         # no logits: a block model's first token comes from its first
         # block (llama_block_step), not from the prompt's last position
@@ -1829,6 +2124,10 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         conv_tails=(lambda k_pages, v_pages: v_pages.conv)
         if cfg.layer_pattern else None,
         page_kind="latent" if cfg.kv_lora_rank else "kv",
+        chunked=llama_prefill_chunks(cfg),
+        index_pool=(lambda k_pages, v_pages: v_pages)
+        if cfg.index_heads else None,
+        select_topk=cfg.index_topk,
         expert_stack=(lambda params: _expert_stack(cfg, params))
         if cfg.num_experts else None,
         prefill=llama_prefill,

@@ -28,10 +28,12 @@ class ServedModel(NamedTuple):
     #                              (k_pages, v_pages), zeroed; v_pages None:
     #                              one pool; either may be a tree of arrays
     prefill: Callable            # (params, config, tokens [1, S], length,
-    #                              k_pages, v_pages, page_table, slot) ->
-    #                              (logits, k_pages, v_pages[, the experts'
-    #                              load]); ``slot``: the decode slot the
-    #                              sequence will be stepped in
+    #                              k_pages, v_pages, page_table, slot[,
+    #                              start]) -> (logits, k_pages, v_pages[,
+    #                              the experts' load]); ``slot``: the decode
+    #                              slot the sequence will be stepped in;
+    #                              ``start`` (``chunked`` models): the
+    #                              position of the chunk's first token
     step: Callable               # the decode step, (params, config, token,
     #                              pos, ...) likewise; a block model's takes
     #                              the blocks' state and ends for token, pos
@@ -61,6 +63,14 @@ class ServedModel(NamedTuple):
     #                              "latent" (one, no V pool)
     expert_stack: Any = None     # (params) -> a stack of routed experts as
     #                              stored ({"wd", ...}); None: a dense model
+    chunked: bool = False        # ``prefill`` takes a ``start`` and reads
+    #                              what lies before it from the pages: a
+    #                              prompt may run as a row of calls
+    index_pool: Any = None       # (k_pages, v_pages) -> the pool that holds
+    #                              a sparse-attention indexer's key a
+    #                              position, beside the pages; None: none
+    select_topk: int = 0         # > 0: a decode step's attention reads at
+    #                              most this many positions a sequence
 
 
 def greedy(config, logits, token, pos):

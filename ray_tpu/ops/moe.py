@@ -156,7 +156,8 @@ def moe_mlp(x, p, *, top_k: int, capacity_factor: float,
 
 
 def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
-           routed_scaling: float, norm_eps: float = 1e-20):
+           routed_scaling: float, norm_eps: float = 1e-20,
+           groups: Tuple[int, int] = (1, 1)):
     """A token's experts and their gates from the router's float32 logits
     [T, E] -> (gates [T, k], experts [T, k]).  ``softmax``: the ``top_k``
     largest of a softmax over all experts are the gates, as they are unless
@@ -166,7 +167,13 @@ def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
     only SELECTS); the gates are the chosen experts' scores WITHOUT it,
     renormalised if ``norm_topk_prob`` (divided by their sum + ``norm_eps``:
     DeepSeek-V3's and Kimi's 1e-20, LFM2's 1e-6) and times
-    ``routed_scaling``."""
+    ``routed_scaling``.  ``groups`` (n, k) limits the sigmoid selection to
+    groups: the E experts lie in n equal groups in their order, a group's
+    mark is the sum of its two largest score + bias (its largest score
+    where there is no bias), the k groups that mark highest are kept and
+    the ``top_k`` experts are the largest of THOSE groups' (an expert of
+    another group is chosen never, whatever it scores); (1, 1): no limit,
+    and nothing of it is traced."""
     if scoring == "softmax":
         gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
                                        top_k)                    # [T, k]
@@ -174,8 +181,17 @@ def _route(logits, bias, top_k: int, scoring: str, norm_topk_prob: bool,
             gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         return gates, experts
     scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(
-        scores if bias is None else scores + bias, top_k)
+    choice = scores if bias is None else scores + bias
+    if groups[0] > 1:
+        n, best = groups
+        grouped = choice.reshape(choice.shape[0], n, -1)
+        mark = jnp.max(grouped, axis=-1) if bias is None else \
+            jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)       # [T, n]
+        _, kept = jax.lax.top_k(mark, best)
+        kept = jnp.any(kept[:, :, None] == jnp.arange(n), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(choice.shape)
+    _, experts = jax.lax.top_k(choice, top_k)
     gates = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + norm_eps)
@@ -187,7 +203,8 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                  layer: Optional[jax.Array] = None,
                  scoring: str = "softmax", routed_scaling: float = 1.0,
                  shared: Optional[dict] = None, first_expert: int = 0,
-                 norm_eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
+                 norm_eps: float = 1e-20, groups: Tuple[int, int] = (1, 1)
+                 ) -> Tuple[jax.Array, jax.Array]:
     """Dropless top-k expert FFN over flat tokens.
 
     x [T, D]; p = {"router": [D, E], "wgu": [E, 2, D, M] (SwiGLU gate and
@@ -202,7 +219,8 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
     renormalises them to sum to 1, or with ``scoring="sigmoid"`` sigmoid
     scores selected with ``p["router_bias"]`` [E] added (if the tree has
     it), renormalised over their sum + ``norm_eps`` and scaled by
-    ``routed_scaling``.  ``shared`` ({"wgu": [2, D, Ms],
+    ``routed_scaling``, inside the best of ``groups`` where that is a
+    limit.  ``shared`` ({"wgu": [2, D, Ms],
     "wd": [Ms, D]}, this layer's) is a SwiGLU expert that every token goes
     through, ungated, added to the routed sum.  ``live`` [T] bool marks the
     tokens that are somebody's (not padding, not an idle decode slot); all
@@ -265,11 +283,13 @@ def moe_dropless(x, p, *, top_k: int, norm_topk_prob: bool = False,
                             p["router"][layer].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
         bias = p["router_bias"][layer] if "router_bias" in p else None
-        # (the epsilon by name and only where it is not the default's: the
-        # numerics tools plant routers of the six-argument form)
+        # (the epsilon and the groups by name and only where they are not
+        # the defaults: the numerics tools plant routers of the six-argument
+        # form)
         gates, experts = _route(
             logits, bias, top_k, scoring, norm_topk_prob, routed_scaling,
-            **({} if norm_eps == 1e-20 else {"norm_eps": norm_eps}))
+            **({} if norm_eps == 1e-20 else {"norm_eps": norm_eps}),
+            **({} if tuple(groups) == (1, 1) else {"groups": groups}))
     # An assignment is KEPT where its row is somebody's and its expert is
     # held; the others get E, one past the held experts (which count from
     # 0): nobody's here.

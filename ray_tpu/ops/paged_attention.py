@@ -311,18 +311,27 @@ def append_latent(pages: jax.Array, layer: jax.Array, new: jax.Array,
 
 
 def prefill_latent(pages: jax.Array, layer: jax.Array, seq: jax.Array,
-                   length: jax.Array, page_table_row) -> jax.Array:
+                   length: jax.Array, page_table_row,
+                   start: Optional[jax.Array] = None) -> jax.Array:
     """Scatter a whole (padded) prompt's latent rows ``seq`` [S, W] of ONE
     sequence into one layer of the pool ``[L, P, page, Wp]``, a page at a
     time (S is a multiple of the page size).  Pages wholly past ``length``
     go to scratch page 0; the padding that shares the prompt's last page
     lands in the slots the sequence's next positions will overwrite before
-    any step reads them."""
+    any step reads them.  With ``start`` (a multiple of the page size) the
+    rows are a CHUNK of the prompt, positions ``start .. start + S - 1``,
+    ``length`` of them real: they go to the pages that hold those positions.
+    Any pool of rows a position is written so (the indexer's keys, ``[L, P,
+    page, d]``)."""
     S = seq.shape[0]
     page, Wp = pages.shape[2:]
     with jax.named_scope("latent_append"):
         first = jnp.arange(S // page) * page        # a page's first position
-        pid = jnp.where(first < length, page_table_row[:S // page], 0)
+        pid = jnp.where(
+            first < length,
+            page_table_row[:S // page] if start is None else
+            jax.lax.dynamic_slice_in_dim(page_table_row, start // page,
+                                         S // page), 0)
         return pages.at[layer, pid].set(
             _padded(seq, pages).reshape(S // page, page, Wp))
 
@@ -359,3 +368,141 @@ def paged_latent_attention(q: jax.Array, pages: jax.Array, layer: jax.Array,
                            -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bns,bsw->bnw", probs, rows)[..., :rank]
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention over latent pages (DeepSeek-V3.2's lightning
+# indexer; ``models/llama.py`` with ``index_heads``).  A position keeps a
+# second row, the indexer's key, in a pool of its own ``[L, P, page, d]``
+# beside the latent pages (``append_latent`` / ``prefill_latent`` write it: a
+# row a position is a row a position).  A query scores every position it may
+# see, ``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . k[s])``, keeps the ``k``
+# positions that score highest (EXACTLY those: a tie goes to the lower
+# position, as ``lax.top_k`` breaks it) and its attention runs over them
+# alone.  The token step gathers a sequence's keys through its page table,
+# takes the top ``k`` and gathers those latent rows (``paged_index_scores``,
+# ``select_positions``, ``paged_latent_attention_selected``: plain jnp; the
+# kernel that would walk the key pages and copy the selected rows where they
+# lie is not written).  A prompt's chunk scores its queries against the whole
+# table's positions in blocks and turns the selection into a mask a QUERY,
+# shared by all heads (``index_scores``, ``select_mask``), for the blocked
+# attention of ``models/llama.py``.  The scores are float32 sums of exact
+# products of the stored (bfloat16) queries and keys, in both.
+
+
+def block_size(n: int, want: int) -> int:
+    """The largest divisor of ``n`` that is at most ``want``."""
+    return max(b for b in range(1, min(n, want) + 1) if n % b == 0)
+
+
+def paged_rows(pages: jax.Array, layer: jax.Array, page_table_row,
+               first: jax.Array, count: int) -> jax.Array:
+    """``count`` pages of one sequence, from its ``first``-th on, as rows
+    ``[count * page, W]`` in the order of their positions."""
+    pids = jax.lax.dynamic_slice_in_dim(page_table_row, first, count)
+    return pages[layer, pids].reshape(count * pages.shape[2], pages.shape[3])
+
+
+def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array,
+                 q_block: int = 512, k_block: int = 1024) -> jax.Array:
+    """``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . keys[s])`` in float32: q [T,
+    H, d], w [T, H] float32, keys [S, d] -> [T, S].  Computed a block of
+    queries by a block of keys at a time, so that the heads' products
+    ``[H, T, S]`` never exist whole (18 GB at 64 heads, 4,096 queries and
+    17,408 keys)."""
+    T, H, d = q.shape
+    S = keys.shape[0]
+    tb, sb = block_size(T, q_block), block_size(S, k_block)
+
+    def rows(block):
+        qb, wb = block                              # [tb, H, d], [tb, H]
+
+        def cols(kb):                               # [sb, d]
+            dots = jnp.einsum("thd,sd->ths", qb, kb,
+                              preferred_element_type=jnp.float32)
+            return jnp.sum(jax.nn.relu(dots) * wb[:, :, None], axis=1)
+        out = jax.lax.map(cols, keys.reshape(S // sb, sb, d))
+        return jnp.swapaxes(out, 0, 1).reshape(tb, S)
+    return jax.lax.map(rows, (q.reshape(T // tb, tb, H, d),
+                              w.reshape(T // tb, tb, H))).reshape(T, S)
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 that orders as the floats do."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def select_mask(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
+    """For every row of ``scores`` [T, S] float32 the mask of its ``k``
+    largest among the positions ``valid`` [T, S] marks (all of them where
+    they are ``k`` or fewer), a tie to the lower position: what
+    ``lax.top_k`` of the masked row keeps, as a mask and without a sort.
+    The ``k``-th largest value of a row is found bit by bit (32 counts of
+    the row against a threshold), exact in any case; the rows' ties at that
+    value, which float32 sums of 64 products all but never have, are
+    resolved by position only where some row has one to resolve."""
+    T, S = scores.shape
+    if k >= S:
+        return valid
+    # an invalid position orders below every score (-inf is 0x007FFFFF)
+    key = jnp.where(valid, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], axis=1) >= k
+        return jnp.where(enough, cand, kth)
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((T,), jnp.uint32))
+    above = key > kth[:, None]
+    ties = key == kth[:, None]
+    room = k - jnp.sum(above, axis=1)               # of the ties, how many
+    crowded = jnp.any(jnp.sum(ties & valid, axis=1) > room)
+    keep = jax.lax.cond(
+        crowded,
+        lambda: above | (ties & (jnp.cumsum(ties, axis=1) <= room[:, None])),
+        lambda: above | ties)
+    return keep & valid
+
+
+def paged_index_scores(q: jax.Array, w: jax.Array, key_pages: jax.Array,
+                       layer: jax.Array, page_table: jax.Array) -> jax.Array:
+    """The token step's index scores: q [B, H, d], w [B, H] float32, the
+    indexer's key pool ``[L, P, page, d]`` -> [B, S] float32 over every
+    position of the ``page_table`` [B, maxp] it is given (S = maxp x page);
+    the caller masks by length."""
+    B = q.shape[0]
+    keys = key_pages[layer, page_table].reshape(B, -1, key_pages.shape[3])
+    dots = jnp.einsum("bhd,bsd->bhs", q, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
+
+
+def select_positions(scores: jax.Array, lengths: jax.Array, k: int):
+    """The ``min(k, length)`` positions under ``lengths`` [B] that score
+    highest in ``scores`` [B, S]: (positions [B, k'] int32, which of them
+    count [B, k'] bool), ``k'`` = ``min(k, S)``; exact (``lax.top_k``; a
+    tie to the lower position)."""
+    S = scores.shape[1]
+    live = jnp.arange(S)[None] < lengths[:, None]
+    _, at = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), min(k, S))
+    return at.astype(jnp.int32), \
+        jnp.arange(at.shape[1])[None] < lengths[:, None]
+
+
+def paged_latent_attention_selected(
+        q: jax.Array, pages: jax.Array, layer: jax.Array, at: jax.Array,
+        counted: jax.Array, page_table: jax.Array, *, sm_scale: float,
+        rank: int) -> jax.Array:
+    """``paged_latent_attention`` over the positions ``at`` [B, K] alone
+    (those that ``counted`` [B, K] marks): the rows are gathered through the
+    page table, a row a selected position, and nothing else of the pool is
+    read.  q [B, N, W] -> [B, N, rank]."""
+    B, N, W = q.shape
+    page, Wp = pages.shape[2:]
+    pid = jnp.take_along_axis(page_table, at // page, axis=1)    # [B, K]
+    rows = pages[layer, pid, at % page]                          # [B, K, Wp]
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, Wp - W)))
+    scores = jnp.einsum("bnw,bkw->bnk", q, rows) * sm_scale
+    scores = jnp.where(counted[:, None], scores.astype(jnp.float32), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bnk,bkw->bnw", probs, rows)[..., :rank]

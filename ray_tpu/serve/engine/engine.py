@@ -244,6 +244,37 @@ as the growth of ``tracing``'s always-on sums, ``stats()["stream"]`` /
 ``stats()`` carries the always-on counters of the same places,
 ``decode_ahead_steps`` and ``stray_slot_steps`` among them.
 
+A prompt may run as CHUNKS (``EngineConfig.prefill_chunk`` > 0; the record's
+``chunked`` says the model's prefill takes a ``start`` and reads what lies
+before it from the pages; the constructor refuses the field for any other: a
+recurrent state or a convolution's tail is not addressable by position).  The
+prefill ladder then ends at the chunk's width, and a prompt of n positions is
+``ceil(n / prefill_chunk)`` calls of the exec lane, back to back with the pipe
+drained as for any prefill (and ONE prompt a pass of the loop: between two
+prompts the live batch takes a step, where an engine without chunks prefills
+every admission before it steps again): each a chunk of at most ``prefill_chunk``
+positions padded to its rung, told its ``start`` (a traced argument: one
+program a rung wherever the chunk lies), writing its rows to the sequence's
+own pages and attending over them; the last call's token is the sequence's
+first.  Each call is an ``rt:engine.prefill`` of its own whose ``prompt_len``
+is the CHUNK's real positions, with ``start``, ``width`` and ``rung`` beside
+``padded_len``, and every chunk of a prompt carries the wait its first one
+found (``waited_us``); ``stats()`` counts the calls and their positions
+(``prefill_chunks``, ``prefill_chunk_tokens``; ``prefill_tokens`` and
+``admitted`` count prompts as before).  With ``prefill_chunk`` 0 nothing of
+this runs and every program, region and counter is what it was.
+
+A model may keep a SECOND pool a position beside its pages (the record's
+``index_pool``: a sparse-attention indexer's keys, in the V pool's place, one
+page table for both): the loop makes, donates and threads it as it does any
+pool and looks inside neither; ``stats()["index_pool_bytes"]`` is its share
+of ``kv_pool_bytes``.  Such a model's decode step reads at most
+``select_topk`` positions a sequence (the record's): the host, which knows
+every position, says on each ``rt:engine.decode.dispatch`` how many the step's
+sequences held (``live``) and how many their reads kept (``selected``:
+``min(select_topk, pos + 1)`` each), and sums them in ``stats()``
+(``dsa_live_positions``, ``dsa_selected_positions``).
+
 A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
 ``model="llama"`` like any other.  Its two programs return a fourth result,
 the live tokens' assignments per layer and expert; it reaches the host with
@@ -362,6 +393,10 @@ class EngineConfig:
     max_new_tokens: int = 32           # per-request cap
     eos_token: Optional[int] = None
     dtype: Any = None                  # KV pool dtype (default: model's)
+    prefill_chunk: int = 0             # > 0: a prompt runs as chunks of at
+    #                                    most this many positions (whole
+    #                                    pages), each against the
+    #                                    sequence's own pages; 0: one call
 
 
 def prefill_rungs(max_prompt_len: int, page_size: int) -> Tuple[int, ...]:
@@ -403,7 +438,8 @@ def rung_for(rungs: Sequence[int], need: int) -> int:
 class _Sequence:
     __slots__ = ("prompt", "max_new", "pages", "row", "queue", "generated",
                  "pos", "last_token", "cancelled", "slot", "prefilled",
-                 "deadline", "queued", "block", "masked", "passes", "end")
+                 "deadline", "queued", "block", "masked", "passes", "end",
+                 "waited")
 
     def __init__(self, prompt: List[int], max_new: int,
                  deadline: Optional[float] = None, block: int = 0,
@@ -435,6 +471,7 @@ class _Sequence:
         self.slot: Optional[int] = None
         self.prefilled = False
         self.queued = time.perf_counter()   # generate() to prefill: the wait
+        self.waited = 0.0                   # ... as its first call found it
 
 
 class InferenceEngine:
@@ -458,6 +495,15 @@ class InferenceEngine:
                 f"model max_seq_len {mc.max_seq_len} < max_prompt_len + "
                 f"max_new_tokens ({cfg.max_prompt_len + cfg.max_new_tokens})")
 
+        if cfg.prefill_chunk and (cfg.prefill_chunk % cfg.page_size
+                                  or not served.chunked):
+            raise ValueError(
+                f"prefill_chunk={cfg.prefill_chunk} must be whole pages of "
+                f"{cfg.page_size}, for a model whose prefill can start at a "
+                "position other than 0 and read what lies before it from "
+                "the pages (the record's ``chunked``): a recurrent state or "
+                "a convolution's tail is not addressable by position, and "
+                "the scans that make them take no initial row")
         self.config = cfg
         self.model_config = mc
         self._tree = jax.tree
@@ -490,6 +536,12 @@ class InferenceEngine:
         # layers keeps those alone)
         self._conv_tail_bytes = served.conv_tails(
             self._k_pages, self._v_pages).nbytes if served.conv_tails else 0
+        # of the pages, a sparse-attention indexer's keys (a second pool a
+        # position, addressed by the same page table), and the positions a
+        # step's attention reads at most
+        self._index_pool_bytes = served.index_pool(
+            self._k_pages, self._v_pages).nbytes if served.index_pool else 0
+        self._select_topk = served.select_topk
         self._alloc = PageAllocator(cfg.num_pages)
         self._maxp = -(-(cfg.max_prompt_len + cfg.max_new_tokens)
                        // cfg.page_size)
@@ -501,9 +553,9 @@ class InferenceEngine:
         # are arguments, not closed over: as constants they would be part of
         # the program and of its compile-cache key, one copy per entry point.
         # Both donate the pools (module docstring).
-        def _prefill(params, tokens, length, kp, vp, pt, slot=0):
+        def _prefill(params, tokens, length, kp, vp, pt, slot=0, *start):
             return served.prefill(params, mc, tokens, length, kp, vp, pt,
-                                  slot)
+                                  slot, *start)
 
         def _decode(params, token, pos, kp, vp, pt):
             return served.step(params, mc, token, pos, kp, vp, pt)
@@ -538,7 +590,10 @@ class InferenceEngine:
         # keep the jitted function, which takes any [1, S] and any tree.
         # The loop's decode programs likewise: ``_decode_next_donating``
         # compiled for every width of the decode ladder.
-        self._rungs = prefill_rungs(cfg.max_prompt_len, cfg.page_size)
+        # (under ``prefill_chunk`` no call is wider than a chunk)
+        self._rungs = prefill_rungs(
+            min(cfg.prefill_chunk or cfg.max_prompt_len, cfg.max_prompt_len),
+            cfg.page_size)
         # what each rung's attention runs as ("flash": the kernel, "dense")
         self._rung_attention = {rung: served.prefill_attention(mc, rung)
                                 for rung in self._rungs}
@@ -601,6 +656,9 @@ class InferenceEngine:
         self._kv_live_token_steps = 0
         self._kv_gathered_token_steps = 0
         self._state_rows_written = 0
+        self._prefill_chunks = 0
+        self._prefill_chunk_tokens = 0
+        self._selected_positions = 0
         self._host_s = dict.fromkeys(
             ("schedule", "submit", "dispatch", "fetch", "resume", "deliver"),
             0.0)
@@ -675,7 +733,9 @@ class InferenceEngine:
             self._wake.set()
 
     def stats(self) -> Dict[str, Any]:
-        """Gauges (``active``, ``waiting``, ``free_pages``) and counters
+        """Gauges (``active``, ``waiting``, ``free_pages``; ``prefilling``: of
+        the active, the admitted sequences whose prompt's prefill has not
+        ended, which under ``prefill_chunk`` wait a prompt a pass) and counters
         since the engine started: ``steps`` decode steps dispatched, of them
         ``decode_ahead_steps`` while the step before was in flight (the
         rest on a drained pipe: module docstring), and the
@@ -724,6 +784,15 @@ class InferenceEngine:
         them, and ``kv_pool_in_place`` says of each program ("prefill",
         "decode"), once the loop has called it, whether that first call's
         result pools lay in its arguments' buffers (the donation was used).
+        ``index_pool_bytes`` is, of ``kv_pool_bytes``, the pool of a
+        sparse-attention indexer's keys (0: the model has none);
+        ``prefill_chunks`` counts the prefill calls that ran a CHUNK of a
+        prompt under ``prefill_chunk`` (every call of every prompt then, a
+        short prompt's one) and ``prefill_chunk_tokens`` their real
+        positions; ``dsa_selected_positions`` sums over decode steps the
+        positions the live sequences' attention READ, ``min(index_topk, pos
+        + 1)`` each, against the ``dsa_live_positions`` they held (both 0 for
+        a model that selects nothing).
         A model with rows a decode slot (module docstring) adds
         ``recurrent_state_bytes``, what those rows take for all slots
         (``conv_tail_bytes`` of it the short convolutions' last inputs and
@@ -790,6 +859,8 @@ class InferenceEngine:
         by kind and type under ``rpc``'s ``out`` and ``in``
         (``request.stream_yield``, ``reply``)."""
         return {"active": len(self._active), "waiting": len(self._waiting),
+                "prefilling": sum(not seq.prefilled
+                                  for seq in self._active.values()),
                 "free_pages": self._alloc.free_pages, "steps": self._steps,
                 "decode_ahead_steps": self._decode_ahead_steps,
                 "slot_steps": self._slot_steps,
@@ -819,6 +890,12 @@ class InferenceEngine:
                 "kv_live_token_steps": self._kv_live_token_steps,
                 "kv_gathered_token_steps": self._kv_gathered_token_steps,
                 "kv_pool_in_place": dict(self._kv_in_place),
+                "index_pool_bytes": self._index_pool_bytes,
+                "prefill_chunks": self._prefill_chunks,
+                "prefill_chunk_tokens": self._prefill_chunk_tokens,
+                "dsa_selected_positions": self._selected_positions,
+                "dsa_live_positions": self._kv_live_token_steps
+                if self._select_topk else 0,
                 **self._recurrent_stats(),
                 "device": self._device,
                 "first_call_s": dict(self._first_call_s),
@@ -917,7 +994,9 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((1, rung), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32), kp, vp,
             jax.ShapeDtypeStruct((1, self._maxp), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32))
+            # the slot and, under ``prefill_chunk``, the chunk's start
+            *[jax.ShapeDtypeStruct((), jnp.int32)] * (
+                1 + bool(self.config.prefill_chunk)))
 
     def _compile_decode_rung(self, width: int, params, kp, vp):
         """``_decode_next_donating`` compiled for a page table of
@@ -1031,7 +1110,8 @@ class InferenceEngine:
                     if not (flying.get(slot) is seq and not seq.masked.any()
                             and seq.pos + self._block >= seq.end)}
         return {slot: seq for slot, seq in self._active.items()
-                if seq.generated + (flying.get(slot) is seq) < seq.max_new}
+                if seq.prefilled
+                and seq.generated + (flying.get(slot) is seq) < seq.max_new}
 
     def _block_inputs(self, stepped: Dict[int, _Sequence]):
         """``_decode_inputs`` of a block model: the blocks' state where the
@@ -1319,6 +1399,11 @@ class InferenceEngine:
         # what only some models' steps carry
         more = {**({"block_len": self._block} if self._block else {}),
                 **({"linear_state": linear_state} if linear_state else {})}
+        if self._select_topk:    # what the selection leaves of the read
+            selected = int(np.minimum(
+                pos[pos > 0] + 1, self._select_topk).sum())
+            more.update(selected=selected, live=live_tokens)
+            self._selected_positions += selected
         submitted, sampled = self._submit(cpu=True)
         # everything the step before cost the loop: its delivery,
         # the streams' fan-out, schedule, the prefills between
@@ -1369,6 +1454,55 @@ class InferenceEngine:
         else:
             self._deliver_step(prev, fetched, submitted, lane)
 
+    async def _prefill_call(self, loop, seq: _Sequence, start: int,
+                            width: int, last: bool):
+        """One prefill call for ``seq`` on the exec lane, padded to its
+        rung: the whole prompt (``start`` 0, ``width`` its length) or, under
+        ``prefill_chunk``, the chunk of ``width`` positions from ``start``,
+        which reads what the calls before it left in the sequence's pages.
+        The ``last`` call's token is the sequence's first."""
+        import jax.numpy as jnp
+        chunked = bool(self.config.prefill_chunk)
+        S = rung_for(self._rungs, width)
+        program = self._rung_programs[S].result()
+        toks = np.zeros((1, S), np.int32)
+        toks[0, :width] = seq.prompt[start:start + width]
+        submitted, sampled = self._submit()
+        if not start:
+            self._queue_wait_s += submitted.wall - seq.queued
+        self._prefill_padded_tokens += S
+        self._prefill_shapes[S] += 1
+        attention = self._rung_attention[S]
+        self._prefill_attention[attention] += 1
+        self._prefill_chunks += chunked
+        self._prefill_chunk_tokens += width * chunked
+        # (the chunk's place, only where prompts run as chunks: the region
+        # is then the CHUNK's, ``prompt_len`` the real positions it ran, and
+        # every chunk of a prompt carries the wait its first one found)
+        where = {"prompt_len": len(seq.prompt)} if not chunked else \
+            {"prompt_len": width, "start": start, "width": width, "rung": S}
+
+        def _run():
+            begun = self._clocks(sampled)
+            if not start:
+                seq.waited = begun.wall - seq.queued
+            with region("engine.prefill", padded_len=S, attention=attention,
+                        waited_us=_us(seq.waited),
+                        submit_us=_us(begun.wall - submitted.wall),
+                        **where):
+                logits, kp, vp, *load = self._donate_pools(
+                    "prefill", program, toks, np.int32(width),
+                    seq.row[None], np.int32(seq.slot),
+                    *([np.int32(start)] if chunked else []))
+                tok = int(jnp.argmax(logits[0])) \
+                    if last and not self._block else None
+                load = [np.asarray(a) for a in load]
+            return tok, kp, vp, load, (begun, begun, self._clocks(sampled))
+        tok, self._k_pages, self._v_pages, load, lane = \
+            await loop.run_in_executor(self._exec, _run)
+        self._count_moe("prefill", load, int(width), S)
+        self._deliver([] if tok is None else [(seq, tok)], submitted, lane)
+
     def _count_moe(self, program: str, load: Sequence[np.ndarray],
                    tokens: int, rows: int):
         """What an expert model's program said of its ``tokens`` real
@@ -1410,7 +1544,6 @@ class InferenceEngine:
             (eos is not None and token == eos)
 
     async def _run_loop(self):
-        import jax.numpy as jnp
         loop = asyncio.get_running_loop()
         cfg = self.config
         self._loop_cpu_clock = time.pthread_getcpuclockid(
@@ -1458,49 +1591,24 @@ class InferenceEngine:
                     continue
 
                 # Prefill new admissions one at a time (B=1), each padded
-                # to its prompt's rung; the pipe is drained.
-                for seq in fresh:
+                # to its prompt's rung; the pipe is drained.  Where prompts
+                # run as chunks (seconds of device time each), ONE prompt a
+                # pass: the live batch takes a step between two prompts, so
+                # nobody's stream stands still for a queue of long prompts.
+                for seq in fresh[:1] if cfg.prefill_chunk else fresh:
                     # a block model prefills the prompt's whole blocks;
                     # what is left of it is in its first block
                     whole = seq.pos if self._block else len(seq.prompt)
-                    S = rung_for(self._rungs, whole)
-                    program = self._rung_programs[S].result()
-                    toks = np.zeros((1, S), np.int32)
-                    toks[0, :whole] = seq.prompt[:whole]
-                    submitted, sampled = self._submit()
-                    self._queue_wait_s += submitted.wall - seq.queued
                     self._prefill_tokens += len(seq.prompt)
-                    self._prefill_padded_tokens += S
-                    self._prefill_shapes[S] += 1
-                    attention = self._rung_attention[S]
-                    self._prefill_attention[attention] += 1
-
-                    def _run(seq=seq, S=S, program=program, toks=toks,
-                             submitted=submitted, sampled=sampled,
-                             whole=whole, attention=attention):
-                        start = self._clocks(sampled)
-                        with region("engine.prefill",
-                                    prompt_len=len(seq.prompt), padded_len=S,
-                                    attention=attention,
-                                    waited_us=_us(start.wall - seq.queued),
-                                    submit_us=_us(
-                                        start.wall - submitted.wall)):
-                            logits, kp, vp, *load = self._donate_pools(
-                                "prefill", program, toks,
-                                np.int32(whole), seq.row[None],
-                                np.int32(seq.slot))
-                            tok = None if self._block \
-                                else int(jnp.argmax(logits[0]))
-                            load = [np.asarray(a) for a in load]
-                        return tok, kp, vp, load, \
-                            (start, start, self._clocks(sampled))
-                    tok, self._k_pages, self._v_pages, load, lane = \
-                        await loop.run_in_executor(self._exec, _run)
+                    # under ``prefill_chunk`` a row of calls, each a chunk
+                    # against the sequence's pages; else the one
+                    chunk = cfg.prefill_chunk or max(whole, 1)
+                    for start in range(0, max(whole, 1), chunk):
+                        await self._prefill_call(
+                            loop, seq, start, min(chunk, whole - start),
+                            last=start + chunk >= whole)
                     seq.prefilled = True
                     self._state_rows_written += self._slot_rows
-                    self._count_moe("prefill", load, int(whole), S)
-                    self._deliver([] if tok is None else [(seq, tok)],
-                                  submitted, lane)
 
                 if not self._active:
                     continue
@@ -1520,6 +1628,8 @@ class InferenceEngine:
                     with region("engine.schedule", active=len(self._active),
                                 waiting=len(self._waiting)):
                         stepped = self._steppable()
+                        if not stepped:      # only prompts still to prefill
+                            continue
                         batch = self._decode_inputs(stepped)
                 await self._decode_step(loop, stepped, batch)
             except asyncio.CancelledError:

@@ -32,8 +32,8 @@ pages.
   serving replica dies (ActorDiedError from the stream) or stalls past
   ``RT_SERVE_STALL_S`` (a stream that has yielded nothing yet is left
   alone past that, up to ``RT_SERVE_STREAM_IDLE_S``, only while its
-  replica says that requests wait for a decode slot and its engine takes
-  steps: queued, not stalled), the ingress cancels the broken stream,
+  replica says that requests wait for a decode slot or for their prefill
+  and its engine takes steps or prefills: queued, not stalled), the ingress cancels the broken stream,
   picks a healthy replica, and resumes: for token-generation payloads
   (``{"tokens": [...], "max_new_tokens": N}``) it re-prefills
   ``prompt + delivered`` with the remaining token budget — under greedy
@@ -309,7 +309,9 @@ class HTTPIngress:
         stall window, holds that request in a queue that moves.  The layer
         that owns the queue says: two readings of the handler's ``stats()``
         a moment apart must show requests waiting for a decode slot and
-        the engine's step count rising.  A handler without such ``stats``,
+        (or, admitted, for their prompt's prefill) and the engine's count of
+        steps and of positions prefilled rising.  A handler without such
+        ``stats``,
         a replica that does not answer, an empty queue or an engine that
         stands still is a stall."""
         replica = next((r for r in self._replicas.get(name) or ()
@@ -323,8 +325,13 @@ class HTTPIngress:
                     replica.handle_request.remote([], {}, "stats", None),
                     min(5.0, self._stall_s)))
                 await asyncio.sleep(pause)
-            return seen[1]["waiting"] > 0 and \
-                seen[1]["steps"] > seen[0]["steps"]
+            # (a prompt in chunks is seconds of prefill calls with no step
+            # between them, and an admitted request waits for its own
+            # prefill behind the prompts ahead of it: both are work)
+            queued = seen[1]["waiting"] + seen[1].get("prefilling", 0)
+            done = [s["steps"] + s.get("prefill_padded_tokens", 0)
+                    for s in seen]
+            return queued > 0 and done[1] > done[0]
         except Exception:   # noqa: BLE001
             return False
 

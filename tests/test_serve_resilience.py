@@ -255,17 +255,22 @@ class _Gen:
 class _Replica:
     """A replica handle whose handler's ``stats()`` reads ``waiting``
     queued requests and a step count that rises by ``pace`` a reading."""
-    def __init__(self, waiting, pace):
+    def __init__(self, waiting, pace, **more):
         self._actor_id = "replica-1"
         self.waiting, self.pace, self.steps = waiting, pace, 100
+        # further keys of ``stats()``: a value, or a function of the readings
+        self.more, self.reads = more, 0
         self.handle_request = self
 
     def remote(self, args, kwargs, method, deadline):
         assert method == "stats"
         self.steps += self.pace
+        self.reads += 1
 
         async def answer():
-            return {"waiting": self.waiting, "steps": self.steps}
+            return {"waiting": self.waiting, "steps": self.steps,
+                    **{key: value(self.reads) if callable(value) else value
+                       for key, value in self.more.items()}}
         return answer()
 
 
@@ -302,6 +307,34 @@ def test_a_queued_stream_is_not_a_stalled_one():
     assert len(gens) == 1 and not gens[0].cancelled
     assert sent.count(b"data: ") == 4 and b"event: end" in sent
     assert b"event: error" not in sent
+
+
+@pytest.mark.parametrize("replica", [
+    # admitted, waiting for its own prompt's prefill behind others', while
+    # the engine prefills (no step in sight): ``prefilling`` and the
+    # positions prefilled
+    dict(waiting=0, pace=0, prefilling=3,
+         prefill_padded_tokens=lambda reads: 4096 * reads),
+    # waiting for a slot while the engine runs a long prompt's chunks
+    dict(waiting=4, pace=0, prefilling=1,
+         prefill_padded_tokens=lambda reads: 4096 * reads)])
+def test_a_stream_behind_long_prefills_is_not_a_stalled_one(replica):
+    """An engine that runs prompts as chunks takes no step for seconds: what
+    it prefills counts as its work, and an admitted request whose prefill
+    has not begun as queued."""
+    replica = _Replica(**replica)    # (its steps stand still)
+    sent, gens = _stream_through_ingress(first=0.7, gap=0.01,
+                                         replica=replica)
+    assert len(gens) == 1 and not gens[0].cancelled
+    assert sent.count(b"data: ") == 4 and b"event: error" not in sent
+
+
+def test_prefilling_that_stands_still_is_a_stall():
+    replica = _Replica(waiting=0, pace=0, prefilling=3,
+                       prefill_padded_tokens=4096)
+    sent, gens = _stream_through_ingress(first=3.0, gap=0.01,
+                                         replica=replica)
+    assert gens[0].cancelled and b"event: error" in sent
 
 
 @pytest.mark.parametrize("waiting,pace", [(8, 0), (0, 3)])
