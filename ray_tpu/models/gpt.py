@@ -545,7 +545,7 @@ def served(config: Optional[GPTConfig] = None, seq: int = 0):
         config=cfg, init=gpt_init, stored=gpt_serving_params,
         new_pools=functools.partial(init_paged_cache, cfg),
         prefill=gpt_prefill, step=gpt_decode_step,
-        prefill_attention=lambda cfg, rung: "dense",
+        prefill_attention=lambda cfg, rung, start=False: "dense",
         paged_read=gpt_paged_read, block=0, feed=greedy)
 
 

@@ -144,9 +144,11 @@ token's experts inside its k best of n groups (``ops/moe.py::_route``).
 A model whose whole past is addressable by position in latent pages
 (``llama_prefill_chunks``) takes a prompt IN CHUNKS: ``llama_prefill`` with a
 ``start`` runs the positions ``start ..`` of the prompt, writes their rows
-to the sequence's pages and attends over the PAGES (``_mla_blocked``: blocks
-of keys gathered through the page table, an online softmax, the selection a
-mask a query that all heads share), so no call is wider than the widest
+to the sequence's pages and attends over the PAGES (``_mla_chunk``: the
+table's rows gathered and expanded per head once a layer, then
+``ops/latent_prefill.py``'s walk, queries outermost with an online softmax,
+the selection a mask a query that all heads share; on the chip ONE kernel
+whose scores never leave fast memory), so no call is wider than the widest
 compiled and the scores ``[heads, S, S]`` never exist (8.6 GB at 128 heads
 and 4,096 positions).  An indexer model's prefill always runs so (``start`` 0
 where none is given); every other model without a ``start`` keeps
@@ -972,76 +974,37 @@ def _index_project(cfg: LlamaConfig, p, h, cq, cos, sin):
             rotated(k, cos, sin))
 
 
-def _mla_blocked(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer, row,
-                 start, length, keep=None):
+def _mla_chunk(cfg: LlamaConfig, p, q_nope, q_rope, pages, layer, row,
+               start, length, keep=None):
     """Causal latent attention of a CHUNK of one sequence against its own
-    pages, a block of keys at a time with an online softmax: q_nope [S, N,
-    dn], q_rope [S, N, dr] are the queries of positions ``start .. start + S
-    - 1`` (``length`` of them real), ``row`` [maxp] the sequence's page
-    table, whose pages already hold every position under ``start + length``
-    (the chunk's own, just written, among them).  A block of the table's
-    pages is gathered, its keys and values expanded per head from the
-    latent rows as ``_mla_expanded`` expands a whole sequence's, and every
-    block of queries that may see it is scored against it; the scores
-    ``[N, S, S]`` never exist (8.6 GB at 128 heads and 4,096 positions).
-    Blocks past ``start + length`` are not visited.  ``keep`` [S, maxp x
-    page] bool is the selection, a mask a QUERY that all heads share and
-    that already holds the causal bound; None: every causal position.
-    Returns [S, N, dv]."""
-    from ray_tpu.ops.paged_attention import block_size, paged_rows
-    rank, dn, dv, dt = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.v_head_dim,
-                        cfg.dtype)
-    S, N = q_nope.shape[:2]
-    page = pages.shape[2]
-    per = block_size(row.shape[0], max(1024 // page, 1))   # pages a block
-    kb, qb = per * page, block_size(S, 512)
+    pages: q_nope [S, N, dn], q_rope [S, N, dr] are the queries of positions
+    ``start .. start + S - 1`` (``length`` of them real), ``row`` [maxp] the
+    sequence's page table, whose pages already hold every position under
+    ``start + length`` (the chunk's own, just written, among them).  The
+    table's rows are gathered and their keys and values expanded per head
+    ONCE (``wkv_b``, as ``_mla_expanded`` expands a whole sequence's: 1.1 GB
+    at 128 heads and 17,408 positions), and
+    ``ops/latent_prefill.py::latent_chunk_attention`` walks them, queries
+    outermost: one kernel that keeps a block's scores, probabilities and
+    running statistics in fast memory (interpreted where the backend has no
+    compiler for it); the scores ``[N, S, S]`` never exist (8.6 GB at 128 heads
+    and 4,096 positions) and nothing past ``start + length`` is visited.
+    ``keep`` [S, maxp x page] bool is the selection, a mask a QUERY that all
+    heads share and that already holds the causal bound; None: every causal
+    position.  Returns [S, N, dv]."""
+    from ray_tpu.ops.latent_prefill import latent_chunk_attention
+    from ray_tpu.ops.paged_attention import paged_rows
+    rank, dn, dt = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.dtype
+    rows = paged_rows(pages, layer, row, 0, row.shape[0])        # [T, Wp]
     wkv_b = p["attn"]["wkv_b"].astype(dt)
-    scale = mla_softmax_scale(cfg)
-
-    def keys(j, carry):
-        rows = paged_rows(pages, layer, row, j * per, per)       # [kb, Wp]
-        kv = jnp.einsum("sc,cnh->snh", rows[:, :rank], wkv_b)
-        k_rope = rows[:, rank:rank + cfg.qk_rope_dim]
-        kpos = j * kb + jnp.arange(kb)
-
-        def queries(i, carry):
-            m, l, acc = carry
-            at = i * qb
-            qn = jax.lax.dynamic_slice_in_dim(q_nope, at, qb)
-            qr = jax.lax.dynamic_slice_in_dim(q_rope, at, qb)
-            scores = (jnp.einsum("qnh,knh->nqk", qn, kv[..., :dn])
-                      + jnp.einsum("qnh,kh->nqk", qr, k_rope)) * scale
-            if keep is None:
-                seen = kpos[None] <= (start + at + jnp.arange(qb))[:, None]
-            else:
-                seen = jax.lax.dynamic_slice(keep, (at, j * kb), (qb, kb))
-            scores = jnp.where(seen[None], scores.astype(jnp.float32),
-                               -1e30)
-            m_old = jax.lax.dynamic_slice_in_dim(m, at, qb, axis=1)
-            m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1))
-            # (a query that sees nothing of this block adds exactly nothing)
-            probs = jnp.where(seen[None],
-                              jnp.exp(scores - m_new[..., None]), 0.0)
-            fade = jnp.exp(m_old - m_new)
-            l_new = jax.lax.dynamic_slice_in_dim(l, at, qb, axis=1) * fade \
-                + jnp.sum(probs, axis=-1)
-            acc_new = jax.lax.dynamic_slice_in_dim(acc, at, qb, axis=1) \
-                * fade[..., None] + jnp.einsum(
-                    "nqk,knh->nqh", probs.astype(dt), kv[..., dn:],
-                    preferred_element_type=jnp.float32)
-            return (jax.lax.dynamic_update_slice_in_dim(m, m_new, at, 1),
-                    jax.lax.dynamic_update_slice_in_dim(l, l_new, at, 1),
-                    jax.lax.dynamic_update_slice_in_dim(acc, acc_new, at, 1))
-        # the first block of queries that reaches this block of keys
-        first = jnp.maximum(j * kb - start, 0) // qb
-        return jax.lax.fori_loop(first, S // qb, queries, carry)
-
-    blocks = (start + length + kb - 1) // kb
-    m, l, acc = jax.lax.fori_loop(0, blocks, keys, (
-        jnp.full((N, S), -1e30, jnp.float32), jnp.zeros((N, S), jnp.float32),
-        jnp.zeros((N, S, dv), jnp.float32)))
-    o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return jnp.swapaxes(o, 0, 1).astype(dt)
+    # two plain matrix products, a head a run of columns of a position's
+    # row: what the kernel reads as it lies (the einsum to [T, N, h] comes
+    # out position-minor on the TPU and is copied back, 1.1 GB a layer)
+    k, v = (jnp.dot(rows[:, :rank], w.reshape(rank, -1))
+            for w in (wkv_b[..., :dn], wkv_b[..., dn:]))
+    return latent_chunk_attention(
+        q_nope, q_rope, k, v, rows[:, rank:rank + cfg.qk_rope_dim], keep,
+        start, length, sm_scale=mla_softmax_scale(cfg))
 
 
 def _mla_expanded(cfg: LlamaConfig, p, q_nope, q_rope, latent):
@@ -1374,7 +1337,7 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
     slot ``slot`` what stands after position ``length - 1``.  With ``start``
     (latent pages alone) the rows are a chunk of the prompt from that
     position on, and they attend over the sequence's PAGES, what earlier
-    chunks left there and their own rows (``_mla_blocked``); an indexer's
+    chunks left there and their own rows (``_mla_chunk``); an indexer's
     keys go to their own pool likewise and its selection is the mask."""
     def kv(p, layer, pools, q, k, v):
         from ray_tpu.ops.flash_attention import flash_attention
@@ -1410,8 +1373,8 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
                 keep = select_mask(scores, seen, cfg.index_topk)
         with jax.named_scope("dsa_read" if index is not None
                              else "latent_read"):
-            o = _mla_blocked(cfg, p, q_nope[0], q_rope[0], pool, layer, row,
-                             start, length, keep)
+            o = _mla_chunk(cfg, p, q_nope[0], q_rope[0], pool, layer, row,
+                           start, length, keep)
         return o[None], (pool, keys)
 
     def recurrent(p, layer, pools, qkv, g, beta):
@@ -1851,20 +1814,27 @@ def _paged_results(logits, k_pages, v_pages, load):
     return logits, k_pages, v_pages, load
 
 
-def llama_prefill_attention(cfg: LlamaConfig, S: int) -> str:
+def llama_prefill_attention(cfg: LlamaConfig, S: int,
+                            start: bool = False) -> str:
     """The attention ``llama_prefill`` runs over a padded sequence of S
-    positions, "flash" or "dense": what ``resolve_attention`` says of
-    ``cfg.attention`` and S, as for ``llama_hidden``.  Two kinds of model
-    are dense at every S: a block model, whose mask is causal over blocks
-    (the kernel's sub-tile walk does not express it), and a latent one,
-    whose q/k heads are wider than its v heads; ``flash`` pinned on either
-    raises."""
+    positions, "flash", "dense" or "latent_chunk"; ``llama_prefill`` itself
+    asks, so what the engine reports is what the program decided.  A call
+    with a ``start`` (``start`` says whether it has one; an indexer model's
+    always has) is a CHUNK's, over the sequence's pages: "latent_chunk", the
+    kernel of ``ops/latent_prefill.py``.  Every other call: what
+    ``resolve_attention`` says of ``cfg.attention`` and S, as for
+    ``llama_hidden``.  Two kinds of model are dense at every S without a
+    ``start``: a block model, whose mask is causal over blocks (the kernel's
+    sub-tile walk does not express it), and a latent one, whose q/k heads
+    are wider than its v heads; ``flash`` pinned on either raises."""
     unwritten = "block_length" if cfg.block_length else \
         "kv_lora_rank" if cfg.kv_lora_rank else None
     if unwritten and cfg.attention == "flash":
         raise NotImplementedError(
             f"llama_prefill: the flash kernel is not written for a model "
             f"with {unwritten}; leave attention 'auto' or 'dense'")
+    if (start or cfg.index_heads) and llama_prefill_chunks(cfg):
+        return "latent_chunk"
     if unwritten or resolve_attention(cfg.attention, S) != "flash":
         return "dense"
     return "flash"
@@ -1957,15 +1927,17 @@ def llama_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     this way (``start`` 0 where none is given): its selection is a read of
     the pages."""
     S = tokens.shape[1]
-    flash = llama_prefill_attention(cfg, S) == "flash"
-    if start is None and cfg.index_heads:
-        start = jnp.int32(0)
     if start is not None and not llama_prefill_chunks(cfg):
         raise NotImplementedError(
             "llama_prefill: a prefill that starts at a position other than "
             "0 reads what lies before it from latent pages; K/V pages are "
             "not read so yet, and a recurrent state or a convolution's tail "
             "cannot be (their scans take no initial row)")
+    # the one decision, which the engine reports by the same call
+    attention = llama_prefill_attention(cfg, S, start is not None)
+    flash = attention == "flash"
+    if attention == "latent_chunk" and start is None:    # an indexer's
+        start = jnp.int32(0)
     if start is None:
         cos, sin = _rope_tables(cfg, S)
     else:                            # the chunk's rows of the whole table

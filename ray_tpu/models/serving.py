@@ -37,7 +37,9 @@ class ServedModel(NamedTuple):
     step: Callable               # the decode step, (params, config, token,
     #                              pos, ...) likewise; a block model's takes
     #                              the blocks' state and ends for token, pos
-    prefill_attention: Callable  # (config, rung) -> "flash" | "dense"
+    prefill_attention: Callable  # (config, rung, whether the call is given
+    #                              a start) -> "flash" | "dense" |
+    #                              "latent_chunk"
     paged_read: Callable         # (config, k_pages) -> "kernel" |
     #                              "gather": what the step's programs read
     #                              the pages with (``ops/paged_attention.py``)

@@ -385,14 +385,18 @@ def paged_latent_attention(q: jax.Array, pages: jax.Array, layer: jax.Array,
 # kernel that would walk the key pages and copy the selected rows where they
 # lie is not written).  A prompt's chunk scores its queries against the whole
 # table's positions in blocks and turns the selection into a mask a QUERY,
-# shared by all heads (``index_scores``, ``select_mask``), for the blocked
-# attention of ``models/llama.py``.  The scores are float32 sums of exact
-# products of the stored (bfloat16) queries and keys, in both.
+# shared by all heads (``index_scores``, ``select_mask``), for the chunk's
+# attention over the pages, which IS a kernel on the chip
+# (``ops/latent_prefill.py``: it reads the mask a tile a grid step, exactly).
+# The scores are float32 sums of exact products of the stored (bfloat16)
+# queries and keys, in both.
 
 
-def block_size(n: int, want: int) -> int:
-    """The largest divisor of ``n`` that is at most ``want``."""
-    return max(b for b in range(1, min(n, want) + 1) if n % b == 0)
+def block_size(n: int, want: int, of: int = 1) -> int:
+    """The largest divisor of ``n`` that is at most ``want`` and a multiple
+    of ``of``; 0 if there is none."""
+    return max((b for b in range(of, min(n, want) + 1, of) if n % b == 0),
+               default=0)
 
 
 def paged_rows(pages: jax.Array, layer: jax.Array, page_table_row,

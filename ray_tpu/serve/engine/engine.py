@@ -28,8 +28,10 @@ after the prompt under a causal mask and its K/V go to scratch page 0, so a
 shorter rung gives the logits and pages of a longer one.  A rung's causal
 attention is the flash forward kernel where the model's own selector says so
 (``models/llama.py::llama_prefill_attention``: on the chip the rungs of 1024
-and more of a model with K/V pages and a causal mask), else dense with its
-scores in memory; ``rt:engine.prefill`` names which as ``attention`` and
+and more of a model with K/V pages and a causal mask; a CHUNK's latent
+attention over the pages is the kernel of ``ops/latent_prefill.py``,
+"latent_chunk"), else dense with its scores in memory;
+``rt:engine.prefill`` names which as ``attention`` and
 ``stats()["prefill"]`` counts the prefills by it.  Every rung's
 program is compiled while the engine is constructed, a few at a time on
 threads of their own, and the loop admits nobody before all of them are
@@ -594,9 +596,11 @@ class InferenceEngine:
         self._rungs = prefill_rungs(
             min(cfg.prefill_chunk or cfg.max_prompt_len, cfg.max_prompt_len),
             cfg.page_size)
-        # what each rung's attention runs as ("flash": the kernel, "dense")
-        self._rung_attention = {rung: served.prefill_attention(mc, rung)
-                                for rung in self._rungs}
+        # what each rung's attention runs as ("flash": the kernel, "dense";
+        # a chunk over latent pages by its own kernel: "latent_chunk")
+        self._rung_attention = {
+            rung: served.prefill_attention(mc, rung, bool(cfg.prefill_chunk))
+            for rung in self._rungs}
         self._decode_rungs = decode_rungs(self._maxp)
         # what the decode programs read the pages with ("kernel": each
         # sequence's own pages copied where they lie; "gather")
@@ -619,7 +623,7 @@ class InferenceEngine:
             for width in self._decode_rungs}
         pool.shutdown(wait=False)    # the threads end with their compiles
         self._prefill_shapes = dict.fromkeys(self._rungs, 0)
-        self._prefill_attention = {"dense": 0, "flash": 0}
+        self._prefill_attention = {"dense": 0, "flash": 0, "latent_chunk": 0}
         self._decode_shapes = dict.fromkeys(self._decode_rungs, 0)
         self._decode_paged_read = {"gather": 0, "kernel": 0}
         self._decode_linear_state = {"kernel": 0, "rule": 0}
@@ -749,7 +753,8 @@ class InferenceEngine:
         ``prefill_padded_tokens`` the padded programs ran (a prefill adds
         its rung), ``prefill_shapes``, the prefills by rung, and
         ``prefill["attention"]``, the prefills by what their rung's
-        attention ran as ("flash": the kernel, "dense"),
+        attention ran as ("flash": the kernel, "dense", "latent_chunk": a
+        chunk's kernel over latent pages),
         ``decode_shapes``, the decode steps by the width of their page table
         in pages (a rung of ``decode_rungs``; they sum to ``steps``),
         ``decode["paged_read"]``, the decode steps by what their program

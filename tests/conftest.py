@@ -15,7 +15,9 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 import contextlib  # noqa: E402
 import faulthandler  # noqa: E402
+import shutil  # noqa: E402
 import signal  # noqa: E402
+import tempfile  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -165,6 +167,27 @@ STALE_SNAPSHOTS = {
         "assertions run as test_spec_deepseek_v32.py::"
         "test_the_unlisted_readers_are_files_with_no_entry",
 }
+
+
+def pytest_configure(config):
+    """One persistent compile cache for the whole run, in a directory of its
+    own under the run's ``TMPDIR``, made here and removed when the run ends:
+    the xdist workers (which inherit this process's environment), the tests
+    of a file and the processes a test starts compile many of the same tiny
+    programs (an engine's two of one tiny model in a dozen files, a
+    replica's beside its twin's), and a program one of them has compiled the
+    others read back.  A run starts with the cache empty, so nothing is
+    carried from an earlier tree.  A directory the environment already names
+    stands, and so does one a test sets itself (``tests/benchmark/tiny.py``,
+    ``test_chip_smoke.py``).  CHANGES.md, PR 58, has the suite's times with
+    and without, file by file."""
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        return
+    cache = tempfile.mkdtemp(prefix="rt_jax_cache_")
+    config.add_cleanup(lambda: shutil.rmtree(cache, ignore_errors=True))
+    os.environ.update(JAX_COMPILATION_CACHE_DIR=cache,
+                      JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0.3",
+                      JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
 
 
 def pytest_collection_modifyitems(items):

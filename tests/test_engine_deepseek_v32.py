@@ -63,6 +63,9 @@ def test_the_two_pools_and_the_counters(runs):
             <= 8 * stats["slot_steps"] < stats["dsa_live_positions"]
         assert stats["prefill_tokens"] == sum(map(len, PROMPTS))
         assert engine._rungs == prefill_rungs(chunk or 48, 8)
+    # an indexer's prefill is a chunk's whether or not the engine cuts it
+    assert [r[1]["prefill"]["attention"] for r in runs.values()] == [
+        {"dense": 0, "flash": 0, "latent_chunk": calls} for calls in (3, 7)]
     assert runs[0][1]["prefill_chunks"] == 0
     assert runs[0][1]["prefill_shapes"] == {48: 3}
     # 37 = 16 + 16 + 5, 11, 48 = 3 x 16: seven calls of the one rung
@@ -76,7 +79,10 @@ def test_the_two_pools_and_the_counters(runs):
 def test_the_regions_say_where_a_chunk_lies(runs, monkeypatch):
     """``rt:engine.prefill`` of a chunked engine is the CHUNK's (its real
     positions as ``prompt_len``, its ``start``, ``width`` and ``rung``) and
-    every chunk of a prompt carries the wait its first one found;
+    every chunk of a prompt carries the wait its first one found, and what
+    the model's selector says its attention runs as (the chunk's kernel) is
+    the region's ``attention`` and the key
+    ``stats()["prefill"]["attention"]`` counts it under;
     ``rt:engine.decode.dispatch`` carries ``selected`` and ``live``."""
     from ray_tpu.serve.engine import engine as module
     seen = []
@@ -96,9 +102,12 @@ def test_the_regions_say_where_a_chunk_lies(runs, monkeypatch):
         params=runs[16][2]._params)
     try:
         asyncio.run(_all(engine, PROMPTS[:2], 3))
+        counted = engine.stats()["prefill"]["attention"]
     finally:
         engine.close()
     chunks = [a for name, a in seen if name == "engine.prefill"]
+    assert {c["attention"] for c in chunks} == {"latent_chunk"}
+    assert counted == {"dense": 0, "flash": 0, "latent_chunk": 4}
     assert [(c["start"], c["width"], c["rung"], c["prompt_len"],
              c["padded_len"]) for c in chunks] == [
         (0, 16, 16, 16, 16), (16, 16, 16, 16, 16), (32, 5, 16, 5, 16),

@@ -4,7 +4,7 @@ attention's own query bottleneck, one LayerNormed key a position in a pool of
 its own, the first ``qk_rope_dim`` columns rotated), the ``index_topk``
 positions a query keeps (``ops/paged_attention.py``: a mask a query in a
 prompt's chunk, ``lax.top_k`` and a gather of the selected rows in the token
-step), a prompt in chunks over the sequence's pages (``_mla_blocked``), and
+step), a prompt in chunks over the sequence's pages (``_mla_chunk``), and
 experts chosen inside groups.  Small widths on the CPU in float32, against
 the benchmark's plain reference, which makes none of these the same way."""
 

@@ -168,7 +168,8 @@ def test_the_engine_says_what_each_prefill_ran(model, want, tmp_path):
                                 dtype=jnp.float32)}[model]
     stats, regions = engine_run(cfg, tmp_path)
     other = "dense" if want == "flash" else "flash"
-    assert stats["prefill"] == {"attention": {want: 3, other: 0}}
+    assert stats["prefill"] == {"attention": {want: 3, other: 0,
+                                              "latent_chunk": 0}}
     assert stats["prefill_shapes"] == {128: 2, 256: 1}
     assert [(r["padded_len"], r["attention"]) for r in regions] == \
         [(256, want), (128, want)]

@@ -4,8 +4,10 @@ compiled for a described v5e from shapes alone (ISSUE 57), beside
 4,096-wide chunk of a prompt fit the chip with the cell's two pools (latent
 pages and the indexer's keys), both pools come back in their arguments'
 buffers, the chunk makes neither the attention's ``[heads, S, S]`` scores nor
-the indexer's ``[heads, S, context]`` products whole, and the experts run as
-the grouped-matmul kernel."""
+the indexer's ``[heads, S, context]`` products whole, the experts run as
+the grouped-matmul kernel, and the chunk's attention over the pages as the
+kernel of ``ops/latent_prefill.py`` (ISSUE 58): no block of float32 scores
+and no whole-chunk accumulator is made in device memory."""
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,14 @@ from test_tpu_compile import (_fits, compiled_experts,  # noqa: F401
                               no_persistent_cache, topo)
 
 GIB = 1024 ** 3
+
+
+@pytest.fixture
+def compiled_chunk(monkeypatch):
+    """``latent_chunk_attention`` asks the backend whether its kernel is
+    compiled or interpreted; make it answer as on the chip."""
+    from ray_tpu.ops import latent_prefill
+    monkeypatch.setattr(latent_prefill, "_kernel_backend", lambda: True)
 
 
 @pytest.fixture(scope="module")
@@ -60,13 +70,14 @@ def compiled(topo, cell, program):
             donate_argnums=(3, 4)).lower(
                 params, arg(1, program), arg(), *pools, arg(1, maxp), arg(),
                 arg())
-    return params, pools, lowered.compile()
+    return params, pools, lowered, lowered.compile()
 
 
 @pytest.mark.parametrize("program", ["decode", 4096])
 def test_the_program_fits_and_keeps_both_pools_in_place(
-        topo, cell, compiled_experts, compiled_kernels, program):
-    params, pools, exe = compiled(topo, cell, program)
+        topo, cell, compiled_experts, compiled_kernels, compiled_chunk,
+        program):
+    params, pools, lowered, exe = compiled(topo, cell, program)
     weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
     held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
     assert abs(weights / 9.27e9 - 1) < 0.01      # 9.286: the router is f32
@@ -86,5 +97,19 @@ def test_the_program_fits_and_keeps_both_pools_in_place(
         assert made_of_shape(text, "bf16[16,17408,640]") == []
     else:
         assert made_of_shape(text, "f32[128,4096,4096]") == []
+        # the chunk's attention is one kernel: its scores, probabilities and
+        # accumulator stay in fast memory
+        assert "latent_chunk" in text
+        # and its module names no file, wherever the scalar arithmetic of
+        # its index maps was first traced (ops/kernel_source.py)
+        import base64
+        import re
+        modules = [base64.b64decode(body) for body in re.findall(
+            r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered.as_text())]
+        chunk = [m for m in modules if b"latent_chunk" in m]
+        assert chunk and not any(b".py" in m for m in chunk)
+        assert made_of_shape(text, "f32[128,512,1024]") == []
+        assert made_of_shape(text, "bf16[128,512,1024]") == []
+        assert made_of_shape(text, "f32[128,4096,128]") == []
         assert made_of_shape(text, "f32[4096,64,17408]") == []
         assert made_of_shape(text, "f32[64,4096,17408]") == []
