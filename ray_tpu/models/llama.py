@@ -168,8 +168,9 @@ import numpy as np
 
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
-from ray_tpu.models.gpt import (_cast_leaves, ce_head_loglike_sum,
-                                resolve_attention)
+from ray_tpu.models.gpt import _cast_leaves, ce_head_loglike_sum
+from ray_tpu.ops.attention import (_dense_causal_attention_bnsh,
+                                   _flash_attention_bnsh, resolve_attention)
 from ray_tpu.parallel.sharding import (LogicalAxisRules,
                                        with_logical_constraint)
 
@@ -1641,11 +1642,8 @@ def llama_hidden(params: Dict[str, Any], tokens: jax.Array,
     S = tokens.shape[1]
     if not cfg.kv_lora_rank and \
             resolve_attention(cfg.attention, S) == "flash":
-        from ray_tpu.models.gpt import _flash_attention_bnsh
         attn_fn = _flash_attention_bnsh(rules, mesh)
     else:
-        from ray_tpu.models.gpt import _dense_causal_attention_bnsh
-
         def attn_fn(q, k, v):
             return _dense_causal_attention_bnsh(q, k, v)
         attn_fn._gqa_native = True

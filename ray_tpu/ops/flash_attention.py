@@ -42,7 +42,7 @@ Grouped queries, forward only: k and v may have G heads for q's N = G * rep,
 and a query head reads its group's through the BlockSpec index map (``b //
 rep``), so nothing is repeated in memory.  That is the served prefill's call
 (``models/llama.py::llama_prefill`` on the rungs where
-``models/gpt.py::resolve_attention`` says flash); a gradient through it
+``ops/attention.py::resolve_attention`` says flash); a gradient through it
 raises.
 
 On the CPU backend the same kernels run in Pallas interpret mode, keeping
@@ -641,10 +641,7 @@ def flash_attention(q, k, v, causal: bool = True,
 
 def _resolve(q, block_q, block_k, interpret, layout):
     if interpret is None:
-        # The interpreter is for the CPU backend, where the tests run.  On
-        # any other backend the kernel is compiled, and a kernel that does
-        # not compile there is an error, not a slower run.
-        interpret = jax.default_backend() == "cpu"
+        interpret = not kernel_source.kernels_compiled()
     if block_q is None or block_k is None:
         S = q.shape[2 if layout == "bnsh" else 1]
         bq, bk = _default_blocks(S, strict=not interpret)

@@ -90,8 +90,7 @@ def _tiles(m: int, K: int, N: int, itemsize: int) -> tuple:
 
 def _resolve(m, K, N, itemsize, interpret):
     if interpret is None:
-        # The interpreter is for the CPU backend, where the tests run.
-        interpret = jax.default_backend() == "cpu"
+        interpret = not kernel_source.kernels_compiled()
     return (*_tiles(m, K, N, itemsize), interpret)
 
 

@@ -8,6 +8,9 @@ kernel again (PERF.md section 6, PR 41).  A kernel file calls
 ``exclude(__file__)`` once, so that its own frames are not "user code" to
 JAX, and makes its ``pallas_call`` under ``nowhere()``, which gives the
 kernel's operations a traceback that holds no other frames either.
+
+``kernels_compiled()`` is the one answer to "is this a backend the kernels
+are compiled for?": every kernel's ``_resolve`` and every chooser asks it.
 """
 
 from __future__ import annotations
@@ -15,6 +18,7 @@ from __future__ import annotations
 import functools
 import threading
 
+import jax
 from jax._src import source_info_util
 from jax._src.lib import xla_client
 
@@ -36,3 +40,12 @@ def _traceback():
 def nowhere():
     """The context a kernel file makes its ``pallas_call`` under."""
     return source_info_util.user_context(_traceback())
+
+
+def kernels_compiled() -> bool:
+    """Whether programs are being made for a backend the kernels are
+    compiled for: anything but the CPU.  The interpreter is for the CPU,
+    where the tests run; on any other backend a kernel that does not compile
+    is an error, not a slower run.  Asked of JAX at every call, never at
+    import: a tool describes another backend for the length of a run."""
+    return jax.default_backend() != "cpu"

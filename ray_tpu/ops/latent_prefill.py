@@ -61,6 +61,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import kernel_source
+# bound here by this name: a test replaces it on this module alone
+from ray_tpu.ops.kernel_source import kernels_compiled as _kernel_backend
 from ray_tpu.ops.paged_attention import block_size
 
 _LANES = 128
@@ -73,12 +75,6 @@ _VMEM_LIMIT_BYTES = 96 * 2 ** 20
 
 # The kernel's module names no file (kernel_source.py says why).
 kernel_source.exclude(__file__)
-
-
-def _kernel_backend() -> bool:
-    """Whether programs are being made for a backend the kernel is compiled
-    for: anything but the CPU, where it would run in the interpreter."""
-    return jax.default_backend() != "cpu"
 
 
 def _compiled(S: int, T: int, dn: int, dv: int) -> bool:

@@ -98,6 +98,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import linear_state
+# bound here by this name: a test replaces it on this module alone
+from ray_tpu.ops.kernel_source import kernels_compiled as _kernel_backend
 
 CHUNK = 64
 SUB_CHUNK = 16           # of a chunk, where the decay is a key channel's
@@ -472,12 +474,6 @@ def kda_step(q, k, v, g, beta, folded):
 # the module's own two steps: a caller that replaces one (a numerics tool
 # planting a fault) gets its step run, on every backend
 _OWN_STEPS = (gated_delta_step, kda_step)
-
-
-def _kernel_backend() -> bool:
-    """Whether programs are being made for a backend the kernel is compiled
-    for: anything but the CPU, where it would run in the interpreter."""
-    return jax.default_backend() != "cpu"
 
 
 def state_step_kind(pool, N: int, dv: int) -> str:

@@ -116,12 +116,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import paged_read
-
-
-def _kernel_backend() -> bool:
-    """Whether programs are being made for a backend the kernel is compiled
-    for: anything but the CPU, where it would run in the interpreter."""
-    return jax.default_backend() != "cpu"
+# bound here by this name: a test replaces it on this module alone
+from ray_tpu.ops.kernel_source import kernels_compiled as _kernel_backend
 
 
 def paged_read_kind(q, k_pages) -> str:

@@ -137,8 +137,7 @@ def _resolve(page, page_bytes, pages_per_block, interpret):
     positions, doubled until the copies of a block, ``page_bytes`` a page
     over all pools, are ``_BLOCK_BYTES``; whether to interpret)."""
     if interpret is None:
-        # The interpreter is for the CPU backend, where the tests run.
-        interpret = jax.default_backend() == "cpu"
+        interpret = not kernel_source.kernels_compiled()
     if not pages_per_block:
         pages_per_block = max(1, _BLOCK_TOKENS // page)
         while pages_per_block * page_bytes < _BLOCK_BYTES:

@@ -17,21 +17,15 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import logging
-import os
 import time
 from typing import Any, Dict, List, Optional
+
+from ray_tpu.serve import resilience
 
 logger = logging.getLogger(__name__)
 
 CONTROLLER_NAME = "_serve_controller"
 RECONCILE_PERIOD_S = 0.5
-
-
-def _env_f(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
 
 
 @dataclasses.dataclass
@@ -88,8 +82,6 @@ class Replica:
                              method: Optional[str] = None,
                              deadline: Optional[float] = None):
         import functools
-
-        from ray_tpu.serve import resilience
 
         async def _invoke():
             fn = self._resolve(
@@ -155,7 +147,6 @@ class Replica:
         decode with it) and re-checked here at every yield."""
         import functools
 
-        from ray_tpu.serve import resilience
         from ray_tpu.util import fault_injection
 
         def _check_deadline():
@@ -360,8 +351,7 @@ class ServeController:
                             drain_s: Optional[float] = None):
         """Drain then kill (reference: replica graceful shutdown —
         deployment_state waits for in-flight requests before stopping).
-        Bounded by ``RT_SERVE_DRAIN_S`` (poll cadence
-        ``RT_SERVE_DRAIN_POLL_S``): a wedged request must not block
+        Bounded by ``RT_SERVE_DRAIN_S``: a wedged request must not block
         scale-down forever.  Streams still live at the deadline are
         killed with the replica and complete through the ingress's
         mid-stream failover — counted as ``drain_handoffs`` and logged
@@ -369,8 +359,7 @@ class ServeController:
         forced ones.  Async kill: the blocking ray_tpu.kill would
         deadlock the actor loop this controller runs on."""
         if drain_s is None:
-            drain_s = _env_f("RT_SERVE_DRAIN_S", 10.0)
-        poll_s = max(0.01, _env_f("RT_SERVE_DRAIN_POLL_S", 0.1))
+            drain_s = resilience.env_f("RT_SERVE_DRAIN_S", 10.0)
         deadline = time.monotonic() + drain_s
         leftover = 0
         while True:
@@ -382,7 +371,7 @@ class ServeController:
                 break   # dead/unreachable: nothing to drain
             if leftover == 0 or time.monotonic() >= deadline:
                 break
-            await asyncio.sleep(poll_s)
+            await asyncio.sleep(0.1)
         if leftover:
             logger.warning(
                 "serve: drain_timeout — replica %s still had %d in-flight "

@@ -2,299 +2,103 @@
 
 Reference analogs: vLLM's LLMEngine/Scheduler (continuous batching,
 paged KV) and the reference repo's serve replicas; the model side is
-``models/gpt.py``/``models/llama.py``'s ``*_prefill``/``*_decode_step``
-paged entry points.
+``models/serving.py``'s record (``ServedModel``), asked once at construction:
+what is a model's is named nowhere here.
 
-The core loop is **iteration-level scheduling**: instead of batching
-whole requests (every sequence waits for the slowest), the engine admits
-and retires sequences *per decode step* — a new request joins the live
-batch at the next step boundary, a finished one frees its slot and pages
-immediately.  One replica therefore decodes up to ``max_batch``
-sequences per forward dispatch, each at its own position, with per-token
-results streamed to callers through per-sequence asyncio queues (the
-transport half — serve's ``handle_stream`` + ``num_returns="streaming"``
-— rides on those queues).
+The narrative is ARCHITECTURE.md's: "Continuous batching" (iteration-level
+scheduling, the prefill and decode rungs, the loop one decode step ahead of
+its own tokens, weights stored once), "The loop, kind of model by kind of
+model" (a block's pass, latent pages, rows a decode slot, what a paged read
+fetches, chunked prompts, an index pool, experts: what the loop does about
+each and which ``stats()`` keys and region attributes say so) and
+"Observability of the serving path" (the regions, a call's six boundaries
+on three clocks).  ``InferenceEngine.stats()``'s docstring has the keys.
+Below are the conditions the code relies on and does not show.
 
-Admission reserves the worst case ``ceil((prompt + max_new) / page)``
-pages up front (see kv_cache.py), so a sequence admitted is a sequence
-that finishes: the loop never preempts and never OOMs mid-decode.
-Prefill runs one sequence per dispatch (B=1), padded to its prompt's
-**rung**: the least of a short ladder of lengths that holds the prompt
-(``prefill_rungs``: 128, 256, 512, ... below ``max_prompt_len``, each rounded
-up to whole pages, and ``max_prompt_len`` itself on top).  The ladder is
-derived from ``max_prompt_len`` and ``page_size`` alone, nothing configures
-it, and a ``max_prompt_len`` of 128 or less has the one rung.  Padding lies
-after the prompt under a causal mask and its K/V go to scratch page 0, so a
-shorter rung gives the logits and pages of a longer one.  A rung's causal
-attention is the flash forward kernel where the model's own selector says so
-(``models/llama.py::llama_prefill_attention``: on the chip the rungs of 1024
-and more of a model with K/V pages and a causal mask; a CHUNK's latent
-attention over the pages is the kernel of ``ops/latent_prefill.py``,
-"latent_chunk"), else dense with its scores in memory;
-``rt:engine.prefill`` names which as ``attention`` and
-``stats()["prefill"]`` counts the prefills by it.  Every rung's
-program is compiled while the engine is constructed, a few at a time on
-threads of their own, and the loop admits nobody before all of them are
-there: no request meets a compile.  Decode runs the whole batch (fixed shape
-[max_batch]) with inactive slots parked on scratch page 0, against a page
-table as wide as the batch's longest live sequence needs and no wider:
-``[max_batch, W]`` with ``W`` the least rung of a second ladder
-(``decode_rungs``: multiples of ``ceil(maxp / 4)`` pages and ``maxp``, the
-reservation's width, on top) that holds the page of the largest ``pos``.
-That ladder follows from ``maxp`` alone; its programs are compiled beside
-the prefill rungs' and awaited with them.  A row keeps its pages in order, so its first ``W`` columns are the
-sequence's first ``W`` pages, and what a narrower table leaves out is
-positions that the step would have masked to a weight of exactly 0: a narrow
-rung gives the top rung's logits (to the rounding of a shorter sum) and
-pools.  A batch whose longest sequence fills its reservation takes the top
-rung.  Dispatches run on a single-thread executor so the actor's event loop
-keeps serving admissions and cancellations while XLA computes.
+**One exec lane.**  Every dispatch (a prefill, a decode step and the fetch
+behind it) runs on ONE single-thread executor, so the actor's event loop
+keeps serving admissions and cancellations while XLA computes, and the pools
+change in program order.  Between a donating dispatch and the loop taking
+the result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the lane
+may touch the pools.
 
-The loop runs **one decode step ahead of its own tokens**.  The decode
-program chooses every slot's next token itself (the ``argmax`` of its logits,
-a last result ``int32[max_batch]``), and the next step takes that array where
-it lies, on the device, as its ``token``: everything else the host knows
-without it (a position advances by one at dispatch, a page table does not
-change, an end by ``max_new`` is a count).  So an iteration hands the exec
-lane ONE call that dispatches step N+1 and starts its results' copy to the
-host, and then fetches step N's tokens, which ``_deliver`` streams while the
-device runs N+1: the per-token host round trip (dispatch, the copy down, two
-thread crossings, delivery, schedule) runs while the device does, and its
-queue is never empty between two steps of a settled batch.  At most one step
-is in flight (``_Step``); it remembers its own ``{slot: sequence}``, and a
-token goes to the sequence that was stepped, never to whoever holds the slot
-now.  A sequence whose ``generated`` and token in flight make ``max_new`` is
-left out of the next dispatch (its slot parked on page 0 like an empty one):
-no step is dispatched for a sequence whose last token is coming.  What the
-host cannot foresee (an ``eos_token``, a cancellation, a deadline) retires the
-sequence when the loop learns of it; the step already in flight still
-computes its slot, a **stray** slot step: its token is dropped, its K/V row
-lands inside the sequence's own reservation (it had not reached
-``max_new``), and the pools thread through every call, so a later prefill
-into the freed pages is ordered after it on the device.  The pipe **drains**
-(the step in flight is fetched and delivered with nothing queued behind it,
-the order every step had before) when the batch about to be stepped is not
-the batch in flight less those that end: an admission, whose prefill runs
-alone and whose first token the host has to see; the last token of a batch;
-the chaos hook's stall.  The step after a drain takes its tokens from the
-host.  A drain costs the device what every step cost it before, no more, and
-nothing configures any of this.  A failure surfaces at a dispatch or at the
+**Pools donated and taken back.**  Both programs carry the pools through
+their layer scan and the loop's two calls donate them, so a call's result
+pools are its argument's buffers and no copy of a pool, whole or a layer's,
+is made or held (``stats()["kv_pool_in_place"]`` says whether each program's
+first loop call did come back so).  Either pool may be a tree of arrays, and
+``_v_pages`` None (one pool): the loop hands them on, donates them and copies
+them as the trees they are and never looks inside.  Callers outside the
+loop, while it is idle, have two kinds of view of the same steps (the decode
+views' program is the loop's less its last result, the chosen tokens:
+``_decode_donating``, the same function under ``jax.jit``, beside the loop's
+``_decode_next_donating``).  ``_prefill_program`` / ``_decode_program`` hand
+them a copy of the pools they are given and never consume their arguments (a
+test, a tool that wants both).  The three-result ``_prefill`` / ``_decode``
+copy nothing, because a pool may be the largest thing on the chip (a looped
+model's is ``ut_steps`` times a plain one's) and then no second one fits:
+they CONSUME the pools they are given, and where those are the engine's own
+the engine keeps the result as its pools, so the names the caller gets back
+alias ``_k_pages`` / ``_v_pages`` and can go straight into the next such
+call.  A readiness check made of them holds one pool.
+
+**Page 0 is scratch.**  A prefill's padding (it lies after the prompt under
+a causal mask) writes its K/V there, and a decode step's inactive slots are
+parked there, so a shorter rung gives the logits and pages of a longer one
+and the decode shape is always ``[max_batch]``; the allocator never hands
+page 0 out.  A row of the page table keeps its pages in order, so the first
+``W`` columns of a narrower table (``decode_rungs``) are the sequence's first
+``W`` pages and what it leaves out is positions the step would have masked to
+a weight of exactly 0: a narrow rung gives the top rung's logits (to the
+rounding of a shorter sum) and pools.
+
+**A sequence writes a position before any step reads it.**  Admission
+reserves the worst case ``ceil((prompt + max_new) / page)`` pages up front
+(kv_cache.py), so a sequence admitted is a sequence that finishes: the loop
+never preempts and never runs out mid-decode.  What a readiness check, a
+stray step or a block's denoise pass leaves in pages is inside some
+sequence's own reservation or never read.  Rows a decode SLOT (the record's
+``slot_rows``) are allocated and freed by nobody: a prefill is told the slot
+its sequence will be stepped in and overwrites the rows whole, and the pools
+thread through every call, so that prefill is ordered after the last step
+that touched them.  A block model's page size is a multiple of its block, so
+a block lies in one page.
+
+**The pipe is drained before a prefill.**  At most one decode step is in
+flight (``_Step``); it remembers its own ``{slot: sequence}``, and a token
+goes to the sequence that was stepped, never to whoever holds the slot now.
+The loop drains (the step in flight is fetched and delivered with nothing
+queued behind it) when the batch about to be stepped is not the batch in
+flight less those that end: an admission, whose prefill runs alone and whose
+first token the host has to see; the last token of a batch; the chaos hook's
+stall.  The step after a drain takes its tokens (a block model: its blocks'
+state) from the host.  No step is dispatched for a sequence whose last token
+is coming.  What the host cannot foresee (an ``eos_token``, a cancellation, a
+deadline) retires the sequence when the loop learns of it; the step already
+in flight still computes its slot, a **stray** slot step: its token is
+dropped, its K/V row lands inside the sequence's own reservation, and a later
+prefill into the freed pages is ordered after it on the device.  With
+``prefill_chunk`` a prompt is a row of prefill calls back to back and ONE
+prompt a pass of the loop; the constructor refuses the field for a model
+whose record is not ``chunked``.  The host of a block model learns a step
+late which masks a pass lifted, but a block without masks is committed by the
+pass it is handed to, so it knows every ``pos0`` of the step it dispatches.
+
+**No request meets a compile.**  Every rung's program (``prefill_rungs``,
+``decode_rungs``: derived from ``max_prompt_len``, ``page_size`` and the
+reservation's width alone, nothing configures them) is compiled while the
+engine is constructed, ``_COMPILE_THREADS`` at a time on threads of their
+own, and the loop admits nobody before all of them are there.
+
+**What a failed step leaves.**  A failure surfaces at a dispatch or at the
 fetch of the step in flight: every live and waiting caller gets it once, the
-step in flight is dropped (its slots counted stray).
-
-What is a model's is asked of ``models/`` and named nowhere here:
-``models/serving.py::serving_model`` hands the constructor ONE record
-(``ServedModel``) for ``EngineConfig.model`` and ``model_config``, and the
-loop drives whatever it holds.  What a latent model's pages, an expert
-model's routing and a block model's pass ARE is ``models/llama.py``'s to
-say; below is what the loop does about each.
-
-A model whose ``step`` is a **block's pass** (the record's ``block`` = B > 0;
-``llama_block_step``, and ``block_unmask`` as its ``feed``) comes through the
-same loop, its decode program under the same name, and **a step yields 0 or up
-to B tokens a sequence**: none while its block has masks left (a denoise
-pass), the whole block when the step was handed it without any (the commit
-pass).  What feeds back on the device from step N to N+1 is the blocks' state
-(``tokens [max_batch, B]``, the boolean ``masked``, ``pos0``, the block's
-passes so far) where the one-token step has ``token``; the host sends the page
-table and each slot's ``end`` (the position its last block ends at; 0 parks
-the slot on page 0).  Every pass writes its block's K/V into the sequence's
-OWN reserved positions, so "a sequence writes a position before any step reads
-it" holds and one program serves a batch in which some slots denoise and some
-commit.  The host fetches step N's ``(committed, emitted, state)`` while N+1
-runs and keeps each sequence's block as of the last step it fetched
-(``_Sequence.block``, ``.masked``, ``.pos``, ``.passes``): **it learns a step
-late which masks a pass lifted**, but a block without masks is committed by
-the pass it is handed to, so the host knows every ``pos0`` of the step it
-dispatches (the table's width, ``live_tokens``) and that a sequence's last
-block is being committed by the step in flight (no step is dispatched behind
-it).  A committed block reaches its caller as tokens of its own, in order:
-those at the positions the request asked for (the first block begins with the
-prompt's ``len % B`` trailing tokens, which the prefill leaves out; the last
-may end past ``max_new``: the dropped tail), as far as an ``eos_token``.  An
-admission drains the pipe as it always did, and the step after a drain takes
-the host's copy of the state.  ``stats()["block"]`` counts the slot steps by
-kind, the blocks by the passes they took and the tokens committed, dropped and
-unmasked by either rule; ``rt:engine.decode.dispatch`` carries ``block_len``
-beside ``live_tokens`` (positions held: what is committed and the block), and
-the ``rt:engine.deliver`` of a fetched step ``tokens``, ``dropped_tail``,
-``dropped_stray``, ``denoise_slots`` and ``commit_slots``: on the delivery and
-not the dispatch, because which slots commit is known when the step is
-fetched.  The page size is a multiple of B, so a block lies in one page.
+step in flight is dropped (its slots counted stray), and since a call that
+fails after it was given the pools may have consumed them, the loop makes
+fresh pools (no live sequence is left to own a page).
 
 The parameters are stored once in the dtype the two programs read them in
-(the record's ``stored``: ``gpt_serving_params`` / ``llama_serving_params``,
-which say leaf by leaf what is cast and what stays f32), so no step casts a
-weight again and the same bits come out as from the caller's tree.  The
-engine keeps no reference to that tree: once the caller drops it, a bf16
-engine holds half the bytes (``stats()["weight_bytes"]``).
-
-The KV pools are updated in place: both programs carry them through their
-layer scan (``models/``: a step scatters one token a sequence into the whole
-pool and gathers from it) and the loop's two calls donate them, so a call's
-result pools are its argument's buffers and no copy of a pool, whole or a
-layer's, is made or held.  Between a donating dispatch and the loop taking the
-result, ``_k_pages`` / ``_v_pages`` name deleted arrays: only the single exec
-lane may touch the pools.  Callers outside the loop, while it is idle, have
-two kinds of view of the same steps (the decode views' program is the loop's
-less its last result, the chosen tokens: ``_decode_donating``, the same
-function under ``jax.jit``, beside the loop's ``_decode_next_donating``).
-``_prefill_program`` / ``_decode_program`` hand them a copy of the pools they
-are given and never consume their arguments (a test, a tool that wants both).
-The three-result ``_prefill`` / ``_decode`` copy nothing, because a pool may
-be the largest thing on the chip (a looped model's is ``ut_steps`` times a
-plain one's) and then no second one fits: they CONSUME the pools they are
-given, and where those are the engine's own the engine keeps the result as its
-pools, so the names the caller gets back alias ``_k_pages`` / ``_v_pages`` and
-can go straight into the next such call.  A readiness check made of them holds
-one pool.  What it leaves in the pages it used is never read: a sequence
-writes a position before any step reads it.  A call that fails after it was
-given the pools costs the live sequences an error, and the loop makes fresh
-pools (no live sequence is left to own a page).  ``stats()`` says whether each
-program's first loop call did come back in its argument's buffers
-(``kv_pool_in_place``).
-
-A model's pages may be of another kind (a latent model's: ONE pool, and
-``_v_pages`` is None).  The kind follows from the model (the record's
-``new_pools``), nothing configures it; the programs take and return the pair
-of pools either way, so the loop, the donation, the views and the counters
-below are the same code for both kinds.  ``stats()["kv_page_kind"]`` says
-which (the record's ``page_kind``; a model may keep rows a decode slot
-beside latent pages, and then ``_v_pages`` holds those rows and no V pool).
-
-Either pool may be a TREE of arrays, and some of a model's arrays may hold
-**a row a decode slot** instead of pages (the record's ``slot_rows``; a
-recurrent layer's state, which does not grow with the sequence, or a short
-convolution's last inputs: a model of conv layers keeps those tails alone and
-no state matrix, the record's ``conv_tails`` says which array they are).  The loop
-hands them on, donates them and copies them as the trees they are and never
-looks inside.  What it does for such a model: the pools are made for
-``max_batch`` slots, and a prefill is told the slot its sequence will be
-stepped in, so that it leaves the rows there as they stand after the prompt's
-last real position, whatever its rung; a decode step's row ``b`` is slot
-``b``.  Nothing allocates or frees a slot's rows: the next prefill into the
-slot overwrites them whole, and the pools thread through every call, so that
-prefill is ordered after the last step that touched them.  The page
-reservation counts pages as before, which are then only some layers'.
-``stats()`` has the rows' bytes apart from the pages'
-(``recurrent_state_bytes``, of which ``conv_tail_bytes`` are the
-convolutions' tails and ``recurrent_matrix_bytes`` the state matrices;
-``kv_pool_bytes`` and ``kv_bytes_per_token``
-are the pages' alone), the prefills that wrote a slot's rows
-(``state_rows_written``) and the rows' share in what the decode steps read
-and wrote (``recurrent_step_bytes_share``).
-
-What a decode step's paged read FETCHES depends on what its program was
-compiled with (the record's ``paged_read``, asked once at construction:
-``ops/paged_attention.py::paged_read_kind``).  "gather" (a block model's
-read, GPT-2's equal heads of 64, every model on the CPU):
-every page of the table the step is given, for every slot, once per pool
-layer: the step's rung, bounded by the longest live sequence and not by
-each.  "kernel" (the token step's K/V or latent pages on the chip): each
-slot's own pages as far as its position, a parked slot's one page of page
-0; the rung then bounds only what the kernel is told, not what it reads.
-``stats()``
-counts both sides: ``kv_live_token_steps`` (positions the live sequences
-held, summed over decode steps) against ``kv_gathered_token_steps`` (what
-the steps fetched: ``max_batch x W x page_size`` a step under the gather,
-``W`` the step's rung; every slot's positions rounded up to whole pages
-under the kernel), with ``kv_bytes_per_token`` and ``kv_pool_layers`` to
-turn either into bytes, ``decode_shapes``, the steps by rung, and
-``decode["paged_read"]``, the steps by what read their pages; each
-``rt:engine.decode.dispatch`` carries its step's numbers as ``live_tokens``,
-``gathered_tokens``, ``width_pages`` and ``paged_read``.
-
-What a decode step steps a linear layer's states with is likewise its
-program's (``ops/linear_attention.py::state_step_kind``, asked once at
-construction): "kernel" on the chip (``ops/linear_state.py``: a slot's rows
-read once and written where they lie), "rule" on the CPU (the jnp step on
-the layer's slab).  ``stats()["decode"]["linear_state"]`` counts the steps
-by which, and each ``rt:engine.decode.dispatch`` of such a model carries it
-as ``linear_state``.
-
-Observability: every synchronous section of the per-token path is a
-``tracing.region`` (``rt:engine.schedule``, ``.prefill``,
-``.decode.dispatch``, ``.decode.fetch``, ``.deliver``), visible in a JAX
-profile beside the device's programs (``LLMServer.profile``); the two thread
-crossings of a call ride as the ``submit_us`` and ``resume_us`` attributes of
-the region that follows them.  A call of the exec lane is a decode step's
-dispatch and, behind it, the fetch of the step before (``ahead`` 1 on the
-``.decode.dispatch``), a dispatch alone on a drained pipe (``ahead`` 0), a
-drain's fetch alone, or a prefill; each ends in a ``.deliver``, one that
-fetched nothing with no tokens.  A call's six boundaries (submitted on the
-loop thread; dispatch start, dispatch end and returned on the exec lane;
-``_deliver``'s entry and exit on the loop thread again) are each read on up to
-three clocks (``_Clocks``: wall, the reading thread's CPU, the loop thread's
-CPU), and that one set of reads feeds both the regions' attributes
-(``tracing``'s module docstring has the convention: ``dispatch_us`` /
-``dispatch_cpu_us`` / ``dispatch_loop_cpu_us`` on ``.decode.fetch``: the
-dispatch phases that ended on the lane since the fetch before, step N+1's
-beside step N's fetch, 0 on a drain's; ``fetch_loop_cpu_us`` and
-``resume_loop_cpu_us`` on ``.deliver``, ``step_us`` / ``step_loop_cpu_us`` on
-``.decode.dispatch``) and the always-on sums ``stats()["host_s"]`` /
-``["host_cpu_s"]``: wall less the exec lane's own CPU is what it spent not
-running (the GIL, a lock of the runtime), and the loop thread's CPU in the
-same interval says whether the loop ran against it.  The wall is read at every
-boundary of every call; the CPU clocks between a call's submission and its
-delivery are system calls, read for every call while a profiler session
-records and for one call in ``_CPU_EVERY`` otherwise.  The collector's passes
-are ``rt:gc`` regions and ``stats()["gc"]`` (``tracing.watch_gc``).  What the
-loop thread did BESIDE the engine between two submissions (the streams'
-fan-out, the transport's frames: ``_BESIDE``) rides on the ``.decode.dispatch``
-as the growth of ``tracing``'s always-on sums, ``stats()["stream"]`` /
-``["rpc"]``.
-``stats()`` carries the always-on counters of the same places,
-``decode_ahead_steps`` and ``stray_slot_steps`` among them.
-
-A prompt may run as CHUNKS (``EngineConfig.prefill_chunk`` > 0; the record's
-``chunked`` says the model's prefill takes a ``start`` and reads what lies
-before it from the pages; the constructor refuses the field for any other: a
-recurrent state or a convolution's tail is not addressable by position).  The
-prefill ladder then ends at the chunk's width, and a prompt of n positions is
-``ceil(n / prefill_chunk)`` calls of the exec lane, back to back with the pipe
-drained as for any prefill (and ONE prompt a pass of the loop: between two
-prompts the live batch takes a step, where an engine without chunks prefills
-every admission before it steps again): each a chunk of at most ``prefill_chunk``
-positions padded to its rung, told its ``start`` (a traced argument: one
-program a rung wherever the chunk lies), writing its rows to the sequence's
-own pages and attending over them; the last call's token is the sequence's
-first.  Each call is an ``rt:engine.prefill`` of its own whose ``prompt_len``
-is the CHUNK's real positions, with ``start``, ``width`` and ``rung`` beside
-``padded_len``, and every chunk of a prompt carries the wait its first one
-found (``waited_us``); ``stats()`` counts the calls and their positions
-(``prefill_chunks``, ``prefill_chunk_tokens``; ``prefill_tokens`` and
-``admitted`` count prompts as before).  With ``prefill_chunk`` 0 nothing of
-this runs and every program, region and counter is what it was.
-
-A model may keep a SECOND pool a position beside its pages (the record's
-``index_pool``: a sparse-attention indexer's keys, in the V pool's place, one
-page table for both): the loop makes, donates and threads it as it does any
-pool and looks inside neither; ``stats()["index_pool_bytes"]`` is its share
-of ``kv_pool_bytes``.  Such a model's decode step reads at most
-``select_topk`` positions a sequence (the record's): the host, which knows
-every position, says on each ``rt:engine.decode.dispatch`` how many the step's
-sequences held (``live``) and how many their reads kept (``selected``:
-``min(select_topk, pos + 1)`` each), and sums them in ``stats()``
-(``dsa_live_positions``, ``dsa_selected_positions``).
-
-A model with experts (a ``LlamaConfig`` with ``num_experts``) comes through
-``model="llama"`` like any other.  Its two programs return a fourth result,
-the live tokens' assignments per layer and expert; it reaches the host with
-the step's tokens, and what it says of the step (assignments, distinct experts
-touched summed over layers, the largest single-expert load summed over layers)
-is the attributes of an ``rt:engine.decode.moe`` or ``rt:engine.prefill.moe``
-region, beside the ``weight_itemsize`` the experts are stored in (what a step
-reads of a touched expert, for a roofline), and adds to the ``moe_*`` counters
-of ``stats()``.  The experts are those the program HOLDS: where that is a
-share of what the router scores (one chip's part of a layer divided over
-several), ``assignments`` are those that fell on a held expert and
-``assignments_made`` every real token's, and ``stats()["moe_load"]`` is the
-held experts' load by layer.  ``rows_offered`` beside them counts the
-assignments of every row the program's shape offered, padding and idle slots
-too: those rows are routed to no expert (``ops/moe.py::moe_dropless``'s
-``live``), so ``assignments_made / rows_offered`` is the share of the rows
-that the experts' products multiplied.  A dense model's programs return
-three results and none of this runs."""
+(the record's ``stored``), and the engine keeps no reference to the caller's
+tree (``stats()["weight_bytes"]``)."""
 
 from __future__ import annotations
 

@@ -43,7 +43,7 @@ pages.
   stream bumps the ``streams_resumed`` counter.
 
 * *Circuit breaking + bounded retry.*  Per-replica consecutive-failure
-  breakers (``RT_SERVE_CB_THRESHOLD``/``RT_SERVE_CB_COOLDOWN_S``) eject
+  breakers (three failures open one, five seconds cool it) eject
   failing replicas from routing with half-open probe re-admission; every
   request carries a retry budget (``RT_SERVE_RETRY_BUDGET``) spent on
   exponential-backoff-with-jitter re-sends (``router_retries`` counter).
@@ -77,12 +77,12 @@ from ray_tpu._private.async_utils import spawn
 import itertools
 import json
 import logging
-import os
 import time
 from typing import Dict, Optional, Tuple
 
 from ray_tpu.serve import metrics as serve_metrics
 from ray_tpu.serve import resilience
+from ray_tpu.serve.resilience import env_f
 
 logger = logging.getLogger(__name__)
 
@@ -94,13 +94,6 @@ async def _materialize(item):
     if isinstance(item, ObjectRef):
         return await item
     return item
-
-
-def _env_f(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
 
 
 class _BadRequest(Exception):
@@ -117,8 +110,6 @@ class HTTPIngress:
     def __init__(self, host: str = "127.0.0.1", port: int = 0,
                  namespace: str = "default", *,
                  max_connections: Optional[int] = None,
-                 max_body_bytes: Optional[int] = None,
-                 read_timeout_s: Optional[float] = None,
                  write_timeout_s: Optional[float] = None,
                  stream_idle_timeout_s: Optional[float] = None,
                  stall_timeout_s: Optional[float] = None):
@@ -141,22 +132,19 @@ class HTTPIngress:
         self._ctrl_retry_at = 0.0         # monotonic gate
         self._ctrl_reresolves = 0         # successful re-resolves (stats)
         self._max_conn = int(max_connections if max_connections is not None
-                             else _env_f("RT_SERVE_MAX_CONNECTIONS", 256))
-        self._max_body = int(max_body_bytes if max_body_bytes is not None
-                             else _env_f("RT_SERVE_MAX_BODY_BYTES",
-                                         10 * 1024 * 1024))
-        self._read_timeout = (read_timeout_s if read_timeout_s is not None
-                              else _env_f("RT_SERVE_READ_TIMEOUT_S", 120.0))
+                             else env_f("RT_SERVE_MAX_CONNECTIONS", 256))
+        self._max_body = 10 * 1024 * 1024
+        self._read_timeout = 120.0
         self._write_timeout = (write_timeout_s
                                if write_timeout_s is not None
-                               else _env_f("RT_SERVE_WRITE_TIMEOUT_S", 30.0))
+                               else env_f("RT_SERVE_WRITE_TIMEOUT_S", 30.0))
         self._stream_idle = (stream_idle_timeout_s
                              if stream_idle_timeout_s is not None
-                             else _env_f("RT_SERVE_STREAM_IDLE_S", 120.0))
+                             else env_f("RT_SERVE_STREAM_IDLE_S", 120.0))
         # A stream quiet past this long is treated as a stalled replica
         # and failed over (vs. _stream_idle, which is the terminal bound).
         self._stall_s = (stall_timeout_s if stall_timeout_s is not None
-                         else _env_f("RT_SERVE_STALL_S", 30.0))
+                         else env_f("RT_SERVE_STALL_S", 30.0))
 
     async def _ensure_started(self):
         if self._server is not None:

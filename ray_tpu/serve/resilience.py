@@ -67,7 +67,7 @@ class DecodeStalled(Exception):
     is nominally alive."""
 
 
-def _env_f(name: str, default: float) -> float:
+def env_f(name: str, default: float) -> float:
     try:
         return float(os.environ.get(name, default))
     except ValueError:
@@ -174,13 +174,10 @@ class CircuitBreaker:
     from one event loop; ``DeploymentHandle`` wraps calls in its own
     lock."""
 
-    def __init__(self, threshold: Optional[int] = None,
-                 cooldown_s: Optional[float] = None,
+    def __init__(self, threshold: int = 3, cooldown_s: float = 5.0,
                  on_open=None):
-        self.threshold = int(threshold if threshold is not None
-                             else _env_f("RT_SERVE_CB_THRESHOLD", 3))
-        self.cooldown_s = (cooldown_s if cooldown_s is not None
-                           else _env_f("RT_SERVE_CB_COOLDOWN_S", 5.0))
+        self.threshold = threshold
+        self.cooldown_s = cooldown_s
         self._breakers: Dict[str, _Breaker] = {}
         self._on_open = on_open          # callback(replica_id) on ejection
 
@@ -292,14 +289,11 @@ class RetryPolicy:
     construction is cheap."""
 
     def __init__(self, budget: Optional[int] = None,
-                 base_s: Optional[float] = None,
-                 cap_s: Optional[float] = None):
+                 base_s: float = 0.05, cap_s: float = 2.0):
         self.budget = int(budget if budget is not None
-                          else _env_f("RT_SERVE_RETRY_BUDGET", 3))
-        self.base_s = (base_s if base_s is not None
-                       else _env_f("RT_SERVE_RETRY_BASE_S", 0.05))
-        self.cap_s = (cap_s if cap_s is not None
-                      else _env_f("RT_SERVE_RETRY_CAP_S", 2.0))
+                          else env_f("RT_SERVE_RETRY_BUDGET", 3))
+        self.base_s = base_s
+        self.cap_s = cap_s
         self.attempts = 0
 
     def can_retry(self) -> bool:

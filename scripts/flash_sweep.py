@@ -19,7 +19,7 @@ prefill runs where the kernel is not chosen
 ``--kv-heads`` k and v heads shared by the ``--heads`` query heads of one
 sequence: ``--seq 512 1024 2048 --heads 32 --kv-heads 8 --head-size 128
 --fwd-only`` is Mistral's prefill attention at three rungs, ``--heads 16
---kv-heads 16`` OLMoE's: the crossover ``models/gpt.py::_flash_profitable``
+--kv-heads 16`` OLMoE's: the crossover ``ops/attention.py::_flash_profitable``
 stands for.  (Grouped heads need a tree from PR 46 on, and have no backward
 kernels.)  For each edge given (default: the
 table's own) the module's ``_SUB_TILE`` is rebound, which is how a sweep

@@ -16,7 +16,8 @@ family's ``program_config``, the stored tree's and the pools' shapes from
 ``jax.eval_shape``, every program is lowered with the pools donated for a
 DESCRIBED ``v5e:2x2`` device, and ``jax.default_backend()`` answers "tpu" to
 this repo's code for the length of the run, so that what asks it
-(``models/gpt.py::_flash_profitable``, the two kernels' ``_resolve``,
+(``ops/kernel_source.py::kernels_compiled``, and through it
+``ops/attention.py::_flash_profitable``, the kernels' ``_resolve`` and
 ``ops/latent_prefill.py::_compiled``) decides
 as on the chip: Mistral's prefill rungs of 1024 and 2048 hold the flash
 forward kernel, the expert models' programs the grouped matmul, both as

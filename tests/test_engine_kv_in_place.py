@@ -285,12 +285,21 @@ def test_a_failed_call_costs_its_requests_and_leaves_fresh_pools(
         engine.close()
 
 
-def pools_alive(engine):
-    """Live arrays of the engine's pool shape: two is one K and one V."""
-    kp = engine._k_pages
+def alive():
+    """Every live array of this process by ``id``, and held: no id here is
+    given out again while the result is."""
     gc.collect()                # engines of earlier tests, in cycles
+    return {id(a): a for a in jax.live_arrays()}
+
+
+def pools_alive(engine, earlier):
+    """Live arrays of the engine's pool shape: two is one K and one V.
+    ``earlier`` is ``alive()`` from before the test built its engines, and
+    is left out: an earlier test's engine that something in this process
+    still holds (the worker ran other engines first) is not this test's."""
+    kp = engine._k_pages
     return sum(a.shape == kp.shape and a.dtype == kp.dtype
-               for a in jax.live_arrays())
+               for i, a in alive().items() if i not in earlier)
 
 
 @pytest.mark.parametrize("family", ["llama-dense", "llama-experts"])
@@ -300,22 +309,24 @@ def test_a_check_made_of_the_consuming_views_holds_one_pool(family):
     of it again for a second sequence.  The engine's pools are replaced by
     each result (the caller's names alias them), nothing is copied, and the
     engine serves afterwards as one that was never checked."""
+    earlier = alive()
     _, engine = build(family)
     _, fresh = build(family)
     try:
         want = serve(fresh, [3, 1, 4, 1, 5])
         fresh.close()
         del fresh
-        assert pools_alive(engine) == 2
+        assert pools_alive(engine, earlier) == 2
         for _ in range(2):
             params, tokens, length, _, _, row = program_args(
                 engine, "prefill", engine._params)
-            assert pools_alive(engine) == 2      # program_args' copy is gone
+            # program_args' copy is gone
+            assert pools_alive(engine, earlier) == 2
             before = engine._k_pages
             logits, kp, vp = engine._prefill(
                 params, tokens, length, engine._k_pages, engine._v_pages,
                 row)
-            assert before.is_deleted() and pools_alive(engine) == 2
+            assert before.is_deleted() and pools_alive(engine, earlier) == 2
             assert kp is engine._k_pages and vp is engine._v_pages
             table = np.zeros((BATCH, engine._maxp), np.int32)
             table[0] = row[0]
@@ -325,7 +336,8 @@ def test_a_check_made_of_the_consuming_views_holds_one_pool(family):
                 before = kp
                 logits, kp, vp = engine._decode(params, token, pos, kp, vp,
                                                 table)
-                assert before.is_deleted() and pools_alive(engine) == 2
+                assert before.is_deleted()
+                assert pools_alive(engine, earlier) == 2
                 assert kp is engine._k_pages and vp is engine._v_pages
                 assert len((logits, kp, vp)) == 3
             del kp, vp, before

@@ -140,7 +140,7 @@ def test_llama_sharded_matches_single_device():
 def test_gqa_grouped_matches_repeat_path():
     """The repeat-free grouped dense attention must equal the
     materialized-repeat formulation exactly."""
-    from ray_tpu.models.gpt import _dense_causal_attention_bnsh
+    from ray_tpu.ops.attention import _dense_causal_attention_bnsh
     from ray_tpu.models.llama import _dense_causal_attention_gqa
     rng = jax.random.PRNGKey(0)
     kq, kk, kv = jax.random.split(rng, 3)

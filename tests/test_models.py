@@ -160,7 +160,7 @@ def test_mlp_trains():
 def test_attention_auto_dispatch(attention, S, backend, want, monkeypatch):
     """The one function that picks the attention variant: "auto" by S and
     the backend, a pinned value as it is."""
-    from ray_tpu.models.gpt import resolve_attention
+    from ray_tpu.ops.attention import resolve_attention
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert resolve_attention(attention, S) == want
 

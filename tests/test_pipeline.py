@@ -12,8 +12,8 @@ import pytest
 
 from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
 from ray_tpu.parallel.mesh import MeshSpec
-from ray_tpu.parallel.pipeline import (gpt_loss_pipelined,
-                                       make_pipeline_train_step)
+from ray_tpu.models.gpt_pipeline import (gpt_loss_pipelined,
+                                        make_pipeline_train_step)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
@@ -158,7 +158,7 @@ def test_pipeline_moe_ep_trains():
 def test_1f1b_loss_and_grad_parity():
     """The hand-scheduled 1F1B backward must match autodiff numerics
     (VERDICT r3 #6): loss vs gpt_loss and grads vs jax.grad, in f32."""
-    from ray_tpu.parallel.pipeline import gpt_loss_1f1b
+    from ray_tpu.models.gpt_pipeline import gpt_loss_1f1b
     mesh, cfg, params, batch = _setup()
     M = 4   # microbatch size 16/M must stay divisible by dp=4
     ref = float(gpt_loss(params, batch, cfg))
@@ -183,8 +183,8 @@ def test_1f1b_trains_and_memory_win():
     must still reduce the loss."""
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ray_tpu.parallel.pipeline import (make_1f1b_train_step,
-                                           make_pipeline_train_step)
+    from ray_tpu.models.gpt_pipeline import (make_1f1b_train_step,
+                                            make_pipeline_train_step)
     mesh = MeshSpec(dp=2, pp=2).build()
     cfg = GPTConfig(vocab_size=128, max_seq_len=32, num_layers=4,
                     num_heads=2, embed_dim=32, dtype=jnp.float32)
@@ -246,7 +246,7 @@ def test_1f1b_bf16_default_dtype_grads():
     hand back a bf16 x_mbs cotangent or jax rejects the rule (regression
     for an f32-only bug — every other pipeline test pins f32)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ray_tpu.parallel.pipeline import gpt_loss_1f1b
+    from ray_tpu.models.gpt_pipeline import gpt_loss_1f1b
     mesh = MeshSpec(dp=2, pp=2).build()
     cfg = GPTConfig(vocab_size=128, max_seq_len=32, num_layers=4,
                     num_heads=2, embed_dim=32)   # default dtype = bf16
@@ -273,7 +273,7 @@ def test_pipeline_auto_attention_is_gpt_hiddens(S, monkeypatch):
     gpt_hidden takes at the batch's own length: one rule, asked with S (not
     max_seq_len: flash at 2048 does not make 1100 divisible)."""
     import ray_tpu.models.gpt as gpt
-    import ray_tpu.parallel.pipeline as pipeline
+    import ray_tpu.models.gpt_pipeline as pipeline
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = GPTConfig(vocab_size=128, max_seq_len=2048, num_layers=2,
                     num_heads=2, embed_dim=32, attention="auto")

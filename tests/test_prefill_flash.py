@@ -2,11 +2,11 @@
 (``models/llama.py::llama_prefill``), at tiny widths on the CPU, the kernel
 interpreted.
 
-One selector decides (``llama_prefill_attention``: ``resolve_attention`` of
-the config's ``attention`` and the padded rung, dense for a block model and a
-latent one whatever the rung); where it says flash the prefill gives the
-logits and writes the pages the dense function gives, a padded tail and
-grouped k and v included; and the engine says of every prefill which of the
+One selector decides (``llama_prefill_attention``:
+``ops/attention.py::resolve_attention`` of the config's ``attention`` and the
+padded rung, dense for a block model and a latent one whatever the rung);
+where it says flash the prefill gives the logits and writes the pages the
+dense function gives, a padded tail and grouped k and v included; and the engine says of every prefill which of the
 two its rung ran, on ``rt:engine.prefill`` and in ``stats()``.
 """
 
@@ -85,7 +85,7 @@ def test_flash_prefill_gives_the_dense_logits_and_pages(model, S, length,
 
 
 def test_auto_is_what_resolve_attention_says_of_the_rung(monkeypatch):
-    """One selector: ``auto`` follows ``models/gpt.py::resolve_attention``
+    """One selector: ``auto`` follows ``ops/attention.py::resolve_attention``
     rung by rung (dense on this backend, whatever the rung), a pin is the
     pin."""
     assert [llama_prefill_attention(BASE, S) for S in (512, 1024, 2048)] == \

@@ -239,6 +239,18 @@ def _arrived(core, oid_hex, timeout=60):
         time.sleep(0.005)
 
 
+def _told_once_closed(producer, timeout=60):
+    """``told()`` once the body's ``finally`` has run.  A cancel ends the
+    stream's turn on the producer before the cancelled body is closed, so a
+    call right behind it can be answered in between: ask until ``closed``."""
+    deadline = time.monotonic() + timeout
+    while True:
+        told = ray_tpu.get(producer.told.remote(), timeout=60)
+        if told[2] or time.monotonic() > deadline:
+            return told
+        time.sleep(0.005)
+
+
 def test_the_producer_counts_and_borrows_none_of_its_yields(cluster,
                                                             tmp_path):
     producer = Producer.remote()
@@ -315,7 +327,7 @@ def test_a_cancelled_stream_runs_the_bodys_finally_and_leaves_nothing_owned(
     _arrived(core, ids[4])        # three more wait on the stream's queue
     gen.cancel()
     gate.touch()
-    held, _, closed = ray_tpu.get(producer.told.remote(), timeout=60)
+    held, _, closed = _told_once_closed(producer)
     assert closed and 4 <= len(held) < N
     del taken, gen
     _settled()

@@ -520,8 +520,8 @@ def test_mistral_prefill_compiles_at_every_lower_rung(topo, rung):
 @pytest.mark.parametrize("rung", [1024, 2048])
 def test_mistral_prefill_runs_the_flash_kernel_on_its_long_rungs(
         topo, compiled_kernels, rung):
-    """On the chip ``resolve_attention`` gives the rungs of 1024 and 2048
-    the flash forward kernel (here the backend is the CPU, so the test pins
+    """On the chip ``ops/attention.py::resolve_attention`` gives the rungs
+    of 1024 and 2048 the flash forward kernel (here the backend is the CPU, so the test pins
     it): one kernel in the layer scan, grouped k and v read where they lie
     (no [1, 32, S, 128] copy of them), no [S, S] scores in memory, which
     were 0.5 GiB of the dense program's temporaries at 2048; and the
