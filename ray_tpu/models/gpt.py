@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import operator
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -23,6 +24,8 @@ import numpy as np
 
 from ray_tpu.ops.attention import (_dense_causal_attention_bnsh,
                                    _flash_attention_bnsh, resolve_attention)
+from ray_tpu.parallel.collectives import (add_bias_first, gathered_einsum,
+                                          scattered_einsum, tp_size)
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
 
@@ -180,6 +183,15 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
+def _tp_mesh(rules: Optional[LogicalAxisRules]):
+    """The current mesh where it splits a layer over ``tp`` and the caller
+    shards by ``rules``, else None: what decides the block's form."""
+    if rules is None:
+        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if tp_size(mesh) > 1 else None
+
+
 def _block(cfg: GPTConfig, rules: Optional[LogicalAxisRules],
            attn_fn: Callable, x, layer_params, moe_ep_axis=None):
     """One transformer block. `layer_params` has the [L] dim already sliced.
@@ -188,9 +200,28 @@ def _block(cfg: GPTConfig, rules: Optional[LogicalAxisRules],
     (0.0 for a dense FFN) so the scan over layers can accumulate it.
     ``moe_ep_axis`` switches the MoE to its shard_map expert-parallel mode
     (weights pre-sharded on the expert dim; see ops/moe.py).
+
+    Where the current mesh has ``tp > 1`` the residual stream ``x`` comes
+    and goes sharded along the sequence over ("sp", "tp") (``res_seq``):
+    the norms, bias adds and residual adds work on a device's own rows, each
+    pair's first product gathers the rows of tp and its last scatters the
+    partial sums, in chunks that travel while the products run
+    (``parallel/collectives.py``).  With no ``tp`` the same einsums stand
+    alone and the compiler shards them.
     """
     lc = (lambda a, ax: with_logical_constraint(a, rules, ax)) if rules \
         else (lambda a, ax: a)
+    ring = _tp_mesh(rules)
+    if ring is None:
+        first = last = lambda eq, a, w, shard, by_step=False: \
+            jnp.einsum(eq, a, w)
+        plus = operator.add
+        res = ("batch", "seq", "embed")
+    else:
+        first = functools.partial(gathered_einsum, mesh=ring)
+        last = functools.partial(scattered_einsum, mesh=ring)
+        plus = add_bias_first
+        res = ("batch", "res_seq", "embed")
     p = layer_params
     dt = cfg.dtype
 
@@ -199,23 +230,26 @@ def _block(cfg: GPTConfig, rules: Optional[LogicalAxisRules],
         # Head-major attention path: the qkv projection WRITES [B,N,S,H]
         # (layout picked in the matmul epilogue, nearly free) so the flash
         # kernels get their native view with zero standalone relayouts.
-        qkv = jnp.einsum("bsd,dcnh->bcnsh", h, p["attn"]["wqkv"].astype(dt))
+        qkv = first("bsd,dcnh->bcnsh", h, p["attn"]["wqkv"].astype(dt), "n")
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         q = lc(q, ("batch", "heads", "seq", "kv"))
         k = lc(k, ("batch", "heads", "seq", "kv"))
         v = lc(v, ("batch", "heads", "seq", "kv"))
+        if ring is not None:
+            # kept for the backward as the kernel reads them (gpt_hidden)
+            q, k, v = (_checkpoint_name(a, "attn_in") for a in (q, k, v))
         o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
-        o = jnp.einsum("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
+        o = last("bnsh,nhd->bsd", o, p["attn"]["wo"].astype(dt), "n")
     else:
-        qkv = jnp.einsum("bsd,dcnh->bscnh", h, p["attn"]["wqkv"].astype(dt))
+        qkv = first("bsd,dcnh->bscnh", h, p["attn"]["wqkv"].astype(dt), "n")
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         q = lc(q, ("batch", "seq", "heads", "kv"))
         k = lc(k, ("batch", "seq", "heads", "kv"))
         v = lc(v, ("batch", "seq", "heads", "kv"))
         o = _checkpoint_name(attn_fn(q, k, v), "attn_out")
-        o = jnp.einsum("bsnh,nhd->bsd", o, p["attn"]["wo"].astype(dt))
-    x = x + o + p["attn"]["bo"].astype(dt)
-    x = lc(x, ("batch", "seq", "embed"))
+        o = last("bsnh,nhd->bsd", o, p["attn"]["wo"].astype(dt), "n")
+    x = plus(x + o, p["attn"]["bo"].astype(dt))
+    x = lc(x, res)
 
     h = _layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"])
     if cfg.num_experts:
@@ -225,14 +259,16 @@ def _block(cfg: GPTConfig, rules: Optional[LogicalAxisRules],
                          ep_axis=moe_ep_axis)
     else:
         aux = jnp.zeros((), jnp.float32)
-        h = jnp.einsum("bsd,dm->bsm", h, p["mlp"]["wi"].astype(dt)) \
-            + p["mlp"]["bi"].astype(dt)
-        h = lc(h, ("batch", "seq", "mlp"))
-        h = jax.nn.gelu(h)
-        h = jnp.einsum("bsm,md->bsd", h, p["mlp"]["wo"].astype(dt)) \
-            + p["mlp"]["bo"].astype(dt)
+        # row by row from here to the last product: under the ring the
+        # rows stay as they came, one array a step
+        h = first("bsd,dm->bsm", h, p["mlp"]["wi"].astype(dt), "m",
+                  by_step=True)
+        h = jax.tree.map(lambda rows: jax.nn.gelu(lc(
+            rows + p["mlp"]["bi"].astype(dt), ("batch", "seq", "mlp"))), h)
+        h = plus(last("bsm,md->bsd", h, p["mlp"]["wo"].astype(dt), "m",
+                      by_step=True), p["mlp"]["bo"].astype(dt))
     x = x + h
-    return lc(x, ("batch", "seq", "embed")), aux
+    return lc(x, res), aux
 
 
 def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
@@ -247,6 +283,10 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
     single while-loop body (fast compiles, and the [L] dim shards over pp).
     With ``cfg.attention == "ring"`` and a mesh, attention runs as ring
     attention shard_mapped over the `sp` axis (KV rotating via ppermute).
+    The residual stream the scan carries is [B, S, D] with the batch over
+    (dp, fsdp) and the sequence over sp; where the current mesh has
+    ``tp > 1`` its sequence lies over (sp, tp) from the embedding to the
+    final norm (``_block`` says why), and is whole again for the head.
     """
     dt = cfg.dtype
     S = tokens.shape[1]
@@ -266,8 +306,13 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
 
     x = params["wte"].astype(dt)[tokens] \
         + params["wpe"].astype(dt)[:S][None]
+    sharded_rows = _tp_mesh(rules) is not None
     if rules is not None:
         x = with_logical_constraint(x, rules, ("batch", "seq", "embed"))
+    if sharded_rows:
+        # after the lookup's own constraint: the tables' gradients keep
+        # the tables' layout (a step's results lie as its arguments do)
+        x = with_logical_constraint(x, rules, ("batch", "res_seq", "embed"))
 
     block = functools.partial(_block, cfg, rules, attn_fn)
     if cfg.remat:
@@ -287,6 +332,12 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
                 cp.save_only_these_names("attn_out"))
         else:
             policy = None
+        if sharded_rows and policy is not None:
+            # what the kernel reads, in its layout, in place of the
+            # chunks' products it was put in order from: the same bytes,
+            # and the rematerialised forward puts nothing in order again
+            policy = cp.save_from_both_policies(
+                policy, cp.save_only_these_names("attn_in"))
         block = jax.checkpoint(block, policy=policy)
 
     def scan_body(carry, layer_params):
@@ -294,6 +345,9 @@ def gpt_hidden(params: Dict[str, Any], tokens: jax.Array,
 
     x, aux = jax.lax.scan(scan_body, x, params["layers"])
     x = _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    if sharded_rows:
+        # the head takes whole sequences, as it does with no tp
+        x = with_logical_constraint(x, rules, ("batch", "seq", "embed"))
     return x, jnp.sum(aux)
 
 
@@ -609,9 +663,11 @@ def make_train_step(cfg: GPTConfig, tx,
                     forward_fn: Optional[Callable] = None,
                     loss_fn: Optional[Callable] = None):
     """Returns jittable (params, opt_state, batch) -> (params, opt_state,
-    metrics).  Under a Mesh + sharded inputs, XLA emits all collectives
-    (gradient reduction across dp/fsdp, tp/sp activation collectives) — the
-    TPU equivalent of the reference's DDP allreduce hook.
+    metrics).  Under a Mesh + sharded inputs, XLA emits the collectives
+    (gradient reduction across dp/fsdp, the gathers of fsdp's weights, sp's
+    and the head's) — the TPU equivalent of the reference's DDP allreduce
+    hook — but for tp's activation sums, which ``_block`` writes out as
+    chunks that travel beside the projections (parallel/collectives.py).
 
     ``loss_fn(params, batch) -> scalar`` overrides the whole loss (the
     pipelined trainer plugs its fused-epilogue loss in here), so the

@@ -6,7 +6,9 @@ communication/parallelism stack (`ray.util.collective` NCCL groups,
 `train/torch/train_loop_utils.py:24-74`).  On TPU, parallelism is not a
 runtime library but a *compilation strategy*: you pick a `jax.sharding.Mesh`
 over the slice, annotate array shardings, and XLA emits the ICI collectives
-inside the step function.  The classes here make that recipe declarative:
+inside the step function (all but one: tp's activation sums, which
+`collectives.py` writes out as chunks that travel beside the projections).
+The classes here make that recipe declarative:
 
     spec = MeshSpec(dp=2, fsdp=2, tp=2)        # 8 chips
     mesh = spec.build()

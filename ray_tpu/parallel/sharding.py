@@ -55,6 +55,11 @@ class LogicalAxisRules:
 
         batch    -> (dp, fsdp)   activations' leading dim
         seq      -> sp           sequence/context parallelism
+        res_seq  -> (sp, tp)     the residual stream's rows BETWEEN a
+                                 block's projection pairs where the mesh has
+                                 tp > 1: each pair gathers them over tp in
+                                 its first product and scatters the partial
+                                 sums in its last (collectives.py)
         embed    -> fsdp         ZeRO-3 weight sharding on the data axis
         heads    -> tp           attention heads (Megatron col-parallel)
         kv       -> None         head_dim stays replicated
@@ -62,10 +67,17 @@ class LogicalAxisRules:
         vocab    -> tp           embedding/LM-head vocab sharding
         expert   -> ep           MoE expert dim
         layers   -> pp           stacked-layer dim (pipeline stages)
+
+        ``spec`` is still read for nothing: the table is one for every
+        mesh (an axis of size 1 shards nothing).  What differs by mesh, a
+        block's form under tp, is chosen where the block is traced, from
+        the mesh that is current there (``models/gpt.py::_tp_mesh``), so a
+        caller that hands no spec gets the same program as one that does.
         """
         return LogicalAxisRules([
             ("batch", ("dp", "fsdp")),
             ("seq", "sp"),
+            ("res_seq", ("sp", "tp")),
             ("embed", "fsdp"),
             ("heads", "tp"),
             ("kv", None),

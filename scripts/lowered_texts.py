@@ -1,7 +1,9 @@
 """Whether two trees lower to the same programs: one ``sha256  configuration
 program@rung`` line for every program the engine compiles for the benchmark's
 serving configurations, for the program that makes each one's weights
-(``init``), and for a tiny llama train step.
+(``init``), for the train step of each training configuration on its own
+mesh (``gpt2-medium`` on one chip, ``gpt2-large`` over fsdp=2 x tp=2), and
+for a tiny llama train step.
 
     JAX_PLATFORMS=cpu python scripts/lowered_texts.py [--tree DIR]
         [--only mistral xing ...] [--texts OUT_DIR]
@@ -138,6 +140,52 @@ def _serving(name: str, device):
             arg((batch, width))).as_text()
 
 
+def _trained(name: str, devices):
+    """(label, lowered text) of a training configuration's step as
+    ``benchmark/generators/train_steps.py`` builds it: the family's
+    ``train_config`` at the published length, the configuration's mesh over
+    the described chips, parameters and moments placed by the rule table."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+    from benchmark import spec
+    from ray_tpu.parallel import LogicalAxisRules, MeshSpec
+    from ray_tpu.parallel.sharding import logical_sharding
+
+    config = spec.load_json("configs", name)
+    family = spec.load_part("families", config["family"])
+    seq = config["n_positions"]
+    cfg = family.train_config(config, seq)
+    mesh_spec = MeshSpec(**config["mesh"])
+    mesh = mesh_spec.build(devices=devices[:mesh_spec.num_devices])
+    rules = LogicalAxisRules.for_transformer(mesh_spec)
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    shardings = jax.tree.map(
+        lambda axes: logical_sharding(mesh, rules, axes),
+        family.param_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    params = placed(jax.eval_shape(
+        lambda: family.init(jax.random.PRNGKey(0), cfg)), shardings)
+    tx = optax.adamw(3e-4, b2=0.95)
+    opt = optax.tree_map_params(
+        tx, lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(tx.init, params), shardings,
+        transform_non_params=lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, PartitionSpec())))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (config["train"]["batch"], seq + 1), jnp.int32,
+        sharding=logical_sharding(mesh, rules, ("batch", None)))}
+    with jax.sharding.set_mesh(mesh):
+        step = family.make_train_step(cfg, tx, rules)
+        label = "x".join(f"{axis}{n}" for axis, n in
+                         mesh_spec.axis_sizes.items() if n > 1) or "1chip"
+        yield f"train_step@{label}", step.lower(params, opt, batch).as_text()
+
+
 def _train(device):
     """The tiny llama's train step, its attention dense and by the flash
     kernels (the two branches of the training trunk's attention)."""
@@ -182,8 +230,9 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     jax.config.update("jax_enable_compilation_cache", False)
     jax.default_backend = lambda: "tpu"   # what this repo's code is told
-    device = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0]
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    device = devices[0]
 
     def wanted(name):
         return args.only is None or any(part in name for part in args.only)
@@ -193,9 +242,11 @@ def main(argv=None) -> int:
                 os.path.join(tree, "benchmark", "configs", "*.json"))):
             name = os.path.basename(path)
             with open(path) as f:
-                if "engine" not in json.load(f) or not wanted(name):
-                    continue
-            for label, text in _serving(name, device):
+                config = json.load(f)
+            if not wanted(name):
+                continue
+            for label, text in (_serving(name, device) if "engine" in config
+                                else _trained(name, devices)):
                 yield name[:-len(".json")], label, text
         if wanted("train"):
             for label, text in _train(device):

@@ -5,6 +5,8 @@ here the interesting property is that one model definition trains correctly
 under any MeshSpec on the virtual 8-device mesh.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -111,6 +113,44 @@ def test_gpt_sharded_matches_single_device(attention):
     l_tp = run(MeshSpec(tp=2, fsdp=4))
     assert abs(l_single - l_dp) < 1e-4
     assert abs(l_single - l_tp) < 1e-4
+
+
+TP_RING_SPECS = {"fsdp2xtp2": MeshSpec(fsdp=2, tp=2),
+                 "tp4xfsdp2": MeshSpec(tp=4, fsdp=2),
+                 "fsdp2xsp2xtp2": MeshSpec(fsdp=2, sp=2, tp=2)}
+
+
+@functools.lru_cache(maxsize=None)
+def _single_device_loss_and_grads(cfg):
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    batch = _batch(B=8, key=7)
+    return params, batch, jax.jit(jax.value_and_grad(
+        lambda p: gpt_loss(p, batch, cfg)))(params)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("layout", TP_RING_SPECS)
+def test_gpt_tp_ring_matches_single_device(layout, remat):
+    """Under tp > 1 the block moves its activation sums as chunks around
+    the tp ring (parallel/collectives.py), the residual stream sharded
+    along the sequence: loss AND gradients are the single device's, at
+    tp = 2, around a ring of four, and with sp beside tp."""
+    cfg = dataclasses_replace(TINY, num_heads=4, remat=remat,
+                              remat_policy="dots")
+    params, batch, (want, want_grads) = _single_device_loss_and_grads(cfg)
+    spec = TP_RING_SPECS[layout]
+    mesh = spec.build()
+    rules = LogicalAxisRules.for_transformer(spec)
+    with jax.sharding.set_mesh(mesh):
+        placed = shard_params(params, mesh, rules, gpt_param_axes(cfg))
+        lowered = jax.jit(jax.value_and_grad(
+            lambda p: gpt_loss(p, batch, cfg, rules))).lower(placed)
+        got, got_grads = lowered.compile()(placed)
+    # through the ring, not around it
+    assert "collective_permute" in lowered.as_text()
+    np.testing.assert_allclose(float(got), float(want), atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
 def test_gpt_ring_attention_mode_trains():

@@ -200,6 +200,39 @@ def _scoped(text: str, scope: str) -> bool:
                for name in re.findall(r'op_name="([^"]*)"', text))
 
 
+def _permutes_by_body(text: str, shape: str) -> dict:
+    """The computations of a compiled (scheduled) program that hold a
+    ``collective-permute-start`` of ``shape``, each with, for every such
+    start, how many matrix products (a convolution, or a fusion that holds
+    one) the schedule put between it and its ``-done``."""
+    bodies = {}
+    for block in text.split("\n\n"):
+        head, _, body = block.strip("\n").partition("\n")
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", head)
+        if name:
+            bodies[name.group(1)] = body.split("\n")
+
+    def is_product(line):
+        called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+        return " convolution(" in line or bool(called) and any(
+            " convolution(" in inner for inner in bodies[called.group(1)])
+
+    found = {}
+    for name, lines in bodies.items():
+        started = {}
+        for at, line in enumerate(lines):
+            start = re.match(r"\s*%?([\w.\-]+) = \(" + re.escape(shape)
+                             + r"\S* .* collective-permute-start\(", line)
+            done = re.search(r" collective-permute-done\(%?([\w.\-]+)\)",
+                             line)
+            if start:
+                started[start.group(1)] = at
+            elif done and done.group(1) in started:
+                found.setdefault(name, []).append(sum(
+                    map(is_product, lines[started[done.group(1)] + 1:at])))
+    return found
+
+
 def _names_no_source(lowered, name: bytes) -> None:
     """The one Pallas kernel of a lowered program: its serialized module
     holds the kernel's ``name`` and no file's."""
@@ -309,8 +342,27 @@ def test_gpt2_small_train_step_compiles(topo, compiled_kernels, spec):
     assert _kernel_calls(text) == ["flash_dkv", "flash_dq", "flash_fwd",
                                    "flash_fwd"]
     assert _scoped(text, "ce_head") and _scoped(text, "optimizer")
-    if spec.num_devices > 1:
-        assert "all-reduce" in text or "reduce-scatter" in text
+    # a step's results lie as its arguments do, or its second call
+    # compiles again (and a compiled step refuses its own results)
+    (was, _), now = compiled.input_shardings, compiled.output_shardings
+    for arg, a, b in zip(jax.tree.leaves(args[:2]),
+                         jax.tree.leaves(was[:2]), jax.tree.leaves(now[:2])):
+        assert a.is_equivalent_to(b, arg.ndim), (arg.shape, a, b)
+    if spec.tp > 1:
+        # tp's activation sums travel as chunks beside the products
+        # (parallel/collectives.py): no all-reduce of a whole activation
+        # [B/fsdp, S, D] is left, and both layer bodies (forward; backward
+        # with the rematerialised forward) move [B/fsdp, S/tp, D] by
+        # collective-permute, each with a product inside some pair
+        rows, width = B // spec.fsdp, GPT2.embed_dim
+        assert not re.search(
+            rf"= bf16\[{rows},{S},{width}\]\S* all-reduce(-start)?\(", text)
+        found = _permutes_by_body(
+            text, f"bf16[{rows},{S // spec.tp},{width}]")
+        # a layer moves four half-sums (the entry computation moves one:
+        # the head takes whole sequences)
+        bodies = [pairs for pairs in found.values() if len(pairs) >= 4]
+        assert len(bodies) == 2 and all(map(max, bodies)), found
     _fits(compiled)
 
 
