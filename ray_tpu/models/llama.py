@@ -71,8 +71,10 @@ hyper-connections, arXiv:2512.24880; ``_sublayer``).  ``shared_experts``,
 not train here.
 
 With a ``layer_pattern`` the stack is layers of two kinds in a repeating
-period, "full" and one of "linear" and "conv" (three kinds are written;
-Olmo-Hybrid's period is three "linear" then one "full"): a linear layer's
+period, "full" (anywhere in the period) and ONE of "linear", "conv" and
+"ssm" (four kinds are written; ``_check`` refuses two of the last three in
+one stack; Olmo-Hybrid's period is three "linear" then one "full"): a
+linear layer's
 attention is the gated delta rule (``_linear_attention``;
 ``ops/linear_attention.py`` has the rule's chunked scan, its one-position
 step and the short causal convolution ahead of it), which keeps of its past
@@ -154,6 +156,35 @@ and 4,096 positions).  An indexer model's prefill always runs so (``start`` 0
 where none is given); every other model without a ``start`` keeps
 ``_mla_expanded`` or the flash kernel.  Such a model serves; the training
 trunk refuses the indexer.
+
+The FOURTH kind, "ssm", is Mamba-2's mixer (``_ssm_mixer``; transformers'
+``GraniteMoeHybridMambaLayer`` with one group; State Space Duality,
+arXiv:2405.21060): ``[z | xBC | dt] = h W_in``, a causal depthwise
+convolution of ``xBC`` over ``linear_conv`` positions WITH a bias, then SiLU,
+``[x | B | C] = xBC``; ``delta = softplus(dt + dt_bias)`` and ``a =
+exp(-exp(A_log) delta)`` a head; a head's state ``S_n`` [``linear_key_dim``,
+``linear_value_dim``] does ``S_n <- a_n S_n + B (delta_n x_n)^T``, ``y_n =
+S_n^T C + D_n x_n``: the delta rule's row a slot WITHOUT its correction, ONE
+key ``B`` and ONE query ``C`` for all ``linear_heads`` heads, a step size
+that scales the input and a skip; then ``y * silu(z)`` RMS-normed over ALL
+channels at once (the gate first, one norm) and ``W_out``.  It keeps what a
+linear layer keeps, a float32 state row and a convolution tail a decode slot
+in ``RecurrentPools`` (the ``recurrent`` entries of the three states take the
+rule by the layer's leaves; no fourth kind of cache): the prefill runs
+``ops/linear_attention.py::ssm_chunked`` (products alone, no triangular
+solve: nothing a position writes depends on what the state held) on the
+padded rung, where a padded position gets a = 1 and an input of 0, and the
+token step is ``step_pool`` with no beta: the kernel of
+``ops/linear_state.py`` in its shared kind, which reads the one key and
+query a slot and spreads nothing over the heads in HBM.  Heads of 64 values
+lie two to a 128-lane panel (``_panel_plan``).  Under a ``layer_pattern`` the
+feed-forwards may be experts in EVERY layer, no leading dense layer
+(``_group_a_layer``: a group a layer, ``_unrolled_layers``), and the period's
+full layer may stand anywhere in it.  ``embedding_multiplier``,
+``residual_multiplier``, ``attention_multiplier`` (the softmax's scale where
+it is not ``head_dim ** -0.5``) and ``logits_scaling`` (a divisor) are four
+scalars that add no operation at their defaults: Granite 4.0-H's block, one
+chip's share of it.  Such a model serves and runs ``llama_forward``.
 """
 
 from __future__ import annotations
@@ -224,10 +255,11 @@ class LlamaConfig:
     mask_token: int = 0              #   the count alone), masks of this id
     pre_norm: bool = True            # RMSNorm a sublayer's input
     # one period of the stack's layer kinds, "full" and one of "linear" |
-    # "conv" (the stack repeats it; empty: every layer is full attention; a
-    # "conv" layer is a gated short convolution over ``linear_conv``
-    # positions and takes none of the other linear_* fields), and a linear
-    # layer's
+    # "conv" | "ssm" (the stack repeats it; empty: every layer is full
+    # attention; a "conv" layer is a gated short convolution over
+    # ``linear_conv`` positions and takes none of the other linear_* fields;
+    # an "ssm" layer is Mamba-2's mixer with ONE key and query for all heads,
+    # ``linear_key_dim`` its state's width), and a linear or ssm layer's
     layer_pattern: Tuple[str, ...] = ()
     linear_heads: int = 0            #   heads (keys' and values' alike),
     linear_key_dim: int = 0          #   a head's q/k width,
@@ -251,6 +283,14 @@ class LlamaConfig:
     # (n, k): the routed experts lie in n equal groups and a token's experts
     # are chosen inside its k best groups (``ops/moe.py::_route``)
     expert_groups: Tuple[int, int] = (1, 1)
+    # Granite's four scalars, none of which adds an operation where it is
+    # left as it stands: the embedding times this, a sublayer's output times
+    # this before it joins the stream, the softmax's scale (0: head_dim to
+    # the -1/2), the logits DIVIDED by this
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -338,37 +378,42 @@ def _check(cfg: LlamaConfig) -> None:
                          "qk_norm_per_head")
     if cfg.layer_pattern:
         kinds, others = set(cfg.layer_pattern), ("hc_mult", "block_length")
-        if not (kinds <= {"linear", "full"} or kinds <= {"conv", "full"}) \
+        if not any(kinds <= {kind, "full"}
+                   for kind in ("linear", "conv", "ssm")) \
                 or kinds == {"full"} \
                 or cfg.num_layers % len(cfg.layer_pattern):
             raise ValueError(
                 f"layer_pattern={cfg.layer_pattern} is one period of 'full' "
-                "layers and layers of ONE other kind, at least one of them "
-                "linear (the delta rule) or at least one a 'conv' (a gated "
-                f"short convolution), and num_layers={cfg.num_layers} whole "
-                "periods")
-        if cfg.linear_conv < 2 or ("linear" in kinds and not (
+                "layers, anywhere in it, and layers of ONE other kind, at "
+                "least one of them linear (the delta rule), or at least one "
+                "a 'conv' (a gated short convolution), or at least one an "
+                "'ssm' (Mamba-2's state-space rule), and "
+                f"num_layers={cfg.num_layers} whole periods")
+        if cfg.linear_conv < 2 or (kinds & {"linear", "ssm"} and not (
                 cfg.linear_heads and cfg.linear_key_dim
                 and cfg.linear_value_dim)):
             raise ValueError("linear layers need linear_heads, "
-                             "linear_key_dim and linear_value_dim, and they "
-                             "and conv layers a linear_conv of 2 or more")
+                             "linear_key_dim and linear_value_dim, ssm "
+                             "layers too, and they and conv layers a "
+                             "linear_conv of 2 or more")
+        if "ssm" in kinds and (cfg.linear_gate_rank
+                               or cfg.linear_neg_eigval):
+            raise ValueError("an ssm layer's rule has no correction: "
+                             "neither linear_gate_rank nor "
+                             "linear_neg_eigval")
         if "conv" in kinds and (cfg.kv_lora_rank or cfg.linear_gate_rank
                                 or cfg.linear_heads):
             raise ValueError("a stack with conv layers is written with K/V "
                              "pages for its full layers (no kv_lora_rank) "
                              "and takes of the linear_* fields linear_conv "
                              "alone")
-        if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others) or (
-                cfg.num_experts and not cfg.first_dense_layers):
-            raise ValueError("a stack with linear or conv layers "
+        if cfg.ut_steps > 1 or any(getattr(cfg, o) for o in others):
+            raise ValueError("a stack with linear, conv or ssm layers "
                              "(layer_pattern) is not written for "
-                             "ut_steps > 1, "
-                             + ", ".join(others) + " or num_experts without "
-                             "first_dense_layers (experts inside the scan "
-                             "over periods); its full layers keep K/V or "
-                             "latent pages, and behind leading dense layers "
-                             "its feed-forwards may be experts")
+                             "ut_steps > 1 or " + " or ".join(others)
+                             + "; its full layers keep K/V or latent pages, "
+                             "and its feed-forwards may be experts, behind "
+                             "leading dense layers or in every layer")
         if cfg.linear_gate_rank and cfg.linear_neg_eigval:
             raise ValueError("a decay a key channel (linear_gate_rank) has "
                              "beta in (0, 1): no linear_neg_eigval")
@@ -392,6 +437,12 @@ def _check(cfg: LlamaConfig) -> None:
     elif cfg.denoise_steps or cfg.confidence_threshold or cfg.mask_token:
         raise ValueError("denoise_steps, confidence_threshold and "
                          "mask_token belong to a block_length")
+    if cfg.attention_multiplier and cfg.kv_lora_rank:
+        raise ValueError("latent attention has a softmax scale of its own "
+                         "(mla_softmax_scale), not attention_multiplier")
+    if cfg.residual_multiplier != 1.0 and cfg.hc_mult:
+        raise ValueError("a hyper-connected residual (hc_mult) weighs a "
+                         "sublayer's output itself: no residual_multiplier")
 
 
 def _slot_layers(cfg: LlamaConfig) -> int:
@@ -415,13 +466,31 @@ def _layer_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
     return cfg.layer_pattern * (cfg.num_layers // len(cfg.layer_pattern))
 
 
+def _group_a_layer(cfg: LlamaConfig) -> bool:
+    """Whether a pattern stack keeps a group a LAYER (each a stack of one,
+    run by ``_unrolled_layers``) and not a group a position of the period:
+    where its feed-forwards are experts, behind leading dense layers or in
+    every layer (an expert layer's experts are its own ``mlp``, handed to the
+    grouped matmuls whole, never a scan's slice)."""
+    return bool(cfg.layer_pattern
+                and (cfg.first_dense_layers or cfg.num_experts))
+
+
+def _mixer_channels(cfg: LlamaConfig, kind: str) -> int:
+    """The channels a ``kind`` layer's short convolution runs over: a linear
+    layer's q | k | v, an ssm layer's x | B | C."""
+    N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    return {"linear": N * (2 * dk + dv), "ssm": N * dv + 2 * dk}[kind]
+
+
 def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                 experts: int, M: int, kind: str = "full") -> Dict[str, Any]:
     """``L`` layers of one ``kind`` stacked on a leading dim: ``experts`` of
     width ``M`` each (0: one dense SwiGLU of ``M``; of ``cfg.num_experts``
     the program's share, ``_held_experts``: the router keeps them all);
-    a "linear" kind's leaves under ``"linear"`` and a "conv" kind's under
-    ``"conv"`` where the others have ``"attn"``."""
+    a "linear" kind's leaves under ``"linear"``, a "conv" kind's under
+    ``"conv"`` and an "ssm" kind's under ``"ssm"`` where the others have
+    ``"attn"``."""
     k = jax.random.split(rng, 8)
     D, H = cfg.embed_dim, cfg.head_dim
     nh, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -458,6 +527,33 @@ def _init_group(rng: jax.Array, cfg: LlamaConfig, L: int,
                     jax.random.fold_in(k[2], 1), (L, K, D), jnp.float32,
                     -K ** -0.5, K ** -0.5),
                 "wout": normal(k[3], (L, D, D), rscale)}
+    elif kind == "ssm":
+        # Mamba-2's leaves as its layer draws them (transformers'
+        # ``GraniteMoeHybridMambaLayer``): z | x B C | dt in one projection,
+        # the convolution with a BIAS (torch's Conv1d: both uniform within
+        # K^-1/2), A uniform over (1, 16) kept as its logarithm, dt
+        # log-uniform over (0.001, 0.1) kept as its inverse softplus, the
+        # skip D and the gated norm's scale ones
+        N, dk, dv, K = (cfg.linear_heads, cfg.linear_key_dim,
+                        cfg.linear_value_dim, cfg.linear_conv)
+        C = _mixer_channels(cfg, "ssm")
+        dt = jnp.exp(jax.random.uniform(
+            jax.random.fold_in(k[2], 2), (L, N), jnp.float32,
+            np.log(0.001), np.log(0.1)))
+        attn = {"win": normal(k[1], (L, D, N * dv + C + N)),
+                "conv": jax.random.uniform(
+                    jax.random.fold_in(k[2], 1), (L, K, C), jnp.float32,
+                    -K ** -0.5, K ** -0.5),
+                "conv_bias": jax.random.uniform(
+                    jax.random.fold_in(k[2], 4), (L, C), jnp.float32,
+                    -K ** -0.5, K ** -0.5),
+                "A_log": jnp.log(jax.random.uniform(
+                    jax.random.fold_in(k[2], 3), (L, N), jnp.float32,
+                    1.0, 16.0)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "D": jnp.ones((L, N), jnp.float32),
+                "norm": jnp.ones((L, N * dv), jnp.float32),
+                "wout": normal(k[3], (L, N * dv, D), rscale)}
     elif kind == "linear":
         # The gated delta rule's leaves (transformers' Qwen3NextGatedDeltaNet
         # draws them so): the convolution as torch's Conv1d, uniform within
@@ -551,7 +647,8 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     ``layers`` is a tuple, one group a POSITION of the pattern, each stacked
     over the periods [num_layers / len(pattern)]: layer ``l`` is row ``l //
     len(pattern)`` of group ``l % len(pattern)``; with a ``layer_pattern``
-    AND ``first_dense_layers`` it is a tuple with a group a LAYER, each a
+    AND experts (behind ``first_dense_layers`` or in every layer:
+    ``_group_a_layer``) it is a tuple with a group a LAYER, each a
     stack of one (the leading dense layers' feed-forwards and the others'
     experts are not one shape to stack over periods; ``_unrolled_layers``),
     an expert layer's experts and router under its own ``mlp``."""
@@ -567,7 +664,7 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
         if not cfg.layer_pattern:
             return _init_group(rng, cfg, cfg.num_layers - Ld,
                                _held_experts(cfg), cfg.mlp_dim)
-        if Ld:
+        if _group_a_layer(cfg):
             return tuple(
                 _init_group(jax.random.fold_in(rng, 16 + at), cfg, 1,
                             *((0, cfg.dense_mlp_dim) if at < Ld else
@@ -604,6 +701,13 @@ def _group_axes(cfg: LlamaConfig, experts: bool,
     if kind == "conv":
         attn = {"win": ("layers", "embed", "heads"),
                 "taps": ("layers", None, "heads"),
+                "wout": ("layers", "heads", "embed")}
+    elif kind == "ssm":
+        attn = {"win": ("layers", "embed", "heads"),
+                "conv": ("layers", None, "heads"),
+                "conv_bias": ("layers", "heads"),
+                "A_log": ("layers", None), "dt_bias": ("layers", None),
+                "D": ("layers", None), "norm": ("layers", "norm"),
                 "wout": ("layers", "heads", "embed")}
     elif kind == "linear":
         gates = {"wg_a": ("layers", "embed", None),
@@ -661,7 +765,7 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     dense = {"dense_layers": _group_axes(cfg, False)} \
         if cfg.first_dense_layers and not cfg.layer_pattern else {}
     layers = _group_axes(cfg, bool(cfg.num_experts))
-    if cfg.layer_pattern and cfg.first_dense_layers:     # a group a layer
+    if _group_a_layer(cfg):
         layers = tuple(
             _group_axes(cfg, at >= cfg.first_dense_layers, kind)
             for at, kind in enumerate(_layer_kinds(cfg)))
@@ -810,6 +914,8 @@ def _add_sublayer(cfg: LlamaConfig, p, post: str, x, y):
     if cfg.post_norm:
         with jax.named_scope("loop_norm"):
             y = _rms_norm(y, p[post]["scale"], cfg.rms_eps)
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
     return x + y
 
 
@@ -894,6 +1000,8 @@ def _embed(cfg: LlamaConfig, params, tokens):
     """The tokens' rows of the table; hyper-connected, each repeated to the
     stream's ``hc_mult`` rows."""
     x = params["wte"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.hc_mult:
         x = jnp.repeat(x[..., None, :], cfg.hc_mult, axis=-2)
     return x
@@ -1115,7 +1223,7 @@ def _scan_layers(cfg: LlamaConfig, params, body, carry, t=None,
     the last carry and the scanned layers' ``ys`` (the expert layers'
     loads)."""
     if cfg.layer_pattern:
-        return (_unrolled_layers if cfg.first_dense_layers
+        return (_unrolled_layers if _group_a_layer(cfg)
                 else _scan_periods)(cfg, params, body, carry, served)
     first = cfg.first_dense_layers
     layers, experts = _scanned_layers(cfg, params)
@@ -1159,7 +1267,8 @@ def _scan_periods(cfg: LlamaConfig, params, body, carry, served: bool):
 
 def _unrolled_layers(cfg: LlamaConfig, params, body, carry, served: bool):
     """``_scan_layers`` for a stack of two kinds of layer whose feed-forwards
-    are not one shape either (leading dense layers, then experts): every
+    are experts (all of them, or all behind leading dense layers, which are
+    another shape): every
     layer is its own group in ``params["layers"]``, a stack of one, and the
     layers run in a Python loop (an index of 0 into a stack of one moves
     nothing).  An expert layer's experts are its own ``mlp``, handed to
@@ -1297,6 +1406,36 @@ def _linear_sequence(cfg: LlamaConfig, p, qkv, g, beta, length=None):
     return o, state, tail
 
 
+def _ssm_split(cfg: LlamaConfig, mixed):
+    """The convolved channels [..., C] of an ssm layer as the rule's float32
+    operands: x [..., N, dv] a head, and the ONE key B and ONE query C [...,
+    dk] that all heads share."""
+    N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    mixed = mixed.astype(jnp.float32)
+    x = mixed[..., :N * dv].reshape(*mixed.shape[:-1], N, dv)
+    return x, mixed[..., N * dv:N * dv + dk], mixed[..., N * dv + dk:]
+
+
+def _ssm_sequence(cfg: LlamaConfig, p, xbc, g, delta, length=None):
+    """An ssm layer over one whole sequence from an empty state, by the
+    chunked form: xbc [S, C] as projected, g = log a and delta [S, N].
+    Returns (y [S, N, dv] float32 with the skip ``D x`` in it, the state [N,
+    dk, dv] and the convolution's last inputs as they stand after position
+    ``length - 1``; None: after the last).  A padded position writes nothing
+    and decays nothing: ``ssm_chunked`` gives it a = 1 and an input of 0."""
+    from ray_tpu.ops.linear_attention import (causal_conv, conv_tail,
+                                              ssm_chunked)
+    a = p["ssm"]
+    with jax.named_scope("linear_conv"):
+        mixed = causal_conv(xbc, a["conv"], bias=a["conv_bias"])
+        tail = conv_tail(xbc, xbc.shape[0] if length is None else length,
+                         a["conv"].shape[0])
+    with jax.named_scope("linear_state"):
+        x, key, query = _ssm_split(cfg, mixed)
+        o, state = ssm_chunked(query, key, delta[..., None] * x, g, length)
+        return o + a["D"][:, None] * x, state, tail
+
+
 def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
     """The training trunk's: nothing is written, and whole sequences attend
     to themselves by ``attn_fn`` (the flash kernels, or dense)."""
@@ -1317,7 +1456,8 @@ def _no_cache(cfg: LlamaConfig, attn_fn: Callable, lc) -> AttentionState:
         return _checkpoint_name(attn_fn(q, k, v), "attn_out"), pools
 
     def recurrent(p, layer, pools, qkv, g, beta):
-        return jax.vmap(lambda *row: _linear_sequence(cfg, p, *row)[0])(
+        sequence = _ssm_sequence if "ssm" in p else _linear_sequence
+        return jax.vmap(lambda *row: sequence(cfg, p, *row)[0])(
             qkv, g, beta), pools
 
     def conv(p, layer, pools, u):
@@ -1380,8 +1520,8 @@ def _prefill_state(cfg: LlamaConfig, length, page_table,
 
     def recurrent(p, layer, pools, qkv, g, beta):
         from ray_tpu.ops.linear_attention import fold_state
-        o, state, tail = _linear_sequence(cfg, p, qkv[0], g[0], beta[0],
-                                          length)
+        sequence = _ssm_sequence if "ssm" in p else _linear_sequence
+        o, state, tail = sequence(cfg, p, qkv[0], g[0], beta[0], length)
         rows = pools[1]
         with jax.named_scope("linear_state"):
             rows = rows._replace(
@@ -1437,13 +1577,23 @@ def _token_state(cfg: LlamaConfig, pos, page_table) -> AttentionState:
         # parked slot (pos 0) keeps what it holds.
         from ray_tpu.ops.linear_attention import causal_conv_step, step_pool
         rows, live = pools[1], pos > 0
+        a = p["ssm" if "ssm" in p else "linear"]
         with jax.named_scope("linear_conv"):
-            mixed, tail = causal_conv_step(qkv, p["linear"]["conv"],
-                                           rows.conv[layer])
+            # (the bias BY NAME and only where the layer has one: the
+            # numerics tools plant convolutions of the four-argument form)
+            mixed, tail = causal_conv_step(
+                qkv, a["conv"], rows.conv[layer],
+                **({"bias": a["conv_bias"]} if "conv_bias" in a else {}))
             tail = jnp.where(live[:, None], tail, rows.conv[layer])
         with jax.named_scope("linear_state"):
-            o, state = step_pool(*_linear_split(cfg, mixed), g, beta,
-                                 rows.state, layer, live)
+            if "ssm" in p:       # beta is the step size: no correction
+                x, key, query = _ssm_split(cfg, mixed)
+                o, state = step_pool(query, key, beta[..., None] * x, g,
+                                     None, rows.state, layer, live)
+                o = o + a["D"][:, None] * x
+            else:
+                o, state = step_pool(*_linear_split(cfg, mixed), g, beta,
+                                     rows.state, layer, live)
             rows = rows._replace(
                 state=state, conv=jax.lax.dynamic_update_index_in_dim(
                     rows.conv, tail, layer, 0))
@@ -1495,6 +1645,9 @@ def _attention(cfg: LlamaConfig, p, h, cos, sin, state: AttentionState,
                     a["wkv"].astype(dt))
     k, v = kv[:, 0], kv[:, 1]
     q, k = _qk(cfg, p, q, k, cos, sin)
+    if cfg.attention_multiplier:     # every read scales by head_dim^-1/2
+        q = q * jnp.asarray(
+            cfg.attention_multiplier * cfg.head_dim ** 0.5, q.dtype)
     o, pages = state.kv(p, layer, _pages(pools), q, k, v)
     return jnp.einsum("bnsh,nhd->bsd" if rows else "bnh,nhd->bd", o,
                       a["wo"].astype(dt)), _with_pages(pools, pages)
@@ -1566,6 +1719,41 @@ def _gated_norm(cfg: LlamaConfig, scale, o, z, gate=jax.nn.silu):
                 * gate(z.astype(jnp.float32))).astype(cfg.dtype)
 
 
+def _ssm_mixer(cfg: LlamaConfig, p, h, state: AttentionState, layer, pools):
+    """An ssm layer's mixer on ``h`` [..., D] (Mamba-2's, State Space
+    Duality, arXiv:2405.21060; transformers' ``GraniteMoeHybridMambaLayer``
+    with one group): ``[z | xBC | dt] = h W_in``; the ``state``'s convolution
+    of ``xBC`` with its bias and SiLU, ``[x | B | C]``, the rule ``S_n <- a_n
+    S_n + B (delta_n x_n)^T``, ``y_n = S_n^T C + D_n x_n`` with ``delta =
+    softplus(dt + dt_bias)`` and ``a = exp(-exp(A_log) delta)`` a head, and
+    the write-back; then ``y * silu(z)`` RMS-normed over ALL ``N dv``
+    channels at once (the gate FIRST, one norm: not ``_gated_norm``'s norm a
+    head, then gate) and projected out.  The state's ``recurrent`` takes the
+    step size where the delta rule's takes beta.  Returns (the sublayer's
+    output, the state's pools)."""
+    if state.recurrent is None:
+        raise NotImplementedError(
+            "models/llama.py: this program's attention state is not "
+            "written for ssm layers (layer_pattern): the block step keeps "
+            "K/V pages only")
+    a, dt = p["ssm"], cfg.dtype
+    inner = cfg.linear_heads * cfg.linear_value_dim
+    C = _mixer_channels(cfg, "ssm")
+    with jax.named_scope("ssm_proj"):
+        zxbcdt = jnp.einsum("...d,dc->...c", h, a["win"].astype(dt))
+    z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner:inner + C]
+    delta = jax.nn.softplus(
+        zxbcdt[..., inner + C:].astype(jnp.float32) + a["dt_bias"])
+    o, pools = state.recurrent(p, layer, pools, xbc,
+                               -jnp.exp(a["A_log"]) * delta, delta)
+    with jax.named_scope("linear_gate_norm"):
+        y = o.reshape(*o.shape[:-2], inner) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        y = _rms_norm(y, a["norm"], cfg.rms_eps).astype(dt)
+    with jax.named_scope("ssm_proj"):
+        return jnp.einsum("...c,cd->...d", y, a["wout"].astype(dt)), pools
+
+
 def _conv_operator(cfg: LlamaConfig, p, h, state: AttentionState, layer,
                    pools):
     """A conv layer's operator on ``h`` [..., D] (LFM2's gated short
@@ -1602,6 +1790,9 @@ def _layer(cfg: LlamaConfig, p, x, cos, sin, state: AttentionState, layer,
     elif "conv" in p:
         def mixer(h):
             return _conv_operator(cfg, p, h, state, layer, pools)
+    elif "ssm" in p:
+        def mixer(h):
+            return _ssm_mixer(cfg, p, h, state, layer, pools)
     else:
         def mixer(h):
             return _attention(cfg, p, h, cos, sin, state, layer, pools)
@@ -1675,10 +1866,14 @@ def _head(cfg: LlamaConfig, params, x, rows: str = "bs"):
     """Logits of the final hidden ``x`` [*rows, D] in the compute dtype: by
     the head's own matrix, or with ``cfg.tie_embeddings`` by the table."""
     if cfg.tie_embeddings:
-        return jnp.einsum(f"{rows}d,vd->{rows}v", x,
-                          params["wte"].astype(cfg.dtype))
-    return jnp.einsum(f"{rows}d,dv->{rows}v", x,
-                      params["lm_head"].astype(cfg.dtype))
+        logits = jnp.einsum(f"{rows}d,vd->{rows}v", x,
+                            params["wte"].astype(cfg.dtype))
+    else:
+        logits = jnp.einsum(f"{rows}d,dv->{rows}v", x,
+                            params["lm_head"].astype(cfg.dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
 
 
 def llama_forward(params: Dict[str, Any], tokens: jax.Array,
@@ -1718,7 +1913,8 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
     must never hand it out.  With a ``layer_pattern`` the pages are the
     full layers' alone, K/V or latent, and where the V pool would be come
     ``RecurrentPools``: that pool (None beside latent pages), and the linear
-    layers' state and convolution rows for ``slots`` decode slots, zeroed
+    or ssm layers' state and convolution rows for ``slots`` decode slots,
+    zeroed
     (an empty state); conv layers keep a convolution tail a slot (the last
     ``linear_conv - 1`` positions of D channels) and no state: None.  A
     latent model with an indexer (``index_heads``) has TWO pools a position:
@@ -1752,10 +1948,12 @@ def llama_init_paged_cache(cfg: LlamaConfig, num_pages: int,
         return pages[0], RecurrentPools(pages[1], None, jnp.zeros(
             (*rows, (cfg.linear_conv - 1) * cfg.embed_dim), dt))
     N, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    kind = "ssm" if "ssm" in cfg.layer_pattern else "linear"
     return pages[0], RecurrentPools(
         pages[1],
         jnp.zeros((*rows, *state_shape(N, dk, dv)), jnp.float32),
-        jnp.zeros((*rows, (cfg.linear_conv - 1) * N * (2 * dk + dv)), dt))
+        jnp.zeros((*rows, (cfg.linear_conv - 1)
+                   * _mixer_channels(cfg, kind)), dt))
 
 
 def llama_serving_params(params: Dict[str, Any],
@@ -1789,6 +1987,8 @@ def llama_serving_params(params: Dict[str, Any],
             if "linear" in layers else \
             {"conv": cast(layers["conv"], "win", "taps", "wout")} \
             if "conv" in layers else \
+            {"ssm": cast(layers["ssm"], "win", "conv", "wout")} \
+            if "ssm" in layers else \
             {"attn": cast(layers["attn"], *matrices)}
         out = {**layers, **mixer,
                "mlp": layers["mlp"] if experts else
@@ -1870,7 +2070,8 @@ def llama_linear_state(cfg: LlamaConfig, v_pages) -> str:
     rows' pool ``v_pages.state``)."""
     from ray_tpu.ops.linear_attention import state_step_kind
     return state_step_kind(v_pages.state, cfg.linear_heads,
-                           cfg.linear_value_dim)
+                           cfg.linear_value_dim,
+                           "ssm" in cfg.layer_pattern)
 
 
 def _served_trunk(cfg: LlamaConfig, params, x, cos, sin,
@@ -2105,7 +2306,7 @@ def served(config: Optional[LlamaConfig] = None, seq: int = 0):
         prefill_attention=llama_prefill_attention,
         paged_read=llama_paged_read,
         linear_state=llama_linear_state
-        if "linear" in cfg.layer_pattern else None,
+        if {"linear", "ssm"} & set(cfg.layer_pattern) else None,
         block=cfg.block_length,
         feed=(lambda cfg, logits, state, end: (
             None, block_unmask(cfg, logits, state, end)))
@@ -2133,10 +2334,11 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
     objective such a model is published with."""
     if cfg.layer_pattern:
         raise NotImplementedError(
-            "models/llama.py serves its model with linear-attention or conv "
-            "layers (layer_pattern) and runs llama_forward, but does not "
-            "train it: the chunked scan's backward pass is not written, and "
-            "neither is a pattern stack's remat and sharding")
+            "models/llama.py serves its model with linear-attention, conv "
+            "or ssm layers (layer_pattern) and runs llama_forward, but does "
+            "not train it: the chunked scan's backward pass is not written "
+            "(nor the state-space rule's), and neither is a pattern stack's "
+            "remat and sharding")
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             "models/llama.py serves its looped model (ut_steps > 1) but "
@@ -2151,6 +2353,10 @@ def llama_loss(params, batch: Dict[str, jax.Array], cfg: LlamaConfig,
         raise NotImplementedError(
             "models/llama.py serves its expert model but does not train "
             "it: the router's load-balancing loss is not written")
+    if cfg.logits_scaling != 1.0:
+        raise NotImplementedError(
+            "models/llama.py's fused loss reads the head's products as they "
+            "are: logits_scaling is not written for it")
     toks = batch["tokens"]
     targets = toks[:, 1:]
     x = llama_hidden(params, toks[:, :-1], cfg, rules, mesh)

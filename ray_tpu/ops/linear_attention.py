@@ -102,6 +102,7 @@ from ray_tpu.ops import linear_state
 from ray_tpu.ops.kernel_source import kernels_compiled as _kernel_backend
 
 CHUNK = 64
+SSM_CHUNK = 128          # of the rule without the correction: products alone
 SUB_CHUNK = 16           # of a chunk, where the decay is a key channel's
 GROUP = 8                # chunks whose products are made together, there
 LANES = 128
@@ -110,14 +111,17 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 # ------------------------------------------------------------ convolution
 
-def causal_conv(x: jax.Array, w: jax.Array, silu: bool = True) -> jax.Array:
+def causal_conv(x: jax.Array, w: jax.Array, silu: bool = True,
+                bias=None) -> jax.Array:
     """SiLU of the causal depthwise convolution of x [S, C] over time with
     w [K, C] (``w[K - 1]`` meets the position itself, zeros lie before the
-    sequence), computed in float32, in x's dtype; without ``silu`` the
-    convolution itself."""
+    sequence) plus ``bias`` [C] where there is one, computed in float32, in
+    x's dtype; without ``silu`` the convolution itself."""
     K, S = w.shape[0], x.shape[0]
     xp = jnp.pad(x.astype(jnp.float32), ((K - 1, 0), (0, 0)))
     y = sum(xp[i:i + S] * w[i].astype(jnp.float32) for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return (jax.nn.silu(y) if silu else y).astype(x.dtype)
 
 
@@ -130,14 +134,18 @@ def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
 
 
 def causal_conv_step(x: jax.Array, w: jax.Array, tail: jax.Array,
-                     silu: bool = True) -> Tuple[jax.Array, jax.Array]:
+                     silu: bool = True,
+                     bias=None) -> Tuple[jax.Array, jax.Array]:
     """One position a slot: x [B, C] after the ``tail`` [B, (K - 1) * C] of
-    ``conv_tail``.  Returns (the convolution's SiLU [B, C], or without
-    ``silu`` the convolution itself; the next tail)."""
+    ``conv_tail``.  Returns (the convolution's SiLU [B, C], with ``bias``
+    [C] added first where there is one, or without ``silu`` the convolution
+    itself; the next tail)."""
     K, C = w.shape
     window = [tail[:, i * C:(i + 1) * C] for i in range(K - 1)] + [x]
     y = sum(a.astype(jnp.float32) * w[i].astype(jnp.float32)
             for i, a in enumerate(window))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return (jax.nn.silu(y) if silu else y).astype(x.dtype), \
         jnp.concatenate(window[1:], axis=-1).astype(tail.dtype)
 
@@ -150,8 +158,10 @@ def _panel_plan(N: int, dv: int) -> Tuple[int, int, int]:
     rest = dv % LANES
     if not rest:
         return LANES, dv // LANES, 0
-    if dv < LANES or LANES % rest or N % (LANES // rest):
+    if LANES % rest or N % (LANES // rest):
         return dv, 1, 0                  # plain: a head's columns, one panel
+    # (heads narrower than a panel have no whole one: ``side`` of them lie
+    # side by side in each, Mamba-2's 64 values a head two to a panel)
     return LANES, dv // LANES, LANES // rest
 
 
@@ -190,7 +200,7 @@ def _keys_to_panels(k: jax.Array, N: int, dv: int) -> jax.Array:
     arrays (a panel's first head, its second, ...), for the compiler to
     fuse into what reads the state."""
     W, whole, side = _panel_plan(N, dv)
-    full = jnp.repeat(k, whole, axis=-2) if whole > 1 else k
+    full = jnp.repeat(k, whole, axis=-2) if whole != 1 else k
     if not side:
         return jnp.broadcast_to(full[..., None], (*full.shape, W))
     # head ``m * side + at`` of left-over panel m holds lanes ``at * W / side``
@@ -469,27 +479,122 @@ def kda_step(q, k, v, g, beta, folded):
     return _panels_to_values(o, N, dv), folded
 
 
+# ------------------------- the rule without the correction (Mamba-2's)
+
+def ssm_recurrent(q, k, v, g, state=None):
+    """The state-space rule as it is written, a position at a time: q, k
+    [S, dk] (ONE query and ONE key a position for all heads: Mamba-2's ``C``
+    and ``B`` with one group), v [S, N, dv] (a head's input times its step
+    size), g = log a [S, N], all float32; ``state`` [N, dk, dv] or zeros.
+    ``S_n <- a_n S_n + k v_n^T``, ``o_n = S_n^T q``.  Returns (o [S, N, dv],
+    the state after the last position)."""
+    N, dv, dk = v.shape[1], v.shape[2], q.shape[1]
+    if state is None:
+        state = jnp.zeros((N, dk, dv), jnp.float32)
+
+    def position(S, row):
+        q_t, k_t, v_t, g_t = row
+        S = S * jnp.exp(g_t)[:, None, None] \
+            + k_t[None, :, None] * v_t[:, None, :]
+        return S, jnp.einsum("nij,i->nj", S, q_t, precision=_HIGHEST)
+
+    state, o = jax.lax.scan(position, state, (q, k, v, g))
+    return o, state
+
+
+def ssm_chunked(q, k, v, g, length=None, chunk: int = SSM_CHUNK):
+    """``ssm_recurrent`` over a whole sequence from an empty state, ``chunk``
+    positions at a time (State Space Duality's blocks, arXiv:2405.21060): a
+    chunk's positions meet each other through ONE matrix of products ``q_i .
+    k_j`` that all heads share, times a head's decay between the two, and
+    the chunks before it through the state; products alone, no triangular
+    solve, because nothing a position writes depends on what the state
+    held.  Positions from ``length`` on (None: there are none) leave the
+    state alone (a = 1 and nothing written).  Returns (o [S, N, dv], the
+    state after position ``length - 1`` [N, dk, dv])."""
+    S_len, N, dv = v.shape
+    dk = q.shape[-1]
+    if length is not None:
+        real = jnp.arange(S_len) < length
+        g = jnp.where(real[:, None], g, 0.0)
+        v = jnp.where(real[:, None, None], v, 0.0)
+    chunk = min(chunk, S_len)
+    pad = -S_len % chunk
+    C = (S_len + pad) // chunk
+
+    def chunks(a):       # [S, ...] -> [C, chunk, ...]
+        a = jnp.pad(a, ((0, pad), *((0, 0),) * (a.ndim - 1)))
+        return a.reshape(C, chunk, *a.shape[1:])
+    q, k, v, g = map(chunks, (q, k, v, g))
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=_HIGHEST)
+    G = jnp.cumsum(g, axis=1)                            # [C, c, N]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # a head's decay from position j to position i >= j of a chunk; the
+    # exponent is never above 0
+    decay = jnp.exp(jnp.where(lower[None, :, :, None],
+                              G[:, :, None] - G[:, None], -jnp.inf))
+    within = mm("cid,cjd->cij", q, k)[..., None] * decay  # [C, c, c, N]
+    inside = mm("cijn,cjnp->cinp", within, v)
+    # what a chunk adds to the state that reaches its end, and how much of
+    # the state before it is left there
+    last = G[:, -1]                                      # [C, N]
+    written = mm("cjd,cjnp->cndp", k,
+                 v * jnp.exp(last[:, None] - G)[..., None])
+
+    def one_chunk(S, xs):
+        q_c, G_c, last_c, written_c = xs
+        o = mm("id,ndp->inp", q_c, S) * jnp.exp(G_c)[..., None]
+        return S * jnp.exp(last_c)[:, None, None] + written_c, o
+
+    state, before = jax.lax.scan(
+        one_chunk, jnp.zeros((N, dk, dv), jnp.float32),
+        (q, G, last, written))
+    o = (inside + before).reshape(C * chunk, N, dv)
+    return o[:S_len], state
+
+
+def ssm_step(q, k, v, g, folded):
+    """One position for each slot on the folded states: q, k [B, dk], v [B,
+    N, dv], g [B, N], ``folded`` [B, panels, dk, lanes].  ``o = a S^T q + (k
+    . q) v`` is the new state's read-out written out, the new state ``a S +
+    k v^T``.  Returns (o [B, N, dv], the folded states after it)."""
+    N, dv = v.shape[-2], v.shape[-1]
+    alpha = _values_to_panels(
+        jnp.broadcast_to(jnp.exp(g)[..., None], v.shape), N, dv)
+    Vp = _values_to_panels(v, N, dv)
+    r_q = jnp.sum(folded * q[:, None, :, None], axis=-2)     # S^T q
+    o = alpha * r_q + jnp.sum(k * q, axis=-1)[:, None, None] * Vp
+    folded = alpha[..., None, :] * folded \
+        + k[:, None, :, None] * Vp[..., None, :]
+    return _panels_to_values(o, N, dv), folded
+
+
 # ------------------------------------------------ the step, on the pool
 
 # the module's own two steps: a caller that replaces one (a numerics tool
 # planting a fault) gets its step run, on every backend
-_OWN_STEPS = (gated_delta_step, kda_step)
+_OWN_STEPS = (gated_delta_step, kda_step, ssm_step)
 
 
-def state_step_kind(pool, N: int, dv: int) -> str:
+def state_step_kind(pool, N: int, dv: int, shared: bool = False) -> str:
     """What ``step_pool`` steps the states of ``N`` heads of ``dv`` values
     in ``pool`` (an array or its shape) with, "kernel" (``ops/
-    linear_state.py``) or "rule" (``gated_delta_step`` / ``kda_step`` on the
-    layer's slab), from what it can observe: the backend, whether the
-    kernel is written for the pool (``linear_state.supported``: float32,
-    folded into panels of 128 lanes, key channels of whole sublane tiles),
-    and whether this module's two steps are still its own.  Measured on the
-    chip at both published shapes (``scripts/linear_state_sweep.py``;
-    PERF.md section 6, PR 52)."""
+    linear_state.py``) or "rule" (``gated_delta_step`` / ``kda_step`` /
+    ``ssm_step`` on the layer's slab), from what it can observe: the
+    backend, whether the kernel is written for the pool
+    (``linear_state.supported``: float32, folded into panels of 128 lanes,
+    key channels of whole sublane tiles; ``shared``: one key and one query
+    for all heads, the rule without the correction), and whether this
+    module's steps are still its own.  Measured on the chip at the
+    published shapes (``scripts/linear_state_sweep.py``; PERF.md section 6,
+    PR 52 and PR 61)."""
     _, whole, side = _panel_plan(N, dv)
-    if _kernel_backend() and (gated_delta_step, kda_step) == _OWN_STEPS \
+    if _kernel_backend() \
+            and (gated_delta_step, kda_step, ssm_step) == _OWN_STEPS \
             and linear_state.supported(pool.shape, pool.dtype, N, whole,
-                                       side):
+                                       side, shared):
         return "kernel"
     return "rule"
 
@@ -500,24 +605,40 @@ def step_pool(q, k, v, g, beta, pool, layer, live):
     head's) or [B, N, dk] (a key channel's), ``pool`` [L, B, panels, dk,
     lanes] float32 (every linear layer's folded states), ``layer`` the
     layer's index into it, ``live`` [B] bool: a slot that is not keeps its
-    rows to the bit.  Returns (o [B, N, dv], the pool)."""
+    rows to the bit.  With ``beta`` None the rule is the one WITHOUT the
+    correction (``ssm_step``): q, k [B, dk] are one query and one key a slot
+    for all heads, which no program spreads over the heads in HBM.  Returns
+    (o [B, N, dv], the pool)."""
     N, dv = v.shape[-2], v.shape[-1]
-    if state_step_kind(pool, N, dv) == "rule":
+    shared = beta is None
+    if state_step_kind(pool, N, dv, shared) == "rule":
         held = pool[layer]
-        step = kda_step if g.ndim == 3 else gated_delta_step
-        o, state = step(q, k, v, g, beta, held)
+        if shared:
+            o, state = ssm_step(q, k, v, g, held)
+        else:
+            step = kda_step if g.ndim == 3 else gated_delta_step
+            o, state = step(q, k, v, g, beta, held)
         state = jnp.where(live[:, None, None, None], state, held)
         return o, jax.lax.dynamic_update_index_in_dim(pool, state, layer, 0)
-    if g.ndim == 2:      # a head's decay: its key channels' all equal
-        g = jnp.broadcast_to(g[..., None], k.shape)
 
     def per_head(a):     # [B, N] -> its head's value for every column
         return _values_to_panels(
             jnp.broadcast_to(a[..., None], v.shape), N, dv)
-    rows = jnp.stack([_values_to_panels(v, N, dv), per_head(beta),
-                      per_head(jnp.sum(k * q, axis=-1))], axis=1)
     _, whole, side = _panel_plan(N, dv)
+    if shared:
+        # what varies along a panel's lanes: v, the head's decay, k . q
+        rows = jnp.stack([
+            _values_to_panels(v, N, dv), per_head(jnp.exp(g)),
+            jnp.broadcast_to(jnp.sum(k * q, axis=-1)[:, None, None],
+                             (v.shape[0], *pool.shape[2:3], LANES))], axis=1)
+        cols = linear_state.columns(k[:, None], q[:, None])
+    else:
+        if g.ndim == 2:  # a head's decay: its key channels' all equal
+            g = jnp.broadcast_to(g[..., None], k.shape)
+        rows = jnp.stack([_values_to_panels(v, N, dv), per_head(beta),
+                          per_head(jnp.sum(k * q, axis=-1))], axis=1)
+        cols = linear_state.columns(jnp.exp(g), k, q)
     o, pool = linear_state.state_step(
-        pool, layer, live, linear_state.columns(jnp.exp(g), k, q), rows,
-        heads=N, whole=whole, side=side)
+        pool, layer, live, cols, rows, heads=N, whole=whole, side=side,
+        shared=shared)
     return _panels_to_values(o, N, dv), pool

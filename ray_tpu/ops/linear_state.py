@@ -98,17 +98,19 @@ kernel_source.exclude(__file__)
 
 
 def supported(pool_shape, pool_dtype, heads: int, whole: int,
-              side: int) -> bool:
+              side: int, shared: bool = False) -> bool:
     """Whether the kernel is written for this pool: float32 panels of whole
     128-lane tiles, a key channel a sublane of whole 8-sublane tiles, as many
     panels as ``heads`` heads fold into (``whole`` panels a head and one of
     left-over columns for every ``side`` heads), and every head's three
-    columns in one lane tile (the kernel turns that tile as a whole)."""
+    columns in one lane tile (the kernel turns that tile as a whole);
+    ``shared``: one key and one query for all heads, two columns whatever
+    the heads."""
     if len(pool_shape) != 5:
         return False
     panels, dk, lanes = pool_shape[2:]
     return (jnp.dtype(pool_dtype) == jnp.float32 and lanes == _LANES
-            and dk % _SUBLANES == 0 and 3 * heads <= _LANES
+            and dk % _SUBLANES == 0 and (shared or 3 * heads <= _LANES)
             and panels == heads * whole + (heads // side if side else 0))
 
 
@@ -125,16 +127,18 @@ def _turn(groups: int, panels: int) -> int:
                if groups % u == 0 and (u == 1 or u * panels <= _TURN_PANELS))
 
 
-def columns(a, k, q):
+def columns(*parts):
     """The operand ``cols`` [B, dk, 128] of a, k, q [B, N, dk]: head n's a,
-    k and q in lanes n, N + n and 2 N + n."""
-    cols = jnp.concatenate([a, k, q], axis=1).astype(jnp.float32)
+    k and q in lanes n, N + n and 2 N + n; of the SHARED rule's k, q [B, 1,
+    dk]: lanes 0 and 1."""
+    cols = jnp.concatenate(parts, axis=1).astype(jnp.float32)
     return jnp.swapaxes(
         jnp.pad(cols, ((0, 0), (0, _LANES - cols.shape[1]), (0, 0))), 1, 2)
 
 
 def state_step(pool, layer, live, cols, rows, *, heads: int, whole: int,
-               side: int, slots: Optional[int] = None,
+               side: int, shared: bool = False,
+               slots: Optional[int] = None,
                unroll: Optional[int] = None,
                interpret: Optional[bool] = None):
     """One position of the rule for every slot on layer ``layer`` of
@@ -142,11 +146,14 @@ def state_step(pool, layer, live, cols, rows, *, heads: int, whole: int,
     not keeps its rows), ``cols`` [B, dk, 128] (``columns``), ``rows`` [B, 3,
     panels, 128] (v, beta and k . q as the panels lie), ``heads`` heads
     folded ``whole`` whole panels each and ``side`` to a panel of left-over
-    columns (0: none are left over); ``slots`` slots a grid step and
-    ``unroll`` groups of heads a turn of the loop (the module's choices
-    unless a sweep or a test says).  Returns (o [B, panels, 128], the pool:
-    its argument's buffer where the caller donates it)."""
-    if not supported(pool.shape, pool.dtype, heads, whole, side):
+    columns (0: none are left over); ``shared`` (static, like the layout):
+    the rule WITHOUT the correction, ``S <- a S + k v^T``, one key and one
+    query a slot for all heads in lanes 0 and 1 of ``cols`` and a head's
+    decay, a value a COLUMN, where ``rows`` has beta; ``slots`` slots a grid
+    step and ``unroll`` groups of heads a turn of the loop (the module's
+    choices unless a sweep or a test says).  Returns (o [B, panels, 128],
+    the pool: its argument's buffer where the caller donates it)."""
+    if not supported(pool.shape, pool.dtype, heads, whole, side, shared):
         raise ValueError(
             f"no linear-state kernel for a pool {pool.dtype}"
             f"{list(pool.shape)} of {heads} heads, {whole} whole panels a "
@@ -164,17 +171,19 @@ def state_step(pool, layer, live, cols, rows, *, heads: int, whole: int,
             f"of {unroll}")
     return _step(pool, jnp.asarray(layer, jnp.int32).reshape(1),
                  live.astype(jnp.int32), cols, rows, heads=heads,
-                 whole=whole, side=side, slots=slots,
+                 whole=whole, side=side, shared=shared, slots=slots,
                  unroll=unroll, interpret=interpret)
 
 
 # jitted and inlined where it is called, as ``paged_read._walk`` is: a
 # model's programs trace the kernel once a shape, not once a call.
 @functools.partial(jax.jit, static_argnames=("heads", "whole", "side",
-                                             "slots", "unroll", "interpret"),
+                                             "shared", "slots", "unroll",
+                                             "interpret"),
                    inline=True)
 def _step(pool, where, live, cols, rows, *, heads: int, whole: int,
-          side: int, slots: int, unroll: int, interpret: bool):
+          side: int, shared: bool, slots: int, unroll: int,
+          interpret: bool):
     _, B, panels, dk, W = pool.shape
     N, C = heads, cols.shape[2]
     # the heads of a group: those of one panel of left-over columns
@@ -183,7 +192,16 @@ def _step(pool, where, live, cols, rows, *, heads: int, whole: int,
     def kernel(where_ref, live_ref, cols_ref, rows_ref, s_ref, o_ref,
                out_ref):
         def panel(s, p, a, k, q):
-            """Panel ``p`` of slot ``s`` under its rows' a, k, q [dk, W]."""
+            """Panel ``p`` of slot ``s`` under its rows' a, k, q [dk, W];
+            the shared rule's a is None: the decay is a value a column, the
+            second of the panel's rows, and nothing is corrected."""
+            if a is None:
+                v, a, kq = (rows_ref[s, i, pl.ds(p, 1), :] for i in range(3))
+                decayed = a * s_ref[s, p]
+                r_q = jnp.sum(decayed * q, axis=0, keepdims=True)
+                o_ref[s, pl.ds(p, 1), :] = r_q + kq * v
+                out_ref[s, p] = decayed + k * v
+                return
             decayed = a * s_ref[s, p]
             r_k = jnp.sum(decayed * k, axis=0, keepdims=True)
             r_q = jnp.sum(decayed * q, axis=0, keepdims=True)
@@ -197,7 +215,16 @@ def _step(pool, where, live, cols, rows, *, heads: int, whole: int,
 
             def one_group(m, at0, turned):
                 """Group ``m``, whose first head's columns lie in lanes
-                ``at0``, ``N + at0`` and ``2 N + at0`` of ``turned``."""
+                ``at0``, ``N + at0`` and ``2 N + at0`` of ``turned``; the
+                shared rule's one key and query in lanes 0 and 1."""
+                if shared:
+                    k, q = (jnp.broadcast_to(turned[:, x:x + 1], (dk, W))
+                            for x in range(2))
+                    for j in range(group * whole):
+                        panel(s, m * group * whole + j, None, k, q)
+                    if side:
+                        panel(s, N * whole + m, None, k, q)
+                    return
                 spread = []
                 for at in range(group):
                     akq = [jnp.broadcast_to(
@@ -221,7 +248,8 @@ def _step(pool, where, live, cols, rows, *, heads: int, whole: int,
                 # rotation of the tile): its heads' a, k, q then lie in
                 # lanes known here, at, N + at, 2 N + at
                 first = t * unroll * group
-                turned = pltpu.roll(cols_ref[s], C - first, 1)
+                turned = cols_ref[s] if shared else \
+                    pltpu.roll(cols_ref[s], C - first, 1)
                 for u in range(unroll):
                     one_group(t * unroll + u, u * group, turned)
                 return _

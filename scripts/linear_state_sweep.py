@@ -1,16 +1,19 @@
 """The token step of a linear layer alone on the chip, the kernel
 (``ops/linear_state.py``) against the jnp rule (``gated_delta_step`` /
-``kda_step`` on the layer's slab, selected and written back, as the CPU
-backend's program has it), at the two published shapes (PERF.md section 6,
-PR 52): the evidence ``ops/linear_attention.py::state_step_kind`` stands on,
-and what ``ops/linear_state.py::_SLOTS`` was chosen from.
+``kda_step`` / ``ssm_step`` on the layer's slab, selected and written back,
+as the CPU backend's program has it), at the three published shapes (PERF.md
+section 6, PR 52 and PR 61): the evidence
+``ops/linear_attention.py::state_step_kind`` stands on, and what
+``ops/linear_state.py::_SLOTS`` was chosen from.
 
     chiprun -- python scripts/linear_state_sweep.py [--cells NAME ...]
                     [--slots N ...] [--unroll N ...] [--parked N] [--seed N]
 
 A cell's shape is its engine's (``benchmark/configs/*.json``: slots, linear
 layers, heads, key and value widths) and the decay its rule's: a head's
-scalar (Gated DeltaNet) or a key channel's (Kimi Delta Attention).  Each form
+scalar (Gated DeltaNet), a key channel's (Kimi Delta Attention), or
+"shared": the rule without a correction, one key and one query a slot for all
+heads (Mamba-2).  Each form
 is timed alone: 36 calls chained in one jitted ``lax.scan`` through the
 donated pool (the layer goes round the pool's; a call's read-out is the next
 call's values), every slot live but ``--parked`` of them.  ``rule`` is
@@ -52,10 +55,11 @@ CALLS = 36
 HBM_BYTES_PER_S = 819e9
 
 # name: slots, linear layers, heads, key width, value width, whether the
-# decay is a key channel's
+# decay is a key channel's ("shared": the rule without a correction)
 CELLS = {
     "serve-olmo-hybrid-decode-wide": (48, 9, 30, 96, 192, False),
     "serve-kimi-linear-reasoning-wide": (64, 6, 32, 128, 128, True),
+    "serve-granite-h-decode-wide": (64, 9, 128, 128, 64, "shared"),
 }
 
 
@@ -131,8 +135,10 @@ def main():
         k = la.l2_normalise(jax.random.normal(keys[1], (B, N, dk)))
         v = jax.random.normal(keys[2], (B, N, dv))
         g = -0.5 * jax.random.uniform(
-            keys[3], (B, N, dk) if channel else (B, N))
+            keys[3], (B, N, dk) if channel is True else (B, N))
         beta = jax.nn.sigmoid(jax.random.normal(keys[4], (B, N)))
+        if channel == "shared":      # one key and query a slot, no beta
+            q, k, beta = q[:, 0], k[:, 0], None
         live = jnp.arange(B) < B - args.parked
         shape = (L, B, *la.state_shape(N, dk, dv))
         pool = jax.random.normal(keys[5], shape)

@@ -199,10 +199,20 @@ def pytest_configure(config):
 # out, as it always was first (its rehearsals trace a second and a half of a
 # three-second window, so they keep the company they have passed in), and the
 # run ends when the work does: 1,416 -> 1,325 s in the same hour.
+# PR 61: every file outside ``tests/benchmark/`` that took 130 s or more of
+# one worker in a whole run of six (the junit file's times), longest first: a
+# file of 150-250 s that starts in the run's last minutes idles five workers
+# as surely as one of 430 s (the run with PR 61's files was 8,350 s of work,
+# 1,392 s a worker, and ended at 1,447 s: 55 s of idle workers at its end).
 LONG_FILES = (
-    "tests/test_tpu_compile.py", "tests/test_serve_resilience.py",
-    "tests/test_pipeline.py", "tests/test_models.py",
-    "tests/test_tpu_compile_kimi_linear.py",
+    "tests/test_tpu_compile_models.py", "tests/test_models.py",
+    "tests/test_serve_resilience.py", "tests/test_linear_attention.py",
+    "tests/test_kda.py", "tests/test_tpu_compile_kimi_linear.py",
+    "tests/test_pipeline.py", "tests/test_llama_kimi_linear.py",
+    "tests/test_engine_kimi_linear.py", "tests/test_tpu_compile.py",
+    "tests/test_gbdt_sklearn_trainer.py", "tests/test_moe_dead_rows.py",
+    "tests/test_ops.py", "tests/test_paged_attention_kernel.py",
+    "tests/test_moe_dropless.py", "tests/test_serve_streaming.py",
 )
 
 

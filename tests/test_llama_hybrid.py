@@ -175,7 +175,8 @@ def test_what_is_not_written_refuses_with_a_message(params):
 @pytest.mark.parametrize("change,message", [
     ({"block_length": 4, "denoise_steps": 2}, "not written for"),
     ({"ut_steps": 2}, "not written for"),
-    ({"num_experts": 4, "experts_per_token": 2}, "not written for"),
+    # (experts in every layer of a pattern stack serve since PR 61)
+    ({"hc_mult": 2}, "not written for"),
     ({"layer_pattern": ("full", "full")}, "at least one of them linear"),
     ({"layer_pattern": ("linear", "window")}, "one period of"),
     ({"num_layers": 6}, "whole periods"),

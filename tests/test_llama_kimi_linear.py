@@ -241,8 +241,10 @@ def test_what_check_allows_now(change):
 
 
 @pytest.mark.parametrize("cfg,change,message", [
-    (CFG, {"first_dense_layers": 0, "dense_mlp_dim": 0},
-     "num_experts without first_dense_layers"),
+    # (experts with no leading dense layer serve since PR 61; a stack that
+    # mixes two of the slot kinds is still refused)
+    (CFG, {"layer_pattern": ("linear", "ssm", "linear", "full")},
+     "ONE other kind"),
     (CFG, {"hc_mult": 4}, "not written for"),
     (CFG, {"ut_steps": 2}, "not written for"),
     (CFG, {"block_length": 4, "denoise_steps": 2}, "not written for"),

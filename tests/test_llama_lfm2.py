@@ -229,8 +229,9 @@ def test_what_check_allows_now(change):
     (CFG, {"kv_lora_rank": 8, "qk_nope_dim": 8, "qk_rope_dim": 8,
            "v_head_dim": 8, "rope_theta": 0.0, "head_size": 0,
            "qk_norm_per_head": False}, "no kv_lora_rank"),
-    (CFG, {"first_dense_layers": 0, "dense_mlp_dim": 0},
-     "num_experts without first_dense_layers"),
+    # (experts with no leading dense layer serve since PR 61; a
+    # hyper-connected stream under a pattern is still refused)
+    (CFG, {"hc_mult": 2}, "hc_mult"),
     (CFG, {"ut_steps": 2}, "ut_steps"),
     (PLAIN, {"block_length": 4, "denoise_steps": 2}, "block_length"),
     (CFG, {"layer_pattern": ("linear", "full") * 3}, "linear_heads")])
